@@ -14,6 +14,7 @@ from .bucket import (
     DEFAULT_MIN_BUCKET,
     BucketLadder,
     backend_compiles,
+    compile_cache_hits,
     dispatch_signature,
     mesh_key,
 )
@@ -70,6 +71,7 @@ __all__ = [
     "cache_view",
     "cells_prog",
     "clear_caches",
+    "compile_cache_hits",
     "core_for",
     "data_mesh",
     "dispatch_signature",

@@ -127,7 +127,7 @@ def dispatch_signature(
     )
 
 
-_METER = {"installed": False, "count": 0}
+_METER = {"installed": False, "count": 0, "cache_hits": 0}
 
 
 def _install_meter() -> None:
@@ -141,7 +141,12 @@ def _install_meter() -> None:
             if name.endswith("backend_compile_duration"):
                 _METER["count"] += 1
 
+        def _on_event(name: str, **kw) -> None:
+            if name.endswith("compilation_cache/cache_hits"):
+                _METER["cache_hits"] += 1
+
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
         _METER["available"] = True
     except Exception:  # lint: broad-except-ok (xla monitoring listener is optional; meter reports unavailable)
         _METER["available"] = False
@@ -151,6 +156,21 @@ def backend_compiles() -> int | None:
     """Process-wide XLA backend-compile count since the meter was first
     read (monotonic; diff two reads to scope a region). ``None`` when
     this jax build exposes no monitoring hook — callers fall back to
-    signature counting, which upper-bounds real compiles."""
+    signature counting, which upper-bounds real compiles.
+
+    jax times the compile stage around its persistent-cache lookup, so
+    a program loaded from a warm `jax_compilation_cache_dir` fires the
+    same duration event as one XLA compiled; those loads are subtracted
+    here (and counted by :func:`compile_cache_hits`) — this number is
+    what the backend actually compiled."""
     _install_meter()
-    return _METER["count"] if _METER.get("available") else None
+    if not _METER.get("available"):
+        return None
+    return _METER["count"] - _METER["cache_hits"]
+
+
+def compile_cache_hits() -> int | None:
+    """Programs served from JAX's persistent compilation cache instead
+    of being compiled (0 when no cache directory is configured)."""
+    _install_meter()
+    return _METER["cache_hits"] if _METER.get("available") else None

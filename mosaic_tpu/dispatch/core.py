@@ -180,23 +180,6 @@ def _stats_of(cached_fn) -> dict:
     return out
 
 
-def _jit_cache_size(fn) -> int:
-    try:
-        return int(fn._cache_size())
-    except Exception:  # lint: broad-except-ok (jax version without the introspection hook; -1 means unknown)
-        return -1
-
-
-def _clear_jit(fn) -> None:
-    try:
-        fn.clear_cache()
-    except Exception:  # lint: broad-except-ok (older jax spells it _clear_cache)
-        try:
-            fn._clear_cache()
-        except Exception:  # lint: broad-except-ok (no clear hook on this jax; cache drops at process exit)
-            pass
-
-
 def cache_view(name: str) -> dict:
     """`{hits, misses, maxsize, currsize}` for one registered cache
     (zeros if it was never created — nothing is cached yet)."""
@@ -217,9 +200,9 @@ def cache_stats(emit: bool = True) -> dict:
     growth and decide when to call :func:`clear_caches`."""
     stats = {name: _stats_of(c) for name, c in sorted(_CACHES.items())}
     stats["jit_programs"] = {
-        "join": _jit_cache_size(jit_join()),
-        "counts": _jit_cache_size(jit_counts()),
-        "compact": _jit_cache_size(jit_compact()),
+        "join": jit_join()._cache_size(),
+        "counts": jit_counts()._cache_size(),
+        "compact": jit_compact()._cache_size(),
     }
     if emit:
         _telemetry.record("dispatch_cache_stats", **stats)
@@ -244,7 +227,7 @@ def clear_caches(names=None, emit: bool = True) -> dict:
     )
     for name, c in targets:
         if name in _JIT_FACTORIES and c.cache_info().currsize:
-            _clear_jit(c())
+            c().clear_cache()
         c.cache_clear()
     if emit:
         _telemetry.record("dispatch_caches_cleared", **stats)
@@ -322,8 +305,8 @@ def join_cache_view() -> dict:
     "jit_compact": n}`)."""
     return {
         "cells_prog": cache_view("cells_prog"),
-        "jit_join": _jit_cache_size(jit_join()),
-        "jit_compact": _jit_cache_size(jit_compact()),
+        "jit_join": jit_join()._cache_size(),
+        "jit_compact": jit_compact()._cache_size(),
     }
 
 
@@ -424,14 +407,12 @@ def sharded_pointwise(fn, mesh: Mesh, *, n_out: int = 1, check_rep: bool = True)
     output axis 0 is point-sharded. Because every per-point result
     depends only on that point and the replicated index, the wrapped
     program is bit-identical to single-device execution."""
-    from ..parallel._compat import shard_map as _shard_map
-
     pspec = P(mesh.axis_names)
     ispec = _replicated_index_specs()
     out_specs = pspec if n_out == 1 else tuple(pspec for _ in range(n_out))
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(pspec, pspec, ispec),
-        out_specs=out_specs, check_rep=check_rep,
+        out_specs=out_specs, check_vma=check_rep,
     )
 
 
@@ -695,7 +676,13 @@ class DispatchCore:
         except (ProgramStoreCorrupt, ProgramFingerprintMismatch):
             pass  # typed telemetry already recorded by the store
         if payload is not None:
-            fn = deserialize_compiled(payload, example_args, out_aval)
+            # the store serves single-device cores only (a meshed core
+            # refuses it above), so every persisted program was compiled
+            # for the device that holds the index
+            fn = deserialize_compiled(
+                payload, example_args, out_aval,
+                devices=self.index.cells.devices(),
+            )
             self.aot_stats["loaded"] += 1
             return fn
         compiled = compile_fn()

@@ -28,9 +28,9 @@ frontend (`StreamJoin.run_durable` rides it for segments,
   output appends, snapshot submission) belong in the separate
   ``commit`` callback, which runs on the caller thread only after the
   guarded pull returned — a timed-out pull therefore commits nothing.
-- **replay** is the transient-failure contract: a stall or tunnel drop
-  surfacing at the drain poisons everything in flight, so the pipeline
-  discards the window and replays ``[last materialized + 1, last
+- **replay** is the transient-failure contract: a stall or dropped
+  connection surfacing at the drain poisons everything in flight, so
+  the pipeline discards the window and replays ``[last materialized + 1, last
   launched]`` synchronously through the caller's guarded path (full
   retry budget + host-oracle degradation, unchanged), then resumes
   pipelining. Fatal (non-transient) errors drain what they can and
@@ -141,8 +141,8 @@ def execute_pipeline(
     ``commit`` returns, so a ``commit`` that raises a transient
     replays its own item rather than skipping or double-applying it.
 
-    A *transient* failure (``runtime.errors.is_transient``: tunnel
-    drops, typed stalls) at launch, drain, or commit discards the
+    A *transient* failure (``runtime.errors.is_transient``: dropped
+    connections, typed stalls) at launch, drain, or commit discards the
     in-flight window and calls ``replay(lo, hi)`` — the caller re-runs
     items ``lo..hi`` (inclusive) synchronously from its last
     materialized carry, with its own guarded retry/degradation

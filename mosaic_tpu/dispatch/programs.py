@@ -288,15 +288,24 @@ def serialize_compiled(compiled) -> bytes:
     return payload
 
 
-def deserialize_compiled(payload: bytes, example_args: tuple, out_aval):
+def deserialize_compiled(
+    payload: bytes, example_args: tuple, out_aval, devices
+):
     """Reload a payload as a callable, reconstructing the in/out
-    PyTreeDefs from the live prototypes the lowering saw."""
+    PyTreeDefs from the live prototypes the lowering saw.
+
+    ``devices`` are the devices the program was compiled for: left
+    unset, `deserialize_and_load` binds the executable to EVERY local
+    device, and a one-device program then refuses its one-shard
+    arguments on a multi-device host."""
     from jax.experimental import serialize_executable as _se
     from jax.tree_util import tree_structure
 
     in_tree = tree_structure((tuple(example_args), {}))
     out_tree = tree_structure(out_aval)
-    return _se.deserialize_and_load(payload, in_tree, out_tree)
+    return _se.deserialize_and_load(
+        payload, in_tree, out_tree, execution_devices=list(devices)
+    )
 
 
 def core_program_statics(core, bucket: int, kind: str) -> dict:

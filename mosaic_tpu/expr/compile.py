@@ -297,13 +297,11 @@ def overlay_program(
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel._compat import shard_map as _shard_map
-
         p, r = P(mesh.axis_names), P()
-        stage = _shard_map(
+        stage = jax.shard_map(
             per_pair, mesh=mesh,
             in_specs=(p, p, r, r, r, r, r, r, r, r, r, r, r, r),
-            out_specs=(p, p), check_rep=False,
+            out_specs=(p, p), check_vma=False,
         )
         # replicate the per-pair outputs before the fold: left sharded,
         # GSPMD would split the segment sum into per-shard partials plus

@@ -13,7 +13,8 @@ pixel folds nowhere" (nodata, tile pad, or no containing zone):
 - :func:`zonal_tiled` — the Pallas TPU lane (f32, like every Mosaic
   kernel: no f64 path on the MXU/VPU). Grid is (segment blocks, pixel
   blocks) with pixels innermost, so each (1, TILE_S) accumulator block
-  stays resident in VMEM while every pixel block streams past it; a
+  (a lane-aligned column window of one (1, S_pad) output row) stays
+  resident in VMEM while every pixel block streams past it; a
   pixel block broadcasts against the segment-lane iota and folds with
   one VPU reduction per statistic. Counts accumulate in f32 — exact up
   to 2**24 pixels per segment, a documented bound enforced at call
@@ -40,8 +41,10 @@ from .pip import TilingError
 __all__ = ["zonal_fold", "zonal_fold_masked", "zonal_tiled", "TilingError"]
 
 #: inert fill for min/max lanes — far beyond any geographic or sensor
-#: value, well inside f32 range (same constant family as kernels/pip.py)
-_BIG_F = 1e30
+#: value, well inside f32 range (same constant family as kernels/pip.py).
+#: Typed: under the package-wide x64 a python float enters the kernel as
+#: an f64 constant, and Mosaic has no f64 -> f32 cast.
+_BIG_F = np.float32(1e30)
 
 _I0 = np.int32(0)  # index-map literal: python 0 traces as i64 under x64
 
@@ -186,13 +189,15 @@ def zonal_tiled(
         )
 
     def acc_spec():
+        # one (1, s_pad) row windowed along the lane axis: a block's
+        # sublane extent must be a multiple of 8 or the whole axis, which
+        # a (1, tile_s) block of an (s_blocks, tile_s) array is not
         return pl.BlockSpec(
-            (1, tile_s), lambda s, p: (s, _I0),
+            (1, tile_s), lambda s, p: (_I0, s),
             memory_space=pltpu.VMEM,
         )
 
-    out_shape = jax.ShapeDtypeStruct((s_pad // tile_s, tile_s),
-                                     jnp.float32)
+    out_shape = jax.ShapeDtypeStruct((1, s_pad), jnp.float32)
     cnt, s, mn, mx = pl.pallas_call(
         functools.partial(_zonal_kernel, tile_n=tile_n, tile_s=tile_s),
         grid=grid,

@@ -165,7 +165,6 @@ def _sharded_point_pairs(mesh):
 
     from ..core.geometry.device import take_rows
     from ..functions.geometry import _distance_dense, _vmap_pair
-    from ..parallel._compat import shard_map as _shard_map
     from ..parallel.dist_overlay import geom_specs
 
     row = P(mesh.axis_names)
@@ -176,7 +175,7 @@ def _sharded_point_pairs(mesh):
         return _vmap_pair(_distance_dense, dq, take_rows(dcs, crows))
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=(rep, row, row), out_specs=row
         )
     )
@@ -356,7 +355,12 @@ class KNNFrontend:
         except (ProgramStoreCorrupt, ProgramFingerprintMismatch):
             pass  # typed telemetry already recorded by the store
         if payload is not None:
-            fn = deserialize_compiled(payload, example_args, out_aval)
+            # single-device programs (a meshed frontend refuses the
+            # store): compiled for the device holding the candidates
+            fn = deserialize_compiled(
+                payload, example_args, out_aval,
+                devices=self.kx.dc.verts.devices(),
+            )
             self.aot_stats["loaded"] += 1
             return fn
         compiled = compile_fn()

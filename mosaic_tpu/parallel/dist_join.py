@@ -28,7 +28,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.geometry.device import DeviceGeometry
-from ._compat import shard_map as _shard_map
 from ..dispatch import core as _dispatch
 from ..runtime import faults as _faults, telemetry as _telemetry
 from ..runtime.errors import DegradedResult, RetryExhausted
@@ -274,13 +273,13 @@ def distributed_join_step(
         counts = lax.psum(counts, mesh.axis_names)
         return match, counts
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(point_spec, point_spec, index_spec),
         out_specs=(point_spec, P()),
         # the heavy lane's pallas_call has no shard_map replication rule
-        check_rep=probe in ("scatter", "adaptive-light", "adaptive-convex"),
+        check_vma=probe in ("scatter", "adaptive-light", "adaptive-convex"),
     )
     return jax.jit(sharded)
 

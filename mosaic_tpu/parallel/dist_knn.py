@@ -21,7 +21,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..core.geometry.device import DeviceGeometry, take_rows
 from ..dispatch import core as _dispatch
 from ..runtime import telemetry as _telemetry
-from ._compat import shard_map as _shard_map
 from .dist_overlay import geom_specs
 
 
@@ -42,7 +41,7 @@ def _sharded_distance_fn(mesh: Mesh):
         )
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=(rep, rep, row, row), out_specs=row
         )
     )

@@ -18,7 +18,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.geometry.device import DeviceGeometry
-from ._compat import shard_map as _shard_map
 
 
 def geom_specs(row: P) -> DeviceGeometry:
@@ -83,7 +82,7 @@ def distributed_pair_intersects(
         return _vmap_pair(_dense, a, b)
 
     out = jax.jit(
-        _shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=(spec, spec), out_specs=P(mesh.axis_names)
         )
     )(da, db)

@@ -48,6 +48,7 @@ from ..kernels.zonal import zonal_fold, zonal_tiled
 from ..obs import trace as _trace
 from ..runtime import faults as _faults, telemetry as _telemetry
 from ..runtime.errors import CapacityOverflow
+from ..runtime.platform import interpret_kernels
 from ..sql.join import (
     EDGE_BAND_K,
     OVERFLOW,
@@ -256,7 +257,7 @@ class ZonalEngine:
                 if lane_resolved == "tiled":
                     return zonal_tiled(
                         vals, seg, g,
-                        interpret=jax.devices()[0].platform == "cpu",
+                        interpret=interpret_kernels(),
                     )
                 return zonal_fold(vals, seg, g, acc_dtype=acc_dt)
 

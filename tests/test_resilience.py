@@ -69,7 +69,15 @@ def test_backoff_delays_grow_and_cap():
 
 def test_is_transient_classification():
     assert is_transient(TransientDeviceError("x"))
-    assert is_transient(RuntimeError("remote_compile: HTTP 500"))
+    assert is_transient(RuntimeError("UNAVAILABLE: connection reset by peer"))
+    # deterministic compile refusals must raise at once, never retry
+    # into the host oracle (XLA and Mosaic report them under these codes)
+    assert not is_transient(
+        RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+    )
+    assert not is_transient(
+        RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem")
+    )
     assert not is_transient(ValueError("bad argument"))
     assert not is_transient(RuntimeError("shape mismatch"))
     assert not is_transient(TypeError("nope"))
@@ -246,6 +254,30 @@ def test_pip_join_retry_exhausted_degrades_to_host_oracle(problem):
     expect = host_join(pts, index.host, h3, RES)
     np.testing.assert_array_equal(np.asarray(out), expect)
     assert any(e["event"] == "degraded" for e in ev)
+
+
+def test_pip_join_compile_failure_raises_not_degrades(problem):
+    """A deterministic compile refusal (XLA and Mosaic report them as
+    ``INTERNAL``) must surface at once: retried and then answered by the
+    host oracle, the process would exit 0 having run nothing on the
+    device."""
+    h3, zones, index, pts, clean = problem
+
+    def refusal(site):
+        return RuntimeError(
+            "INTERNAL: Mosaic failed to compile TPU kernel: "
+            "scoped vmem limit exceeded"
+        )
+
+    with telemetry.capture() as ev:
+        with faults.transient_errors(
+            50, sites=("pip_join.device",), exc_factory=refusal
+        ) as plan:
+            with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+                pip_join(pts, None, h3, RES, chip_index=index, recheck=False)
+    assert plan.failed == 1  # raised on the first attempt, no retry
+    kinds = {e["event"] for e in ev}
+    assert not kinds & {"transient_retry", "retry_exhausted", "degraded"}
 
 
 def test_pip_join_points_still_reports_overflow_at_low_level(problem):

@@ -151,6 +151,6 @@ def test_ring_from_host_shape_and_residency(ring):
 def test_hbm_peak_never_zero(ring):
     """The r05 artifact recorded peak_hbm_bytes: 0 — the census fallback
     must always see at least the resident ring."""
-    peak, source = hbm_peak(fallback_arrays=[ring])
+    peak, source = hbm_peak()
     assert peak > 0
     assert source  # a named source, never silent

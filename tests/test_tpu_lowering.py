@@ -35,13 +35,6 @@ def _tpu_lower(traced):
     return traced.lower(lowering_platforms=("tpu",)).as_text()
 
 
-@pytest.mark.xfail(
-    reason="this jax build's Mosaic lowering has no rule for integer "
-    "min reductions inside the Pallas kernel (LoweringException in "
-    "pallas/mosaic/lowering.py on the int32 jnp.min); lowers fine on "
-    "newer jax — environment-bound, PR 3 triage",
-    strict=False,
-)
 def test_pallas_pip_kernel_lowers_for_tpu():
     from mosaic_tpu.core.geometry import wkt
     from mosaic_tpu.core.geometry.device import pack_to_device
@@ -57,6 +50,57 @@ def test_pallas_pip_kernel_lowers_for_tpu():
 
     hlo = _tpu_lower(jax.jit(f).trace(pts, planes))
     assert "tpu_custom_call" in hlo  # the Pallas kernel actually lowered
+
+
+@pytest.mark.parametrize("banded", [False, True])
+@pytest.mark.parametrize("heavy_rows", [40, 128, 129, 300])
+def test_pallas_heavy_kernel_lowers_for_tpu(heavy_rows, banded):
+    """`pip_heavy_tiled`, plain and banded (the recheck kernel), with
+    heavy-row counts on both sides of the 128-lane tile."""
+    from mosaic_tpu.kernels.pip import pip_heavy_tiled
+
+    H, E2, M2, K = heavy_rows, 48, 2, 1000
+    args = (
+        jnp.zeros(K, jnp.float32), jnp.zeros(K, jnp.float32),
+        jnp.zeros(K, jnp.int32),
+        jnp.zeros((H, E2, 4), jnp.float32), jnp.zeros((H, E2), jnp.uint32),
+        jnp.zeros((H, M2), jnp.int32),
+    )
+
+    def f(px, py, rows, edges, ebits, geom):
+        return pip_heavy_tiled(
+            px, py, rows, edges, ebits, geom,
+            eps2=jnp.float32(1e-6) if banded else None,
+        )
+
+    hlo = _tpu_lower(jax.jit(f).trace(*args))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("segments", [35, 263])
+def test_pallas_zonal_kernel_lowers_for_tpu(segments):
+    """`zonal_tiled` on both sides of one 128-segment accumulator block:
+    typed f32 fill constants (a python float is an f64 constant under
+    x64, which Mosaic cannot cast) and a legal (1, tile_s) block."""
+    from mosaic_tpu.kernels.zonal import zonal_tiled
+
+    vals = jnp.ones(5000, jnp.float32)
+    seg = jnp.zeros(5000, jnp.int32)
+    hlo = _tpu_lower(zonal_tiled.trace(vals, seg, segments))
+    assert "tpu_custom_call" in hlo
+
+
+def test_mxu_row_lookup_split_survives_for_tpu():
+    """`_mm_rows` splits f32 into three bf16 terms. Written as an
+    f32 -> bf16 -> f32 convert pair the split is "excess precision" the
+    TPU compiler may drop (it did: the lookup returned a bf16-rounded
+    table on the chip); `reduce_precision` is the op it must keep."""
+    from mosaic_tpu.sql.join import _mm_rows
+
+    idx = jnp.zeros(256, jnp.int32)
+    tab = jnp.zeros((512, 16), jnp.float32)
+    hlo = _tpu_lower(jax.jit(_mm_rows).trace(idx, tab))
+    assert hlo.count("reduce_precision") >= 3
 
 
 def test_bench_step_lowers_for_tpu(problem):

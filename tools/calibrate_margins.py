@@ -165,9 +165,6 @@ def measure_edge_drift(
 
 
 def run_sweep(n: int, seeds=(3, 11)) -> dict:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     from mosaic_tpu.core.index import BNG, H3
     from mosaic_tpu.datasets import synthetic_zones
     from mosaic_tpu.sql.join import CELL_MARGIN_K, EDGE_BAND_K

@@ -114,7 +114,6 @@ def main() -> int:
     try:
         import tempfile
 
-        import jax
         import numpy as np
 
         from mosaic_tpu import obs
@@ -130,7 +129,14 @@ def main() -> int:
         stages = cap.__enter__()
         root_span = obs.start_span("epoch_bench", n_side=args.n_side,
                                    res=args.res)
-        detail["platform"] = str(jax.devices()[0].platform)
+        from mosaic_tpu.runtime.platform import (
+            configure_compile_cache,
+            require_device,
+        )
+
+        # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
+        detail["platform"] = require_device()["platform"]
+        detail["compile_cache_dir"] = configure_compile_cache()
         grid = CustomIndexSystem(GridConf(-180, 180, -90, 90, 2,
                                           10.0, 10.0))
         cw, _ = grid.cell_size(args.res)

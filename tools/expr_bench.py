@@ -128,8 +128,6 @@ def main() -> int:
     root_span = None
     rc = 1
     try:
-        import jax
-
         from mosaic_tpu import expr as E, obs
         from mosaic_tpu.dispatch import core as dispatch
         from mosaic_tpu.functions.raster import rst_mapbands
@@ -144,7 +142,14 @@ def main() -> int:
         root_span = obs.start_span(
             "expr_bench", width=args.width, height=args.height
         )
-        detail["platform"] = str(jax.devices()[0].platform)
+        from mosaic_tpu.runtime.platform import (
+            configure_compile_cache,
+            require_device,
+        )
+
+        # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
+        detail["platform"] = require_device()["platform"]
+        detail["compile_cache_dir"] = configure_compile_cache()
         detail["shape"] = [args.height, args.width]
         detail["tile"] = list(tile)
 

@@ -30,7 +30,7 @@ Last stdout line is ALWAYS one machine-parseable JSON object; everything
 else goes to stderr.
 
 CPU CI smoke:
-  JAX_PLATFORMS=cpu MOSAIC_BENCH_PLATFORM=cpu python tools/knn_bench.py \
+  JAX_PLATFORMS=cpu python tools/knn_bench.py \
       --requests 40 --overload-requests 60 --out /tmp/KNN.json
 """
 
@@ -347,11 +347,15 @@ def main() -> None:
         "unit": "queries/sec",
         "detail": detail,
     }
-    try:
-        if os.environ.get("MOSAIC_BENCH_PLATFORM") == "cpu":
-            import jax
+    from mosaic_tpu.runtime.platform import (
+        configure_compile_cache,
+        require_device,
+    )
 
-            jax.config.update("jax_platforms", "cpu")
+    # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
+    detail["device_info"] = require_device()
+    detail["compile_cache_dir"] = configure_compile_cache()
+    try:
         import jax
 
         from mosaic_tpu.knn import KNNFrontend

@@ -28,8 +28,8 @@ pool — see the multichip-smoke CI job).
 
 Usage:
   python tools/multichip_bench.py --points 262144 --out MULTICHIP_r07.json
-  (CPU: env JAX_PLATFORMS=cpu MOSAIC_BENCH_PLATFORM=cpu; the bench
-   forces 8 virtual devices itself when the platform exposes fewer)
+  (CPU: env JAX_PLATFORMS=cpu; the bench then forces 8 virtual host
+   devices itself unless XLA_FLAGS already pins a count)
 """
 
 from __future__ import annotations
@@ -91,12 +91,21 @@ def main() -> None:
     emit_to = sys.stdout
     sys.stdout = sys.stderr
 
-    if os.environ.get("MOSAIC_BENCH_PLATFORM", "cpu") == "cpu":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from mosaic_tpu.runtime.platform import (
+        configure_compile_cache,
+        cpu_requested,
+        require_device,
+    )
+
+    if cpu_requested():  # before the first backend touch
         _force_host_devices(max(counts))
 
     t_all = time.perf_counter()
-    detail: dict = {}
+    # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
+    detail: dict = {
+        "device_info": require_device(),
+        "compile_cache_dir": configure_compile_cache(),
+    }
     line = {
         "metric": "multichip_join_points_per_sec",
         "value": 0.0,

@@ -108,7 +108,6 @@ def main() -> int:
     root_span = None
     rc = 1
     try:
-        import jax
         import numpy as np
 
         from mosaic_tpu import expr as E, obs
@@ -123,7 +122,14 @@ def main() -> int:
         stages = cap.__enter__()
         root_span = obs.start_span("overlay_bench", n=args.n,
                                    res=args.res)
-        detail["platform"] = str(jax.devices()[0].platform)
+        from mosaic_tpu.runtime.platform import (
+            configure_compile_cache,
+            require_device,
+        )
+
+        # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
+        detail["platform"] = require_device()["platform"]
+        detail["compile_cache_dir"] = configure_compile_cache()
         detail["n_per_side"] = args.n
 
         grid = CustomIndexSystem(GridConf(-180, 180, -90, 90, 2,

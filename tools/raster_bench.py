@@ -173,8 +173,6 @@ def main() -> int:
     root_span = None
     rc = 1
     try:
-        import jax
-
         from mosaic_tpu import obs
         from mosaic_tpu.raster import read_raster
         from mosaic_tpu.raster.zonal import (
@@ -191,7 +189,14 @@ def main() -> int:
         root_span = obs.start_span(
             "raster_bench", width=args.width, height=args.height
         )
-        detail["platform"] = str(jax.devices()[0].platform)
+        from mosaic_tpu.runtime.platform import (
+            configure_compile_cache,
+            require_device,
+        )
+
+        # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
+        detail["platform"] = require_device()["platform"]
+        detail["compile_cache_dir"] = configure_compile_cache()
         detail["shape"] = [args.height, args.width]
         detail["tile"] = list(tile)
         peak = _hbm_peak_gbps()

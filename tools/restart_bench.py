@@ -38,7 +38,7 @@ one wall-clock timeline — ``detail.fleet`` carries the restart chain
 and the relaunch's first event).
 
 CPU CI smoke:
-  JAX_PLATFORMS=cpu MOSAIC_BENCH_PLATFORM=cpu python tools/restart_bench.py \
+  JAX_PLATFORMS=cpu python tools/restart_bench.py \
       --restarts 2 --requests 120 --rate 120
 """
 
@@ -106,14 +106,15 @@ def child_main(args) -> None:
     """One serve lifetime: warm from the store, answer the probe set,
     flush an early report (the parent's kill gate), then serve open-loop
     until done or killed."""
-    if os.environ.get("MOSAIC_BENCH_PLATFORM") == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
     from mosaic_tpu.runtime import telemetry
     from mosaic_tpu.runtime.errors import Overloaded
+    from mosaic_tpu.runtime.platform import require_device
     from mosaic_tpu.serve import BucketLadder, ServeEngine, backend_compiles
+
+    # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU. No
+    # persistent compile cache here, on purpose: the cold lane's compile
+    # storm is the thing this bench prices against the program store.
+    require_device()
 
     t0 = time.perf_counter()
     index, grid = _build_index()
@@ -242,6 +243,14 @@ def child_main(args) -> None:
 
 
 # --------------------------------------------------------------- parent
+#
+# One process for each chip: a process that has initialised a JAX backend
+# holds the chip, and a child that needs it then fails or hangs. So the
+# parent never touches a device — it imports no backend-initialising
+# module (``main`` asserts it at the end) — and its children run strictly
+# one after another: every launcher below returns only after
+# ``proc.wait()`` has reaped its child, SIGKILLed or not, and a dead
+# process holds no device.
 
 def _spawn(store: str, report: str, args, extra=(), trail=None):
     if os.path.exists(report):
@@ -516,6 +525,13 @@ def main() -> None:
         check(
             all("gap_s" in link for link in fleet["chain"][1:]),
             "fleet chain links every incarnation to its predecessor",
+        )
+
+        import jax._src.xla_bridge as _xb  # loaded by fleet_report's imports
+
+        check(
+            not _xb.backends_are_initialized(),
+            "parent stayed off the device (no JAX backend initialised)",
         )
 
         detail["answers_sha256"] = hashes

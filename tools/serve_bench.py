@@ -23,7 +23,7 @@ batch occupancy, shed/quarantine counters, and the compile story
 compile count when jax's monitoring hook is available).
 
 CPU CI smoke:
-  JAX_PLATFORMS=cpu MOSAIC_BENCH_PLATFORM=cpu python tools/serve_bench.py \
+  JAX_PLATFORMS=cpu python tools/serve_bench.py \
       --mode closed --requests 200 --concurrency 8 --rows-max 512
 """
 
@@ -275,11 +275,15 @@ def main() -> None:
         "unit": "requests/sec",
         "detail": detail,
     }
-    try:
-        if os.environ.get("MOSAIC_BENCH_PLATFORM") == "cpu":
-            import jax
+    from mosaic_tpu.runtime.platform import (
+        configure_compile_cache,
+        require_device,
+    )
 
-            jax.config.update("jax_platforms", "cpu")
+    # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
+    detail["device_info"] = require_device()
+    detail["compile_cache_dir"] = configure_compile_cache()
+    try:
         import jax
 
         from bench import RES, _load_or_build_index, _load_zones

@@ -8,7 +8,7 @@ pipeline alone, full fused step, tier split), so the artifact carries
 numbers even where the trace viewer isn't available.
 
 Usage: python tools/trace_join.py [--points 4000000] [--out TRACE_r05.json]
-(CPU validation: MOSAIC_BENCH_PLATFORM=cpu --points 200000)
+(CPU validation: JAX_PLATFORMS=cpu ... --points 200000)
 """
 
 from __future__ import annotations
@@ -33,12 +33,18 @@ def main() -> None:
     ap.add_argument("--trace-dir", default=os.path.join(REPO, "traces", "r05"))
     args = ap.parse_args()
 
-    if os.environ.get("MOSAIC_BENCH_PLATFORM") == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import jax.numpy as jnp
+
+    from mosaic_tpu.runtime.platform import (
+        configure_compile_cache,
+        per_chip,
+        require_device,
+    )
+
+    # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
+    device = require_device()
+    configure_compile_cache()
 
     from bench import RES, _load_or_build_index, _load_zones
     from mosaic_tpu.core.index.h3 import H3IndexSystem
@@ -112,7 +118,7 @@ def main() -> None:
     line = {
         "metric": "join_trace",
         "value": round(n / step_s, 1),
-        "unit": "points/sec/chip",
+        "unit": per_chip("points/sec", device),
         "detail": {
             "n_points": n,
             "cells_only_s": round(cells_s, 4),
@@ -120,6 +126,7 @@ def main() -> None:
             "probe_s_approx": round(step_s - cells_s, 4),
             "caps": [fcap, hcap],
             "device": str(jax.devices()[0]),
+            "device_info": device,
             "zones": zones_src,
             "trace_dir": os.path.relpath(args.trace_dir, REPO),
         },

@@ -106,7 +106,6 @@ def main() -> int:
     root_span = None
     rc = 1
     try:
-        import jax
         import numpy as np
 
         from mosaic_tpu import datasets, obs
@@ -129,7 +128,14 @@ def main() -> int:
         root_span = obs.start_span(
             "tune_bench", points_a=args.points_a, points_b=args.points_b
         )
-        detail["platform"] = str(jax.devices()[0].platform)
+        from mosaic_tpu.runtime.platform import (
+            configure_compile_cache,
+            require_device,
+        )
+
+        # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
+        detail["platform"] = require_device()["platform"]
+        detail["compile_cache_dir"] = configure_compile_cache()
         detail["default_resolution"] = DEFAULT_RES
 
         grid = CustomIndexSystem(GridConf(-180, 180, -90, 90, 2, 10.0, 10.0))
