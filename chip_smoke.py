@@ -450,6 +450,12 @@ def phase_serve(dep: dict, size: dict, seed: int) -> dict:
         rungs=rungs, requests=size["requests"], rows=rows,
         threads=size["threads"], batches=m["batches"],
         occupancy=m["occupancy_mean"], degraded=m["degraded"],
+        real_row_share=round(m["batched_rows"] / m["padded_rows"], 4),
+        linger_closed_by={
+            k: m["linger_closed_by_" + k]
+            for k in ("window", "rows", "put_back")
+        },
+        h2d_bytes=m["h2d_bytes"], d2h_bytes=m["d2h_bytes"],
         compile_spans_after_warmup=0, backend_compiles=cold_compiles,
         cold_warmup_s=round(warm_s, 1), load_wall_s=round(load_s, 2),
         store_warmed_backend_compiles=store_compiles,

@@ -107,7 +107,7 @@ def thread_context_adoption(ctx: FileContext) -> list[Finding]:
                 ),
                 hint=(
                     "adopt sinks/context/plans in the worker (see "
-                    "serve/batcher.py:_process) or suppress with "
+                    "serve/batcher.py:_loop) or suppress with "
                     "`# lint: thread-context-adoption-ok (reason)`"
                 ),
             ))

@@ -46,6 +46,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs import metrics as _metrics
+from ..obs import stages as _stages
 from ..obs import trace as _trace
 from ..runtime import checkpoint as _checkpoint
 from ..runtime import telemetry as _telemetry, watchdog as _watchdog
@@ -290,13 +291,16 @@ def cells_prog(index_system, resolution: int, variant: str = "cells"):
     harmless; :func:`clear_caches` is the escape hatch for servers
     cycling many custom grids.
     """
-    if variant == "margin":
-        fn = lambda p: index_system.point_to_cell_margin(p, resolution)  # noqa: E731
-    elif variant == "alt":
-        fn = lambda p: index_system.point_to_cell_alt(p, resolution)  # noqa: E731
-    else:
-        fn = lambda p: index_system.point_to_cell(p, resolution)  # noqa: E731
-    return jax.jit(fn)
+    assign = {
+        "margin": index_system.point_to_cell_margin,
+        "alt": index_system.point_to_cell_alt,
+    }.get(variant, index_system.point_to_cell)
+
+    def cells(p):
+        with jax.named_scope("pip.cells"):
+            return assign(p, resolution)
+
+    return jax.jit(cells)
 
 
 def join_cache_view() -> dict:
@@ -528,6 +532,9 @@ class DispatchCore:
             self._programs = None
         self._aot: dict = {}  # bucket -> (cells_fn, join_fn) | None
         self.aot_stats = {"loaded": 0, "exported": 0, "fallback": 0}
+        #: bytes put to and pulled from the device by `execute_padded`
+        #: (the ``nbytes`` of its transfer spans, summed)
+        self.transfer_bytes = {"h2d": 0, "d2h": 0}
 
     # ------------------------------------------------------- accounting
 
@@ -646,10 +653,7 @@ class DispatchCore:
 
         shifted_proto = _jax.ShapeDtypeStruct((bucket, 2), self._dtype)
         jj = jit_join()
-        statics = dict(
-            heavy_cap=hcap, found_cap=fcap, writeback=self.writeback,
-            lookup=self.lookup, probe=self.probe, convex_cap=ccap,
-        )
+        statics = self._join_statics(fcap, hcap, ccap)
         out_aval = _jax.eval_shape(
             lambda a, b, c: jj(a, b, c, **statics),
             shifted_proto, cells_aval, self.index,
@@ -738,6 +742,7 @@ class DispatchCore:
                 dev = jnp.asarray(padded)
                 if self.cell_dtype is not None:
                     dev = dev.astype(self.cell_dtype)
+            self.transfer_bytes["h2d"] += int(padded.nbytes)
             # always the JITTED cell program (shared `cells_prog` lru,
             # one compile per bucket, precompiled by warmup): the
             # batch-path heuristic of going eager below 64k rows on CPU
@@ -745,12 +750,16 @@ class DispatchCore:
             # the right trade for a single cold batch, the wrong one on
             # a hot path. With a program store bound, the bucket's
             # AOT-loaded executables replace both programs outright.
-            if bundle is not None:
-                cells = bundle[0](dev)
-            else:
-                cells = cells_prog(
-                    self.index_system, self.resolution, "cells"
-                )(dev)
+            # The launch spans time the ENQUEUE, not the execution.
+            with _trace.span(
+                "dispatch.launch", program="cells", bucket=bucket,
+            ):
+                if bundle is not None:
+                    cells = bundle[0](dev)
+                else:
+                    cells = cells_prog(
+                        self.index_system, self.resolution, "cells"
+                    )(dev)
             with _trace.span(
                 "dispatch.transfer.h2d", nbytes=int(padded.nbytes),
                 bucket=bucket, shifted=True,
@@ -763,22 +772,23 @@ class DispatchCore:
                 shifted = jnp.asarray(
                     np.asarray(padded - self._shift, dtype=self._dtype)
                 )
-            if bundle is not None:
-                out = bundle[1](shifted, cells, self.index)
-            elif self.mesh is None:
-                out = jit_join()(
-                    shifted, cells, self.index,
-                    heavy_cap=hcap, found_cap=fcap,
-                    writeback=self.writeback, lookup=self.lookup,
-                    probe=self.probe, convex_cap=ccap,
-                )
-            else:
-                prog = sharded_join_prog(
-                    self.mesh, writeback=self.writeback,
-                    lookup=self.lookup, probe=self.probe,
-                    found_cap=fcap, heavy_cap=hcap, convex_cap=ccap,
-                )
-                out = prog(shifted, cells, self.index)
+            self.transfer_bytes["h2d"] += int(shifted.nbytes)
+            with _trace.span(
+                "dispatch.launch", program="join", bucket=bucket,
+            ):
+                if bundle is not None:
+                    out = bundle[1](shifted, cells, self.index)
+                elif self.mesh is None:
+                    out = jit_join()(
+                        shifted, cells, self.index,
+                        **self._join_statics(fcap, hcap, ccap),
+                    )
+                else:
+                    out = self._sharded_prog(fcap, hcap, ccap)(
+                        shifted, cells, self.index
+                    )
+            if new_sig and bundle is None:
+                self._register_stages(bucket, dev, shifted, cells)
             # the result pull also blocks on device compute on async
             # backends, so this upper-bounds the true D2H copy — still
             # the only host-visible interval the copy has
@@ -787,6 +797,7 @@ class DispatchCore:
                 nbytes=int(getattr(out, "nbytes", 0)), bucket=bucket,
             ):
                 res = np.asarray(out)
+            self.transfer_bytes["d2h"] += int(res.nbytes)
             return res
         finally:
             if comp_span is not None:
@@ -794,6 +805,40 @@ class DispatchCore:
                 if comp_c0 is not None and c1 is not None:
                     comp_span.set(backend_compiles=c1 - comp_c0)
                 comp_span.end()
+
+    def _register_stages(self, bucket, dev, shifted, cells) -> None:
+        """Tell `obs.stages` how to lower this bucket's two programs
+        again (shapes only; nothing is lowered here)."""
+        rows = bucket if self.mesh is None else bucket // self.mesh.size
+        fcap, hcap, ccap = self.caps(bucket)
+        shapes = _stages.shapes_of
+        _stages.register(
+            cells_prog(self.index_system, self.resolution, "cells"),
+            shapes((dev,)), rows=rows,
+        )
+        args = shapes((shifted, cells, self.index))
+        if self.mesh is None:
+            _stages.register(
+                jit_join(), args, self._join_statics(fcap, hcap, ccap),
+                rows=rows,
+            )
+        else:
+            _stages.register(
+                self._sharded_prog(fcap, hcap, ccap), args, rows=rows
+            )
+
+    def _join_statics(self, fcap, hcap, ccap) -> dict:
+        return dict(
+            heavy_cap=hcap, found_cap=fcap, writeback=self.writeback,
+            lookup=self.lookup, probe=self.probe, convex_cap=ccap,
+        )
+
+    def _sharded_prog(self, fcap, hcap, ccap):
+        return sharded_join_prog(
+            self.mesh, writeback=self.writeback, lookup=self.lookup,
+            probe=self.probe, found_cap=fcap, heavy_cap=hcap,
+            convex_cap=ccap,
+        )
 
     def execute(self, points) -> np.ndarray:
         """Pad → dispatch → unpad (exact, unguarded)."""

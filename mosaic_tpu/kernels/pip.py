@@ -240,7 +240,7 @@ def pip_zone(
     )
     # named scope: the streaming pipeline's per-stage accounting extends
     # into traces — xprof groups this lane's ops under one label so the
-    # kernel's share of a fused step is attributable (tools/trace_join.py)
+    # kernel's share of a fused step is attributable
     with jax.named_scope("pip_zone.pallas"):
         out = pl.pallas_call(
             kernel,

@@ -29,6 +29,10 @@ backward-compatible with) `runtime/telemetry.py`'s flat event trail:
   reconstruction from span ``start_mono``/``seconds``, per-track
   gap/overlap, and the priority sweep that classifies lost wall time
   into {transfer, compile, queue_wait, host_callback, device, idle};
+- **stage tables** (`obs/stages.py`) — programs register how to lower
+  them again; a trace reader gets ``{module: {op: pip.*/stream.* stage}}``
+  from the optimized HLO's metadata, so device ops carry the join's own
+  stage names (nothing is lowered until a reader asks);
 - **SLO monitor** (`obs/slo.py`) — the live ops plane's alerting core:
   sliding-window burn-rate evaluation over registered SLO specs
   (default set gated on ``MOSAIC_SLO_ENABLE``), breaches emitted as
@@ -65,6 +69,7 @@ from . import (
     ops_server,
     recorder,
     slo,
+    stages,
     timeline,
     trace,
 )
@@ -137,6 +142,7 @@ __all__ = [
     "slo",
     "snapshot",
     "span",
+    "stages",
     "start_span",
     "timeline",
     "trace",

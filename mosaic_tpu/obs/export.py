@@ -12,8 +12,8 @@ trail into the formats the outside world reads:
 - :func:`chrome_trace` — Chrome trace-event JSON (the ``traceEvents``
   array format Perfetto and ``chrome://tracing`` load): spans become
   complete ``"X"`` events on one timeline row per trace, flat events
-  become instants — the host-side complement of the xprof device traces
-  under ``traces/r05/``;
+  become instants — the host-side complement of a profiler trace, in
+  which every non-detached span is also a ``mosaic.<name>`` annotation;
 - :func:`prometheus_text` — the metrics registry snapshot in Prometheus
   text exposition format (``# TYPE``/``# HELP``, ``_bucket``/``_sum``/
   ``_count`` histogram series), ready for a scrape endpoint or a

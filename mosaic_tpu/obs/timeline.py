@@ -70,8 +70,10 @@ CLASS_RULES: tuple = (
     ("transfer", "span.dispatch.transfer.d2h"),
     ("transfer", "span.stream.ring_build"),
     ("transfer", "stream_stage.ring_build"),
-    # -- queue_wait: admitted but not yet in a forming batch
+    # -- queue_wait: admitted but not yet dispatched (in the queue, then
+    #    in the batch that lingers for batchmates)
     ("queue_wait", "serve_stage.queue_wait"),
+    ("queue_wait", "span.serve.linger"),
     # -- host_callback: host-side work the device waits out
     #    (snapshot writes, admission scrubbing, quarantine probes);
     #    pipelined runs emit stream.snapshot from the writer thread —
@@ -86,21 +88,31 @@ CLASS_RULES: tuple = (
     ("host_callback", "stream_stage.pipeline_flush"),
     ("host_callback", "quarantine_stage.*"),
     ("host_callback", "recheck_narrow"),
+    #    the serve dispatch's host pieces: concat, pad, the hand-off to
+    #    the watchdog's worker thread and back, enqueueing the two
+    #    programs, scatter-back (``serve.wait``, the empty queue, stays
+    #    unclassified: idle)
+    ("host_callback", "span.serve.concat"),
+    ("host_callback", "span.serve.pad"),
+    ("host_callback", "span.serve.deliver"),
+    ("host_callback", "span.dispatch.guard.handoff"),
+    ("host_callback", "span.dispatch.launch"),
+    ("host_callback", "span.stream.launch"),
     # -- device: the useful work everything above steals from
     #    (the pipeline drain is the bounded window's one blocking pull:
     #    the wall it spends is device execution the host waits out)
     ("device", "span.stream.pipeline.drain"),
     ("device", "stream_stage.pipeline_drain"),
+    #    (`StreamJoin.run`'s pull of the fold likewise; a serve dispatch
+    #    is a host interval — its pieces are classified above, and the
+    #    chip's own intervals come from a trace: see :func:`attribute`)
+    ("device", "span.stream.pull"),
     ("device", "span.stream.segment"),
-    ("device", "span.serve.dispatch"),
-    ("device", "span.serve.batch"),
     ("device", "span.raster.zonal"),
     ("device", "span.raster.tile"),
     ("device", "span.raster.assign"),
     ("device", "span.join.pip"),
     ("device", "span.join.probe.*"),
-    ("device", "serve_stage.dispatch"),
-    ("device", "serve_stage.batch"),
     ("device", "stream_stage.gen_loop"),
     ("device", "probe_stage.*"),
     ("device", "raster_stage.*"),
@@ -113,6 +125,10 @@ CONTAINER_KEYS = frozenset({
     "span.stream.durable_run",
     "span.stream.run",
     "span.serve.request",
+    "span.serve.batch",
+    "span.serve.dispatch",
+    "serve_stage.batch",
+    "serve_stage.dispatch",
     "span.raster.scan",
     "stream_stage.durable_loop",
     "stream_stage.join_loop",
@@ -251,7 +267,9 @@ def pick_window(events) -> tuple[float, float, str] | None:
 
 
 def attribute(
-    events, window: tuple[float, float] | None = None
+    events,
+    window: tuple[float, float] | None = None,
+    device_intervals=None,
 ) -> dict | None:
     """Classified wall-time attribution over a window.
 
@@ -259,6 +277,13 @@ def attribute(
     "share"}}, "sum_s", "segments": n, "critical_path": [...]}`` —
     the classes (idle included) partition the wall exactly; the
     critical path is the flattened owner sequence's top segments.
+
+    ``device_intervals`` are the chip's own busy intervals as ``(start,
+    end)`` on the monotonic clock: a profiler trace's module runs, moved
+    onto it by the offset every ``mosaic.*`` annotation carries
+    (`obs.trace.device_intervals`; `tools/stall_report.py --xplane`).
+    They take class ``device``, which a host span around a dispatch can
+    only bound.
     """
     if window is None:
         w = pick_window(events)
@@ -271,7 +296,12 @@ def attribute(
     wall = t1 - t0
     if wall <= 0:
         return None
-    segs = flatten(intervals(events), (t0, t1))
+    ivals = intervals(events) + [
+        {"start": float(a), "end": float(b), "key": "device_trace",
+         "cls": "device", "seq": 0}
+        for a, b in (device_intervals or ())
+    ]
+    segs = flatten(ivals, (t0, t1))
     classes = {c: 0.0 for c in (*CLASS_PRIORITY, "idle")}
     for s in segs:
         classes[s["cls"]] += s["end"] - s["start"]

@@ -81,8 +81,9 @@ def incarnation_event() -> dict:
 _LOGGER = logging.getLogger("mosaic_tpu.runtime")
 
 #: registered by ``mosaic_tpu.obs.trace`` — an object with
-#: ``ids() -> dict | None``, ``current() -> context | None``, and
-#: ``adopt(context) -> None``; None until the obs subsystem is imported
+#: ``ids() -> dict | None``, ``current() -> context | None``,
+#: ``adopt(context) -> None`` and ``start_span(name, **kw) -> span``;
+#: None until the obs subsystem is imported
 _TRACER = None
 
 #: process-wide event observers (``fn(evt) -> None``) — the obs metrics
@@ -132,6 +133,12 @@ def adopt_trace(context) -> None:
     tracer or with ``context=None``)."""
     if _TRACER is not None and context is not None:
         _TRACER.adopt(context)
+
+
+def start_span(name: str, **kw):
+    """``obs.trace.start_span`` for runtime modules, which never import
+    obs; the caller owns ``.end()``. None without a tracer."""
+    return None if _TRACER is None else _TRACER.start_span(name, **kw)
 
 
 def add_observer(fn) -> None:

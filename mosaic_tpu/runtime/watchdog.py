@@ -74,6 +74,27 @@ def deadline_for(site: str, default_s: float | None = None) -> float | None:
     return float(v)
 
 
+def _handoff(site: str, leg: str):
+    """One leg of the hand-off to the worker thread and back as a detached
+    span (it starts on one thread and ends on the other), or None.
+    Tracing never fails a guarded call or its wake-up: neither this nor
+    :func:`_end` raises."""
+    try:
+        return telemetry.start_span(
+            "dispatch.guard.handoff", detached=True, site=site, leg=leg
+        )
+    except Exception:  # lint: broad-except-ok (observability must not turn into a missed wake-up: the leg goes unrecorded)
+        return None
+
+
+def _end(span) -> None:
+    if span is not None:
+        try:
+            span.end()
+        except Exception:  # lint: broad-except-ok (as in _handoff: the dispatch's answer outranks its span)
+            pass
+
+
 def guard(site: str, fn, *args, default_s: float | None = None, **kwargs):
     """Run blocking ``fn(*args, **kwargs)`` under the site's deadline.
 
@@ -99,20 +120,25 @@ def guard(site: str, fn, *args, default_s: float | None = None, **kwargs):
         try:
             telemetry.adopt_sinks(sinks)
             telemetry.adopt_trace(trace)
+            _end(out)
             if stall_s:
                 time.sleep(stall_s)
             box["value"] = fn(*args, **kwargs)
         except BaseException as e:  # noqa: BLE001 — re-raised on caller
             box["error"] = e
         finally:
+            box["back"] = _handoff(site, "back")
             done.set()
 
     t0 = time.monotonic()
     worker = threading.Thread(  # lint: thread-context-adoption-ok (plans stay caller-side: maybe_fail/planned_stall run pre-dispatch, and adopting in the worker would double-count nested sites against exact injection budgets)
         target=work, name=f"mosaic-watchdog:{site}", daemon=True
     )
+    out = _handoff(site, "out")
     worker.start()
-    if not done.wait(timeout=deadline):
+    if done.wait(timeout=deadline):
+        _end(box["back"])
+    else:
         elapsed = time.monotonic() - t0
         telemetry.record(
             "watchdog_stall", site=site,

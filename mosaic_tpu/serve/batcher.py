@@ -84,6 +84,11 @@ class MicroBatcher:
             "failed": 0,
             "degraded": 0,
             "occupancy_sum": 0.0,
+            # what closed each batch's linger: the row budget, the
+            # window, or a request that overshot and went back
+            "linger_closed_by_rows": 0,
+            "linger_closed_by_window": 0,
+            "linger_closed_by_put_back": 0,
         }
         self._stop = threading.Event()
         self._thread = threading.Thread(
@@ -103,9 +108,25 @@ class MicroBatcher:
 
     def _loop(self) -> None:
         while not self._stop.is_set():
+            # the empty-queue wait: an event only when a request ends it,
+            # as that request's child (an idle tick leaves its profiler
+            # annotation and nothing else)
+            _trace.adopt_context(None)
+            wait = _trace.start_span("serve.wait")
             first = self.admission.take(self.idle_tick_s)
             if first is None:
+                wait.drop()
                 continue
+            # this thread adopts the FIRST request's caller context:
+            # fault plans, capture sinks, and span context are
+            # thread-local, and tests install them on the submitting
+            # thread (batchmates from other traces keep their OWN root
+            # spans; only the shared linger/batch/dispatch spans parent
+            # to the first request's trace)
+            _telemetry.adopt_sinks(first.sinks)
+            _faults.adopt_plans(first.plans)
+            _trace.adopt_context(first.ctx)
+            wait.reparent(first.ctx).end()
             batch = self._form_batch(first)
             if batch:
                 self._process(batch)
@@ -115,31 +136,28 @@ class MicroBatcher:
         (measured from ``first``'s arrival at the batcher) is spent."""
         batch = [first]
         rows = first.n
-        window_end = time.monotonic() + self.max_wait_s
-        while rows < self.max_batch_rows:
-            remaining = window_end - time.monotonic()
-            if remaining <= 0:
-                break
-            nxt = self.admission.take(remaining)
-            if nxt is None:
-                break
-            if rows + nxt.n > self.max_batch_rows:
-                self.admission.put_back(nxt)
-                break
-            batch.append(nxt)
-            rows += nxt.n
+        closed_by = "rows"
+        with _trace.span("serve.linger") as linger:
+            window_end = time.monotonic() + self.max_wait_s
+            while rows < self.max_batch_rows:
+                remaining = window_end - time.monotonic()
+                nxt = (
+                    self.admission.take(remaining) if remaining > 0 else None
+                )
+                if nxt is None:
+                    closed_by = "window"
+                    break
+                if rows + nxt.n > self.max_batch_rows:
+                    self.admission.put_back(nxt)
+                    closed_by = "put_back"
+                    break
+                batch.append(nxt)
+                rows += nxt.n
+            linger.set(requests=len(batch), rows=rows, closed_by=closed_by)
+        self.metrics["linger_closed_by_" + closed_by] += 1
         return batch
 
     def _process(self, batch: list[Request]) -> None:
-        # the dispatch worker adopts the FIRST request's caller context:
-        # fault plans, capture sinks, and span context are thread-local,
-        # and tests install them on the submitting thread (batchmates
-        # from other traces keep their OWN root spans; only the shared
-        # batch/dispatch spans parent to the first request's trace)
-        _telemetry.adopt_sinks(batch[0].sinks)
-        _faults.adopt_plans(batch[0].plans)
-        _trace.adopt_context(batch[0].ctx)
-
         now = time.monotonic()
         live = []
         for req in batch:
@@ -150,17 +168,6 @@ class MicroBatcher:
         if not live:
             return
 
-        # queue-wait interval per admitted request: submit stamp →
-        # batch formation (this instant); recorded flat (ts_mono -
-        # seconds recovers the interval) and stamped with the request's
-        # own trace ids so the wait lands inside its serve.request root
-        for req in live:
-            _telemetry.record(
-                "serve_stage", stage="queue_wait",
-                seconds=round(max(now - req.t_submit, 0.0), 6),
-                rows=req.n, **_req_ids(req),
-            )
-
         rows = sum(r.n for r in live)
         self.metrics["batches"] += 1
         self.metrics["batched_rows"] += rows
@@ -168,15 +175,28 @@ class MicroBatcher:
         try:
             with _trace.span(
                 "serve.batch", requests=len(live), rows=rows,
-            ), _telemetry.timed(
+            ) as batch_span, _telemetry.timed(
                 "serve_stage", stage="batch", requests=len(live), rows=rows,
             ):
+                # queue-wait interval per admitted request: submit stamp
+                # → batch formation (``now``); recorded flat (ts_mono -
+                # seconds bounds the interval) and stamped with the
+                # request's own trace ids so the wait lands inside its
+                # serve.request root. Recorded inside the batch's span:
+                # the recording is host work of this batch
+                for req in live:
+                    _telemetry.record(
+                        "serve_stage", stage="queue_wait",
+                        seconds=round(max(now - req.t_submit, 0.0), 6),
+                        rows=req.n, **_req_ids(req),
+                    )
                 _faults.maybe_fail("serve.batch")
-                points = (
-                    live[0].points
-                    if len(live) == 1
-                    else np.concatenate([r.points for r in live])
-                )
+                with _trace.span("serve.concat", requests=len(live)):
+                    points = (
+                        live[0].points
+                        if len(live) == 1
+                        else np.concatenate([r.points for r in live])
+                    )
                 # the watchdog default for this dispatch: the batch's
                 # largest remaining request budget (None = no deadline)
                 rem = [r.remaining(now) for r in live]
@@ -193,6 +213,14 @@ class MicroBatcher:
         degraded = isinstance(out, DegradedResult) or bool(
             getattr(out, "degraded", False)
         )
+        # scatter-back belongs to the batch it answers, whose span and
+        # timed twin closed with the dispatch
+        with _trace.span(
+            "serve.deliver", parent=batch_span.context, requests=len(live),
+        ):
+            self._deliver(live, out, degraded)
+
+    def _deliver(self, live: list[Request], out, degraded: bool) -> None:
         now = time.monotonic()
         off = 0
         for req in live:

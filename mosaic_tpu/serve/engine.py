@@ -198,6 +198,10 @@ class ServeEngine:
                 f"top bucket {self.ladder.max_bucket}"
             )
         self._closed = False
+        # PIP dispatches and the rows they were padded to (batcher thread
+        # only)
+        self._dispatches = 0
+        self._padded_rows = 0
         self.batcher.start()
 
     # ----------------------------------------------------------- public
@@ -395,6 +399,9 @@ class ServeEngine:
         return stats
 
     def metrics(self) -> dict:
+        """Cumulative counters of this engine: outcomes, and the batching
+        and dispatch counted where they happen (what each answers for an
+        operator: docs/ARCHITECTURE.md, "Operator counters")."""
         a, b = self.admission.metrics, self.batcher.metrics
         out = dict(a)
         out.update(b)
@@ -403,6 +410,10 @@ class ServeEngine:
         out["queue_depth"] = self.admission.depth()
         out["compile_signatures"] = len(self.core.signatures)
         out["cold_compiles"] = self.core.cold_compiles
+        out["dispatches"] = self._dispatches
+        out["padded_rows"] = self._padded_rows
+        out["h2d_bytes"] = self.core.transfer_bytes["h2d"]
+        out["d2h_bytes"] = self.core.transfer_bytes["d2h"]
         if self.knn is not None:
             out.update(self.knn.metrics())
             out["cold_compiles"] += self.knn.cold_compiles
@@ -457,7 +468,7 @@ class ServeEngine:
             return self._dispatch_mixed(
                 ladder, core, knn, points, deadline_hint, reqs
             )
-        padded, n = ladder.pad(points)
+        padded, n = self._pad(ladder, points)
         bucket = padded.shape[0]
         with _trace.span(
             "serve.dispatch", bucket=bucket, rows=n,
@@ -467,6 +478,17 @@ class ServeEngine:
             out = self._dispatch_resilient(core, padded, deadline_hint)
         occupancy = n / bucket
         return out[:n], occupancy
+
+    def _pad(self, ladder, points: np.ndarray):
+        """Pad one PIP dispatch to its bucket, counted where it happens:
+        ``padded_rows / dispatches`` against ``batched_rows`` is the
+        occupancy the ladder costs."""
+        with _trace.span("serve.pad", rows=int(points.shape[0])) as sp:
+            padded, n = ladder.pad(points)
+            sp.set(bucket=int(padded.shape[0]))
+        self._dispatches += 1
+        self._padded_rows += int(padded.shape[0])
+        return padded, n
 
     def _dispatch_mixed(self, ladder, core, knn, points, deadline_hint, reqs):
         """Split a mixed batch by request kind: ALL PIP rows go through
@@ -486,7 +508,7 @@ class ServeEngine:
         pip = [(r, a, b) for (r, a, b) in bounds if r.kind != "knn"]
         if pip:
             pts = np.concatenate([points[a:b] for (_r, a, b) in pip])
-            padded, n = ladder.pad(pts)
+            padded, n = self._pad(ladder, pts)
             bucket = padded.shape[0]
             with _trace.span(
                 "serve.dispatch", bucket=bucket, rows=n,

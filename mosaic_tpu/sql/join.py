@@ -960,6 +960,7 @@ def _prefix_inclusive(flag_i32: jax.Array) -> jax.Array:
     return (p + rowoff[:, None]).reshape(-1)[:n].astype(jnp.int32)
 
 
+@jax.named_scope("pip.compact")
 def _compact(flag: jax.Array, cap: int):
     """Stream-compact: indices of up-to-``cap`` True rows (static shape).
 
@@ -997,6 +998,7 @@ def _compact(flag: jax.Array, cap: int):
     return src, valid, flag & (pos >= cap), pos
 
 
+@jax.named_scope("pip.compact")
 def _compact_mxu(
     flag: jax.Array,
     cap: int,
@@ -1440,11 +1442,13 @@ def pip_join_points(
         # fit), and the 3-term bf16 split is exact only for f32 tables
         lookup = "gather"
     N = points.shape[0]
-    # named scopes mark the probe stages in traces so the streaming
-    # pipeline's overlap (cell assign vs these passes) is attributable
+    # named scopes mark the probe stages: every instruction of the join
+    # sits under exactly one innermost pip.* scope, which
+    # `obs.stages` reads back from the optimized HLO to name the device
+    # trace's ops (scopes are HLO metadata only — results do not change)
     with jax.named_scope("pip.hash_probe"):
         u = _probe_slot(pcells, index)
-    found = u >= 0
+        found = u >= 0
     banded_d = edge_eps2 is not None
     H = int(index.heavy_edges.shape[0])
     CV = int(index.convex_edges.shape[0])
@@ -1475,79 +1479,82 @@ def pip_join_points(
         conv = None
 
     if writeback == "direct":
-        us = jnp.maximum(u, 0)
+        with jax.named_scope("pip.tier1"):
+            us = jnp.maximum(u, 0)
 
-        def _direct_tier1(args):
-            px_c, py_c, us_c = args
-            r = _ray_parity(
-                px_c, py_c,
-                index.cell_edges[us_c], index.cell_ebits[us_c],
-                eps2=edge_eps2,
-            )
-            par, near = r if banded_d else (r, None)
-            b = _slot_best(
-                par, index.cell_slot_geom[us_c], index.cell_slot_core[us_c]
-            )
-            return (b, near) if banded_d else b
+            def _direct_tier1(args):
+                px_c, py_c, us_c = args
+                r = _ray_parity(
+                    px_c, py_c,
+                    index.cell_edges[us_c], index.cell_ebits[us_c],
+                    eps2=edge_eps2,
+                )
+                par, near = r if banded_d else (r, None)
+                b = _slot_best(
+                    par, index.cell_slot_geom[us_c], index.cell_slot_core[us_c]
+                )
+                return (b, near) if banded_d else b
 
-        # the un-compacted (N, E1, 4) edge intermediate crosses XLA's
-        # 2 GB buffer limit above ~2M points (tpu_compile_helper crash,
-        # observed at 4M on v5e): chunk the tier-1 row work via lax.map
-        CH = _DIRECT_CHUNK
-        if N > CH:
-            pad = (-N) % CH
-            px_p = jnp.pad(points[:, 0], (0, pad))
-            py_p = jnp.pad(points[:, 1], (0, pad))
-            us_p = jnp.pad(us, (0, pad))
-            n_ch = (N + pad) // CH
-            res = jax.lax.map(
-                _direct_tier1,
-                (
-                    px_p.reshape(n_ch, CH),
-                    py_p.reshape(n_ch, CH),
-                    us_p.reshape(n_ch, CH),
-                ),
-            )
-            if banded_d:
-                best = res[0].reshape(-1)[:N]
-                near1 = res[1].reshape(-1)[:N]
+            # the un-compacted (N, E1, 4) edge intermediate crosses XLA's
+            # 2 GB buffer limit above ~2M points (tpu_compile_helper crash,
+            # observed at 4M on v5e): chunk the tier-1 row work via lax.map
+            CH = _DIRECT_CHUNK
+            if N > CH:
+                pad = (-N) % CH
+                px_p = jnp.pad(points[:, 0], (0, pad))
+                py_p = jnp.pad(points[:, 1], (0, pad))
+                us_p = jnp.pad(us, (0, pad))
+                n_ch = (N + pad) // CH
+                res = jax.lax.map(
+                    _direct_tier1,
+                    (
+                        px_p.reshape(n_ch, CH),
+                        py_p.reshape(n_ch, CH),
+                        us_p.reshape(n_ch, CH),
+                    ),
+                )
+                if banded_d:
+                    best = res[0].reshape(-1)[:N]
+                    near1 = res[1].reshape(-1)[:N]
+                else:
+                    best = res.reshape(-1)[:N]
             else:
-                best = res.reshape(-1)[:N]
-        else:
-            r1 = _direct_tier1((points[:, 0], points[:, 1], us))
-            best, near1 = r1 if banded_d else (r1, None)
-        best = jnp.where(found, best, _SENTINEL)
-        if H:
-            hs = jnp.where(found, index.cell_heavy[us], -1)
-            best2, over2, near_sc = _heavy_tier(
-                points[:, 0], points[:, 1], hs, index, heavy_cap, N, N,
-                edge_eps2,
-            )
-            best = jnp.minimum(best, best2)
-            best = jnp.where(over2, _OVF_MARK, best)
+                r1 = _direct_tier1((points[:, 0], points[:, 1], us))
+                best, near1 = r1 if banded_d else (r1, None)
+            best = jnp.where(found, best, _SENTINEL)
+            if H:
+                hs = jnp.where(found, index.cell_heavy[us], -1)
+                best2, over2, near_sc = _heavy_tier(
+                    points[:, 0], points[:, 1], hs, index, heavy_cap, N, N,
+                    edge_eps2,
+                )
+                best = jnp.minimum(best, best2)
+                best = jnp.where(over2, _OVF_MARK, best)
+                if banded_d:
+                    near1 = near1 | near_sc
+        with jax.named_scope("pip.writeback"):
+            out = jnp.where(best == _SENTINEL, -1, best).astype(jnp.int32)
+            out = jnp.where(best == _OVF_MARK, OVERFLOW, out)
             if banded_d:
-                near1 = near1 | near_sc
-        out = jnp.where(best == _SENTINEL, -1, best).astype(jnp.int32)
-        out = jnp.where(best == _OVF_MARK, OVERFLOW, out)
-        if banded_d:
-            return out, near1 & found
-        return out
+                return out, near1 & found
+            return out
 
-    light = found if conv is None else (found & ~conv)
-    K1 = int(found_cap) if found_cap else N
-    K1 = max(8, min(K1, N))
-    if compaction == "mxu" and N >= (1 << 16):
-        # (the vals channel could also carry u through the one-hot, but
-        # the extra batched dot re-reads the 1 GB one-hot and measured
-        # SLOWER than the (K1,) gather below: 87.0 vs 84.2 ms/iter)
-        src1, valid1, over1, pos1 = _compact_mxu(light, K1, compact_block)
-    else:
-        src1, valid1, over1, pos1 = _compact(light, K1)
-    us = jnp.maximum(u[src1], 0)  # (K1,)
-    # ONE (K1, 2) row gather: indexing the columns separately makes XLA
-    # emit two serialized point gathers (traced at ~14 ms EACH at 4M/640k)
-    pxy = points[src1]
-    px, py = pxy[:, 0], pxy[:, 1]
+    with jax.named_scope("pip.compact"):
+        light = found if conv is None else (found & ~conv)
+        K1 = int(found_cap) if found_cap else N
+        K1 = max(8, min(K1, N))
+        if compaction == "mxu" and N >= (1 << 16):
+            # (the vals channel could also carry u through the one-hot, but
+            # the extra batched dot re-reads the 1 GB one-hot and measured
+            # SLOWER than the (K1,) gather below: 87.0 vs 84.2 ms/iter)
+            src1, valid1, over1, pos1 = _compact_mxu(light, K1, compact_block)
+        else:
+            src1, valid1, over1, pos1 = _compact(light, K1)
+        us = jnp.maximum(u[src1], 0)  # (K1,)
+        # ONE (K1, 2) row gather: indexing the columns separately makes XLA
+        # emit two serialized point gathers (traced at ~14 ms EACH at 4M/640k)
+        pxy = points[src1]
+        px, py = pxy[:, 0], pxy[:, 1]
 
     banded = edge_eps2 is not None
     with jax.named_scope("pip.tier1"):
@@ -1566,24 +1573,25 @@ def pip_join_points(
         best1 = jnp.where(valid1, best1, _SENTINEL)
 
     if H:
-        # tier 2: compact again to the points whose cell is heavy
-        hs = jnp.where(valid1, heavy1, -1)
-        # measured on v5e/NYC: the MXU lookup wins tier 1 but not the
-        # 6 KB heavy rows (gathers get efficient at that row size), so
-        # "mxu" keeps tier 2 on the gather path and "mxu2" forces both
-        best2, over2, near_sc = _heavy_tier(
-            px, py, hs, index, heavy_cap, K1, K1, edge_eps2,
-            lookup="mxu" if lookup == "mxu2" else "gather",
-            compaction=compaction, compact_block=compact_block,
-            engine=heavy_engine,
-        )
-        best1 = jnp.minimum(best1, best2)
-        # an overflowed tier-2 point has an unknown answer even if tier 1
-        # hit: mark it (each compacted row writes its own unique slot, so
-        # the mark survives the writeback scatter verbatim)
-        best1 = jnp.where(over2, _OVF_MARK, best1)
-        if banded:
-            near1 = near1 | near_sc
+        with jax.named_scope("pip.tier2"):
+            # tier 2: compact again to the points whose cell is heavy
+            hs = jnp.where(valid1, heavy1, -1)
+            # measured on v5e/NYC: the MXU lookup wins tier 1 but not the
+            # 6 KB heavy rows (gathers get efficient at that row size), so
+            # "mxu" keeps tier 2 on the gather path and "mxu2" forces both
+            best2, over2, near_sc = _heavy_tier(
+                px, py, hs, index, heavy_cap, K1, K1, edge_eps2,
+                lookup="mxu" if lookup == "mxu2" else "gather",
+                compaction=compaction, compact_block=compact_block,
+                engine=heavy_engine,
+            )
+            best1 = jnp.minimum(best1, best2)
+            # an overflowed tier-2 point has an unknown answer even if tier 1
+            # hit: mark it (each compacted row writes its own unique slot, so
+            # the mark survives the writeback scatter verbatim)
+            best1 = jnp.where(over2, _OVF_MARK, best1)
+            if banded:
+                near1 = near1 | near_sc
 
     if use_convex:
         # convex lane: compact, y-bucket, probe at most EB edges/point.
@@ -1625,50 +1633,51 @@ def pip_join_points(
     # no-combiner scatter (see _compact for the measured win over
     # combiner scatters). The convex lane's rows are disjoint from the
     # light lane's, so its scatter chains onto the same buffer.
-    if writeback == "gather":
-        slot = jnp.clip(pos1, 0, K1 - 1)
-        best = jnp.where(light, best1[slot], _SENTINEL)
-        if use_convex:
-            slot3 = jnp.clip(pos3, 0, K3 - 1)
-            best = jnp.where(conv, best3[slot3], best)
-    else:
-        wdest = jnp.where(
-            valid1, src1, N + jnp.arange(K1, dtype=jnp.int32)
-        )
-        best = (
-            jnp.full(N, _SENTINEL, dtype=jnp.int32)
-            .at[wdest]
-            .set(best1, unique_indices=True, mode="drop")
-        )
-        if use_convex:
-            wdest3 = jnp.where(
-                valid3, src3, N + jnp.arange(K3, dtype=jnp.int32)
-            )
-            best = best.at[wdest3].set(
-                best3, unique_indices=True, mode="drop"
-            )
-    out = jnp.where(best == _SENTINEL, -1, best).astype(jnp.int32)
-    out = jnp.where(best == _OVF_MARK, OVERFLOW, out)
-    out = jnp.where(over1, OVERFLOW, out)
-    if use_convex:
-        out = jnp.where(over3, OVERFLOW, out)
-    if banded:
+    with jax.named_scope("pip.writeback"):
         if writeback == "gather":
-            near = light & ~over1 & near1[slot]
+            slot = jnp.clip(pos1, 0, K1 - 1)
+            best = jnp.where(light, best1[slot], _SENTINEL)
             if use_convex:
-                near = jnp.where(conv, ~over3 & near3[slot3], near)
+                slot3 = jnp.clip(pos3, 0, K3 - 1)
+                best = jnp.where(conv, best3[slot3], best)
         else:
-            near = (
-                jnp.zeros(N, bool)
+            wdest = jnp.where(
+                valid1, src1, N + jnp.arange(K1, dtype=jnp.int32)
+            )
+            best = (
+                jnp.full(N, _SENTINEL, dtype=jnp.int32)
                 .at[wdest]
-                .set(near1, unique_indices=True, mode="drop")
+                .set(best1, unique_indices=True, mode="drop")
             )
             if use_convex:
-                near = near.at[wdest3].set(
-                    near3, unique_indices=True, mode="drop"
+                wdest3 = jnp.where(
+                    valid3, src3, N + jnp.arange(K3, dtype=jnp.int32)
                 )
-        return out, near
-    return out
+                best = best.at[wdest3].set(
+                    best3, unique_indices=True, mode="drop"
+                )
+        out = jnp.where(best == _SENTINEL, -1, best).astype(jnp.int32)
+        out = jnp.where(best == _OVF_MARK, OVERFLOW, out)
+        out = jnp.where(over1, OVERFLOW, out)
+        if use_convex:
+            out = jnp.where(over3, OVERFLOW, out)
+        if banded:
+            if writeback == "gather":
+                near = light & ~over1 & near1[slot]
+                if use_convex:
+                    near = jnp.where(conv, ~over3 & near3[slot3], near)
+            else:
+                near = (
+                    jnp.zeros(N, bool)
+                    .at[wdest]
+                    .set(near1, unique_indices=True, mode="drop")
+                )
+                if use_convex:
+                    near = near.at[wdest3].set(
+                        near3, unique_indices=True, mode="drop"
+                    )
+            return out, near
+        return out
 
 
 # the jitted join/counts/compact executables and the cell-assignment

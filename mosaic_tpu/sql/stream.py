@@ -76,7 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..dispatch import core as _dispatch, pipeline as _pipeline
-from ..obs import metrics as _metrics, trace as _trace
+from ..obs import metrics as _metrics, stages as _stages, trace as _trace
 from ..tune import resolve as _tune_resolve
 from ..runtime import (
     checkpoint as _checkpoint,
@@ -269,11 +269,13 @@ def build_stream_programs(
     """
 
     def assign(pts):
-        c = index_system.point_to_cell(pts.astype(cell_dtype), resolution)
-        return c.astype(jnp.int64)
+        with jax.named_scope("pip.cells"):
+            c = index_system.point_to_cell(pts.astype(cell_dtype), resolution)
+            return c.astype(jnp.int64)
 
     def join_one(pts, cells, chip_index):
-        shifted = (pts - chip_index.border.shift).astype(dtype)
+        with jax.named_scope("pip.recentre"):
+            shifted = (pts - chip_index.border.shift).astype(dtype)
         return pip_join_points(
             shifted,
             cells,
@@ -293,13 +295,18 @@ def build_stream_programs(
             join_one, mesh, check_rep=_dispatch.probe_check_rep(probe)
         )
 
+    def fold(acc, out):
+        with jax.named_scope("stream.fold"):
+            return acc + fold_stats(out)
+
     def loop(ring, chip_index, nb: int, collect: bool):
         k = ring.shape[0]
 
         def slot(i):
-            return jax.lax.dynamic_index_in_dim(
-                ring, i % k, axis=0, keepdims=False
-            )
+            with jax.named_scope("stream.slot"):
+                return jax.lax.dynamic_index_in_dim(
+                    ring, i % k, axis=0, keepdims=False
+                )
 
         if prefetch:
 
@@ -310,7 +317,7 @@ def build_stream_programs(
                 # overlaps the cell pipeline with the probe
                 out = join(slot(i), cells_cur, chip_index)
                 cells_next = assign(slot(i + 1))
-                return (acc + fold_stats(out), cells_next), (
+                return (fold(acc, out), cells_next), (
                     out if collect else None
                 )
 
@@ -320,9 +327,7 @@ def build_stream_programs(
             def body(carry, i):
                 pts = slot(i)
                 out = join(pts, assign(pts), chip_index)
-                return carry + fold_stats(out), (
-                    out if collect else None
-                )
+                return fold(carry, out), (out if collect else None)
 
             carry0 = jnp.zeros(3, jnp.int32)
         carry, outs = jax.lax.scan(
@@ -340,9 +345,10 @@ def build_stream_programs(
         k = ring.shape[0]
 
         def slot(i):
-            return jax.lax.dynamic_index_in_dim(
-                ring, i % k, axis=0, keepdims=False
-            )
+            with jax.named_scope("stream.slot"):
+                return jax.lax.dynamic_index_in_dim(
+                    ring, i % k, axis=0, keepdims=False
+                )
 
         steps = i0 + jnp.arange(nb, dtype=jnp.int32)
         if prefetch:
@@ -351,7 +357,7 @@ def build_stream_programs(
                 a, cells_cur = carry
                 out = join(slot(i), cells_cur, chip_index)
                 cells_next = assign(slot(i + 1))
-                return (a + fold_stats(out), cells_next), (
+                return (fold(a, out), cells_next), (
                     out if collect else None
                 )
 
@@ -361,7 +367,7 @@ def build_stream_programs(
             def body(a, i):
                 pts = slot(i)
                 out = join(pts, assign(pts), chip_index)
-                return a + fold_stats(out), (out if collect else None)
+                return fold(a, out), (out if collect else None)
 
             acc, outs = jax.lax.scan(body, acc, steps)
         return acc, cells, outs
@@ -499,6 +505,8 @@ class StreamJoin:
         #: warmed — the jit cache itself lives on the shared program
         #: bundle, this only stops repeat warm executions per stream
         self._seg_warm: set = set()
+        #: loop signatures already registered with `obs.stages`
+        self._stages_seen: set = set()
 
     def _check_batch(self, batch: int) -> None:
         if self.mesh is not None and int(batch) % self.mesh.size:
@@ -518,12 +526,28 @@ class StreamJoin:
         self._check_batch(pts.shape[0])
         return self._step_stats(pts, self.index)
 
+    def _register_stages(self, ring, n_batches: int, collect: bool) -> None:
+        """Tell `obs.stages` how to lower the loop program again (shapes
+        only, once per (ring shape, steps, collect); no lowering here)."""
+        key = (tuple(ring.shape), int(n_batches), bool(collect))
+        if key in self._stages_seen:
+            return
+        self._stages_seen.add(key)
+        devices = 1 if self.mesh is None else self.mesh.size
+        # the arguments as `run` and `compile` pass them: all by position
+        _stages.register(
+            self._donate_loop if self.donate_ring else self._loop,
+            _stages.shapes_of((ring, self.index, n_batches, collect)),
+            rows=int(ring.shape[1]) // devices,
+        )
+
     def compile(self, ring: jax.Array, n_batches: int, collect=False):
         """Warm the loop program (compile time must not pollute the
         sustained measurement); emits a ``stream_stage`` compile event.
         With ``donate_ring`` the donating twin is warmed on a scratch
         copy, so the caller's ring survives warmup intact."""
         self._check_batch(ring.shape[1])
+        self._register_stages(ring, n_batches, collect)
         with _telemetry.timed(
             "stream_stage", stage="compile", n_batches=n_batches,
             prefetch=self.prefetch, donate_ring=self.donate_ring,
@@ -558,15 +582,22 @@ class StreamJoin:
         with _trace.span(
             "stream.run", n_batches=n_batches, batch=batch, ring_k=k,
         ):
+            self._register_stages(ring, n_batches, collect)
             t0 = time.perf_counter()
-            if self.donate_ring:
-                with _quiet_donation():
-                    acc, outs = self._donate_loop(
+            # the launch returns once the loop is enqueued; the pull of
+            # the (3,) fold is where the host waits for the device
+            with _trace.span("stream.launch", n_batches=n_batches):
+                if self.donate_ring:
+                    with _quiet_donation():
+                        acc, outs = self._donate_loop(
+                            ring, self.index, n_batches, collect
+                        )
+                else:
+                    acc, outs = self._loop(
                         ring, self.index, n_batches, collect
                     )
-            else:
-                acc, outs = self._loop(ring, self.index, n_batches, collect)
-            acc_np = np.asarray(acc)  # blocks: the loop's only host pull
+            with _trace.span("stream.pull"):
+                acc_np = np.asarray(acc)  # the loop's only host pull
             wall = time.perf_counter() - t0
             n_points = n_batches * batch
             if self.donate_ring:

@@ -31,3 +31,16 @@ def devices():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual devices, got {devs}"
     return devs
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _fresh_ops_plane():
+    """The process-wide health and SLO monitors keep a 60 s window, so
+    the faults one test file injects would surface as
+    ``health_transition`` events in the captures of whichever file the
+    same worker runs next: each file starts them empty."""
+    from mosaic_tpu.obs import health, slo
+
+    health.MONITOR.reset()
+    slo.MONITOR.reset()
+    yield

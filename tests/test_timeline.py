@@ -59,7 +59,14 @@ class TestKeysAndClasses:
         ("span.stream.snapshot", "host_callback"),
         ("span.raster.snapshot", "host_callback"),
         ("span.stream.segment", "device"),
-        ("span.serve.dispatch", "device"),
+        ("span.stream.pull", "device"),
+        ("span.serve.linger", "queue_wait"),
+        ("span.serve.concat", "host_callback"),
+        ("span.serve.pad", "host_callback"),
+        ("span.serve.deliver", "host_callback"),
+        ("span.dispatch.guard.handoff", "host_callback"),
+        ("span.dispatch.launch", "host_callback"),
+        ("span.stream.launch", "host_callback"),
         ("span.join.probe.scatter", "device"),
         ("probe_stage.heavy", "device"),
         ("raster_stage.zonal", "device"),
@@ -76,6 +83,11 @@ class TestKeysAndClasses:
             "span.stream.durable_run", "stream_stage.durable_loop",
             "span.serve.request", "span.stream_bench",
             "stream_stage.single_batch", "no_such_key", None,
+            # host intervals around a dispatch: containers of the spans
+            # that say what the host did, not device time
+            "span.serve.dispatch", "span.serve.batch",
+            "serve_stage.dispatch", "serve_stage.batch",
+            "span.serve.wait",
         ):
             assert timeline.classify_key(key) is None
 
@@ -155,13 +167,36 @@ class TestAttribute:
 
     def test_envelope_fallback_without_loop_events(self):
         evts = [
-            _span("serve.dispatch", 1.0, 0.5, seq=1),
-            _span("serve.dispatch", 2.0, 0.5, seq=2),
+            _span("stream.segment", 1.0, 0.5, seq=1),
+            _span("stream.segment", 2.0, 0.5, seq=2),
         ]
         rep = timeline.attribute(evts)
         assert rep["window"]["source"] == "envelope"
         assert rep["wall_s"] == pytest.approx(1.5)
         assert rep["classes"]["idle"]["seconds"] == pytest.approx(0.5)
+
+    def test_device_time_of_a_serve_dispatch_comes_from_the_trace(self):
+        """A serve dispatch's host interval is a container; the chip's
+        own intervals (from a trace, on the monotonic clock) are the
+        device class, and the host pieces keep their own."""
+        evts = [
+            _span("serve.dispatch", 1.0, 1.0, seq=1),
+            _span("dispatch.transfer.h2d", 1.0, 0.1, seq=2),
+            _span("dispatch.launch", 1.1, 0.2, seq=3),
+            _span("dispatch.transfer.d2h", 1.3, 0.6, seq=4),
+        ]
+        rep = timeline.attribute(evts, window=(1.0, 2.0))
+        assert rep["classes"]["device"]["seconds"] == 0.0
+        rep = timeline.attribute(
+            evts, window=(1.0, 2.0), device_intervals=[(1.15, 1.25), (1.9, 2.5)]
+        )
+        c = {k: v["seconds"] for k, v in rep["classes"].items()}
+        # 1.15-1.25 lies under the launch (host_callback outranks
+        # device); the pull (transfer) ends at 1.9, so device owns 1.9-2.0
+        assert c["transfer"] == pytest.approx(0.1 + 0.6)
+        assert c["host_callback"] == pytest.approx(0.2)
+        assert c["device"] == pytest.approx(0.1)
+        assert rep["sum_s"] == pytest.approx(1.0, abs=1e-6)
 
     def test_no_intervals_returns_none(self):
         assert timeline.attribute([{"event": "x", "ts_mono": 1.0}]) is None
@@ -442,6 +477,91 @@ class TestRealDurableRunAttribution:
             v["seconds"] == 0 and v["share"] == 0
             for v in rep["diff"].values()
         )
+
+
+# ------------------------------- the chip's own intervals from a trace
+
+SERVE_FIXTURE = (
+    pathlib.Path(__file__).resolve().parent.parent
+    / "benchmark" / "fixtures" / "taxi_serve_v5e"
+)
+
+
+class TestDeviceClassFromATrace:
+    """A serve trail recorded on the chip (the benchmark's fixture: the
+    program's span events and the profiler trace of the same window)."""
+
+    @pytest.fixture(scope="class")
+    def recorded(self):
+        import gzip
+
+        from mosaic_tpu.obs import trace
+
+        runs = trace.device_intervals(str(SERVE_FIXTURE / "trace.xplane.pb.gz"))
+        lo, hi = runs[0][0] - 0.001, runs[-1][1] + 0.001
+        with gzip.open(SERVE_FIXTURE / "events.jsonl.gz", "rt") as f:
+            events = [json.loads(line) for line in f]
+        inside = []
+        for e in events:
+            iv = timeline.interval_of(e)
+            if iv is not None and lo <= iv[0] and iv[1] <= hi:
+                inside.append(e)
+        return runs, inside
+
+    def test_module_runs_land_inside_their_dispatch_spans(self, recorded):
+        """One clock: placed by the annotations' ``t``, every module run
+        of the trace lies inside a ``serve.dispatch`` span of the trail
+        (their two clocks agree within a millisecond)."""
+        runs, events = recorded
+        assert len(runs) == 46 and all(b > a for a, b in runs)
+        dispatches = [
+            timeline.interval_of(e) for e in events
+            if e.get("name") == "serve.dispatch"
+        ]
+        assert len(dispatches) >= 20
+        homeless = [
+            r for r in runs
+            if not any(a - 1e-3 <= r[0] and r[1] <= b + 1e-3
+                       for a, b in dispatches)
+        ]
+        # two programs a dispatch: the first and the last pair belong to
+        # dispatches whose spans the window cut
+        assert len(homeless) <= 4
+
+    def test_stall_report_xplane_gives_a_serve_trail_its_device_class(
+        self, recorded, tmp_path, monkeypatch, capsys
+    ):
+        import stall_report
+
+        from mosaic_tpu.obs import export
+
+        _runs, events = recorded
+        trail = str(tmp_path / "serve.jsonl")
+        export.write_jsonl(events, trail)
+        reps = []
+        for extra in ([], ["--xplane", str(SERVE_FIXTURE / "trace.xplane.pb.gz")]):
+            monkeypatch.setattr(
+                "sys.argv", ["stall_report.py", trail, *extra]
+            )
+            assert stall_report.main() == 0
+            reps.append(json.loads(
+                capsys.readouterr().out.strip().splitlines()[-1]
+            ))
+        host_only, with_trace = reps
+        # the host spans of a dispatch only bound the chip's work
+        assert host_only["classes"]["device"]["seconds"] == 0.0
+        assert with_trace["classes"]["device"]["seconds"] > 0.0
+        assert with_trace["sum_ok"] is True
+        # what the device class gained, idle lost: the other classes
+        # outrank it and keep their time
+        for c in ("transfer", "queue_wait", "host_callback"):
+            assert with_trace["classes"][c] == host_only["classes"][c]
+
+    def test_a_trace_without_program_annotations_places_nothing(self):
+        from mosaic_tpu.obs import trace
+
+        stream = SERVE_FIXTURE.parent / "taxi_stream_v5e.xplane.pb.gz"
+        assert trace.device_intervals(str(stream)) == []
 
 
 # -------------------------------------------- seg-loop compile hoist

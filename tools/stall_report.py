@@ -29,11 +29,20 @@ to a file. ``--inject-slowdown KEY:FACTOR`` scales the ``seconds`` of
 matching stage keys (fnmatch) before attribution — the CI negative
 lane proves an injected stall surfaces in the RIGHT class.
 
+``--xplane TRACE`` adds the chip's own busy intervals from a profiler
+trace of the same process (a ``.xplane.pb``, gzipped or not): its module
+runs, placed on the trail's clock by the ``t`` every ``mosaic.*``
+annotation carries (`obs.trace.device_intervals`), take class ``device``.
+A serve trail has no device class without one: a dispatch's host spans
+(hand-off, puts, launches, the blocking pull) only bound the chip's work,
+and the pull is booked as ``transfer``.
+
 Usage:
   python tools/stream_bench.py --durable --trail /tmp/stream.jsonl ...
   python tools/stall_report.py /tmp/stream.jsonl
   python tools/stall_report.py fresh.jsonl --against base.jsonl
   python tools/stall_report.py t.jsonl --inject-slowdown 'span.stream.snapshot:10'
+  python tools/stall_report.py serve.jsonl --xplane plugins/profile/*/vm.xplane.pb
 """
 
 from __future__ import annotations
@@ -82,11 +91,13 @@ def _find_stage(events, key: str) -> dict | None:
     return None
 
 
-def build_report(events) -> dict | None:
+def build_report(events, device_intervals=None) -> dict | None:
     """The full stall report for one trail, or None when the trail has
-    no usable window (no classified intervals at all)."""
+    no usable window (no classified intervals at all).
+    ``device_intervals``: the chip's busy intervals on the trail's clock
+    (``--xplane``)."""
     events = [e for e in events if isinstance(e, dict)]
-    attr = timeline.attribute(events)
+    attr = timeline.attribute(events, device_intervals=device_intervals)
     if attr is None:
         return None
     wall = attr["wall_s"]
@@ -245,12 +256,28 @@ def main() -> int:
         help="scale seconds of matching stage keys before attribution "
              "(negative-lane self-test)",
     )
+    ap.add_argument(
+        "--xplane", default=None, metavar="TRACE",
+        help="a profiler trace of the same process: its module runs "
+             "become class device (placed by the mosaic.* annotations)",
+    )
     args = ap.parse_args()
 
     events = export.read_trail(args.trail)
     if args.inject_slowdown:
         events = inject_slowdown(events, args.inject_slowdown)
-    report = build_report(events)
+    device = None
+    if args.xplane:
+        from mosaic_tpu.obs import trace
+
+        device = trace.device_intervals(args.xplane)
+        if not device:
+            print(
+                f"{args.xplane}: no module run, or no mosaic.* annotation "
+                "to place it by; no device class from the trace",
+                file=sys.stderr,
+            )
+    report = build_report(events, device)
     if report is None:
         print(
             "no classified intervals in trail; nothing to attribute",
