@@ -448,7 +448,7 @@ def phase_serve(dep: dict, size: dict, seed: int) -> dict:
               f"store-warmed engine compiled {store_compiles} programs")
     rep = dict(
         rungs=rungs, requests=size["requests"], rows=rows,
-        threads=size["threads"], batches=m["batches"],
+        threads=size["threads"], lookup=m["lookup"], batches=m["batches"],
         occupancy=m["occupancy_mean"], degraded=m["degraded"],
         real_row_share=round(m["batched_rows"] / m["padded_rows"], 4),
         linger_closed_by={

@@ -499,14 +499,7 @@ class DispatchCore:
                 f"over the {self.mesh.size}-device mesh"
             )
         dtype = index.border.verts.dtype
-        if lookup is None:
-            lookup = (
-                "mxu"
-                if jax.devices()[0].platform != "cpu"
-                and dtype == jnp.float32
-                else "gather"
-            )
-        self.lookup = lookup
+        self.lookup = _join_mod().resolve_lookup(lookup, index)
         self._dtype = dtype
         host = getattr(index, "host", None)
         self._host = host

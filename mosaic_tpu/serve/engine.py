@@ -408,6 +408,7 @@ class ServeEngine:
         out["shed"] = a["shed_queue_full"] + b["shed_deadline"]
         out["quarantined"] = a["quarantined_rows"]
         out["queue_depth"] = self.admission.depth()
+        out["lookup"] = self.core.lookup
         out["compile_signatures"] = len(self.core.signatures)
         out["cold_compiles"] = self.core.cold_compiles
         out["dispatches"] = self._dispatches

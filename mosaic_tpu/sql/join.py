@@ -78,10 +78,12 @@ MAX_SLOTS = 32
 #: (unknown result; raise the cap — `pip_join` sizes it exactly)
 OVERFLOW = -2
 
-#: direct-mode tier-1 chunk rows (keeps the un-compacted (CH, E1, 4)
-#: edge intermediate under XLA's 2 GB buffer limit); tests shrink it to
-#: exercise the lax.map path on small inputs
-_DIRECT_CHUNK = 1 << 20
+#: tier-1 chunk rows where the gathered-row work runs under `lax.map`
+#: (`_map_rows`), which bounds its intermediates (direct mode's
+#: un-compacted (N, E1, 4) edges crossed XLA's 2 GB buffer limit at 4M;
+#: see `pip_join_points` for the gather lane). A multiple of 128; tests
+#: shrink it to run the chunked paths small
+_TIER1_CHUNK = 1 << 20
 
 #: epsilon-band multipliers (SURVEY §7 precision strategy): a point is
 #: borderline when its cell-rounding margin (`IndexSystem.
@@ -1122,7 +1124,12 @@ def _mm_rows(idx: jax.Array, table_f32: jax.Array) -> jax.Array:
     Data-dependent row gathers serialize on TPU (~10 GB/s effective on
     the 512 B tier-1 edge rows, ~42 ms at a 640k-point cap); contracting
     a (K, U) one-hot against the (U, D) row table runs on the MXU
-    instead. Exactness: each one-hot row has a single 1, and any f32
+    instead. Cost: linear in K x U whatever is fetched — 5.19 ps a row
+    for every table row on v5e (703 ms for K = 4M, U = 33,898, D = 153,
+    at 90% of the MXU's peak: ledger, PR 24), where the two row gathers
+    of `_tier1_rows_gather` cost the same whatever U is — so
+    `resolve_lookup` no longer picks it (PERF.md section 6, PR 25).
+    Exactness: each one-hot row has a single 1, and any f32
     value splits exactly into three bf16 terms (Sterbenz: the rounded
     high part is within a factor 2 of the remainder, so each residual
     subtraction is exact); each output element is therefore reassembled
@@ -1195,6 +1202,44 @@ def _tier1_rows_mxu(us: jax.Array, index: "ChipIndex"):
     cores = out[:, o + M1 : o + 2 * M1] > 0.5
     heavy = out[:, o + 2 * M1].astype(jnp.int32)
     return edges, ebits, geoms, cores, heavy
+
+
+def _tier1_rows_gather(us: jax.Array, index: "ChipIndex"):
+    """All tier-1 per-cell rows for slots ``us`` in TWO row gathers: the
+    edge row, and one int32 row of everything else. Exact by construction
+    (rows are moved, never converted: ebits travel bit-cast, bools as
+    0/1), for any edge dtype. Same returns as :func:`_tier1_rows_mxu`.
+
+    A gather is paid per gather, not per byte (traced on v5e, each of
+    the four separate gathers cost 17-26 ms a 4M-row step and the 4-byte
+    slot_core row the most: PERF.md section 6, PR 25), so the four small
+    tables travel as one packed row. The edge row is fetched
+    component-major ([ax.. | ay.. | bx.. | by..], a transposed copy built
+    in-program: 13 MB at 33,898 cells), so each coordinate the parity
+    test reads is a contiguous lane range of the fetched row; gathered
+    as (K, E1, 4), XLA laid the rows out E1-minor and padded to 128
+    lanes: 15.3 GB at K = 4M, and 57 ms of parity where this form takes
+    13.5.
+    """
+    U, E1 = index.cell_ebits.shape
+    M1 = index.cell_slot_geom.shape[1]
+    edges = jnp.moveaxis(index.cell_edges, 2, 1).reshape(U, 4 * E1)[us]
+    rest = jnp.concatenate(
+        [
+            jax.lax.bitcast_convert_type(index.cell_ebits, jnp.int32),
+            index.cell_slot_geom.astype(jnp.int32),
+            index.cell_slot_core.astype(jnp.int32),
+            index.cell_heavy.astype(jnp.int32)[:, None],
+        ],
+        axis=1,
+    )[us]
+    return (
+        jnp.moveaxis(edges.reshape(-1, 4, E1), 1, 2),
+        jax.lax.bitcast_convert_type(rest[:, :E1], jnp.uint32),
+        rest[:, E1 : E1 + M1],
+        rest[:, E1 + M1 : E1 + 2 * M1] != 0,
+        rest[:, E1 + 2 * M1],
+    )
 
 
 def _heavy_rows_mxu(h2: jax.Array, index: "ChipIndex"):
@@ -1335,6 +1380,70 @@ def resolve_probe_mode(probe: str) -> str:
     return probe
 
 
+def _map_rows(fn, chunk: int, *cols):
+    """``fn(*cols)`` over row chunks of at most ``chunk`` by `lax.map`, for
+    a row-wise ``fn`` (row i of every output depends on row i of the
+    inputs alone): bounds ``fn``'s intermediates by the chunk, results
+    unchanged. ``cols`` share their leading length; ``fn`` returns a
+    pytree of arrays of that leading length (``None`` leaves pass)."""
+    n = cols[0].shape[0]
+    n_ch = -(-n // chunk)
+    # equal lane-aligned chunks: 4M rows are 4 x 1,000,064, not 4 x 2^20
+    ch = -(-(-(-n // n_ch)) // 128) * 128
+    pad = n_ch * ch - n
+    res = jax.lax.map(
+        lambda c: fn(*c),
+        tuple(
+            jnp.pad(c, [(0, pad)] + [(0, 0)] * (c.ndim - 1)).reshape(
+                (n_ch, ch) + c.shape[1:]
+            )
+            for c in cols
+        ),
+    )
+    return jax.tree.map(
+        lambda r: r.reshape((n_ch * ch,) + r.shape[2:])[:n], res
+    )
+
+
+#: tier-1 row fetch lanes (`pip_join_points` ``lookup=``)
+_LOOKUPS = ("gather", "mxu", "mxu2")
+
+
+def resolve_lookup(
+    lookup: "str | None", index: ChipIndex, *, source: str = "explicit"
+) -> str:
+    """The tier-1 row-fetch lane for ``index`` — the ONE place the auto
+    rule lives (`pip_join`, `StreamJoin` and `DispatchCore` all call it).
+
+    A ``lookup`` the caller resolved already (explicit argument,
+    ``MOSAIC_TUNE_LOOKUP`` or a `TuningProfile`, in `tune/resolve.py`'s
+    order; ``source`` says which) passes through. ``None`` is auto, and
+    auto is ``gather``, on every platform and for every index. Both lanes
+    are exact fetches of the same row; what separates them is cost, read
+    on the v5e at 4M rows a step (PERF.md section 6, PR 25): the one-hot
+    costs 5.19 ps a row for every CELL of the index (703 ms at 33,898
+    cells) on top of a fixed 180-206 ms step, and the two-gather fetch
+    makes a 179-181 ms step at 1,427 cells and at 8,145 alike, 204 at
+    33,898 — the one-hot lost at every size tried, so no cell count is
+    left at which the rule would pick it. ``mxu`` / ``mxu2`` stay
+    selectable until ROADMAP D1 prunes them.
+
+    Records one ``join_lookup`` telemetry event (``lookup``, ``cells``,
+    ``source`` = explicit | env | profile | auto).
+    """
+    if lookup is None:
+        lookup, source = "gather", "auto"
+    elif lookup not in _LOOKUPS:
+        raise ValueError(
+            f"lookup must be one of {_LOOKUPS}, got {lookup!r}"
+        )
+    _telemetry.record(
+        "join_lookup", lookup=lookup, cells=int(index.cell_edges.shape[0]),
+        source=source,
+    )
+    return lookup
+
+
 def pip_join_points(
     points: jax.Array,
     pcells: jax.Array,
@@ -1408,7 +1517,7 @@ def pip_join_points(
         raise ValueError(
             f"writeback must be scatter|gather|direct, got {writeback!r}"
         )
-    if lookup not in ("gather", "mxu", "mxu2"):
+    if lookup not in _LOOKUPS:
         raise ValueError(f"lookup must be gather|mxu|mxu2, got {lookup!r}")
     if compaction not in ("scatter", "mxu"):
         raise ValueError(
@@ -1449,7 +1558,7 @@ def pip_join_points(
     with jax.named_scope("pip.hash_probe"):
         u = _probe_slot(pcells, index)
         found = u >= 0
-    banded_d = edge_eps2 is not None
+    banded = edge_eps2 is not None
     H = int(index.heavy_edges.shape[0])
     CV = int(index.convex_edges.shape[0])
     # adaptive per-cell routing: the density class is a table lookup, so
@@ -1470,7 +1579,7 @@ def pip_join_points(
                 found, index.cell_convex[jnp.maximum(u, 0)], -1
             )
             conv = cvrow >= 0
-            if banded_d:
+            if banded:
                 # band exactness holds only while eps² fits under the
                 # bucket pad guard; wider bands fall back to tier 1
                 guard2 = index.convex_ybin[jnp.maximum(cvrow, 0), 2]
@@ -1478,64 +1587,57 @@ def pip_join_points(
     else:
         conv = None
 
+    def _tier1(px_c, py_c, us_c):
+        """Tier 1 for rows already matched to cell slots ``us_c``: fetch
+        the cell's row, test the crossings, pick the slot. Row-wise."""
+        if lookup in ("mxu", "mxu2"):
+            edges1, ebits1, geoms1, cores1, heavy1 = _tier1_rows_mxu(
+                us_c, index
+            )
+        else:
+            edges1, ebits1, geoms1, cores1, heavy1 = _tier1_rows_gather(
+                us_c, index
+            )
+        r1 = _ray_parity(px_c, py_c, edges1, ebits1, eps2=edge_eps2)
+        parity, near1 = r1 if banded else (r1, None)
+        return (
+            _slot_best(parity, geoms1, cores1), near1,
+            heavy1 if H else None,
+        )
+
+    def _tier1_rows(px_c, py_c, us_c):
+        if lookup == "gather" and us_c.shape[0] > _TIER1_CHUNK:
+            # rows are independent, so chunks are exact; they bound the
+            # fetched rows' footprint (128-lane padded): a 4M-row stream
+            # loop holds 3.9 GB of temporaries in 1M-row chunks against
+            # 11.1 GB whole, at the same step time on v5e (PERF.md
+            # section 6, PR 25). Direct mode's un-compacted rows crossed
+            # XLA's 2 GB buffer limit above ~2M points unchunked
+            # (tpu_compile_helper crash, observed at 4M on v5e)
+            return _map_rows(_tier1, _TIER1_CHUNK, px_c, py_c, us_c)
+        return _tier1(px_c, py_c, us_c)
+
     if writeback == "direct":
         with jax.named_scope("pip.tier1"):
             us = jnp.maximum(u, 0)
-
-            def _direct_tier1(args):
-                px_c, py_c, us_c = args
-                r = _ray_parity(
-                    px_c, py_c,
-                    index.cell_edges[us_c], index.cell_ebits[us_c],
-                    eps2=edge_eps2,
-                )
-                par, near = r if banded_d else (r, None)
-                b = _slot_best(
-                    par, index.cell_slot_geom[us_c], index.cell_slot_core[us_c]
-                )
-                return (b, near) if banded_d else b
-
-            # the un-compacted (N, E1, 4) edge intermediate crosses XLA's
-            # 2 GB buffer limit above ~2M points (tpu_compile_helper crash,
-            # observed at 4M on v5e): chunk the tier-1 row work via lax.map
-            CH = _DIRECT_CHUNK
-            if N > CH:
-                pad = (-N) % CH
-                px_p = jnp.pad(points[:, 0], (0, pad))
-                py_p = jnp.pad(points[:, 1], (0, pad))
-                us_p = jnp.pad(us, (0, pad))
-                n_ch = (N + pad) // CH
-                res = jax.lax.map(
-                    _direct_tier1,
-                    (
-                        px_p.reshape(n_ch, CH),
-                        py_p.reshape(n_ch, CH),
-                        us_p.reshape(n_ch, CH),
-                    ),
-                )
-                if banded_d:
-                    best = res[0].reshape(-1)[:N]
-                    near1 = res[1].reshape(-1)[:N]
-                else:
-                    best = res.reshape(-1)[:N]
-            else:
-                r1 = _direct_tier1((points[:, 0], points[:, 1], us))
-                best, near1 = r1 if banded_d else (r1, None)
+            best, near1, heavy_d = _tier1_rows(
+                points[:, 0], points[:, 1], us
+            )
             best = jnp.where(found, best, _SENTINEL)
             if H:
-                hs = jnp.where(found, index.cell_heavy[us], -1)
+                hs = jnp.where(found, heavy_d, -1)
                 best2, over2, near_sc = _heavy_tier(
                     points[:, 0], points[:, 1], hs, index, heavy_cap, N, N,
                     edge_eps2,
                 )
                 best = jnp.minimum(best, best2)
                 best = jnp.where(over2, _OVF_MARK, best)
-                if banded_d:
+                if banded:
                     near1 = near1 | near_sc
         with jax.named_scope("pip.writeback"):
             out = jnp.where(best == _SENTINEL, -1, best).astype(jnp.int32)
             out = jnp.where(best == _OVF_MARK, OVERFLOW, out)
-            if banded_d:
+            if banded:
                 return out, near1 & found
             return out
 
@@ -1556,20 +1658,8 @@ def pip_join_points(
         pxy = points[src1]
         px, py = pxy[:, 0], pxy[:, 1]
 
-    banded = edge_eps2 is not None
     with jax.named_scope("pip.tier1"):
-        if lookup in ("mxu", "mxu2"):
-            edges1, ebits1, geoms1, cores1, heavy1 = _tier1_rows_mxu(
-                us, index
-            )
-        else:
-            edges1, ebits1 = index.cell_edges[us], index.cell_ebits[us]
-            geoms1 = index.cell_slot_geom[us]
-            cores1 = index.cell_slot_core[us]
-            heavy1 = index.cell_heavy[us]
-        r1 = _ray_parity(px, py, edges1, ebits1, eps2=edge_eps2)
-        parity, near1 = r1 if banded else (r1, None)
-        best1 = _slot_best(parity, geoms1, cores1)
+        best1, near1, heavy1 = _tier1_rows(px, py, us)
         best1 = jnp.where(valid1, best1, _SENTINEL)
 
     if H:
@@ -1801,7 +1891,7 @@ def pip_join(
     ``direct`` — see :func:`pip_join_points`); results are identical,
     the bench autotunes the winner per workload. ``lookup`` picks the
     tier-1 row access (``gather``/``mxu`` one-hot matmul); default None
-    auto-selects ``mxu`` on accelerators for f32 indexes.
+    is auto, which :func:`resolve_lookup` decides (``gather``).
 
     ``cell_margin_k`` / ``edge_band_k`` override the calibrated band
     constants :data:`CELL_MARGIN_K` / :data:`EDGE_BAND_K` for this call —
@@ -1893,12 +1983,9 @@ def pip_join(
         else np.asarray(chip_index.border.shift, dtype=np.float64)
     )
     dtype = chip_index.border.verts.dtype
-    if lookup is None:
-        lookup = (
-            "mxu"
-            if jax.devices()[0].platform != "cpu" and dtype == jnp.float32
-            else "gather"
-        )
+    lookup = resolve_lookup(
+        lookup, chip_index, source=knobs.sources["lookup"]
+    )
     n = raw.shape[0]
     core = (
         None

@@ -89,6 +89,7 @@ from .join import (
     ChipIndex,
     host_join_with_cells,
     pip_join_points,
+    resolve_lookup,
     resolve_probe_mode,
 )
 
@@ -456,12 +457,9 @@ class StreamJoin:
         self._last_quarantine: tuple | None = None
         dtype = index.border.verts.dtype
         platform = jax.devices()[0].platform
-        if lookup is None:
-            lookup = (
-                "mxu"
-                if platform != "cpu" and dtype == jnp.float32
-                else "gather"
-            )
+        lookup = resolve_lookup(
+            lookup, index, source=knobs.sources["lookup"]
+        )
         if compaction is None:
             # the MXU block compaction keeps at most ``compact_block``
             # found points per 2048-point block (found rates up to ~9%)

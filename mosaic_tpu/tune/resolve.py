@@ -119,11 +119,21 @@ def resolve_knob(name: str, explicit, profile, default):
     return default, "default"
 
 
-def resolve_knobs(entry: str, profile, *, explicit: dict, defaults: dict) -> dict:
+class ResolvedKnobs(dict):
+    """``{knob: value}``, with ``sources``: ``{knob: explicit|env|
+    profile|default}`` — which layer of the precedence gave each value."""
+
+    sources: dict
+
+
+def resolve_knobs(
+    entry: str, profile, *, explicit: dict, defaults: dict
+) -> ResolvedKnobs:
     """Resolve every knob in ``explicit``/``defaults`` for one frontend
     entry point and record the single summarizing ``tune_resolve``
-    telemetry event. Returns ``{knob: value}``."""
-    values, sources = {}, {}
+    telemetry event. Returns ``{knob: value}`` (a `ResolvedKnobs`)."""
+    values, sources = ResolvedKnobs(), {}
+    values.sources = sources
     for name, default in defaults.items():
         values[name], sources[name] = resolve_knob(
             name, explicit.get(name), profile, default

@@ -185,11 +185,16 @@ def test_mxu_lookup_bit_exact():
     assert (np.asarray(a) >= 0).any()
 
 
-def test_direct_chunked_path_identical(monkeypatch):
-    """direct mode chunks its tier-1 row work above _DIRECT_CHUNK points
-    (XLA's 2 GB buffer limit at 4M on TPU); shrink the chunk so the
-    lax.map path runs on a small batch and assert bitwise equality with
-    the unchunked scatter path, bands included."""
+@pytest.mark.parametrize("banded", [True, False])
+@pytest.mark.parametrize("writeback", ["direct", "scatter", "gather"])
+def test_tier1_chunked_path_identical(monkeypatch, writeback, banded):
+    """Tier 1 runs its row work in `lax.map` chunks above _TIER1_CHUNK
+    rows: direct mode always (XLA's 2 GB buffer limit at 4M on TPU), the
+    compacting writebacks on the gather lane (its padded edge rows did not
+    fit the chip in a 4M-row stream loop). Shrink the chunk so the chunked
+    path runs on a small batch and assert bitwise equality with the
+    unchunked scatter path, bands and heavy cells included."""
+    import jax
     import jax.numpy as jnp
 
     from mosaic_tpu.core.index import H3
@@ -197,7 +202,10 @@ def test_direct_chunked_path_identical(monkeypatch):
     from mosaic_tpu.sql import join as J
 
     col = wkt.from_wkt(ZONES)
-    cidx = J.build_chip_index(tessellate(col, H3, 3, keep_core_geoms=False))
+    cidx = J.build_chip_index(
+        tessellate(col, H3, 3, keep_core_geoms=False), edge_cap=8
+    )
+    assert cidx.num_heavy_cells  # the chunks carry tier 2's row ids too
     rng = np.random.default_rng(11)
     pts = np.column_stack(
         [rng.uniform(-25, 35, 10000), rng.uniform(-25, 20, 10000)]
@@ -207,15 +215,17 @@ def test_direct_chunked_path_identical(monkeypatch):
         pts - np.asarray(cidx.border.shift, np.float64),
         dtype=cidx.border.verts.dtype,
     )
-    eps2 = jnp.asarray(1e-10, cidx.border.verts.dtype)
-    a, na = J.pip_join_points(shifted, cells, cidx, edge_eps2=eps2)
-    monkeypatch.setattr(J, "_DIRECT_CHUNK", 1536)  # non-divisor: pads
-    d, nd = J.pip_join_points(
-        shifted, cells, cidx, edge_eps2=eps2, writeback="direct"
+    eps2 = jnp.asarray(1e-10, cidx.border.verts.dtype) if banded else None
+    want = J.pip_join_points(shifted, cells, cidx, edge_eps2=eps2)
+    monkeypatch.setattr(J, "_TIER1_CHUNK", 1536)  # non-divisor: pads
+    got = J.pip_join_points(
+        shifted, cells, cidx, edge_eps2=eps2, writeback=writeback
     )
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(d))
-    np.testing.assert_array_equal(np.asarray(na), np.asarray(nd))
-    assert (np.asarray(a) >= 0).any()
+    want, got = jax.tree.leaves(want), jax.tree.leaves(got)  # out[, near]
+    assert len(want) == len(got) == 1 + banded
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+    assert (np.asarray(want[0]) >= 0).any()
 
 
 def test_mxu_compaction_identical():

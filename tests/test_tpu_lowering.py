@@ -103,6 +103,32 @@ def test_mxu_row_lookup_split_survives_for_tpu():
     assert hlo.count("reduce_precision") >= 3
 
 
+@pytest.mark.parametrize("given", [None, "mxu"])
+def test_lookup_lane_in_the_tpu_lowering(problem, given):
+    """The lane `resolve_lookup` names is the one that is lowered: auto
+    holds no (K, U) one-hot contraction on the TPU target, an explicit
+    ``mxu`` holds three `dot_general` (one per bf16 term of the split)."""
+    from mosaic_tpu.sql.join import resolve_lookup
+
+    h3, index, _ = problem
+    U = index.cell_edges.shape[0]
+    lane = resolve_lookup(given, index)
+    assert lane == (given or "gather")
+    K = 4096
+    pts = jnp.asarray(random_points(K, bbox=BBOX, seed=3), jnp.float32)
+    cells = h3.point_to_cell(pts, 7).astype(jnp.int64)
+    hlo = _tpu_lower(
+        jax.jit(functools.partial(pip_join_points, lookup=lane)).trace(
+            pts, cells, index
+        )
+    )
+    onehot = [
+        ln for ln in hlo.splitlines()
+        if "dot_general" in ln and f"tensor<{K}x{U}xbf16>" in ln
+    ]
+    assert len(onehot) == (3 if given else 0)
+
+
 def test_bench_step_lowers_for_tpu(problem):
     h3, index, _ = problem
     dtype = index.border.verts.dtype
