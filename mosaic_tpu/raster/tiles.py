@@ -182,6 +182,7 @@ def stack_tiles(
 
 
 @functools.partial(jax.jit, static_argnames=("th", "tw"))
+@jax.named_scope("zonal.centers")
 def tile_centers(gt6, origin, *, th: int, tw: int):
     """((TH*TW, 2) f64) world coordinates of one tile's pixel centers,
     computed on device from the geotransform and the tile origin alone.
@@ -210,4 +211,5 @@ def assign_tile_cells(gt, origin, shape, index_system, resolution):
     fuse assign + probe + fold into one program."""
     th, tw = shape
     xy = tile_centers(jnp.asarray(gt), jnp.asarray(origin), th=th, tw=tw)
-    return index_system.point_to_cell(xy, resolution).astype(jnp.int64)
+    with jax.named_scope("pip.cells"):
+        return index_system.point_to_cell(xy, resolution).astype(jnp.int64)

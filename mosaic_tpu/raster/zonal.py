@@ -45,7 +45,7 @@ import numpy as np
 
 from ..dispatch import core as _dispatch
 from ..kernels.zonal import zonal_fold, zonal_tiled
-from ..obs import trace as _trace
+from ..obs import stages as _stages, trace as _trace
 from ..runtime import faults as _faults, telemetry as _telemetry
 from ..runtime.errors import CapacityOverflow
 from ..runtime.platform import interpret_kernels
@@ -149,6 +149,8 @@ class ZonalEngine:
         self.index_system = index_system
         self.resolution = int(resolution)
         self.chip_index = chip_index
+        #: (program, tile pixels) already registered with `obs.stages`
+        self._stages_seen: set = set()
         # profile-consumed knobs fold at this host entry point: explicit
         # arg > env knob > profile > built-in default (tune/resolve.py).
         # lane="auto" is the legacy spelling of "not passed".
@@ -221,7 +223,8 @@ class ZonalEngine:
                 )
 
             def probe_core(pts, cells, index):
-                shifted = (pts - index.border.shift).astype(dtype)
+                with jax.named_scope("pip.recentre"):
+                    shifted = (pts - index.border.shift).astype(dtype)
                 out = pip_join_points(
                     shifted, cells, index,
                     heavy_cap=heavy_cap, found_cap=found_cap,
@@ -253,6 +256,7 @@ class ZonalEngine:
 
             self._zones_probe = jax.jit(zones_probe, static_argnums=(3, 4))
 
+            @jax.named_scope("zonal.fold")
             def zones_fold(vals, seg):
                 if lane_resolved == "tiled":
                     return zonal_tiled(
@@ -263,7 +267,18 @@ class ZonalEngine:
 
             self._zones_fold = jax.jit(zones_fold)
 
-    def _tile_zone_rows(self, plan, t: int, maskb=None) -> np.ndarray:
+    def _register_stages(self, fn, args, rows: int) -> None:
+        """Tell `obs.stages` how to lower one of the tile's two programs
+        again (shapes only, once a program and tile shape; no lowering
+        here), so that a device trace can name its ops by stage."""
+        key = (id(fn), rows)
+        if key not in self._stages_seen:
+            self._stages_seen.add(key)
+            _stages.register(fn, _stages.shapes_of(args), rows=rows)
+
+    def _tile_zone_rows(
+        self, plan, t: int, maskb=None, tally: "dict | None" = None,
+    ) -> np.ndarray:
         """(TH*TW,) zone row per pixel center of tile ``t`` (negative =
         outside every zone): device probe with the epsilon band, exact
         f64 host re-join of the banded pixels. The host patch is what
@@ -273,7 +288,8 @@ class ZonalEngine:
         expression path, where validity is decided INSIDE the fused
         program) patches every banded pixel — membership is
         band-independent, so the two are equivalent on every pixel that
-        reaches a fold."""
+        reaches a fold. ``tally["patched_pixels"]`` (a scan's own count)
+        grows by the pixels the host re-joined."""
         th, tw = plan.shape
         if self.mesh is not None and (th * tw) % self.mesh.size:
             raise ValueError(
@@ -282,10 +298,12 @@ class ZonalEngine:
                 "pixel count is a multiple of the device count"
             )
         gt6 = np.asarray(plan.gt, np.float64)
-        geom_d, near_d = self._zones_probe(
-            gt6, plan.origins[t], self.chip_index, th, tw
-        )
-        geom = np.array(geom_d)
+        args = (gt6, plan.origins[t], self.chip_index, th, tw)
+        self._register_stages(self._zones_probe, args, th * tw)
+        with _trace.span("raster.probe", tile=t):
+            geom_d, near_d = self._zones_probe(*args)
+            geom = np.array(geom_d)  # blocks: the probe's pull
+            near = None if self._host is None else np.asarray(near_d)
         if (geom == OVERFLOW).any():
             raise CapacityOverflow(
                 f"zonal probe overflow on tile {t}: "
@@ -293,21 +311,28 @@ class ZonalEngine:
                 "heavy/found/convex caps — leave caps at None for exact "
                 "sizing"
             )
-        if self._host is not None:
-            near = np.asarray(near_d)
-            if maskb is not None:
-                near = near & maskb
-            if near.any():
-                pts = host_tile_centers(plan, t)[near]
-                geom[near] = np.asarray(
-                    host_join(
-                        pts, self._host, self.index_system,
-                        self.resolution,
+        if near is not None:
+            with _trace.span("raster.patch", tile=t) as sp:
+                if maskb is not None:
+                    near = near & maskb
+                rows = int(np.count_nonzero(near))
+                sp.set(rows=rows)
+                if rows:
+                    pts = host_tile_centers(plan, t)[near]
+                    geom[near] = np.asarray(
+                        host_join(
+                            pts, self._host, self.index_system,
+                            self.resolution,
+                        )
                     )
-                )
+            if tally is not None:
+                tally["patched_pixels"] += rows
         return geom
 
-    def _tile_zone_stats_async(self, plan, t: int, vals_flat, mask_flat):
+    def _tile_zone_stats_async(
+        self, plan, t: int, vals_flat, mask_flat,
+        tally: "dict | None" = None,
+    ):
         """One tile's zone partial as DEVICE arrays — async dispatch,
         no blocking pull. The probe + epsilon host patch
         (:meth:`_tile_zone_rows`) still complete on the host (the patch
@@ -316,9 +341,15 @@ class ZonalEngine:
         tile's fold with the next tile's probe and pull at its drain
         point."""
         maskb = np.asarray(mask_flat, bool)
-        geom = self._tile_zone_rows(plan, t, maskb)
-        seg = np.where(maskb & (geom >= 0), geom, -1).astype(np.int32)
-        return self._zones_fold(jnp.asarray(vals_flat), jnp.asarray(seg))
+        geom = self._tile_zone_rows(plan, t, maskb, tally)
+        with _trace.span("raster.fold", tile=t):
+            seg = np.where(maskb & (geom >= 0), geom, -1).astype(np.int32)
+            self._register_stages(
+                self._zones_fold, (vals_flat, seg), int(seg.shape[0])
+            )
+            return self._zones_fold(
+                jnp.asarray(vals_flat), jnp.asarray(seg)
+            )
 
     def _tile_zone_stats(self, plan, t: int, vals_flat, mask_flat):
         """One tile's zone partial ((g,) count, sum, min, max as numpy):

@@ -324,6 +324,8 @@ class RasterStream:
         host = getattr(self.chip_index, "host", None)
         degraded = [0]
         counters = {"snapshots": 0}
+        #: this scan's own count of pixels the host re-joined
+        tally = {"patched_pixels": 0}
         start = int(start_tile)
         win = _pipeline.resolve_window(window)
 
@@ -343,13 +345,13 @@ class RasterStream:
                 def dispatch(t=t):
                     return eng._tile_zone_stats_async(
                         plan, t, vals[t].reshape(-1),
-                        mask[t].reshape(-1),
+                        mask[t].reshape(-1), tally,
                     )
             else:
                 def dispatch(t=t):
                     # probe + epsilon patch, then the fused
                     # expression+fold program — one launch
-                    geom = eng._tile_zone_rows(plan, t)
+                    geom = eng._tile_zone_rows(plan, t, tally=tally)
                     seg = np.where(
                         geom >= 0, geom, -1
                     ).astype(np.int32)
@@ -472,6 +474,14 @@ class RasterStream:
             degraded_tiles=degraded_tiles, resumed_from=resumed_from,
             window=pstats.window,
             pixels_per_sec=round(px_run / max(wall, 1e-9), 1),
+        )
+        # one event a scan: what the tile loop met (the pixels it was
+        # handed, those that could fold, those the host re-joined)
+        _telemetry.record(
+            "raster_scan", seconds=round(wall, 6), tiles=n_run,
+            pixels=plan.pixels, valid_pixels=int(np.count_nonzero(mask)),
+            patched_pixels=tally["patched_pixels"],
+            degraded_tiles=degraded_tiles, window=pstats.window,
         )
         live = cnt_acc > 0
         stats = zonal.ZonalResult(

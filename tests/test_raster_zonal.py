@@ -405,3 +405,60 @@ def test_scan_joins_trace_on_resume(stream, raster, tmp_path):
     assert second["trace_id"] == first["trace_id"]
     assert second["resumed_from"] == 4
     assert first["error"] == "RuntimeError"
+
+
+# --------------------------------------- the scan against plain numpy
+
+
+def _zone_rings():
+    """ZONES as lists of (n, 2) float arrays (shell first, then holes),
+    read off the WKT text: nothing of the program."""
+    import re
+
+    return [
+        [np.array([[float(v) for v in p.split()] for p in ring.split(",")])
+         for ring in re.findall(r"\(([^()]+)\)", text)]
+        for text in ZONES
+    ]
+
+
+def _plain_zone_of(x, y):
+    """Smallest zone whose polygon holds (x, y): even-odd ray casting over
+    every ring of the polygon, so a hole's ring takes its points out."""
+    out = np.full(x.shape, -1)
+    for z, rings in reversed(list(enumerate(_zone_rings()))):
+        inside = np.zeros(x.shape, bool)
+        for ring in rings:
+            a, b = ring[:-1], ring[1:]
+            for (ax, ay), (bx, by) in zip(a, b):
+                if ay == by:
+                    continue
+                xc = ax + (y - ay) * (bx - ax) / (by - ay)
+                inside ^= ((ay > y) != (by > y)) & (x < xc)
+        out[inside] = z
+    return out
+
+
+@pytest.mark.parametrize("seed", [101, 202])
+def test_scan_equals_plain_numpy_zonal_statistics(stream, seed):
+    """An int16 scene whose pixel centres lie on no zone edge: per zone the
+    scan's count, sum, min and max are the plain numpy fold's, exactly."""
+    rng = np.random.default_rng(seed)
+    h, w, nodata = 75, 90, 32767
+    data = rng.integers(0, 10_000, (1, h, w)).astype(np.int16)
+    data[0][rng.random((h, w)) < 0.15] = nodata
+    gt = (-0.13, 1.0, 0.0, 15.21, 0.0, -1.0)
+    got = stream.scan(
+        Raster(data=data, gt=gt, srid=0, nodata=nodata), tile=(32, 32)
+    ).stats
+    rows, cols = np.mgrid[0:h, 0:w]
+    zone = _plain_zone_of(gt[0] + (cols + 0.5) * gt[1],
+                          gt[3] + (rows + 0.5) * gt[5])
+    keep = (zone >= 0) & (data[0] != nodata)
+    assert sorted(np.unique(zone[keep])) == got.keys.tolist() == [0, 1, 2]
+    for i, z in enumerate(got.keys):
+        v = data[0][keep & (zone == z)].astype(np.int64)
+        assert got.count[i] == v.size
+        assert got.sum[i] == v.sum() and got.sum.dtype == np.float64
+        assert (got.min[i], got.max[i]) == (v.min(), v.max())
+    assert got.pixels == int(keep.sum())

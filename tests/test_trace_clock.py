@@ -11,6 +11,7 @@ import glob
 import statistics
 import threading
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -566,3 +567,77 @@ def test_a_program_that_no_longer_lowers_is_left_out():
         assert stages.tables() == {}
     assert [e["event"] for e in events] == ["stages_lowering_failed"]
     stages.clear()
+
+
+# ------------------------------------------------------- the raster scan
+
+ZONAL_STAGES = {
+    "zonal.centers", "pip.cells", "pip.recentre", "pip.hash_probe",
+    "pip.compact", "pip.tier1", "pip.writeback",
+}
+
+
+@pytest.fixture(scope="module")
+def scanned(index, grid):
+    from mosaic_tpu.raster import Raster
+    from mosaic_tpu.sql import RasterStream
+
+    rng = np.random.default_rng(5)
+    data = rng.integers(0, 10_000, (1, 50, 60)).astype(np.int16)
+    raster = Raster(data=data, gt=(-25.3, 1.0, 0.0, 20.2, 0.0, -0.9),
+                    srid=0, nodata=32767)
+    rs = RasterStream(index, grid, RES)
+    stages.clear()
+    with telemetry.capture() as events:
+        result = rs.scan(raster, tile=(32, 32))
+    return SimpleNamespace(rs=rs, events=events, result=result)
+
+
+def test_a_scanned_tile_holds_probe_patch_and_fold_spans(scanned):
+    spans = [e for e in scanned.events if e["event"] == "span"]
+    by_id = {e["span_id"]: e for e in spans}
+    scan = [e for e in spans if e["name"] == "raster.scan"]
+    tiles = [e for e in spans if e["name"] == "raster.zonal"]
+    assert len(scan) == 1 and len(tiles) == scanned.result.ntiles == 4
+    assert all(t["parent_id"] == scan[0]["span_id"] and t["pipelined"]
+               for t in tiles)
+    for name in ("raster.probe", "raster.patch", "raster.fold"):
+        mine = [e for e in spans if e["name"] == name]
+        assert sorted(e["tile"] for e in mine) == [0, 1, 2, 3]
+        assert all(by_id[e["parent_id"]]["name"] == "raster.zonal"
+                   and by_id[e["parent_id"]]["step"] == e["tile"] for e in mine)
+    # within a tile: probe, then patch, then fold
+    for t in range(4):
+        order = [e["name"] for e in spans
+                 if e.get("tile") == t and e["name"].startswith("raster.")]
+        assert order == ["raster.probe", "raster.patch", "raster.fold"]
+    # the tile's pull is the pipeline's drain, beside the tiles
+    drains = [e for e in spans if e["name"] == "stream.pipeline.drain"]
+    assert len(drains) == 4
+    assert all(d["site"] == "raster.pipeline.drain"
+               and d["parent_id"] == scan[0]["span_id"] for d in drains)
+
+
+def test_a_scan_records_one_raster_scan_event(scanned):
+    evts = [e for e in scanned.events if e["event"] == "raster_scan"]
+    assert len(evts) == 1
+    e = evts[0]
+    assert (e["tiles"], e["pixels"], e["valid_pixels"]) == (4, 3000, 3000)
+    patched = sum(s["rows"] for s in scanned.events
+                  if s["event"] == "span" and s["name"] == "raster.patch")
+    assert e["patched_pixels"] == patched and e["degraded_tiles"] == 0
+    assert e["window"] == 4 and e["seconds"] > 0
+
+
+def test_both_zonal_programs_are_registered_and_map_to_stages(scanned):
+    assert sorted(stages.registered()) == [
+        ("jit_zones_fold", 1024), ("jit_zones_probe", 1024)]
+    tables = stages.tables()
+    fold = set(tables["jit_zones_fold"].values())
+    assert fold == {"zonal.fold"}
+    probe = set(tables["jit_zones_probe"].values())
+    assert ZONAL_STAGES <= probe
+    unscoped = [k for k, v in tables["jit_zones_probe"].items()
+                if v == stages.UNSCOPED]
+    assert len(unscoped) <= 0.05 * len(tables["jit_zones_probe"]), unscoped
+    assert stages.stage_of("jit(f)/zonal.fold/scatter-add") == "zonal.fold"
