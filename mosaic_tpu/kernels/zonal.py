@@ -9,7 +9,12 @@ pixel folds nowhere" (nodata, tile pad, or no containing zone):
   bit-identity contract on CPU (x64): XLA's CPU scatter applies updates
   sequentially in row order, so an f64 fold here is bit-identical to a
   sequential numpy accumulation in the same pixel order — which is
-  exactly what the host oracle in `raster/zonal.py` computes.
+  exactly what the host oracle in `raster/zonal.py` computes. Narrow
+  integer values (an int16 scene) fold at their own width instead:
+  :func:`fold_lane` names the int32 lane wherever one tile's sum is
+  exact there, a dense compare-and-reduce with no scatter and no f64
+  (the chip emulates f64: 13.0 ms a 65,536-pixel tile against 0.044);
+  exact integers, so the same numbers in any order.
 - :func:`zonal_tiled` — the Pallas TPU lane (f32, like every Mosaic
   kernel: no f64 path on the MXU/VPU). Grid is (segment blocks, pixel
   blocks) with pixels innermost, so each (1, TILE_S) accumulator block
@@ -38,7 +43,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .pip import TilingError
 
-__all__ = ["zonal_fold", "zonal_fold_masked", "zonal_tiled", "TilingError"]
+__all__ = [
+    "DENSE_LANES_MAX", "fold_lane", "zonal_fold", "zonal_fold_masked",
+    "zonal_tiled", "TilingError",
+]
 
 #: inert fill for min/max lanes — far beyond any geographic or sensor
 #: value, well inside f32 range (same constant family as kernels/pip.py).
@@ -52,21 +60,84 @@ _I0 = np.int32(0)  # index-map literal: python 0 traces as i64 under x64
 # ------------------------------------------------------------ jnp lane
 
 
+#: the int32 lane is a dense compare-and-reduce over segments x pixels
+#: lanes: 0.044 ms for 256 x 65,536 on a v5e (2.6 ps a lane), where four
+#: int32 scatters take 2.29 ms and the wide lane's emulated-f64 scatters
+#: 13.0 ms at any segment count (chip run, PR 28; PERF.md §6). Its cost
+#: grows with the segment count, so past this many lanes (2.8 ms) a fold
+#: stays on the wide lane.
+DENSE_LANES_MAX = 1 << 30
+
+
+def fold_lane(values_dtype, pixels: int, num_segments: int) -> str:
+    """Which lane one :func:`zonal_fold` sums in, from what the caller
+    hands in and nothing else (the storage dtype, the tile's pixel
+    count, the segment count), so the host can stage for it and a
+    compiled program never reads a knob:
+
+    - ``"int32"`` where the values are integers narrower than 32 bits,
+      ``pixels`` times the dtype's largest magnitude cannot leave int32
+      (int8, uint8 and int16 at any tile of up to 65,536 pixels; uint16
+      up to 32,768) — the sum is exact there — and ``num_segments`` x
+      ``pixels`` is at most :data:`DENSE_LANES_MAX`;
+    - ``"wide"`` otherwise: the ``acc_dtype`` accumulator.
+    """
+    dt = np.dtype(values_dtype)
+    if dt.kind not in "iu" or dt.itemsize >= 4:
+        return "wide"
+    info, i32 = np.iinfo(dt), np.iinfo(np.int32)
+    n = int(pixels)
+    if (
+        n * int(info.min) >= i32.min
+        and n * int(info.max) <= i32.max
+        and n * int(num_segments) <= DENSE_LANES_MAX
+    ):
+        return "int32"
+    return "wide"
+
+
 def zonal_fold(values, seg, num_segments: int, *, acc_dtype=None):
     """((S,) i32 count, (S,) sum, (S,) min, (S,) max) of ``values``
-    grouped by ``seg`` (-1 folds nowhere). Empty segments report
-    count 0, sum 0, min +inf, max -inf — callers mask on count.
+    grouped by ``seg`` (-1 folds nowhere).
 
-    ``acc_dtype`` picks the accumulator (default: the value dtype; the
-    zonal frontends stage f64 under x64 for the oracle contract).
+    ``min`` and ``max`` select a value and round nothing, so they
+    reduce in the dtype of ``values`` as handed in, whatever
+    ``acc_dtype`` says, and come back in it. ``sum`` takes the lane
+    :func:`fold_lane` names: int32 for narrow integers (exact: the
+    number the wide lane gives, bit for bit once cast), else
+    ``acc_dtype`` (default: the value dtype; the zonal frontends stage
+    f64 under x64 for the oracle contract). A caller that hands f64
+    values gets the f64 scatter program.
+
+    Empty segments report count 0, sum 0 and, in min / max, the inert
+    fill of the value dtype: +inf / -inf for floats, the dtype's
+    largest / smallest value for integers — callers mask on count.
     """
     values = jnp.asarray(values).reshape(-1)
     seg = jnp.asarray(seg, jnp.int32).reshape(-1)
-    dt = jnp.dtype(acc_dtype) if acc_dtype is not None else values.dtype
+    vdt = values.dtype
+    k = int(num_segments)
+    if jnp.issubdtype(vdt, jnp.integer):
+        hi, lo = (np.array(v, vdt) for v in
+                  (jnp.iinfo(vdt).max, jnp.iinfo(vdt).min))
+    else:
+        hi, lo = jnp.inf, -jnp.inf
+    if fold_lane(vdt, values.shape[0], k) == "int32":
+        # every (segment, pixel) lane compared and reduced on the VPU,
+        # which has no lanes narrower than 32 bits: widen once. XLA
+        # fuses the four reductions; nothing (S, P) is materialised
+        hit = seg[None, :] == jnp.arange(k, dtype=jnp.int32)[:, None]
+        v = values.astype(jnp.int32)[None, :]
+        cnt = jnp.sum(hit, axis=1, dtype=jnp.int32)
+        s = jnp.sum(jnp.where(hit, v, np.int32(0)), axis=1, dtype=jnp.int32)
+        mn = jnp.min(jnp.where(hit, v, hi.astype(np.int32)), axis=1)
+        mx = jnp.max(jnp.where(hit, v, lo.astype(np.int32)), axis=1)
+        return cnt, s, mn.astype(vdt), mx.astype(vdt)
+    dt = jnp.dtype(acc_dtype) if acc_dtype is not None else vdt
     av = values.astype(dt)
-    ns = int(num_segments) + 1  # one overflow bucket for seg == -1
+    ns = k + 1  # one overflow bucket for seg == -1
     valid = seg >= 0
-    segc = jnp.where(valid, seg, np.int32(num_segments))
+    segc = jnp.where(valid, seg, np.int32(k))
     zero = jnp.zeros((), dt)
     cnt = jax.ops.segment_sum(
         valid.astype(jnp.int32), segc, num_segments=ns
@@ -75,12 +146,11 @@ def zonal_fold(values, seg, num_segments: int, *, acc_dtype=None):
         jnp.where(valid, av, zero), segc, num_segments=ns
     )
     mn = jax.ops.segment_min(
-        jnp.where(valid, av, jnp.inf), segc, num_segments=ns
+        jnp.where(valid, values, hi), segc, num_segments=ns
     )
     mx = jax.ops.segment_max(
-        jnp.where(valid, av, -jnp.inf), segc, num_segments=ns
+        jnp.where(valid, values, lo), segc, num_segments=ns
     )
-    k = int(num_segments)
     return cnt[:k], s[:k], mn[:k], mx[:k]
 
 

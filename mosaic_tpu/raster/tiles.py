@@ -147,7 +147,15 @@ def stack_tiles(
     """Stage one band as ((T, TH, TW) ``dtype`` values, (T, TH, TW) bool
     mask). Mask True = in-bounds AND not nodata (NaN nodata handled like
     :attr:`RasterBand.mask`); pad pixels carry value 0 and mask False,
-    so they are inert in every downstream fold."""
+    so they are inert in every downstream fold.
+
+    ``dtype`` is the caller's fold's: f64 for the grid fold, the
+    expression compiler and every zones fold on the wide lane; the
+    raster's own dtype where `ZonalEngine.fold_staging` found the int32
+    lane (an int16 tile is then put as 131 KB, not 524 KB, and nothing
+    is converted here). Handing a dtype the band's values do not fit
+    is the caller's error: numpy casts as ``ndarray.__setitem__``
+    does."""
     th, tw = plan.shape
     b = raster.band(band)
     t0 = time.perf_counter()

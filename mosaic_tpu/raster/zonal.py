@@ -19,7 +19,15 @@ bounded-shape device pipelines over the tile plan of `raster/tiles.py`:
 
 Fold contract (the bit-identity spine, pinned by tests): per-tile
 partials are computed with an f64 accumulator (under x64) in row-major
-pixel order, then merged in row-major TILE order with a left fold. The
+pixel order, then merged in row-major TILE order with a left fold —
+or, for a narrow integer raster (int8 / uint8 / int16, uint16 on small
+tiles), at the pixels' own width: `kernels.zonal.fold_lane` says from
+the storage dtype, the tile's pixel count and the zone count alone
+when one tile's sum is exact in int32; the zones fold then stages the
+raster's own dtype (a quarter of the f64 put), folds count, sum, min
+and max in int32 lanes and hands the host the same numbers, bit for
+bit, that the f64 fold would (an int32 partial enters the host's f64
+tile merge exactly; min and max select, they round nothing). The
 host oracles (:func:`host_zonal_grid_oracle`,
 :func:`host_zonal_zones_oracle`) mirror exactly that decomposition in
 pure numpy f64 — per-tile sequential accumulation, then the same
@@ -44,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..dispatch import core as _dispatch
-from ..kernels.zonal import zonal_fold, zonal_tiled
+from ..kernels.zonal import fold_lane, zonal_fold, zonal_tiled
 from ..obs import stages as _stages, trace as _trace
 from ..runtime import faults as _faults, telemetry as _telemetry
 from ..runtime.errors import CapacityOverflow
@@ -267,14 +275,26 @@ class ZonalEngine:
 
             self._zones_fold = jax.jit(zones_fold)
 
-    def _register_stages(self, fn, args, rows: int) -> None:
+    def _register_stages(self, fn, args, rows: int, dtype=None) -> None:
         """Tell `obs.stages` how to lower one of the tile's two programs
-        again (shapes only, once a program and tile shape; no lowering
-        here), so that a device trace can name its ops by stage."""
-        key = (id(fn), rows)
+        again (shapes only, once a program, tile shape and value dtype;
+        no lowering here), so that a device trace can name its ops by
+        stage."""
+        key = (id(fn), rows, dtype)
         if key not in self._stages_seen:
             self._stages_seen.add(key)
             _stages.register(fn, _stages.shapes_of(args), rows=rows)
+
+    def fold_staging(self, raster, band: int, plan) -> tuple:
+        """(dtype the zones fold stages ``band``'s tiles in, the fold
+        lane's name) — the raster's own dtype where
+        `kernels.zonal.fold_lane` says a tile sums exactly in int32,
+        else f64 and ``"wide"``. Decided here, on the host, from the
+        storage dtype, the tile shape and the zone count."""
+        dt = raster.band(band).values.dtype
+        th, tw = plan.shape
+        lane = fold_lane(dt, th * tw, self.num_zones)
+        return (dt if lane == "int32" else np.dtype(np.float64)), lane
 
     def _tile_zone_rows(
         self, plan, t: int, maskb=None, tally: "dict | None" = None,
@@ -345,7 +365,8 @@ class ZonalEngine:
         with _trace.span("raster.fold", tile=t):
             seg = np.where(maskb & (geom >= 0), geom, -1).astype(np.int32)
             self._register_stages(
-                self._zones_fold, (vals_flat, seg), int(seg.shape[0])
+                self._zones_fold, (vals_flat, seg), int(seg.shape[0]),
+                str(vals_flat.dtype),
             )
             return self._zones_fold(
                 jnp.asarray(vals_flat), jnp.asarray(seg)
@@ -442,10 +463,11 @@ class ZonalEngine:
                 "folds need the vector side"
             )
         plan = plan_tiles(raster, tile)
-        vals, mask = stack_tiles(
-            raster, plan, band,
-            dtype=np.float64 if self.lane == "fold" else np.float32,
-        )
+        if self.lane == "fold":
+            stage_dt, fold = self.fold_staging(raster, band, plan)
+        else:  # the Pallas lane's own f32 accumulators
+            stage_dt, fold = np.dtype(np.float32), None
+        vals, mask = stack_tiles(raster, plan, band, dtype=stage_dt)
         g = self.num_zones
         acc_np = np.float64 if self.lane == "fold" else np.float32
         cnt_acc = np.zeros(g, np.int64)
@@ -455,7 +477,8 @@ class ZonalEngine:
         t0 = time.perf_counter()
         with _trace.span(
             "raster.zonal", mode="zones", ntiles=plan.ntiles,
-            zones=g, band=band, lane=self.lane,
+            zones=g, band=band, lane=self.lane, fold_lane=fold,
+            values_dtype=stage_dt.name,
         ):
             for t in range(plan.ntiles):
                 _faults.maybe_fail("raster.zonal")
@@ -475,6 +498,7 @@ class ZonalEngine:
             "raster_stage", stage="zonal",
             seconds=round(seconds, 6), mode="zones",
             ntiles=plan.ntiles, zones=g, lane=self.lane,
+            fold_lane=fold, values_dtype=stage_dt.name,
             pixels=plan.pixels,
             pixels_per_sec=round(plan.pixels / max(seconds, 1e-9), 1),
         )

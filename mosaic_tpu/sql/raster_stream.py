@@ -99,9 +99,11 @@ class RasterStream:
             defaults={"probe": "adaptive", "lookup": "gather"},
         )
         probe, lookup = knobs["probe"], knobs["lookup"]
-        # the stream always folds on the f64-capable jnp lane — the
-        # durable contract is bit-identity through kill/resume, and the
-        # f32 Pallas lane only holds it on exact-summable data
+        # the stream always folds on the exact jnp lane (f64, or int32
+        # where `fold_lane` finds a narrow integer raster's tile sums
+        # exact) — the durable contract is bit-identity through
+        # kill/resume, and the f32 Pallas lane only holds it on
+        # exact-summable data
         _tiles, zonal = _zonal()
         self.engine = zonal.ZonalEngine(
             index_system, resolution, chip_index=chip_index,
@@ -266,8 +268,9 @@ class RasterStream:
         eng = self.engine
         expr_sha = None
         if expr is None:
+            stage_dt, fold_lane = eng.fold_staging(raster, band, plan)
             vals, mask = tiles.stack_tiles(
-                raster, plan, band, dtype=np.float64
+                raster, plan, band, dtype=stage_dt
             )
         else:
             # fused expression scan: stage the whole referenced band
@@ -297,6 +300,8 @@ class RasterStream:
                 eng.index_system, eng.resolution, eng.mesh,
             )
             band = 0  # snapshot meta: fused scans read the stack
+            # the fused program computes in f64 whatever the bands hold
+            stage_dt, fold_lane = np.dtype(np.float64), "wide"
         if acc0 is None:
             cnt_acc = np.zeros(g, np.int64)
             sum_acc = np.zeros(g, np.float64)
@@ -362,7 +367,8 @@ class RasterStream:
                     )
 
             with _trace.span(
-                "raster.zonal", step=t, n=1, pipelined=True
+                "raster.zonal", step=t, n=1, pipelined=True,
+                fold_lane=fold_lane, values_dtype=stage_dt.name,
             ):
                 try:
                     return ("dev", _dispatch.guarded_call(
@@ -482,6 +488,7 @@ class RasterStream:
             pixels=plan.pixels, valid_pixels=int(np.count_nonzero(mask)),
             patched_pixels=tally["patched_pixels"],
             degraded_tiles=degraded_tiles, window=pstats.window,
+            fold_lane=fold_lane, values_dtype=stage_dt.name,
         )
         live = cnt_acc > 0
         stats = zonal.ZonalResult(

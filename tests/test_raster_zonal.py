@@ -17,7 +17,7 @@ from mosaic_tpu.core.geometry import wkt
 from mosaic_tpu.core.index import CustomIndexSystem, GridConf
 from mosaic_tpu.core.tessellate import tessellate
 from mosaic_tpu.kernels.pip import TilingError
-from mosaic_tpu.kernels.zonal import zonal_fold, zonal_tiled
+from mosaic_tpu.kernels.zonal import fold_lane, zonal_fold, zonal_tiled
 from mosaic_tpu.raster import Raster
 from mosaic_tpu.raster.zonal import (
     ZonalEngine,
@@ -110,6 +110,93 @@ def test_zonal_fold_matches_sequential_numpy():
     live = want_c > 0
     np.testing.assert_array_equal(mn[live], want_mn[live])
     np.testing.assert_array_equal(mx[live], want_mx[live])
+
+
+FOLD_DTYPES = (
+    np.int8, np.uint8, np.int16, np.uint16, np.int32, np.float32,
+    np.float64,
+)
+FOLD_SEGMENTS = 256  # the raster cell's zone count
+
+
+def _dtype_limits(dt):
+    dt = np.dtype(dt)
+    info = np.iinfo(dt) if dt.kind in "iu" else np.finfo(dt)
+    return info.min, info.max
+
+
+def _fold_case(dt, pixels, content, rng):
+    lo, hi = _dtype_limits(dt)
+    seg = rng.integers(-1, FOLD_SEGMENTS, pixels).astype(np.int32)
+    if np.dtype(dt).kind in "iu":
+        vals = rng.integers(lo, int(hi) + 1, pixels).astype(dt)
+    else:
+        vals = rng.uniform(-50, 50, pixels).astype(dt)
+    if content == "all_min":
+        vals, seg = np.full(pixels, lo, dt), np.full(pixels, 5, np.int32)
+    elif content == "all_max":
+        vals, seg = np.full(pixels, hi, dt), np.full(pixels, 5, np.int32)
+    elif content == "all_invalid":
+        seg = np.full(pixels, -1, np.int32)
+    return vals, seg
+
+
+@pytest.mark.parametrize(
+    "content", ["random", "all_min", "all_max", "all_invalid"])
+@pytest.mark.parametrize("pixels", [4096, 65536])
+@pytest.mark.parametrize("dt", FOLD_DTYPES, ids=lambda d: np.dtype(d).name)
+def test_zonal_fold_exact_at_every_value_dtype(dt, pixels, content):
+    """The zones fold's call (f64 accumulator offered) on every storage
+    dtype: after the cast `RasterStream`'s ``land`` does, the four
+    statistics are the sequential int64 / f64 numpy fold's, bit for bit
+    — on the int32 lane and on the wide one, at the dtype's extremes and
+    with one segment taking a whole 65,536-pixel tile."""
+    vals, seg = _fold_case(dt, pixels, content, np.random.default_rng(3))
+    with np.errstate(over="ignore"):  # 65,536 x f64's largest is inf
+        cnt, s, mn, mx = zonal_fold(
+            vals, seg, FOLD_SEGMENTS, acc_dtype=jnp.float64)
+        assert mn.dtype == mx.dtype == np.dtype(dt)  # the values' own
+        lane = fold_lane(dt, pixels, FOLD_SEGMENTS)
+        assert s.dtype == (np.int32 if lane == "int32" else np.float64)
+        cnt = np.asarray(cnt, np.int64)
+        s, mn, mx = (np.asarray(a, np.float64) for a in (s, mn, mx))
+        ok = seg >= 0
+        want_c = np.zeros(FOLD_SEGMENTS, np.int64)
+        np.add.at(want_c, seg[ok], 1)
+        acc = np.int64 if np.dtype(dt).kind in "iu" else np.float64
+        want_s = np.zeros(FOLD_SEGMENTS, acc)
+        np.add.at(want_s, seg[ok], vals[ok].astype(acc))  # in pixel order
+        want_mn = np.full(FOLD_SEGMENTS, np.inf)
+        want_mx = np.full(FOLD_SEGMENTS, -np.inf)
+        np.minimum.at(want_mn, seg[ok], vals[ok].astype(np.float64))
+        np.maximum.at(want_mx, seg[ok], vals[ok].astype(np.float64))
+    live = want_c > 0
+    assert live.sum() == {"all_invalid": 0, "random": FOLD_SEGMENTS}.get(
+        content, 1)
+    np.testing.assert_array_equal(cnt, want_c)
+    np.testing.assert_array_equal(s, want_s.astype(np.float64))
+    np.testing.assert_array_equal(mn[live], want_mn[live])
+    np.testing.assert_array_equal(mx[live], want_mx[live])
+
+
+@pytest.mark.parametrize("dt, pixels, segments, lane", [
+    (np.int16, 65536, 256, "int32"),
+    (np.int16, 512 * 512, 256, "wide"),    # 2**18 x 2**15 leaves int32
+    (np.int8, 65536, 256, "int32"),
+    (np.uint8, 65536, 256, "int32"),
+    (np.uint16, 32768, 256, "int32"),
+    (np.uint16, 65536, 256, "wide"),       # 65,536 x 65,535 > 2**31 - 1
+    (np.int16, 65536, 1 << 20, "wide"),    # past the dense form's lanes
+    (np.int32, 1, 1, "wide"), (np.int32, 65536, 256, "wide"),
+    (np.int64, 1024, 4, "wide"), (np.uint32, 1024, 4, "wide"),
+    (np.float32, 1, 1, "wide"), (np.float32, 65536, 256, "wide"),
+    (np.float64, 65536, 256, "wide"), (np.bool_, 1024, 4, "wide"),
+])
+def test_fold_lane_rule(dt, pixels, segments, lane):
+    """The lane is a table over (storage dtype, tile pixels, segments):
+    int32 exactly where a narrow integer tile's sum cannot leave it."""
+    assert fold_lane(dt, pixels, segments) == lane
+    assert fold_lane(np.dtype(dt).name, pixels, segments) == lane
 
 
 def test_zonal_tiled_matches_fold_on_exact_summable():
@@ -462,3 +549,90 @@ def test_scan_equals_plain_numpy_zonal_statistics(stream, seed):
         assert got.sum[i] == v.sum() and got.sum.dtype == np.float64
         assert (got.min[i], got.max[i]) == (v.min(), v.max())
     assert got.pixels == int(keep.sum())
+
+
+# ------------------------------------------- the fold at the pixels' width
+
+
+def _mk_int16_raster(seed=31, h=75, w=90, nodata=32767):
+    """The fixture's geometry with int16 pixels over the WHOLE dtype
+    (negative too; 32767 is nodata), 12% of them nodata."""
+    rng = np.random.default_rng(seed)
+    data = rng.integers(-32768, 32767, (1, h, w)).astype(np.int16)
+    data[0][rng.random((h, w)) < 0.12] = nodata
+    return Raster(
+        data=data, gt=(-0.5, 1.0, 0.0, 15.5, 0.0, -1.0), srid=0,
+        nodata=nodata,
+    )
+
+
+def _as_float64(r):
+    return Raster(
+        data=r.data.astype(np.float64), gt=r.gt, srid=r.srid,
+        nodata=float(r.nodata),
+    )
+
+
+def _scan_event(events):
+    (e,) = [e for e in events if e["event"] == "raster_scan"]
+    return e
+
+
+def test_int16_scan_is_the_float64_scan_bit_for_bit(stream, index, tmp_path):
+    """An int16 raster folds on the int32 lane, the same pixels as f64 on
+    the wide one: `RasterStream.scan`, `ZonalEngine.zones`, the host
+    oracle and a resume from a mid-scan snapshot agree field for field."""
+    r16 = _mk_int16_raster()
+    r64 = _as_float64(r16)
+    want = host_zonal_zones_oracle(r16, index, CUSTOM, RES, tile=(32, 32))
+    with telemetry.capture() as ev16:
+        got16 = stream.scan(r16, tile=(32, 32))
+    with telemetry.capture() as ev64:
+        got64 = stream.scan(r64, tile=(32, 32))
+    e16, e64 = _scan_event(ev16), _scan_event(ev64)
+    assert (e16["fold_lane"], e16["values_dtype"]) == ("int32", "int16")
+    assert (e64["fold_lane"], e64["values_dtype"]) == ("wide", "float64")
+    tiles16 = [e for e in ev16 if e["event"] == "span"
+               and e["name"] == "raster.zonal"]
+    assert len(tiles16) == 9 and all(
+        (t["fold_lane"], t["values_dtype"]) == ("int32", "int16")
+        for t in tiles16)
+    for got in (got16.stats, got64.stats):
+        _assert_result_equal(got, want)
+        assert got.sum.dtype == got.min.dtype == np.float64
+    assert got16.stats.min.min() < 0  # negatives reached the fold
+    eng = ZonalEngine(CUSTOM, RES, chip_index=index)
+    with telemetry.capture() as evz:
+        _assert_result_equal(eng.zones(r16, tile=(32, 32)), want)
+        _assert_result_equal(eng.zones(r64, tile=(32, 32)), want)
+    assert [e["fold_lane"] for e in evz if e["event"] == "raster_stage"
+            and e.get("stage") == "zonal"] == ["int32", "wide"]
+    # killed after 4 tiles, resumed from the f64 snapshot
+    d = str(tmp_path / "kill")
+    with faults.inject(
+        fail_first=99, skip_first=4, sites=("raster.zonal",),
+        exc_factory=lambda s: RuntimeError(f"simulated device loss @ {s}"),
+    ):
+        with pytest.raises(RuntimeError, match="simulated device loss"):
+            stream.scan(r16, tile=(32, 32), run_dir=d, snapshot_every=2,
+                        retry_policy=FAST)
+    _step, arrays, _meta = checkpoint.load_latest(d)
+    assert {k: v.dtype.name for k, v in arrays.items()} == {
+        "count": "int64", "sum": "float64", "min": "float64",
+        "max": "float64"}
+    resumed = stream.resume(d, r16, retry_policy=FAST)
+    assert resumed.metrics["resumed_from"] == 4
+    _assert_result_equal(resumed.stats, want)
+
+
+def test_int16_scan_degraded_tile_still_matches(stream, index):
+    """The host twin answers tile 0 of an int16 scan from the int16
+    staging (it casts to f64 itself): the fold is still the oracle's."""
+    r16 = _mk_int16_raster(seed=37)
+    want = host_zonal_zones_oracle(r16, index, CUSTOM, RES, tile=(32, 32))
+    with telemetry.capture() as ev:
+        with faults.transient_errors(3, sites=("raster.zonal",)):
+            r = stream.scan(r16, tile=(32, 32), retry_policy=FAST)
+    assert r.metrics["degraded_tiles"] == 1
+    assert _scan_event(ev)["fold_lane"] == "int32"
+    _assert_result_equal(r.stats, want)

@@ -90,6 +90,22 @@ def test_pallas_zonal_kernel_lowers_for_tpu(segments):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("dt, scatters", [(np.int16, 0), (np.float64, 4)])
+def test_zonal_fold_lane_in_the_tpu_lowering(dt, scatters):
+    """A full int16 tile into 256 zones lowers to the int32 lane: no
+    scatter and no f64 anywhere in the program (the chip emulates f64);
+    the same call on f64 values is the four-scatter program."""
+    from mosaic_tpu.kernels.zonal import zonal_fold
+
+    def f(vals, seg):
+        return zonal_fold(vals, seg, 256, acc_dtype=jnp.float64)
+
+    hlo = _tpu_lower(jax.jit(f).trace(
+        jnp.zeros(65536, dt), jnp.zeros(65536, jnp.int32)))
+    assert hlo.count('"stablehlo.scatter"') == scatters
+    assert ("f64" in hlo) == (scatters > 0)
+
+
 def test_mxu_row_lookup_split_survives_for_tpu():
     """`_mm_rows` splits f32 into three bf16 terms. Written as an
     f32 -> bf16 -> f32 convert pair the split is "excess precision" the
