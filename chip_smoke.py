@@ -300,8 +300,8 @@ def phase_stream(dep: dict, size: dict, seed: int) -> dict:
     # one slot against the f64 oracle. Tolerance 1e-3 of rows: StreamJoin
     # assigns cells in f32 (its default ``cell_dtype``), which moves ~0.1%
     # of points into a neighbouring cell; the answer changes only when the
-    # point also sits at a zone boundary (bench.py's floor for the same
-    # effect is 0.998; a CPU count on this index gave 0.9998)
+    # point also sits at a zone boundary (a CPU count on this index gave
+    # 0.9998)
     ns = min(size["slot_check_rows"], batch)
     slot_pts = np.asarray(ring[0][:ns])
     slot_truth = host_join(slot_pts, index.host, h3, res)
@@ -448,7 +448,7 @@ def phase_serve(dep: dict, size: dict, seed: int) -> dict:
               f"store-warmed engine compiled {store_compiles} programs")
     rep = dict(
         rungs=rungs, requests=size["requests"], rows=rows,
-        threads=size["threads"], lookup=m["lookup"], batches=m["batches"],
+        threads=size["threads"], batches=m["batches"],
         occupancy=m["occupancy_mean"], degraded=m["degraded"],
         real_row_share=round(m["batched_rows"] / m["padded_rows"], 4),
         linger_closed_by={
@@ -592,7 +592,7 @@ def _mesh_replicated(dep: dict, pts, want, n: int):
     padded, rows = core.ladder.pad(pts[:top])
     fcap, hcap, ccap = core.caps(padded.shape[0])
     prog = dispatch.sharded_join_prog(
-        core.mesh, writeback=core.writeback, lookup=core.lookup,
+        core.mesh, writeback=core.writeback,
         probe=core.probe, found_cap=fcap, heavy_cap=hcap, convex_cap=ccap,
     )
     cells = dispatch.cells_prog(h3, res, "cells")(jnp.asarray(padded))

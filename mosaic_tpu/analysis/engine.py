@@ -13,9 +13,7 @@ from .registry import Rule, all_rules
 from .suppress import parse_suppressions
 
 #: what the repo lints, relative to the root (same set as the seed gate)
-DEFAULT_TARGETS = (
-    "mosaic_tpu", "tests", "tools", "bench.py", "__graft_entry__.py",
-)
+DEFAULT_TARGETS = ("mosaic_tpu", "tests", "tools", "__graft_entry__.py")
 
 
 @dataclasses.dataclass

@@ -32,7 +32,7 @@ REGISTRY_NOTE = (
 )
 
 #: library + tool code carries registered names; tests exercise them
-SCAN_TARGETS = ("mosaic_tpu", "tools", "bench.py")
+SCAN_TARGETS = ("mosaic_tpu", "tools")
 
 #: call tails whose first literal argument is a fault/watchdog site.
 #: `guarded_call` / `execute_resilient` are the dispatch core's guarded

@@ -110,7 +110,7 @@ def mesh_key(mesh) -> "tuple | None":
 
 
 def dispatch_signature(
-    bucket: int, index, *, writeback: str, lookup: str,
+    bucket: int, index, *, writeback: str,
     found_cap: int | None, heavy_cap: int | None,
     probe: str = "scatter", convex_cap: int | None = None,
     mesh=None,
@@ -122,7 +122,7 @@ def dispatch_signature(
     core asserts the signature set stops growing after
     :meth:`DispatchCore.warmup`."""
     return (
-        int(bucket), id(index), writeback, lookup, found_cap, heavy_cap,
+        int(bucket), id(index), writeback, found_cap, heavy_cap,
         probe, convex_cap, mesh_key(mesh),
     )
 

@@ -256,8 +256,7 @@ def jit_join():
     return jax.jit(
         m.pip_join_points,
         static_argnames=(
-            "heavy_cap", "found_cap", "writeback", "lookup", "compaction",
-            "compact_block", "probe", "convex_cap",
+            "heavy_cap", "found_cap", "writeback", "probe", "convex_cap",
         ),
     )
 
@@ -323,8 +322,6 @@ def stream_programs(
     cell_dtype,
     found_cap,
     heavy_cap,
-    lookup,
-    compaction,
     probe,
     convex_cap,
     prefetch,
@@ -339,9 +336,9 @@ def stream_programs(
 
     return m.build_stream_programs(
         index_system, resolution, dtype=dtype, cell_dtype=cell_dtype,
-        found_cap=found_cap, heavy_cap=heavy_cap, lookup=lookup,
-        compaction=compaction, probe=probe, convex_cap=convex_cap,
-        prefetch=prefetch, donate_ring=donate_ring, mesh=mesh,
+        found_cap=found_cap, heavy_cap=heavy_cap, probe=probe,
+        convex_cap=convex_cap, prefetch=prefetch, donate_ring=donate_ring,
+        mesh=mesh,
     )
 
 
@@ -425,7 +422,6 @@ def sharded_join_prog(
     mesh: Mesh,
     *,
     writeback: str,
-    lookup: str,
     probe: str,
     found_cap,
     heavy_cap,
@@ -441,8 +437,7 @@ def sharded_join_prog(
         return m.pip_join_points(
             shifted, cells, index,
             heavy_cap=heavy_cap, found_cap=found_cap,
-            writeback=writeback, lookup=lookup,
-            probe=probe, convex_cap=convex_cap,
+            writeback=writeback, probe=probe, convex_cap=convex_cap,
         )
 
     return jax.jit(sharded_pointwise(
@@ -472,7 +467,6 @@ class DispatchCore:
         *,
         ladder: BucketLadder | None = None,
         writeback: str = "scatter",
-        lookup: str | None = None,
         probe: str = "scatter",
         cell_dtype=None,
         mesh=None,
@@ -499,7 +493,6 @@ class DispatchCore:
                 f"over the {self.mesh.size}-device mesh"
             )
         dtype = index.border.verts.dtype
-        self.lookup = _join_mod().resolve_lookup(lookup, index)
         self._dtype = dtype
         host = getattr(index, "host", None)
         self._host = host
@@ -560,9 +553,8 @@ class DispatchCore:
     def signature(self, bucket: int) -> tuple:
         fcap, hcap, ccap = self.caps(bucket)
         return dispatch_signature(
-            bucket, self.index, writeback=self.writeback,
-            lookup=self.lookup, found_cap=fcap, heavy_cap=hcap,
-            probe=self.probe, convex_cap=ccap, mesh=self.mesh,
+            bucket, self.index, writeback=self.writeback, found_cap=fcap,
+            heavy_cap=hcap, probe=self.probe, convex_cap=ccap, mesh=self.mesh,
         )
 
     def freeze(self) -> None:
@@ -823,14 +815,13 @@ class DispatchCore:
     def _join_statics(self, fcap, hcap, ccap) -> dict:
         return dict(
             heavy_cap=hcap, found_cap=fcap, writeback=self.writeback,
-            lookup=self.lookup, probe=self.probe, convex_cap=ccap,
+            probe=self.probe, convex_cap=ccap,
         )
 
     def _sharded_prog(self, fcap, hcap, ccap):
         return sharded_join_prog(
-            self.mesh, writeback=self.writeback, lookup=self.lookup,
-            probe=self.probe, found_cap=fcap, heavy_cap=hcap,
-            convex_cap=ccap,
+            self.mesh, writeback=self.writeback, probe=self.probe,
+            found_cap=fcap, heavy_cap=hcap, convex_cap=ccap,
         )
 
     def execute(self, points) -> np.ndarray:
@@ -988,7 +979,6 @@ def core_for(
     *,
     ladder: BucketLadder | None = None,
     writeback: str = "scatter",
-    lookup: str | None = None,
     probe: str = "scatter",
     cell_dtype=None,
     mesh=None,
@@ -1002,14 +992,14 @@ def core_for(
     mesh = resolve_mesh(mesh)
     key = (
         id(index), id(index_system), index_system.resolution_arg(resolution),
-        writeback, lookup, probe, str(cell_dtype), mesh_key(mesh),
+        writeback, probe, str(cell_dtype), mesh_key(mesh),
         ladder or BucketLadder(),
     )
     core = _BATCH_CORES.get(key)
     if core is None or core.index is not index:
         core = DispatchCore(
             index, index_system, resolution, ladder=ladder,
-            writeback=writeback, lookup=lookup, probe=probe,
+            writeback=writeback, probe=probe,
             cell_dtype=cell_dtype, mesh=mesh,
         )
         _BATCH_CORES.put(key, core)

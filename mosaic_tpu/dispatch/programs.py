@@ -324,7 +324,7 @@ def core_program_statics(core, bucket: int, kind: str) -> dict:
     }
     if kind == "join":
         statics.update(
-            writeback=core.writeback, lookup=core.lookup, probe=core.probe,
+            writeback=core.writeback, probe=core.probe,
             found_cap=fcap, heavy_cap=hcap, convex_cap=ccap,
         )
     return statics

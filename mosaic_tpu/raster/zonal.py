@@ -146,8 +146,6 @@ class ZonalEngine:
         chip_index=None,
         found_cap: "int | None" = None,
         heavy_cap: "int | None" = None,
-        lookup: "str | None" = None,
-        compaction: str = "scatter",
         probe: "str | None" = None,
         convex_cap: "int | None" = None,
         lane: "str | None" = None,
@@ -165,14 +163,12 @@ class ZonalEngine:
         knobs = _tune_resolve.resolve_knobs(
             "zonal_engine", profile,
             explicit={
-                "probe": probe, "lookup": lookup,
+                "probe": probe,
                 "zonal_lane": None if lane in (None, "auto") else lane,
             },
-            defaults={
-                "probe": "adaptive", "lookup": "gather", "zonal_lane": "fold",
-            },
+            defaults={"probe": "adaptive", "zonal_lane": "fold"},
         )
-        probe, lookup = knobs["probe"], knobs["lookup"]
+        probe = knobs["probe"]
         self.lane = resolve_zonal_lane(knobs["zonal_lane"])
         # placement resolves host-side once (dispatch core discipline):
         # with a mesh bound, the PIP probe runs data-parallel over the
@@ -237,7 +233,6 @@ class ZonalEngine:
                     shifted, cells, index,
                     heavy_cap=heavy_cap, found_cap=found_cap,
                     edge_eps2=eps2,
-                    lookup=lookup, compaction=compaction,
                     probe=probe, convex_cap=convex_cap,
                 )
                 if eps2 is None:

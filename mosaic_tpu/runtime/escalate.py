@@ -1,7 +1,7 @@
 """Capacity-escalation engine: bounded geometric cap growth to exactness.
 
 The join paths bound their stream-compaction shapes with static caps
-(``found_cap``/``heavy_cap``/``compact_block`` — `sql/join.py`); rows past
+(``found_cap``/``heavy_cap``/``convex_cap`` — `sql/join.py`); rows past
 a cap come back as the :data:`~mosaic_tpu.sql.join.OVERFLOW` sentinel
 instead of a wrong answer. This module owns the ONE policy that turns
 that sentinel into an exact answer: re-run with every involved cap grown

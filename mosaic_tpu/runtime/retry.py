@@ -1,7 +1,7 @@
 """Bounded transient-failure retry with backoff, jitter, and degradation.
 
-Generalizes the salvage logic the bench grew organically (probe backoff
-loop, agreement-lane HTTP 500 catch — `bench.py`): one policy object,
+Generalizes the salvage logic the first bench grew organically (probe
+backoff loop, agreement-lane HTTP 500 catch): one policy object,
 one functional wrapper, one decorator. On budget exhaustion the wrapper
 either raises :class:`RetryExhausted` or — when the caller supplies a
 ``fallback`` (typically the f64 host oracle) — returns the fallback's

@@ -106,7 +106,6 @@ class ServeEngine:
         bounds: tuple | None = None,
         park_point: np.ndarray | None = None,
         writeback: str | None = None,
-        lookup: str | None = None,
         cell_dtype=None,
         watchdog_grace_s: float = 0.5,
         probe: str | None = None,
@@ -125,19 +124,17 @@ class ServeEngine:
         knobs = resolve_knobs(
             "serve_engine", profile,
             explicit={
-                "probe": probe, "writeback": writeback, "lookup": lookup,
+                "probe": probe, "writeback": writeback,
                 "bucket_min": None, "bucket_max": None,
                 "knn_lane": knn_lane,
             },
             defaults={
-                "probe": "scatter", "writeback": "scatter", "lookup": None,
+                "probe": "scatter", "writeback": "scatter",
                 "bucket_min": None, "bucket_max": None,
                 "knn_lane": None,
             },
         )
-        probe, writeback, lookup = (
-            knobs["probe"], knobs["writeback"], knobs["lookup"]
-        )
+        probe, writeback = knobs["probe"], knobs["writeback"]
         if ladder is None and (knobs["bucket_min"] or knobs["bucket_max"]):
             ladder = BucketLadder(
                 min_bucket=int(knobs["bucket_min"] or 64),
@@ -151,7 +148,7 @@ class ServeEngine:
         # only guards the rebind and the dispatch-side snapshot of the
         # pair, never the dispatch itself
         self._swap_lock = threading.Lock()
-        # the core owns probe/lookup resolution (force-lane env folds
+        # the core owns probe resolution (force-lane env folds
         # once, so the compile-cache signature stays honest), caps,
         # signature accounting, the guarded execute path, and (when a
         # store is bound — explicit arg or MOSAIC_PROGRAM_STORE) the
@@ -160,13 +157,12 @@ class ServeEngine:
         self.program_store = resolve_program_store(program_store)
         self.core = DispatchCore(
             index, index_system, resolution, ladder=self.ladder,
-            writeback=writeback, lookup=lookup, probe=probe,
+            writeback=writeback, probe=probe,
             cell_dtype=cell_dtype, mesh=mesh,
             on_cold_compile=self._on_cold_compile,
             program_store=self.program_store,
         )
         self.probe = self.core.probe
-        self.lookup = self.core.lookup
         self.mesh = self.core.mesh
         # optional KNN frontend riding the same queue/batcher: a
         # KNNIndex builds a fresh frontend sharing the engine's mesh,
@@ -318,7 +314,6 @@ class ServeEngine:
         resolution: int | None = None,
         probe: str | None = None,
         writeback: str | None = None,
-        lookup: str | None = None,
         ladder: BucketLadder | None = None,
         knn=None,
         knn_lane: str | None = None,
@@ -342,13 +337,12 @@ class ServeEngine:
             "serve_engine.hot_swap", profile,
             explicit={
                 "resolution": resolution,
-                "probe": probe, "writeback": writeback, "lookup": lookup,
+                "probe": probe, "writeback": writeback,
                 "bucket_min": None, "bucket_max": None,
             },
             defaults={
                 "resolution": self.resolution,
                 "probe": self.core.probe, "writeback": self.writeback,
-                "lookup": self.core.lookup,
                 "bucket_min": None, "bucket_max": None,
             },
         )
@@ -367,7 +361,7 @@ class ServeEngine:
         ), _telemetry.timed("serve_stage", stage="hot_swap"):
             core = DispatchCore(
                 index, self.index_system, new_resolution, ladder=ladder,
-                writeback=knobs["writeback"], lookup=knobs["lookup"],
+                writeback=knobs["writeback"],
                 probe=knobs["probe"], cell_dtype=self.cell_dtype,
                 mesh=self.mesh, on_cold_compile=self._on_cold_compile,
                 program_store=self.program_store,
@@ -390,7 +384,6 @@ class ServeEngine:
                 self.knn = new_knn
                 self.writeback = knobs["writeback"]
                 self.probe = core.probe
-                self.lookup = core.lookup
                 # keep the coalescing window inside the new ladder's span
                 self.batcher.max_batch_rows = min(
                     self.batcher.max_batch_rows, ladder.max_bucket
@@ -408,7 +401,6 @@ class ServeEngine:
         out["shed"] = a["shed_queue_full"] + b["shed_deadline"]
         out["quarantined"] = a["quarantined_rows"]
         out["queue_depth"] = self.admission.depth()
-        out["lookup"] = self.core.lookup
         out["compile_signatures"] = len(self.core.signatures)
         out["cold_compiles"] = self.core.cold_compiles
         out["dispatches"] = self._dispatches

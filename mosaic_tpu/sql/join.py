@@ -35,7 +35,6 @@ static shapes) and only that compacted subset pays the wide heavy gather.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import os
 import time
 
@@ -81,7 +80,7 @@ OVERFLOW = -2
 #: tier-1 chunk rows where the gathered-row work runs under `lax.map`
 #: (`_map_rows`), which bounds its intermediates (direct mode's
 #: un-compacted (N, E1, 4) edges crossed XLA's 2 GB buffer limit at 4M;
-#: see `pip_join_points` for the gather lane). A multiple of 128; tests
+#: see `pip_join_points` for the compacted rows). A multiple of 128; tests
 #: shrink it to run the chunked paths small
 _TIER1_CHUNK = 1 << 20
 
@@ -1000,215 +999,12 @@ def _compact(flag: jax.Array, cap: int):
     return src, valid, flag & (pos >= cap), pos
 
 
-@jax.named_scope("pip.compact")
-def _compact_mxu(
-    flag: jax.Array,
-    cap: int,
-    s_cap: int = 256,
-    vals: jax.Array | None = None,
-):
-    """Two-level stream compaction: block-local one-hot int8 matmuls on
-    the MXU, then ONE small unique scatter.
-
-    The single global scatter in :func:`_compact` costs ~5 ns per SOURCE
-    row on v5e (21.5 ms at 4M — the largest op in the traced join step).
-    Here each 2048-row block compacts locally: an (R, C, S) int8 one-hot
-    of the block-local prefix positions contracts against the local row
-    ids split into two 6-bit factors (exact in int8), yielding每 block's
-    first ``s_cap`` flagged row ids; a block's s-th element owns global
-    slot ``rowoff[r] + s`` DIRECTLY, so the second level is a unique
-    no-combiner scatter of only R*S (~N/8) sources — no second prefix.
-
-    Same contract as :func:`_compact`. Additionally, rows flagged beyond
-    ``s_cap`` within one block are reported in the overflow mask (their
-    output slots stay invalid), so results are never silently wrong —
-    callers retry with a bigger ``s_cap`` exactly like a cap overflow.
-    ``s_cap`` must be a multiple of 128 (lane width).
-
-    ``vals`` (optional, (N,) int32 in [0, 2^24)) rides the SAME one-hot
-    through one extra batched int8 dot (four 6-bit factors, exact) and
-    comes back compacted as a fifth output — cheaper than gathering
-    ``vals[src]`` afterwards (the (cap,) gather costs ~4.7 ms at 640k on
-    v5e; the extra dot re-reads the already-resident one-hot).
-    """
-    n = flag.shape[0]
-    C = 2048
-    pad = (-n) % C
-    f = jnp.pad(flag, (0, pad)).reshape(-1, C)  # (R, C)
-    R = f.shape[0]
-    fi = f.astype(jnp.float32)
-    tri = (
-        jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-        <= jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    ).astype(jnp.float32)
-    incl = jax.lax.dot(
-        fi, tri, precision=jax.lax.Precision.HIGHEST
-    )  # exact: counts < 2^24
-    pos_local = (incl - fi).astype(jnp.int32)  # (R, C) block-local excl
-    cnt = incl[:, -1].astype(jnp.int32)  # (R,)
-    rowoff = jnp.cumsum(cnt) - cnt  # (R,) global exclusive offsets
-    pos = (pos_local + rowoff[:, None]).reshape(-1)[:n]
-
-    sidx = jnp.arange(s_cap, dtype=jnp.int32)
-    oh = (
-        (pos_local[..., None] == sidx[None, None, :]) & f[..., None]
-    ).astype(jnp.int8)  # (R, C, S) — 1 GB at 4M/2048/256
-    cloc = jnp.arange(C, dtype=jnp.int32)
-    qr = jnp.stack([cloc >> 6, cloc & 63], axis=1).astype(jnp.int8)
-    out = jax.lax.dot_general(
-        oh, qr, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )  # (R, S, 2) — exact integer accumulation
-    lc = out[..., 0] * 64 + out[..., 1]  # block-local row ids
-    src_b = lc + (jnp.arange(R, dtype=jnp.int32) * C)[:, None]
-
-    valid_b = sidx[None, :] < jnp.minimum(cnt, s_cap)[:, None]  # (R, S)
-    slot_b = rowoff[:, None] + sidx[None, :]  # global slot per (r, s)
-    rs = R * s_cap
-    # invalid slots start past n: valid slot_b values are <= n, so the
-    # two classes stay disjoint even when count exceeds cap (both then
-    # drop, but unique_indices must still hold globally)
-    dest2 = jnp.where(
-        valid_b,
-        slot_b,
-        cap + n + jnp.arange(rs, dtype=jnp.int32).reshape(R, -1),
-    ).reshape(-1)
-    src = (
-        jnp.zeros(cap, dtype=jnp.int32)
-        .at[dest2]
-        .set(src_b.reshape(-1), unique_indices=True, mode="drop")
-    )
-    valid = (
-        jnp.zeros(cap, dtype=bool)
-        .at[dest2]
-        .set(valid_b.reshape(-1), unique_indices=True, mode="drop")
-    )
-    over = flag & (pos >= cap)
-    blk_over = (cnt > s_cap)[:, None] & (pos_local >= s_cap)
-    over = over | (flag & blk_over.reshape(-1)[:n])
-    if vals is None:
-        return src, valid, over, pos
-    v = jnp.pad(vals.astype(jnp.int32), (0, pad)).reshape(-1, C)
-    v8 = jnp.stack(
-        [
-            v & 63,
-            (v >> 6) & 63,
-            (v >> 12) & 63,
-            (v >> 18) & 63,
-        ],
-        axis=-1,
-    ).astype(jnp.int8)  # (R, C, 4)
-    vout = jax.lax.dot_general(
-        oh, v8,
-        (((1,), (1,)), ((0,), (0,))),  # contract c, batch r
-        preferred_element_type=jnp.int32,
-    )  # (R, S, 4)
-    vloc = (
-        vout[..., 0]
-        + (vout[..., 1] << 6)
-        + (vout[..., 2] << 12)
-        + (vout[..., 3] << 18)
-    )
-    vals_c = (
-        jnp.zeros(cap, dtype=jnp.int32)
-        .at[dest2]
-        .set(vloc.reshape(-1), unique_indices=True, mode="drop")
-    )
-    return src, valid, over, pos, vals_c
-
-
-def _mm_rows(idx: jax.Array, table_f32: jax.Array) -> jax.Array:
-    """``table_f32[idx]`` as a one-hot MXU matmul — bit-exact f32 row
-    gather.
-
-    Data-dependent row gathers serialize on TPU (~10 GB/s effective on
-    the 512 B tier-1 edge rows, ~42 ms at a 640k-point cap); contracting
-    a (K, U) one-hot against the (U, D) row table runs on the MXU
-    instead. Cost: linear in K x U whatever is fetched — 5.19 ps a row
-    for every table row on v5e (703 ms for K = 4M, U = 33,898, D = 153,
-    at 90% of the MXU's peak: ledger, PR 24), where the two row gathers
-    of `_tier1_rows_gather` cost the same whatever U is — so
-    `resolve_lookup` no longer picks it (PERF.md section 6, PR 25).
-    Exactness: each one-hot row has a single 1, and any f32
-    value splits exactly into three bf16 terms (Sterbenz: the rounded
-    high part is within a factor 2 of the remainder, so each residual
-    subtraction is exact); each output element is therefore reassembled
-    from <= 3 exact partial products in a f32 accumulator — a bit-exact
-    gather, asserted against the real gather in tests.
-
-    The split rounds with `lax.reduce_precision`, never with an
-    f32 -> bf16 -> f32 convert pair: XLA may drop such a pair as "excess
-    precision" (``xla_allow_excess_precision``, on by default), which
-    turns every residual into zero and the lookup into a bf16-rounded
-    table. `reduce_precision` is the op the compiler must keep.
-
-    idx: (K,) int32 in [0, U); table_f32: (U, D) f32 -> (K, D) f32.
-    """
-    U = table_f32.shape[0]
-    oh = (
-        idx[:, None] == jnp.arange(U, dtype=idx.dtype)[None, :]
-    ).astype(jnp.bfloat16)
-
-    def head(x):  # nearest bf16-representable value, still in f32
-        return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
-
-    hi = head(table_f32)
-    r = table_f32 - hi
-    mid = head(r)
-    lo = head(r - mid)
-    dot = functools.partial(
-        jax.lax.dot_general,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    # each term is bf16-representable, so these narrowing casts are exact
-    return sum(dot(oh, t.astype(jnp.bfloat16)) for t in (hi, mid, lo))
-
-
-def _tier1_rows_mxu(us: jax.Array, index: "ChipIndex"):
-    """All tier-1 per-cell rows for slots ``us`` in ONE MXU lookup.
-
-    Packs cell_edges / cell_ebits (split into exact 16-bit halves) /
-    cell_slot_geom / cell_slot_core / cell_heavy into a single (U, D)
-    f32 matrix so the one-hot operand is built and contracted once.
-    Integer fields survive exactly: every value (parity-bit halves
-    <= 65535, geom/heavy ids < 2^24, bools) is an integer exactly
-    representable in f32. Returns (edges (K, E1, 4), ebits (K, E1) u32,
-    geoms (K, M1) i32, cores (K, M1) bool, heavy (K,) i32).
-    """
-    U, E1 = index.cell_ebits.shape
-    M1 = index.cell_slot_geom.shape[1]
-    eb = index.cell_ebits
-    tab = jnp.concatenate(
-        [
-            index.cell_edges.reshape(U, E1 * 4).astype(jnp.float32),
-            (eb >> jnp.uint32(16)).astype(jnp.float32),
-            (eb & jnp.uint32(0xFFFF)).astype(jnp.float32),
-            index.cell_slot_geom.astype(jnp.float32),
-            index.cell_slot_core.astype(jnp.float32),
-            index.cell_heavy.astype(jnp.float32)[:, None],
-        ],
-        axis=1,
-    )
-    out = _mm_rows(us, tab)
-    o = E1 * 4
-    edges = out[:, :o].reshape(-1, E1, 4)
-    hi16, lo16 = out[:, o : o + E1], out[:, o + E1 : o + 2 * E1]
-    o += 2 * E1
-    ebits = (hi16.astype(jnp.uint32) << jnp.uint32(16)) | lo16.astype(
-        jnp.uint32
-    )
-    geoms = out[:, o : o + M1].astype(jnp.int32)
-    cores = out[:, o + M1 : o + 2 * M1] > 0.5
-    heavy = out[:, o + 2 * M1].astype(jnp.int32)
-    return edges, ebits, geoms, cores, heavy
-
-
 def _tier1_rows_gather(us: jax.Array, index: "ChipIndex"):
     """All tier-1 per-cell rows for slots ``us`` in TWO row gathers: the
     edge row, and one int32 row of everything else. Exact by construction
     (rows are moved, never converted: ebits travel bit-cast, bools as
-    0/1), for any edge dtype. Same returns as :func:`_tier1_rows_mxu`.
+    0/1), for any edge dtype. Returns (edges (K, E1, 4), ebits (K, E1)
+    u32, geoms (K, M1) i32, cores (K, M1) bool, heavy (K,) i32).
 
     A gather is paid per gather, not per byte (traced on v5e, each of
     the four separate gathers cost 17-26 ms a 4M-row step and the 4-byte
@@ -1242,37 +1038,9 @@ def _tier1_rows_gather(us: jax.Array, index: "ChipIndex"):
     )
 
 
-def _heavy_rows_mxu(h2: jax.Array, index: "ChipIndex"):
-    """Heavy-table rows for slots ``h2`` via the one-hot MXU lookup —
-    same exactness argument as :func:`_tier1_rows_mxu` (the heavy one-hot
-    is tiny: (K2, H) with H typically < 128)."""
-    H, E2 = index.heavy_ebits.shape
-    M2 = index.heavy_slot_geom.shape[1]
-    eb = index.heavy_ebits
-    tab = jnp.concatenate(
-        [
-            index.heavy_edges.reshape(H, E2 * 4).astype(jnp.float32),
-            (eb >> jnp.uint32(16)).astype(jnp.float32),
-            (eb & jnp.uint32(0xFFFF)).astype(jnp.float32),
-            index.heavy_slot_geom.astype(jnp.float32),
-        ],
-        axis=1,
-    )
-    out = _mm_rows(h2, tab)
-    o = E2 * 4
-    edges = out[:, :o].reshape(-1, E2, 4)
-    hi16, lo16 = out[:, o : o + E2], out[:, o + E2 : o + 2 * E2]
-    ebits = (hi16.astype(jnp.uint32) << jnp.uint32(16)) | lo16.astype(
-        jnp.uint32
-    )
-    geoms = out[:, o + 2 * E2 : o + 2 * E2 + M2].astype(jnp.int32)
-    return edges, ebits, geoms
-
-
+@jax.named_scope("pip.tier2")
 def _heavy_tier(
-    px, py, hs, index, heavy_cap, k2_default, out_len, eps2,
-    lookup="gather", compaction="scatter", compact_block=256,
-    engine="gather",
+    px, py, hs, index, heavy_cap, k2_default, out_len, eps2, engine="gather",
 ):
     """Tier 2, shared by every probe plumbing mode: compact the rows whose
     cell is heavy, probe the wide rows, scatter back to ``out_len``.
@@ -1286,23 +1054,9 @@ def _heavy_tier(
 
     Returns (best2 (out_len,), over2 (out_len,) overflow mask,
     near2 (out_len,) | None when ``eps2`` is None)."""
-    with jax.named_scope("pip.tier2"):
-        return _heavy_tier_impl(
-            px, py, hs, index, heavy_cap, k2_default, out_len, eps2,
-            lookup, compaction, compact_block, engine,
-        )
-
-
-def _heavy_tier_impl(
-    px, py, hs, index, heavy_cap, k2_default, out_len, eps2,
-    lookup, compaction, compact_block, engine,
-):
     K2 = int(heavy_cap) if heavy_cap else k2_default
     K2 = max(8, min(K2, k2_default))
-    if compaction == "mxu" and hs.shape[0] >= (1 << 16):
-        src2, valid2, over2, _ = _compact_mxu(hs >= 0, K2, compact_block)
-    else:
-        src2, valid2, over2, _ = _compact(hs >= 0, K2)
+    src2, valid2, over2, _ = _compact(hs >= 0, K2)
     h2 = jnp.maximum(hs[src2], 0)
     # one (K2, 2) gather, not two serialized column gathers (see tier 1)
     pq2 = jnp.stack([px, py], axis=1)[src2]
@@ -1318,11 +1072,8 @@ def _heavy_tier_impl(
         if near2 is None and eps2 is not None:  # pragma: no cover
             near2 = jnp.zeros(pq2.shape[0], bool)
     else:
-        if lookup == "mxu":
-            hedges, hebits, hgeoms = _heavy_rows_mxu(h2, index)
-        else:
-            hedges, hebits = index.heavy_edges[h2], index.heavy_ebits[h2]
-            hgeoms = index.heavy_slot_geom[h2]
+        hedges, hebits = index.heavy_edges[h2], index.heavy_ebits[h2]
+        hgeoms = index.heavy_slot_geom[h2]
         r2 = _ray_parity(pq2[:, 0], pq2[:, 1], hedges, hebits, eps2=eps2)
         par2, near2 = r2 if eps2 is not None else (r2, None)
         best2k = _slot_best(par2, hgeoms)  # invalid slots never land (drop)
@@ -1405,45 +1156,6 @@ def _map_rows(fn, chunk: int, *cols):
     )
 
 
-#: tier-1 row fetch lanes (`pip_join_points` ``lookup=``)
-_LOOKUPS = ("gather", "mxu", "mxu2")
-
-
-def resolve_lookup(
-    lookup: "str | None", index: ChipIndex, *, source: str = "explicit"
-) -> str:
-    """The tier-1 row-fetch lane for ``index`` — the ONE place the auto
-    rule lives (`pip_join`, `StreamJoin` and `DispatchCore` all call it).
-
-    A ``lookup`` the caller resolved already (explicit argument,
-    ``MOSAIC_TUNE_LOOKUP`` or a `TuningProfile`, in `tune/resolve.py`'s
-    order; ``source`` says which) passes through. ``None`` is auto, and
-    auto is ``gather``, on every platform and for every index. Both lanes
-    are exact fetches of the same row; what separates them is cost, read
-    on the v5e at 4M rows a step (PERF.md section 6, PR 25): the one-hot
-    costs 5.19 ps a row for every CELL of the index (703 ms at 33,898
-    cells) on top of a fixed 180-206 ms step, and the two-gather fetch
-    makes a 179-181 ms step at 1,427 cells and at 8,145 alike, 204 at
-    33,898 — the one-hot lost at every size tried, so no cell count is
-    left at which the rule would pick it. ``mxu`` / ``mxu2`` stay
-    selectable until ROADMAP D1 prunes them.
-
-    Records one ``join_lookup`` telemetry event (``lookup``, ``cells``,
-    ``source`` = explicit | env | profile | auto).
-    """
-    if lookup is None:
-        lookup, source = "gather", "auto"
-    elif lookup not in _LOOKUPS:
-        raise ValueError(
-            f"lookup must be one of {_LOOKUPS}, got {lookup!r}"
-        )
-    _telemetry.record(
-        "join_lookup", lookup=lookup, cells=int(index.cell_edges.shape[0]),
-        source=source,
-    )
-    return lookup
-
-
 def pip_join_points(
     points: jax.Array,
     pcells: jax.Array,
@@ -1452,9 +1164,6 @@ def pip_join_points(
     found_cap: int | None = None,
     edge_eps2: jax.Array | None = None,
     writeback: str = "scatter",
-    lookup: str = "gather",
-    compaction: str = "scatter",
-    compact_block: int = 256,
     probe: str = "scatter",
     convex_cap: int | None = None,
 ) -> jax.Array:
@@ -1479,20 +1188,11 @@ def pip_join_points(
     sqrt(edge_eps2) of any probed chip edge — the set whose f32 parity may
     disagree with f64 (`pip_join` rechecks them on the host oracle).
 
-    ``compaction="mxu"`` (with ``compact_block``) switches stream
-    compaction to block-local one-hot int8 matmuls (`_compact_mxu`):
-    identical results while no 2048-point block holds more than
-    ``compact_block`` found points; beyond that the affected points
-    return :data:`OVERFLOW` (never a wrong answer) — size
-    ``compact_block`` to ~6 sigma above the expected per-block found
-    count (256 covers found rates up to ~9%).
-
-    ``writeback`` picks the probe plumbing — identical results, a TPU
-    autotuning knob the bench measures and picks the winner of:
+    ``writeback`` picks the probe plumbing — identical results:
     ``"scatter"`` compacts found points then returns results via a
     unique-destination set scatter; ``"gather"`` compacts but inverts by
-    per-point gather of
-    the prefix slot; ``"direct"`` skips tier-1 compaction entirely —
+    per-point gather of the prefix slot; ``"direct"`` skips tier-1
+    compaction entirely —
     every point gathers its own 512 B edge row (wasted gathers on misses,
     but no prefix scan, no point permutation and no writeback, which cost
     ~65 ms combined at 4M on v5e while the full row-gather runs ~30 ms;
@@ -1517,17 +1217,6 @@ def pip_join_points(
         raise ValueError(
             f"writeback must be scatter|gather|direct, got {writeback!r}"
         )
-    if lookup not in _LOOKUPS:
-        raise ValueError(f"lookup must be gather|mxu|mxu2, got {lookup!r}")
-    if compaction not in ("scatter", "mxu"):
-        raise ValueError(
-            f"compaction must be scatter|mxu, got {compaction!r}"
-        )
-    if compact_block % 128:
-        raise ValueError(
-            f"compact_block must be a multiple of 128 (TPU lane width), "
-            f"got {compact_block}"
-        )
     # validate only — no env fold here: this function is jit-traced
     # (`dispatch.jit_join` keys its compile cache on the UNRESOLVED
     # `probe` static arg), so reading MOSAIC_PROBE_FORCE_LANE at this point
@@ -1544,12 +1233,6 @@ def pip_join_points(
             "probe='adaptive' routes through compaction; it composes "
             "with writeback scatter|gather, not direct"
         )
-    if lookup != "gather" and (
-        writeback == "direct" or index.cell_edges.dtype != jnp.float32
-    ):
-        # direct mode probes ALL N points (a (N, U) one-hot would not
-        # fit), and the 3-term bf16 split is exact only for f32 tables
-        lookup = "gather"
     N = points.shape[0]
     # named scopes mark the probe stages: every instruction of the join
     # sits under exactly one innermost pip.* scope, which
@@ -1590,14 +1273,9 @@ def pip_join_points(
     def _tier1(px_c, py_c, us_c):
         """Tier 1 for rows already matched to cell slots ``us_c``: fetch
         the cell's row, test the crossings, pick the slot. Row-wise."""
-        if lookup in ("mxu", "mxu2"):
-            edges1, ebits1, geoms1, cores1, heavy1 = _tier1_rows_mxu(
-                us_c, index
-            )
-        else:
-            edges1, ebits1, geoms1, cores1, heavy1 = _tier1_rows_gather(
-                us_c, index
-            )
+        edges1, ebits1, geoms1, cores1, heavy1 = _tier1_rows_gather(
+            us_c, index
+        )
         r1 = _ray_parity(px_c, py_c, edges1, ebits1, eps2=edge_eps2)
         parity, near1 = r1 if banded else (r1, None)
         return (
@@ -1606,7 +1284,7 @@ def pip_join_points(
         )
 
     def _tier1_rows(px_c, py_c, us_c):
-        if lookup == "gather" and us_c.shape[0] > _TIER1_CHUNK:
+        if us_c.shape[0] > _TIER1_CHUNK:
             # rows are independent, so chunks are exact; they bound the
             # fetched rows' footprint (128-lane padded): a 4M-row stream
             # loop holds 3.9 GB of temporaries in 1M-row chunks against
@@ -1645,13 +1323,7 @@ def pip_join_points(
         light = found if conv is None else (found & ~conv)
         K1 = int(found_cap) if found_cap else N
         K1 = max(8, min(K1, N))
-        if compaction == "mxu" and N >= (1 << 16):
-            # (the vals channel could also carry u through the one-hot, but
-            # the extra batched dot re-reads the 1 GB one-hot and measured
-            # SLOWER than the (K1,) gather below: 87.0 vs 84.2 ms/iter)
-            src1, valid1, over1, pos1 = _compact_mxu(light, K1, compact_block)
-        else:
-            src1, valid1, over1, pos1 = _compact(light, K1)
+        src1, valid1, over1, pos1 = _compact(light, K1)
         us = jnp.maximum(u[src1], 0)  # (K1,)
         # ONE (K1, 2) row gather: indexing the columns separately makes XLA
         # emit two serialized point gathers (traced at ~14 ms EACH at 4M/640k)
@@ -1666,13 +1338,8 @@ def pip_join_points(
         with jax.named_scope("pip.tier2"):
             # tier 2: compact again to the points whose cell is heavy
             hs = jnp.where(valid1, heavy1, -1)
-            # measured on v5e/NYC: the MXU lookup wins tier 1 but not the
-            # 6 KB heavy rows (gathers get efficient at that row size), so
-            # "mxu" keeps tier 2 on the gather path and "mxu2" forces both
             best2, over2, near_sc = _heavy_tier(
                 px, py, hs, index, heavy_cap, K1, K1, edge_eps2,
-                lookup="mxu" if lookup == "mxu2" else "gather",
-                compaction=compaction, compact_block=compact_block,
                 engine=heavy_engine,
             )
             best1 = jnp.minimum(best1, best2)
@@ -1690,12 +1357,7 @@ def pip_join_points(
         K3 = int(convex_cap) if convex_cap else N
         K3 = max(8, min(K3, N))
         with jax.named_scope("pip.convex"):
-            if compaction == "mxu" and N >= (1 << 16):
-                src3, valid3, over3, pos3 = _compact_mxu(
-                    conv, K3, compact_block
-                )
-            else:
-                src3, valid3, over3, pos3 = _compact(conv, K3)
+            src3, valid3, over3, pos3 = _compact(conv, K3)
             cv3 = jnp.maximum(cvrow[src3], 0)
             pq3 = points[src3]
             px3, py3 = pq3[:, 0], pq3[:, 1]
@@ -1846,7 +1508,6 @@ def pip_join(
     recheck: bool | None = None,
     cell_dtype=None,
     writeback: "str | None" = None,
-    lookup: str | None = None,
     cell_margin_k: float | None = None,
     edge_band_k: float | None = None,
     probe: "str | None" = None,
@@ -1888,10 +1549,7 @@ def pip_join(
     reproduce TPU f32 behavior exactly.
 
     ``writeback`` selects the probe plumbing (``scatter``/``gather``/
-    ``direct`` — see :func:`pip_join_points`); results are identical,
-    the bench autotunes the winner per workload. ``lookup`` picks the
-    tier-1 row access (``gather``/``mxu`` one-hot matmul); default None
-    is auto, which :func:`resolve_lookup` decides (``gather``).
+    ``direct`` — see :func:`pip_join_points`); results are identical.
 
     ``cell_margin_k`` / ``edge_band_k`` override the calibrated band
     constants :data:`CELL_MARGIN_K` / :data:`EDGE_BAND_K` for this call —
@@ -1919,7 +1577,7 @@ def pip_join(
     ``profile`` takes a `tune.TuningProfile`; its knobs apply with the
     one documented precedence — explicit argument > env knob > profile >
     built-in default (`mosaic_tpu/tune/resolve.py`). Profile-consumed
-    knobs here: ``resolution``, ``probe``, ``writeback``, ``lookup``,
+    knobs here: ``resolution``, ``probe``, ``writeback``,
     ``batch_size`` (pass ``batch_size=0`` to explicitly force the
     unbatched path past a profile's recommendation).
     """
@@ -1931,17 +1589,14 @@ def pip_join(
         "pip_join", profile,
         explicit={
             "resolution": resolution, "probe": probe,
-            "writeback": writeback, "lookup": lookup,
-            "batch_size": batch_size,
+            "writeback": writeback, "batch_size": batch_size,
         },
         defaults={
             "resolution": None, "probe": "scatter", "writeback": "scatter",
-            "lookup": None, "batch_size": None,
+            "batch_size": None,
         },
     )
-    resolution, writeback, lookup = (
-        knobs["resolution"], knobs["writeback"], knobs["lookup"]
-    )
+    resolution, writeback = knobs["resolution"], knobs["writeback"]
     batch_size = knobs["batch_size"] or None  # 0 = explicitly unbatched
     if resolution is None:
         raise ValueError(
@@ -1983,16 +1638,13 @@ def pip_join(
         else np.asarray(chip_index.border.shift, dtype=np.float64)
     )
     dtype = chip_index.border.verts.dtype
-    lookup = resolve_lookup(
-        lookup, chip_index, source=knobs.sources["lookup"]
-    )
     n = raw.shape[0]
     core = (
         None
         if mesh is None
         else _dispatch.core_for(
             chip_index, index_system, resolution,
-            writeback=writeback, lookup=lookup, probe=probe,
+            writeback=writeback, probe=probe,
             cell_dtype=cell_dtype, mesh=mesh,
         )
     )
@@ -2086,8 +1738,7 @@ def pip_join(
                         shifted, cells, chip_index,
                         heavy_cap=c.get("heavy_cap", hcap),
                         found_cap=c.get("found_cap", fcap),
-                        writeback=writeback, lookup=lookup,
-                        probe=probe,
+                        writeback=writeback, probe=probe,
                         convex_cap=c.get("convex_cap", ccap),
                     )
                 )
@@ -2121,8 +1772,8 @@ def pip_join(
                 shifted, cells, chip_index,
                 heavy_cap=c.get("heavy_cap", hcap),
                 found_cap=c.get("found_cap", fcap), edge_eps2=eps2,
-                writeback=writeback, lookup=lookup,
-                probe=probe, convex_cap=c.get("convex_cap", ccap),
+                writeback=writeback, probe=probe,
+                convex_cap=c.get("convex_cap", ccap),
             )
             return np.array(o), np.array(nr)  # writable host copies
 
@@ -2149,11 +1800,10 @@ def pip_join(
                 # band-compacted narrow re-join: the epsilon band is
                 # compacted ONCE (the probe tiers' own `_compact`
                 # machinery) and a single re-join over just the compacted
-                # band — sized exactly from its own device-side counts,
-                # on the caller's tier-1 lookup path — resolves the
-                # runner-up cell. Only result TIES (plus cell corners and
-                # invalid alternates) escalate to the host oracle; the
-                # full point axis is never re-probed.
+                # band — sized exactly from its own device-side counts —
+                # resolves the runner-up cell. Only result TIES (plus cell
+                # corners and invalid alternates) escalate to the host
+                # oracle; the full point axis is never re-probed.
                 cap = min(_next_pow2(n_flag), chunk.shape[0])
                 src, _, _, _ = _dispatch.jit_compact()(flagged, cap=cap)
                 alt = _assign_cells(
@@ -2189,7 +1839,6 @@ def pip_join(
                         _dispatch.jit_join()(
                             shifted[src], alt, chip_index,
                             heavy_cap=hcap2, found_cap=fcap2,
-                            lookup=lookup,
                         )
                     )[:n_flag]
                     vertex = np.asarray(margins[src, 1])[:n_flag] < km

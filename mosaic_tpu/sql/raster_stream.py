@@ -82,8 +82,6 @@ class RasterStream:
         *,
         found_cap: "int | None" = None,
         heavy_cap: "int | None" = None,
-        lookup: "str | None" = None,
-        compaction: str = "scatter",
         probe: "str | None" = None,
         convex_cap: "int | None" = None,
         mesh=None,
@@ -95,10 +93,10 @@ class RasterStream:
         self._profile = profile
         knobs = _tune_resolve.resolve_knobs(
             "raster_stream", profile,
-            explicit={"probe": probe, "lookup": lookup},
-            defaults={"probe": "adaptive", "lookup": "gather"},
+            explicit={"probe": probe},
+            defaults={"probe": "adaptive"},
         )
-        probe, lookup = knobs["probe"], knobs["lookup"]
+        probe = knobs["probe"]
         # the stream always folds on the exact jnp lane (f64, or int32
         # where `fold_lane` finds a narrow integer raster's tile sums
         # exact) — the durable contract is bit-identity through
@@ -107,8 +105,8 @@ class RasterStream:
         _tiles, zonal = _zonal()
         self.engine = zonal.ZonalEngine(
             index_system, resolution, chip_index=chip_index,
-            found_cap=found_cap, heavy_cap=heavy_cap, lookup=lookup,
-            compaction=compaction, probe=probe, convex_cap=convex_cap,
+            found_cap=found_cap, heavy_cap=heavy_cap,
+            probe=probe, convex_cap=convex_cap,
             lane="fold", mesh=mesh,
         )
         self.chip_index = chip_index

@@ -89,7 +89,6 @@ from .join import (
     ChipIndex,
     host_join_with_cells,
     pip_join_points,
-    resolve_lookup,
     resolve_probe_mode,
 )
 
@@ -247,8 +246,6 @@ def build_stream_programs(
     cell_dtype,
     found_cap,
     heavy_cap,
-    lookup,
-    compaction,
     probe,
     convex_cap,
     prefetch,
@@ -283,8 +280,6 @@ def build_stream_programs(
             chip_index,
             heavy_cap=heavy_cap,
             found_cap=found_cap,
-            lookup=lookup,
-            compaction=compaction,
             probe=probe,
             convex_cap=convex_cap,
         )
@@ -419,6 +414,11 @@ class StreamJoin:
     declines donation and keeps the copy).
     """
 
+    # constants, read only by the benchmark's `stream_ready` line
+    # (benchmark/traffic_kinds/device_ring_stream.py); ROADMAP D15
+    lookup = "gather"
+    compaction = "scatter"
+
     def __init__(
         self,
         index: ChipIndex,
@@ -427,8 +427,6 @@ class StreamJoin:
         *,
         found_cap: int | None = None,
         heavy_cap: int | None = None,
-        lookup: str | None = None,
-        compaction: str | None = None,
         cell_dtype=jnp.float32,
         prefetch: bool = True,
         probe: "str | None" = None,
@@ -449,30 +447,13 @@ class StreamJoin:
         # arg > env knob > profile > built-in default (tune/resolve.py)
         knobs = _tune_resolve.resolve_knobs(
             "stream_join", profile,
-            explicit={"probe": probe, "lookup": lookup},
-            defaults={"probe": "scatter", "lookup": None},
+            explicit={"probe": probe},
+            defaults={"probe": "scatter"},
         )
-        probe, lookup = knobs["probe"], knobs["lookup"]
+        probe = knobs["probe"]
         #: (ring fingerprint, report) of the last admission, if any
         self._last_quarantine: tuple | None = None
         dtype = index.border.verts.dtype
-        platform = jax.devices()[0].platform
-        lookup = resolve_lookup(
-            lookup, index, source=knobs.sources["lookup"]
-        )
-        if compaction is None:
-            # the MXU block compaction keeps at most ``compact_block``
-            # found points per 2048-point block (found rates up to ~9%)
-            # and marks the rest OVERFLOW. A caller who passes no
-            # ``found_cap`` has claimed nothing about the found rate, and
-            # an uncapped join must be exact at any rate — on the chip a
-            # zone layer that covers its bounding box overflowed 72% of
-            # rows here — so only a capped stream takes the MXU lane.
-            compaction = (
-                "mxu" if platform != "cpu" and found_cap is not None
-                else "scatter"
-            )
-        self.lookup, self.compaction = lookup, compaction
         self.found_cap, self.heavy_cap = found_cap, heavy_cap
         # resolve the adaptive/force-lane and mesh knobs HERE, before
         # the values are closed over by the jitted scan (env changes
@@ -483,10 +464,9 @@ class StreamJoin:
 
         progs = _dispatch.stream_programs(
             index_system, resolution, dtype=dtype, cell_dtype=cell_dtype,
-            found_cap=found_cap, heavy_cap=heavy_cap, lookup=lookup,
-            compaction=compaction, probe=probe, convex_cap=convex_cap,
-            prefetch=self.prefetch, donate_ring=self.donate_ring,
-            mesh=self.mesh,
+            found_cap=found_cap, heavy_cap=heavy_cap, probe=probe,
+            convex_cap=convex_cap, prefetch=self.prefetch,
+            donate_ring=self.donate_ring, mesh=self.mesh,
         )
         self._programs = progs
         # eager twin for tiny host-side lookups (park-point search): a

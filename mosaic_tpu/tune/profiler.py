@@ -1,10 +1,10 @@
 """Workload profiling: sample a workload into a typed `WorkloadProfile`.
 
 The profile is the optimizer's input contract — the same statistics the
-bench ``detail`` blocks already collect (`bench.py caps_for` presample,
-`tools/raster_bench.py` occupancy), computed once on a capped host-side
-sample and recorded under a ``tune.profile`` span so profiling shows up in
-trails like any other stage:
+bench ``detail`` blocks already collect (`tools/stream_bench.py`
+presample, `tools/raster_bench.py` occupancy), computed once on a capped
+host-side sample and recorded under a ``tune.profile`` span so profiling
+shows up in trails like any other stage:
 
 - **match rate / class shares** — fraction of sampled points whose cell is
   in the index, split light/heavy/convex by the index's own density

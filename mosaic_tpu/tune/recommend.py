@@ -64,7 +64,6 @@ class TuningProfile:
     resolution: "int | None" = None
     probe: "str | None" = None
     writeback: "str | None" = None
-    lookup: "str | None" = None
     batch_size: "int | None" = None
     bucket_min: "int | None" = None
     bucket_max: "int | None" = None

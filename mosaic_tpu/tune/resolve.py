@@ -13,9 +13,8 @@ the single documented order (ARCHITECTURE "Workload optimizer"):
 Knobs that already had an env spelling keep it (``MOSAIC_STREAM_WINDOW``,
 ``MOSAIC_STREAM_PIPELINE``, ``MOSAIC_RASTER_TILE``, ``MOSAIC_RASTER_LANE``);
 tune-only knobs read the ``MOSAIC_TUNE_*`` family (``MOSAIC_TUNE_PROBE``,
-``MOSAIC_TUNE_WRITEBACK``, ``MOSAIC_TUNE_LOOKUP``, ``MOSAIC_TUNE_BATCH``,
-``MOSAIC_TUNE_BUCKET_MIN``, ``MOSAIC_TUNE_BUCKET_MAX``,
-``MOSAIC_TUNE_KNN_LANE``). ``resolution`` has
+``MOSAIC_TUNE_WRITEBACK``, ``MOSAIC_TUNE_BATCH``, ``MOSAIC_TUNE_BUCKET_MIN``,
+``MOSAIC_TUNE_BUCKET_MAX``, ``MOSAIC_TUNE_KNN_LANE``). ``resolution`` has
 deliberately NO env layer: it changes the tessellation artifact, not just
 the execution schedule, so it only flows explicitly or via a profile.
 
@@ -46,7 +45,6 @@ def _parse_tile(raw: str):
 _TUNE_ENV = {
     "probe": ("PROBE", str),
     "writeback": ("WRITEBACK", str),
-    "lookup": ("LOOKUP", str),
     "batch_size": ("BATCH", int),
     "bucket_min": ("BUCKET_MIN", int),
     "bucket_max": ("BUCKET_MAX", int),

@@ -135,56 +135,6 @@ def test_writeback_variants_identical():
     np.testing.assert_array_equal(a2[ok], d2[ok])
 
 
-def test_mxu_lookup_bit_exact():
-    """The one-hot MXU row lookup is an autotuning knob: `_mm_rows` must
-    be a bit-exact f32 gather (3-term bf16 split, single one-hot hit per
-    row), and the full join must be bitwise identical to the gather
-    lookup, bands included."""
-    import jax
-    import jax.numpy as jnp
-
-    from mosaic_tpu.core.index import H3
-    from mosaic_tpu.core.tessellate import tessellate
-    from mosaic_tpu.sql.join import _mm_rows, build_chip_index, pip_join_points
-
-    rng = np.random.default_rng(5)
-    # exponents spanning the f32 range stress the bf16 split exactness
-    tab = jnp.asarray(
-        (rng.standard_normal((90, 50))
-         * (10.0 ** rng.integers(-20, 20, (90, 50)))).astype(np.float32)
-    )
-    idx = jnp.asarray(rng.integers(0, 90, 2048).astype(np.int32))
-    got = np.asarray(jax.jit(_mm_rows)(idx, tab))
-    np.testing.assert_array_equal(got, np.asarray(tab)[np.asarray(idx)])
-
-    col = wkt.from_wkt(ZONES)
-    cidx = build_chip_index(tessellate(col, H3, 3, keep_core_geoms=False))
-    pts = np.column_stack(
-        [rng.uniform(-25, 35, 20000), rng.uniform(-25, 20, 20000)]
-    )
-    cells = H3.point_to_cell(jnp.asarray(pts, jnp.float32), 3)
-    shifted = jnp.asarray(
-        pts - np.asarray(cidx.border.shift, np.float64),
-        dtype=cidx.border.verts.dtype,
-    )
-    eps2 = jnp.asarray(1e-10, cidx.border.verts.dtype)
-    for wb in ("scatter", "gather"):
-        a, na = pip_join_points(
-            shifted, cells, cidx, edge_eps2=eps2, writeback=wb
-        )
-        for lk in ("mxu", "mxu2"):
-            m, nm = pip_join_points(
-                shifted, cells, cidx, edge_eps2=eps2, writeback=wb, lookup=lk
-            )
-            np.testing.assert_array_equal(
-                np.asarray(a), np.asarray(m), f"{wb}/{lk}"
-            )
-            np.testing.assert_array_equal(
-                np.asarray(na), np.asarray(nm), f"{wb}/{lk}"
-            )
-    assert (np.asarray(a) >= 0).any()
-
-
 @pytest.mark.parametrize("banded", [True, False])
 @pytest.mark.parametrize("writeback", ["direct", "scatter", "gather"])
 def test_tier1_chunked_path_identical(monkeypatch, writeback, banded):
@@ -228,80 +178,91 @@ def test_tier1_chunked_path_identical(monkeypatch, writeback, banded):
     assert (np.asarray(want[0]) >= 0).any()
 
 
-def test_mxu_compaction_identical():
-    """_compact_mxu (block one-hot int8 matmuls + one small unique
-    scatter) must match _compact exactly, including pos, validity and
-    both overflow kinds (global cap + block-local s_cap)."""
+@pytest.mark.parametrize("order", ["in-order", "random"])
+@pytest.mark.parametrize("heavy", [False, True])
+@pytest.mark.parametrize("edge_dtype", ["float32", "float64"])
+def test_tier1_rows_gather_is_plain_indexing(edge_dtype, heavy, order):
+    """The two-gather tier-1 fetch against numpy indexing of the five
+    tables, bit for bit: the component-major edge row comes back as
+    (K, E1, 4), ebits survive their int32 bit-cast (top bit included),
+    bools travel as 0/1, for either edge dtype."""
+    import dataclasses
+
     import jax
-    import jax.numpy as jnp
-
-    from mosaic_tpu.sql.join import _compact, _compact_mxu
-
-    rng = np.random.default_rng(0)
-    for n, p, cap, s_cap in [
-        (100000, 0.09, 16384, 256),
-        (70000, 0.5, 65536, 1280),
-        (2048, 1.0, 4096, 2048),
-    ]:
-        flag = jnp.asarray(rng.random(n) < p)
-        a = jax.jit(lambda f, cap=cap: _compact(f, cap))(flag)
-        m = jax.jit(
-            lambda f, cap=cap, s=s_cap: _compact_mxu(f, cap, s)
-        )(flag)
-        for x, y, name in zip(a, m, ("src", "valid", "over", "pos")):
-            np.testing.assert_array_equal(
-                np.asarray(x), np.asarray(y), f"{n}/{p}/{name}"
-            )
-        # the vals channel (4x6-bit int8 dots) must equal vals[src]
-        vals = jnp.asarray(
-            rng.integers(0, 1 << 24, n).astype(np.int32)
-        )
-        mv = jax.jit(
-            lambda f, v, cap=cap, s=s_cap: _compact_mxu(f, cap, s, vals=v)
-        )(flag, vals)
-        got_v = np.asarray(mv[4])
-        want_v = np.asarray(vals)[np.asarray(a[0])]
-        valid_np = np.asarray(a[1])
-        np.testing.assert_array_equal(
-            got_v[valid_np], want_v[valid_np], f"{n}/{p}/vals"
-        )
-    # clustered flags exceeding s_cap in one block: dropped rows must be
-    # flagged overflow (never a silently wrong/missing result)
-    flag = np.zeros(100000, bool)
-    flag[1000:1900] = True
-    fm = jnp.asarray(flag)
-    a = [np.asarray(x) for x in _compact(fm, 4096)]
-    m = [np.asarray(x) for x in _compact_mxu(fm, 4096, 256)]
-    np.testing.assert_array_equal(a[3], m[3])
-    np.testing.assert_array_equal(m[0][:256], a[0][:256])
-    assert m[2][1256:1900].all() and not m[2][:1256].any()
-    assert not m[1][256:900].any()
-
-
-def test_compaction_knob_end_to_end():
     import jax.numpy as jnp
 
     from mosaic_tpu.core.index import H3
     from mosaic_tpu.core.tessellate import tessellate
     from mosaic_tpu.sql import join as J
 
-    col = wkt.from_wkt(ZONES)
-    cidx = J.build_chip_index(tessellate(col, H3, 3, keep_core_geoms=False))
-    rng = np.random.default_rng(13)
-    n = 1 << 17  # above the mxu-compaction threshold
-    pts = np.column_stack(
-        [rng.uniform(-25, 35, n), rng.uniform(-25, 20, n)]
+    table = tessellate(wkt.from_wkt(ZONES), H3, 3, keep_core_geoms=False)
+    cidx = J.build_chip_index(table, **({"edge_cap": 8} if heavy else {}))
+    assert bool(cidx.num_heavy_cells) == heavy
+    rng = np.random.default_rng(17)
+    U = cidx.cell_edges.shape[0]
+    ebits = np.asarray(cidx.cell_ebits).copy()
+    ebits[:: 3] |= np.uint32(1 << 31)  # a bit-cast must keep the sign bit
+    cidx = dataclasses.replace(
+        cidx,
+        cell_edges=jnp.asarray(
+            rng.standard_normal(cidx.cell_edges.shape), edge_dtype
+        ),
+        cell_ebits=jnp.asarray(ebits),
     )
-    cells = H3.point_to_cell(jnp.asarray(pts, jnp.float32), 3)
-    shifted = jnp.asarray(
-        pts - np.asarray(cidx.border.shift, np.float64),
-        dtype=cidx.border.verts.dtype,
+    us = (
+        np.arange(U, dtype=np.int32) if order == "in-order"
+        else rng.integers(0, U, 3 * U).astype(np.int32)
     )
-    eps2 = jnp.asarray(1e-10, cidx.border.verts.dtype)
-    a, na = J.pip_join_points(shifted, cells, cidx, edge_eps2=eps2)
-    m, nm = J.pip_join_points(
-        shifted, cells, cidx, edge_eps2=eps2, lookup="mxu",
-        compaction="mxu", compact_block=1024,
+    got = jax.jit(J._tier1_rows_gather)(jnp.asarray(us), cidx)
+    want = (
+        np.asarray(cidx.cell_edges)[us], ebits[us],
+        np.asarray(cidx.cell_slot_geom)[us],
+        np.asarray(cidx.cell_slot_core)[us],
+        np.asarray(cidx.cell_heavy)[us],
     )
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(m))
-    np.testing.assert_array_equal(np.asarray(na), np.asarray(nm))
+    assert np.asarray(cidx.cell_slot_core).any()
+    assert (np.asarray(cidx.cell_heavy) >= 0).any() == heavy
+    for g, w, name in zip(
+        got, want, ("edges", "ebits", "geoms", "cores", "heavy")
+    ):
+        g = np.asarray(g)
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(g.view(np.uint8), w.view(np.uint8), name)
+
+
+@pytest.mark.parametrize(
+    "case, n, every, cap",
+    [
+        ("none-set", 1000, 0, 16),
+        ("all-set", 1024, 1, 1024),
+        ("cap-is-count", 5000, 3, 1667),
+        ("cap-one-under", 5000, 3, 1666),
+        ("not-a-multiple-of-128", 8191 + 4 * 2048, 3, 4096),
+    ],
+)
+def test_compact_against_flatnonzero(case, n, every, cap):
+    """`_compact` against `np.flatnonzero` (every ``every``-th row set):
+    ``src`` lists the first ``cap`` flagged rows in order, ``valid``
+    counts them, ``pos`` is the exclusive prefix and ``overflow`` names
+    exactly the flagged rows past the cap. The last case is long enough
+    for the matmul prefix scan."""
+    import jax
+    import jax.numpy as jnp
+
+    from mosaic_tpu.sql.join import _compact
+
+    flag = np.arange(n) % every == 0 if every else np.zeros(n, bool)
+    rows = np.flatnonzero(flag)
+    src, valid, over, pos = (
+        np.asarray(x)
+        for x in jax.jit(_compact, static_argnames="cap")(
+            jnp.asarray(flag), cap=cap
+        )
+    )
+    kept = min(rows.size, cap)
+    assert src.shape == valid.shape == (cap,)
+    np.testing.assert_array_equal(valid, np.arange(cap) < kept)
+    np.testing.assert_array_equal(src[:kept], rows[:kept])
+    assert not src[kept:].any()  # pad slots hold row 0, masked by valid
+    np.testing.assert_array_equal(pos, np.cumsum(flag) - flag)
+    np.testing.assert_array_equal(np.flatnonzero(over), rows[cap:])

@@ -293,8 +293,13 @@ def test_recheck_runs_one_narrow_compacted_rejoin():
 
 
 def test_recheck_narrow_respects_margin_override():
-    """cell_margin_k=0 disables the cell band entirely (no narrow event);
-    a wider band flags more points than the default."""
+    """cell_margin_k=0 narrows the cell band to the rows whose f32 margin
+    is NEGATIVE — the cube-rounding tie-fix picked the other centre
+    (`hex_round_margins`), so the row is maximally borderline and the
+    exact path must still re-join it: k = 0 clamps nothing away. A wider
+    band flags more rows than the default."""
+    from mosaic_tpu.sql.join import _assign_cells
+
     col = _nyc_zones()
     res = 9
     rng = np.random.default_rng(8)
@@ -303,26 +308,25 @@ def test_recheck_narrow_respects_margin_override():
          rng.uniform(40.68, 40.82, 8_000)]
     )
     idx = build_chip_index(tessellate(col, H3, res, keep_core_geoms=False))
-    with telemetry.capture() as ev0:
-        pip_join(
-            pts, None, H3, res, chip_index=idx, recheck=True,
-            cell_dtype=jnp.float32, cell_margin_k=0.0,
-        )
-    assert not [e for e in ev0 if e["event"] == "recheck_narrow"]
-    with telemetry.capture() as ev_def:
-        pip_join(
-            pts, None, H3, res, chip_index=idx, recheck=True,
-            cell_dtype=jnp.float32,
-        )
-    with telemetry.capture() as ev_wide:
-        pip_join(
-            pts, None, H3, res, chip_index=idx, recheck=True,
-            cell_dtype=jnp.float32, cell_margin_k=4 * CELL_MARGIN_K,
-        )
-    band_def = [e for e in ev_def if e["event"] == "recheck_narrow"]
-    band_wide = [e for e in ev_wide if e["event"] == "recheck_narrow"]
-    assert band_def and band_wide
-    assert band_wide[0]["band"] > band_def[0]["band"]
+    _, margins = _assign_cells(
+        H3, res, jnp.asarray(pts).astype(jnp.float32), "margin"
+    )
+    negative = int((np.asarray(margins)[:, 0] < 0).sum())
+    assert negative >= 1  # this seed holds one; k = 0 must keep it
+
+    def band(**kw):
+        with telemetry.capture() as ev:
+            pip_join(
+                pts, None, H3, res, chip_index=idx, recheck=True,
+                cell_dtype=jnp.float32, **kw,
+            )
+        return sum(e["band"] for e in ev if e["event"] == "recheck_narrow")
+
+    band_0 = band(cell_margin_k=0.0)
+    band_def = band()
+    band_wide = band(cell_margin_k=4 * CELL_MARGIN_K)
+    assert band_0 == negative
+    assert band_0 <= band_def < band_wide
 
 
 def test_pip_join_recheck_bng_no_alt_fallback():

@@ -297,20 +297,6 @@ def test_pip_join_points_still_reports_overflow_at_low_level(problem):
     assert (out == OVERFLOW).any()
 
 
-def test_compact_block_must_be_multiple_of_128(problem):
-    h3, zones, index, pts, clean = problem
-    cells = np.asarray(h3.point_to_cell(jnp.asarray(pts), RES))
-    shift = index.host.shift
-    dt = np.asarray(index.border.verts).dtype
-    with pytest.raises(
-        ValueError, match=r"compact_block must be a multiple of 128"
-    ):
-        pip_join_points(
-            jnp.asarray((pts - shift).astype(dt)), jnp.asarray(cells),
-            index, compaction="mxu", compact_block=200,
-        )
-
-
 # --------------------------------------------- overlay_join under faults
 
 

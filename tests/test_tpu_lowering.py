@@ -106,43 +106,24 @@ def test_zonal_fold_lane_in_the_tpu_lowering(dt, scatters):
     assert ("f64" in hlo) == (scatters > 0)
 
 
-def test_mxu_row_lookup_split_survives_for_tpu():
-    """`_mm_rows` splits f32 into three bf16 terms. Written as an
-    f32 -> bf16 -> f32 convert pair the split is "excess precision" the
-    TPU compiler may drop (it did: the lookup returned a bf16-rounded
-    table on the chip); `reduce_precision` is the op it must keep."""
-    from mosaic_tpu.sql.join import _mm_rows
-
-    idx = jnp.zeros(256, jnp.int32)
-    tab = jnp.zeros((512, 16), jnp.float32)
-    hlo = _tpu_lower(jax.jit(_mm_rows).trace(idx, tab))
-    assert hlo.count("reduce_precision") >= 3
-
-
-@pytest.mark.parametrize("given", [None, "mxu"])
-def test_lookup_lane_in_the_tpu_lowering(problem, given):
-    """The lane `resolve_lookup` names is the one that is lowered: auto
-    holds no (K, U) one-hot contraction on the TPU target, an explicit
-    ``mxu`` holds three `dot_general` (one per bf16 term of the split)."""
-    from mosaic_tpu.sql.join import resolve_lookup
-
+def test_tier1_fetch_in_the_tpu_lowering(problem):
+    """Tier 1 fetches a cell's row by gathers on the TPU target: the
+    edge row and the packed int32 row come from (U, .) operands, and no
+    (K, U) one-hot contraction is in the program."""
     h3, index, _ = problem
-    U = index.cell_edges.shape[0]
-    lane = resolve_lookup(given, index)
-    assert lane == (given or "gather")
+    U, E1 = index.cell_ebits.shape
+    M1 = index.cell_slot_geom.shape[1]
     K = 4096
     pts = jnp.asarray(random_points(K, bbox=BBOX, seed=3), jnp.float32)
     cells = h3.point_to_cell(pts, 7).astype(jnp.int64)
-    hlo = _tpu_lower(
-        jax.jit(functools.partial(pip_join_points, lookup=lane)).trace(
-            pts, cells, index
-        )
-    )
-    onehot = [
-        ln for ln in hlo.splitlines()
-        if "dot_general" in ln and f"tensor<{K}x{U}xbf16>" in ln
+    hlo = _tpu_lower(jax.jit(pip_join_points).trace(pts, cells, index))
+    lines = hlo.splitlines()
+    assert not [
+        ln for ln in lines if "dot_general" in ln and f"tensor<{K}x{U}x" in ln
     ]
-    assert len(onehot) == (3 if given else 0)
+    gathers = [ln for ln in lines if '"stablehlo.gather"' in ln]
+    for row in (f"tensor<{U}x{4 * E1}xf32>", f"tensor<{U}x{E1 + 2 * M1 + 1}xi32>"):
+        assert sum(f"({row}," in ln for ln in gathers) == 1, row
 
 
 def test_bench_step_lowers_for_tpu(problem):

@@ -29,12 +29,13 @@ import pytest
 from mosaic_tpu.core.geometry import wkt
 from mosaic_tpu.core.index import CustomIndexSystem, GridConf
 from mosaic_tpu.core.tessellate import tessellate
+from mosaic_tpu.dispatch import DispatchCore
 from mosaic_tpu.raster import Raster
 from mosaic_tpu.raster.zonal import ZonalEngine
 from mosaic_tpu.runtime import telemetry
 from mosaic_tpu.serve import BucketLadder, ServeEngine
 from mosaic_tpu.sql.analyzer import SampleStrategy
-from mosaic_tpu.sql.join import build_chip_index, pip_join
+from mosaic_tpu.sql.join import build_chip_index, pip_join, pip_join_points
 from mosaic_tpu.sql.overlay import candidate_pairs
 from mosaic_tpu.sql.raster_stream import RasterStream
 from mosaic_tpu.sql.stream import StreamJoin, ring_from_host
@@ -65,7 +66,7 @@ ZONES = [
 BBOX = (-25.0, -25.0, 35.0, 20.0)
 
 ALL_TUNE_ENV = (
-    "MOSAIC_TUNE_PROBE", "MOSAIC_TUNE_WRITEBACK", "MOSAIC_TUNE_LOOKUP",
+    "MOSAIC_TUNE_PROBE", "MOSAIC_TUNE_WRITEBACK",
     "MOSAIC_TUNE_BATCH", "MOSAIC_TUNE_BUCKET_MIN", "MOSAIC_TUNE_BUCKET_MAX",
     "MOSAIC_STREAM_WINDOW", "MOSAIC_STREAM_PIPELINE",
     "MOSAIC_RASTER_TILE", "MOSAIC_RASTER_LANE",
@@ -179,15 +180,14 @@ class TestResolveKnob:
         """The full matrix at the resolver: each knob accepts each layer."""
         profile_values = {
             "resolution": 5, "probe": "adaptive", "writeback": "sort",
-            "lookup": "gather", "batch_size": 2048, "bucket_min": 128,
-            "bucket_max": 1024, "stream_window": 6, "stream_pipeline": True,
+            "batch_size": 2048, "bucket_min": 128, "bucket_max": 1024,
+            "stream_window": 6, "stream_pipeline": True,
             "raster_tile": (64, 64), "zonal_lane": "tiled",
             "knn_lane": "voronoi",
         }
         env_values = {
             "probe": ("MOSAIC_TUNE_PROBE", "scatter", "scatter"),
             "writeback": ("MOSAIC_TUNE_WRITEBACK", "scatter", "scatter"),
-            "lookup": ("MOSAIC_TUNE_LOOKUP", "mxu", "mxu"),
             "batch_size": ("MOSAIC_TUNE_BATCH", "512", 512),
             "bucket_min": ("MOSAIC_TUNE_BUCKET_MIN", "64", 64),
             "bucket_max": ("MOSAIC_TUNE_BUCKET_MAX", "256", 256),
@@ -228,7 +228,7 @@ class TestResolveKnob:
 class TestPipJoinPrecedence:
     PROFILE = TuningProfile(
         resolution=RES, probe="adaptive", writeback="scatter",
-        lookup="gather", batch_size=1024,
+        batch_size=1024,
     )
 
     def run(self, points, index, **kw):
@@ -240,8 +240,7 @@ class TestPipJoinPrecedence:
 
     def test_profile_layer(self, points, index):
         out, ev = self.run(points, index, profile=self.PROFILE)
-        for knob in ("resolution", "probe", "writeback", "lookup",
-                     "batch_size"):
+        for knob in ("resolution", "probe", "writeback", "batch_size"):
             assert ev[f"{knob}_source"] == "profile", (knob, ev)
         assert ev["probe"] == "adaptive" and ev["batch_size"] == 1024
         base, _ = self.run(points, index, resolution=RES)
@@ -260,11 +259,10 @@ class TestPipJoinPrecedence:
         monkeypatch.setenv("MOSAIC_TUNE_PROBE", "adaptive")
         _, ev = self.run(
             points, index, resolution=RES, probe="scatter",
-            writeback="scatter", lookup="gather", batch_size=256,
+            writeback="scatter", batch_size=256,
             profile=self.PROFILE,
         )
-        for knob in ("resolution", "probe", "writeback", "lookup",
-                     "batch_size"):
+        for knob in ("resolution", "probe", "writeback", "batch_size"):
             assert ev[f"{knob}_source"] == "explicit", (knob, ev)
 
     def test_no_resolution_anywhere_is_typed(self, points, index):
@@ -274,12 +272,11 @@ class TestPipJoinPrecedence:
 
 class TestStreamJoinPrecedence:
     def test_constructor_knobs(self, index, monkeypatch):
-        prof = TuningProfile(probe="adaptive", lookup="gather")
+        prof = TuningProfile(probe="adaptive")
         with telemetry.capture() as events:
             StreamJoin(index, CUSTOM, RES, profile=prof)
         (ev,) = resolve_events(events, "stream_join")
         assert ev["probe_source"] == "profile"
-        assert ev["lookup_source"] == "profile"
 
         monkeypatch.setenv("MOSAIC_TUNE_PROBE", "scatter")
         with telemetry.capture() as events:
@@ -332,15 +329,14 @@ class TestStreamJoinPrecedence:
 class TestServeEnginePrecedence:
     def test_profile_builds_ladder(self, index):
         prof = TuningProfile(
-            probe="adaptive", writeback="scatter", lookup="gather",
+            probe="adaptive", writeback="scatter",
             bucket_min=64, bucket_max=256,
         )
         with telemetry.capture() as events:
             with ServeEngine(index, CUSTOM, RES, profile=prof) as eng:
                 assert eng.ladder.buckets == (64, 128, 256)
         (ev,) = resolve_events(events, "serve_engine")
-        for knob in ("probe", "writeback", "lookup", "bucket_min",
-                     "bucket_max"):
+        for knob in ("probe", "writeback", "bucket_min", "bucket_max"):
             assert ev[f"{knob}_source"] == "profile", (knob, ev)
 
     def test_env_beats_profile(self, index, monkeypatch):
@@ -378,22 +374,19 @@ class TestServeEnginePrecedence:
 
 class TestZonalEnginePrecedence:
     def test_all_layers(self, index, monkeypatch):
-        prof = TuningProfile(probe="adaptive", lookup="gather",
-                             zonal_lane="tiled")
+        prof = TuningProfile(probe="adaptive", zonal_lane="tiled")
         with telemetry.capture() as events:
             eng = ZonalEngine(CUSTOM, RES, chip_index=index, profile=prof)
         (ev,) = resolve_events(events, "zonal_engine")
-        for knob in ("probe", "lookup", "zonal_lane"):
+        for knob in ("probe", "zonal_lane"):
             assert ev[f"{knob}_source"] == "profile", (knob, ev)
         assert eng.lane == "tiled"
 
         monkeypatch.setenv("MOSAIC_RASTER_LANE", "fold")
-        monkeypatch.setenv("MOSAIC_TUNE_LOOKUP", "gather")
         with telemetry.capture() as events:
             eng = ZonalEngine(CUSTOM, RES, chip_index=index, profile=prof)
         (ev,) = resolve_events(events, "zonal_engine")
         assert ev["zonal_lane_source"] == "env" and eng.lane == "fold"
-        assert ev["lookup_source"] == "env"
 
         with telemetry.capture() as events:
             eng = ZonalEngine(
@@ -407,12 +400,11 @@ class TestZonalEnginePrecedence:
 
 class TestRasterStreamPrecedence:
     def test_constructor_knobs(self, index, monkeypatch):
-        prof = TuningProfile(probe="scatter", lookup="gather")
+        prof = TuningProfile(probe="scatter")
         with telemetry.capture() as events:
             RasterStream(index, CUSTOM, RES, profile=prof)
         (ev,) = resolve_events(events, "raster_stream")
         assert ev["probe_source"] == "profile"
-        assert ev["lookup_source"] == "profile"
 
         monkeypatch.setenv("MOSAIC_TUNE_PROBE", "adaptive")
         with telemetry.capture() as events:
@@ -445,6 +437,80 @@ class TestRasterStreamPrecedence:
         np.testing.assert_array_equal(
             np.asarray(out_prof.stats.keys), np.asarray(out_expl.stats.keys)
         )
+
+
+# ------------------- what the chip decided is no longer a knob (PR 29)
+
+def _hot_swap(index, points, **kw):
+    with ServeEngine(index, CUSTOM, RES) as eng:
+        eng.hot_swap(**kw)
+
+
+#: every entry point that took ``lookup=`` before PR 29
+ENTRY_POINTS = {
+    "pip_join": lambda index, points, **kw: pip_join(
+        points, None, CUSTOM, RES, chip_index=index, **kw
+    ),
+    "pip_join_points": lambda index, points, **kw: pip_join_points(
+        points, np.zeros(len(points), np.int64), index, **kw
+    ),
+    "StreamJoin": lambda index, points, **kw: StreamJoin(
+        index, CUSTOM, RES, **kw
+    ),
+    "DispatchCore": lambda index, points, **kw: DispatchCore(
+        index, CUSTOM, RES, **kw
+    ),
+    "ServeEngine": lambda index, points, **kw: ServeEngine(
+        index, CUSTOM, RES, **kw
+    ),
+    "ServeEngine.hot_swap": _hot_swap,
+    "ZonalEngine": lambda index, points, **kw: ZonalEngine(
+        CUSTOM, RES, chip_index=index, **kw
+    ),
+    "RasterStream": lambda index, points, **kw: RasterStream(
+        index, CUSTOM, RES, **kw
+    ),
+}
+
+
+@pytest.mark.parametrize("entry", sorted(ENTRY_POINTS))
+def test_entry_point_takes_no_lookup(entry, index, points):
+    """How tier 1 fetches a row is a fact of `pip_join_points`, not an
+    argument of its callers: every entry point that once took ``lookup=``
+    answers Python's TypeError — none swallows it in a ``**kwargs``."""
+    with pytest.raises(TypeError, match="unexpected keyword.*lookup"):
+        ENTRY_POINTS[entry](index, points, lookup="mxu")
+
+
+def test_stream_join_names_the_one_fetch_and_compaction(index):
+    """The benchmark's ``stream_ready`` line prints both fields; a cap
+    says nothing about the found rate and picks no other lane."""
+    for cap in (None, 64):
+        sj = StreamJoin(index, CUSTOM, RES, found_cap=cap)
+        assert (sj.lookup, sj.compaction) == ("gather", "scatter"), cap
+
+
+def test_profile_stored_with_a_lookup_still_loads(index, tmp_path, monkeypatch):
+    """A profile written before PR 29 carries ``"lookup": "gather"``: it
+    passes the store's checksum, loads, and resolves as if the key were
+    absent; a set MOSAIC_TUNE_LOOKUP is not read."""
+    from mosaic_tpu.tune.store import _body_sha256
+
+    store = ProfileStore(str(tmp_path))
+    path = store.save(TuningProfile(probe="adaptive"))
+    payload = json.loads(open(path).read())
+    payload["profile"]["lookup"] = "gather"
+    payload["sha256"] = _body_sha256(payload)
+    with open(path, "w") as f:
+        json.dump(payload, f)
+    prof, _ = store.load_latest()
+    assert prof == TuningProfile(probe="adaptive")
+    monkeypatch.setenv("MOSAIC_TUNE_LOOKUP", "mxu")
+    with telemetry.capture() as events:
+        StreamJoin(index, CUSTOM, RES, profile=prof)
+    (ev,) = resolve_events(events, "stream_join")
+    assert ev["probe_source"] == "profile" and ev["probe"] == "adaptive"
+    assert not [k for k in ev if "lookup" in k]
 
 
 # ---------------------------------------------------------- profile store

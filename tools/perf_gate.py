@@ -2,8 +2,8 @@
 
 The `BENCH_*.json` trajectory records how fast each round was; nothing
 so far FAILED a build when a stage silently got slower. This gate turns
-the bench trails (`--trail`, exported by serve_bench/stream_bench/
-bench.py) into an enforced contract against a committed golden
+the bench trails (`--trail`, exported by serve_bench/stream_bench)
+into an enforced contract against a committed golden
 (`tests/goldens/perf_gate.json`), MLPerf-style but CPU-safe:
 
 **What is compared.** For every stage key (see

@@ -174,7 +174,7 @@ def main() -> None:
         batch = min(args.batch, args.points)
         n_batches = (args.points + batch - 1) // batch
 
-        # caps from a host presample, margined like bench.py; an overflow
+        # caps from a host presample with a margin; an overflow
         # in any batch is counted on device, reported in detail.overflow
         rng = np.random.default_rng(77)
         n_pre = min(200_000, max(20_000, batch))
@@ -208,7 +208,7 @@ def main() -> None:
         )
         detail.update(
             n_points=n_batches * batch, n_batches=n_batches, batch=batch,
-            caps=[fcap, hcap], lookup=sj.lookup, compaction=sj.compaction,
+            caps=[fcap, hcap],
         )
 
         # sync round-trip: every blocking scalar pull pays this — it must
@@ -368,7 +368,6 @@ def main() -> None:
             if not args.no_ab:
                 sj0 = StreamJoin(
                     index, h3, RES, found_cap=fcap, heavy_cap=hcap,
-                    lookup=sj.lookup, compaction=sj.compaction,
                     prefetch=False,
                 )
                 sj0.compile(ring, n_batches)
@@ -388,7 +387,6 @@ def main() -> None:
             if args.donate:
                 sj_d = StreamJoin(
                     index, h3, RES, found_cap=fcap, heavy_cap=hcap,
-                    lookup=sj.lookup, compaction=sj.compaction,
                     prefetch=True, donate_ring=True,
                 )
                 ring_d = jnp.array(ring, copy=True)  # sacrificial

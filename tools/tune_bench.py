@@ -289,7 +289,7 @@ def main() -> int:
                     np.concatenate(queries), None, grid,
                     int(rec.resolution), chip_index=rec_index,
                     recheck=False, probe=engine.probe,
-                    writeback=engine.writeback, lookup=engine.lookup,
+                    writeback=engine.writeback,
                 )
                 agree = bool(np.array_equal(
                     np.concatenate(post).astype(np.int64),
