@@ -536,11 +536,14 @@ class DispatchCore:
     def warmed(self) -> bool:
         return self._warmed is not None
 
+    def _shard_rows(self, bucket: int) -> int:
+        return bucket if self.mesh is None else bucket // self.mesh.size
+
     def caps(self, bucket: int):
         """Full-bucket caps — PER SHARD under a mesh — so tier overflow
         is structurally impossible and the static-arg set per bucket
         never changes at runtime."""
-        rows = bucket if self.mesh is None else bucket // self.mesh.size
+        rows = self._shard_rows(bucket)
         fcap = None if self.writeback == "direct" else rows
         hcap = rows if self.index.num_heavy_cells else None
         ccap = (
@@ -549,6 +552,15 @@ class DispatchCore:
             else None
         )
         return fcap, hcap, ccap
+
+    def compacted(self, bucket: int) -> bool:
+        """`join.tier1_compacts` of this core's join program at
+        ``bucket``: with full-bucket caps, true only of an adaptive
+        probe."""
+        return _join_mod().tier1_compacts(
+            self._shard_rows(bucket), self.caps(bucket)[0], self.probe,
+            self.writeback,
+        )
 
     def signature(self, bucket: int) -> tuple:
         fcap, hcap, ccap = self.caps(bucket)

@@ -1131,6 +1131,28 @@ def resolve_probe_mode(probe: str) -> str:
     return probe
 
 
+def tier1_compacts(
+    n: int, found_cap: "int | None", probe: str, writeback: str = "scatter"
+) -> bool:
+    """Whether an ``n``-row `pip_join_points` program compacts its found
+    rows before tier 1: the one rule, from static facts, at trace time.
+
+    Compaction reorders the found rows into ``K1 = max(8, min(found_cap
+    or n, n))`` slots so that tier 1 tests ``K1`` rows instead of ``n``.
+    With ``K1 >= n`` (no cap, or a cap of the whole batch: the stream,
+    `DispatchCore`'s full-bucket caps, `pip_join` on a batch more than
+    half found) it shortens nothing and no row can overflow, so the
+    program tests the rows in place, as ``writeback="direct"`` always
+    does. An adaptive probe always compacts: its lanes split the found
+    rows between them. Read on v5e in PERF.md section 6, PR 30.
+    """
+    if probe != "scatter":
+        return True
+    if writeback == "direct":
+        return False
+    return max(8, min(int(found_cap) if found_cap else n, n)) < n
+
+
 def _map_rows(fn, chunk: int, *cols):
     """``fn(*cols)`` over row chunks of at most ``chunk`` by `lax.map`, for
     a row-wise ``fn`` (row i of every output depends on row i of the
@@ -1170,11 +1192,16 @@ def pip_join_points(
     """(N,) int32 — smallest matching polygon row per point, -1 if none.
 
     Jittable (``heavy_cap``/``found_cap`` static); shard the point axis over
-    a mesh and replicate ``index``. Probe = hash lookup (1 gather), then
-    stream-compaction of the points whose cell exists in the index (misses
-    skip all edge work — on sparse workloads most points stop here), then a
-    flat bounded edge gather + XOR crossing parity; points in heavy cells
-    are compacted once more for the tier-2 gather.
+    a mesh and replicate ``index``. Probe = hash lookup (1 gather), then a
+    flat bounded edge gather + XOR crossing parity (tier 1); points in heavy
+    cells are compacted for the tier-2 gather. Where ``found_cap`` is under
+    the row count, the points whose cell exists in the index are
+    stream-compacted into ``found_cap`` slots first, so tier 1 tests that
+    many rows and the misses skip all edge work. Where it is not (no cap,
+    or a cap of the whole batch) compaction would reorder N rows into N
+    slots and back, so tier 1 tests every row in place and masks the
+    misses: :func:`tier1_compacts` is the rule, static, evaluated at
+    trace time, and the answers are the same bit for bit.
 
     ``found_cap`` bounds how many points per call may hit an indexed cell
     and ``heavy_cap`` how many may land in heavy cells. Both default to
@@ -1188,15 +1215,19 @@ def pip_join_points(
     sqrt(edge_eps2) of any probed chip edge — the set whose f32 parity may
     disagree with f64 (`pip_join` rechecks them on the host oracle).
 
-    ``writeback`` picks the probe plumbing — identical results:
-    ``"scatter"`` compacts found points then returns results via a
-    unique-destination set scatter; ``"gather"`` compacts but inverts by
-    per-point gather of the prefix slot; ``"direct"`` skips tier-1
-    compaction entirely —
-    every point gathers its own 512 B edge row (wasted gathers on misses,
-    but no prefix scan, no point permutation and no writeback, which cost
-    ~65 ms combined at 4M on v5e while the full row-gather runs ~30 ms;
-    ``found_cap`` is ignored and tier-1 overflow is impossible).
+    ``writeback`` picks the probe plumbing under a cap that cuts rows —
+    identical results: ``"scatter"`` compacts found points then returns
+    results via a unique-destination set scatter; ``"gather"`` compacts
+    but inverts by per-point gather of the prefix slot; ``"direct"`` never
+    compacts tier 1, whatever the cap — every point gathers its own edge
+    rows (wasted gathers on misses, but no prefix scan, no point
+    permutation and no writeback; ``found_cap`` is ignored and tier-1
+    overflow is impossible). On v5e at 4M rows and E1 = 24 (PERF.md
+    section 6, PR 30) the join in place takes 93.5 ms at every found
+    share; compacted into N - 1 slots 183.0 (``gather`` 186.1), into N/2
+    121.2 (136.0), into N/4 89.8 (110.6), into N/16 68.5 (93.2):
+    compaction costs 57 ms that scale with N plus 126 ms x cap / N, and
+    pays under a cap of about 0.29 N.
 
     ``probe="adaptive"`` switches on per-cell density routing inside this
     one jitted program: light cells keep the tier-1 path above, heavy
@@ -1295,7 +1326,9 @@ def pip_join_points(
             return _map_rows(_tier1, _TIER1_CHUNK, px_c, py_c, us_c)
         return _tier1(px_c, py_c, us_c)
 
-    if writeback == "direct":
+    if not tier1_compacts(N, found_cap, probe, writeback):
+        # in place: every row fetches its own tier-1 row, misses (slot 0,
+        # masked by `found`) included; no row can overflow tier 1
         with jax.named_scope("pip.tier1"):
             us = jnp.maximum(u, 0)
             best, near1, heavy_d = _tier1_rows(
@@ -1657,6 +1690,8 @@ def pip_join(
             # slice the pad off. RetryExhausted falls through to
             # `run_resilient`'s host-oracle degradation like every lane.
             padded, nn = core.ladder.pad(chunk)
+            # (`sp`: the call's `join.pip` span, open around every `run`)
+            sp.attrs["compacted"] |= core.compacted(padded.shape[0])
             return _dispatch.guarded_call(
                 "pip_join.device", core.execute_padded, padded
             )[:nn]
@@ -1725,6 +1760,9 @@ def pip_join(
                     found=nf, heavy=nh, convex=nc,
                     light=nf - nc,
                 )
+        sp.attrs["compacted"] |= tier1_compacts(
+            chunk.shape[0], fcap, probe, writeback
+        )
         shifted = jnp.asarray(chunk - shift, dtype=dtype)
         # every cap that exists escalates together toward the row-count
         # ceiling, at which overflow is structurally impossible
@@ -1897,7 +1935,11 @@ def pip_join(
 
     # one span per pip_join call: escalation/retry/degradation/recheck
     # events inside attach to it, so a trail shows WHICH join they hit
-    with _obs_trace.span("join.pip", n=n, recheck=bool(recheck), probe=probe):
+    # (`compacted`: whether any chunk's program, as first dispatched,
+    # compacts before tier 1 — `tier1_compacts`)
+    with _obs_trace.span(
+        "join.pip", n=n, recheck=bool(recheck), probe=probe, compacted=False
+    ) as sp:
         if batch_size is None or n <= batch_size:
             return run_spanned(raw)
         out = np.empty(n, dtype=np.int32)

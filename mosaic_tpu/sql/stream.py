@@ -90,6 +90,7 @@ from .join import (
     host_join_with_cells,
     pip_join_points,
     resolve_probe_mode,
+    tier1_compacts,
 )
 
 
@@ -602,7 +603,13 @@ class StreamJoin:
             points_per_sec=n_points / max(wall, 1e-9),
             prefetch=self.prefetch,
             outs=np.asarray(outs) if collect else None,
-            metrics=dict(donation),
+            metrics={
+                **donation,
+                "compacted": tier1_compacts(
+                    batch // (1 if self.mesh is None else self.mesh.size),
+                    self.found_cap, self.probe,
+                ),
+            },
         )
 
     def run_batched(self, ring: jax.Array, n_batches: int) -> StreamResult:

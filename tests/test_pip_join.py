@@ -4,6 +4,8 @@ Reference analog: `PointInPolygonJoinTest` — a point lands in polygon P iff
 the managed join reports P (`sql/join/PointInPolygonJoin.scala:15-98`).
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -91,31 +93,52 @@ def test_join_h3_nyc_box():
     np.testing.assert_array_equal(got[off_boundary], want[off_boundary])
 
 
+@functools.lru_cache(maxsize=None)
+def _join_case(heavy: bool):
+    """(shifted points, cells, index) of the writeback tests' fixtures:
+    the NYC box pair (no heavy cell) or ZONES at edge_cap=8 (heavy)."""
+    import jax.numpy as jnp
+
+    from mosaic_tpu.sql import join as J
+
+    rng = np.random.default_rng(2)
+    if heavy:
+        col, res, n = wkt.from_wkt(ZONES), 3, 10000
+        pts = np.column_stack(
+            [rng.uniform(-25, 35, n), rng.uniform(-25, 20, n)]
+        )
+        kw = {"edge_cap": 8}
+    else:
+        col, res, n = wkt.from_wkt([
+            "POLYGON ((-74.02 40.70, -73.96 40.70, -73.96 40.76, "
+            "-74.02 40.76, -74.02 40.70))",
+            "POLYGON ((-73.96 40.70, -73.90 40.70, -73.90 40.76, "
+            "-73.96 40.76, -73.96 40.70))",
+        ]), 8, 5000
+        pts = np.column_stack(
+            [rng.uniform(-74.05, -73.87, n), rng.uniform(40.68, 40.78, n)]
+        )
+        kw = {}
+    idx = J.build_chip_index(
+        tessellate(col, H3, res, keep_core_geoms=False), **kw
+    )
+    assert bool(idx.num_heavy_cells) == heavy
+    cells = H3.point_to_cell(jnp.asarray(pts), res)
+    shifted = jnp.asarray(
+        pts - np.asarray(idx.border.shift, np.float64),
+        dtype=idx.border.verts.dtype,
+    )
+    return shifted, cells, idx
+
+
 def test_writeback_variants_identical():
     """The gather writeback is an autotuning knob: results must be
     bitwise identical to the scatter path, bands included."""
     import jax.numpy as jnp
 
-    from mosaic_tpu.core.index import H3
-    from mosaic_tpu.core.tessellate import tessellate
-    from mosaic_tpu.sql.join import build_chip_index, pip_join_points
+    from mosaic_tpu.sql.join import pip_join_points
 
-    col = wkt.from_wkt([
-        "POLYGON ((-74.02 40.70, -73.96 40.70, -73.96 40.76, "
-        "-74.02 40.76, -74.02 40.70))",
-        "POLYGON ((-73.96 40.70, -73.90 40.70, -73.90 40.76, "
-        "-73.96 40.76, -73.96 40.70))",
-    ])
-    idx = build_chip_index(tessellate(col, H3, 8, keep_core_geoms=False))
-    rng = np.random.default_rng(2)
-    pts = np.column_stack(
-        [rng.uniform(-74.05, -73.87, 5000), rng.uniform(40.68, 40.78, 5000)]
-    )
-    cells = H3.point_to_cell(jnp.asarray(pts), 8)
-    shifted = jnp.asarray(
-        pts - np.asarray(idx.border.shift, np.float64),
-        dtype=idx.border.verts.dtype,
-    )
+    shifted, cells, idx = _join_case(heavy=False)
     eps2 = jnp.asarray(1e-10, idx.border.verts.dtype)
     a, na = pip_join_points(shifted, cells, idx, edge_eps2=eps2)
     for wb in ("gather", "direct"):
@@ -147,35 +170,140 @@ def test_tier1_chunked_path_identical(monkeypatch, writeback, banded):
     import jax
     import jax.numpy as jnp
 
-    from mosaic_tpu.core.index import H3
-    from mosaic_tpu.core.tessellate import tessellate
     from mosaic_tpu.sql import join as J
 
-    col = wkt.from_wkt(ZONES)
-    cidx = J.build_chip_index(
-        tessellate(col, H3, 3, keep_core_geoms=False), edge_cap=8
-    )
-    assert cidx.num_heavy_cells  # the chunks carry tier 2's row ids too
-    rng = np.random.default_rng(11)
-    pts = np.column_stack(
-        [rng.uniform(-25, 35, 10000), rng.uniform(-25, 20, 10000)]
-    )
-    cells = H3.point_to_cell(jnp.asarray(pts, jnp.float32), 3)
-    shifted = jnp.asarray(
-        pts - np.asarray(cidx.border.shift, np.float64),
-        dtype=cidx.border.verts.dtype,
-    )
+    # heavy cells: the chunks carry tier 2's row ids too
+    shifted, cells, cidx = _join_case(heavy=True)
     eps2 = jnp.asarray(1e-10, cidx.border.verts.dtype) if banded else None
-    want = J.pip_join_points(shifted, cells, cidx, edge_eps2=eps2)
+    # a cap one under the batch keeps tier 1 compacting (`tier1_compacts`)
+    cap = shifted.shape[0] - 1
+    want = J.pip_join_points(
+        shifted, cells, cidx, edge_eps2=eps2, found_cap=cap
+    )
     monkeypatch.setattr(J, "_TIER1_CHUNK", 1536)  # non-divisor: pads
     got = J.pip_join_points(
-        shifted, cells, cidx, edge_eps2=eps2, writeback=writeback
+        shifted, cells, cidx, edge_eps2=eps2, writeback=writeback,
+        found_cap=cap,
     )
     want, got = jax.tree.leaves(want), jax.tree.leaves(got)  # out[, near]
     assert len(want) == len(got) == 1 + banded
     for w, g in zip(want, got):
         np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
     assert (np.asarray(want[0]) >= 0).any()
+
+
+@pytest.mark.parametrize(
+    "n, found_cap, probe, writeback, compacts",
+    [
+        (4096, None, "scatter", "scatter", False),   # uncapped: K1 = n
+        (4096, None, "scatter", "gather", False),
+        (4096, 4096, "scatter", "scatter", False),   # the whole batch
+        (4096, 8192, "scatter", "scatter", False),   # clipped to n
+        (4096, 4095, "scatter", "scatter", True),    # cuts one slot
+        (4096, 2048, "scatter", "gather", True),
+        (4096, 1, "scatter", "scatter", True),       # K1 = 8 < n
+        (4, None, "scatter", "scatter", False),      # K1 = 8 >= n
+        (4, 2, "scatter", "scatter", False),
+        (4096, 2048, "scatter", "direct", False),    # direct never does
+        (4096, None, "adaptive", "scatter", True),   # lanes split rows
+        (4096, 4096, "adaptive-light", "scatter", True),
+        (4096, None, "adaptive-convex", "gather", True),
+    ],
+)
+def test_tier1_compacts_rule(n, found_cap, probe, writeback, compacts):
+    """Tier 1 compacts only where ``K1 = max(8, min(found_cap or n, n))``
+    is under the row count, or the probe is adaptive (with or without
+    convex cells: the index is not asked)."""
+    from mosaic_tpu.sql.join import tier1_compacts
+
+    assert tier1_compacts(n, found_cap, probe, writeback) is compacts
+
+
+@pytest.mark.parametrize("banded", [True, False])
+@pytest.mark.parametrize("heavy", [False, True])
+def test_uncompacted_default_equals_direct_and_capped(heavy, banded):
+    """A call whose cap cuts no rows tests them in place: its answers,
+    ``near`` and tier-2 OVERFLOW marks are those of ``writeback="direct"``
+    and of the compacted program (a cap one under the batch), bit for
+    bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from mosaic_tpu.sql.join import OVERFLOW, pip_join_points
+
+    shifted, cells, idx = _join_case(heavy)
+    n = shifted.shape[0]
+    eps2 = jnp.asarray(1e-10, idx.border.verts.dtype) if banded else None
+    hcaps = (None, 8) if heavy else (None,)
+    for hcap in hcaps:
+        kw = {"edge_eps2": eps2, "heavy_cap": hcap}
+        want = jax.tree.leaves(pip_join_points(shifted, cells, idx, **kw))
+        assert len(want) == 1 + banded
+        out = np.asarray(want[0])
+        assert (out >= 0).any() and (out == -1).any()
+        assert (out == OVERFLOW).any() == (hcap is not None)
+        for other in (
+            {"writeback": "direct"},
+            {"writeback": "gather"},
+            {"found_cap": n},
+            {"found_cap": n - 1},
+            {"found_cap": n - 1, "writeback": "gather"},
+        ):
+            got = jax.tree.leaves(
+                pip_join_points(shifted, cells, idx, **kw, **other)
+            )
+            for w, g in zip(want, got, strict=True):
+                np.testing.assert_array_equal(
+                    np.asarray(w), np.asarray(g), str((hcap, other))
+                )
+
+
+def _join_text(J, shifted, cells, idx, **kw):
+    """StableHLO of one `pip_join_points` call (a new function object
+    every time: no trace is shared between two texts)."""
+    import jax
+
+    return jax.jit(
+        lambda p, c, i: J.pip_join_points(p, c, i, **kw)
+    ).lower(shifted, cells, idx).as_text()
+
+
+def test_program_scatters_only_where_a_cap_cuts_rows():
+    """The lowered StableHLO of an uncapped default call holds no scatter
+    (no compaction, no writeback scatter; the index has no heavy cell);
+    a capped one holds them."""
+    from mosaic_tpu.sql import join as J
+
+    shifted, cells, idx = _join_case(heavy=False)
+    n = shifted.shape[0]
+    for kw in ({}, {"found_cap": n}, {"writeback": "gather"},
+               {"writeback": "direct"}):
+        assert "scatter" not in _join_text(J, shifted, cells, idx, **kw), kw
+    for kw in ({"found_cap": n // 2}, {"found_cap": n - 1},
+               {"found_cap": n // 2, "writeback": "gather"}):
+        assert "stablehlo.scatter" in _join_text(
+            J, shifted, cells, idx, **kw
+        ), kw
+
+
+@pytest.mark.parametrize("probe", ["adaptive", "adaptive-light"])
+@pytest.mark.parametrize("found_cap", [None, 2048])
+def test_adaptive_program_is_the_parents(monkeypatch, probe, found_cap):
+    """Under the parent's rule (tier 1 compacts unless the writeback is
+    direct) an adaptive program lowers to the same text byte for byte:
+    the rule changes nothing for it, capped or not. The same swap does
+    change the uncapped scatter-probe program, so the swap is seen."""
+    from mosaic_tpu.sql import join as J
+
+    shifted, cells, idx = _join_case(heavy=False)
+    kw = {"probe": probe, "found_cap": found_cap}
+    mine = _join_text(J, shifted, cells, idx, **kw)
+    plain = _join_text(J, shifted, cells, idx)
+    monkeypatch.setattr(
+        J, "tier1_compacts", lambda n, cap, probe, wb="scatter": wb != "direct"
+    )
+    assert _join_text(J, shifted, cells, idx, **kw) == mine
+    assert _join_text(J, shifted, cells, idx) != plain
 
 
 @pytest.mark.parametrize("order", ["in-order", "random"])

@@ -353,6 +353,55 @@ def test_stream_launch_and_pull_are_children_of_the_run(stream):
     assert len(loop) == 1  # the timed twin stays, undoubled
 
 
+# ------------------------------------- the rule's verdict, where it is read
+
+@pytest.mark.parametrize("probe,compacted", [
+    ("scatter", False),   # full-bucket caps cut no rows
+    ("adaptive", True),
+])
+def test_engine_metrics_say_whether_tier1_compacts(
+        index, grid, probe, compacted):
+    with _engine(index, grid, probe=probe) as eng:
+        assert eng.metrics()["compacted"] is compacted
+
+
+@pytest.mark.parametrize("found_cap,compacted", [
+    (None, False), (256, False), (255, True),
+])
+def test_stream_result_says_whether_tier1_compacts(
+        stream, index, grid, found_cap, compacted):
+    _sj, ring = stream  # 256 rows a slot
+    res = StreamJoin(index, grid, RES, found_cap=found_cap).run(ring, 2)
+    assert res.metrics["compacted"] is compacted
+    assert res.overflow == 0
+
+
+@pytest.mark.parametrize("dense,kw,compacted", [
+    # most rows found: the cap sized from the count is the whole batch
+    (True, {}, False),
+    # most rows miss: the cap is under the batch, tier 1 compacts
+    (False, {}, True),
+    (False, {"writeback": "direct"}, False),
+    (True, {"probe": "adaptive"}, True),
+    # any chunk's verdict; the mesh lane's full per-shard caps
+    (True, {"batch_size": 500}, False),
+    (False, {"mesh": 2}, False),
+])
+def test_join_pip_span_says_whether_tier1_compacts(
+        index, grid, dense, kw, compacted):
+    from mosaic_tpu.sql.join import pip_join
+
+    box = (1.0, 1.0, 12.0, 11.0) if dense else (40.0, 40.0, 170.0, 80.0)
+    pts = np.random.default_rng(4).uniform(box[:2], box[2:], (2048, 2))
+    with telemetry.capture() as events:
+        out = pip_join(pts, None, grid, RES, chip_index=index,
+                       recheck=False, **kw)
+    (span,) = [e for e in events
+               if e["event"] == "span" and e["name"] == "join.pip"]
+    assert span["compacted"] is compacted
+    assert ((np.asarray(out) >= 0).mean() > 0.5) == dense
+
+
 # --------------------------------------------------------- stage tables
 
 def test_no_lowering_until_a_reader_asks(stream):
@@ -400,8 +449,15 @@ def test_tables_lower_the_jaxpr_the_programs_own_call_traced(
         stages.clear()
 
 
-def test_stream_loop_maps_every_instruction_to_a_stage(stream):
+@pytest.mark.parametrize("found_cap", [None, 128])
+def test_stream_loop_maps_every_instruction_to_a_stage(
+        stream, index, grid, found_cap):
+    """Every stage of the join shows in the loop's table; `pip.compact`
+    only where the cap cuts rows (128 of the ring's 256 a slot)."""
     sj, ring = stream
+    if found_cap:
+        sj = StreamJoin(index, grid, RES, found_cap=found_cap)
+    stages.clear()
     sj._stages_seen.clear()
     sj.compile(ring, 2)
     with telemetry.capture() as events:
@@ -410,7 +466,8 @@ def test_stream_loop_maps_every_instruction_to_a_stage(stream):
     # looks like an executable from before a scope changed
     assert not [e for e in events if e["event"] == "stages_stale_executable"]
     found = set(table.values())
-    assert JOIN_STAGES <= found
+    assert JOIN_STAGES - {"pip.compact"} <= found
+    assert ("pip.compact" in found) == bool(found_cap)
     assert found <= JOIN_STAGES | {stages.UNSCOPED}
     # what is left without a scope: the entry's parameters and constants
     unscoped = [k for k, v in table.items() if v == stages.UNSCOPED]
@@ -429,9 +486,20 @@ def test_join_program_maps_every_instruction_to_a_stage(index, grid):
     assert not [e for e in events if e["event"] == "stages_stale_executable"]
     assert set(tables["jit_cells"].values()) <= {"pip.cells", stages.UNSCOPED}
     join = set(tables["jit_pip_join_points"].values())
+    # a full-bucket cap cuts no rows: the program has no compaction stage
+    assert not core.compacted(64)
+    assert {"pip.hash_probe", "pip.tier1", "pip.writeback"} <= join
+    assert "pip.compact" not in join
+    assert join <= JOIN_STAGES | {stages.UNSCOPED}
+    # an adaptive core's program compacts, and names the stage
+    adaptive = dispatch_core.DispatchCore(
+        index, grid, RES, ladder=BucketLadder(64, 64), probe="adaptive")
+    stages.clear()
+    got = np.asarray(adaptive.execute_padded(_points(9, 64)))
+    assert adaptive.compacted(64) and (got == want).all()
+    join = set(stages.tables()["jit_pip_join_points"].values())
     assert {"pip.hash_probe", "pip.compact", "pip.tier1",
             "pip.writeback"} <= join
-    assert join <= JOIN_STAGES | {stages.UNSCOPED}
     # scopes are metadata: the answers are those of the unscoped function
     from mosaic_tpu.sql.join import host_join
 
