@@ -75,6 +75,9 @@ SIZES = {
         ladder=(64, 65536), requests=300, threads=4, rows_max=1024,
         kernel_points=60_000, raster_side=2400,
         mesh_rows=1 << 20,
+        # a 1M-row step, so that tier 2 runs in row chunks (419,328 at E2 80)
+        buildings=dict(index_system="H3", resolution=11, count=2_000,
+                       rows=1_000_000, check_rows=100_000),
     ),
     # a coarse custom grid: H3's unrolled digit pipeline costs the CPU
     # ~15 s of compiles that prove nothing about this script's control flow
@@ -86,6 +89,9 @@ SIZES = {
         ladder=(64, 128), requests=12, threads=2, rows_max=100,
         kernel_points=1_000, raster_side=48,
         mesh_rows=2_048,
+        buildings=dict(index_system="CUSTOM(-75,-73,40,42,2,1,1)",
+                       resolution=11, count=300, rows=4_096,
+                       check_rows=4_096),
     ),
 }
 
@@ -323,6 +329,70 @@ def phase_stream(dep: dict, size: dict, seed: int) -> dict:
         hbm_source=source,
     )
     say("stream", **rep)
+    return rep
+
+
+# --------------------------------------------------------------- buildings
+
+def phase_buildings(size: dict, seed: int) -> dict:
+    """A fabric of building footprints on a grid fine enough to hold it
+    (the benchmark's `osm-buildings-h3r11` at a 30th of its size): an index
+    with heavy cells, so tier 2 runs — in row chunks at the full size — and
+    cells so small that the stream's rule assigns them in f64. One stream
+    step with package defaults against the f64 host oracle."""
+    import jax
+    import numpy as np
+
+    import mosaic_tpu
+    from benchmark.generators import buildings
+    from mosaic_tpu.core.tessellate import tessellate
+    from mosaic_tpu.core.types import GeometryBuilder, GeometryType
+    from mosaic_tpu.datasets import random_points
+    from mosaic_tpu.sql.join import build_chip_index, host_join
+    from mosaic_tpu.sql.stream import StreamJoin
+
+    b = size["buildings"]
+    grid = mosaic_tpu.enable_mosaic(b["index_system"]).index_system
+    res = b["resolution"]
+    footprints, _ = buildings.fabric(
+        {"count": b["count"], "centre": [-73.95, 40.70], "seed": 11})
+    col = GeometryBuilder()
+    for rings in footprints:
+        col.add_geometry(GeometryType.POLYGON, [rings], srid=4326)
+    t0 = time.perf_counter()
+    index = build_chip_index(
+        tessellate(col.build(), grid, res, keep_core_geoms=False))
+    build_s = time.perf_counter() - t0
+    check(index.num_heavy_cells > 0,
+          "the fabric index has no heavy cell: tier 2 would not run")
+    sj = StreamJoin(index, grid, res)
+    check(sj.cell_dtype == "float64",
+          f"the stream's rule chose {sj.cell_dtype} cells at resolution "
+          f"{res}, where an f32 ulp is a fortieth of a cell")
+    pts = random_points(
+        b["rows"], bbox=buildings.footprints_bbox(footprints), seed=seed)
+    ring = jax.numpy.asarray(pts)[None]
+    result = sj.run(ring, 1, collect=True)
+    check(result.overflow == 0,
+          f"stream reported {result.overflow} OVERFLOW rows")
+    n = b["check_rows"]
+    truth = host_join(pts[:n], index.host, grid, res)
+    agree = float((result.outs[0][:n] == truth).mean())
+    check(agree >= 0.9995,
+          f"the fabric stream agrees with the f64 host oracle on "
+          f"{agree:.6f} of rows (< 0.9995)")
+    check(result.metrics["heavy_rows"] > 0, "no row's cell was heavy")
+    rep = dict(
+        footprints=len(footprints), cells=index.num_cells,
+        heavy_cells=index.num_heavy_cells,
+        E2=int(index.heavy_edges.shape[1]),
+        M2=int(index.heavy_slot_geom.shape[1]), rows=b["rows"],
+        heavy_rows=result.metrics["heavy_rows"],
+        cell_dtype=result.metrics["cell_dtype"], check_rows=n,
+        agreement=round(agree, 6), matches=result.matches,
+        index_build_s=round(build_s, 1), run_wall_s=round(result.wall_s, 2),
+    )
+    say("buildings", **rep)
     return rep
 
 
@@ -732,6 +802,7 @@ def run(args) -> tuple[dict, dict]:
         report["stream"] = phase_stream(dep, size, args.seed)
         report["serve"] = phase_serve(dep, size, args.seed)
         report["kernels"] = phase_kernels(dep, size, args.seed)
+        report["buildings"] = phase_buildings(size, args.seed)
         if device["count"] > 1:
             report["mesh"] = phase_mesh(dep, size)
     bad = [e for e in events if e.get("event") in FORBIDDEN_EVENTS]

@@ -41,7 +41,14 @@ import dataclasses
 import numpy as np
 
 from .index.base import IndexSystem
-from .types import GeometryBuilder, GeometryType, PackedGeometry, ring_signed_area
+from ..obs import trace as _trace
+from .types import (
+    GeometryBuilder,
+    GeometryType,
+    PackedGeometry,
+    concat_packed,
+    ring_signed_area,
+)
 
 _EPS = 1e-12
 
@@ -367,6 +374,103 @@ def _classify_cells_batch(
     return is_core, is_border
 
 
+def _classify_pairs(
+    rings: np.ndarray,
+    rlen: np.ndarray,
+    cells: np.ndarray,
+    klen: np.ndarray,
+) -> tuple[np.ndarray, np.ndarray]:
+    """`_classify_cells_batch` for P independent (ring, cell) pairs: row p
+    holds one open ring (``rings`` (P, n, 2) left-packed, ``rlen`` (P,)
+    vertices) and one cell window (``cells`` (P, L, 2), ``klen`` (P,)).
+    Returns (is_core (P,), is_border (P,)).
+
+    The arithmetic per (cell vertex, ring edge) is that function's, term
+    for term; only its locality prefilters are left out, which drop edges
+    and vertices that cannot contribute (an edge wholly above, below or to
+    the left of a cell crosses no +x ray from it; a vertex outside a
+    cell's bbox is not inside it; segments with disjoint bboxes neither
+    cross nor touch), so a pair's verdict is the batch's.
+    """
+    P, L, _ = cells.shape
+    n = rings.shape[1]
+    idx = np.arange(L)[None, :]
+    vdx = np.arange(n)[None, :]
+    jmask = idx < klen[:, None]  # (P, L) valid cell vertices == edges
+    vmask = vdx < rlen[:, None]  # (P, n) ring vertices
+    centers = cells.sum(axis=1) / klen[:, None]
+    nxt = np.where(idx + 1 < klen[:, None], idx + 1, 0)
+    cb = np.take_along_axis(cells, nxt[:, :, None], axis=1)  # (P, L, 2)
+    d = cb - cells
+    # ring edges a -> b; pad edges are zero-length (never straddle a ray)
+    rn = np.where(vdx + 1 < rlen[:, None], vdx + 1, 0)
+    ga = np.where(vmask[:, :, None], rings, rings[:, :1])
+    gb = np.where(
+        vmask[:, :, None],
+        np.take_along_axis(rings, rn[:, :, None], axis=1),
+        rings[:, :1],
+    )
+    # even-odd parity of every cell corner and the centre (`_even_odd_edges`)
+    pts = np.concatenate([cells, centers[:, None, :]], axis=1)  # (P, L+1, 2)
+    px, py = pts[:, :, 0, None], pts[:, :, 1, None]
+    ay, by = ga[:, None, :, 1], gb[:, None, :, 1]
+    straddle = (ay > py) != (by > py)
+    denom = by - ay
+    denom = np.where(denom == 0, 1.0, denom)
+    xc = ga[:, None, :, 0] + (py - ay) * (
+        gb[:, None, :, 0] - ga[:, None, :, 0]
+    ) / denom
+    par = (np.sum(straddle & (px < xc), axis=2) & 1) == 1  # (P, L+1)
+    corners_in, centers_in = par[:, :L], par[:, L]
+    # any ring vertex strictly inside the cell
+    sgn = d[:, :, 0, None] * (
+        rings[:, None, :, 1] - cells[:, :, 1, None]
+    ) - d[:, :, 1, None] * (rings[:, None, :, 0] - cells[:, :, 0, None])
+    strict = np.all((sgn > _EPS) | ~jmask[:, :, None], axis=1)  # (P, n)
+    vin = (strict & vmask).any(axis=1)
+    # any ring edge crossing or touching any cell edge (`_segments_cross`)
+    da = gb - ga  # (P, n, 2)
+
+    def cross(o, dv, pt):
+        # o, dv (P, E, 2) against pt (P, F, 2) -> (P, E, F)
+        return dv[:, :, None, 0] * (
+            pt[:, None, :, 1] - o[:, :, None, 1]
+        ) - dv[:, :, None, 1] * (pt[:, None, :, 0] - o[:, :, None, 0])
+
+    def on_seg(o, dv, pt, c):
+        lo = np.minimum(o, o + dv)
+        hi = np.maximum(o, o + dv)
+        inside = (
+            (pt[:, None, :, 0] >= lo[:, :, None, 0] - _EPS)
+            & (pt[:, None, :, 0] <= hi[:, :, None, 0] + _EPS)
+            & (pt[:, None, :, 1] >= lo[:, :, None, 1] - _EPS)
+            & (pt[:, None, :, 1] <= hi[:, :, None, 1] + _EPS)
+        )
+        return (np.abs(c) <= _EPS) & inside
+
+    d1 = cross(ga, da, cells)  # (P, n, L)
+    d2 = cross(ga, da, cb)
+    d3 = cross(cells, d, ga).transpose(0, 2, 1)
+    d4 = cross(cells, d, gb).transpose(0, 2, 1)
+    cm = ((d1 > _EPS) != (d2 > _EPS)) & ((d3 > _EPS) != (d4 > _EPS)) & (
+        (d1 < -_EPS) != (d2 < -_EPS)
+    ) & ((d3 < -_EPS) != (d4 < -_EPS))
+    cm |= (
+        on_seg(ga, da, cells, d1)
+        | on_seg(ga, da, cb, d2)
+        | on_seg(cells, d, ga, d3.transpose(0, 2, 1)).transpose(0, 2, 1)
+        | on_seg(cells, d, gb, d4.transpose(0, 2, 1)).transpose(0, 2, 1)
+    )
+    cm &= vmask[:, :, None] & jmask[:, None, :]
+    crossing = cm.any(axis=(1, 2))
+
+    all_in = np.all(corners_in | ~jmask, axis=1)
+    any_in = np.any(corners_in & jmask, axis=1)
+    is_core = all_in & ~crossing & ~vin
+    is_border = ~is_core & (any_in | crossing | vin | centers_in)
+    return is_core, is_border
+
+
 def clip_rings_convex_batch(
     ring: np.ndarray, cells: np.ndarray, klen: np.ndarray
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -378,16 +482,31 @@ def clip_rings_convex_batch(
     (< 3 vertices). Equivalent to per-cell `clip_ring_convex` up to
     consecutive-duplicate vertices, which are removed at the end.
     """
-    K, L, _ = cells.shape
+    K = cells.shape[0]
     n = ring.shape[0]
     if K == 0 or n == 0:
         return np.zeros((K, 1, 2)), np.zeros(K, dtype=np.int64)
+    return _clip_rows_convex(
+        np.broadcast_to(ring[None, :, :], (K, n, 2)),
+        np.full(K, n, dtype=np.int64), cells, klen,
+    )
+
+
+def _clip_rows_convex(
+    rings: np.ndarray, rlen: np.ndarray, cells: np.ndarray, klen: np.ndarray
+) -> tuple[np.ndarray, np.ndarray]:
+    """`clip_rings_convex_batch` with a ring of its own per row: ``rings``
+    (K, n, 2) left-packed open rings of ``rlen`` (K,) vertices, row k
+    clipped against window k. Every operation is per element, so a row's
+    result does not depend on what it is batched with."""
+    K, L, _ = cells.shape
+    n = rings.shape[1]
     # concave rings can emit 2 points per vertex against one half-plane, so
     # there is no small static bound; the buffer grows to each round's true
     # need (new_len.max()) below
     cur = np.zeros((K, n + L + 2, 2))
-    cur[:, :n] = ring[None, :, :]
-    clen = np.full(K, n, dtype=np.int64)
+    cur[:, :n] = rings
+    clen = np.asarray(rlen, dtype=np.int64).copy()
     for e in range(L):
         jdx = np.arange(cur.shape[1])[None, :]
         active = (e < klen) & (clen > 0)
@@ -731,6 +850,161 @@ def _point_chips(
         builder.add_geometry(GeometryType.POINT, [[pts[i : i + 1]]], srid)
 
 
+#: the batched path of `tessellate` takes polygons of one part and one ring
+#: of at most this many vertices (rings are padded to the longest of a
+#: batch); the rest go through `_polygon_chips` one by one. Tests set it to
+#: 0 to hold the two paths equal
+_FAST_MAX_VERTS = 16
+#: (polygon, candidate cell) pairs classified and clipped per batch: bounds
+#: the (pairs, L, verts) intermediates at ~100 MB
+_FAST_PAIR_CHUNK = 1 << 16
+#: margin of the batched path's bbox prefilter, in CRS units: far above the
+#: classification's own tolerance (`_EPS`, and a few ulps of a coordinate)
+_BBOX_PAD = 1e-9
+
+
+def _simple_polygons(col: PackedGeometry, poly_ids: np.ndarray) -> np.ndarray:
+    """Mask over ``poly_ids``: one part, one ring, 3 to `_FAST_MAX_VERTS`
+    vertices — what the batched path takes."""
+    p0 = col.geom_offsets[poly_ids]
+    one_part = col.geom_offsets[poly_ids + 1] - p0 == 1
+    p0 = np.minimum(p0, max(col.num_parts - 1, 0))
+    r0 = col.part_offsets[p0]
+    one_ring = col.part_offsets[p0 + 1] - r0 == 1
+    r0 = np.minimum(r0, max(col.num_rings - 1, 0))
+    n = col.ring_offsets[r0 + 1] - col.ring_offsets[r0]
+    return one_part & one_ring & (n >= 3) & (n <= _FAST_MAX_VERTS)
+
+
+def _fast_polygon_chips(
+    col: PackedGeometry,
+    gids: np.ndarray,
+    gbounds: np.ndarray,
+    pair_g: np.ndarray,
+    pair_cand: np.ndarray,
+    pair_cells: np.ndarray,
+    pair_klen: np.ndarray,
+    keep_core_geoms: bool,
+) -> ChipTable:
+    """Chips of the simple polygons ``gids``, all (polygon, candidate cell)
+    pairs at once: the rows `_polygon_chips` would emit for them, in its
+    order (by polygon, then by candidate) and with its coordinates.
+
+    ``gbounds`` (G, 4) are their bboxes; ``pair_g`` (P,) indexes
+    ``gids``; ``pair_cand`` / ``pair_cells`` /
+    ``pair_klen`` are the pair's candidate cell id and deduped boundary.
+    """
+    # a candidate whose bbox lies clear of the polygon's touches nothing
+    # of it (no corner inside, no vertex inside, no edge contact: outside
+    # by every test of `_classify_pairs`); most candidates are such, the
+    # 1-ring around the cells a small polygon really meets
+    L = pair_cells.shape[1]
+    real = (np.arange(L)[None, :] < pair_klen[:, None])[:, :, None]
+    gb = gbounds[pair_g]
+    ok = (
+        (pair_klen >= 3)
+        & (np.where(real, pair_cells, np.inf).min(axis=1) <= gb[:, 2:] + _BBOX_PAD).all(axis=1)
+        & (np.where(real, pair_cells, -np.inf).max(axis=1) >= gb[:, :2] - _BBOX_PAD).all(axis=1)
+    )
+    pair_g, pair_cand = pair_g[ok], pair_cand[ok]
+    pair_cells, pair_klen = pair_cells[ok], pair_klen[ok]
+    P = pair_g.shape[0]
+    r0 = col.part_offsets[col.geom_offsets[gids]]
+    v0 = col.ring_offsets[r0]
+    rlen_g = col.ring_offsets[r0 + 1] - v0
+    n = int(rlen_g.max(initial=1))
+    jj = np.arange(n)[None, :]
+    rings_g = np.where(
+        (jj < rlen_g[:, None])[:, :, None],
+        col.xy[np.minimum(v0[:, None] + jj, col.num_vertices - 1)],
+        0.0,
+    )  # (G, n, 2) left-packed, zero pad
+    is_core = np.zeros(P, dtype=bool)
+    keep = np.zeros(P, dtype=bool)
+    clip_xy: list[np.ndarray] = []
+    clip_len = np.zeros(P, dtype=np.int64)
+    for s0 in range(0, P, _FAST_PAIR_CHUNK):
+        sl = slice(s0, s0 + _FAST_PAIR_CHUNK)
+        rg, rl = rings_g[pair_g[sl]], rlen_g[pair_g[sl]]
+        core, border = _classify_pairs(rg, rl, pair_cells[sl], pair_klen[sl])
+        is_core[sl] = core
+        b = np.nonzero(border)[0]
+        out, olen = _clip_rows_convex(
+            rg[b], rl[b], pair_cells[sl][b], pair_klen[sl][b]
+        )
+        # a border cell whose clip is empty is grazing contact: no chip
+        clip_len[s0 + b] = olen
+        clip_xy.append(out[np.arange(out.shape[1])[None, :] < olen[:, None]])
+        keep[sl] = core
+        keep[s0 + b] |= olen >= 3
+    kept = np.nonzero(keep)[0]
+    core_k = is_core[kept]
+    if keep_core_geoms:
+        L = pair_cells.shape[1]
+        core_rows = kept[core_k]
+        cmask = np.arange(L)[None, :] < pair_klen[core_rows][:, None]
+        core_xy = pair_cells[core_rows][cmask]
+        vlen = np.where(core_k, pair_klen[kept], clip_len[kept])
+    else:
+        core_xy = np.zeros((0, 2))
+        vlen = np.where(core_k, 0, clip_len[kept])
+    # vertices in chip order: core rows and border rows interleave
+    border_xy = (
+        np.concatenate(clip_xy) if clip_xy else np.zeros((0, 2))
+    )
+    ring_off = np.concatenate([[0], np.cumsum(vlen)]).astype(np.int64)
+    xy = np.zeros((int(ring_off[-1]), 2))
+    vert_core = np.repeat(core_k, vlen)
+    xy[vert_core] = core_xy
+    xy[~vert_core] = border_xy
+    C = kept.shape[0]
+    one = np.arange(C + 1, dtype=np.int64)
+    chips = PackedGeometry(
+        xy=xy, ring_offsets=ring_off, part_offsets=one, geom_offsets=one,
+        geom_type=np.full(C, int(GeometryType.POLYGON), np.uint8),
+        srid=col.srid[gids[pair_g[kept]]],
+    )
+    return ChipTable(
+        geom_id=gids[pair_g[kept]].astype(np.int64),
+        cell_id=pair_cand[kept].astype(np.int64),
+        is_core=core_k,
+        chips=chips,
+        has_geom=np.where(core_k, keep_core_geoms, True),
+    )
+
+
+def _take_chips(table: ChipTable, order: np.ndarray) -> ChipTable:
+    """``table``'s rows in ``order``: a CSR gather at each level of the
+    chip column (`PackedGeometry.take` appends geometry by geometry)."""
+
+    def gather(offsets, sel):
+        # the ranges offsets[sel] : offsets[sel + 1], one after another
+        lens = offsets[sel + 1] - offsets[sel]
+        new_off = np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
+        idx = np.repeat(offsets[sel] - new_off[:-1], lens) + np.arange(
+            new_off[-1]
+        )
+        return idx, new_off
+
+    c = table.chips
+    parts, geom_off = gather(c.geom_offsets, order)
+    rings, part_off = gather(c.part_offsets, parts)
+    verts, ring_off = gather(c.ring_offsets, rings)
+    return ChipTable(
+        geom_id=table.geom_id[order],
+        cell_id=table.cell_id[order],
+        is_core=table.is_core[order],
+        chips=PackedGeometry(
+            xy=c.xy[verts], ring_offsets=ring_off, part_offsets=part_off,
+            geom_offsets=geom_off, geom_type=c.geom_type[order],
+            srid=c.srid[order],
+            z=None if c.z is None else c.z[verts],
+            geom_has_z=c.geom_has_z[order],
+        ),
+        has_geom=table.has_geom[order],
+    )
+
+
 def tessellate(
     col: PackedGeometry,
     index: IndexSystem,
@@ -741,9 +1015,26 @@ def tessellate(
 
     Reference analog: `grid_tessellateexplode` / `MosaicExplode.eval`
     (`expressions/index/MosaicExplode.scala:70-79`) — but batch-first: one
-    call chips a whole column.
+    call chips a whole column. Polygons of one small ring (a layer of
+    building footprints is all but made of them) are classified and
+    clipped over all their (polygon, candidate cell) pairs at once
+    (`_fast_polygon_chips`); every other geometry goes through its
+    per-geometry emitter. Rows come out by geometry, then by candidate
+    cell, whichever path made them.
     """
     resolution = index.resolution_arg(resolution)
+    with _trace.span("index.tessellate", geometries=len(col)) as sp:
+        table = _tessellate(col, index, resolution, keep_core_geoms)
+        sp.set(chips=len(table), core_chips=table.core_count())
+    return table
+
+
+def _tessellate(
+    col: PackedGeometry,
+    index: IndexSystem,
+    resolution: int,
+    keep_core_geoms: bool,
+) -> ChipTable:
     geom_id: list[int] = []
     cell: list[int] = []
     core: list[bool] = []
@@ -752,24 +1043,46 @@ def tessellate(
     bounds = col.bounds()
     bases = [col.geometry_type(g).base for g in range(len(col))]
     # batch the index-system work for ALL polygons up front: candidates in
-    # one fused call, then one cell_boundary + dedupe over every candidate
-    poly_ids = [g for g in range(len(col)) if bases[g] == GeometryType.POLYGON]
+    # one fused call, then one cell_boundary + dedupe over every distinct
+    # candidate (neighbouring polygons share most of theirs)
+    poly_ids = np.asarray(
+        [g for g in range(len(col)) if bases[g] == GeometryType.POLYGON],
+        dtype=np.int64,
+    )
     cand_of: dict[int, np.ndarray] = {}
     cells_of: dict[int, np.ndarray] = {}
     klen_of: dict[int, np.ndarray] = {}
-    if poly_ids:
+    fast: ChipTable | None = None
+    if poly_ids.size:
         cand_lists = index.polyfill_candidates_batch(bounds[poly_ids], resolution)
-        sizes = [c.shape[0] for c in cand_lists]
-        if sum(sizes):
+        sizes = np.asarray([c.shape[0] for c in cand_lists], dtype=np.int64)
+        if sizes.sum():
             all_cand = np.concatenate(cand_lists)
-            bnds = np.asarray(index.cell_boundary(all_cand), dtype=np.float64)
-            cells_all, klen_all = _dedupe_boundaries_batch(bnds)
-            off = np.cumsum([0] + sizes)
-            for t, g in enumerate(poly_ids):
+            uniq, inv = np.unique(all_cand, return_inverse=True)
+            bnds = np.asarray(index.cell_boundary(uniq), dtype=np.float64)
+            cells_u, klen_u = _dedupe_boundaries_batch(bnds)
+            off = np.concatenate([[0], np.cumsum(sizes)])
+            simple = _simple_polygons(col, poly_ids)
+            if simple.any():
+                pair_t = np.repeat(np.arange(poly_ids.size), sizes)
+                sel = simple[pair_t]
+                # polygon t's rank among the simple ones indexes `gids`
+                rank = np.cumsum(simple) - 1
+                fast = _fast_polygon_chips(
+                    col, poly_ids[simple], bounds[poly_ids[simple]],
+                    rank[pair_t[sel]], all_cand[sel],
+                    cells_u[inv[sel]], klen_u[inv[sel]], keep_core_geoms,
+                )
+            for t in np.nonzero(~simple)[0]:
+                g = int(poly_ids[t])
                 sl = slice(off[t], off[t + 1])
                 cand_of[g] = cand_lists[t]
-                cells_of[g] = cells_all[sl]
-                klen_of[g] = klen_all[sl]
+                cells_of[g] = cells_u[inv[sl]]
+                klen_of[g] = klen_u[inv[sl]]
+    # (a simple polygon that left no chip at all is done too)
+    done = np.zeros(len(col), dtype=bool)
+    if fast is not None:
+        done[poly_ids[simple]] = True
     # batch cell assignment for ALL point geometries in one call
     point_ids = [
         g for g in range(len(col)) if bases[g] == GeometryType.POINT
@@ -788,6 +1101,8 @@ def tessellate(
     empty = (np.zeros(0, np.int64), np.zeros((0, 1, 2)), np.zeros(0, np.int64))
     for g in range(len(col)):
         base = bases[g]
+        if done[g]:
+            continue
         if base == GeometryType.POLYGON:
             cand = cand_of.get(g, empty[0])
             _polygon_chips(
@@ -823,13 +1138,25 @@ def tessellate(
             )
         else:
             raise ValueError(f"cannot tessellate geometry type {base}")
-    return ChipTable(
+    table = ChipTable(
         geom_id=np.asarray(geom_id, dtype=np.int64),
         cell_id=np.asarray(cell, dtype=np.int64),
         is_core=np.asarray(core, dtype=bool),
         chips=builder.build(),
         has_geom=np.asarray(hasgeom, dtype=bool),
     )
+    if fast is None or not len(fast):
+        return table
+    if not len(table):
+        return fast
+    both = ChipTable(
+        geom_id=np.concatenate([fast.geom_id, table.geom_id]),
+        cell_id=np.concatenate([fast.cell_id, table.cell_id]),
+        is_core=np.concatenate([fast.is_core, table.is_core]),
+        chips=concat_packed([fast.chips, table.chips]),
+        has_geom=np.concatenate([fast.has_geom, table.has_geom]),
+    )
+    return _take_chips(both, np.argsort(both.geom_id, kind="stable"))
 
 
 def tessellate_subset(

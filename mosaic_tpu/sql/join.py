@@ -418,9 +418,33 @@ def build_chip_index(
     edge_cap: int = EDGE_CAP,
 ) -> ChipIndex:
     """Host: compile a ChipTable into the device join index."""
-    C = len(table)
-    if C == 0:
+    if len(table) == 0:
         raise ValueError("empty chip table")
+    with _obs_trace.span("index.build", chips=len(table)) as sp:
+        idx = _build_chip_index(
+            table, dtype, max_chips_per_cell, recenter, edge_cap
+        )
+        sp.set(
+            cells=idx.num_cells, heavy=idx.num_heavy_cells,
+            convex=idx.num_convex_cells,
+            E1=int(idx.cell_edges.shape[1]),
+            M1=int(idx.cell_slot_geom.shape[1]),
+            E2=int(idx.heavy_edges.shape[1]),
+            M2=int(idx.heavy_slot_geom.shape[1]),
+            # a cell past MAX_SLOTS chips a tier is refused, not spilled
+            spilled=0,
+            table_bytes=sum(
+                int(getattr(a, "nbytes", 0))
+                for a in jax.tree_util.tree_leaves(idx)
+            ),
+        )
+    return idx
+
+
+def _build_chip_index(
+    table, dtype, max_chips_per_cell, recenter, edge_cap
+) -> ChipIndex:
+    C = len(table)
     order = np.argsort(table.cell_id, kind="stable")
     sorted_cells = table.cell_id[order]
     uniq, starts, counts = np.unique(
@@ -434,9 +458,9 @@ def build_chip_index(
     U = uniq.size
     rows = np.full((U, M), -1, dtype=np.int32)
     chip_cell_slot = np.full(C, -1, dtype=np.int64)  # chip -> cell row u
-    for i, (s, c) in enumerate(zip(starts, counts)):
-        rows[i, :c] = order[s : s + c]
-        chip_cell_slot[order[s : s + c]] = i
+    cell_of_sorted = np.repeat(np.arange(U), counts)
+    rows[cell_of_sorted, np.arange(C) - np.repeat(starts, counts)] = order
+    chip_cell_slot[order] = cell_of_sorted
     # only border rows need vertices: blank core chip geometries before
     # padding so V is set by the clipped border chips, not the cell polygons
     chips = table.chips
@@ -530,10 +554,18 @@ def build_chip_index(
     M1 = max(1, int(n1_per_cell.max(initial=0)))
     M2 = max(1, int(n2_per_cell.max(initial=0)))
     if M1 > MAX_SLOTS or M2 > MAX_SLOTS:
+        # a step finer cuts a cell into 7 (H3) or 4 (a square grid), and a
+        # cell's chips with it at best: the coarsest resolution that can
+        # hold the layer is `lo` steps finer, `hi` at the thinner rate
+        over = max(M1, M2) / MAX_SLOTS
+        lo = int(np.ceil(np.log(over) / np.log(7.0)))
+        hi = int(np.ceil(np.log(over) / np.log(4.0)))
         raise ValueError(
-            f"a cell holds more than {MAX_SLOTS} chips per probe tier "
-            f"(M1={M1}, M2={M2}); parity bits are uint32 — merge chips or "
-            "raise the tessellation resolution"
+            f"a cell holds more than {MAX_SLOTS} chips per probe tier (the "
+            f"fullest cell: {M1} chips in tier 1, {M2} in tier 2); parity "
+            f"bits are uint32 — merge chips, or tessellate {lo} "
+            f"resolution{'s' if lo > 1 else ''} finer at the least "
+            f"({hi} on a square grid)"
         )
     slot_geom = np.full((U, M1), -1, dtype=np.int32)
     slot_core = np.zeros((U, M1), dtype=bool)
@@ -1060,23 +1092,40 @@ def _heavy_tier(
     h2 = jnp.maximum(hs[src2], 0)
     # one (K2, 2) gather, not two serialized column gathers (see tier 1)
     pq2 = jnp.stack([px, py], axis=1)[src2]
-    if engine == "pallas":
-        from ..kernels.pip import pip_heavy_tiled
 
-        rows2 = jnp.where(valid2, h2, -1)
-        best2k, near2 = pip_heavy_tiled(
-            pq2[:, 0], pq2[:, 1], rows2,
-            index.heavy_edges, index.heavy_ebits, index.heavy_slot_geom,
-            eps2=eps2, interpret=interpret_kernels(),
-        )
-        if near2 is None and eps2 is not None:  # pragma: no cover
-            near2 = jnp.zeros(pq2.shape[0], bool)
+    def _tier2(px_c, py_c, h_c, valid_c):
+        """The wide rows' probe for compacted rows ``h_c``. Row-wise."""
+        if engine == "pallas":
+            from ..kernels.pip import pip_heavy_tiled
+
+            best_c, near_c = pip_heavy_tiled(
+                px_c, py_c, jnp.where(valid_c, h_c, -1),
+                index.heavy_edges, index.heavy_ebits, index.heavy_slot_geom,
+                eps2=eps2, interpret=interpret_kernels(),
+            )
+            if near_c is None and eps2 is not None:  # pragma: no cover
+                near_c = jnp.zeros(px_c.shape[0], bool)
+            return best_c, near_c
+        hedges, hebits = index.heavy_edges[h_c], index.heavy_ebits[h_c]
+        hgeoms = index.heavy_slot_geom[h_c]
+        r2 = _ray_parity(px_c, py_c, hedges, hebits, eps2=eps2)
+        par2, near_c = r2 if eps2 is not None else (r2, None)
+        # invalid slots never land (drop)
+        return _slot_best(par2, hgeoms), near_c
+
+    # rows are independent, so chunks are exact (see `_tier1_rows`); a
+    # stream passes no cap, so K2 is the batch, and 4M gathered rows of
+    # E2 = 80 edges are 5 GB before lane padding. The chunk holds as many
+    # edges as tier 1's holds at its widest row
+    chunk2 = max(
+        128,
+        _TIER1_CHUNK * EDGE_CAP // int(index.heavy_edges.shape[1]) // 128 * 128,
+    )
+    cols2 = (pq2[:, 0], pq2[:, 1], h2, valid2)
+    if K2 > chunk2:
+        best2k, near2 = _map_rows(_tier2, chunk2, *cols2)
     else:
-        hedges, hebits = index.heavy_edges[h2], index.heavy_ebits[h2]
-        hgeoms = index.heavy_slot_geom[h2]
-        r2 = _ray_parity(pq2[:, 0], pq2[:, 1], hedges, hebits, eps2=eps2)
-        par2, near2 = r2 if eps2 is not None else (r2, None)
-        best2k = _slot_best(par2, hgeoms)  # invalid slots never land (drop)
+        best2k, near2 = _tier2(*cols2)
     # unique no-combiner scatter back (see _compact): valid src2 row ids
     # are unique; invalid slots drop via distinct out-of-bounds dests
     dest2 = jnp.where(
@@ -1244,6 +1293,33 @@ def pip_join_points(
     before jit ever sees the argument. Convex-lane overflow returns
     :data:`OVERFLOW`, exactly like the other caps.
     """
+    out, near, _ = _join_points(
+        points, pcells, index, heavy_cap, found_cap, edge_eps2, writeback,
+        probe, convex_cap, with_heavy=False,
+    )
+    return out if edge_eps2 is None else (out, near)
+
+
+def pip_join_points_heavy(
+    points, pcells, index, heavy_cap=None, found_cap=None, probe="scatter",
+    convex_cap=None,
+):
+    """:func:`pip_join_points` and, beside the rows, the (N,) bool mask of
+    the points whose cell is heavy (the rows that need tier 2) — the
+    stream folds its count. For an index with heavy cells only."""
+    out, _, heavy = _join_points(
+        points, pcells, index, heavy_cap, found_cap, None, "scatter", probe,
+        convex_cap, with_heavy=True,
+    )
+    return out, heavy
+
+
+def _join_points(
+    points, pcells, index, heavy_cap, found_cap, edge_eps2, writeback, probe,
+    convex_cap, *, with_heavy,
+):
+    """The join behind :func:`pip_join_points`: ``(out, near | None,
+    heavy | None)``; ``heavy`` only where ``with_heavy`` and H > 0."""
     if writeback not in ("scatter", "gather", "direct"):
         raise ValueError(
             f"writeback must be scatter|gather|direct, got {writeback!r}"
@@ -1348,9 +1424,10 @@ def pip_join_points(
         with jax.named_scope("pip.writeback"):
             out = jnp.where(best == _SENTINEL, -1, best).astype(jnp.int32)
             out = jnp.where(best == _OVF_MARK, OVERFLOW, out)
-            if banded:
-                return out, near1 & found
-            return out
+            return (
+                out, near1 & found if banded else None,
+                hs >= 0 if H and with_heavy else None,
+            )
 
     with jax.named_scope("pip.compact"):
         light = found if conv is None else (found & ~conv)
@@ -1461,8 +1538,12 @@ def pip_join_points(
                     near = near.at[wdest3].set(
                         near3, unique_indices=True, mode="drop"
                     )
-            return out, near
-        return out
+        else:
+            near = None
+        heavy = None
+        if H and with_heavy:
+            heavy = found & (index.cell_heavy[jnp.maximum(u, 0)] >= 0)
+        return out, near, heavy
 
 
 # the jitted join/counts/compact executables and the cell-assignment

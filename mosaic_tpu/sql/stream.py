@@ -89,6 +89,7 @@ from .join import (
     ChipIndex,
     host_join_with_cells,
     pip_join_points,
+    pip_join_points_heavy,
     resolve_probe_mode,
     tier1_compacts,
 )
@@ -105,6 +106,32 @@ def fold_stats(out: jax.Array) -> jax.Array:
             (out == -2).sum().astype(jnp.int32),
         ]
     )
+
+
+#: the stream assigns cells in f32 while one f32 ulp of the layer's
+#: coordinates stays under this share of the resolution's cell radius
+#: (`IndexSystem.buffer_radius`). Counted on 400,000 uniform points over the
+#: NYC box (|lon| ~ 74, ulp 7.6e-6 deg): the f32 cell differs from the f64
+#: cell on 0.14% of points at H3 res 9 (share 3.8e-3), 0.96% at res 11
+#: (2.7e-2), 2.6% at res 12 (7.1e-2) — 0.37 of the share, so the threshold
+#: admits under 0.2% of points moved to a neighbour cell, where they are
+#: tested against that cell's chips and mostly come out unmatched
+CELL_F32_MAX_ULP_SHARE = 5e-3
+
+
+def stream_cell_dtype(index: ChipIndex, index_system, resolution: int):
+    """The dtype a stream assigns cells in: the one rule, from static
+    facts of the index (where its cells lie) and the resolution, in the
+    manner of `tier1_compacts`. f32 where its rounding moves few points
+    across a cell edge (`CELL_F32_MAX_ULP_SHARE`), f64 (emulated on the
+    TPU, ~46 bits) where the cells are too small for the coordinates'
+    magnitude: an H3 res-9 layer over New York keeps f32, a res-11 one
+    does not."""
+    cells = np.asarray(index.cells[:: max(index.num_cells - 1, 1)])
+    centres = np.asarray(index_system.cell_center(cells), dtype=np.float64)
+    ulp = float(np.spacing(np.float32(np.abs(centres).max())))
+    share = ulp / float(index_system.buffer_radius(resolution))
+    return jnp.float32 if share <= CELL_F32_MAX_ULP_SHARE else jnp.float64
 
 
 def ring_from_host(batches) -> jax.Array:
@@ -272,18 +299,25 @@ def build_stream_programs(
             c = index_system.point_to_cell(pts.astype(cell_dtype), resolution)
             return c.astype(jnp.int64)
 
-    def join_one(pts, cells, chip_index):
-        with jax.named_scope("pip.recentre"):
-            shifted = (pts - chip_index.border.shift).astype(dtype)
-        return pip_join_points(
-            shifted,
-            cells,
-            chip_index,
-            heavy_cap=heavy_cap,
-            found_cap=found_cap,
-            probe=probe,
-            convex_cap=convex_cap,
-        )
+    def recentred(join_points):
+        def joined(pts, cells, chip_index):
+            with jax.named_scope("pip.recentre"):
+                shifted = (pts - chip_index.border.shift).astype(dtype)
+            return join_points(
+                shifted,
+                cells,
+                chip_index,
+                heavy_cap=heavy_cap,
+                found_cap=found_cap,
+                probe=probe,
+                convex_cap=convex_cap,
+            )
+
+        return joined
+
+    join_one = recentred(pip_join_points)
+    # the rows and the mask of rows whose cell is heavy (H > 0 only)
+    join_heavy = recentred(pip_join_points_heavy)
 
     if mesh is None:
         join = join_one
@@ -291,13 +325,24 @@ def build_stream_programs(
         join = _dispatch.sharded_pointwise(
             join_one, mesh, check_rep=_dispatch.probe_check_rep(probe)
         )
+        join_heavy = _dispatch.sharded_pointwise(
+            join_heavy, mesh, check_rep=_dispatch.probe_check_rep(probe)
+        )
 
-    def fold(acc, out):
+    def fold(acc, out, heavy=None):
         with jax.named_scope("stream.fold"):
-            return acc + fold_stats(out)
+            if heavy is None:
+                return acc + fold_stats(out)
+            return acc + jnp.concatenate(
+                [fold_stats(out), heavy.sum(dtype=jnp.int32)[None]]
+            )
 
     def loop(ring, chip_index, nb: int, collect: bool):
         k = ring.shape[0]
+        # over an index with heavy cells the fold has a fourth entry, the
+        # count of rows whose cell is heavy (`metrics["heavy_rows"]`); an
+        # index without them keeps its three, and not one instruction more
+        counted = bool(chip_index.heavy_edges.shape[0])
 
         def slot(i):
             with jax.named_scope("stream.slot"):
@@ -305,6 +350,12 @@ def build_stream_programs(
                     ring, i % k, axis=0, keepdims=False
                 )
 
+        def joined(pts, cells):
+            if counted:
+                return join_heavy(pts, cells, chip_index)
+            return join(pts, cells, chip_index), None
+
+        acc0 = jnp.zeros(4 if counted else 3, jnp.int32)
         if prefetch:
 
             def body(carry, i):
@@ -312,21 +363,21 @@ def build_stream_programs(
                 # join batch i against the cells prefetched at i-1;
                 # assign batch i+1's cells in the SAME program so XLA
                 # overlaps the cell pipeline with the probe
-                out = join(slot(i), cells_cur, chip_index)
+                out, heavy = joined(slot(i), cells_cur)
                 cells_next = assign(slot(i + 1))
-                return (fold(acc, out), cells_next), (
+                return (fold(acc, out, heavy), cells_next), (
                     out if collect else None
                 )
 
-            carry0 = (jnp.zeros(3, jnp.int32), assign(ring[0]))
+            carry0 = (acc0, assign(ring[0]))
         else:
 
             def body(carry, i):
                 pts = slot(i)
-                out = join(pts, assign(pts), chip_index)
-                return fold(carry, out), (out if collect else None)
+                out, heavy = joined(pts, assign(pts))
+                return fold(carry, out, heavy), (out if collect else None)
 
-            carry0 = jnp.zeros(3, jnp.int32)
+            carry0 = acc0
         carry, outs = jax.lax.scan(
             body, carry0, jnp.arange(nb, dtype=jnp.int32)
         )
@@ -428,7 +479,7 @@ class StreamJoin:
         *,
         found_cap: int | None = None,
         heavy_cap: int | None = None,
-        cell_dtype=jnp.float32,
+        cell_dtype=None,
         prefetch: bool = True,
         probe: "str | None" = None,
         convex_cap: int | None = None,
@@ -462,6 +513,13 @@ class StreamJoin:
         probe = resolve_probe_mode(probe)
         self.probe, self.convex_cap = probe, convex_cap
         self.mesh = _dispatch.resolve_mesh(mesh)
+        # the cell-assignment precision: an explicit dtype wins, else the
+        # rule (resolved here, like the probe: a compiled program keeps it)
+        if cell_dtype is None:
+            cell_dtype = stream_cell_dtype(index, index_system, resolution)
+        #: the dtype cells are assigned in, by name (in every result's
+        #: ``metrics`` and on the ``stream.run`` span)
+        self.cell_dtype = jnp.dtype(cell_dtype).name
 
         progs = _dispatch.stream_programs(
             index_system, resolution, dtype=dtype, cell_dtype=cell_dtype,
@@ -560,7 +618,7 @@ class StreamJoin:
         ring_bytes = int(ring.nbytes)  # before the loop may delete it
         with _trace.span(
             "stream.run", n_batches=n_batches, batch=batch, ring_k=k,
-        ):
+        ) as sp:
             self._register_stages(ring, n_batches, collect)
             t0 = time.perf_counter()
             # the launch returns once the loop is enqueued; the pull of
@@ -579,6 +637,12 @@ class StreamJoin:
                 acc_np = np.asarray(acc)  # the loop's only host pull
             wall = time.perf_counter() - t0
             n_points = n_batches * batch
+            # the fold's fourth entry, where the index has heavy cells
+            heavy_rows = int(acc_np[3]) if acc_np.shape[0] > 3 else 0
+            sp.set(
+                rows=n_points, heavy_rows=heavy_rows,
+                cell_dtype=self.cell_dtype,
+            )
             if self.donate_ring:
                 donation = {
                     "donate_ring": True,
@@ -609,6 +673,8 @@ class StreamJoin:
                     batch // (1 if self.mesh is None else self.mesh.size),
                     self.found_cap, self.probe,
                 ),
+                "heavy_rows": heavy_rows,
+                "cell_dtype": self.cell_dtype,
             },
         )
 

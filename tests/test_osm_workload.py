@@ -8,6 +8,13 @@ resolution where cell size ~ building size. Synthetic buildings
 (rotated rectangles + L-shapes, deterministic) stand in for the OSM
 extract; structural digests are golden-pinned and area conservation is
 asserted per building.
+
+This is the CPU-sized copy (800 footprints, tessellation only). The
+full-size run is the benchmark's configuration `osm-buildings-h3r11`
+(`benchmark/configs/osm-buildings-h3r11.json`, cell `osm-buildings.join`):
+a borough's fabric at H3 res 11, tessellated, indexed and joined to pings
+through `StreamJoin.run` on the chip; `tests/test_building_join.py` holds
+its pieces at small size.
 """
 
 import json
