@@ -562,6 +562,15 @@ class DispatchCore:
             self.writeback,
         )
 
+    def tier2_compacted(self, bucket: int) -> bool:
+        """`join.tier2_compacted` of the same program: with full-bucket
+        caps, false of every probe."""
+        fcap, hcap, _ = self.caps(bucket)
+        return _join_mod().tier2_compacted(
+            self._shard_rows(bucket), self.index.num_heavy_cells, fcap,
+            hcap, self.probe, self.writeback,
+        )
+
     def signature(self, bucket: int) -> tuple:
         fcap, hcap, ccap = self.caps(bucket)
         return dispatch_signature(

@@ -404,6 +404,9 @@ class ServeEngine:
         out["compile_signatures"] = len(self.core.signatures)
         out["cold_compiles"] = self.core.cold_compiles
         out["compacted"] = self.core.compacted(self.core.ladder.max_bucket)
+        out["tier2_compacted"] = self.core.tier2_compacted(
+            self.core.ladder.max_bucket
+        )
         out["dispatches"] = self._dispatches
         out["padded_rows"] = self._padded_rows
         out["h2d_bytes"] = self.core.transfer_bytes["h2d"]

@@ -28,8 +28,12 @@ belongs to. This kills the max-verts padding blow-up that a per-chip
 force every cell row to carry V=309 — ~10 GB of gather per 1M points, which
 made every >=1M batch fail TPU compilation in round 2). Cells whose chips
 carry more than ``EDGE_CAP`` edges (<8% of NYC cells) divert to a HEAVY side
-table: points landing in them are stream-compacted (cumsum + scatter, all
-static shapes) and only that compacted subset pays the wide heavy gather.
+table (tier 2). Under a ``heavy_cap`` below the row count the points landing
+in them are stream-compacted (cumsum + scatter, all static shapes) and only
+that compacted subset pays the wide heavy gather; without one (the stream,
+full-bucket caps) compaction would move every row into as many slots and
+back, so every row fetches a wide row in place and the rows of no heavy cell
+are masked (:func:`tier2_compacts`, as :func:`tier1_compacts` one tier up).
 """
 
 from __future__ import annotations
@@ -1071,11 +1075,18 @@ def _tier1_rows_gather(us: jax.Array, index: "ChipIndex"):
 
 
 @jax.named_scope("pip.tier2")
-def _heavy_tier(
-    px, py, hs, index, heavy_cap, k2_default, out_len, eps2, engine="gather",
-):
-    """Tier 2, shared by every probe plumbing mode: compact the rows whose
-    cell is heavy, probe the wide rows, scatter back to ``out_len``.
+def _heavy_tier(px, py, hs, index, heavy_cap, eps2, engine="gather"):
+    """Tier 2, shared by every probe plumbing mode: probe the wide rows of
+    the points whose cell is heavy (``hs >= 0``, a heavy-table row each).
+
+    Where ``heavy_cap`` is under the row count (:func:`tier2_compacts`)
+    those rows are compacted into ``heavy_cap`` slots, probed there and
+    scattered back; rows beyond the cap overflow. Where it is not (no cap,
+    or a cap of all the rows: the stream, full-bucket caps) compaction
+    would move every row into as many slots and back, so each row probes
+    the wide row of ``max(hs, 0)`` in place and rows with ``hs < 0`` are
+    masked; no row can overflow. Rows are independent and nothing is
+    converted: the answers are the same bit for bit.
 
     ``engine="pallas"`` runs the probe through the tiled
     :func:`~mosaic_tpu.kernels.pip.pip_heavy_tiled` kernel (heavy tables
@@ -1084,17 +1095,12 @@ def _heavy_tier(
     on the CPU platform (`runtime.platform.interpret_kernels`), so CPU
     tests exercise the same kernel and every chip compiles it.
 
-    Returns (best2 (out_len,), over2 (out_len,) overflow mask,
-    near2 (out_len,) | None when ``eps2`` is None)."""
-    K2 = int(heavy_cap) if heavy_cap else k2_default
-    K2 = max(8, min(K2, k2_default))
-    src2, valid2, over2, _ = _compact(hs >= 0, K2)
-    h2 = jnp.maximum(hs[src2], 0)
-    # one (K2, 2) gather, not two serialized column gathers (see tier 1)
-    pq2 = jnp.stack([px, py], axis=1)[src2]
+    Returns (best2, over2 overflow mask, near2 | None when ``eps2`` is
+    None), each of ``hs``'s length."""
+    rows = hs.shape[0]
 
     def _tier2(px_c, py_c, h_c, valid_c):
-        """The wide rows' probe for compacted rows ``h_c``. Row-wise."""
+        """The wide rows' probe for rows ``h_c``. Row-wise."""
         if engine == "pallas":
             from ..kernels.pip import pip_heavy_tiled
 
@@ -1110,34 +1116,52 @@ def _heavy_tier(
         hgeoms = index.heavy_slot_geom[h_c]
         r2 = _ray_parity(px_c, py_c, hedges, hebits, eps2=eps2)
         par2, near_c = r2 if eps2 is not None else (r2, None)
-        # invalid slots never land (drop)
+        # invalid rows never land (dropped, or masked in place)
         return _slot_best(par2, hgeoms), near_c
 
-    # rows are independent, so chunks are exact (see `_tier1_rows`); a
-    # stream passes no cap, so K2 is the batch, and 4M gathered rows of
-    # E2 = 80 edges are 5 GB before lane padding. The chunk holds as many
-    # edges as tier 1's holds at its widest row
-    chunk2 = max(
-        128,
-        _TIER1_CHUNK * EDGE_CAP // int(index.heavy_edges.shape[1]) // 128 * 128,
-    )
-    cols2 = (pq2[:, 0], pq2[:, 1], h2, valid2)
-    if K2 > chunk2:
-        best2k, near2 = _map_rows(_tier2, chunk2, *cols2)
-    else:
-        best2k, near2 = _tier2(*cols2)
+    def _tier2_rows(*cols):
+        # rows are independent, so chunks are exact (see `_tier1_rows`); a
+        # stream passes no cap, so tier 2 sees the batch, and 4M gathered
+        # rows of E2 = 80 edges are 5 GB before lane padding. The chunk
+        # holds as many edges as tier 1's holds at its widest row
+        chunk2 = max(
+            128,
+            _TIER1_CHUNK * EDGE_CAP
+            // int(index.heavy_edges.shape[1]) // 128 * 128,
+        )
+        if cols[0].shape[0] > chunk2:
+            return _map_rows(_tier2, chunk2, *cols)
+        return _tier2(*cols)
+
+    if not tier2_compacts(rows, heavy_cap):
+        # in place: every row fetches a wide row, the rows of no heavy
+        # cell (row 0, masked) included; no row can overflow tier 2
+        heavy = hs >= 0
+        best2, near2 = _tier2_rows(px, py, jnp.maximum(hs, 0), heavy)
+        return (
+            jnp.where(heavy, best2, _SENTINEL),
+            jnp.zeros(rows, bool),
+            near2 & heavy if eps2 is not None else None,
+        )
+
+    K2 = _cap_rows(heavy_cap, rows)
+    src2, valid2, over2, _ = _compact(hs >= 0, K2)
+    h2 = jnp.maximum(hs[src2], 0)
+    # one (K2, 2) gather, not two serialized column gathers (see tier 1)
+    pq2 = jnp.stack([px, py], axis=1)[src2]
+    best2k, near2 = _tier2_rows(pq2[:, 0], pq2[:, 1], h2, valid2)
     # unique no-combiner scatter back (see _compact): valid src2 row ids
     # are unique; invalid slots drop via distinct out-of-bounds dests
     dest2 = jnp.where(
-        valid2, src2, out_len + jnp.arange(src2.shape[0], dtype=jnp.int32)
+        valid2, src2, rows + jnp.arange(src2.shape[0], dtype=jnp.int32)
     )
     best2 = (
-        jnp.full(out_len, _SENTINEL, dtype=jnp.int32)
+        jnp.full(rows, _SENTINEL, dtype=jnp.int32)
         .at[dest2]
         .set(best2k, unique_indices=True, mode="drop")
     )
     near_sc = (
-        jnp.zeros(out_len, bool)
+        jnp.zeros(rows, bool)
         .at[dest2]
         .set(near2, unique_indices=True, mode="drop")
         if eps2 is not None
@@ -1180,6 +1204,12 @@ def resolve_probe_mode(probe: str) -> str:
     return probe
 
 
+def _cap_rows(cap: "int | None", rows: int) -> int:
+    """Slots a tier compacts ``rows`` rows into under ``cap`` (None or 0:
+    no cap): at least 8, at most ``rows`` — unless ``rows`` is under 8."""
+    return max(8, min(int(cap) if cap else rows, rows))
+
+
 def tier1_compacts(
     n: int, found_cap: "int | None", probe: str, writeback: str = "scatter"
 ) -> bool:
@@ -1199,7 +1229,36 @@ def tier1_compacts(
         return True
     if writeback == "direct":
         return False
-    return max(8, min(int(found_cap) if found_cap else n, n)) < n
+    return _cap_rows(found_cap, n) < n
+
+
+def tier2_compacts(rows: int, heavy_cap: "int | None") -> bool:
+    """Whether tier 2 compacts the ``rows`` rows it is handed (the batch
+    where tier 1 ran in place, tier 1's ``K1`` slots where it compacted)
+    before it probes the wide rows: :func:`tier1_compacts`' rule one tier
+    down. With ``K2 = max(8, min(heavy_cap or rows, rows)) >= rows`` (no
+    cap: the stream; `DispatchCore`'s full-bucket caps; `pip_join` where
+    its count sizes the cap at the batch) compaction selects nothing, so
+    `_heavy_tier` probes every row in place. Read on v5e in PERF.md
+    section 6, PR 32."""
+    return _cap_rows(heavy_cap, rows) < rows
+
+
+def tier2_compacted(
+    n: int, num_heavy_cells: int, found_cap: "int | None",
+    heavy_cap: "int | None", probe: str, writeback: str = "scatter",
+) -> bool:
+    """:func:`tier2_compacts` of an ``n``-row `pip_join_points` program,
+    for the counters beside ``compacted``: False where the index has no
+    heavy cell (no tier 2 is compiled)."""
+    if not num_heavy_cells:
+        return False
+    rows = (
+        _cap_rows(found_cap, n)
+        if tier1_compacts(n, found_cap, probe, writeback)
+        else n
+    )
+    return tier2_compacts(rows, heavy_cap)
 
 
 def _map_rows(fn, chunk: int, *cols):
@@ -1243,7 +1302,10 @@ def pip_join_points(
     Jittable (``heavy_cap``/``found_cap`` static); shard the point axis over
     a mesh and replicate ``index``. Probe = hash lookup (1 gather), then a
     flat bounded edge gather + XOR crossing parity (tier 1); points in heavy
-    cells are compacted for the tier-2 gather. Where ``found_cap`` is under
+    cells also probe the heavy table's wide rows (tier 2: compacted into
+    ``heavy_cap`` slots first where that is under the rows tier 1 hands
+    over, in place where it is not — :func:`tier2_compacts`, the same rule
+    one tier down, the same answers). Where ``found_cap`` is under
     the row count, the points whose cell exists in the index are
     stream-compacted into ``found_cap`` slots first, so tier 1 tests that
     many rows and the misses skip all edge work. Where it is not (no cap,
@@ -1276,7 +1338,8 @@ def pip_join_points(
     share; compacted into N - 1 slots 183.0 (``gather`` 186.1), into N/2
     121.2 (136.0), into N/4 89.8 (110.6), into N/16 68.5 (93.2):
     compaction costs 57 ms that scale with N plus 126 ms x cap / N, and
-    pays under a cap of about 0.29 N.
+    pays under a cap of about 0.29 N. Tier 2's readings (E2 = 88, a third
+    of the rows heavy): PERF.md section 6, PR 32.
 
     ``probe="adaptive"`` switches on per-cell density routing inside this
     one jitted program: light cells keep the tier-1 path above, heavy
@@ -1414,7 +1477,7 @@ def _join_points(
             if H:
                 hs = jnp.where(found, heavy_d, -1)
                 best2, over2, near_sc = _heavy_tier(
-                    points[:, 0], points[:, 1], hs, index, heavy_cap, N, N,
+                    points[:, 0], points[:, 1], hs, index, heavy_cap,
                     edge_eps2,
                 )
                 best = jnp.minimum(best, best2)
@@ -1431,8 +1494,7 @@ def _join_points(
 
     with jax.named_scope("pip.compact"):
         light = found if conv is None else (found & ~conv)
-        K1 = int(found_cap) if found_cap else N
-        K1 = max(8, min(K1, N))
+        K1 = _cap_rows(found_cap, N)
         src1, valid1, over1, pos1 = _compact(light, K1)
         us = jnp.maximum(u[src1], 0)  # (K1,)
         # ONE (K1, 2) row gather: indexing the columns separately makes XLA
@@ -1446,11 +1508,11 @@ def _join_points(
 
     if H:
         with jax.named_scope("pip.tier2"):
-            # tier 2: compact again to the points whose cell is heavy
+            # tier 2: the slots whose cell is heavy (compacted again
+            # where `heavy_cap` is under K1: `tier2_compacts`)
             hs = jnp.where(valid1, heavy1, -1)
             best2, over2, near_sc = _heavy_tier(
-                px, py, hs, index, heavy_cap, K1, K1, edge_eps2,
-                engine=heavy_engine,
+                px, py, hs, index, heavy_cap, edge_eps2, engine=heavy_engine,
             )
             best1 = jnp.minimum(best1, best2)
             # an overflowed tier-2 point has an unknown answer even if tier 1
@@ -1464,8 +1526,7 @@ def _join_points(
         # convex lane: compact, y-bucket, probe at most EB edges/point.
         # The single-chip eligibility contract makes `parity bit 0 set ->
         # that chip's geom` exactly _slot_best on the cell's tier-1 row.
-        K3 = int(convex_cap) if convex_cap else N
-        K3 = max(8, min(K3, N))
+        K3 = _cap_rows(convex_cap, N)
         with jax.named_scope("pip.convex"):
             src3, valid3, over3, pos3 = _compact(conv, K3)
             cv3 = jnp.maximum(cvrow[src3], 0)
@@ -1773,6 +1834,9 @@ def pip_join(
             padded, nn = core.ladder.pad(chunk)
             # (`sp`: the call's `join.pip` span, open around every `run`)
             sp.attrs["compacted"] |= core.compacted(padded.shape[0])
+            sp.attrs["tier2_compacted"] |= core.tier2_compacted(
+                padded.shape[0]
+            )
             return _dispatch.guarded_call(
                 "pip_join.device", core.execute_padded, padded
             )[:nn]
@@ -1843,6 +1907,10 @@ def pip_join(
                 )
         sp.attrs["compacted"] |= tier1_compacts(
             chunk.shape[0], fcap, probe, writeback
+        )
+        sp.attrs["tier2_compacted"] |= tier2_compacted(
+            chunk.shape[0], chip_index.num_heavy_cells, fcap, hcap, probe,
+            writeback,
         )
         shifted = jnp.asarray(chunk - shift, dtype=dtype)
         # every cap that exists escalates together toward the row-count
@@ -2016,10 +2084,12 @@ def pip_join(
 
     # one span per pip_join call: escalation/retry/degradation/recheck
     # events inside attach to it, so a trail shows WHICH join they hit
-    # (`compacted`: whether any chunk's program, as first dispatched,
-    # compacts before tier 1 — `tier1_compacts`)
+    # (`compacted`, `tier2_compacted`: whether any chunk's program, as
+    # first dispatched, compacts before tier 1 — `tier1_compacts` — and
+    # before tier 2 — `tier2_compacted`)
     with _obs_trace.span(
-        "join.pip", n=n, recheck=bool(recheck), probe=probe, compacted=False
+        "join.pip", n=n, recheck=bool(recheck), probe=probe, compacted=False,
+        tier2_compacted=False,
     ) as sp:
         if batch_size is None or n <= batch_size:
             return run_spanned(raw)

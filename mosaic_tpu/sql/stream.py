@@ -92,6 +92,7 @@ from .join import (
     pip_join_points_heavy,
     resolve_probe_mode,
     tier1_compacts,
+    tier2_compacted,
 )
 
 
@@ -639,9 +640,20 @@ class StreamJoin:
             n_points = n_batches * batch
             # the fold's fourth entry, where the index has heavy cells
             heavy_rows = int(acc_np[3]) if acc_np.shape[0] > 3 else 0
+            # the join's two rules at this shard size (static facts)
+            shard = batch // (1 if self.mesh is None else self.mesh.size)
+            rules = {
+                "compacted": tier1_compacts(
+                    shard, self.found_cap, self.probe
+                ),
+                "tier2_compacted": tier2_compacted(
+                    shard, self.index.num_heavy_cells, self.found_cap,
+                    self.heavy_cap, self.probe,
+                ),
+            }
             sp.set(
                 rows=n_points, heavy_rows=heavy_rows,
-                cell_dtype=self.cell_dtype,
+                cell_dtype=self.cell_dtype, **rules,
             )
             if self.donate_ring:
                 donation = {
@@ -669,10 +681,7 @@ class StreamJoin:
             outs=np.asarray(outs) if collect else None,
             metrics={
                 **donation,
-                "compacted": tier1_compacts(
-                    batch // (1 if self.mesh is None else self.mesh.size),
-                    self.found_cap, self.probe,
-                ),
+                **rules,
                 "heavy_rows": heavy_rows,
                 "cell_dtype": self.cell_dtype,
             },

@@ -221,6 +221,147 @@ def test_chunked_tier2_equals_whole(grid, index2000, fabric2000, monkeypatch,
         (host.cells[u] == np.asarray(cells)) & (host.cell_heavy[u] >= 0))
 
 
+# ------------------------- tier 2: in place = compacted, and which is run
+
+TIER2_CASES = [
+    # probe, found_cap: the caller of `_heavy_tier`, its engine
+    ("scatter", None),          # tier 1 in place, the row gather
+    ("scatter", 2999),          # tier 1 compacted (K1 slots), the row gather
+    ("adaptive-heavy", None),   # tier 1 compacted, the Pallas lane
+    ("adaptive", 2999),
+]
+
+
+def _join3000(grid, index, fabric, **kw):
+    pts = _points(fabric, 3000, 21)
+    cells = jnp.asarray(grid.point_to_cell(pts, 11))
+    shifted = jnp.asarray(pts - index.host.shift, jnp.float32)
+    # (rows, near) where banded, else (rows, the mask of heavy rows)
+    join = (pip_join_points if "edge_eps2" in kw
+            else join_mod.pip_join_points_heavy)
+    return [np.asarray(a) for a in join(shifted, cells, index, **kw)]
+
+
+@pytest.mark.parametrize("banded", [False, True])
+@pytest.mark.parametrize("probe,found_cap", TIER2_CASES)
+def test_tier2_in_place_equals_compacted(grid, index2000, fabric2000,
+                                         monkeypatch, probe, found_cap, banded):
+    """A tier 2 whose cap cuts no rows probes the wide rows in place; under
+    the parent's rule (always compact, into as many slots as rows) the
+    answers, the band mask and the overflow marks are the same row for
+    row."""
+    kw = dict(probe=probe, found_cap=found_cap)
+    if banded:
+        kw["edge_eps2"] = jnp.asarray(1e-9, jnp.float32)
+    seen = []
+    real = join_mod.tier2_compacts
+    monkeypatch.setattr(
+        join_mod, "tier2_compacts",
+        lambda rows, cap: seen.append((rows, cap)) or real(rows, cap))
+    mine = _join3000(grid, index2000, fabric2000, **kw)
+    assert seen == [(found_cap or 3000, None)] and not real(*seen[0])
+    monkeypatch.setattr(join_mod, "tier2_compacts", lambda rows, cap: True)
+    parents = _join3000(grid, index2000, fabric2000, **kw)
+    assert len(mine) == len(parents) == 2
+    for a, b in zip(mine, parents):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    out, second = mine
+    assert (out >= 0).mean() > 0.2 and (out == -1).any()
+    assert second.sum() > (20 if banded else 500)  # near rows | heavy rows
+    # full caps (the dispatch core's) cut nothing either
+    full = _join3000(grid, index2000, fabric2000, heavy_cap=3000, **kw)
+    assert np.array_equal(full[0], out)
+
+
+@pytest.mark.parametrize("probe,found_cap", TIER2_CASES[:3])
+def test_a_capped_tier2_still_compacts_and_marks_overflow(
+        grid, index2000, fabric2000, monkeypatch, probe, found_cap):
+    """``heavy_cap`` under the row count: the first ``heavy_cap`` heavy rows
+    are answered as the uncapped join answers them, the rest read
+    OVERFLOW; the rule says so and `_compact` runs for it."""
+    whole, heavy = _join3000(
+        grid, index2000, fabric2000, probe=probe, found_cap=found_cap)
+    calls = []
+    real = join_mod._compact
+    monkeypatch.setattr(
+        join_mod, "_compact",
+        lambda flag, cap: calls.append(cap) or real(flag, cap))
+    capped, heavy_c = _join3000(
+        grid, index2000, fabric2000, probe=probe, found_cap=found_cap,
+        heavy_cap=64)
+    assert 64 in calls and join_mod.tier2_compacts(found_cap or 3000, 64)
+    assert np.array_equal(heavy, heavy_c) and heavy.sum() > 500
+    over = capped == join_mod.OVERFLOW
+    assert over.sum() == heavy.sum() - 64 and not over[~heavy].any()
+    assert np.array_equal(over[heavy], np.arange(heavy.sum()) >= 64)
+    assert np.array_equal(capped[~over], whole[~over])
+
+
+@pytest.mark.parametrize("rows,cap,compacts", [
+    (4096, None, False),     # no cap: the stream
+    (4096, 0, False),
+    (4096, 4096, False),     # the rows: full-bucket caps, K2 = K1
+    (4096, 8192, False),     # clipped to the rows
+    (4096, 4095, True),      # cuts one slot
+    (4096, 1024, True),
+    (4096, 1, True),         # K2 = 8 < rows
+    (8, 1, False),           # K2 = 8 >= rows
+    (4, None, False),        # rows < 8
+    (4, 2, False),
+])
+def test_tier2_compacts_rule(rows, cap, compacts):
+    assert join_mod.tier2_compacts(rows, cap) is compacts
+
+
+@pytest.mark.parametrize("n,heavy_cells,found_cap,heavy_cap,probe,wb,want", [
+    (4096, 0, None, 16, "scatter", "scatter", False),     # no tier 2 at all
+    (4096, 9, None, None, "scatter", "scatter", False),
+    (4096, 9, None, 4096, "scatter", "scatter", False),
+    (4096, 9, None, 2048, "scatter", "scatter", True),
+    (4096, 9, 2048, 2048, "scatter", "scatter", False),   # K2 = K1
+    (4096, 9, 2048, 2048, "scatter", "direct", True),     # tier 1 in place
+    (4096, 9, 2048, 1024, "scatter", "gather", True),
+    (4096, 9, None, None, "adaptive", "scatter", False),
+    (4096, 9, 1024, 2048, "adaptive-heavy", "scatter", False),
+    (4096, 9, 1024, 512, "adaptive", "scatter", True),
+])
+def test_tier2_compacted_of_a_join_program(
+        n, heavy_cells, found_cap, heavy_cap, probe, wb, want):
+    """The counter's verdict: tier 2's rule on the rows tier 1 hands it
+    (the batch in place, ``K1`` slots compacted), False without heavy
+    cells."""
+    assert join_mod.tier2_compacted(
+        n, heavy_cells, found_cap, heavy_cap, probe, wb) is want
+
+
+@pytest.mark.parametrize("heavy_cap,compacts", [(None, False), (1024, True)])
+def test_stream_loop_on_a_heavy_index_scatters_only_under_a_cap(
+        grid, index2000, fabric2000, heavy_cap, compacts):
+    """The stream's loop on an index with heavy cells, package defaults:
+    no scatter in its lowered text and no instruction under `pip.compact`;
+    tier 2 is there. A ``heavy_cap`` under the slot's rows brings both."""
+    from mosaic_tpu.obs import stages
+
+    ring = jnp.asarray(np.stack(
+        [_points(fabric2000, 2048, s) for s in (41, 42)]))
+    sj = StreamJoin(index2000, grid, 11, heavy_cap=heavy_cap)
+    text = sj._loop.lower(ring, index2000, 2, False).as_text()
+    assert ("stablehlo.scatter" in text) == compacts
+    if not compacts:
+        assert "scatter" not in text
+    stages.clear()
+    try:
+        sj.compile(ring, 2)
+        found = set(stages.tables({"jit_loop"})["jit_loop"].values())
+    finally:
+        stages.clear()
+    assert {"pip.tier1", "pip.tier2", "pip.hash_probe", "pip.cells"} <= found
+    assert ("pip.compact" in found) == compacts
+    res = sj.run(ring, 2)
+    assert res.metrics["tier2_compacted"] is compacts
+    assert res.metrics["compacted"] is False and res.overflow == 0
+
+
 # ------------------------------------------------- the cell-precision rule
 
 @pytest.mark.parametrize("res,want", [

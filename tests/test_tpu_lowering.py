@@ -31,6 +31,15 @@ def problem():
     return h3, build_chip_index(table), len(zones)
 
 
+@pytest.fixture(scope="module")
+def heavy_problem():
+    """The same zones at an edge cap of 8: some cells are heavy."""
+    h3 = H3IndexSystem()
+    table = tessellate(
+        synthetic_zones(4, 4, bbox=BBOX), h3, 7, keep_core_geoms=False)
+    return h3, build_chip_index(table, edge_cap=8)
+
+
 def _tpu_lower(traced):
     return traced.lower(lowering_platforms=("tpu",)).as_text()
 
@@ -124,6 +133,29 @@ def test_tier1_fetch_in_the_tpu_lowering(problem):
     gathers = [ln for ln in lines if '"stablehlo.gather"' in ln]
     for row in (f"tensor<{U}x{4 * E1}xf32>", f"tensor<{U}x{E1 + 2 * M1 + 1}xi32>"):
         assert sum(f"({row}," in ln for ln in gathers) == 1, row
+
+
+@pytest.mark.parametrize("heavy_cap,scatters", [
+    (None, False), (4096, False), (2048, True),
+])
+def test_tier2_in_the_tpu_lowering(heavy_problem, heavy_cap, scatters):
+    """On an index with heavy cells the TPU-target program fetches the
+    wide rows from the (H, E2, 4) table for every one of the K rows in
+    place, with no scatter, unless ``heavy_cap`` cuts rows."""
+    h3, index = heavy_problem
+    H, E2 = index.heavy_ebits.shape
+    assert H > 0
+    K = 4096
+    pts = jnp.asarray(random_points(K, bbox=BBOX, seed=3), jnp.float32)
+    cells = h3.point_to_cell(pts, 7).astype(jnp.int64)
+    hlo = _tpu_lower(jax.jit(
+        functools.partial(pip_join_points, heavy_cap=heavy_cap)
+    ).trace(pts, cells, index))
+    assert ('"stablehlo.scatter"' in hlo) == scatters
+    rows = K if not scatters else heavy_cap
+    wide = [ln for ln in hlo.splitlines() if '"stablehlo.gather"' in ln
+            and f"(tensor<{H}x{E2}x4xf32>," in ln]
+    assert len(wide) == 1 and f"-> tensor<{rows}x{E2}x4xf32>" in wide[0]
 
 
 def test_bench_step_lowers_for_tpu(problem):

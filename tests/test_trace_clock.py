@@ -41,14 +41,18 @@ def grid():
     return CustomIndexSystem(GridConf(-180, 180, -90, 90, 2, 10.0, 10.0))
 
 
-@pytest.fixture(scope="module")
-def index(grid):
+def _chip_table(grid):
     col = wkt.from_wkt([
         "POLYGON ((1 1, 13 2, 12 11, 6 14, 2 9, 1 1))",
         "POLYGON ((-20 -20, -5 -20, -5 -5, -20 -5, -20 -20))",
         "POLYGON ((20 -10, 30 -10, 30 5, 20 5, 20 -10))",
     ])
-    return build_chip_index(tessellate(col, grid, RES, keep_core_geoms=False))
+    return tessellate(col, grid, RES, keep_core_geoms=False)
+
+
+@pytest.fixture(scope="module")
+def index(grid):
+    return build_chip_index(_chip_table(grid))
 
 
 def _points(seed, n):
@@ -400,6 +404,71 @@ def test_join_pip_span_says_whether_tier1_compacts(
                if e["event"] == "span" and e["name"] == "join.pip"]
     assert span["compacted"] is compacted
     assert ((np.asarray(out) >= 0).mean() > 0.5) == dense
+
+
+# ------------------------------------------- the same, one tier down
+
+@pytest.fixture(scope="module")
+def heavy_index(grid):
+    """The same three polygons at an edge cap of 4: 9 heavy cells."""
+    index = build_chip_index(_chip_table(grid), edge_cap=4)
+    assert index.num_heavy_cells == 9
+    return index
+
+
+@pytest.mark.parametrize("probe", ["scatter", "adaptive"])
+def test_engine_metrics_say_whether_tier2_compacts(
+        index, heavy_index, grid, probe):
+    """Full-bucket caps cut no rows of either tier's."""
+    for ix in (index, heavy_index):
+        with _engine(ix, grid, probe=probe) as eng:
+            assert eng.metrics()["tier2_compacted"] is False
+            assert not eng.core.tier2_compacted(64)
+
+
+@pytest.mark.parametrize("heavy,found_cap,heavy_cap,compacted", [
+    (True, None, None, False), (True, None, 256, False),
+    (True, None, 255, True), (True, 128, 128, False), (True, 128, 64, True),
+    (False, None, 64, False),   # no heavy cell: no tier 2 to compact
+])
+def test_stream_result_and_span_say_whether_tier2_compacts(
+        stream, index, heavy_index, grid, heavy, found_cap, heavy_cap,
+        compacted):
+    _sj, ring = stream  # 256 rows a slot
+    sj = StreamJoin(heavy_index if heavy else index, grid, RES,
+                    found_cap=found_cap, heavy_cap=heavy_cap)
+    with telemetry.capture() as events:
+        res = sj.run(ring, 2)
+    assert res.metrics["tier2_compacted"] is compacted
+    (span,) = [e for e in events
+               if e["event"] == "span" and e["name"] == "stream.run"]
+    assert span["tier2_compacted"] is compacted
+    assert span["compacted"] is res.metrics["compacted"] is bool(found_cap)
+
+
+@pytest.mark.parametrize("heavy,kw,compacted", [
+    # few rows in heavy cells: the cap sized from their count cuts rows
+    (True, {}, True),
+    (True, {"writeback": "direct"}, True),
+    (True, {"probe": "adaptive"}, True),
+    # the mesh lane's full per-shard caps
+    (True, {"mesh": 2}, False),
+    (False, {}, False),
+])
+def test_join_pip_span_says_whether_tier2_compacts(
+        index, heavy_index, grid, heavy, kw, compacted):
+    from mosaic_tpu.sql.join import pip_join
+
+    ix = heavy_index if heavy else index
+    pts = np.random.default_rng(4).uniform((1.0, 1.0), (12.0, 11.0), (2048, 2))
+    with telemetry.capture() as events:
+        got = pip_join(pts, None, grid, RES, chip_index=ix, recheck=False,
+                       **kw)
+    (span,) = [e for e in events
+               if e["event"] == "span" and e["name"] == "join.pip"]
+    assert span["tier2_compacted"] is compacted
+    want = pip_join(pts, None, grid, RES, chip_index=index, recheck=False)
+    assert (np.asarray(got) == np.asarray(want)).all()
 
 
 # --------------------------------------------------------- stage tables
