@@ -91,6 +91,31 @@ class IndexSystem(abc.ABC):
     def k_loop(self, cells: jax.Array, k: int) -> jax.Array:
         """(N,) -> (N, M) hollow ring at exactly distance k; -1 pads."""
 
+    def ring_cells(self, cells, k: int) -> np.ndarray:
+        """(N,) -> (N, M) the cells a ring search visits at iteration
+        ``k``, for all seeds at once: the disk ``k_ring(cells, 1)`` at
+        ``k == 1``, the hollow ring ``k_loop(cells, k)`` after it; -1
+        pads (reference: `GridRingNeighbours.leftTransform`). A system
+        whose ``k_loop`` is costly at scale offers `lattice_keys`."""
+        fn = self.k_ring if k == 1 else self.k_loop
+        return np.asarray(fn(cells, k), dtype=np.int64)
+
+    def ring_width(self, resolution: int, cells=None) -> float:
+        """The radius, in coordinate units, a ring search may credit every
+        completed ring: once rings 1..j around a point's cell are visited,
+        every point within ``j * ring_width`` of it lies in a visited
+        cell. On a grid of squares a ring adds a whole side in every
+        direction, and ``sqrt(area) / 1.5`` is under it. ``cells`` is
+        (a sample of) the cells a table holds, for a grid whose cells
+        vary from place to place (`H3IndexSystem`)."""
+        return float(np.sqrt(self.cell_area_approx(resolution)) / 1.5)
+
+    def lattice_keys(self, cells):
+        """``(keys, margin)`` where the system's cells lie on a lattice a
+        ring search can step over with integer adds (`H3IndexSystem`),
+        else None: the search then asks `ring_cells`."""
+        return None
+
     @abc.abstractmethod
     def grid_distance(self, cells_a: jax.Array, cells_b: jax.Array) -> jax.Array:
         """(N,),(N,) -> (N,) int64 grid distance, consistent with k_loop:

@@ -38,6 +38,11 @@ def _cell_radius_rad(res: int) -> float:
     return float(np.arctan(C.RES0_U_GNOMONIC / np.sqrt(3.0) / (C.SQRT7**res)))
 
 
+#: bits of one axial lattice coordinate in a lattice key
+_AXIS_BITS = 26
+_SIN60 = float(np.sqrt(3.0) / 2.0)
+
+
 class H3IndexSystem(IndexSystem):
     name = "H3"
     boundary_max_verts = 7  # 6 + closing vertex
@@ -423,6 +428,118 @@ class H3IndexSystem(IndexSystem):
             ).any(axis=2)
         out = np.where(keep, full, np.int64(-1))
         return self._row_unique(out, width=m_out)
+
+    def ring_width(self, resolution: int, cells=None) -> float:
+        """Hexagons: a point at a vertex of its cell has unvisited ground
+        ONE EDGE away after ring 1 (the next vertex out, where the edge
+        between two ring-1 cells ends, belongs to a ring-2 cell) — the
+        cell's circumradius, 0.62 of ``sqrt(area)``, and less than the
+        ``sqrt(area) / 1.5`` a grid of squares may credit; after ``j``
+        rings it is ``(1.5 j - 0.5)`` circumradii, so the first ring's
+        reach is safe for every ``j``. Measured on up to 64 of ``cells``:
+        the distance from each to the nearest of its ring-2 cells, as
+        polygons, in the cell's own local frame (longitude scaled by the
+        cosine of the latitude: a distance in degrees is never shorter
+        than that frame's); the smallest over the sample, less 3% for the
+        cells not sampled. Without cells: the mean hexagon's circumradius,
+        less the 26% by which the grid's smallest hexagons are narrower."""
+        cells = np.zeros(0, np.int64) if cells is None else np.asarray(cells)
+        cells = cells[cells >= 0]
+        if not cells.size:
+            return float(
+                0.74 * np.sqrt(self.cell_area_approx(resolution) / 2.598)
+            )
+        pick = np.unique(cells)
+        pick = pick[np.linspace(0, pick.size - 1, min(64, pick.size)).astype(int)]
+        centre = np.asarray(self.cell_center(pick))  # (S, 2) lon, lat
+        scale = np.stack(
+            [np.cos(np.radians(centre[:, 1])), np.ones(pick.size)], axis=-1
+        )
+
+        def outline(c):  # (S, M) cells -> (S, M, 7, 2) closed, local frame
+            d = np.asarray(self.cell_boundary(c.ravel())).reshape(
+                c.shape + (-1, 2)
+            ) - centre[:, None, None, :]
+            d[..., 0] = (d[..., 0] + 180.0) % 360.0 - 180.0
+            return d * scale[:, None, None, :]
+
+        def gap(pts, poly):  # (S, P, 2) points, (S, E + 1, 2) closed rings
+            a, b = poly[:, None, :-1], poly[:, None, 1:]
+            ab, ap = b - a, pts[:, :, None] - a
+            t = np.clip(
+                (ap * ab).sum(-1) / np.maximum((ab * ab).sum(-1), 1e-300), 0, 1
+            )
+            return np.hypot(*np.moveaxis(ap - t[..., None] * ab, -1, 0)).min((1, 2))
+
+        ring2 = np.asarray(self.k_loop(pick, 2))
+        mine = outline(pick[:, None])[:, 0]
+        reach = np.full(pick.size, np.inf)
+        for m in range(ring2.shape[1]):  # at most 12 ring-2 cells
+            ok = ring2[:, m] >= 0
+            theirs = outline(np.where(ok, ring2[:, m], pick)[:, None])[:, 0]
+            d = np.minimum(gap(mine, theirs), gap(theirs, mine))
+            reach = np.minimum(reach, np.where(ok, d, np.inf))
+        return float(0.97 * reach.min())
+
+    def lattice_keys(self, cells):
+        """(N,) cells -> ``(keys (N,) int64, margin (N,) int64)``: each
+        cell's place on its owning face's hexagon lattice, packed so that
+        the cell ``(da, db)`` axial steps away on the same face has the key
+        ``key + lattice_step(da, db)`` — a ring search steps over keys
+        with integer adds and never rounds a coordinate (the reference
+        calls the H3 C core's ``kRing`` a row). ``margin`` is how many
+        rings around the cell are sure to stay on that face's plain
+        lattice (the centre's distance to the face triangle's nearest
+        edge, in cells, less one); a key is -1 where the cell's centre is
+        not on the lattice at all (a pentagon base cell's children, whose
+        host frame is repaired to round-trip and is not aligned)."""
+        cells = np.asarray(cells, dtype=np.int64).reshape(-1)
+        if not cells.size:
+            return cells.copy(), cells.copy()
+        face, x, y, res = core.cell_center_frame(cells, np)
+        b = np.rint(y / _SIN60)
+        a = np.rint(x - b / 2.0)
+        pent = core._tables_for(np)[0].is_pentagon[hm.unpack(cells, np)[1]]
+        ok = (
+            (np.abs(x - (a + b / 2.0)) < 1e-3)
+            & (np.abs(y - b * _SIN60) < 1e-3) & ~pent
+        )
+        half = 1 << (_AXIS_BITS - 1)
+        keys = (
+            (face.astype(np.int64) << (2 * _AXIS_BITS))
+            + ((a.astype(np.int64) + half) << _AXIS_BITS)
+            + (b.astype(np.int64) + half)
+        )
+        corners = core._corners_by_res(np)[res]  # (N, 3, 2)
+        p = np.stack([x, y], axis=-1)
+        dist = np.full(cells.shape, np.inf)
+
+        def cross(u, v):
+            return u[:, 0] * v[:, 1] - u[:, 1] * v[:, 0]
+
+        for i in range(3):
+            c0, c1, c2 = corners[:, i], corners[:, (i + 1) % 3], corners[:, (i + 2) % 3]
+            e = c1 - c0
+            inward = np.sign(cross(e, c2 - c0))
+            dist = np.minimum(
+                dist, inward * cross(e, p - c0) / np.hypot(e[:, 0], e[:, 1])
+            )
+        margin = np.floor(dist).astype(np.int64) - 1
+        return np.where(ok, keys, np.int64(-1)), np.where(ok, margin, -1)
+
+    def lattice_ring(self, k: int) -> np.ndarray:
+        """(M,) key offsets of the lattice positions a ring search visits
+        at iteration ``k``: the centre and its six neighbours at ``k ==
+        1``, the ``6k`` positions at hex distance ``k`` after it (side
+        ``s`` starts ``k`` steps along direction ``s`` and walks ``k``
+        steps along direction ``s + 2``)."""
+        u = np.array([[1, 0], [0, 1], [-1, 1], [-1, 0], [0, -1], [1, -1]])
+        j = np.arange(k)[None, :, None]
+        ab = (k * u[:, None, :] + j * u[(np.arange(6) + 2) % 6][:, None, :])
+        ab = ab.reshape(-1, 2)
+        if k == 1:
+            ab = np.concatenate([np.zeros((1, 2), dtype=ab.dtype), ab])
+        return (ab[:, 0].astype(np.int64) << _AXIS_BITS) + ab[:, 1]
 
     def grid_distance(self, cells_a, cells_b) -> np.ndarray:
         """Hex grid distance via planar ijk on a common face projection.

@@ -1,0 +1,375 @@
+"""The ring-expansion engine: one KNN search over arrays of queries.
+
+Reference analog: `models/knn/SpatialKNN.scala:136-164`
+(`iterationTransform`) and `GridRingNeighbours.scala:76-99`: iteration 1
+joins each query's cover k-ring(1) against the cell-sorted candidate
+chips, iteration i > 1 only the k-loop(i) shell, so a candidate is
+inspected once; a query rests when it holds k matches and the
+grid-guaranteed radius ``(i - 1) * cell_width`` reaches its kth distance.
+
+Both `SpatialKNN.transform` and `KNNFrontend` (hence
+`ServeEngine.submit_knn`) run :func:`ring_search`. Every step of an
+iteration is an array expression over all active queries at once — ring
+cells (`KNNIndex.ring_keys`: integer adds on a lattice), the CSR probe, the fresh (query,
+candidate) pairs, the top-k merge under the oracle's tie rule (distance,
+then candidate id), the rest criterion — so no Python statement runs once
+a query or once a pair. Two ways to evaluate an iteration's pairs:
+
+- **pairs** (any geometry on either side): the fresh pairs are made on
+  the host from the CSR, deduplicated against the pairs already seen
+  (a polygon's chips lie in many cells), handed to the caller's distance
+  function, and merged on the host (:func:`merge_topk`).
+- **blocks** (point queries against an all-point index,
+  `index.PointBlocks`): rings of a point are disjoint and a point has one
+  chip, so nothing is deduplicated and no pair is materialised on the
+  host. The host lists (query, block) chunks; the device gathers each
+  chunk's block row, evaluates the distances, keeps the chunk's k best
+  and folds the chunks of one query together (:func:`block_topk_prog`).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import numpy as np
+
+from ..dispatch import bounded_cache
+from ..obs import trace as _trace
+from ..runtime import telemetry as _telemetry
+from ..runtime.errors import DegradedResult
+from .index import KNNIndex, expand_ranges
+
+_NO_ID = np.iinfo(np.int32).max
+
+
+@dataclasses.dataclass
+class RingResult:
+    dist: np.ndarray  # (n, k) f64, inf = unfilled
+    cid: np.ndarray  # (n, k) int64, -1 = unfilled
+    iterations: int = 0
+    #: queries still owed a ring when ``max_iterations`` (or, in an
+    #: approximate search, the early stop) ended the loop
+    unrested: int = 0
+    pairs: int = 0  # (query, candidate) pairs whose distance was evaluated
+    pairs_padded: int = 0  # slots the device evaluated for them
+    launches: int = 0  # device launches of the distance programs
+    degraded: "DegradedResult | None" = None
+
+
+# ------------------------------------------------------------ host merge
+
+
+def merge_topk(dist, cid, qi, ci, d, k):
+    """Pure top-k merge: fold (query, candidate, distance) triples into
+    the running (dist, cid) state, ranked by ``(distance, candidate id)``
+    — the oracle's tie rule. Pairs are deduplicated upstream, so a
+    candidate never appears twice in a row. One lexsort over the touched
+    rows' state and the triples; no loop over queries."""
+    dist, cid = dist.copy(), cid.copy()
+    if not qi.size:
+        return dist, cid
+    uq = np.unique(qi)
+    aq = np.concatenate([np.repeat(uq, k), qi])
+    ad = np.concatenate([dist[uq].ravel(), d])
+    ac = np.concatenate([cid[uq].ravel(), ci])
+    live = ac >= 0  # unfilled slots of the state carry no candidate
+    aq, ad, ac = aq[live], ad[live], ac[live]
+    order = np.lexsort((ac, ad, aq))
+    aq, ad, ac = aq[order], ad[order], ac[order]
+    start = np.flatnonzero(np.r_[True, aq[1:] != aq[:-1]])
+    rank = np.arange(aq.size) - np.repeat(start, np.diff(np.r_[start, aq.size]))
+    keep = rank < k
+    dist[uq], cid[uq] = np.inf, -1
+    dist[aq[keep], rank[keep]] = ad[keep]
+    cid[aq[keep], rank[keep]] = ac[keep]
+    return dist, cid
+
+
+def _merge_rows(dist, cid, fd, fi, k):
+    """Row-wise merge of two ranked (m, k) lists (disjoint candidates)."""
+    cd = np.concatenate([dist, fd], axis=1)
+    cc = np.concatenate([cid, fi], axis=1)
+    # unfilled slots (inf, -1) sort after every real pair at inf
+    key = np.where(cc < 0, np.iinfo(np.int64).max, cc)
+    order = np.lexsort((key, cd), axis=1)[:, :k]
+    return (
+        np.take_along_axis(cd, order, axis=1),
+        np.take_along_axis(cc, order, axis=1),
+    )
+
+
+# ---------------------------------------------------------- device blocks
+
+
+def _topk_rows(d, ids, k):
+    """The k smallest of each row by (distance, id): ``k`` rounds of a
+    lane minimum. Empty slots carry (inf, _NO_ID) and come out so."""
+    import jax.numpy as jnp
+
+    out_d, out_i = [], []
+    for _ in range(k):
+        m = jnp.min(d, axis=1, keepdims=True)
+        j = jnp.min(jnp.where(d == m, ids, _NO_ID), axis=1, keepdims=True)
+        out_d.append(m)
+        out_i.append(j)
+        taken = (d == m) & (ids == j)
+        d = jnp.where(taken, jnp.inf, d)
+        ids = jnp.where(taken, _NO_ID, ids)
+    return jnp.concatenate(out_d, axis=1), jnp.concatenate(out_i, axis=1)
+
+
+@bounded_cache("knn_block_topk", 1)
+def block_topk_prog():
+    """The ONE jitted block program every frontend shares (jax's trace
+    cache keys the chunk rung and k). Per (query, block) chunk: gather
+    the block's row of candidates, evaluate the distances to the chunk's
+    query point, keep the k best by (distance, id); then fold the chunks
+    of one query — they are adjacent — by doubling: after step ``s`` a
+    chunk holds the best of the ``2s`` chunks from it on, so the first
+    chunk of a query ends with the query's k best of this launch."""
+    import jax
+    import jax.numpy as jnp
+
+    def knn_blocks(bx, by, rid, px, py, blk, qid, thr, steps, k):
+        with jax.named_scope("knn.gather"):
+            gx, gy, gid = bx[blk], by[blk], rid[blk]
+        with jax.named_scope("knn.distance"):
+            dx, dy = px[:, None] - gx, py[:, None] - gy
+            d = jnp.sqrt(dx * dx + dy * dy)
+            live = (gid >= 0) & (d <= thr)
+            d = jnp.where(live, d, jnp.inf)
+            gid = jnp.where(live, gid, _NO_ID)
+        with jax.named_scope("knn.topk"):
+            d, gid = _topk_rows(d, gid, k)
+
+            def fold(i, state):
+                d, gid = state
+                s = jnp.left_shift(jnp.int32(1), i)
+                same = (jnp.roll(qid, -s) == qid) & (
+                    jnp.arange(qid.shape[0]) + s < qid.shape[0]
+                )
+                nd = jnp.where(same[:, None], jnp.roll(d, -s, axis=0), jnp.inf)
+                ni = jnp.where(
+                    same[:, None], jnp.roll(gid, -s, axis=0), _NO_ID
+                )
+                return _topk_rows(
+                    jnp.concatenate([d, nd], axis=1),
+                    jnp.concatenate([gid, ni], axis=1), k,
+                )
+
+            return jax.lax.fori_loop(0, steps, fold, (d, gid))
+
+    return jax.jit(knn_blocks, static_argnames=("k",))
+
+
+def block_chunks(pb, ring: np.ndarray):
+    """The (query, block) chunks of one iteration: ``ring`` is (a, M) ring
+    cells of ``a`` active point queries, -1 pads. Returns ``(cq, blk,
+    fresh)`` — chunk owner (index into the active set, ascending), chunk
+    block, and (a,) candidates met per query."""
+    a, m = ring.shape
+    flat = ring.ravel()
+    pos = np.minimum(np.searchsorted(pb.ucells, flat), pb.ucells.size - 1)
+    hit = np.flatnonzero(pb.ucells[pos] == flat)
+    own, pos = hit // m, pos[hit]
+    nblk = pb.blk_start[pos + 1] - pb.blk_start[pos]
+    fresh = np.bincount(own, weights=pb.count[pos], minlength=a)
+    return (
+        np.repeat(own, nblk), expand_ranges(pb.blk_start[pos], nblk),
+        fresh.astype(np.int64),
+    )
+
+
+def fold_heads(cq, out_d, out_i, cap: int, a: int, k: int):
+    """Per active query the k best of this iteration, from the launches'
+    per-chunk outputs (concatenated, ``cap`` chunks a launch): the first
+    chunk of a query within a launch holds that launch's answer for it; a
+    query whose chunks straddle launches has one such row a launch, merged
+    here. Returns (a, k) f64 distances and int64 ids (inf / -1 unfilled)."""
+    fd = np.full((a, k), np.inf)
+    fi = np.full((a, k), -1, dtype=np.int64)
+    n = cq.shape[0]
+    if not n:
+        return fd, fi
+    at = np.arange(n)
+    head = np.flatnonzero((at % cap == 0) | np.r_[True, cq[1:] != cq[:-1]])
+    hq = cq[head]
+    hd = out_d[head].astype(np.float64)
+    hi = out_i[head].astype(np.int64)
+    hi[hi == _NO_ID] = -1
+    first = np.r_[True, hq[1:] != hq[:-1]]
+    fd[hq[first]], fi[hq[first]] = hd[first], hi[first]
+    rest = np.flatnonzero(~first)  # at most one a launch boundary
+    if rest.size:
+        live = hi[rest] >= 0
+        fd, fi = merge_topk(
+            fd, fi, np.repeat(hq[rest], k)[live.ravel()],
+            hi[rest][live], hd[rest][live], k,
+        )
+    return fd, fi
+
+
+def ring_pairs(kx: KNNIndex, owner: np.ndarray, ring: np.ndarray):
+    """Every (query, candidate) pair of one iteration from the CSR:
+    ``ring`` is (s, M) ring cells of seed ``s`` whose query is
+    ``owner[s]``. Pairs repeat where a candidate's chips lie in several
+    of a query's cells."""
+    flat = ring.ravel()
+    lo = np.searchsorted(kx.cells, flat, side="left")
+    hi = np.searchsorted(kx.cells, flat, side="right")
+    hi[flat < 0] = lo[flat < 0]
+    qi = np.repeat(np.repeat(owner, ring.shape[1]), hi - lo)
+    return qi, kx.rows[expand_ranges(lo, hi - lo)]
+
+
+# ------------------------------------------------------------- the search
+
+
+def ring_search(
+    kx: KNNIndex, seed_ptr: np.ndarray, seed_cells: np.ndarray, k: int, *,
+    exact: bool = True, max_iterations: int, early_stop: "int | None" = None,
+    threshold: "float | None" = None, pair_distances=None, block_topk=None,
+    guard=None, on_iteration=None,
+) -> RingResult:
+    """Ring-expansion KNN for ``n`` queries given by their cover cells
+    (CSR ``seed_ptr`` / ``seed_cells``; a point has one).
+
+    ``pair_distances(qi, ci) -> (P,) f64`` evaluates fresh pairs (it may
+    return a `DegradedResult`); ``block_topk(active, cq, blk, steps, ring)
+    -> (out_d, out_i, cap, padded, launches)`` evaluates (query, block)
+    chunks on the device — given, it is the lane taken; degraded, it
+    returns a `DegradedResult` of (P, 3) rows ``(query, candidate,
+    distance)`` the host oracle answered. ``guard(stage, fn)`` runs the
+    pure stages (``knn.expand``, ``knn.scatter``: the frontend's failure
+    domains). ``exact=False`` rests a query at k matches, and
+    ``early_stop`` rounds without a new match or a newly filled query end
+    the loop (the reference's `earlyStoppingCheck`); an exact search ends
+    by the rest criterion or ``max_iterations`` alone.
+    ``on_iteration(it, qi, ci, d)`` sees each iteration's evaluated pairs
+    (the pairs lane only: the checkpoint log)."""
+    n = seed_ptr.shape[0] - 1
+    guard = guard or (lambda site, fn: fn())
+    out = RingResult(
+        dist=np.full((n, k), np.inf), cid=np.full((n, k), -1, dtype=np.int64)
+    )
+    w = kx.cell_width
+    thr = np.inf if threshold is None else float(threshold)
+    seen_keys = np.zeros(0, dtype=np.int64)  # pairs lane: q * N + c, sorted
+    seen_count = np.zeros(n, dtype=np.int64)
+    nseed = np.diff(seed_ptr)
+    seed_keys, seed_margin = kx.probe_keys(seed_cells)
+    stable, prev = 0, (n, 0)
+
+    def owed(it):
+        """Queries that iteration ``it`` still has to serve: candidates
+        remain, and the radius the rings before it are sure to cover,
+        ``(it - 1) * w``, reaches neither the threshold nor (exact) the
+        kth distance / (approximate) a kth match at all."""
+        reach = (it - 1) * w
+        need = (seen_count < kx.n) & (nseed > 0) & (reach < thr)
+        if exact:
+            return need & (reach < out.dist[:, k - 1])
+        return need & (out.cid[:, k - 1] < 0)
+
+    for it in range(1, max_iterations + 1):
+        active = np.flatnonzero(owed(it))
+        if not active.size:
+            return out
+        out.iterations = it
+
+        def expand():
+            # pure: the state commits after the guarded call returns, so
+            # a transient-fault retry re-reads identical state
+            owner = np.repeat(np.arange(active.size), nseed[active])
+            at = expand_ranges(seed_ptr[active], nseed[active])
+            ring = kx.ring_keys(
+                seed_cells[at], seed_keys[at],
+                None if seed_margin is None else seed_margin[at], it,
+            )
+            if block_topk is not None:
+                return ring, block_chunks(kx.points, ring)
+            qi, ci = ring_pairs(kx, active[owner], ring)
+            keys = np.unique(qi * kx.n + ci)
+            keys = keys[~np.isin(keys, seen_keys, assume_unique=True)]
+            return ring, keys
+
+        with _trace.span("knn.expand", iteration=it, queries=int(active.size)), \
+                _telemetry.timed(
+                    "knn_stage", stage="expand", iteration=it,
+                    queries=int(active.size),
+                ):
+            ring, found = guard("knn.expand", expand)
+
+        if block_topk is not None:
+            cq, blk, fresh = found
+            seen_count[active] += fresh
+            pairs = int(fresh.sum())
+            if not cq.size:
+                continue
+            nchunk = np.bincount(cq, minlength=active.size)
+            steps = int(max(int(nchunk.max()) - 1, 0)).bit_length()
+            with _trace.span(
+                "knn.distance", iteration=it, pairs=pairs,
+                chunks=int(cq.size),
+            ), _telemetry.timed("knn_stage", stage="distance", pairs=pairs):
+                got = block_topk(active, cq, blk, steps, ring)
+            if isinstance(got, DegradedResult):
+                # the host oracle answered: (qi, ci, d) triples
+                out.degraded = out.degraded or got
+                rows = np.asarray(got)
+                qi, ci = rows[:, 0].astype(np.int64), rows[:, 1].astype(np.int64)
+                d = rows[:, 2]
+                keep = d <= thr
+                with _trace.span("knn.scatter", iteration=it, pairs=pairs):
+                    out.dist, out.cid = guard("knn.scatter", lambda: merge_topk(
+                        out.dist, out.cid, qi[keep], ci[keep], d[keep], k))
+                out.pairs += pairs
+                continue
+            out_d, out_i, cap, padded, launches = got
+            out.pairs += pairs
+            out.pairs_padded += padded
+            out.launches += launches
+
+            def scatter():
+                fd, fi = fold_heads(cq, out_d, out_i, cap, active.size, k)
+                if it == 1:
+                    return fd, fi
+                return _merge_rows(out.dist[active], out.cid[active], fd, fi, k)
+
+            with _trace.span("knn.scatter", iteration=it, pairs=pairs), \
+                    _telemetry.timed("knn_stage", stage="scatter", pairs=pairs):
+                out.dist[active], out.cid[active] = guard("knn.scatter", scatter)
+        else:
+            keys = found
+            seen_keys = np.union1d(seen_keys, keys)
+            qi, ci = keys // kx.n, keys % kx.n
+            seen_count += np.bincount(qi, minlength=n)
+            if not qi.size:
+                continue
+            pairs = int(qi.size)
+            with _trace.span("knn.distance", iteration=it, pairs=pairs), \
+                    _telemetry.timed("knn_stage", stage="distance", pairs=pairs):
+                d = pair_distances(qi, ci)
+            if isinstance(d, DegradedResult):
+                out.degraded = out.degraded or d
+                d = np.asarray(d)
+            out.pairs += pairs
+            keep = d <= thr
+            qi, ci, d = qi[keep], ci[keep], d[keep]
+            with _trace.span("knn.scatter", iteration=it, pairs=pairs), \
+                    _telemetry.timed("knn_stage", stage="scatter", pairs=pairs):
+                out.dist, out.cid = guard(
+                    "knn.scatter",
+                    lambda: merge_topk(out.dist, out.cid, qi, ci, d, k),
+                )
+            if on_iteration is not None:
+                on_iteration(it, qi, ci, d)
+        if not exact and early_stop is not None:
+            now = (int((out.cid[:, k - 1] < 0).sum()), int((out.cid >= 0).sum()))
+            stable = stable + 1 if now == prev else 0
+            prev = now
+            if stable >= early_stop:
+                break
+    # the loop's end cut these off: they were owed the next ring
+    out.unrested = int(owed(out.iterations + 1).sum())
+    return out

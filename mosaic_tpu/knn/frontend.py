@@ -70,10 +70,12 @@ from ..dispatch import (
     resolve_program_store,
 )
 from ..dispatch.programs import deserialize_compiled, serialize_compiled
+from ..obs import stages as _stages
 from ..obs import trace as _trace
 from ..runtime import telemetry as _telemetry
 from ..runtime.errors import DegradedResult
 from ..utils import get_logger
+from . import engine as _engine
 from .index import KNNIndex
 from .oracle import host_pair_distances
 
@@ -85,6 +87,11 @@ logger = get_logger(__name__)
 DEFAULT_PAIR_LADDER = BucketLadder(min_bucket=256, max_bucket=16384)
 #: default row ladder for query cell assignment
 DEFAULT_ROW_LADDER = BucketLadder(min_bucket=64, max_bucket=4096)
+#: (query, block) chunks a launch of the block program (point queries on
+#: an all-point index): a handful of served queries' rings fit the first
+#: rung; a transform's chunks are cut at the top one, which the chip
+#: chose (PERF.md section 6, PR 33)
+BLOCK_LADDER = BucketLadder(min_bucket=1024, max_bucket=1 << 16, growth=4)
 
 
 @dataclasses.dataclass
@@ -181,25 +188,6 @@ def _sharded_point_pairs(mesh):
     )
 
 
-def _merge_topk(dist, cid, qi, ci, d, k):
-    """Pure top-k merge: fold (query, candidate, distance) triples into
-    the running (dist, cid) state, ranked lexicographically by
-    ``(distance, candidate_id)`` — the oracle's tie rule, and equal to
-    the batch model's insertion merge on tie-free data. Pairs are
-    deduplicated upstream (``seen`` sets), so a candidate can never
-    appear twice in one row."""
-    dist = dist.copy()
-    cid = cid.copy()
-    for i in np.unique(qi):
-        m = qi == i
-        cd = np.concatenate([dist[i], d[m]])
-        cc = np.concatenate([cid[i], ci[m]])
-        take = np.lexsort((cc, cd))[:k]
-        dist[i] = cd[take]
-        cid[i] = cc[take]
-    return dist, cid
-
-
 class KNNFrontend:
     """Online KNN over a resident :class:`KNNIndex` (see module doc)."""
 
@@ -235,7 +223,7 @@ class KNNFrontend:
                         f"pair bucket {b} does not divide over the "
                         f"{self.mesh.size}-device mesh"
                     )
-        self._dtype = np.dtype(kx.dc.verts.dtype)
+        self._dtype = np.dtype(kx.dtype)
         self._signatures: set = set()
         self._warmed: "frozenset | None" = None
         self._cold_compiles = 0
@@ -256,6 +244,7 @@ class KNNFrontend:
             "queries": 0,
             "pairs": 0,
             "pairs_padded": 0,
+            "launches": 0,
             "iterations": 0,
             "degraded": 0,
             "lane_ring": 0,
@@ -376,16 +365,14 @@ class KNNFrontend:
         import jax.numpy as jnp
 
         b = padded.shape[0]
-        self._note("cells", b)
         dev = jnp.asarray(padded)
+        plain = cells_prog(self.kx.index_system, self.kx.resolution, "cells")
+        if self._note("cells", b):
+            _stages.register(plain, _stages.shapes_of((dev,)), rows=b)
         fn = None
         if self._programs is not None:
             fn = self._aot_program("cells", b)
-        if fn is None:
-            fn = cells_prog(
-                self.kx.index_system, self.kx.resolution, "cells"
-            )
-        return np.asarray(fn(dev))
+        return np.asarray((fn or plain)(dev))
 
     def _assign_cells(self, pts: np.ndarray) -> np.ndarray:
         """(n, 2) raw query coords -> (n,) int64 seed cells, chunked
@@ -419,6 +406,7 @@ class KNNFrontend:
         self._note("pairs", b)
         self.stats["pairs"] += m
         self.stats["pairs_padded"] += b
+        self.stats["launches"] += 1
         with _trace.span("knn.pairs", bucket=b, pairs=m):
             qdev = jnp.asarray(np.ascontiguousarray(qxy), dtype=self._dtype)
             rdev = jnp.asarray(np.ascontiguousarray(rows, dtype=np.int64))
@@ -462,87 +450,118 @@ class KNNFrontend:
 
     # ------------------------------------------------------- ring lane
 
-    def _ring_lane(self, pts, k, default_s):
-        """Exact iterative lane — the batch model's loop
-        (`models/knn.SpatialKNN.transform`) with serve discipline."""
+    def _block_topk(self, qs64, qsd, k, thr, default_s):
+        """The engine's block evaluator (`engine.ring_search`): the
+        ``knn.distance`` failure domain over (query, block) chunks, cut at
+        the block ladder's top rung and padded to a rung; every launch is
+        enqueued before the first answer is pulled. Past the retry budget
+        the iteration's pairs are made from the CSR and answered by the
+        f64 host oracle."""
+        import jax.numpy as jnp
+
+        kx, pb = self.kx, self.kx.points
+        prog = _engine.block_topk_prog()
+        cap = BLOCK_LADDER.max_bucket
+        thr = jnp.asarray(thr, dtype=self._dtype)
+
+        def evaluate(active, cq, blk, steps, ring):
+            def device():
+                outs, padded = [], 0
+                qx, qy = qsd[active[cq], 0], qsd[active[cq], 1]
+                for c0 in range(0, cq.shape[0], cap):
+                    m = min(cap, cq.shape[0] - c0)
+                    b = BLOCK_LADDER.bucket_for(m)
+                    sl = slice(c0, c0 + m)
+                    args = (
+                        pb.x, pb.y, pb.rid,
+                        np.pad(qx[sl], (0, b - m)),
+                        np.pad(qy[sl], (0, b - m)),
+                        np.pad(blk[sl].astype(np.int32), (0, b - m),
+                               constant_values=pb.n_blocks),
+                        np.pad(cq[sl].astype(np.int32), (0, b - m),
+                               constant_values=-1),
+                        thr, np.int32(steps),
+                    )
+                    if self._note(f"blocks.k{k}", b):
+                        # how to lower this rung again, for a device
+                        # trace's stage table (nothing is lowered here)
+                        _stages.register(
+                            prog, _stages.shapes_of(args), {"k": k}, rows=b
+                        )
+                    with _trace.span("knn.blocks", bucket=b, chunks=m):
+                        outs.append((m, prog(*args, k=k)))
+                    padded += b * pb.width
+                with _trace.span("knn.pull", launches=len(outs)):
+                    out_d = np.concatenate(
+                        [np.asarray(o[0])[:m] for m, o in outs]
+                    )
+                    out_i = np.concatenate(
+                        [np.asarray(o[1])[:m] for m, o in outs]
+                    )
+                return out_d, out_i, cap, padded, len(outs)
+
+            def oracle():
+                qi, ci = _engine.ring_pairs(kx, active, ring)
+                return np.column_stack(
+                    [qi, ci, host_pair_distances(qs64, kx, qi, ci)]
+                )
+
+            return guarded_call(
+                "knn.distance", device, default_s=default_s, fallback=oracle
+            )
+
+        return evaluate
+
+    def search(
+        self, k: int, *, points=None, seed_ptr=None, seed_cells=None,
+        exact: bool = True, max_iterations=None, early_stop=None,
+        threshold=None, pair_distances=None, on_iteration=None,
+        default_s=None,
+    ) -> "_engine.RingResult":
+        """One ring search (`engine.ring_search`) under this frontend's
+        discipline: ``points`` (n, 2) raw query coordinates — their cells
+        assigned here, their pairs evaluated by the block program where
+        the index is all points and there is no mesh, else by the pair
+        program (or the caller's ``pair_distances``, where it brings
+        one) — or, for geometry queries, their cover cells (``seed_ptr``
+        / ``seed_cells``) with the caller's own ``pair_distances``.
+        ``knn.expand`` and ``knn.scatter`` guard the pure stages."""
         kx = self.kx
-        n = pts.shape[0]
-        qs64 = pts - kx.shift
-        qsd = qs64.astype(self._dtype, copy=False)
-        dist = np.full((n, k), np.inf)
-        cid = np.full((n, k), -1, dtype=np.int64)
-        seen: list = [set() for _ in range(n)]
-        seeds = self._assign_cells(pts)
-        w = kx.cell_width
-        degraded = None
-        for it in range(1, self.max_iterations + 1):
-            # the batch model's rest criterion: a query rests once it
-            # holds k matches AND the grid-guaranteed covered radius
-            # (it-1)*w reaches its kth distance; candidate exhaustion
-            # rests it early (pure optimization — no candidates remain)
-            active = [
-                i
-                for i in range(n)
-                if len(seen[i]) < kx.n
-                and (
-                    int((cid[i] >= 0).sum()) < k
-                    or (it - 1) * w < dist[i, k - 1]
-                )
-            ]
-            if not active:
-                break
-            self.stats["iterations"] += 1
+        block_topk = None
+        if points is not None:
+            n = points.shape[0]
+            qs64 = points - kx.shift
+            qsd = qs64.astype(self._dtype, copy=False)
+            seed_ptr = np.arange(n + 1, dtype=np.int64)
+            seed_cells = self._assign_cells(points)
+            if pair_distances is None:
+                def pair_distances(qi, ci):
+                    return self._distances(qs64, qsd, qi, ci, default_s)
+                if kx.points is not None and self.mesh is None:
+                    block_topk = self._block_topk(
+                        qs64, qsd, k,
+                        np.inf if threshold is None else threshold, default_s,
+                    )
+        res = _engine.ring_search(
+            kx, seed_ptr, seed_cells, k, exact=exact,
+            max_iterations=max_iterations or self.max_iterations,
+            early_stop=early_stop, threshold=threshold,
+            pair_distances=pair_distances, block_topk=block_topk,
+            guard=guarded_call,
+            on_iteration=on_iteration,
+        )
+        self.stats["iterations"] += res.iterations
+        if block_topk is not None:
+            self.stats["pairs"] += res.pairs
+            self.stats["pairs_padded"] += res.pairs_padded
+            self.stats["launches"] += res.launches
+        return res
 
-            def expand():
-                # pure: fresh (query, sorted candidate rows) pairs; the
-                # ``seen`` commit happens AFTER the guarded call returns
-                # so a transient-fault retry re-reads identical state
-                found = []
-                for i in active:
-                    if it == 1:
-                        cells = np.asarray(
-                            kx.index_system.k_ring(seeds[i : i + 1], 1)
-                        )
-                    else:
-                        cells = np.asarray(
-                            kx.index_system.k_loop(seeds[i : i + 1], it)
-                        )
-                    cells = np.unique(cells[cells >= 0])
-                    rows = kx.candidate_rows(cells)
-                    fresh = sorted(set(rows.tolist()) - seen[i])
-                    if fresh:
-                        found.append((i, fresh))
-                return found
-
-            with _telemetry.timed(
-                "knn_stage", stage="expand", iteration=it,
-                queries=len(active),
-            ):
-                found = guarded_call("knn.expand", expand)
-            qi_l, ci_l = [], []
-            for i, fresh in found:
-                seen[i].update(fresh)
-                qi_l.extend([i] * len(fresh))
-                ci_l.extend(fresh)
-            qi = np.asarray(qi_l, dtype=np.int64)
-            ci = np.asarray(ci_l, dtype=np.int64)
-            if not qi.size:
-                continue
-            with _telemetry.timed(
-                "knn_stage", stage="distance", pairs=int(qi.size),
-            ):
-                d = self._distances(qs64, qsd, qi, ci, default_s)
-            if isinstance(d, DegradedResult):
-                degraded = degraded or d
-                d = np.asarray(d)
-            with _telemetry.timed(
-                "knn_stage", stage="scatter", pairs=int(qi.size),
-            ):
-                dist, cid = guarded_call(
-                    "knn.scatter",
-                    lambda: _merge_topk(dist, cid, qi, ci, d, k),
-                )
-        return dist, cid, degraded
+    def _ring_lane(self, pts, k, default_s):
+        """Exact iterative lane — the batch model's search
+        (`models/knn.SpatialKNN.transform`) with serve discipline."""
+        res = self.search(k, points=pts, default_s=default_s)
+        return res.dist, res.cid, res.degraded
 
     # ---------------------------------------------------- voronoi lane
 
@@ -654,7 +673,7 @@ class KNNFrontend:
             ):
                 dist, cid = guarded_call(
                     "knn.scatter",
-                    lambda: _merge_topk(dist, cid, qi, ci, d, k),
+                    lambda: _engine.merge_topk(dist, cid, qi, ci, d, k),
                 )
         if fallback:
             sub = np.asarray(fallback, dtype=np.int64)
@@ -721,19 +740,38 @@ class KNNFrontend:
             for i in range(ids.shape[0])
         ]
 
-    def warmup(self) -> dict:
+    def warmup(self, k: "int | None" = None) -> dict:
         """Touch every (kind, rung) pair so serving can only replay:
         compiles (or AOT loads) every cell and pair program, then
         freezes the signature set — any later signature is a cold
-        compile and fires ``on_cold_compile``."""
+        compile and fires ``on_cold_compile``. An all-point index
+        answers point queries from its blocks: given ``k`` (the block
+        program keeps the k best on the device, so k is part of its
+        shape) every block rung is touched, and the pair rungs, which only
+        geometry queries reach, are not."""
         c0 = backend_compiles()
+        blocks = (
+            k is not None and self.kx.points is not None and self.mesh is None
+        )
         with _trace.span("knn.warmup"):
             for b in self.row_ladder.buckets:
                 with _telemetry.timed(
                     "knn_stage", stage="warmup", kind="cells", bucket=b,
                 ):
                     self._cells_bucket(np.zeros((b, 2)))
-            for b in self.pair_ladder.buckets:
+            if blocks:
+                evaluate = self._block_topk(
+                    None, np.zeros((1, 2), self._dtype), int(k), np.inf, None
+                )
+                for b in BLOCK_LADDER.buckets:
+                    with _telemetry.timed(
+                        "knn_stage", stage="warmup", kind="blocks", bucket=b,
+                    ):
+                        evaluate(
+                            np.zeros(1, np.int64), np.zeros(b, np.int64),
+                            np.zeros(b, np.int64), 1, None,
+                        )
+            for b in () if blocks else self.pair_ladder.buckets:
                 with _telemetry.timed(
                     "knn_stage", stage="warmup", kind="pairs", bucket=b,
                 ):
@@ -747,6 +785,7 @@ class KNNFrontend:
             "signatures": len(self._signatures),
             "row_buckets": len(self.row_ladder.buckets),
             "pair_buckets": len(self.pair_ladder.buckets),
+            "block_buckets": len(BLOCK_LADDER.buckets) if blocks else 0,
             "backend_compiles": (
                 c1 - c0 if c0 is not None and c1 is not None else None
             ),
@@ -764,6 +803,7 @@ class KNNFrontend:
                 if self.stats["pairs_padded"]
                 else None
             ),
+            "knn_launches": self.stats["launches"],
             "knn_iterations": self.stats["iterations"],
             "knn_degraded": self.stats["degraded"],
             "knn_lane_ring": self.stats["lane_ring"],

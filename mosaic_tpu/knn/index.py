@@ -30,6 +30,7 @@ import numpy as np
 
 from ..core.geometry import affine as _affine
 from ..core.geometry.device import DeviceGeometry, pack_to_device
+from ..dispatch import cells_prog
 from ..core.index.base import IndexSystem
 from ..core.tessellate import tessellate
 from ..core.types import GeometryType, PackedGeometry
@@ -37,6 +38,14 @@ from ..functions._coerce import to_packed
 from ..utils import get_logger
 
 logger = get_logger(__name__)
+
+
+#: candidates a resident point block holds: one TPU lane row. A cell's
+#: candidates fill whole blocks, so a (query, block) chunk is one row
+#: gather; 32 and 512 read slower on the chip (PERF.md section 6, PR 33)
+BLOCK_WIDTH = 128
+#: rows of one cell-assignment launch of the index build
+_BUILD_ROWS = 1 << 18
 
 
 @dataclasses.dataclass
@@ -47,12 +56,33 @@ class HostCandidates:
     edges (closed rings for polygons, open runs for lines, none for
     points), and the closed polygon rings for the containment parity
     test — exactly the three masked terms of
-    `core/geometry/predicates.min_distance` / `crossing_number`.
+    `core/geometry/predicates.min_distance` / `crossing_number`. A column
+    of points alone keeps one (N, 2) array, ``xy``, and no lists.
     """
 
-    verts: list  # g -> (V, 2) f64
-    edges: list  # g -> ((E, 2), (E, 2)) f64 boundary edge endpoints
-    poly_edges: list  # g -> ((E, 2), (E, 2)) closed polygon edges or None
+    verts: "list | None"  # g -> (V, 2) f64
+    edges: "list | None"  # g -> ((E, 2), (E, 2)) f64 boundary edge endpoints
+    poly_edges: "list | None"  # g -> ((E, 2), (E, 2)) closed polygon edges or None
+    xy: "np.ndarray | None" = None  # (N, 2) f64: an all-point column
+
+
+@dataclasses.dataclass
+class PointBlocks:
+    """An all-point candidate column laid out for the ring search's
+    device path: the candidates of one cell fill ``ceil(count / width)``
+    blocks of ``width`` slots, cell after cell in cell order, so a ring
+    cell is a run of whole blocks and a (query, block) chunk one row
+    gather. Block ``n_blocks`` holds no candidate: padding chunks point
+    at it."""
+
+    width: int
+    ucells: np.ndarray  # (U,) int64 occupied cells, ascending
+    blk_start: np.ndarray  # (U+1,) int64 first block of each cell
+    count: np.ndarray  # (U,) int64 candidates of each cell
+    n_blocks: int
+    x: object  # (n_blocks+1, width) device dtype, shifted frame
+    y: object
+    rid: object  # (n_blocks+1, width) int32 candidate row, -1 = empty
 
 
 @dataclasses.dataclass
@@ -62,18 +92,42 @@ class KNNIndex:
     candidates: PackedGeometry
     index_system: IndexSystem
     resolution: int
-    cells: np.ndarray  # (T,) int64 chip cells, sorted
-    rows: np.ndarray  # (T,) int64 candidate row per chip, cell-sorted
-    dc: DeviceGeometry  # shifted device candidate column
+    #: (T,) int64 probe keys of the chips, sorted: the chips' cell ids,
+    #: or (``lattice``) their `IndexSystem.lattice_keys`, which a ring
+    #: search steps over with integer adds
+    cells: np.ndarray
+    rows: np.ndarray  # (T,) int64 candidate row per chip, key-sorted
     shift: np.ndarray  # (2,) f64 recenter origin of dc and the twin
-    cell_width: float  # guaranteed covered radius added per ring
+    #: radius every completed ring is sure to cover (`IndexSystem.ring_width`)
+    cell_width: float
     host: HostCandidates
     chip_index: object  # ChipIndex | None (non-polygonal candidates)
     fingerprint: str  # restart-stable identity for AOT program keys
+    dtype: np.dtype  # device dtype of the candidate coordinates
+    #: the block layout of an all-point column, else None
+    points: "PointBlocks | None" = None
+    #: ``cells`` holds lattice keys (every chip's cell lies on the grid's
+    #: lattice), not cell ids
+    lattice: bool = False
+    _dc: "DeviceGeometry | None" = None
 
     @property
     def n(self) -> int:
         return len(self.candidates)
+
+    @property
+    def dc(self) -> DeviceGeometry:
+        """The shifted device candidate column the pair programs gather
+        from. An all-point index answers point queries from its blocks
+        and packs this only when a pair program first asks for it."""
+        if self._dc is None:
+            self._dc = pack_to_device(
+                _affine.translate(
+                    self.candidates, -self.shift[0], -self.shift[1]
+                ),
+                dtype=self.dtype,
+            )
+        return self._dc
 
     @property
     def voronoi(self):
@@ -82,16 +136,144 @@ class KNNIndex:
         return getattr(self.chip_index, "voronoi", None)
 
     def candidate_rows(self, cells: np.ndarray) -> np.ndarray:
-        """Distinct candidate rows whose chips land in ``cells``
-        (the batch model's searchsorted CSR probe)."""
+        """Distinct candidate rows whose chips land in the cells
+        ``cells`` (cell ids; the searchsorted CSR probe), ascending."""
         if not cells.size:
             return np.zeros(0, dtype=np.int64)
-        lo = np.searchsorted(self.cells, cells, side="left")
-        hi = np.searchsorted(self.cells, cells, side="right")
-        out: set = set()
-        for a, b in zip(lo, hi):
-            out.update(self.rows[a:b].tolist())
-        return np.fromiter(out, dtype=np.int64, count=len(out))
+        keys = self.probe_keys(cells)[0]
+        lo = np.searchsorted(self.cells, keys, side="left")
+        hi = np.searchsorted(self.cells, keys, side="right")
+        return np.unique(self.rows[expand_ranges(lo, hi - lo)])
+
+    def probe_keys(self, cells: np.ndarray):
+        """``(keys, margin)`` of cell ids in the table's own key space:
+        the ids themselves (margin None), or their lattice keys with the
+        rings each is sure to keep on its face's lattice."""
+        cells = np.asarray(cells, dtype=np.int64)
+        if not self.lattice:
+            return cells, None
+        uniq, inv = np.unique(cells, return_inverse=True)
+        keys, margin = self.index_system.lattice_keys(uniq)
+        return keys[inv], margin[inv]
+
+    def ring_keys(self, cells, keys, margin, it: int) -> np.ndarray:
+        """(S, M) probe keys of iteration ``it``'s ring around each seed
+        (cell ids ``cells``, their `probe_keys`): k-ring(1) at ``it ==
+        1``, the k-loop(it) shell after it; -1 pads. On a lattice the
+        ring is the seed's key plus the ring's offsets; a seed too near
+        its face's edge for that (or off the lattice) has its ring
+        walked by the grid and looked up."""
+        if not self.lattice:
+            return self.index_system.ring_cells(cells, it)
+        out = keys[:, None] + self.index_system.lattice_ring(it)[None, :]
+        walk = np.flatnonzero((keys < 0) | (margin < it + 1))
+        if walk.size:
+            ring = self.index_system.ring_cells(cells[walk], it)
+            out[walk] = -1
+            got = np.where(
+                ring >= 0, self.probe_keys(ring.ravel())[0].reshape(ring.shape),
+                -1,
+            )
+            out[walk, : got.shape[1]] = got[:, : out.shape[1]]
+        return out
+
+
+def expand_ranges(start: np.ndarray, count: np.ndarray) -> np.ndarray:
+    """``concatenate([arange(s, s + c) for s, c in zip(start, count)])``
+    as array code."""
+    count = np.asarray(count, dtype=np.int64)
+    total = int(count.sum())
+    if not total:
+        return np.zeros(0, dtype=np.int64)
+    first = np.cumsum(count) - count  # output offset of each range
+    return np.arange(total, dtype=np.int64) + np.repeat(
+        np.asarray(start, dtype=np.int64) - first, count
+    )
+
+
+def point_coords(data) -> "np.ndarray | None":
+    """(N, 2) f64 coordinates where ``data`` is a column of single
+    points — a float (N, 2) array, or a geometry column whose every row
+    is one POINT — else None."""
+    if isinstance(data, np.ndarray) and data.ndim == 2 and data.shape[1] == 2 \
+            and data.dtype.kind == "f":
+        return np.asarray(data, dtype=np.float64)
+    if (
+        isinstance(data, PackedGeometry)
+        and len(data)
+        and data.num_vertices == len(data)
+        and bool(np.all(data.geom_type == int(GeometryType.POINT)))
+        and bool(np.all(np.diff(data.geom_offsets) == 1))
+    ):
+        return data.xy
+    return None
+
+
+def points_column(xy: np.ndarray, srid: int = 4326) -> PackedGeometry:
+    """(N, 2) coordinates as a column of POINTs, in array code
+    (`functions.st_point` builds the same column a row at a time)."""
+    n = xy.shape[0]
+    off = np.arange(n + 1, dtype=np.int64)
+    return PackedGeometry(
+        xy=xy, ring_offsets=off, part_offsets=off, geom_offsets=off,
+        geom_type=np.full(n, int(GeometryType.POINT), dtype=np.uint8),
+        srid=np.full(n, srid, dtype=np.int32),
+    )
+
+
+def assign_cells(index_system, resolution: int, xy: np.ndarray) -> np.ndarray:
+    """(N, 2) -> (N,) int64 cells through the shared jitted
+    `dispatch.cells_prog`, a power-of-two launch at a time."""
+    n = xy.shape[0]
+    out = np.empty(n, dtype=np.int64)
+    prog = cells_prog(index_system, resolution, "cells")
+    for c0 in range(0, n, _BUILD_ROWS):
+        chunk = xy[c0 : c0 + _BUILD_ROWS]
+        m = chunk.shape[0]
+        b = max(64, 1 << (m - 1).bit_length())
+        padded = np.concatenate([chunk, np.broadcast_to(chunk[:1], (b - m, 2))])
+        out[c0 : c0 + m] = np.asarray(prog(padded))[:m]
+    return out
+
+
+def _lattice_of(index_system, cells: np.ndarray):
+    """The chips' lattice keys where the grid offers them and every chip's
+    cell lies on the lattice (a table with a pentagon's children keeps
+    cell ids: their rings are walked by the grid), else None."""
+    uniq, inv = np.unique(cells, return_inverse=True)
+    got = index_system.lattice_keys(uniq)
+    if got is None or (got[0] < 0).any():
+        return None
+    return got[0][inv]
+
+
+def _point_blocks(cells, rows, xs, dtype, width: int) -> PointBlocks:
+    """Lay cell-sorted candidates (``rows`` under sorted ``cells``, shifted
+    coordinates ``xs`` by row) out in blocks of ``width``."""
+    import jax.numpy as jnp
+
+    ucells, first, count = np.unique(
+        cells, return_index=True, return_counts=True
+    )
+    nblk = -(-count // width)
+    blk_start = np.concatenate([[0], np.cumsum(nblk)]).astype(np.int64)
+    n_blocks = int(blk_start[-1])
+    # slot of every candidate: its cell's first block, then its rank there
+    rank = np.arange(cells.shape[0], dtype=np.int64) - np.repeat(first, count)
+    slot = np.repeat(blk_start[:-1] * width, count) + rank
+    shape = ((n_blocks + 1) * width,)
+    x = np.zeros(shape, dtype=np.float64)
+    y = np.zeros(shape, dtype=np.float64)
+    rid = np.full(shape, -1, dtype=np.int32)
+    x[slot], y[slot], rid[slot] = xs[rows, 0], xs[rows, 1], rows
+    to = (n_blocks + 1, width)
+    return PointBlocks(
+        width=width, ucells=ucells.astype(np.int64), blk_start=blk_start,
+        count=count.astype(np.int64), n_blocks=n_blocks,
+        x=jnp.asarray(x.reshape(to), dtype=dtype),
+        y=jnp.asarray(y.reshape(to), dtype=dtype),
+        rid=jnp.asarray(rid.reshape(to)),
+    )
 
 
 def _candidate_shift(cand: PackedGeometry) -> np.ndarray:
@@ -156,39 +338,74 @@ def build_knn_index(
     candidates,
     index_system: "IndexSystem | None" = None,
     resolution: "int | None" = None,
+    dtype=None,
 ) -> KNNIndex:
     """Tessellate + pack + twin the candidate column into a
-    :class:`KNNIndex` the serve frontend can hold resident."""
+    :class:`KNNIndex` that `KNNFrontend` and `SpatialKNN.transform` hold
+    resident. ``candidates`` is any geometry input, or a float (N, 2)
+    array of points. A column of points alone is built in array code —
+    a point's chip is its cell, assigned on the device — and laid out in
+    blocks for the ring search's device path. ``dtype`` is the device
+    dtype of the candidate coordinates and of the distances (the
+    package's default: float64 where x64 is on)."""
     if index_system is None:
         from ..context import current_context
 
         index_system = current_context().index_system
-    cand = to_packed(candidates)
+    xy = point_coords(candidates)
+    cand = points_column(xy) if xy is not None and not isinstance(
+        candidates, PackedGeometry
+    ) else to_packed(candidates)
+    if xy is None:
+        xy = point_coords(cand)
     if resolution is not None:
         res = index_system.resolution_arg(resolution)
     else:
         from ..sql.analyzer import MosaicAnalyzer
 
         res = MosaicAnalyzer(index_system).get_optimal_resolution(cand)
-
-    table = tessellate(cand, index_system, res, keep_core_geoms=False)
-    order = np.argsort(table.cell_id, kind="stable")
-    cells = np.asarray(table.cell_id[order], dtype=np.int64)
-    rows = table.geom_id[order].astype(np.int64)
-
-    shift = _candidate_shift(cand)
     from ..functions.geometry import _device_dtype
 
-    dc = pack_to_device(
-        _affine.translate(cand, -shift[0], -shift[1]),
-        dtype=_device_dtype(),
-    )
+    dtype = np.dtype(_device_dtype() if dtype is None else dtype)
+
+    if xy is not None:
+        finite = xy[np.isfinite(xy).all(axis=1)]
+        shift = (
+            (finite.min(axis=0) + finite.max(axis=0)) / 2.0
+            if finite.size else np.zeros(2)
+        )
+        pcells = assign_cells(index_system, res, xy)
+        cell_width = index_system.ring_width(res, pcells)
+        keys = _lattice_of(index_system, pcells)
+        pcells = pcells if keys is None else keys
+        order = np.argsort(pcells, kind="stable")
+        cells = pcells[order]
+        rows = order.astype(np.int64)
+        xs = xy - shift
+        return KNNIndex(
+            candidates=cand, index_system=index_system, resolution=res,
+            cells=cells, rows=rows, shift=shift, cell_width=cell_width,
+            host=HostCandidates(None, None, None, xy=xs), chip_index=None,
+            fingerprint=_fingerprint(cells, rows, shift, res, index_system),
+            dtype=dtype, lattice=keys is not None,
+            points=_point_blocks(cells, rows, xs, dtype, BLOCK_WIDTH),
+        )
+
+    table = tessellate(cand, index_system, res, keep_core_geoms=False)
+    tcells = np.asarray(table.cell_id, dtype=np.int64)
+    cell_width = index_system.ring_width(res, tcells)
+    keys = _lattice_of(index_system, tcells)
+    tcells = tcells if keys is None else keys
+    order = np.argsort(tcells, kind="stable")
+    cells = tcells[order]
+    rows = table.geom_id[order].astype(np.int64)
+    shift = _candidate_shift(cand)
 
     chip_index = None
-    if all(
-        cand.geometry_type(g).base == GeometryType.POLYGON
-        for g in range(len(cand))
-    ):
+    if bool(np.isin(
+        cand.geom_type,
+        (int(GeometryType.POLYGON), int(GeometryType.MULTIPOLYGON)),
+    ).all()):
         from ..sql.join import build_chip_index
 
         chip_index = build_chip_index(table)
@@ -199,12 +416,10 @@ def build_knn_index(
         resolution=res,
         cells=cells,
         rows=rows,
-        dc=dc,
         shift=np.asarray(shift, dtype=np.float64),
-        cell_width=float(
-            np.sqrt(index_system.cell_area_approx(res)) / 1.5
-        ),
+        cell_width=cell_width,
         host=_host_twin(cand, shift),
         chip_index=chip_index,
         fingerprint=_fingerprint(cells, rows, shift, res, index_system),
+        dtype=dtype, lattice=keys is not None,
     )

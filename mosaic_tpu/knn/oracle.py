@@ -81,6 +81,8 @@ def host_pair_distances(
     """(P,) exact f64 distances for (query, candidate) pairs —
     ``qs`` are SHIFTED query coordinates (``raw - kx.shift``). The
     frontend's degradation fallback and the walk-bound evaluator."""
+    if kx.host.xy is not None:  # an all-point column: one array expression
+        return np.sqrt(np.sum((qs[qi] - kx.host.xy[ci]) ** 2, axis=-1))
     out = np.empty(qi.shape[0], dtype=np.float64)
     for p in range(qi.shape[0]):
         out[p] = host_distance(qs[qi[p]], kx.host, int(ci[p]))
@@ -103,9 +105,12 @@ def brute_force_knn(queries: np.ndarray, kx, k: int):
     dist = np.full((n, k), np.inf)
     kk = min(k, m)
     for i in range(n):
-        d = np.array(
-            [host_distance(qs[i], kx.host, g) for g in range(m)]
-        )
+        if kx.host.xy is not None:
+            d = np.sqrt(np.sum((qs[i] - kx.host.xy) ** 2, axis=-1))
+        else:
+            d = np.array(
+                [host_distance(qs[i], kx.host, g) for g in range(m)]
+            )
         order = np.lexsort((np.arange(m), d))[:kk]
         ids[i, :kk] = order
         dist[i, :kk] = d[order]

@@ -11,10 +11,15 @@ pass widens rings until the grid-guaranteed radius covers each landmark's
 current kth-neighbour distance (the reference's buffer-by-kth-distance
 final ring, `resultTransform:176-189`).
 
-TPU-native shape: the ring/cell bookkeeping stays on host (sets of int64
-cells), while ALL geometry distance evaluation is batched per iteration into
-one padded device call (pairs gathered from two DeviceGeometry columns that
-share one f64 recenter shift).
+TPU-native shape: the search itself is `mosaic_tpu.knn.engine.ring_search`,
+the one ring engine `KNNFrontend` serves from too — array code over all
+landmarks at once. The candidates live in a `knn.KNNIndex`
+(`build_knn_index`), which `transform` builds from a geometry column or
+takes prebuilt, so a table held resident on the device answers call after
+call. Point landmarks against an all-point index run the engine's block
+lane (pairs never leave the device); any other pairing hands each
+iteration's fresh pairs to one padded device call over two DeviceGeometry
+columns that share the index's f64 recenter shift.
 """
 
 from __future__ import annotations
@@ -23,11 +28,13 @@ import dataclasses
 
 import numpy as np
 
+from ..core.geometry import affine as _affine
+from ..core.geometry.device import pack_to_device
 from ..core.index.base import IndexSystem
 from ..core.tessellate import tessellate
 from ..functions._coerce import to_packed
 from ..dispatch import core as _dispatch
-from ..runtime.errors import DegradedResult
+from ..obs import trace as _trace
 from .core import CheckpointManager
 
 
@@ -60,8 +67,9 @@ def _pair_distance_prog():
 
 
 class GridRingNeighbours:
-    """One iteration's candidate generation + distance evaluation
-    (reference: GridRingNeighbours.transform / leftTransform:76-99).
+    """One iteration's distance evaluation over two geometry columns
+    (reference: GridRingNeighbours.transform; the ring cells of
+    `leftTransform:76-99` are the engine's, `KNNIndex.ring_keys`).
 
     With ``mesh`` set, each iteration's pair batch shards over the mesh
     devices (`parallel/dist_knn.py`) — the reference's distributed
@@ -71,22 +79,6 @@ class GridRingNeighbours:
         self.index = index
         self.resolution = resolution
         self.mesh = mesh
-
-    # ------------------------------------------------------------ cells
-    def ring_cells(self, cover: list[np.ndarray], iteration: int) -> list[np.ndarray]:
-        """Iteration 1: k-ring(1) of the cover; i>1: k-loop(i) shell only
-        (`GridRingNeighbours.leftTransform`: kring for i==1 else kloop)."""
-        out = []
-        for seed in cover:
-            if not seed.size:
-                out.append(seed)
-                continue
-            if iteration == 1:
-                cells = np.asarray(self.index.k_ring(seed, 1))
-            else:
-                cells = np.asarray(self.index.k_loop(seed, iteration))
-            out.append(np.unique(cells[cells >= 0]))
-        return out
 
     # --------------------------------------------------------- distances
     def pair_distances(
@@ -163,174 +155,149 @@ class SpatialKNN:
         #: batch over its devices (parallel/dist_knn.py)
         self.mesh = mesh
         self.metrics: dict = {}
-        #: GridRingNeighbours per resolution — MUST survive across
-        #: transform() calls: its _dist_cache holds the jitted distance
-        #: kernels, and rebuilding it each call recompiled them every
-        #: time (~27 s per transform measured on the chip, round 5)
-        self._ring_cache: dict = {}
+        #: the frontend of the index last searched — it MUST survive
+        #: across transform() calls on a resident index: it holds the
+        #: signature set of the warmed programs
+        self._frontend: "tuple | None" = None
 
     # ------------------------------------------------------------ helpers
-    def _cover_cells(self, col, res: int) -> list[np.ndarray]:
-        table = tessellate(col, self.index, res, keep_core_geoms=False)
-        return [
-            np.unique(table.cell_id[table.geom_id == g])
-            for g in range(len(col))
-        ]
+    def _index(self, candidates):
+        """``candidates`` as a `knn.KNNIndex`: itself where the caller
+        holds one resident, else built for this call."""
+        from ..knn import KNNIndex, build_knn_index
 
-    def _cell_width(self, res: int) -> float:
-        # conservative per-ring growth of the guaranteed-covered radius:
-        # one ring adds at least the cell in-diameter ~ sqrt(area)/1.5
-        return float(np.sqrt(self.index.cell_area_approx(res)) / 1.5)
+        if isinstance(candidates, KNNIndex):
+            return candidates
+        return build_knn_index(candidates, self.index, self.resolution)
+
+    def _frontend_of(self, kx):
+        from ..knn import KNNFrontend
+
+        if self._frontend is None or self._frontend[0] is not kx:
+            self._frontend = (kx, KNNFrontend(kx))
+        return self._frontend[1]
+
+    def warmup(self, candidates) -> dict:
+        """Compile every program a transform against ``candidates`` (a
+        resident `knn.KNNIndex`) can launch: `KNNFrontend.warmup` with
+        this model's k."""
+        return self._frontend_of(self._index(candidates)).warmup(k=self.k)
 
     # ----------------------------------------------------------- transform
     def transform(self, landmarks, candidates) -> KNNResult:
-        land = to_packed(landmarks)
-        cand = to_packed(candidates)
-        res = (
-            self.index.resolution_arg(self.resolution)
-            if self.resolution is not None
-            else _default_resolution(self.index, cand)
-        )
-        L = len(land)
+        """The k nearest ``candidates`` of every landmark. ``landmarks``
+        is any geometry input or a float (N, 2) array of points;
+        ``candidates`` likewise, or a prebuilt `knn.KNNIndex` held
+        resident across calls."""
+        from ..knn.index import point_coords, points_column
 
-        # right side: chip cells -> candidate rows (tessellate once,
-        # `SpatialKNN.transform:205-211` candidates tessellation)
-        ctable = tessellate(cand, self.index, res, keep_core_geoms=False)
-        order = np.argsort(ctable.cell_id, kind="stable")
-        ccells = ctable.cell_id[order]
-        crows = ctable.geom_id[order].astype(np.int64)
-
-        # left cover + shared-shift device columns for distance evaluation
-        cover = self._cover_cells(land, res)
-        from ..functions.geometry import _pair_pack
-
-        dl, dc = _pair_pack(land, cand)
-        ring = self._ring_cache.get(res)
-        if ring is None or ring.mesh is not self.mesh:
-            ring = GridRingNeighbours(self.index, res, mesh=self.mesh)
-            self._ring_cache[res] = ring
-
+        kx = self._index(candidates)
+        fe = self._frontend_of(kx)
+        lxy = point_coords(landmarks)
+        land = None if lxy is not None else to_packed(landmarks)
+        if land is not None:
+            lxy = point_coords(land)
+        L = lxy.shape[0] if lxy is not None else len(land)
+        ring = GridRingNeighbours(kx.index_system, kx.resolution, self.mesh)
         ckpt = (
             CheckpointManager(self.checkpoint_dir, overwrite=True)
             if self.checkpoint_dir
             else None
         )
+        #: the shared-shift landmark column: packed when a pair batch
+        #: first needs it (the block lane never does)
+        packed: dict = {}
 
-        # state
-        dist = np.full((L, self.k), np.inf)
-        cid = np.full((L, self.k), -1, dtype=np.int64)
-        seen: list[set] = [set() for _ in range(L)]
-        stable_rounds = 0
-        prev_unfinished = L
-        prev_matches = 0
-        w = self._cell_width(res)
-        iterations = 0
-        degraded = False
-
-        def matched(i: int) -> int:
-            return int((cid[i] >= 0).sum())
-
-        for it in range(1, self.max_iterations + 1):
-            iterations = it
-            # guarantee radius after ring r: (r-1) rings fully covered
-            need = np.array(
-                [
-                    matched(i) < self.k
-                    or (
-                        not self.approximate
-                        and (it - 1) * w < dist[i, self.k - 1]
-                    )
-                    for i in range(L)
-                ]
-            )
-            if not need.any():
-                break
-            shells = ring.ring_cells(
-                [c if need[i] else np.zeros(0, np.int64) for i, c in enumerate(cover)],
-                it,
-            )
-            li_list: list[int] = []
-            ci_list: list[int] = []
-            for i in range(L):
-                cells = shells[i]
-                if not cells.size:
-                    continue
-                lo = np.searchsorted(ccells, cells, side="left")
-                hi = np.searchsorted(ccells, cells, side="right")
-                rows: set = set()
-                for a, b in zip(lo, hi):
-                    rows.update(crows[a:b].tolist())
-                rows -= seen[i]
-                seen[i].update(rows)
-                for rr in rows:
-                    li_list.append(i)
-                    ci_list.append(rr)
-            li = np.asarray(li_list, dtype=np.int64)
-            ci = np.asarray(ci_list, dtype=np.int64)
-            d = _resilient_distances(ring, dl, dc, li, ci, land, cand)
-            if isinstance(d, DegradedResult):
-                degraded = True
-                d = np.asarray(d)
-            if self.distance_threshold is not None:
-                keep = d <= self.distance_threshold
-                li, ci, d = li[keep], ci[keep], d[keep]
-            # merge into running top-k per landmark
-            for i, c, dd in zip(li, ci, d):
-                row_d = dist[i]
-                if dd < row_d[-1]:
-                    j = int(np.searchsorted(row_d, dd))
-                    dist[i] = np.insert(row_d, j, dd)[: self.k]
-                    cid[i] = np.insert(cid[i], j, c)[: self.k]
-            if ckpt is not None:
-                ckpt.append(
-                    {"iteration": np.full(li.shape, it), "landmark": li,
-                     "candidate": ci, "distance": d}
+        def pair_distances(li, ci):
+            if not packed:
+                col = land if land is not None else points_column(lxy)
+                packed["land"] = col
+                packed["dl"] = pack_to_device(
+                    _affine.translate(col, -kx.shift[0], -kx.shift[1]),
+                    dtype=kx.dtype,
                 )
-            # early stopping (`earlyStoppingCheck`): unmatched count and
-            # total match count both stable
-            unfinished = int(sum(matched(i) < self.k for i in range(L)))
-            total_matches = int((cid >= 0).sum())
-            if unfinished == prev_unfinished and total_matches == prev_matches:
-                stable_rounds += 1
-                if stable_rounds >= self.early_stop:
-                    break
-            else:
-                stable_rounds = 0
-            prev_unfinished, prev_matches = unfinished, total_matches
+            return _resilient_distances(
+                ring, packed["dl"], kx.dc, li, ci, packed["land"],
+                kx.candidates,
+            )
 
-        # flatten result
-        li_out, ci_out, d_out, rank_out = [], [], [], []
-        for i in range(L):
-            for r in range(self.k):
-                if cid[i, r] >= 0:
-                    li_out.append(i)
-                    ci_out.append(int(cid[i, r]))
-                    d_out.append(float(dist[i, r]))
-                    rank_out.append(r + 1)
+        def log(it, li, ci, d):
+            ckpt.append(
+                {"iteration": np.full(li.shape, it), "landmark": li,
+                 "candidate": ci, "distance": d}
+            )
+
+        search = dict(
+            exact=not self.approximate, max_iterations=self.max_iterations,
+            early_stop=self.early_stop,
+            threshold=self.distance_threshold,
+            on_iteration=log if ckpt is not None else None,
+        )
+        with _trace.span("knn.transform", landmarks=L, k=self.k) as sp:
+            blocks = (
+                lxy is not None and kx.points is not None
+                and self.mesh is None and ckpt is None
+            )
+            if blocks:
+                res = fe.search(self.k, points=lxy, **search)
+            elif lxy is not None:
+                res = fe.search(
+                    self.k, points=lxy, pair_distances=pair_distances,
+                    **search,
+                )
+            else:
+                # a geometry landmark searches from every cell of its cover
+                table = tessellate(
+                    land, kx.index_system, kx.resolution,
+                    keep_core_geoms=False,
+                )
+                cover = np.unique(
+                    np.stack([table.geom_id.astype(np.int64), table.cell_id]),
+                    axis=1,
+                )
+                seed_ptr = np.concatenate(
+                    [[0], np.cumsum(np.bincount(cover[0], minlength=L))]
+                )
+                res = fe.search(
+                    self.k, seed_ptr=seed_ptr, seed_cells=cover[1],
+                    pair_distances=pair_distances, **search,
+                )
+            sp.set(
+                iterations=res.iterations, pairs=res.pairs,
+                pairs_padded=res.pairs_padded, launches=res.launches,
+                unrested_landmarks=res.unrested,
+            )
+
+        # flatten result: one row a filled (landmark, rank) slot
+        li_out, slot = np.nonzero(res.cid >= 0)
+        filled = res.cid[:, self.k - 1] >= 0
+        finite = res.dist[np.isfinite(res.dist)]
         self.metrics = {
-            "match_count": len(li_out),
-            "iterations": iterations,
+            "match_count": int(li_out.size),
+            "iterations": res.iterations,
             "landmarks": L,
-            "candidates": len(cand),
-            "complete_landmarks": int(
-                sum(matched(i) >= self.k for i in range(L))
-            ),
-            "max_kth_distance": float(
-                np.nanmax(np.where(np.isinf(dist), np.nan, dist), initial=0.0)
-            ),
-            "resolution": res,
+            "candidates": kx.n,
+            "complete_landmarks": int(filled.sum()),
+            "max_kth_distance": float(finite.max()) if finite.size else 0.0,
+            "resolution": kx.resolution,
             "approximate": self.approximate,
+            # landmarks that ``max_iterations`` (or an approximate run's
+            # early stop) cut off while they were still owed a ring
+            "unrested_landmarks": res.unrested,
+            "pairs": res.pairs,
+            "pairs_padded": res.pairs_padded,
+            "launches": res.launches,
             # True when any iteration's distances came from the f64 host
             # oracle after the device path failed past its retry budget
-            "degraded": degraded,
+            "degraded": res.degraded is not None,
         }
         if ckpt is not None:
             ckpt.write_meta(self.metrics)
         return KNNResult(
-            landmark_id=np.asarray(li_out, dtype=np.int64),
-            candidate_id=np.asarray(ci_out, dtype=np.int64),
-            distance=np.asarray(d_out),
-            rank=np.asarray(rank_out, dtype=np.int64),
+            landmark_id=li_out.astype(np.int64),
+            candidate_id=res.cid[li_out, slot],
+            distance=res.dist[li_out, slot],
+            rank=(slot + 1).astype(np.int64),
             metrics=dict(self.metrics),
         )
 
@@ -362,9 +329,3 @@ def _resilient_distances(ring, dl, dc, li, ci, land, cand):
     return _dispatch.guarded_call(
         "knn.pair_distances", device_eval, fallback=oracle_eval
     )
-
-
-def _default_resolution(index: IndexSystem, col) -> int:
-    from ..sql.analyzer import MosaicAnalyzer
-
-    return MosaicAnalyzer(index).get_optimal_resolution(col)

@@ -4,7 +4,8 @@
 The join names its stages with ``jax.named_scope`` (``pip.cells``,
 ``pip.hash_probe``, ``pip.compact``, ``pip.tier1``, ``pip.writeback``,
 ``stream.fold``, ...; the raster tile's own are ``zonal.centers`` and
-``zonal.fold``). This libtpu's device trace does not carry them: an
+``zonal.fold``; the KNN block program's ``knn.gather``, ``knn.distance``,
+``knn.topk``). This libtpu's device trace does not carry them: an
 ``XLA Ops`` event is named by the HLO instruction's text
 (``%fusion.504 = f32[4000000,153]{...} fusion(...)``) and the number
 changes with every edit of the join. The scopes do survive in the
@@ -28,7 +29,7 @@ so the names are recovered there, after the fact:
   compiled once more under a key that adds the lowering's scoped op
   names — by the traced run alone, and cached for the next one.
 
-An op's stage is the innermost ``pip.*``/``stream.*``/``zonal.*`` component of its own
+An op's stage is the innermost ``pip.*``/``stream.*``/``zonal.*``/``knn.*`` component of its own
 ``op_name``; else, for a fusion, the commonest stage among the
 instructions of its fused computation; else (compiler-made ops with no
 metadata: x64 splits of a parameter, copies, bitcasts) the stage of the
@@ -60,7 +61,7 @@ _LOCK = threading.Lock()
 _LOWERINGS = [0]
 _RECOMPILED = [0]
 
-_STAGE = re.compile(r"^(?:pip|stream|zonal)\.[A-Za-z0-9_.]+$")
+_STAGE = re.compile(r"^(?:pip|stream|zonal|knn)\.[A-Za-z0-9_.]+$")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')

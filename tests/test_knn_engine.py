@@ -1,0 +1,468 @@
+"""The array ring engine (`mosaic_tpu/knn/engine.py`, PR 33): one search
+under `SpatialKNN.transform` and `KNNFrontend.dispatch`, held against the
+benchmark's plain brute force on clustered points (a dense head, a sparse
+tail, landmarks with fewer than k candidates in reach), against a
+per-query loop written the way the deleted one was, and piece by piece
+(merge, chunk folding across launches, ring cells, the rest criterion)."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+from mosaic_tpu import functions as F
+from mosaic_tpu.core.index import CustomIndexSystem, GridConf
+from mosaic_tpu.core.index.h3 import H3IndexSystem
+from mosaic_tpu.dispatch import BucketLadder
+from mosaic_tpu.knn import (
+    KNNFrontend, brute_force_knn, build_knn_index, decode_knn, engine,
+)
+from mosaic_tpu.knn import frontend as knn_frontend
+from mosaic_tpu.knn.index import expand_ranges, point_coords, points_column
+from mosaic_tpu.models import SpatialKNN
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+from benchmark.references import knn_bruteforce  # noqa: E402
+
+BOX = (-74.3, 40.4, -73.6, 41.0)
+#: 3.9e-3 degree cells: the cluster fills a few, the background one in ten
+GRID, RES = CustomIndexSystem(GridConf(-75, -73, 40, 42, 2, 1.0, 1.0)), 8
+K = 5
+
+
+@pytest.fixture(scope="module")
+def clustered():
+    """Candidates: 3,000 in one 300 m cluster, 900 uniform over the box.
+    Landmarks: 40 in the cluster (ring 1 holds hundreds), 60 uniform (they
+    walk rings), 2 in an empty corner region with candidates removed
+    around them."""
+    rng = np.random.default_rng(33)
+    centre = np.array([-73.98, 40.75])
+    cand = np.concatenate([
+        centre + rng.normal(0, 0.003, (3000, 2)),
+        np.column_stack([rng.uniform(BOX[0], BOX[2], 900),
+                         rng.uniform(BOX[1], BOX[3], 900)]),
+    ])
+    land = np.concatenate([
+        centre + rng.normal(0, 0.003, (40, 2)),
+        np.column_stack([rng.uniform(BOX[0], BOX[2], 60),
+                         rng.uniform(BOX[1], BOX[3], 60)]),
+    ])
+    return land, cand
+
+
+def _table(res, n, k):
+    ids = np.full((n, k), -1, np.int64)
+    dist = np.full((n, k), np.inf)
+    ids[res.landmark_id, res.rank - 1] = res.candidate_id
+    dist[res.landmark_id, res.rank - 1] = res.distance
+    return ids, dist
+
+
+def _model(**kw):
+    args = dict(index=GRID, resolution=RES, k_neighbours=K,
+                approximate=False, max_iterations=64)
+    args.update(kw)
+    return SpatialKNN(**args)
+
+
+# ------------------------------------------------ against the brute force
+
+
+def test_transform_equals_bruteforce_head_and_tail(clustered):
+    land, cand = clustered
+    kx = build_knn_index(cand, GRID, RES)
+    assert kx.points is not None and kx.points.count.max() > 200
+    res = _model().transform(land, kx)
+    ids, dist = _table(res, len(land), K)
+    want_ids, want_d = knn_bruteforce.answers(land, cand, K)
+    assert np.array_equal(ids, want_ids)
+    np.testing.assert_allclose(dist, want_d, rtol=0, atol=1e-12)
+    m = res.metrics
+    assert m["unrested_landmarks"] == 0 and m["complete_landmarks"] == len(land)
+    assert m["iterations"] >= 4 and m["launches"] >= m["iterations"]
+    # the head: a cluster landmark met hundreds of candidates in ring 1
+    assert m["pairs"] > 40 * 200 and 0 < m["pairs"] <= m["pairs_padded"]
+
+
+def test_frontend_dispatch_equals_bruteforce_and_transform(clustered):
+    land, cand = clustered
+    kx = build_knn_index(cand, GRID, RES)
+    fe = KNNFrontend(kx, row_ladder=BucketLadder(8, 64))
+    out, occupancy = fe.dispatch(land, K)
+    ids, dist = decode_knn(np.asarray(out), K)
+    want_ids, want_d = knn_bruteforce.answers(land, cand, K)
+    assert np.array_equal(ids, want_ids)
+    np.testing.assert_allclose(dist, want_d, rtol=0, atol=1e-12)
+    assert 0 < occupancy <= 1
+    tids, tdist = _table(_model().transform(land, kx), len(land), K)
+    assert np.array_equal(tids, ids) and np.array_equal(tdist, dist)
+
+
+def test_geometry_inputs_take_the_same_engine(clustered):
+    """POINT columns on either side, and a candidate column in place of
+    an index, answer as the arrays do."""
+    land, cand = clustered
+    lcol = F.st_point(land[:30, 0], land[:30, 1])
+    ccol = points_column(cand)
+    assert point_coords(lcol) is not None
+    a = _table(_model().transform(lcol, ccol), 30, K)
+    b = _table(_model().transform(land[:30], build_knn_index(cand, GRID, RES)),
+               30, K)
+    assert np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
+
+
+def test_fewer_than_k_in_reach_is_reported_not_hidden():
+    """Three candidates, k 5: every landmark ends with 3 matches, rested
+    because no candidate is left; with far candidates and few iterations
+    the cut-off landmarks are counted."""
+    cand = np.array([[-74.0, 40.7], [-74.001, 40.7], [-73.999, 40.701]])
+    land = np.array([[-74.0005, 40.7002], [-73.93, 40.74]])
+    res = _model(max_iterations=40).transform(land, cand)
+    ids, dist = _table(res, 2, K)
+    want_ids, want_d = knn_bruteforce.answers(land, cand, K)
+    assert np.array_equal(ids, want_ids) and (ids[:, 3:] == -1).all()
+    np.testing.assert_allclose(dist[:, :3], want_d[:, :3], atol=1e-12)
+    assert res.metrics["unrested_landmarks"] == 0  # candidates exhausted
+    cut = _model(max_iterations=3).transform(land, cand)
+    assert cut.metrics["unrested_landmarks"] == 1  # the far landmark
+
+
+@pytest.mark.parametrize("lane", ["transform", "dispatch"])
+def test_polygon_candidates_equal_the_host_oracle(lane):
+    rng = np.random.default_rng(5)
+    cx = rng.uniform(-74.1, -73.9, 30)
+    cy = rng.uniform(40.6, 40.8, 30)
+    s = rng.uniform(0.002, 0.02, 30)
+    cand = F.st_geomfromwkt(np.array([
+        f"POLYGON(({x} {y}, {x + w} {y}, {x + w} {y + w}, {x} {y + w}, {x} {y}))"
+        for x, y, w in zip(cx, cy, s)
+    ]))
+    kx = build_knn_index(cand, GRID, RES)
+    assert kx.points is None
+    q = np.column_stack([rng.uniform(cx.min(), cx.max(), 12),
+                         rng.uniform(cy.min(), cy.max(), 12)])
+    want_ids, want_d = brute_force_knn(q, kx, 3)
+    if lane == "transform":
+        res = _model(k_neighbours=3).transform(q, kx)
+        ids, dist = _table(res, 12, 3)
+        assert res.metrics["unrested_landmarks"] == 0
+    else:
+        out, _ = KNNFrontend(kx, row_ladder=BucketLadder(8, 64),
+                             pair_ladder=BucketLadder(64, 1024)).dispatch(q, 3)
+        ids, dist = decode_knn(np.asarray(out), 3)
+    assert np.array_equal(ids, want_ids) and np.array_equal(dist, want_d)
+
+
+def test_polygon_landmarks_search_from_their_cover():
+    rng = np.random.default_rng(8)
+    cand = np.column_stack([rng.uniform(-74.2, -73.7, 400),
+                            rng.uniform(40.5, 40.9, 400)])
+    land = F.st_geomfromwkt(np.array([
+        "POLYGON((-74.0 40.7, -73.98 40.7, -73.98 40.72, -74.0 40.72, -74.0 40.7))",
+        "POLYGON((-73.8 40.6, -73.79 40.6, -73.79 40.61, -73.8 40.61, -73.8 40.6))",
+    ]))
+    res = _model(k_neighbours=3).transform(land, cand)
+    ids, dist = _table(res, 2, 3)
+    d = np.asarray(F.st_distance(
+        land.take(np.repeat(np.arange(2), 400)),
+        points_column(cand).take(np.tile(np.arange(400), 2)),
+    )).reshape(2, 400)
+    want = np.lexsort((np.broadcast_to(np.arange(400), d.shape), d), axis=1)[:, :3]
+    assert np.array_equal(ids, want)
+    np.testing.assert_allclose(dist, np.take_along_axis(d, want, 1), atol=1e-9)
+
+
+# ----------------------------------------- against the per-query loop
+
+
+def _per_query_loop(kx, pts, k, max_iterations=64):
+    """The ring lane as it was before PR 33: Python once a query, sets of
+    seen rows, one sort a query (kept here as the array engine's
+    reference; f64 host distances)."""
+    from mosaic_tpu.knn import host_pair_distances
+
+    n = pts.shape[0]
+    qs = pts - kx.shift
+    dist = np.full((n, k), np.inf)
+    cid = np.full((n, k), -1, np.int64)
+    seen = [set() for _ in range(n)]
+    seeds = np.asarray(kx.index_system.point_to_cell(pts, kx.resolution))
+    for it in range(1, max_iterations + 1):
+        for i in range(n):
+            if len(seen[i]) >= kx.n:
+                continue
+            if (cid[i] >= 0).sum() >= k and (it - 1) * kx.cell_width >= dist[i, k - 1]:
+                continue
+            fn = kx.index_system.k_ring if it == 1 else kx.index_system.k_loop
+            cells = np.asarray(fn(seeds[i : i + 1], it))
+            rows = kx.candidate_rows(np.unique(cells[cells >= 0]))
+            fresh = np.array(sorted(set(rows.tolist()) - seen[i]), np.int64)
+            seen[i].update(fresh.tolist())
+            if not fresh.size:
+                continue
+            d = host_pair_distances(qs, kx, np.full(fresh.size, i), fresh)
+            cd = np.concatenate([dist[i], d])
+            cc = np.concatenate([cid[i], fresh])
+            take = np.lexsort((cc, cd))[:k]
+            dist[i], cid[i] = cd[take], cc[take]
+    return cid, dist
+
+
+@pytest.mark.parametrize("candidates", ["points", "polygons"])
+def test_array_engine_equals_the_per_query_loop(candidates, clustered):
+    land, cand = clustered
+    if candidates == "polygons":
+        rng = np.random.default_rng(2)
+        cx, cy = rng.uniform(-74.1, -73.9, 30), rng.uniform(40.6, 40.8, 30)
+        cand = F.st_geomfromwkt(np.array([
+            f"POLYGON(({x} {y}, {x + .01} {y}, {x + .01} {y + .01}, {x} {y + .01}, {x} {y}))"
+            for x, y in zip(cx, cy)
+        ]))
+        land = np.column_stack([rng.uniform(-74.08, -73.92, 12),
+                                rng.uniform(40.62, 40.78, 12)])
+    kx = build_knn_index(cand, GRID, RES)
+    want_ids, want_d = _per_query_loop(kx, land[:24], 3)
+    out, _ = KNNFrontend(kx, row_ladder=BucketLadder(8, 64),
+                         pair_ladder=BucketLadder(64, 1024)).dispatch(land[:24], 3)
+    ids, dist = decode_knn(np.asarray(out), 3)
+    assert np.array_equal(ids, want_ids) and np.array_equal(dist, want_d)
+
+
+# ------------------------------------------------------- the rest criterion
+
+
+def test_exact_run_is_not_ended_by_the_early_stop():
+    """Every landmark holds k after ring 1 or 2, and the match counts stay
+    stable from then on, while the slow landmark still widens rings for
+    the exactness criterion: its true 5th neighbour lies 6 rings out, past
+    nearer-looking ones found early. ``early_stop_iterations=1`` would
+    have ended the old loop there."""
+    w = 3.90625e-3  # the cell at RES 8
+    seed = np.array([-74.0 + 0.5 * w, 40.7 + 0.5 * w])
+    # five candidates 7-8 cells out (found late, all nearer than the
+    # decoys' ring suggests) and five decoys in a far corner of ring 6
+    near = seed + np.array([[5.6 * w, 0], [-5.6 * w, 0], [0, 5.6 * w],
+                            [0, -5.6 * w], [5.5 * w, 0.3 * w]])
+    decoys = seed + np.array([[5.9 * w, 5.9 * w], [-5.9 * w, 5.9 * w],
+                              [5.9 * w, -5.9 * w], [-5.9 * w, -5.9 * w],
+                              [5.8 * w, 5.8 * w]])
+    filler = seed + np.array([[40 * w, 40 * w]]) + np.random.default_rng(1).normal(
+        0, w, (50, 2))
+    cand = np.concatenate([decoys, near, filler])
+    land = np.concatenate([seed[None], filler[:6] + 1e-4])
+    res = _model(early_stop_iterations=1).transform(land, cand)
+    ids, dist = _table(res, len(land), K)
+    want_ids, want_d = knn_bruteforce.answers(land, cand, K)
+    assert np.array_equal(ids, want_ids)
+    assert set(ids[0]) == {5, 6, 7, 8, 9}
+    assert res.metrics["unrested_landmarks"] == 0
+    assert res.metrics["iterations"] >= 7
+    # the approximate search does stop early, and says who it left
+    approx = _model(approximate=True, early_stop_iterations=1).transform(land, cand)
+    assert approx.metrics["iterations"] < res.metrics["iterations"]
+
+
+def test_threshold_rests_an_exact_search_and_drops_far_pairs(clustered):
+    land, cand = clustered
+    thr = 0.004
+    res = _model(distance_threshold=thr).transform(land, cand)
+    ids, dist = _table(res, len(land), K)
+    want_ids, want_d = knn_bruteforce.answers(land, cand, K)
+    keep = want_d <= thr
+    assert np.array_equal(ids[keep], want_ids[keep])
+    assert (ids[~keep] == -1).all() and (res.distance <= thr).all()
+    assert res.metrics["unrested_landmarks"] == 0
+    assert res.metrics["iterations"] <= 3  # (it - 1) * w reaches thr
+
+
+def test_resident_index_answers_call_after_call(clustered, monkeypatch):
+    land, cand = clustered
+    kx = build_knn_index(cand, GRID, RES)
+    m = _model()
+    a = m.transform(land, kx)
+    from mosaic_tpu import knn as knn_pkg
+
+    monkeypatch.setattr(knn_pkg, "build_knn_index",
+                        lambda *a, **k: pytest.fail("index rebuilt"))
+    fe = m._frontend[1]
+    b = m.transform(land, kx)
+    assert m._frontend[1] is fe
+    for f in ("landmark_id", "candidate_id", "distance", "rank"):
+        assert np.array_equal(getattr(a, f), getattr(b, f))
+
+
+# ------------------------------------------------------------------ pieces
+
+
+def test_merge_topk_ranks_by_distance_then_id():
+    dist = np.array([[0.5, np.inf], [np.inf, np.inf], [0.1, 0.2]])
+    cid = np.array([[9, -1], [-1, -1], [4, 5]])
+    qi = np.array([0, 0, 0, 1])
+    ci = np.array([3, 7, 2, 8])
+    d = np.array([0.5, 0.25, 0.9, 1.5])
+    nd, nc = engine.merge_topk(dist, cid, qi, ci, d, 2)
+    assert nc.tolist() == [[7, 3], [8, -1], [4, 5]]  # 3 before 9 at 0.5
+    assert nd[0].tolist() == [0.25, 0.5] and nd[1, 0] == 1.5
+    assert np.isinf(nd[1, 1]) and dist[0, 1] == np.inf  # input untouched
+
+
+def test_chunks_of_one_query_fold_across_launches(clustered, monkeypatch):
+    """A top rung of 4 chunks: the cluster landmarks' chunks straddle
+    launches, and the answer does not change."""
+    land, cand = clustered
+    kx = build_knn_index(cand, GRID, RES)
+    want = _table(_model().transform(land, kx), len(land), K)
+    monkeypatch.setattr(knn_frontend, "BLOCK_LADDER", BucketLadder(2, 4))
+    res = _model().transform(land, kx)
+    got = _table(res, len(land), K)
+    assert np.array_equal(got[0], want[0]) and np.array_equal(got[1], want[1])
+    assert res.metrics["launches"] > 40
+
+
+def test_expand_ranges_and_block_layout(clustered):
+    assert expand_ranges(np.array([5, 0, 9]), np.array([2, 0, 3])).tolist() == [
+        5, 6, 9, 10, 11]
+    _, cand = clustered
+    kx = build_knn_index(cand, GRID, RES)
+    pb = kx.points
+    rid = np.asarray(pb.rid)
+    assert rid.shape == (pb.n_blocks + 1, pb.width) and (rid[-1] == -1).all()
+    assert sorted(rid[rid >= 0].tolist()) == list(range(len(cand)))
+    # a cell's candidates sit in that cell's blocks, in row order
+    u = int(np.argmax(pb.count))
+    mine = rid[pb.blk_start[u] : pb.blk_start[u + 1]].ravel()
+    assert np.array_equal(mine[mine >= 0], kx.rows[kx.cells == pb.ucells[u]])
+    xs = np.asarray(pb.x)[rid >= 0]
+    assert np.allclose(xs, (cand - kx.shift)[rid[rid >= 0], 0])
+
+
+@pytest.mark.parametrize("res,k", [(8, 1), (8, 3), (10, 2), (10, 5)])
+def test_h3_lattice_rings_equal_the_walked_rings(res, k):
+    """A seed's key plus the ring's offsets are the keys of the cells
+    `k_ring` / `k_loop` walk to, one each."""
+    h3 = H3IndexSystem()
+    rng = np.random.default_rng(res * 10 + k)
+    pts = np.column_stack([rng.uniform(BOX[0], BOX[2], 12),
+                           rng.uniform(BOX[1], BOX[3], 12)])
+    cells = np.asarray(h3.point_to_cell(pts, res))
+    keys, margin = h3.lattice_keys(cells)
+    assert (keys >= 0).all() and margin.min() > 100
+    got = keys[:, None] + h3.lattice_ring(k)[None, :]
+    walked = np.asarray((h3.k_ring if k == 1 else h3.k_loop)(cells, k))
+    want = h3.lattice_keys(walked.ravel())[0].reshape(walked.shape)
+    assert got.shape == want.shape == (12, 7 if k == 1 else 6 * k)
+    for a, b in zip(got, want):
+        assert set(a) == set(b) and len(set(a)) == a.size
+
+
+def test_h3_seeds_near_a_face_edge_or_a_pentagon_have_their_rings_walked():
+    """Over the globe at res 4: a pentagon base cell's children are off
+    the lattice (key -1), a seed near its face's edge has a small margin;
+    `KNNIndex.ring_keys` walks those seeds' rings with the grid and looks
+    the cells up, and steps the rest — the same keys either way."""
+    h3 = H3IndexSystem()
+    rng = np.random.default_rng(0)
+    g = np.column_stack([rng.uniform(-180, 180, 600), rng.uniform(-85, 85, 600)])
+    cells = np.asarray(h3.point_to_cell(g, 4))
+    keys, margin = h3.lattice_keys(cells)
+    aligned = np.flatnonzero(keys >= 0)
+    assert 0 < (keys < 0).sum() < 60 and (margin[aligned] < 3).any()
+    kx = build_knn_index(g[aligned], h3, 4)
+    assert kx.lattice
+    ring = kx.ring_keys(cells, keys, margin, 2)
+    walked = np.asarray(h3.k_loop(cells, 2))
+    want = np.where(
+        walked >= 0, h3.lattice_keys(walked.ravel())[0].reshape(walked.shape), -1)
+    for a, b in zip(ring, want):
+        # a walked cell off the lattice (-1) holds no candidate of this table
+        assert set(b[b >= 0]) <= set(a[a >= 0])
+        assert set(a[a >= 0]) - set(b[b >= 0]) == set() or (b < 0).any()
+    # a table holding an off-lattice cell keeps cell ids
+    assert not build_knn_index(g, h3, 4).lattice
+
+
+def test_h3_transform_exact_at_the_cells_resolution():
+    """The benchmark's grid at a small size: H3 res 10 through the
+    lattice ring program."""
+    rng = np.random.default_rng(10)
+    centre = np.array([-73.98, 40.75])
+    cand = np.concatenate([
+        centre + rng.normal(0, 0.002, (600, 2)),
+        centre + rng.uniform(-0.02, 0.02, (300, 2)),
+    ])
+    land = np.concatenate([centre + rng.normal(0, 0.002, (10, 2)),
+                           centre + rng.uniform(-0.015, 0.015, (20, 2))])
+    res = SpatialKNN(index=H3IndexSystem(), resolution=10, k_neighbours=K,
+                     approximate=False, max_iterations=32).transform(land, cand)
+    ids, dist = _table(res, len(land), K)
+    want_ids, want_d = knn_bruteforce.answers(land, cand, K)
+    assert np.array_equal(ids, want_ids)
+    np.testing.assert_allclose(dist, want_d, atol=1e-12)
+    assert res.metrics["unrested_landmarks"] == 0
+
+
+def test_h3_landmark_at_a_cell_vertex_is_exact():
+    """A landmark at a vertex of its hexagon has unvisited ground one
+    edge away after ring 1 — less than the ``sqrt(area) / 1.5`` the rest
+    criterion credited a ring before PR 33, which let it rest on five
+    neighbours 7.2e-4 out while a nearer candidate lay in a ring-2 cell
+    7.0e-4 out. `H3IndexSystem.ring_width` measures the reach."""
+    h3 = H3IndexSystem()
+    c = np.asarray(h3.point_to_cell(np.array([[-73.98, 40.75]]), 10))
+    centre = np.asarray(h3.cell_center(c))[0]
+    ring1 = np.asarray(h3.k_ring(c, 1)).ravel()
+    best = None
+    for v in np.asarray(h3.cell_boundary(c))[0][:6]:
+        out = (v - centre) / np.linalg.norm(v - centre)
+        t = np.linspace(1e-5, 1.2e-3, 240)
+        walk = np.asarray(h3.point_to_cell(v + t[:, None] * out, 10))
+        first = t[np.flatnonzero(~np.isin(walk, ring1))[0]]
+        if best is None or first < best[0]:
+            best = (first, v, out)
+    reach, v, out = best
+    old = np.sqrt(h3.cell_area_approx(10)) / 1.5
+    assert h3.ring_width(10, c) < reach < old
+    land = (v - 1e-6 * out)[None]
+    near = v + (reach + 3e-6) * out  # in a ring-2 cell, the true nearest
+    r = (np.linalg.norm(near - land[0]) + old) / 2
+    turn = lambda a: np.array([[np.cos(a), -np.sin(a)],  # noqa: E731
+                               [np.sin(a), np.cos(a)]])
+    decoys = np.array([land[0] + turn(a) @ (-out) * r
+                       for a in np.linspace(-0.5, 0.5, 5)])
+    assert np.isin(np.asarray(h3.point_to_cell(decoys, 10)), ring1).all()
+    cand = np.concatenate([decoys, near[None]])
+    res = SpatialKNN(index=h3, resolution=10, k_neighbours=K,
+                     approximate=False, max_iterations=32).transform(land, cand)
+    ids, _ = _table(res, 1, K)
+    want_ids, _ = knn_bruteforce.answers(land, cand, K)
+    assert ids[0, 0] == 5 and np.array_equal(ids, want_ids)
+    assert res.metrics["iterations"] >= 2 and res.metrics["unrested_landmarks"] == 0
+
+
+def test_ring_width_of_a_square_grid_is_what_the_model_always_credited():
+    assert GRID.ring_width(RES) == pytest.approx(3.90625e-3 / 1.5)
+    h3 = H3IndexSystem()
+    # no cells to measure: the mean hexagon's circumradius, with room
+    assert h3.ring_width(10) < 0.62 * np.sqrt(h3.cell_area_approx(10))
+
+
+def test_device_programs_register_their_stage_tables(clustered):
+    """A device trace names the block program's ops by scope
+    (`obs.stages`): every rung launched is registered, nothing lowered
+    until a reader asks."""
+    from mosaic_tpu.obs import stages
+
+    land, cand = clustered
+    stages.clear()
+    n0 = stages.lowerings()
+    _model().transform(land, build_knn_index(cand, GRID, RES))
+    rungs = dict(stages.registered())
+    assert rungs.get("jit_knn_blocks") in knn_frontend.BLOCK_LADDER.buckets
+    assert stages.lowerings() == n0
+    table = stages.tables({"jit_knn_blocks"}, {rungs["jit_knn_blocks"]})
+    assert {"knn.gather", "knn.distance", "knn.topk"} <= set(
+        table["jit_knn_blocks"].values())
