@@ -122,13 +122,13 @@ def pad_index_for_shards(index: ChipIndex, shards: int) -> ChipIndex:
             shift=b.shift,
         ),
         # T is a power of two >= shards, so the table needs no padding; the
-        # hash stays valid because its size is unchanged. table_slot values
+        # hash stays valid because its size is unchanged. The table's slots
         # index the (padded) U axis, which only grew at the end.
         hash_mult=index.hash_mult,
+        table_rows=index.table_rows,
         table_cell=index.table_cell,
         table_slot=index.table_slot,
         table_pack=index.table_pack,
-        pack_low=index.pack_low,
         cell_edges=pad0(index.cell_edges, du),
         cell_ebits=pad0(index.cell_ebits, du),
         cell_slot_geom=pad0(index.cell_slot_geom, du, -1),
@@ -168,10 +168,10 @@ def _index_specs(spec, table_spec) -> ChipIndex:
             shift=P(),
         ),
         hash_mult=P(),
+        table_rows=table_spec,
         table_cell=table_spec,
         table_slot=table_spec,
         table_pack=table_spec,
-        pack_low=P(),
         cell_edges=spec,
         cell_ebits=spec,
         cell_slot_geom=spec,
@@ -192,10 +192,11 @@ def _gather_index(idx: ChipIndex, axis_name: str, table_sharded: bool) -> ChipIn
     """All-gather the PROBE leaves of the chip index over ``axis_name``.
 
     Leading-axis shards were contiguous, so tiled all-gather reassembles the
-    arrays in their original row order and table_slot entries stay valid.
-    Legacy per-chip leaves (cells/chip_rows/chip_geom/chip_core/border) are
-    not read by the probe, so they pass through sharded — no ICI traffic or
-    replicated HBM is spent on them.
+    arrays in their original row order and the table's slot words stay valid.
+    Leaves the probe does not read (cells/chip_rows/chip_geom/chip_core/
+    border, and the host-side table_cell/table_slot/table_pack beside the
+    table_rows it does) pass through sharded — no ICI traffic or replicated
+    HBM is spent on them.
     """
 
     def g(x):
@@ -203,13 +204,7 @@ def _gather_index(idx: ChipIndex, axis_name: str, table_sharded: bool) -> ChipIn
 
     return dataclasses.replace(
         idx,
-        table_cell=g(idx.table_cell) if table_sharded else idx.table_cell,
-        table_slot=g(idx.table_slot) if table_sharded else idx.table_slot,
-        table_pack=(
-            g(idx.table_pack)
-            if table_sharded and idx.table_pack.shape[0]
-            else idx.table_pack
-        ),
+        table_rows=g(idx.table_rows) if table_sharded else idx.table_rows,
         cell_edges=g(idx.cell_edges),
         cell_ebits=g(idx.cell_ebits),
         cell_slot_geom=g(idx.cell_slot_geom),

@@ -45,6 +45,10 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import (
+    Layout as _Layout,
+    with_layout_constraint as _with_layout,
+)
 
 from ..core.geometry.device import (
     DeviceGeometry,
@@ -123,16 +127,20 @@ class ChipIndex:
     Probe fast path (see module docstring):
 
     hash_mult:  (1,) uint64 — multiply-shift hash multiplier.
-    table_cell: (T, B) int64 — bucketed hash table of cell ids (-1 empty);
-                T is a power of two, B the max bucket occupancy.
-    table_slot: (T, B) int32 — cell slot u for each bucket entry (-1 empty).
-    table_pack: (T, B) int64 — slot-packed probe table: when every indexed
-                cell shares its low ``k`` bits (H3 at a fixed resolution
-                keeps the unused finer digits constant), entry =
-                ``(cell & ~low) | (slot + 1)`` and the probe needs ONE
-                gather instead of two. (0, 0) when packing is impossible
-                (too few constant bits); probe falls back to the pair.
-    pack_low:   (2,) int64 — [low-bit mask, constant low-bit value].
+    table_rows: (T, 3B) uint32 — the bucketed hash table as the device
+                probes it, one row a bucket: the B entries' cell ids' low
+                words, their high words, their cell slots u (an empty
+                entry is 0xFFFFFFFF in all three: cell -1, slot -1). T is
+                a power of two, B the max bucket occupancy. The chip has
+                no 64-bit lanes, so a table held as u32 words is fetched
+                by ONE row gather and is never split per launch.
+    table_cell: (T, B) int64, table_slot: (T, B) int32, table_pack: (T, B)
+                int64 or (0, 0) — the same table as `_build_hash` returns
+                it (cell ids, slots, the slot-packed entries where the ids
+                share enough low bits). No device program reads them (jit
+                drops an unused argument): they stay for host inspection,
+                tests, and the benchmark's byte count, which reads B and
+                whether the index packs off their shapes.
 
     Tier-1 flat edge probe (light cells):
 
@@ -179,10 +187,10 @@ class ChipIndex:
     chip_core: jax.Array
     border: DeviceGeometry
     hash_mult: jax.Array
+    table_rows: jax.Array
     table_cell: jax.Array
     table_slot: jax.Array
     table_pack: jax.Array
-    pack_low: jax.Array
     cell_edges: jax.Array
     cell_ebits: jax.Array
     cell_slot_geom: jax.Array
@@ -326,10 +334,11 @@ def host_join(
 def _build_hash(cells: np.ndarray, max_bucket: int = 8):
     """Host: bucketed multiply-shift hash over the unique cell ids.
 
-    Returns (mult, table_cell (T, B), table_slot (T, B)). T is sized ~4x the
-    cell count (power of two); the multiplier is retried (growing the table
-    each time) until the fullest bucket holds <= max_bucket entries, then B
-    shrinks to the realized max. The fallback keeps ``keys`` consistent with
+    Returns (mult, table_cell (T, B), table_slot (T, B), table_pack (T, B)
+    or (0, 0), pack_low (2,)). T is sized ~4x the cell count (power of
+    two); the multiplier is retried (growing the table each time) until
+    the fullest bucket holds <= max_bucket entries, then B shrinks to the
+    realized max. The fallback keeps ``keys`` consistent with
     the final ``bits`` even if every retry clusters: the last computed keys
     are used as-is with a (possibly larger) realized B.
     """
@@ -339,12 +348,15 @@ def _build_hash(cells: np.ndarray, max_bucket: int = 8):
     rng = np.random.default_rng(0xC0FFEE)
     # NOTE: do not chase smaller B by growing T — measured on v5e, gather
     # cost is dominated by table footprint (a 262k-row table probes ~8x
-    # slower per element than an 8k-row one), so T ~= 4U with a hard-won
-    # small B beats a larger table. The probe gather cost is linear in B
-    # ((N, B) rows fetched per batch: 16.6 ms/4M at B=3), so FIRST spend
-    # host-side effort hunting a B<=2 multiplier at the SAME T — success
-    # odds per multiplier are ~1% at T=4U (Poisson tail), so a few
-    # hundred tries (microseconds each over U keys) usually land one.
+    # slower per element than an 8k-row one), so T ~= 4U beats a larger
+    # table. The probe's row gather is paid per index, not per word: the
+    # ledger reads 15.76 ms / 4M points at B=2 and 15.74 at B=3 (PR 33),
+    # and the device fetches a bucket as ONE (3B)-word row (`_hash_rows`),
+    # so a smaller B no longer buys device time. The hunt below for a
+    # B<=2 multiplier at the SAME T predates that reading (success odds
+    # per multiplier are ~1% at T=4U, Poisson tail, so a few hundred
+    # tries, microseconds each over U keys, usually land one); whether
+    # it still earns its set-up time is open (PERF.md §7).
     cells_u64 = cells.astype(np.uint64)
     counts = np.zeros(1, dtype=np.int64)
     mult = np.uint64(1)
@@ -391,8 +403,9 @@ def _build_hash(cells: np.ndarray, max_bucket: int = 8):
 
     # slot-packed variant: if all cells share their low k bits (H3 at a
     # fixed res keeps the unused finer digits constant) and slot+1 fits in
-    # k bits, one int64 entry carries both the cell and the slot — the
-    # device probe then needs a single (N, B) gather instead of two
+    # k bits, one int64 entry carries both the cell and the slot. The
+    # device probe reads `_hash_rows` whether or not an index packs; what
+    # still reads this is the benchmark's byte count (`hash_packed`)
     table_pack = np.zeros((0, 0), dtype=np.int64)
     pack_low = np.zeros(2, dtype=np.int64)
     if U:
@@ -408,6 +421,21 @@ def _build_hash(cells: np.ndarray, max_bucket: int = 8):
             )
             pack_low = np.asarray([low, cells[0] & low], dtype=np.int64)
     return mult, table_cell, table_slot, table_pack, pack_low
+
+
+def _hash_rows(table_cell: np.ndarray, table_slot: np.ndarray) -> np.ndarray:
+    """Host: the (T, B) hash table as the (T, 3B) uint32 rows the device
+    probes — per bucket the B cell ids' low words, their high words and
+    their slots, so one row gather fetches a bucket whole."""
+    cell = table_cell.astype(np.int64, copy=False)
+    return np.concatenate(
+        [
+            (cell & 0xFFFFFFFF).astype(np.uint32),
+            (cell >> 32).astype(np.uint32),
+            table_slot.astype(np.uint32),  # -1 wraps to 0xFFFFFFFF
+        ],
+        axis=1,
+    )
 
 
 def _round8(n: int, lo: int = 8) -> int:
@@ -437,6 +465,9 @@ def build_chip_index(
             M2=int(idx.heavy_slot_geom.shape[1]),
             # a cell past MAX_SLOTS chips a tier is refused, not spilled
             spilled=0,
+            # the probe fetches one row of this many u32 words a point
+            hash_row_words=int(idx.table_rows.shape[1]),
+            hash_gathers=1,
             table_bytes=sum(
                 int(getattr(a, "nbytes", 0))
                 for a in jax.tree_util.tree_leaves(idx)
@@ -499,7 +530,7 @@ def _build_chip_index(
     )
 
     # probe fast path: hash table + flat per-cell edge rows
-    mult, table_cell, table_slot, table_pack, pack_low = _build_hash(uniq)
+    mult, table_cell, table_slot, table_pack, _ = _build_hash(uniq)
 
     from ..core.types import GeometryType
 
@@ -640,10 +671,10 @@ def _build_chip_index(
         chip_core=jnp.asarray(table.is_core),
         border=border,
         hash_mult=jnp.asarray(np.asarray([mult], dtype=np.uint64)),
+        table_rows=jnp.asarray(_hash_rows(table_cell, table_slot)),
         table_cell=jnp.asarray(table_cell),
         table_slot=jnp.asarray(table_slot),
         table_pack=jnp.asarray(table_pack),
-        pack_low=jnp.asarray(pack_low),
         cell_edges=jnp.asarray(cell_edges),
         cell_ebits=jnp.asarray(cell_ebits),
         cell_slot_geom=jnp.asarray(slot_geom),
@@ -875,27 +906,26 @@ def _build_convex_tables(
 
 def _probe_slot(pcells: jax.Array, index: ChipIndex) -> jax.Array:
     """(N,) cell ids -> (N,) cell row u, -1 on miss — the multiply-shift
-    hash probe (one gather on the slot-packed table when available)."""
-    T = index.table_cell.shape[0]
-    shift_bits = jnp.uint64(64 - int(np.log2(T)))
+    hash probe: ONE row gather fetches a point's bucket whole."""
+    T, B = index.table_rows.shape[0], index.table_rows.shape[1] // 3
+    pu = pcells.astype(jnp.uint64)
     key = (
-        (pcells.astype(jnp.uint64) * index.hash_mult[0]) >> shift_bits
+        (pu * index.hash_mult[0]) >> jnp.uint64(64 - int(np.log2(T)))
     ).astype(jnp.int32)
-    if index.table_pack.shape[0]:
-        # slot-packed probe: one (N, B) gather carries cell + slot
-        low = index.pack_low[0]
-        ent = index.table_pack[key]  # (N, B)
-        slotp = (ent & low).astype(jnp.int32)
-        match = (
-            (((ent ^ pcells[:, None]) & ~low) == 0)
-            & (slotp > 0)
-            & ((pcells[:, None] & low) == index.pack_low[1])
-        )
-        return jnp.max(jnp.where(match, slotp - 1, -1), axis=1)  # (N,)
-    cand_cell = index.table_cell[key]  # (N, B)
-    cand_slot = index.table_slot[key]  # (N, B)
-    match = (cand_cell == pcells[:, None]) & (cand_slot >= 0)
-    return jnp.max(jnp.where(match, cand_slot, -1), axis=1)  # (N,)
+    # the chip writes the gathered (N, 3B) block row-major, each row padded
+    # to 128 lanes, and a compare that slices it reads all of it once a
+    # slice (2.76 ms a pass at 4M rows). Held point-minor it is dense: one
+    # transposing copy, then the compare costs nothing (PERF.md §6, PR 34)
+    words = _with_layout(
+        index.table_rows[key].T, _Layout(major_to_minor=(0, 1))
+    )  # (3B, N): low words | high words | slots
+    slot = jax.lax.bitcast_convert_type(words[2 * B:], jnp.int32)
+    match = (
+        (words[:B] == pu.astype(jnp.uint32))
+        & (words[B:2 * B] == (pu >> jnp.uint64(32)).astype(jnp.uint32))
+        & (slot >= 0)
+    )
+    return jnp.max(jnp.where(match, slot, -1), axis=0)  # (N,)
 
 
 def _probe_counts(pcells: jax.Array, index: ChipIndex):

@@ -164,6 +164,9 @@ def test_index_spans_carry_the_layer_and_the_tables(grid, fabric2000):
         index.cell_edges.shape[1], index.cell_slot_geom.shape[1],
         index.heavy_edges.shape[1], index.heavy_slot_geom.shape[1])
     assert b["spilled"] == 0 and b["table_bytes"] > 0 and b["heavy"] > 0
+    # the probe's shape: one gather of a row of 3 words a bucket entry
+    assert (b["hash_row_words"], b["hash_gathers"]) == (
+        3 * index.table_cell.shape[1], 1)
 
 
 def test_refusal_names_the_chip_counts_and_a_resolution(grid):

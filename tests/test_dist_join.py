@@ -8,9 +8,11 @@ Reference semantics: `sql/join/PointInPolygonJoin.scala:68-84` (equi-join
 on cell + ``is_core || st_contains``).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from mosaic_tpu.core.index.h3 import H3IndexSystem
 from mosaic_tpu.core.tessellate import tessellate
@@ -20,8 +22,12 @@ from mosaic_tpu.parallel import (
     make_mesh,
     pad_index_for_shards,
 )
-from mosaic_tpu.parallel.dist_join import pad_points
-from mosaic_tpu.sql.join import build_chip_index, pip_join_points
+from mosaic_tpu.parallel.dist_join import (
+    _gather_index,
+    _index_specs,
+    pad_points,
+)
+from mosaic_tpu.sql.join import _probe_slot, build_chip_index, pip_join_points
 
 RES = 7
 BBOX = (-74.05, 40.60, -73.85, 40.78)
@@ -100,3 +106,58 @@ def test_pad_points_sentinels_never_match(problem, devices):
     match, _ = step(jnp.asarray(p), jnp.asarray(c), idx)
     match = np.asarray(match)
     assert (match[shifted.shape[0] :] == -1).all()
+
+
+def test_cell_sharded_table_rows_are_probed_after_the_all_gather(
+        problem, devices):
+    """The probe's table takes the table's spec: padded for the shards it
+    is the index's own, each chip holds T / shards of its rows, and after
+    the step's all-gather the probe finds what one device finds — while
+    the host-side copies of the table stay sharded (no ICI spent)."""
+    h3, index, shifted, cells, single, nz = problem
+    mesh = make_mesh(8, cell_axis=4)
+    idx = pad_index_for_shards(index, 4)
+    np.testing.assert_array_equal(idx.table_rows, index.table_rows)
+    T = int(index.table_rows.shape[0])
+    specs = _index_specs(P("cell"), P("cell"))
+    assert specs.table_rows == specs.table_cell == P("cell")
+    assert _index_specs(P("cell"), P()).table_rows == P()
+
+    def probe(pcells, shard):
+        assert shard.table_rows.shape[0] == T // 4
+        full = _gather_index(shard, "cell", table_sharded=True)
+        assert full.table_rows.shape[0] == T
+        assert full.table_cell.shape[0] == T // 4
+        return _probe_slot(pcells, full)
+
+    _, c = pad_points(shifted, cells, mesh.size)
+    got = jax.shard_map(
+        probe, mesh=mesh, in_specs=(P(mesh.axis_names), specs),
+        out_specs=P(mesh.axis_names),
+    )(jnp.asarray(c), idx)
+    want = np.asarray(_probe_slot(jnp.asarray(c), index))
+    assert (want >= 0).any() and (want[cells.shape[0]:] == -1).all()
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("res, bbox, n, bucket, packed", [
+    (9, BBOX, 3, 1, True),
+    (11, (-74.00, 40.70, -73.96, 40.73), 2, 2, False),
+], ids=["packs", "does-not-pack"])
+def test_cost_index_shapes_read_what_they_read_before_the_row_table(
+        res, bbox, n, bucket, packed):
+    """`benchmark/harness/cost.py` sizes `join_hbm_share.stream`'s bytes a
+    row from `table_cell.shape[1]` and `table_pack.shape[0] > 0`: on an
+    index whose ids pack and on one whose ids cannot, both read what they
+    read before the probe moved to `table_rows` (the values are the
+    parent commit's), so the share counts the same bytes whatever
+    implements the probe."""
+    from benchmark.harness import cost
+
+    index = build_chip_index(tessellate(
+        synthetic_zones(n, n, bbox=bbox), H3IndexSystem(), res,
+        keep_core_geoms=False))
+    shapes = cost.index_shapes(index)
+    assert shapes["hash_bucket"] == bucket
+    assert shapes["hash_packed"] is packed
+    assert index.table_rows.shape == (index.table_cell.shape[0], 3 * bucket)

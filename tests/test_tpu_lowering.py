@@ -18,7 +18,7 @@ import pytest
 from mosaic_tpu.core.index.h3 import H3IndexSystem
 from mosaic_tpu.core.tessellate import tessellate
 from mosaic_tpu.datasets import random_points, synthetic_zones
-from mosaic_tpu.sql.join import build_chip_index, pip_join_points
+from mosaic_tpu.sql.join import _probe_slot, build_chip_index, pip_join_points
 
 BBOX = (-74.05, 40.60, -73.85, 40.78)
 
@@ -133,6 +133,35 @@ def test_tier1_fetch_in_the_tpu_lowering(problem):
     gathers = [ln for ln in lines if '"stablehlo.gather"' in ln]
     for row in (f"tensor<{U}x{4 * E1}xf32>", f"tensor<{U}x{E1 + 2 * M1 + 1}xi32>"):
         assert sum(f"({row}," in ln for ln in gathers) == 1, row
+
+
+@pytest.mark.parametrize("edge_cap", [None, 8], ids=["light", "heavy"])
+def test_probe_is_one_u32_row_gather(problem, heavy_problem, edge_cap):
+    """The hash probe fetches a point's bucket with ONE gather, from the
+    (T, 3B) u32 row table: in the TPU-target lowering and in the optimized
+    HLO a CPU can compile, and nothing of the table's size is 64 bits wide
+    (the chip would split an int64 table into halves on every launch)."""
+    h3, index = (problem if edge_cap is None else heavy_problem)[:2]
+    T, W = index.table_rows.shape
+    assert index.table_rows.dtype == jnp.uint32 and W % 3 == 0
+    cells = h3.point_to_cell(
+        jnp.asarray(random_points(4096, bbox=BBOX, seed=3), jnp.float32), 7
+    ).astype(jnp.int64)
+    traced = jax.jit(_probe_slot).trace(cells, index)
+    hlo = _tpu_lower(traced)
+    gathers = [ln for ln in hlo.splitlines() if '"stablehlo.gather"' in ln]
+    assert len(gathers) == 1 and f"(tensor<{T}x{W}xui32>," in gathers[0]
+    assert f"tensor<{T}x" not in hlo.replace(f"tensor<{T}x{W}xui32>", "")
+    opt = traced.lower().compile().as_text()
+    lines = opt.splitlines()
+    assert sum(" gather(" in ln for ln in lines) == 1
+    assert not [ln for ln in lines if f"64[{T}," in ln]
+    # the whole join reads the table through that one gather too
+    pts = jnp.zeros((4096, 2), jnp.float32)
+    join = _tpu_lower(jax.jit(pip_join_points).trace(pts, cells, index))
+    assert sum(
+        '"stablehlo.gather"' in ln and f"(tensor<{T}x" in ln
+        for ln in join.splitlines()) == 1
 
 
 @pytest.mark.parametrize("heavy_cap,scatters", [

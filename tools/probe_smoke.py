@@ -218,9 +218,8 @@ def main() -> int:
         detail["oracle_identical"] = True
 
         # 3) timed forced-lane dispatches -> the gated probe_stage keys
-        bucket_b = int(index.table_cell.shape[1]) * (
-            index.table_cell.dtype.itemsize
-            + index.table_slot.dtype.itemsize
+        bucket_b = (
+            int(index.table_rows.shape[1]) * index.table_rows.dtype.itemsize
         )
         edge_b = (
             int(index.cell_edges.shape[-1])
