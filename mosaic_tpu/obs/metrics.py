@@ -312,6 +312,3 @@ def install_bridge() -> None:
     (idempotent; done automatically when ``mosaic_tpu.obs`` imports)."""
     _telemetry.add_observer(_on_event)
 
-
-def uninstall_bridge() -> None:
-    _telemetry.remove_observer(_on_event)

@@ -68,6 +68,11 @@ CLASS_RULES: tuple = (
     #    stream's H2D; snapshot cell pulls are a true D2H)
     ("transfer", "span.dispatch.transfer.h2d"),
     ("transfer", "span.dispatch.transfer.d2h"),
+    #    (a batch `pip_join` call's two puts and its answer's pull; its
+    #    count sync is a pull too: the host waits on three scalars)
+    ("transfer", "span.join.put*"),
+    ("transfer", "span.join.pull"),
+    ("transfer", "span.join.counts"),
     ("transfer", "span.stream.ring_build"),
     ("transfer", "stream_stage.ring_build"),
     # -- queue_wait: admitted but not yet dispatched (in the queue, then
@@ -98,6 +103,15 @@ CLASS_RULES: tuple = (
     ("host_callback", "span.dispatch.guard.handoff"),
     ("host_callback", "span.dispatch.launch"),
     ("host_callback", "span.stream.launch"),
+    #    the batch call's host pieces: enqueueing the cells and join
+    #    programs, the f64 subtract-and-narrow, the recheck's band and
+    #    its f64 host re-join (only what no child covers is left to
+    #    `join.pip`, class `device`: the chip's own intervals come from a
+    #    trace, as for serve)
+    ("host_callback", "span.join.cells"),
+    ("host_callback", "span.join.launch"),
+    ("host_callback", "span.join.shift"),
+    ("host_callback", "span.join.recheck.*"),
     # -- device: the useful work everything above steals from
     #    (the pipeline drain is the bounded window's one blocking pull:
     #    the wall it spends is device execution the host waits out)

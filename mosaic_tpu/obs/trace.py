@@ -179,6 +179,12 @@ class Span:
         self.attrs.update(attrs)
         return self
 
+    def elapsed(self) -> float:
+        """Seconds since the span began, on the clock its ``seconds``
+        will be read from — for an event recorded inside the span that
+        states the span's own duration (`recheck_narrow`)."""
+        return max(time.perf_counter() - self._t0, 0.0)
+
     def end(self, **attrs) -> dict | None:
         """Record the span event and release it (idempotent — a request
         span may race completion against shutdown shedding; the first

@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +58,7 @@ from ..core.index.base import IndexSystem
 from ..core.tessellate import ChipTable, tessellate
 from ..core.types import PackedGeometry
 from ..dispatch import core as _dispatch
+from ..obs import stages as _stages
 from ..obs import trace as _obs_trace
 from ..runtime import (
     faults as _faults,
@@ -928,11 +928,13 @@ def _probe_slot(pcells: jax.Array, index: ChipIndex) -> jax.Array:
     return jnp.max(jnp.where(match, slot, -1), axis=0)  # (N,)
 
 
+@jax.named_scope("pip.counts")
 def _probe_counts(pcells: jax.Array, index: ChipIndex):
     """Device-side exact compaction-cap inputs: one (3,) array of (found
     count, heavy-cell count, convex-cell count) — `pip_join` pulls these
     ints in a single transfer instead of the whole cell column (32 MB at
-    4M points)."""
+    4M points). Its own scope, `pip.counts`, so that the probe that sizes
+    the caps reads apart from `pip.hash_probe`, the probe that answers."""
     u = _probe_slot(pcells, index)
     found = u >= 0
     nf = found.sum()
@@ -1690,12 +1692,52 @@ def clear_join_caches() -> dict:
 _JIT_CELLS_MIN = 65536
 
 
+#: what `pip_join` has told `obs.stages` about: (program, rows, argument
+#: dtypes — an index by identity — and keywords). Bounded like every
+#: program cache: full, it starts over and a program registers once more
+_STAGES_SEEN: set = set()
+_STAGES_SEEN_MAX = 256
+
+
+def _register_stages(fn, args: tuple, kw: dict, rows: int) -> None:
+    """Tell `obs.stages` how to lower ``fn(*args, **kw)`` again (shapes
+    only; nothing is lowered here), so that a device trace can name the
+    batch path's ops by stage. A signature seen before costs one set
+    lookup and no `shapes_of`."""
+    key = (
+        id(fn), rows,
+        tuple(a.dtype if hasattr(a, "dtype") else id(a) for a in args),
+        tuple(
+            (k, v.dtype if hasattr(v, "dtype") else v)
+            for k, v in sorted(kw.items())
+        ),
+    )
+    if key in _STAGES_SEEN:
+        return
+    if len(_STAGES_SEEN) >= _STAGES_SEEN_MAX:
+        _STAGES_SEEN.clear()
+    _STAGES_SEEN.add(key)
+    _stages.register(
+        fn, _stages.shapes_of(args), _stages.shapes_of(kw), rows=rows
+    )
+
+
+def _counts(cells: jax.Array, index: ChipIndex) -> tuple:
+    """(found, heavy-cell, convex-cell) row counts of ``cells`` as ints:
+    one launch of the counts program and one blocking pull."""
+    prog = _dispatch.jit_counts()
+    _register_stages(prog, (cells, index), {}, cells.shape[0])
+    return tuple(int(v) for v in np.asarray(prog(cells, index)))
+
+
 def _assign_cells(index_system, resolution: int, dev: jax.Array, variant: str):
     if (
         dev.shape[0] >= _JIT_CELLS_MIN
         or jax.devices()[0].platform != "cpu"
     ):
-        return _dispatch.cells_prog(index_system, resolution, variant)(dev)
+        prog = _dispatch.cells_prog(index_system, resolution, variant)
+        _register_stages(prog, (dev,), {}, dev.shape[0])
+        return prog(dev)
     if variant == "margin":
         return index_system.point_to_cell_margin(dev, resolution)
     if variant == "alt":
@@ -1870,56 +1912,48 @@ def pip_join(
             return _dispatch.guarded_call(
                 "pip_join.device", core.execute_padded, padded
             )[:nn]
-        dev = jnp.asarray(chunk)
-        if cell_dtype is not None:
-            dev = dev.astype(cell_dtype)
-        if recheck:
-            cells, margins = _assign_cells(
-                index_system, resolution, dev, "margin"
-            )
-        else:
-            cells = _assign_cells(index_system, resolution, dev, "cells")
-            margins = None
+        rows = chunk.shape[0]
+        with _obs_trace.span(
+            "join.put", rows=rows, nbytes=int(chunk.nbytes),
+            dtype=str(chunk.dtype),
+        ):
+            dev = jnp.asarray(chunk)
+            if cell_dtype is not None:
+                dev = dev.astype(cell_dtype)
+        variant = "margin" if recheck else "cells"
+        with _obs_trace.span("join.cells", variant=variant):
+            assigned = _assign_cells(index_system, resolution, dev, variant)
+        cells, margins = assigned if recheck else (assigned, None)
         # exact cap sizing from two scalars (pow2-bucketed to bound the
         # number of distinct compiled programs) — overflow impossible.
         # Direct mode has no tier-1 compaction: found_cap is unused, so
         # None keeps the jit static key stable across batches (and with
         # no heavy cells the count sync is skipped entirely).
-        if writeback == "direct":
-            fcap = None
-            hcap = (
-                min(
-                    _next_pow2(
-                        int(np.asarray(
-                            _dispatch.jit_counts()(cells, chip_index)
-                        )[1]) + 1
-                    ),
-                    chunk.shape[0],
+        fcap = hcap = ccap = None
+        if writeback != "direct" or chip_index.num_heavy_cells:
+            # the launch and the blocking pull of the three scalars; the
+            # span carries what the sync exists to produce, beside the
+            # call span's `compacted` / `tier2_compacted`
+            with _obs_trace.span("join.counts") as sc:
+                nf, nh, nc = _counts(cells, chip_index)
+                if writeback == "direct":
+                    hcap = min(_next_pow2(nh + 1), rows)
+                else:
+                    fcap = min(_next_pow2(nf + 1), rows)
+                    if chip_index.num_heavy_cells:
+                        hcap = min(_next_pow2(nh + 1), fcap)
+                    if probe != "scatter" and chip_index.num_convex_cells:
+                        ccap = min(_next_pow2(nc + 1), rows)
+                sc.set(
+                    found=nf, heavy=nh, convex=nc, found_cap=fcap,
+                    heavy_cap=hcap, convex_cap=ccap,
                 )
-                if chip_index.num_heavy_cells
-                else None
-            )
+        # fault injection may clamp the exactly-sized caps (no-op
+        # without an active plan); the escalation loop grows them back
+        if writeback == "direct":
             caps = _faults.clamp_caps({"heavy_cap": hcap})
             hcap = caps["heavy_cap"]
-            ccap = None
         else:
-            nf, nh, nc = (
-                int(v)
-                for v in np.asarray(_dispatch.jit_counts()(cells, chip_index))
-            )
-            fcap = min(_next_pow2(nf + 1), chunk.shape[0])
-            hcap = (
-                min(_next_pow2(nh + 1), fcap)
-                if chip_index.num_heavy_cells
-                else None
-            )
-            ccap = (
-                min(_next_pow2(nc + 1), chunk.shape[0])
-                if probe != "scatter" and chip_index.num_convex_cells
-                else None
-            )
-            # fault injection may clamp the exactly-sized caps (no-op
-            # without an active plan); the escalation loop grows them back
             caps = _faults.clamp_caps(
                 {"found_cap": fcap, "heavy_cap": hcap, "convex_cap": ccap}
             )
@@ -1931,88 +1965,95 @@ def pip_join(
                 # splits this chunk (convex leaves the light lane; heavy
                 # points pay both tier 1 and the Pallas tier 2)
                 _telemetry.record(
-                    "probe_route", n=chunk.shape[0], probe=probe,
+                    "probe_route", n=rows, probe=probe,
                     found=nf, heavy=nh, convex=nc,
                     light=nf - nc,
                 )
-        sp.attrs["compacted"] |= tier1_compacts(
-            chunk.shape[0], fcap, probe, writeback
-        )
+        sp.attrs["compacted"] |= tier1_compacts(rows, fcap, probe, writeback)
         sp.attrs["tier2_compacted"] |= tier2_compacted(
-            chunk.shape[0], chip_index.num_heavy_cells, fcap, hcap, probe,
-            writeback,
+            rows, chip_index.num_heavy_cells, fcap, hcap, probe, writeback,
         )
-        shifted = jnp.asarray(chunk - shift, dtype=dtype)
+        # the host's f64 subtract and the narrowing to the index's dtype
+        # (numpy's cast, IEEE round-to-nearest, bit-identical to XLA's
+        # convert: `DispatchCore.execute_padded`), then a plain put
+        with _obs_trace.span("join.shift", rows=rows):
+            narrowed = np.asarray(chunk - shift, dtype=dtype)
+        with _obs_trace.span("join.put_shifted", nbytes=int(narrowed.nbytes)):
+            shifted = jnp.asarray(narrowed)
+        del narrowed  # 32 MB at 4M rows: not held through the join and pull
         # every cap that exists escalates together toward the row-count
         # ceiling, at which overflow is structurally impossible
         grow = {k: v for k, v in caps.items() if v is not None}
-        ceilings = {k: chunk.shape[0] for k in grow}
-        if not recheck:
-
-            def attempt(c):
-                return np.asarray(
-                    _dispatch.jit_join()(
-                        shifted, cells, chip_index,
-                        heavy_cap=c.get("heavy_cap", hcap),
-                        found_cap=c.get("found_cap", fcap),
-                        writeback=writeback, probe=probe,
-                        convex_cap=c.get("convex_cap", ccap),
-                    )
-                )
-
-            # `guarded_call` evaluates the fault hooks (maybe_fail +
-            # planned stalls) on this thread, then runs the blocking
-            # dispatch under the site's watchdog deadline with transient
-            # retry: a hung device surfaces as a typed
-            # StalledDeviceError on the same retry path as a dropped
-            # connection, never a silent hang
-            out, _ = run_escalating(
-                lambda c: _dispatch.guarded_call(
-                    "pip_join.device", attempt, c
-                ),
-                grow, ceilings,
-                overflow_count=lambda o: int((o == OVERFLOW).sum()),
-                stage="pip_join",
+        ceilings = {k: rows for k in grow}
+        banded = bool(recheck)
+        band_kw = {}
+        if banded:
+            # --- epsilon-band recheck (SURVEY §7) ---------------------
+            ebk = EDGE_BAND_K if edge_band_k is None else float(edge_band_k)
+            band_kw["edge_eps2"] = jnp.asarray(
+                (ebk * float(np.finfo(np.dtype(dtype)).eps)
+                 * host.coord_scale) ** 2,
+                dtype=dtype,
             )
-            return out
 
-        # --- epsilon-band recheck (SURVEY §7) -------------------------
-        ebk = EDGE_BAND_K if edge_band_k is None else float(edge_band_k)
-        eps2 = jnp.asarray(
-            (ebk * float(np.finfo(np.dtype(dtype)).eps)
-             * host.coord_scale) ** 2,
-            dtype=dtype,
-        )
-
-        def attempt_banded(c):
-            o, nr = _dispatch.jit_join()(
-                shifted, cells, chip_index,
+        def attempt(c):
+            kw = dict(
+                band_kw,
                 heavy_cap=c.get("heavy_cap", hcap),
-                found_cap=c.get("found_cap", fcap), edge_eps2=eps2,
+                found_cap=c.get("found_cap", fcap),
                 writeback=writeback, probe=probe,
                 convex_cap=c.get("convex_cap", ccap),
             )
-            return np.array(o), np.array(nr)  # writable host copies
+            args = (shifted, cells, chip_index)
+            prog = _dispatch.jit_join()
+            _register_stages(prog, args, kw, rows)
+            with _obs_trace.span(
+                "join.launch", banded=banded, found_cap=kw["found_cap"],
+                heavy_cap=kw["heavy_cap"], convex_cap=kw["convex_cap"],
+            ):
+                res = prog(*args, **kw)
+            # waits for the device, then D2H (the banded pair as writable
+            # host copies: the recheck patches them in place)
+            with _obs_trace.span("join.pull") as spull:
+                if banded:
+                    res = np.array(res[0]), np.array(res[1])
+                    spull.set(nbytes=int(res[0].nbytes + res[1].nbytes))
+                else:
+                    res = np.asarray(res)
+                    spull.set(nbytes=int(res.nbytes))
+            return res
 
-        (out, host_mask), _ = run_escalating(
-            lambda c: _dispatch.guarded_call(
-                "pip_join.device", attempt_banded, c
-            ),
+        # `guarded_call` evaluates the fault hooks (maybe_fail +
+        # planned stalls) on this thread, then runs the blocking
+        # dispatch under the site's watchdog deadline with transient
+        # retry: a hung device surfaces as a typed
+        # StalledDeviceError on the same retry path as a dropped
+        # connection, never a silent hang
+        res, _ = run_escalating(
+            lambda c: _dispatch.guarded_call("pip_join.device", attempt, c),
             grow, ceilings,
-            overflow_count=lambda r: int((r[0] == OVERFLOW).sum()),
-            stage="pip_join.recheck",
+            overflow_count=lambda r: int(
+                ((r[0] if banded else r) == OVERFLOW).sum()
+            ),
+            stage="pip_join.recheck" if banded else "pip_join",
         )
-        # PIP-boundary band -> host (host_mask)
-        if margins is not None:
-            meps = float(np.finfo(np.dtype(margins.dtype)).eps)
-            cmk = (
-                CELL_MARGIN_K if cell_margin_k is None
-                else float(cell_margin_k)
-            )
-            km = cmk * meps
-            t_rc = time.perf_counter()
-            flagged = margins[..., 0] < km
-            n_flag = int(flagged.sum())
+        if not banded:
+            return res
+        out, host_mask = res  # PIP-boundary band -> host (host_mask)
+        # ONE span a chunk, whether or not the cell band is empty, and one
+        # `recheck_narrow` event from inside it, on the span's clock
+        with _obs_trace.span("join.recheck.band") as sb:
+            n_flag = 0
+            if margins is not None:
+                meps = float(np.finfo(np.dtype(margins.dtype)).eps)
+                cmk = (
+                    CELL_MARGIN_K if cell_margin_k is None
+                    else float(cell_margin_k)
+                )
+                km = cmk * meps
+                flagged = margins[..., 0] < km
+                n_flag = int(flagged.sum())
+            narrow = {"band": n_flag, "cap": 0, "ties": 0, "mode": "empty"}
             if n_flag:
                 # band-compacted narrow re-join: the epsilon band is
                 # compacted ONCE (the probe tiers' own `_compact`
@@ -2021,58 +2062,57 @@ def pip_join(
                 # resolves the runner-up cell. Only result TIES (plus cell
                 # corners and invalid alternates) escalate to the host
                 # oracle; the full point axis is never re-probed.
-                cap = min(_next_pow2(n_flag), chunk.shape[0])
-                src, _, _, _ = _dispatch.jit_compact()(flagged, cap=cap)
+                cap = min(_next_pow2(n_flag), rows)
+                prog = _dispatch.jit_compact()
+                _register_stages(prog, (flagged,), {"cap": cap}, rows)
+                src, _, _, _ = prog(flagged, cap=cap)
                 alt = _assign_cells(
                     index_system, resolution, dev[src], "alt"
                 )
                 src_np = np.asarray(src)[:n_flag]
                 if alt is None:  # system without alternate-rounding
                     host_mask[src_np] = True
-                    _telemetry.record(
-                        "recheck_narrow", n=chunk.shape[0], band=n_flag,
-                        cap=cap, ties=n_flag, mode="host_all",
-                        seconds=round(time.perf_counter() - t_rc, 6),
-                    )
+                    narrow.update(cap=cap, ties=n_flag, mode="host_all")
                 else:
                     # exact caps for the narrow join from the band's own
                     # scalar counts (pad rows duplicate row 0, so the
                     # counts upper-bound the real band — still exact; the
                     # rejoin runs the scatter path, so the convex count
                     # is unused)
-                    nf2, nh2, _ = (
-                        int(v)
-                        for v in np.asarray(
-                            _dispatch.jit_counts()(alt, chip_index)
-                        )
-                    )
+                    nf2, nh2, _ = _counts(alt, chip_index)
                     fcap2 = min(_next_pow2(nf2 + 1), cap)
                     hcap2 = (
                         min(_next_pow2(nh2 + 1), fcap2)
                         if chip_index.num_heavy_cells
                         else None
                     )
-                    r_alt = np.asarray(
-                        _dispatch.jit_join()(
-                            shifted[src], alt, chip_index,
-                            heavy_cap=hcap2, found_cap=fcap2,
-                        )
-                    )[:n_flag]
+                    args2 = (shifted[src], alt, chip_index)
+                    kw2 = {"heavy_cap": hcap2, "found_cap": fcap2}
+                    prog = _dispatch.jit_join()
+                    _register_stages(prog, args2, kw2, cap)
+                    r_alt = np.asarray(prog(*args2, **kw2))[:n_flag]
                     vertex = np.asarray(margins[src, 1])[:n_flag] < km
                     alt_np = np.asarray(alt)[:n_flag]
                     tie = (
                         (r_alt != out[src_np]) | vertex | (alt_np < 0)
                     )
                     host_mask[src_np[tie]] = True
-                    _telemetry.record(
-                        "recheck_narrow", n=chunk.shape[0], band=n_flag,
-                        cap=cap, caps=[fcap2, hcap2],
-                        ties=int(tie.sum()), mode="alt_rejoin",
-                        seconds=round(time.perf_counter() - t_rc, 6),
+                    narrow.update(
+                        cap=cap, caps=[fcap2, hcap2], ties=int(tie.sum()),
+                        mode="alt_rejoin",
                     )
-        rows = np.nonzero(host_mask)[0]
-        if rows.size:
-            out[rows] = host_join(chunk[rows], host, index_system, resolution)
+            sb.set(**narrow)
+            _telemetry.record(
+                "recheck_narrow", n=rows, seconds=round(sb.elapsed(), 6),
+                **narrow,
+            )
+        with _obs_trace.span("join.recheck.host", n=rows) as sh:
+            host_rows = np.nonzero(host_mask)[0]
+            sh.set(rows=int(host_rows.size))
+            if host_rows.size:
+                out[host_rows] = host_join(
+                    chunk[host_rows], host, index_system, resolution
+                )
         return out
 
     def run_resilient(chunk: np.ndarray) -> np.ndarray:

@@ -290,6 +290,14 @@ def test_recheck_runs_one_narrow_compacted_rejoin():
     # the re-join is sized to the band, not the batch
     assert e["caps"][0] <= e["cap"]
     assert e["ties"] >= 0 and e["seconds"] >= 0
+    # the event is the band span's own record: made inside it, once, with
+    # its numbers and on its clock (`join.recheck.band`, one a chunk)
+    band = [s for s in events
+            if s["event"] == "span" and s["name"] == "join.recheck.band"]
+    assert len(band) == 1 and e["span_id"] == band[0]["span_id"]
+    assert (band[0]["mode"], band[0]["band"], band[0]["cap"]) == \
+        ("alt_rejoin", e["band"], e["cap"])
+    assert e["seconds"] <= band[0]["seconds"]
 
 
 def test_recheck_narrow_respects_margin_override():

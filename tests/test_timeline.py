@@ -68,6 +68,16 @@ class TestKeysAndClasses:
         ("span.dispatch.launch", "host_callback"),
         ("span.stream.launch", "host_callback"),
         ("span.join.probe.scatter", "device"),
+        ("span.join.pip", "device"),
+        ("span.join.put", "transfer"),
+        ("span.join.put_shifted", "transfer"),
+        ("span.join.pull", "transfer"),
+        ("span.join.counts", "transfer"),
+        ("span.join.cells", "host_callback"),
+        ("span.join.launch", "host_callback"),
+        ("span.join.shift", "host_callback"),
+        ("span.join.recheck.band", "host_callback"),
+        ("span.join.recheck.host", "host_callback"),
         ("probe_stage.heavy", "device"),
         ("raster_stage.zonal", "device"),
         ("span.stream.pipeline.drain", "device"),
