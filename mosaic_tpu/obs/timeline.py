@@ -103,13 +103,14 @@ CLASS_RULES: tuple = (
     ("host_callback", "span.dispatch.guard.handoff"),
     ("host_callback", "span.dispatch.launch"),
     ("host_callback", "span.stream.launch"),
-    #    the batch call's host pieces: enqueueing the cells and join
-    #    programs, the f64 subtract-and-narrow, the recheck's band and
+    #    the batch call's host pieces: enqueueing the cells, counts and
+    #    join programs, the f64 subtract-and-narrow, the recheck's band and
     #    its f64 host re-join (only what no child covers is left to
     #    `join.pip`, class `device`: the chip's own intervals come from a
     #    trace, as for serve)
     ("host_callback", "span.join.cells"),
     ("host_callback", "span.join.launch"),
+    ("host_callback", "span.join.counts_launch"),
     ("host_callback", "span.join.shift"),
     ("host_callback", "span.join.recheck.*"),
     # -- device: the useful work everything above steals from

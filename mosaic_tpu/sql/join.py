@@ -1722,12 +1722,18 @@ def _register_stages(fn, args: tuple, kw: dict, rows: int) -> None:
     )
 
 
-def _counts(cells: jax.Array, index: ChipIndex) -> tuple:
-    """(found, heavy-cell, convex-cell) row counts of ``cells`` as ints:
-    one launch of the counts program and one blocking pull."""
+def _launch_counts(cells: jax.Array, index: ChipIndex) -> jax.Array:
+    """Enqueue the counts program over ``cells``: the (found, heavy-cell,
+    convex-cell) row counts as one device array, not waited for."""
     prog = _dispatch.jit_counts()
     _register_stages(prog, (cells, index), {}, cells.shape[0])
-    return tuple(int(v) for v in np.asarray(prog(cells, index)))
+    return prog(cells, index)
+
+
+def _pull_counts(launched: jax.Array) -> tuple:
+    """The three counts of :func:`_launch_counts` as ints: a blocking
+    pull."""
+    return tuple(int(v) for v in np.asarray(launched))
 
 
 def _assign_cells(index_system, resolution: int, dev: jax.Array, variant: str):
@@ -1769,10 +1775,14 @@ def pip_join(
     row per point (-1 = no polygon). ``batch_size`` chunks the point axis
     to bound the probe intermediates. Compaction caps are sized exactly
     from two device-side scalar counts (no cell column ever crosses back
-    to the host), so no point can overflow. Should a cap overflow anyway
-    (shrunken by `runtime.faults` injection, or user-adversarial inputs),
-    the bounded escalation engine (`runtime/escalate.py`) regrows every
-    cap geometrically until the answer is exact or raises a typed
+    to the host), so no point can overflow. The counts program is
+    launched, not waited for, before the host's f64 ``chunk - shift``;
+    its scalars are pulled after the shifted put, so the subtract runs
+    while the device transfers the batch, assigns cells and counts.
+    Should a cap overflow anyway (shrunken by `runtime.faults`
+    injection, or user-adversarial inputs), the bounded escalation
+    engine (`runtime/escalate.py`) regrows every cap geometrically until
+    the answer is exact or raises a typed
     :class:`~mosaic_tpu.runtime.CapacityOverflow` — :data:`OVERFLOW`
     rows never escape this API. Transient device failures retry with
     backoff (`runtime/retry.py`); past the budget the call degrades to
@@ -1928,14 +1938,34 @@ def pip_join(
         # number of distinct compiled programs) — overflow impossible.
         # Direct mode has no tier-1 compaction: found_cap is unused, so
         # None keeps the jit static key stable across batches (and with
-        # no heavy cells the count sync is skipped entirely).
+        # no heavy cells the count sync is skipped entirely). The counts
+        # program is only enqueued here; its scalars are pulled after the
+        # host's subtract, which needs none of them
+        synced = writeback != "direct" or bool(chip_index.num_heavy_cells)
+        if synced:
+            with _obs_trace.span("join.counts_launch") as sl:
+                launched = _launch_counts(cells, chip_index)
+                launched_at = sl.elapsed()  # `hidden_s` runs on this clock
+        # the host's f64 subtract and the narrowing to the index's dtype
+        # (numpy's cast, IEEE round-to-nearest, bit-identical to XLA's
+        # convert: `DispatchCore.execute_padded`), then a plain put — both
+        # while the device transfers the batch, assigns cells and counts
+        with _obs_trace.span("join.shift", rows=rows):
+            narrowed = np.asarray(chunk - shift, dtype=dtype)
+        with _obs_trace.span("join.put_shifted", nbytes=int(narrowed.nbytes)):
+            shifted = jnp.asarray(narrowed)
+        del narrowed  # 32 MB at 4M rows: not held through the join and pull
         fcap = hcap = ccap = None
-        if writeback != "direct" or chip_index.num_heavy_cells:
-            # the launch and the blocking pull of the three scalars; the
-            # span carries what the sync exists to produce, beside the
-            # call span's `compacted` / `tier2_compacted`
-            with _obs_trace.span("join.counts") as sc:
-                nf, nh, nc = _counts(cells, chip_index)
+        if synced:
+            # the blocking pull of the three scalars; the span carries what
+            # the sync exists to produce, beside the call span's
+            # `compacted` / `tier2_compacted`, and `hidden_s`: the host
+            # work done while the device had the sync's work queued
+            with _obs_trace.span(
+                "join.counts",
+                hidden_s=round(sl.elapsed() - launched_at, 6),
+            ) as sc:
+                nf, nh, nc = _pull_counts(launched)
                 if writeback == "direct":
                     hcap = min(_next_pow2(nh + 1), rows)
                 else:
@@ -1973,14 +2003,6 @@ def pip_join(
         sp.attrs["tier2_compacted"] |= tier2_compacted(
             rows, chip_index.num_heavy_cells, fcap, hcap, probe, writeback,
         )
-        # the host's f64 subtract and the narrowing to the index's dtype
-        # (numpy's cast, IEEE round-to-nearest, bit-identical to XLA's
-        # convert: `DispatchCore.execute_padded`), then a plain put
-        with _obs_trace.span("join.shift", rows=rows):
-            narrowed = np.asarray(chunk - shift, dtype=dtype)
-        with _obs_trace.span("join.put_shifted", nbytes=int(narrowed.nbytes)):
-            shifted = jnp.asarray(narrowed)
-        del narrowed  # 32 MB at 4M rows: not held through the join and pull
         # every cap that exists escalates together toward the row-count
         # ceiling, at which overflow is structurally impossible
         grow = {k: v for k, v in caps.items() if v is not None}
@@ -2079,7 +2101,9 @@ def pip_join(
                     # counts upper-bound the real band — still exact; the
                     # rejoin runs the scatter path, so the convex count
                     # is unused)
-                    nf2, nh2, _ = _counts(alt, chip_index)
+                    nf2, nh2, _ = _pull_counts(
+                        _launch_counts(alt, chip_index)
+                    )
                     fcap2 = min(_next_pow2(nf2 + 1), cap)
                     hcap2 = (
                         min(_next_pow2(nh2 + 1), fcap2)

@@ -2,7 +2,10 @@
 a child span of its `join.pip`, the three programs it launches register
 with `obs.stages` once a signature, `_probe_counts` reads under its own
 scope `pip.counts`, and splitting the shift from its put changed no
-answer. Counts, names and answers only: a CPU run states no time."""
+answer. Since ISSUE 36 the counts program is launched before the host's
+subtract and pulled after the shifted put: the order of the pieces, what
+`hidden_s` holds, one counts launch a chunk, and every lane's answer.
+Counts, names, orders and answers only: a CPU run states no time."""
 
 import numpy as np
 import pytest
@@ -30,16 +33,19 @@ ZONES = [
 ]
 #: the pieces of a default call, in the order they run
 DEFAULT_PIECES = [
-    "join.put", "join.cells", "join.counts", "join.shift",
-    "join.put_shifted", "join.launch", "join.pull",
+    "join.put", "join.cells", "join.counts_launch", "join.shift",
+    "join.put_shifted", "join.counts", "join.launch", "join.pull",
 ]
+#: the two that exist only where the call syncs on its counts
+SYNC_PIECES = ("join.counts_launch", "join.counts")
 RECHECK_PIECES = DEFAULT_PIECES + ["join.recheck.band", "join.recheck.host"]
 #: what each span carries beside its identity and seconds
 ATTRIBUTES = {
     "join.put": {"rows", "nbytes", "dtype"},
     "join.cells": {"variant"},
+    "join.counts_launch": set(),
     "join.counts": {"found", "heavy", "convex", "found_cap", "heavy_cap",
-                    "convex_cap"},
+                    "convex_cap", "hidden_s"},
     "join.shift": {"rows"},
     "join.put_shifted": {"nbytes"},
     "join.launch": {"banded", "found_cap", "heavy_cap", "convex_cap"},
@@ -57,6 +63,13 @@ def table():
 @pytest.fixture(scope="module")
 def index(table):
     return build_chip_index(table)
+
+
+@pytest.fixture(scope="module")
+def heavy_index(table):
+    idx = build_chip_index(table, edge_cap=2)
+    assert idx.num_heavy_cells > 0
+    return idx
 
 
 @pytest.fixture(scope="module")
@@ -137,16 +150,15 @@ def test_children_repeat_per_chunk_under_one_root(points, index):
 
 
 def test_direct_writeback_syncs_only_where_the_index_has_heavy_cells(
-        points, table, index):
+        points, index, heavy_index):
     assert index.num_heavy_cells == 0
     got, spans, _ = _call(points, index, writeback="direct")
     _root, kids = _pieces(spans)
     assert [k["name"] for k in kids] == [
-        p for p in DEFAULT_PIECES if p != "join.counts"]
+        p for p in DEFAULT_PIECES if p not in SYNC_PIECES]
+    assert not any("hidden_s" in k for k in kids)  # no sync, nothing under it
     assert {k["found_cap"] for k in kids if k["name"] == "join.launch"} == {None}
-    heavy = build_chip_index(table, edge_cap=2)
-    assert heavy.num_heavy_cells > 0
-    got_h, spans, _ = _call(points, heavy, writeback="direct")
+    got_h, spans, _ = _call(points, heavy_index, writeback="direct")
     _root, kids = _pieces(spans)
     assert [k["name"] for k in kids] == DEFAULT_PIECES
     c = next(k for k in kids if k["name"] == "join.counts")
@@ -166,9 +178,171 @@ def test_an_escalation_attempt_is_one_more_launch_and_pull(points, index):
     caps = [k["found_cap"] for k in launches]
     assert caps[0] == 16 and caps == sorted(caps) and len(set(caps)) == len(caps)
     assert [k["name"] for k in kids if k["name"] not in
-            ("join.launch", "join.pull")] == DEFAULT_PIECES[:5]
+            ("join.launch", "join.pull")] == DEFAULT_PIECES[:6]
     np.testing.assert_array_equal(
         got, host_join(points, index.host, CUSTOM, RES))
+
+
+# ------------------- the subtract under the count sync (ISSUE 36)
+
+LANES = {
+    "default": {},
+    "recheck": {"recheck": True},
+    "chunks": {"batch_size": 256},
+    "chunks-recheck": {"batch_size": 256, "recheck": True},
+}
+
+
+def _chunks(kids):
+    """The direct children, one list a chunk (a chunk opens with its put)."""
+    out = []
+    for k in kids:
+        if k["name"] == "join.put":
+            out.append([])
+        out[-1].append(k)
+    return out
+
+
+@pytest.mark.parametrize("lane", sorted(LANES))
+def test_the_subtract_and_its_put_lie_between_the_counts_launch_and_its_pull(
+        points, index, lane):
+    _got, spans, _ = _call(points, index, **LANES[lane])
+    _root, kids = _pieces(spans)
+    chunks = _chunks(kids)
+    rows = LANES[lane].get("batch_size", points.shape[0])
+    assert len(chunks) == points.shape[0] // rows
+    for chunk in chunks:
+        by = {k["name"]: k for k in chunk}
+        launch, shift, put, pull = (
+            by["join.counts_launch"], by["join.shift"],
+            by["join.put_shifted"], by["join.counts"])
+        # one thread, no nesting: a piece starts after the one before it ended
+        starts = [k["start_mono"] for k in (
+            by["join.cells"], launch, shift, put, pull, by["join.launch"])]
+        assert starts == sorted(starts)
+        # (start_mono is rounded to the microsecond)
+        assert launch["start_mono"] + launch["seconds"] <= shift["start_mono"] + 2e-6
+        # what the host did while the device had the sync's work queued
+        assert pull["hidden_s"] >= shift["seconds"] + put["seconds"]
+        assert pull["hidden_s"] <= pull["start_mono"] - launch["start_mono"] + 2e-6
+
+
+@pytest.mark.parametrize("lane", sorted(LANES))
+def test_the_counts_program_is_launched_once_a_chunk(
+        points, index, monkeypatch, lane):
+    launched = []
+    real = dispatch.jit_counts()
+
+    def counting(cells, idx):
+        launched.append(cells.shape[0])
+        return real(cells, idx)
+
+    monkeypatch.setattr(dispatch, "jit_counts", lambda: counting)
+    monkeypatch.setattr(join_mod, "_register_stages", lambda *a, **k: None)
+    _got, spans, _ = _call(points, index, **LANES[lane])
+    _root, kids = _pieces(spans)
+    rows = LANES[lane].get("batch_size", points.shape[0])
+    assert launched == [rows] * (points.shape[0] // rows)
+    assert len(launched) == sum(k["name"] == "join.counts" for k in kids)
+    assert len(launched) == sum(k["name"] == "join.counts_launch" for k in kids)
+
+
+def test_with_no_sync_the_shift_still_follows_the_cells_launch(
+        points, index, monkeypatch):
+    """`writeback="direct"` on an index with no heavy cell launches no
+    counts program, records neither counts span, and subtracts right after
+    the cells launch."""
+    def refuse():
+        raise AssertionError("the counts program has no caller here")
+
+    monkeypatch.setattr(dispatch, "jit_counts", refuse)
+    got, spans, _ = _call(points, index, writeback="direct")
+    _root, kids = _pieces(spans)
+    names = [k["name"] for k in kids]
+    assert not set(SYNC_PIECES) & set(names)
+    assert names.index("join.shift") == names.index("join.cells") + 1
+    by = {k["name"]: k for k in kids}
+    assert by["join.cells"]["start_mono"] <= by["join.shift"]["start_mono"]
+    np.testing.assert_array_equal(
+        got, host_join(points, index.host, CUSTOM, RES))
+
+
+@pytest.mark.parametrize("writeback", ["scatter", "gather", "direct"])
+def test_heavy_cap_as_dispatched_is_sized_from_the_late_pull(
+        points, heavy_index, writeback):
+    got, spans, _ = _call(points, heavy_index, writeback=writeback)
+    _root, kids = _pieces(spans)
+    assert [k["name"] for k in kids] == DEFAULT_PIECES
+    by = {k["name"]: k for k in kids}
+    c, n = by["join.counts"], points.shape[0]
+    assert c["heavy"] > 0
+    if writeback == "direct":
+        want_found, want_heavy = None, min(join_mod._next_pow2(c["heavy"] + 1), n)
+    else:
+        want_found = min(join_mod._next_pow2(c["found"] + 1), n)
+        want_heavy = min(join_mod._next_pow2(c["heavy"] + 1), want_found)
+    assert (c["found_cap"], c["heavy_cap"]) == (want_found, want_heavy)
+    assert by["join.launch"]["heavy_cap"] == want_heavy
+    assert by["join.launch"]["found_cap"] == want_found
+    np.testing.assert_array_equal(
+        got, host_join(points, heavy_index.host, CUSTOM, RES))
+
+
+ANSWER_LANES = {
+    **LANES,
+    "heavy": {"heavy": True},
+    "heavy-recheck": {"heavy": True, "recheck": True},
+    "adaptive": {"probe": "adaptive"},
+    "heavy-adaptive": {"heavy": True, "probe": "adaptive"},
+}
+
+
+@pytest.mark.parametrize("lane", sorted(ANSWER_LANES))
+def test_every_lane_answers_as_the_host_oracle_and_joins_the_same_bits(
+        points, index, heavy_index, monkeypatch, lane):
+    """The order of the sync and the subtract reaches no program: the join
+    is handed the one-expression shift's bits, the cells the counts saw and
+    the caps a blocking count of those cells sizes, and every row is the
+    f64 host oracle's."""
+    kw = dict(ANSWER_LANES[lane])
+    idx = heavy_index if kw.pop("heavy", False) else index
+    seen = []
+    real = dispatch.jit_join()
+
+    def spy(shifted, cells, index_, **k):
+        seen.append((shifted, cells, k))
+        return real(shifted, cells, index_, **k)
+
+    monkeypatch.setattr(dispatch, "jit_join", lambda: spy)
+    monkeypatch.setattr(join_mod, "_register_stages", lambda *a, **k: None)
+    got, spans, events = _call(points, idx, **kw)
+    np.testing.assert_array_equal(
+        got, host_join(points, idx.host, CUSTOM, RES))
+    rows = kw.get("batch_size", points.shape[0])
+    shift = np.asarray(idx.host.shift, dtype=np.float64)
+    assert len(seen) == points.shape[0] // rows
+    for i, (shifted, cells, k) in enumerate(seen):
+        chunk = points[i * rows:(i + 1) * rows]
+        before = jnp.asarray(chunk - shift, dtype=idx.border.verts.dtype)
+        np.testing.assert_array_equal(
+            np.asarray(shifted).view(np.uint32),
+            np.asarray(before).view(np.uint32))
+        nf, nh, nc = (int(v) for v in np.asarray(
+            dispatch.jit_counts()(cells, idx)))
+        fcap = min(join_mod._next_pow2(nf + 1), rows)
+        assert k["found_cap"] == fcap
+        assert k["heavy_cap"] == (
+            min(join_mod._next_pow2(nh + 1), fcap)
+            if idx.num_heavy_cells else None)
+        assert k["convex_cap"] == (
+            min(join_mod._next_pow2(nc + 1), rows)
+            if "probe" in kw and idx.num_convex_cells else None)
+    routes = [e for e in events if e["event"] == "probe_route"]
+    assert len(routes) == (len(seen) if "probe" in kw else 0)
+    for e, c in zip(routes, (s for s in spans if s["name"] == "join.counts")):
+        # recorded after the late pull, from its numbers
+        assert (e["found"], e["heavy"], e["convex"]) == (
+            c["found"], c["heavy"], c["convex"])
 
 
 def test_a_band_with_rows_and_no_alternate_cells_goes_to_the_host(points, index):

@@ -75,6 +75,7 @@ class TestKeysAndClasses:
         ("span.join.counts", "transfer"),
         ("span.join.cells", "host_callback"),
         ("span.join.launch", "host_callback"),
+        ("span.join.counts_launch", "host_callback"),
         ("span.join.shift", "host_callback"),
         ("span.join.recheck.band", "host_callback"),
         ("span.join.recheck.host", "host_callback"),
