@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import math
+
 
 class Comparison:
     def __init__(self, name: str, value: float, limit: float, why: str):
@@ -13,6 +15,12 @@ class Comparison:
     def ok(self) -> bool:
         # NaN never passes
         return self.value <= self.limit
+
+    def entry(self) -> dict:
+        """The number beside its limit, as the result line carries it (a
+        value that is no finite number goes as its name: JSON has no NaN)."""
+        value = self.value if math.isfinite(self.value) else repr(self.value)
+        return {"value": value, "limit": self.limit}
 
     def line(self) -> str:
         return (

@@ -10,6 +10,7 @@ kind; each per-layer metric names its reader. All are found by name
 from __future__ import annotations
 
 import os
+import sys
 import time
 
 from . import check as _check
@@ -195,5 +196,10 @@ def run_cell(
             "device_ops": ctx.trace_reduction["device_ops"],
             "idle_gaps": ctx.trace_reduction["idle_gaps"],
         }
+    # each number compared beside its limit: the line's last key, and the
+    # last lines of standard error (what a record of a failed run keeps)
+    line["checks"] = {c.name: c.entry() for c in comparisons}
+    for c in comparisons:
+        print(c.line(), file=sys.stderr, flush=True)
     return line
 

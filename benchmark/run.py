@@ -9,7 +9,9 @@ traffic from ``--seed``, warms the cell's shapes (set-up), measures for
 ``--seconds``, checks the answers against the plain reference after the
 window, and prints one JSON object with exactly the keys ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` with
-``--trace 1``). On any failure it exits non-zero and prints no result.
+``--trace 1``) and, last, ``checks``: each number compared beside its
+limit, which are the last lines of standard error too. On any failure it
+exits non-zero and prints no result.
 
 ``--rehearsal`` is the CPU debugging mode of the harness's own tests: it is
 refused unless ``JAX_PLATFORMS=cpu`` asked for the CPU by name and the

@@ -17,13 +17,30 @@ Set-up calls `pip_join` once on every batch of the pool: the call sizes its
 caps from each batch's own counts (rounded up to a power of two), so a
 batch the warm-up has not seen could compile inside the window.
 
+``pool_banded_batches`` (optional; with it ``pool_max_draws``, 16 where it is
+not given) makes every seed's pool the same work where ``recheck=True``
+does a fixed piece of work for a batch as a whole: a batch that holds even
+one row in the cell band (the program's `recheck_narrow` event, ``band``)
+pays a compaction, alternate cells, counts and a re-join, a batch that holds
+none pays nothing, and a seed's first ``pool_batches`` draws hold none to
+all of them by chance. With the parameter set-up draws one batch
+after another — draw ``i`` is slot ``i`` of the one-call pool, so the first
+draws ARE the pool a mix without the parameter gets — warms each with its
+one call as before, reads the event that call recorded, and keeps the first
+``pool_banded_batches`` banded and the first ``pool_batches -
+pool_banded_batches`` unbanded batches, in the order drawn. After
+``pool_max_draws`` draws it keeps whatever comes and says so
+(``pool_composed=False``); a call that records no such event (no recheck:
+the control) keeps the first draws.
+
 End-to-end: ``batch_rows_per_s`` — rows answered in the window over the
 window's seconds, host clock from before the first call to after the last
 one's answer has arrived and been read, divided by the cell's chips. The
 client reads every answer once: each call's answer after the first pass
 over the pool is compared, inside the window, with the first pass's answer
-on the same batch, and dropped (keeping every 16 MB answer made later
-calls slower: PERF.md section 6, PR 26).
+on the same batch, and dropped before the next call is made (keeping every
+16 MB answer made later calls slower: PERF.md section 6, PR 26; keeping
+only the last one through the next call did too: PR 39).
 
 Correct: the first pass's answers are kept. Once the window has closed each
 batch of the pool is joined once more and every timed answer on that batch
@@ -77,6 +94,44 @@ def _join(ctx, args: dict, batch):
     )
 
 
+class _BandWatch:
+    """Observer of the program's `recheck_narrow` events: the cell-band
+    rows of the calls made since `reset` (None where none was recorded)."""
+
+    def __init__(self):
+        self.band = None
+
+    def reset(self) -> None:
+        self.band = None
+
+    def __call__(self, evt: dict) -> None:
+        if evt.get("event") == "recheck_narrow":
+            self.band = (self.band or 0) + int(evt.get("band", 0))
+
+
+def compose_pool(draw, k: int, banded: int, max_draws: int):
+    """Keep the first ``banded`` banded and the first ``k - banded``
+    unbanded of the batches ``draw(i)`` gives, ``i`` = 0, 1, …, in the
+    order drawn. ``draw(i)`` returns ``(batch, band)``: the batch, warmed,
+    and the cell-band rows its call recorded (None: no event, which keeps
+    the batch whatever it is). After ``max_draws`` draws every batch is
+    kept. Returns ``(batches, bands, draws, composed)``."""
+    room = {True: banded, False: k - banded}
+    kept, bands, draws = [], [], 0
+    while len(kept) < k:
+        batch, band = draw(draws)
+        draws += 1
+        cls = None if band is None else band > 0
+        if cls is not None and room[cls] > 0:
+            room[cls] -= 1
+        elif cls is not None and draws <= max_draws:
+            continue  # its class is full: draw again
+        kept.append(batch)
+        bands.append(band)
+    composed = all(v == 0 for v in room.values())
+    return kept, bands, draws, composed
+
+
 def prepare(ctx) -> dict:
     import numpy as np
 
@@ -84,20 +139,54 @@ def prepare(ctx) -> dict:
     points = ctx.spec.module("generators", "points")
     k = int(mix["pool_batches"])
     args, control = _arguments(ctx), _control(ctx)
-    with ctx.spans.span("pool_build"):
-        gen = points.make_generator(mix["points"], dep.bbox, dep.batch, slots=k)
-        on_device = gen(points.seed_key(ctx.seed))
-        pool = np.asarray(on_device)  # (k, batch, 2) float64, host memory
-        del on_device
-    with ctx.spans.span("call_warmup"):
-        for b in range(k):
-            _join(ctx, args, pool[b])
+    note = {}
+    if "pool_banded_batches" not in mix:
+        with ctx.spans.span("pool_build"):
+            gen = points.make_generator(
+                mix["points"], dep.bbox, dep.batch, slots=k
+            )
+            on_device = gen(points.seed_key(ctx.seed))
+            pool = np.asarray(on_device)  # (k, batch, 2) float64, host memory
+            del on_device
+        with ctx.spans.span("call_warmup"):
+            for b in range(k):
+                _join(ctx, args, pool[b])
+    else:
+        import jax
+
+        from mosaic_tpu.runtime import telemetry
+
+        gen = points.make_generator(mix["points"], dep.bbox, dep.batch)
+        key, watch = points.seed_key(ctx.seed), _BandWatch()
+
+        def draw(i):
+            with ctx.spans.span("pool_build"):
+                batch = np.asarray(gen(jax.random.fold_in(key, i)))
+            watch.reset()
+            with ctx.spans.span("call_warmup"):
+                _join(ctx, args, batch)
+            return batch, watch.band
+
+        telemetry.add_observer(watch)
+        try:
+            kept, bands, draws, composed = compose_pool(
+                draw, k, int(mix["pool_banded_batches"]),
+                int(mix.get("pool_max_draws", 16)),
+            )
+        finally:
+            telemetry.remove_observer(watch)
+        with ctx.spans.span("pool_build"):
+            pool = np.stack(kept)  # (k, batch, 2) float64, host memory
+            del kept
+        note = {"pool_draws": draws, "pool_band_rows": bands,
+                "pool_composed": composed}
     ctx.say(
         "batch_ready", pool=tuple(pool.shape), dtype=str(pool.dtype),
         arguments={k_: str(v) for k_, v in args.items()},
         control=control,
         pool_build_s=round(ctx.spans.seconds("pool_build"), 3),
         call_warmup_s=round(ctx.spans.seconds("call_warmup"), 3),
+        **note,
     )
     # first: the first pass's answers, one a pool batch; odd: (batch,
     # answer) of every later call whose answer was unlike the first pass's
@@ -134,6 +223,10 @@ def window(ctx, st) -> dict:
             if differ or getattr(answer, "degraded", False):
                 odd.append((b, answer))
                 st["unlike"] += differ
+        # let go BEFORE the next call: an answer still bound here through
+        # the next call put the allocator into a cycle over the pool, one
+        # call in four or two in four 35 ms longer (PERF.md section 6, PR 39)
+        del answer
         calls += 1
         t = time.perf_counter()
         if not ctx.tracer.active:

@@ -118,6 +118,23 @@ def make_copy(tmp: str, mesh=None) -> str:
     return root
 
 
+def append_as_a_pr(root: str, add) -> None:
+    """Run ``add(tree, bench)`` — new files under the copy's tree, appended
+    entries in ``bench`` — and hold it to what a later PR may do: no file
+    that was there is written, ``BENCHMARK.json`` alone is rewritten."""
+    before = _snapshot(root)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path, encoding="utf-8") as f:
+        bench = json.load(f)
+    add(os.path.join(root, "benchmark"), bench)
+    with open(path, "w", encoding="utf-8") as f:
+        json.dump(bench, f, indent=1)
+    after = _snapshot(root)
+    changed = [p for p, h in before.items()
+               if p != "BENCHMARK.json" and after.get(p) != h]
+    assert not changed, f"the additions edited existing files: {changed}"
+
+
 def _snapshot(root: str) -> dict:
     import hashlib
 

@@ -2,22 +2,37 @@
 later PR appends a per-layer metric and a cell as new files and entries —
 the cell's name joining the ``workloads`` of metrics that are there — edits
 no file of the benchmark, and every check of the contract and of each
-per-layer entry still holds on the result."""
+per-layer entry still holds on the result. A second "PR" then appends one
+more metric after the first's, and both still pass: the case an assertion
+on the LAST entries of ``per_layer`` refused until PR 39.
 
-import json
+The names below are RESERVED for this test and mean nothing else: no cell,
+mix or metric of the real benchmark, and none of ``PERF.md``'s queue, may
+take them (the first cell of that queue once had this test's names, and
+could not go in until the test let go of them)."""
+
 import os
 import shutil
 
-from bh_fixtures import REPO, _snapshot, _write
+import pytest
+
+from bh_fixtures import REPO, _write, append_as_a_pr
 
 from benchmark.harness.spec import Spec
+from test_benchmark_batch_spans import (
+    test_the_twenty_entries_are_the_batch_cells_and_move_their_rate
+    as check_batch_entries_keep_their_order,
+)
 from test_benchmark_contract import check_cells, check_configs, check_metrics
 from test_benchmark_program_spans import check_entry
 
-CELL, METRIC = "taxi.stream-uniform", "added_dispatches.stream"
+CELL, MIX = "additive-probe.cell", "additive-probe-mix"
+METRIC = "additive_probe_dispatches.stream"
+SECOND_METRIC = "additive_probe_calls.batch"
+BATCH_CELLS = ["taxi.batch", "taxi.batch-exact"]
 
 
-def _copy_with_additions(tmp) -> str:
+def _copy(tmp) -> str:
     root = os.path.join(str(tmp), "copy")
     os.makedirs(root)
     shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
@@ -25,25 +40,26 @@ def _copy_with_additions(tmp) -> str:
         os.path.join(REPO, "benchmark"), os.path.join(root, "benchmark"),
         ignore=shutil.ignore_patterns("__pycache__", ".traces", ".cache"),
     )
-    before = _snapshot(root)
-    tree = os.path.join(root, "benchmark")
-    mix = Spec(root).traffic("pickups-hotspot")
+    return root
+
+
+def _first_pr(tree: str, bench: dict) -> None:
+    """A mix file, a workloads file, a ``layer_metrics`` file; one
+    ``workloads`` entry, one ``per_layer`` entry, the cell's name joined to
+    lists that are there."""
+    mix = Spec(os.path.dirname(tree)).traffic("pickups-hotspot")
     mix.pop("name")
-    mix["points"] = dict(mix["points"], hotspot_share=0.0)
-    _write(os.path.join(tree, "traffic", "pickups-uniform.json"), mix)
+    mix["points"] = dict(mix["points"], hotspot_share=0.5)
+    _write(os.path.join(tree, "traffic", MIX + ".json"), mix)
     _write(os.path.join(tree, "workloads", CELL + ".json"),
            {"check": {"sample_rows": 262144}})
     _write(os.path.join(tree, "layer_metrics", METRIC + ".json"), {
         "what": "dispatches the window made", "reader": "counter",
         "params": {"name": "dispatches"},
     })
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path, encoding="utf-8") as f:
-        bench = json.load(f)
     bench["workloads"].append({
-        "name": CELL, "config": "taxi-zones-h3r9",
-        "traffic": "pickups-uniform", "chips": 1,
-        "why": "the stream on uniform points: control for skew",
+        "name": CELL, "config": "taxi-zones-h3r9", "traffic": MIX,
+        "chips": 1, "why": "reserved for the additivity test: half hotspot",
     })
     for m in bench["end_to_end"] + bench["per_layer"]:
         if m["name"] in ("rows_per_s", "tier1_device_ms.stream"):
@@ -53,38 +69,94 @@ def _copy_with_additions(tmp) -> str:
         "source": "program_counter", "layer": "frontends",
         "moves": "rows_per_s", "workloads": [CELL],
     })
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump(bench, f, indent=1)
-    after = _snapshot(root)
-    changed = [p for p, h in before.items()
-               if p != "BENCHMARK.json" and after.get(p) != h]
-    assert not changed, f"the additions edited existing files: {changed}"
-    return root
 
 
-def test_appended_metric_and_cell_pass_every_check_of_the_real_file(tmp_path):
-    root = _copy_with_additions(tmp_path)
+def _second_pr(tree: str, bench: dict) -> None:
+    """One more metric, of the batch cells, after whatever is last."""
+    _write(os.path.join(tree, "layer_metrics", SECOND_METRIC + ".json"), {
+        "what": "calls the window made", "reader": "counter",
+        "params": {"name": "calls"},
+    })
+    bench["per_layer"].append({
+        "name": SECOND_METRIC, "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "frontends",
+        "moves": "batch_rows_per_s", "workloads": list(BATCH_CELLS),
+    })
+
+
+def _every_check(root: str) -> Spec:
     check_configs(root)
     check_cells(root)
     check_metrics(root)
     spec = Spec(root)
     for m in spec.benchmark["per_layer"]:
         check_entry(spec, m["name"])
-    # the new cell reads what it was listed under, old and new
-    assert [m["name"] for m in spec.end_to_end(CELL)] == ["rows_per_s", "setup_s"]
-    assert {m["name"] for m in spec.per_layer(CELL)} == \
-        {"tier1_device_ms.stream", METRIC}
-    assert spec.traffic(spec.cell(CELL)["traffic"])["kind"] == "device_ring_stream"
-    # and the accepted entries are the real file's, but for the appended name
+    check_batch_entries_keep_their_order(spec)
+    return spec
+
+
+def _accepted_entries_are_the_real_files(spec: Spec) -> None:
+    """Entry for entry the real file's, but for the appended cell's name."""
     real = Spec(REPO).benchmark
     for section in ("configs", "workloads", "end_to_end", "per_layer"):
+        assert len(spec.benchmark[section]) >= len(real[section])
         for was, now in zip(real[section], spec.benchmark[section]):
             now = dict(now)
             if "workloads" in now:
                 now["workloads"] = [c for c in now["workloads"] if c != CELL]
             assert was == now
+
+
+def test_the_names_are_reserved_for_this_test():
+    real = Spec(REPO).benchmark
+    taken = {e["name"] for s in ("workloads", "end_to_end", "per_layer")
+             for e in real[s]} | {w["traffic"] for w in real["workloads"]}
+    assert not taken & {CELL, MIX, METRIC, SECOND_METRIC}, (
+        "these names are reserved for tests/benchmark_harness/"
+        "test_benchmark_additive.py; give the real entry another")
+    for kind, name in (("traffic", MIX), ("workloads", CELL),
+                       ("layer_metrics", METRIC),
+                       ("layer_metrics", SECOND_METRIC)):
+        assert not os.path.exists(
+            os.path.join(REPO, "benchmark", kind, name + ".json"))
+
+
+def test_appended_metric_and_cell_pass_every_check_of_the_real_file(tmp_path):
+    root = _copy(tmp_path)
+    append_as_a_pr(root, _first_pr)
+    spec = _every_check(root)
+    # the new cell reads what it was listed under, old and new
+    assert [m["name"] for m in spec.end_to_end(CELL)] == ["rows_per_s", "setup_s"]
+    assert {m["name"] for m in spec.per_layer(CELL)} == \
+        {"tier1_device_ms.stream", METRIC}
+    assert spec.traffic(spec.cell(CELL)["traffic"])["kind"] == "device_ring_stream"
+    _accepted_entries_are_the_real_files(spec)
     # no cell of the real file reads anything more or less than before
-    for w in real["workloads"]:
+    real = Spec(REPO)
+    for w in real.benchmark["workloads"]:
         for reads in (Spec.end_to_end, Spec.per_layer):
             assert [m["name"] for m in reads(spec, w["name"])] == \
-                [m["name"] for m in reads(Spec(REPO), w["name"])]
+                [m["name"] for m in reads(real, w["name"])]
+
+
+@pytest.mark.parametrize("order", [(_first_pr, _second_pr),
+                                   (_second_pr, _first_pr)],
+                         ids=["stream-then-batch", "batch-then-stream"])
+def test_two_prs_append_a_metric_each_and_both_pass(tmp_path, order):
+    """One metric appended after the accepted list, then one more after
+    it, each by a "PR" of its own: every check holds after each."""
+    root = _copy(tmp_path)
+    real = Spec(REPO)
+    for n, add in enumerate(order, start=1):
+        append_as_a_pr(root, add)
+        spec = _every_check(root)
+        _accepted_entries_are_the_real_files(spec)
+        assert len(spec.benchmark["per_layer"]) == \
+            len(real.benchmark["per_layer"]) + n
+    last_two = [m["name"] for m in spec.benchmark["per_layer"][-2:]]
+    assert sorted(last_two) == sorted([METRIC, SECOND_METRIC])
+    # the batch cells read the second PR's metric last, after PR 35's twenty
+    for cell in BATCH_CELLS:
+        names = [m["name"] for m in spec.per_layer(cell)]
+        assert names[-1] == SECOND_METRIC
+        assert names[:-1] == [m["name"] for m in real.per_layer(cell)]

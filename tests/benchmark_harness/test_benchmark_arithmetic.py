@@ -40,17 +40,46 @@ def test_iqr_share_is_statistics_quantiles():
 
 
 def test_bytes_per_row_from_shapes():
-    shapes = {"hash_bucket": 2, "hash_packed": True, "tier1_edges": 20,
-              "tier1_slots": 3, "edge_itemsize": 4}
-    # point 16 + cell 16 + bucket 2*8 + answer 8 = 56; tier-1 row
+    shapes = {"hash_bucket": 2, "tier1_edges": 20, "tier1_slots": 3,
+              "edge_itemsize": 4}
+    # point 16 + cell 16 + bucket 2*12 + answer 8 = 64; tier-1 row
     # 20*(16+4) + 3*5 = 415 for a found row
-    assert cost.bytes_per_row(shapes, 0.0) == 56
-    assert cost.bytes_per_row(shapes, 1.0) == 56 + 415
-    assert cost.bytes_per_row(shapes, 0.5) == 56 + 207.5
-    unpacked = dict(shapes, hash_packed=False)
-    assert cost.bytes_per_row(unpacked, 0.0) == 56 + 2 * 4
+    assert cost.bytes_per_row(shapes, 0.0) == 64
+    assert cost.bytes_per_row(shapes, 1.0) == 64 + 415
+    assert cost.bytes_per_row(shapes, 0.5) == 64 + 207.5
+    # three u32 words an entry on every index: whether the ids once packed
+    # into int64 entries changes nothing (PR 34 stores them one way)
+    for packed in (True, False, None):
+        assert cost.bytes_per_row(dict(shapes, hash_packed=packed), 0.0) == 64
+    # the taxi index's row (B 2, E1 24 in f32, M1 4) at match share 0.811
+    taxi = {"hash_bucket": 2, "tier1_edges": 24, "tier1_slots": 4,
+            "edge_itemsize": 4}
+    assert cost.bytes_per_row(taxi, 0.811) == pytest.approx(64 + 0.811 * 500)
     with pytest.raises(ValueError):
         cost.bytes_per_row(shapes, 1.5)
+
+
+@pytest.mark.parametrize("bucket", [1, 2, 3])
+def test_index_shapes_read_the_table_the_probe_reads(bucket):
+    """`hash_bucket` is `table_rows.shape[1] // 3`, and an index that has
+    dropped `table_cell` and `table_pack` (read by no device program since
+    PR 34) still gives every shape the byte count needs."""
+    from types import SimpleNamespace
+
+    import numpy as np
+
+    index = SimpleNamespace(
+        table_rows=np.zeros((16, 3 * bucket), np.uint32),
+        cell_edges=np.zeros((5, 24, 4), np.float32),
+        cell_slot_geom=np.zeros((5, 4), np.int32),
+    )
+    shapes = cost.index_shapes(index)
+    assert shapes["hash_bucket"] == bucket and shapes["hash_packed"] is None
+    assert (shapes["tier1_edges"], shapes["tier1_slots"],
+            shapes["edge_itemsize"]) == (24, 4, 4)
+    # a dead field that is still there is reported, never counted
+    index.table_pack = np.zeros((0,), np.int64)
+    assert cost.index_shapes(index)["hash_packed"] is False
 
 
 def test_peaks_table_knows_the_v5e_and_refuses_a_guess():

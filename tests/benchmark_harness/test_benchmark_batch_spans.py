@@ -35,6 +35,8 @@ STAGE_METRICS = [
     "tier1_device_ms.batch", "writeback_device_ms.batch",
     "unscoped_device_share.batch",
 ]
+#: appended by PR 39 after the twenty: the host work under the count sync
+UNDER_SYNC = "host_under_sync_p50_ms.batch"
 IDLE_METRICS = [
     "idle_host_shift_share.batch", "idle_put_share.batch",
     "idle_sync_share.batch",
@@ -50,9 +52,13 @@ def test_the_twenty_entries_are_the_batch_cells_and_move_their_rate(spec):
     names = SPAN_METRICS + EXACT_SPAN_METRICS + STAGE_METRICS + IDLE_METRICS
     assert len(names) == len(set(names)) == 20
     entries = {m["name"]: m for m in spec.benchmark["per_layer"]}
-    # appended: the last twenty of the list, after everything accepted
-    assert [m["name"] for m in spec.benchmark["per_layer"][-20:]] == [
-        n for n in entries if n in names]
+    # all twenty are in the list, in the order PR 35 appended them among
+    # themselves, whatever a later PR appended after them or put between
+    # (order, not position: `[-20:]` here refused every later metric)
+    order = (SPAN_METRICS[:6] + EXACT_SPAN_METRICS + SPAN_METRICS[6:]
+             + STAGE_METRICS + IDLE_METRICS)
+    assert [m["name"] for m in spec.benchmark["per_layer"]
+            if m["name"] in names] == order
     for n in names:
         e = entries[n]
         assert e["moves"] == "batch_rows_per_s"
@@ -142,6 +148,9 @@ def test_traced_batch_cell_reads_every_span_metric_as_a_number(
         assert not set(EXACT_SPAN_METRICS) & set(m)
     else:
         assert 0.0 <= m["recheck_host_row_share.batch"]["value"] < 100.0
+    # PR 36's counter, appended after the twenty (PR 39): call by call the
+    # host work hidden under the count sync holds the shift, so p50 by p50
+    assert m[UNDER_SYNC]["value"] >= m["host_shift_p50_ms.batch"]["value"] > 0.0
     # the pieces are inside the call they are pieces of
     pieces = sum(m[n]["value"] for n in SPAN_METRICS[:6])
     assert 0.0 < pieces and 0.0 < m["span_coverage_p50.batch"]["value"] <= 100.0
@@ -239,6 +248,21 @@ def test_recorded_events_read_every_span_metric(spec, recorded, monkeypatch, nam
         assert 95.0 <= value <= 100.0, "the pieces are the call"
     else:
         assert 0.0 < value < 250.0, "milliseconds of one 4M-row call's piece"
+
+
+def test_recorded_events_of_pr35_hold_nothing_for_the_under_sync_metric(
+        spec, recorded, monkeypatch):
+    """The fixture was recorded at PR 35, whose `join.counts` spans carry no
+    `hidden_s`: the parent's case, nothing to read — never a 0."""
+    ctx = _recorded_ctx(spec, recorded, monkeypatch)
+    desc = spec.data("layer_metrics", UNDER_SYNC)
+    assert any(e.get("name") == "join.counts" for e in recorded.events)
+    assert _read(spec, desc["reader"], ctx, desc["params"]) is None
+    entry = next(m for m in spec.benchmark["per_layer"]
+                 if m["name"] == UNDER_SYNC)
+    assert (entry["moves"], entry["source"], entry["layer"]) == (
+        "batch_rows_per_s", "program_span", "dispatch core")
+    assert entry["workloads"] == ["taxi.batch", "taxi.batch-exact"]
 
 
 def test_recorded_stages_are_the_calls_device_time(spec, recorded, monkeypatch):

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from bh_fixtures import make_copy
+from test_benchmark_uniform import add_uniform_cell
 
 from benchmark.harness.run_cell import run_cell
 
@@ -15,7 +16,9 @@ from benchmark.harness.run_cell import run_cell
 @pytest.fixture()
 def root(tmp_path, monkeypatch):
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    return make_copy(tmp_path)
+    root = make_copy(tmp_path)
+    add_uniform_cell(root)
+    return root
 
 
 def _run(root, cell, seed, **kw):
@@ -40,7 +43,9 @@ def test_serve_sound_run_is_correct_and_bf16_control_is_not(root, seed):
     assert line["correct"] is False
 
 
-def test_stream_with_answers_altered_where_they_are_produced(root, monkeypatch):
+@pytest.mark.parametrize("cell", ["tiny.stream", "tiny.stream-uniform"])
+def test_stream_with_answers_altered_where_they_are_produced(
+        root, monkeypatch, cell):
     """The join inside the timed loop answers the next zone for every
     seventh matched row: timed and collected folds still agree (both are
     wrong alike), and the sample against the plain reference catches it."""
@@ -59,11 +64,16 @@ def test_stream_with_answers_altered_where_they_are_produced(root, monkeypatch):
     dispatch.clear_caches()
     monkeypatch.setattr(stream, "pip_join_points", altered)
     try:
-        line = _run(root, "tiny.stream", 31)
+        line = _run(root, cell, 31)
     finally:
         monkeypatch.undo()
         dispatch.clear_caches()
     assert line["correct"] is False and line["attempted"] > 0
+    # the line says which number failed, beside its limit, and which held
+    said = line["checks"]
+    assert said["stream_disagreement_share"]["value"] > \
+        said["stream_disagreement_share"]["limit"] == 0.001
+    assert said["stream_fold_mismatches"] == {"value": 0.0, "limit": 0.0}
 
 
 def test_stream_whose_timed_loop_differs_from_the_collected_run(root, monkeypatch):

@@ -17,7 +17,9 @@ import sys
 
 from bh_fixtures import REPO, make_copy
 
-CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+#: ``checks`` comes last: each number compared beside its limit (PR 39)
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+                 "checks"}
 
 
 def _run(root, args, devices):
@@ -30,7 +32,24 @@ def _run(root, args, devices):
         cwd=root, env=env, capture_output=True, text=True, timeout=600,
     )
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
-    return json.loads(r.stdout.strip().splitlines()[-1]), r.stdout
+    line = json.loads(r.stdout.strip().splitlines()[-1])
+    _numbers_compared_come_last(line, r.stderr)
+    return line, r.stdout
+
+
+def _numbers_compared_come_last(line, err):
+    """The result line's last key and the last lines of standard error
+    are the numbers compared, each beside its limit, name for name."""
+    assert list(line)[-1] == "checks" and "forbidden_events" in line["checks"]
+    last = err.strip().splitlines()[-len(line["checks"]):]
+    for said, (name, c) in zip(last, line["checks"].items()):
+        assert set(c) == {"value", "limit"}
+        assert said.startswith(
+            f"[check] {name}: value={c['value']!r} limit={c['limit']!r} ")
+        assert said.split(" (")[0].endswith(
+            "ok" if c["value"] <= c["limit"] else "FAILED")
+    assert line["correct"] == all(
+        c["value"] <= c["limit"] for c in line["checks"].values())
 
 
 def test_new_stream_cell_on_a_mesh_traced(tmp_path):

@@ -79,7 +79,8 @@ def _run(root, cell, seed, *, trace=False, **kw):
 @pytest.mark.parametrize("cell", ["tiny.batch", "tiny.batch-exact"])
 def test_batch_cell_untraced(root, cell, capfd):
     line = _run(root, cell, 4_000_000_411)
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(line) == {"correct", "attempted", "failed", "metrics", "device",
+                         "checks"}
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["attempted"] % BATCH == 0
     assert set(line["metrics"]) == {"batch_rows_per_s", "setup_s"}
@@ -270,3 +271,135 @@ def test_a_degraded_call_counts_in_failed(root, monkeypatch):
         line = _run(root, "tiny.batch", 73)
     assert line["correct"] is False
     assert line["failed"] >= BATCH
+
+
+# --- a pool that is the same work on every seed (PR 39, after the refusal) ---
+
+@pytest.mark.parametrize("bands, k, banded, max_draws, want", [
+    # the first two banded and the first two unbanded, in the order drawn
+    ([1, 2, 0, 3, 0, 0, 1], 4, 2, 16, ([0, 1, 2, 4], 5, True)),
+    # a seed whose first draws are the composition already draws no more
+    ([0, 1, 0, 2], 4, 2, 16, ([0, 1, 2, 3], 4, True)),
+    # every batch unbanded where none may be banded
+    ([0, 0, 0, 0, 0], 4, 0, 16, ([0, 1, 2, 3], 4, True)),
+    # past max_draws whatever comes is kept, and the pool says it is not composed
+    ([1, 2, 1, 3, 1, 1, 1, 0, 0], 4, 2, 6, ([0, 1, 6, 7], 8, False)),
+    # a call that records no event (recheck off) keeps the first draws
+    ([None] * 6, 4, 2, 16, ([0, 1, 2, 3], 4, False)),
+    # one class wanted only: three banded of three
+    ([0, 1, 0, 1, 2, 0], 3, 3, 16, ([1, 3, 4], 5, True)),
+])
+def test_compose_pool_keeps_the_first_of_each_class(bands, k, banded,
+                                                    max_draws, want):
+    from benchmark.traffic_kinds.host_batch_join import compose_pool
+
+    kept, got, draws, composed = compose_pool(
+        lambda i: (i, bands[i]), k, banded, max_draws)
+    assert (kept, draws, composed) == want
+    assert got == [bands[i] for i in kept]
+
+
+def test_draw_i_is_slot_i_of_the_one_call_pool():
+    """The composed pool's candidates continue the pool a mix without the
+    parameter gets: slot i of the one-call generator is draw i."""
+    import jax
+
+    from bh_fixtures import REPO
+    from benchmark.harness.spec import Spec
+
+    points = Spec(REPO).module("generators", "points")
+    bbox = (-25.0, -25.0, 35.0, 20.0)
+    key = points.seed_key(4_000_000_617)
+    pool = np.asarray(points.make_generator(TINY_POINTS, bbox, 64, slots=3)(key))
+    one = points.make_generator(TINY_POINTS, bbox, 64)
+    for i in range(3):
+        assert np.array_equal(pool[i], np.asarray(one(jax.random.fold_in(key, i))))
+
+
+def _composed_cell(root, banded, max_draws):
+    """`tiny.batch-exact`'s twin with a composed pool, as new files."""
+    tree = os.path.join(root, "benchmark")
+    with open(os.path.join(tree, "traffic", "tiny-host-exact.json"),
+              encoding="utf-8") as f:
+        mix = json.load(f)
+    mix.update(pool_banded_batches=banded, pool_max_draws=max_draws)
+    _write(os.path.join(tree, "traffic", "tiny-host-composed.json"), mix)
+    _write(os.path.join(tree, "workloads", "tiny.batch-composed.json"),
+           {"check": {"sample_rows": POOL * BATCH, "max_disagreement": 0.0,
+                      "edge_within_deg": EDGE_SLACK_DEG, "max_edge_rows": 0}})
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path, encoding="utf-8") as f:
+        bench = json.load(f)
+    bench["workloads"].append(
+        {"name": "tiny.batch-composed", "config": "tiny-zones",
+         "traffic": "tiny-host-composed", "chips": 1, "why": "test fixture"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "tiny.batch-exact" in m.get("workloads", []):
+            m["workloads"].append("tiny.batch-composed")
+    with open(path, "w", encoding="utf-8") as f:
+        json.dump(bench, f, indent=1)
+
+
+def _ready(out: str) -> str:
+    return [ln for ln in out.splitlines()
+            if ln.startswith("[bench] batch_ready")][-1]
+
+
+@pytest.mark.parametrize("seed", [81, 4_000_000_619])
+def test_composed_pool_holds_as_many_banded_batches_on_every_seed(
+        root, seed, monkeypatch, capfd):
+    """The tiny grid's 2,048-row batches hold no cell-band row, so a wrapper
+    records one more `recheck_narrow` event, with a row in the band, for
+    every second call of set-up: the pool keeps the one banded batch it may
+    and two unbanded ones, draws past the banded ones it has no room for,
+    and the run is correct as any other."""
+    from mosaic_tpu.runtime import telemetry
+    from mosaic_tpu.sql import join
+
+    _composed_cell(root, banded=1, max_draws=16)
+    real, n = join.pip_join, [0]
+
+    def banded_every_second_call(points, *a, **kw):
+        out = real(points, *a, **kw)
+        n[0] += 1
+        if n[0] % 2 == 1 and n[0] <= 5:  # set-up's calls 1, 3, 5
+            telemetry.record("recheck_narrow", n=len(points), seconds=0.0,
+                             band=2, cap=2, ties=0, mode="alt_rejoin")
+        return out
+
+    monkeypatch.setattr(join, "pip_join", banded_every_second_call)
+    line = _run(root, "tiny.batch-composed", seed)
+    assert line["correct"] is True and line["failed"] == 0
+    ready = _ready(capfd.readouterr().out)
+    # draws 0 (banded), 1, 2 (banded: no room), 3: four draws, three kept
+    assert "pool_draws=4 pool_band_rows=[2, 0, 0] pool_composed=True" in ready
+    assert f"pool=({POOL}, {BATCH}, 2) dtype=float64" in ready
+
+
+def test_a_pool_that_cannot_be_composed_says_so_and_still_runs(root, capfd):
+    # no tiny batch holds a band row: after two draws whatever comes is kept
+    _composed_cell(root, banded=2, max_draws=2)
+    line = _run(root, "tiny.batch-composed", 82)
+    assert line["correct"] is True
+    ready = _ready(capfd.readouterr().out)
+    assert "pool_draws=4 pool_band_rows=[0, 0, 0] pool_composed=False" in ready
+
+
+def test_the_control_without_recheck_keeps_the_first_draws(root, capfd):
+    # recheck off records no `recheck_narrow` event: nothing to compose by
+    _composed_cell(root, banded=1, max_draws=16)
+    _run(root, "tiny.batch-composed", 83, control=True)
+    ready = _ready(capfd.readouterr().out)
+    assert ("pool_draws=3 pool_band_rows=[None, None, None] "
+            "pool_composed=False") in ready
+
+
+def test_the_exact_mix_composes_its_pool_and_the_default_mix_does_not():
+    from bh_fixtures import REPO
+    from benchmark.harness.spec import Spec
+
+    spec = Spec(REPO)
+    exact = spec.traffic("pickups-hotspot-host-exact")
+    assert exact["pool_batches"] == 4 and exact["pool_banded_batches"] == 2
+    assert exact["pool_max_draws"] >= 4 * exact["pool_batches"]
+    assert "pool_banded_batches" not in spec.traffic("pickups-hotspot-host")

@@ -102,7 +102,8 @@ def _checks(out: str) -> dict:
 
 def test_raster_cell_untraced(root, capfd):
     line = _run(root, 4_000_000_411)
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(line) == {"correct", "attempted", "failed", "metrics", "device",
+                         "checks"}
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["attempted"] % (SIDE * SIDE) == 0
     assert set(line["metrics"]) == {"batch_rows_per_s", "setup_s"}
