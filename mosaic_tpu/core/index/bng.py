@@ -38,6 +38,17 @@ _FIRST = ["S", "T", "N", "O", "H", "J"]
 _SECOND = [c for c in "ABCDEFGHJKLMNOPQRSTUVWXYZ"]  # 25 letters, I skipped
 
 
+def _xp(a):
+    """The array module ``a`` lives in: jnp for a jax array or a tracer,
+    numpy for everything else (host arrays, lists, scalars)."""
+    return jnp if isinstance(a, jax.Array) else np
+
+
+def _host_or_device(a):
+    """``a`` as an array of its own module (a list becomes numpy)."""
+    return a if isinstance(a, jax.Array) else np.asarray(a)
+
+
 def _letter_pair(e_l: int, n_l: int) -> str:
     """Compute the OS letter pair for 100km square (eL, nL) arithmetically:
     within each 500km block letters run A..Z (no I) west->east, north->south."""
@@ -99,12 +110,18 @@ class BNGIndexSystem(IndexSystem):
 
     # ------------------------------------------------------------- encoding
     def point_to_cell(self, xy: jax.Array, resolution: int) -> jax.Array:
+        """Integer math on whole arrays, in the array module the input
+        lives in: a jax array (or a tracer) computes on the device, a
+        numpy array on the host — tessellation asks for the cells of
+        host arrays of every size, and each new size of an eager device
+        op is a compile of its own."""
+        xp = _xp(xy)
         res = resolution
-        e = jnp.floor(xy[..., 0]).astype(jnp.int64)
-        n = jnp.floor(xy[..., 1]).astype(jnp.int64)
+        e = xp.floor(xy[..., 0]).astype(xp.int64)
+        n = xp.floor(xy[..., 1]).astype(xp.int64)
         if res == -1:
             blk = (n // 500_000) * 2 + (e // 500_000)
-            return (1000 + blk * 10).astype(jnp.int64)
+            return (1000 + blk * 10).astype(xp.int64)
         k = _k_digits(res)
         divisor = 10 ** (7 - abs(res)) if res < 0 else 10 ** (6 - res)
         e_l = e // 100_000
@@ -118,12 +135,12 @@ class BNGIndexSystem(IndexSystem):
             e_half = (e_rem % divisor) >= (divisor // 2)
             n_half = (n_rem % divisor) >= (divisor // 2)
             # SW=1, NW=2, NE=3, SE=4
-            quad = jnp.where(
-                ~e_half & ~n_half, 1, jnp.where(~e_half, 2, jnp.where(n_half, 3, 4))
-            ).astype(jnp.int64)
+            quad = xp.where(
+                ~e_half & ~n_half, 1, xp.where(~e_half, 2, xp.where(n_half, 3, 4))
+            ).astype(xp.int64)
         else:
-            quad = jnp.zeros_like(e)
-        p10 = jnp.int64(10) ** (5 + 2 * k)
+            quad = xp.zeros_like(e)
+        p10 = xp.int64(10) ** (5 + 2 * k)
         cell = (
             p10
             + e_l * 10 ** (3 + 2 * k)
@@ -132,7 +149,7 @@ class BNGIndexSystem(IndexSystem):
             + n_bin * 10
             + quad
         )
-        return cell.astype(jnp.int64)
+        return cell.astype(xp.int64)
 
     def point_to_cell_margin(self, xy: jax.Array, resolution: int):
         """Cells plus the relative distance to the nearest binning
@@ -161,57 +178,62 @@ class BNGIndexSystem(IndexSystem):
         Works per-element without knowing the resolution statically: the
         number of decimal digits encodes it.
         """
-        c = cells.astype(jnp.int64)
+        xp = _xp(cells)
+        c = cells.astype(xp.int64)
         is_500k = c < 10_000  # 4-digit ids are the 500km blocks
         # digits n: 6 + 2k; k in 0..5 -> thresholds
-        k = jnp.zeros_like(c, dtype=jnp.int32)
+        k = xp.zeros_like(c, dtype=xp.int32)
         for kk in range(1, 6):
-            k = jnp.where(c >= 10 ** (5 + 2 * kk), kk, k)
-        quad = (c % 10).astype(jnp.int32)
-        pow10k = jnp.int64(10) ** k
+            k = xp.where(c >= 10 ** (5 + 2 * kk), kk, k)
+        quad = (c % 10).astype(xp.int32)
+        pow10k = xp.int64(10) ** k
         n_bin = (c // 10) % pow10k
         e_bin = (c // (10 * pow10k)) % pow10k
         n_l = (c // (10 * pow10k * pow10k)) % 100
         e_l = (c // (1000 * pow10k * pow10k)) % 100
         # edge size: res = k+1 (q==0) edge=10^(5-k); res=-(k+2) edge=10^(5-k)/2
-        base_edge = jnp.int64(10) ** (5 - k)
-        edge = jnp.where(quad > 0, base_edge // 2, base_edge)
+        base_edge = xp.int64(10) ** (5 - k)
+        edge = xp.where(quad > 0, base_edge // 2, base_edge)
         # bins scale by the base-10 parent edge; quadrant offset refines below
         x = (e_l * pow10k + e_bin) * base_edge
         y = (n_l * pow10k + n_bin) * base_edge
-        x = x + jnp.where((quad == 3) | (quad == 4), edge, 0)
-        y = y + jnp.where((quad == 2) | (quad == 3), edge, 0)
+        x = x + xp.where((quad == 3) | (quad == 4), edge, 0)
+        y = y + xp.where((quad == 2) | (quad == 3), edge, 0)
         # 500km blocks
         blk = (c - 1000) // 10
-        x = jnp.where(is_500k, (blk % 2) * 500_000, x)
-        y = jnp.where(is_500k, (blk // 2) * 500_000, y)
-        edge = jnp.where(is_500k, 500_000, edge)
-        res = jnp.where(quad > 0, -(k + 2), k + 1)
-        res = jnp.where(is_500k, -1, res)
+        x = xp.where(is_500k, (blk % 2) * 500_000, x)
+        y = xp.where(is_500k, (blk // 2) * 500_000, y)
+        edge = xp.where(is_500k, 500_000, edge)
+        res = xp.where(quad > 0, -(k + 2), k + 1)
+        res = xp.where(is_500k, -1, res)
         return x, y, edge, quad, res
 
     def resolution_of(self, cells: jax.Array) -> jax.Array:
         return self._decode(jnp.asarray(cells))[4].astype(jnp.int32)
 
     def cell_center(self, cells: jax.Array) -> jax.Array:
-        x, y, edge, _, _ = self._decode(jnp.asarray(cells))
-        return jnp.stack(
-            [x.astype(jnp.float64) + edge / 2.0, y.astype(jnp.float64) + edge / 2.0],
+        cells = _host_or_device(cells)
+        xp = _xp(cells)
+        x, y, edge, _, _ = self._decode(cells)
+        return xp.stack(
+            [x.astype(xp.float64) + edge / 2.0, y.astype(xp.float64) + edge / 2.0],
             axis=-1,
         )
 
     def cell_boundary(self, cells: jax.Array) -> jax.Array:
-        x, y, edge, _, _ = self._decode(jnp.asarray(cells))
-        x = x.astype(jnp.float64)
-        y = y.astype(jnp.float64)
-        e = edge.astype(jnp.float64)
-        corners = jnp.stack(
+        cells = _host_or_device(cells)
+        xp = _xp(cells)
+        x, y, edge, _, _ = self._decode(cells)
+        x = x.astype(xp.float64)
+        y = y.astype(xp.float64)
+        e = edge.astype(xp.float64)
+        corners = xp.stack(
             [
-                jnp.stack([x, y], -1),
-                jnp.stack([x + e, y], -1),
-                jnp.stack([x + e, y + e], -1),
-                jnp.stack([x, y + e], -1),
-                jnp.stack([x, y], -1),
+                xp.stack([x, y], -1),
+                xp.stack([x + e, y], -1),
+                xp.stack([x + e, y + e], -1),
+                xp.stack([x, y + e], -1),
+                xp.stack([x, y], -1),
             ],
             axis=-2,
         )  # CCW, closed
@@ -269,18 +291,39 @@ class BNGIndexSystem(IndexSystem):
 
     # ------------------------------------------------------------- polyfill
     def polyfill_candidates(self, bounds: np.ndarray, resolution: int) -> np.ndarray:
+        return self.polyfill_candidates_batch(
+            np.asarray(bounds, np.float64).reshape(1, 4), resolution
+        )[0]
+
+    def polyfill_candidates_batch(
+        self, bounds: np.ndarray, resolution: int
+    ) -> list[np.ndarray]:
+        """Every bbox's covering cells in one pass of host integer math
+        (x-major within a bbox, as the one-bbox walk lists them): the
+        grid ranges, one concatenated table of cell centres, one
+        `point_to_cell` on the host. The default loop calls the encoder
+        once a geometry — on a layer of 200,000 parcels 200,000 times."""
+        bounds = np.asarray(bounds, dtype=np.float64).reshape(-1, 4)
         edge = _SIZE[resolution]
-        x0 = max(0, int(np.floor(bounds[0] / edge)) * edge)
-        y0 = max(0, int(np.floor(bounds[1] / edge)) * edge)
-        x1 = min(X_MAX, int(np.ceil(bounds[2] / edge)) * edge)
-        y1 = min(Y_MAX, int(np.ceil(bounds[3] / edge)) * edge)
-        xs = np.arange(x0, x1, edge, dtype=np.float64) + edge / 2
-        ys = np.arange(y0, y1, edge, dtype=np.float64) + edge / 2
-        if not len(xs) or not len(ys):
-            return np.zeros(0, dtype=np.int64)
-        gx, gy = np.meshgrid(xs, ys, indexing="ij")
-        centers = np.stack([gx.ravel(), gy.ravel()], axis=-1)
-        return np.asarray(self.point_to_cell(jnp.asarray(centers), resolution))
+        ix0 = np.maximum(0, np.floor(bounds[:, 0] / edge).astype(np.int64))
+        iy0 = np.maximum(0, np.floor(bounds[:, 1] / edge).astype(np.int64))
+        # ceil division: the 500 km blocks do not divide the grid's extent,
+        # and the partial block at its east and north edge is a cell
+        ix1 = np.minimum(-(-X_MAX // edge), np.ceil(bounds[:, 2] / edge).astype(np.int64))
+        iy1 = np.minimum(-(-Y_MAX // edge), np.ceil(bounds[:, 3] / edge).astype(np.int64))
+        nx = np.maximum(ix1 - ix0, 0)
+        ny = np.maximum(iy1 - iy0, 0)
+        count = nx * ny
+        total = int(count.sum())
+        if not total:
+            return [np.zeros(0, dtype=np.int64) for _ in range(bounds.shape[0])]
+        box = np.repeat(np.arange(bounds.shape[0]), count)
+        off = np.concatenate([[0], np.cumsum(count)])
+        k = np.arange(total) - off[box]
+        cx = (ix0[box] + k // ny[box]) * edge + edge / 2
+        cy = (iy0[box] + k % ny[box]) * edge + edge / 2
+        cells = self.point_to_cell(np.stack([cx, cy], axis=-1), resolution)
+        return np.split(cells, off[1:-1])
 
     # -------------------------------------------------------------- strings
     def format(self, cells: np.ndarray) -> list[str]:

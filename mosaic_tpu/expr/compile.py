@@ -267,83 +267,128 @@ def pixel_program(
 
 @_dispatch.bounded_cache("overlay_programs", 64)
 def overlay_program(
-    value: ast.Expr, Lb: int, Rb: int, Pb: int, Sb: int, vpad: int,
-    acc_name: str, mesh=None,
+    value: ast.Expr, Lb: int, Rb: int, Pb: int, Cb: int, Fb: int, Sb: int,
+    vpad: int, acc_name: str, mesh=None,
 ):
-    """The fused overlay measure program: gather candidate chip pairs
-    from the two sorted side tables, compute per-pair intersection areas
-    (kind routing + convex clip, `kernels.overlay.pair_areas`), fold
-    them into per-geometry-pair totals, and evaluate the pair tree over
-    the folded tables — ONE launch per ``(tree, buckets, mesh)``
-    signature. Under ``mesh`` the per-pair stage runs data-parallel over
-    the pair axis (side tables replicated, candidates sharded) — the
-    stage is pointwise in the pair axis and the fold runs on the
-    gathered output, so a sharded run is bit-identical to single-device
-    by construction."""
+    """The fused overlay measure program: look the candidate rows' kinds
+    and table areas up in the two resident side tables (scope
+    ``overlay.gather``), clip the ``Cb`` border × border rows that have
+    a convex window (``overlay.clip``: in place, or swapped) and fan the
+    ``Fb`` that have none (``overlay.fan``), fold all three streams into
+    per-geometry-pair totals (``overlay.fold``) and evaluate the pair
+    tree over the folded tables — ONE launch per ``(tree, buckets,
+    mesh)`` signature. The host names the clip and fan rows and which
+    ring of each is the window (`sql.overlay.pair_routes`), so the clip
+    runs over the rows that need one and not over the whole candidate
+    bucket. Under ``mesh``
+    the two clip stages run data-parallel over their row axes (side
+    tables replicated) — pointwise in the row axis, and the fold runs
+    on the gathered output, so a sharded run is bit-identical to
+    single-device by construction."""
     acc_dt = jnp.dtype(acc_name)
     from ..kernels import overlay as _ko
 
-    def per_pair(li, ri, lcore, lok, lverts, lvlen, larea, lcell,
-                 rcore, rok, rverts, rvlen, rarea, band):
-        return _ko.pair_areas(
-            lcore[li], rcore[ri], lok[li], rok[ri],
-            lverts[li], lvlen[li], rverts[ri], rvlen[ri],
-            larea[li], rarea[ri], lcell[li], band, xp=jnp,
-        )
+    def clip_stage(lv, ll, rv, rl, swap, sign, band):
+        return _ko.clip_rows(lv, ll, rv, rl, swap, sign, band, xp=jnp)
 
-    stage = per_pair
+    def fan_stage(lv, ll, rv, rl, swap, sign, band):
+        return _ko.fan_rows(lv, ll, rv, rl, swap, sign, band, xp=jnp)
+
     regather = None
     if mesh is not None:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
         p, r = P(mesh.axis_names), P()
-        stage = jax.shard_map(
-            per_pair, mesh=mesh,
-            in_specs=(p, p, r, r, r, r, r, r, r, r, r, r, r, r),
-            out_specs=(p, p), check_vma=False,
+        specs = dict(
+            mesh=mesh, in_specs=(p, p, p, p, p, p, r),
+            out_specs=(p, p, p), check_vma=False,
         )
-        # replicate the per-pair outputs before the fold: left sharded,
+        clip_stage = jax.shard_map(clip_stage, **specs)
+        fan_stage = jax.shard_map(fan_stage, **specs)
+        # replicate the per-row outputs before the fold: left sharded,
         # GSPMD would split the segment sum into per-shard partials plus
         # a cross-shard combine — a different f64 accumulation order
         # (1-ulp reassociation drift vs single-device)
         regather = NamedSharding(mesh, r)
 
-    def fused(li, ri, valid, seg, lcore, lok, lverts, lvlen, larea,
-              lcell, rcore, rok, rverts, rvlen, rarea, seg_larea,
-              seg_rarea, band):
-        area, host_needed = stage(
-            li, ri, lcore, lok, lverts, lvlen, larea, lcell,
-            rcore, rok, rverts, rvlen, rarea, band,
+    def rows_of(idx, n, li, ri, lverts, lvlen, lsign, rverts, rvlen, rsign):
+        live = jnp.arange(idx.shape[0]) < n
+        cl, cr = li[idx], ri[idx]
+        return (
+            live, lverts[cl], jnp.where(live, lvlen[cl], 0),
+            rverts[cr], jnp.where(live, rvlen[cr], 0),
+            lsign[cl] * rsign[cr],
         )
-        if regather is not None:
-            area = jax.lax.with_sharding_constraint(area, regather)
-            host_needed = jax.lax.with_sharding_constraint(
-                host_needed, regather
+
+    def fused(li, ri, valid, seg, clip_idx, clip_swap, n_clip,
+              fan_idx, fan_swap, n_fan,
+              lcore, lsign, lverts, lvlen, larea, lcell,
+              rcore, rsign, rverts, rvlen, rarea,
+              seg_larea, seg_rarea, band):
+        with jax.named_scope("overlay.gather"):
+            base = _ko.base_areas(
+                lcore[li], rcore[ri], larea[li], rarea[ri], lcell[li],
+                xp=jnp,
             )
-        cnt, s, _mn, _mx = zonal_fold_masked(
-            area, valid, seg, Sb, acc_dtype=acc_dt
-        )
-        val, vok = _lower_pair(value, PairCtx(s, seg_larea, seg_rarea))
+            c_live, clv, cll, crv, crl, c_sign = rows_of(
+                clip_idx, n_clip, li, ri, lverts, lvlen, lsign,
+                rverts, rvlen, rsign,
+            )
+            f_live, flv, fll, frv, frl, f_sign = rows_of(
+                fan_idx, n_fan, li, ri, lverts, lvlen, lsign,
+                rverts, rvlen, rsign,
+            )
+        with jax.named_scope("overlay.clip"):
+            c_area, c_host, c_spill = clip_stage(
+                clv, cll, crv, crl, clip_swap, c_sign, band
+            )
+        with jax.named_scope("overlay.fan"):
+            f_area, f_host, f_spill = fan_stage(
+                flv, fll, frv, frl, fan_swap, f_sign, band
+            )
+        with jax.named_scope("overlay.fold"):
+            folded = (
+                jnp.concatenate([base, c_area, f_area]),
+                jnp.concatenate([valid, c_live, f_live]),
+                jnp.concatenate([seg, seg[clip_idx], seg[fan_idx]]),
+            )
+            if regather is not None:
+                folded = tuple(
+                    jax.lax.with_sharding_constraint(x, regather)
+                    for x in folded
+                )
+                c_host, c_spill, f_host, f_spill = (
+                    jax.lax.with_sharding_constraint(x, regather)
+                    for x in (c_host, c_spill, f_host, f_spill)
+                )
+            cnt, s, _mn, _mx = zonal_fold_masked(
+                *folded, Sb, acc_dtype=acc_dt
+            )
+            val, vok = _lower_pair(
+                value, PairCtx(s, seg_larea, seg_rarea)
+            )
         return (
             jnp.broadcast_to(val, (Sb,)).astype(jnp.float64),
             jnp.broadcast_to(vok, (Sb,)),
-            s, cnt, host_needed,
+            s, cnt, c_host, f_host, c_spill, f_spill,
         )
 
+    fused.__name__ = "overlay_measure"
     return jax.jit(fused)
 
 
 def overlay_signature_of(
-    value: ast.Expr, Lb: int, Rb: int, Pb: int, Sb: int, vpad: int,
-    acc_name: str, index_system, resolution, mesh=None,
+    value: ast.Expr, Lb: int, Rb: int, Pb: int, Cb: int, Fb: int, Sb: int,
+    vpad: int, acc_name: str, index_system, resolution, mesh=None,
 ) -> tuple:
     """The dispatch signature an overlay measure execution is tracked
     under: ``(tree-hash, buckets, index, mesh)`` — the overlay twin of
     :func:`signature_of`."""
     return (
         "overlay:" + ast.tree_hash(value)[:16],
-        (int(Lb), int(Rb), int(Pb), int(Sb), int(vpad), str(acc_name)),
+        (int(Lb), int(Rb), int(Pb), int(Cb), int(Fb), int(Sb), int(vpad),
+         str(acc_name)),
         (type(index_system).__name__, int(resolution)),
         _dispatch.mesh_key(mesh),
     )

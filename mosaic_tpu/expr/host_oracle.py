@@ -275,89 +275,145 @@ def interpret_pair(node: ast.Expr, area, larea, rarea):
     )
 
 
-def _general_pair_area(prep, lk: int, rk: int) -> float:
-    """Exact f64 chip∩chip area through the native boolean-op engine —
-    the catch-all for shapes the convex clip cannot answer (multi-ring,
-    holed, over-pad, spilled)."""
-    from ..core.geometry import hostops as _hostops
-    from ..sql.overlay import _csr_geom_areas
+def _host_rings(prep, side, rows, V: int):
+    """``(verts (N, V, 2) f64, vlen, convex, star, sign)`` of side-table
+    rows at pad ``V``: the stored cell-local rings where every row fits
+    the prep's pad, else every row packed again from the chip table at
+    ``V`` (a ring over the device's pad is the host lane's to answer)."""
+    from ..sql.overlay import _pack_rings
 
-    L, R = prep.left, prep.right
-    ga = L.table.chips.take(np.asarray([int(L.rows[lk])]))
-    gb = R.table.chips.take(np.asarray([int(R.rows[rk])]))
-    inter = _hostops.intersection(ga, gb)
-    return float(_csr_geom_areas(inter, prep.shift)[0])
+    rows = np.asarray(rows, np.int64)
+    if V == prep.vpad:
+        return (
+            side.verts[rows], side.vlen[rows], side.convex[rows],
+            side.star[rows], side.sign[rows],
+        )
+    xy = np.asarray(side.table.chips.xy, np.float64)
+    xy = xy.reshape(-1, xy.shape[-1])[:, :2]
+    _ok, convex, star, verts, vlen = _pack_rings(
+        xy, side.ring_start[rows], side.ring_len[rows], V,
+        side.origin[rows], prep.scale,
+    )
+    return verts, vlen, convex, star, side.sign[rows]
 
 
-def host_pair_override(prep, li, ri, valid, seg, flagged):
-    """Whole-pair f64 re-answer for the flagged geometry pairs.
-
-    For every candidate row of a flagged pair, recompute its area in
-    pure f64 (cell/chip area tables for core kinds, the numpy twin of
-    the convex clip for clippable border pairs, the native boolean-op
-    engine otherwise) and accumulate per pair IN EMISSION ORDER — the
-    same stream order both fold lanes use. Returns (len(flagged),) f64
-    sums aligned with ``flagged``."""
+def host_row_areas(prep, lk, rk) -> np.ndarray:
+    """Pure-f64 area of candidate rows ``(lk, rk)`` (sorted side-table
+    rows): the table areas for core kinds, for border × border rows the
+    numpy twins of the device's three clip routes on the f64 cell-local
+    rings — in a buffer no clip can spill, at a pad that holds the
+    longest ring among them. A row's area under the f64 band reads
+    exactly 0.0: the oracle's own arithmetic cannot tell it from a
+    touch."""
     from ..kernels import overlay as _k
 
-    flagged = np.asarray(flagged, np.int64)
-    out = np.zeros(flagged.shape[0], np.float64)
     L, R = prep.left, prep.right
-    seg = np.asarray(seg)
-    mask = np.asarray(valid, bool) & (seg >= 0) & np.isin(seg, flagged)
-    rows = np.nonzero(mask)[0]
-    if not rows.size:
+    lk = np.asarray(lk, np.int64)
+    rk = np.asarray(rk, np.int64)
+    out = _k.base_areas(
+        L.core[lk], R.core[rk], L.chip_area[lk], R.chip_area[rk],
+        L.cell_area[lk], xp=np,
+    ).astype(np.float64)
+    bb = np.nonzero(
+        ~L.core[lk] & ~R.core[rk]
+        & (L.ring_len[lk] >= 3) & (R.ring_len[rk] >= 3)
+    )[0]
+    if not bb.size:
         return out
-    lk = np.asarray(li, np.int64)[rows]
-    rk = np.asarray(ri, np.int64)[rows]
-    # ``flagged`` comes out of np.unique (sorted), so searchsorted maps
-    # each row to its pair slot; np.add.at over ascending ``rows`` then
-    # accumulates each pair's rows in emission order, the same order a
-    # per-row python loop (and both fold lanes) would use
-    pos = np.searchsorted(flagged, seg[rows])
-    lcore, rcore = L.core[lk], R.core[rk]
-    areas = np.zeros(rows.shape[0], np.float64)
-    cc = lcore & rcore
-    areas[cc] = L.cell_area[lk[cc]]
-    cb = lcore & ~rcore
-    areas[cb] = R.chip_area[rk[cb]]
-    bc = ~lcore & rcore
-    areas[bc] = L.chip_area[lk[bc]]
-    bb = ~lcore & ~rcore
-    ok = bb & L.ok_subj[lk] & R.ok_win[rk]
-    general = np.nonzero(bb & ~ok)[0]
-    if ok.any():
-        # one batched numpy clip over every clippable row — elementwise
-        # per row, so bit-identical to clipping them one at a time
-        ar, _, sp = _k.clip_area_convex(
-            L.verts[lk[ok]], L.vlen[lk[ok]],
-            R.verts[rk[ok]], R.vlen[rk[ok]], xp=np,
-        )
-        areas[ok] = ar
-        spilled = np.nonzero(ok)[0][np.asarray(sp, bool)]
-        general = np.concatenate([general, spilled])
-    for idx in general.tolist():
-        # the rare catch-all: multi-ring / holed / over-pad shapes go
-        # through the native boolean-op engine one pair at a time
-        areas[idx] = _general_pair_area(prep, int(lk[idx]), int(rk[idx]))
-    np.add.at(out, pos, areas)
+    V = int(max(
+        prep.vpad, L.ring_len[lk[bb]].max(), R.ring_len[rk[bb]].max()
+    ))
+    lv, ll, lconv, lstar, lsign = _host_rings(prep, L, lk[bb], V)
+    rv, rl, rconv, rstar, rsign = _host_rings(prep, R, rk[bb], V)
+    band = _f64_band(prep)
+    sign = lsign * rsign
+    wide = 4 * V + 2
+    conv = lconv | rconv
+    area = np.zeros(bb.shape[0], np.float64)
+    clip_swap, fan_swap = _k.window_swaps(lconv, rconv, lstar, rstar)
+    for rows, route, swap in (
+        (np.nonzero(conv)[0], _k.clip_rows, clip_swap),
+        (np.nonzero(~conv)[0], _k.fan_rows, fan_swap),
+    ):
+        if rows.size:
+            area[rows] = route(
+                lv[rows], ll[rows], rv[rows], rl[rows], swap[rows],
+                sign[rows], 0.0, xp=np, width=wide,
+            )[0]
+    out[bb] = np.where(np.abs(area) < band, 0.0, area)
     return out
 
 
-def splice_override(prep, value, li, ri, valid, seg, host_needed,
+def _f64_band(prep) -> float:
+    """The f64 host lane's own band: under it the oracle's arithmetic
+    cannot tell an area from a touch."""
+    from ..sql.overlay import overlay_band
+
+    return overlay_band("float64", prep.scale, platform="cpu")
+
+
+def host_pair_override(prep, li, ri, seg, flagged):
+    """Whole-pair f64 re-answer for the flagged geometry pairs.
+
+    For every candidate row of a flagged pair, recompute its area in
+    pure f64 (:func:`host_row_areas`) and accumulate per pair IN
+    EMISSION ORDER — the same stream order both fold lanes use. A sum
+    under the f64 band a row reads exactly 0.0 (a parcel on an island:
+    the shell's row and the hole's cancel to rounding). Returns
+    ``((len(flagged),) f64 sums aligned with flagged, rows
+    recomputed)``."""
+    flagged = np.asarray(flagged, np.int64)
+    out = np.zeros(flagged.shape[0], np.float64)
+    seg = np.asarray(seg)
+    rows = np.nonzero((seg >= 0) & np.isin(seg, flagged))[0]
+    if not rows.size:
+        return out, 0
+    areas = host_row_areas(
+        prep, np.asarray(li, np.int64)[rows], np.asarray(ri, np.int64)[rows]
+    )
+    # ``flagged`` comes out of np.unique (sorted), so searchsorted maps
+    # each row to its pair slot; np.add.at over ascending ``rows`` then
+    # accumulates each pair's rows in emission order
+    slot = np.searchsorted(flagged, seg[rows])
+    np.add.at(out, slot, areas)
+    width = _f64_band(prep) * np.bincount(slot, minlength=flagged.shape[0])
+    return np.where(np.abs(out) < width, 0.0, out), int(rows.size)
+
+
+def cancelled_pairs(prep, li, ri, seg, folded, count):
+    """Geometry pairs whose folded area is what is left of rows that
+    cancel: a pair with a row of negative sign (a hole ring's) whose sum
+    is not 0.0 yet lies under the band a row. Each row was the device's
+    to answer — large, far from any contact — but a subject inside a hole
+    is the shell's row less the hole's, and the difference of two
+    roundings is no area. The f64 host lane answers such a pair."""
+    L, R = prep.left, prep.right
+    seg = np.asarray(seg)
+    neg = (seg >= 0) & (L.sign[np.asarray(li)] * R.sign[np.asarray(ri)] < 0)
+    pairs = np.unique(seg[neg])
+    if not pairs.size:
+        return pairs
+    total = np.asarray(folded, np.float64)[pairs]
+    width = float(prep.band) * np.asarray(count)[pairs]
+    return pairs[(total != 0.0) & (np.abs(total) < width)]
+
+
+def splice_override(prep, value, li, ri, seg, flagged_rows, count,
                     seg_l64, seg_r64, val, vok, area64):
     """Replace every host-flagged pair's folded area AND evaluated value
     with the pure-f64 re-answer (shared by the device lane and its numpy
-    twin, so both lanes splice identically). Returns ``(val, vok,
-    area64, n_overridden)``."""
+    twin, so both lanes splice identically). ``flagged_rows`` are the
+    candidate rows a lane could not answer (band, over-pad ring,
+    spill); pairs whose rows cancel (:func:`cancelled_pairs`, from the
+    folded ``area64`` and the fold's row ``count``) join them. Returns
+    ``(val, vok, area64, n_overridden, rows, n_cancelled)``."""
     seg = np.asarray(seg)
-    flag_rows = (
-        np.asarray(valid, bool) & (seg >= 0) & np.asarray(host_needed)
-    )
-    flagged = np.unique(seg[flag_rows])
+    flagged = np.unique(seg[np.asarray(flagged_rows, np.int64)])
+    cancelled = cancelled_pairs(prep, li, ri, seg, area64, count)
+    flagged = np.union1d(flagged[flagged >= 0], cancelled)
     if not flagged.size:
-        return val, vok, area64, 0
-    over = host_pair_override(prep, li, ri, valid, seg, flagged)
+        return val, vok, area64, 0, 0, 0
+    over, rows = host_pair_override(prep, li, ri, seg, flagged)
     area64[flagged] = over
     fv, fm = interpret_pair(
         value, over, seg_l64[flagged], seg_r64[flagged]
@@ -366,18 +422,20 @@ def splice_override(prep, value, li, ri, valid, seg, host_needed,
         np.asarray(fv, np.float64), flagged.shape
     )
     vok[flagged] = np.broadcast_to(np.asarray(fm, bool), flagged.shape)
-    return val, vok, area64, int(flagged.size)
+    return val, vok, area64, int(flagged.size), rows, int(cancelled.size)
 
 
 def host_overlay_measures(prep, value: ast.Expr, *, pair_cap=None):
     """Pure-host overlay measure lane: the numpy twin (``xp=np``) of the
-    device pipeline, stage for stage — equi-join count/emission, kind-
-    routed clip areas in the prep's accelerated dtype (so the host-
-    recheck flags match), the sequential pair fold, the pair-tree
-    interpretation, and the same f64 override splice. Under x64 this IS
-    the pure-f64 oracle the device lane must match bit for bit; it is
-    also the degradation target when the device path fails. Returns the
-    lane-output dict `sql.overlay.overlay_measures` packages."""
+    device pipeline, stage for stage — equi-join count/emission, the
+    host's routes, table areas and the three clip routes in the prep's
+    accelerated dtype (so the host-recheck flags match), the sequential
+    pair fold over the same three streams in the same order, the
+    pair-tree interpretation, and the same f64 override splice. Under
+    x64 off the TPU this IS the pure-f64 oracle the device lane must
+    match bit for bit; it is also the degradation target when the
+    device path fails. Returns the lane-output dict
+    `sql.overlay.overlay_measures` packages."""
     from ..kernels import overlay as _k
     from ..sql import overlay as _ov
 
@@ -390,15 +448,35 @@ def host_overlay_measures(prep, value: ast.Expr, *, pair_cap=None):
     uniq, seg, sure, Sb, seg_l64, seg_r64 = _ov.pair_glue(
         prep, li, ri, valid
     )
-    acc = np.dtype(prep.acc_name)
-    area, host_needed = _k.pair_areas(
-        L.core[li], R.core[ri], L.ok_subj[li], R.ok_win[ri],
-        L.verts.astype(acc)[li], L.vlen[li],
-        R.verts.astype(acc)[ri], R.vlen[ri],
-        L.chip_area.astype(acc)[li], R.chip_area.astype(acc)[ri],
-        L.cell_area.astype(acc)[li], acc.type(prep.band), xp=np,
+    clip_r, clip_swap, fan_r, fan_swap, shape_r = _ov.pair_routes(
+        prep, li, ri, seg
     )
-    _cnt, s = _k.host_pair_fold(area, valid, seg, Sb, acc_dtype=acc)
+    acc = np.dtype(prep.acc_name)
+    band = acc.type(prep.band)
+    base = _k.base_areas(
+        L.core[li], R.core[ri], L.chip_area.astype(acc)[li],
+        R.chip_area.astype(acc)[ri], L.cell_area.astype(acc)[li], xp=np,
+    )
+
+    def route(kernel, rows, swap):
+        cl, cr = li[rows], ri[rows]
+        area, host, _spill = kernel(
+            L.verts.astype(acc)[cl], L.vlen[cl],
+            R.verts.astype(acc)[cr], R.vlen[cr], swap,
+            (L.sign[cl] * R.sign[cr]).astype(acc), band, xp=np,
+        )
+        return area, host
+
+    c_area, c_host = route(_k.clip_rows, clip_r, clip_swap)
+    f_area, f_host = route(_k.fan_rows, fan_r, fan_swap)
+    cnt, s = _k.host_pair_fold(
+        np.concatenate([base, c_area, f_area]),
+        np.concatenate([
+            valid, np.ones(clip_r.shape[0] + fan_r.shape[0], bool),
+        ]),
+        np.concatenate([seg, seg[clip_r], seg[fan_r]]),
+        Sb, acc_dtype=acc,
+    )
     fv, fm = interpret_pair(
         value, s, seg_l64.astype(acc), seg_r64.astype(acc)
     )
@@ -407,8 +485,9 @@ def host_overlay_measures(prep, value: ast.Expr, *, pair_cap=None):
     ).astype(np.float64).copy()
     vok = np.broadcast_to(np.asarray(fm, bool), (Sb,)).copy()
     area64 = s.astype(np.float64).copy()
-    val, vok, area64, overridden = splice_override(
-        prep, value, li, ri, valid, seg, host_needed,
+    val, vok, area64, overridden, _rows, _cancelled = splice_override(
+        prep, value, li, ri, seg,
+        np.concatenate([clip_r[c_host], fan_r[f_host], shape_r]), cnt,
         seg_l64, seg_r64, val, vok, area64,
     )
     U = uniq.shape[0]

@@ -15,27 +15,48 @@ index build), and the per-query work runs on device:
   dispatch ladder. Caps are full-bucket: overflow is structural (the
   caller truncates at an explicit cap and reports OVERFLOW(-2)
   in-band), never an escalation.
-- :func:`clip_area_convex` — batched Sutherland–Hodgman clip area for
-  convex chip pairs, mirroring `core.tessellate.clip_rings_convex_batch`
-  operation for operation (same half-plane sign test, same ``denom``
-  guard, same parametric intersection formula) but with a STATIC output
-  width: convex ∩ convex emits at most ``Vs + Vw`` vertices, so the
-  buffer never grows. Consecutive duplicate vertices are NOT removed —
+- :func:`clip_area_convex` — batched Sutherland–Hodgman clip area
+  against a CONVEX window, mirroring
+  `core.tessellate.clip_rings_convex_batch` operation for operation
+  (same half-plane sign test, same ``denom`` guard, same parametric
+  intersection formula) but with a STATIC output width. The subject may
+  be any simple ring. Consecutive duplicate vertices are NOT removed —
   they contribute exactly 0.0 to the shoelace sum, and area is the only
-  consumer.
+  consumer. The shoelace is taken about the clipped ring's first
+  vertex, so a ring that collapsed onto an axis-parallel line sums
+  exact zeros: a touch along a shared edge reads 0.0 in any dtype.
+- :func:`fan_area` — the signed fan for a simple NON-convex window:
+  ``area(S ∩ W) = Σ_i sign(T_i) · area(S ∩ |T_i|)`` over the triangles
+  ``T_i = (w_0, w_i, w_{i+1})``, each a convex window of three
+  half-planes for the clip above.
+- :func:`base_areas`, :func:`clip_rows`, :func:`fan_rows`,
+  :func:`in_band` — the three streams the fused measure program folds:
+  table areas for rows with a core chip, the convex clip in place or
+  SWAPPED (the area is symmetric, so the convex ring is the window
+  whichever side it is on), the fan; and the epsilon recheck that hands
+  a row to the f64 host lane.
+
+The rings arrive in the frame of their own cell's corner
+(`sql.overlay._pack_rings`), so a coordinate is at most a cell's extent
+and the band ``EDGE_BAND_K · eps(arithmetic) · cell²`` is as narrow as
+the dtype allows. The device path of the clip holds no gather — on the
+TPU a gather is paid per index, and an edge round has ``P × W`` of them:
+a window vertex is a static slice, a ring's next vertex a shift and a
+select, the left-pack a masked sum with one live term
+(:func:`_pack_rows`).
 
 Every kernel takes ``xp`` (jnp or numpy) and is written against the
 array-API subset the two share, so the f64 host twin used by the
 overlay oracle IS this code: elementwise IEEE ops agree bitwise between
 numpy and XLA CPU, integer searchsorted/cumsum/gather are exact, and
-the only scatter (:func:`_scatter_rows`) writes disjoint targets. The
-shoelace accumulation is an UNROLLED python loop over the static width
-on both sides — XLA preserves the float op order of an unrolled chain,
-which is what makes the device area bit-identical to the numpy twin
-under x64. The fold back to per-geometry-pair totals is
-`kernels.zonal.zonal_fold_masked` on device and :func:`host_pair_fold`
-(``np.add.at`` — sequential in row order, like XLA's CPU scatter) on
-host.
+the only scatter (:func:`_scatter_rows`, the host's left-pack) writes
+disjoint targets. The shoelace accumulation is an UNROLLED python loop
+over the static width on both sides — XLA preserves the float op order
+of an unrolled chain, which is what makes the device area bit-identical
+to the numpy twin under x64 off the TPU. The fold back to
+per-geometry-pair totals is `kernels.zonal.zonal_fold_masked` on device
+and :func:`host_pair_fold` (``np.add.at`` — sequential in row order,
+like XLA's CPU scatter) on host.
 """
 
 from __future__ import annotations
@@ -47,12 +68,17 @@ __all__ = [
     "CLIP_EPS",
     "LEFT_PAD_CELL",
     "RIGHT_PAD_CELL",
+    "base_areas",
     "clip_area_convex",
+    "clip_rows",
     "emit_pairs",
+    "fan_area",
+    "fan_rows",
     "host_pair_fold",
-    "pair_areas",
+    "in_band",
     "pair_count",
     "pair_spans",
+    "window_swaps",
 ]
 
 #: same half-plane epsilon as `core.tessellate._EPS` — device clips and
@@ -66,6 +92,18 @@ LEFT_PAD_CELL = np.int64(2**62 - 1)
 RIGHT_PAD_CELL = np.int64(2**62 - 2)
 
 
+def _scope(name: str, xp):
+    """``jax.named_scope(name)`` on the device lane (`obs/stages.py`
+    names the device's ops by it), nothing on the numpy twin."""
+    if xp is jnp:
+        import jax
+
+        return jax.named_scope(name)
+    import contextlib
+
+    return contextlib.nullcontext()
+
+
 # ----------------------------------------------------- segment equi-join
 
 
@@ -74,12 +112,13 @@ def pair_spans(lcells, rcells, n_left, xp=jnp):
     rows sharing cell ``lcells[i]`` starting at sorted right row
     ``lo[i]``. Both cell columns must be sorted ascending with their pad
     sentinels at the tail; rows at and past ``n_left`` count zero."""
-    lcells = xp.asarray(lcells)
-    rcells = xp.asarray(rcells)
-    lo = xp.searchsorted(rcells, lcells, side="left")
-    hi = xp.searchsorted(rcells, lcells, side="right")
-    valid = xp.arange(lcells.shape[0]) < n_left
-    cnt = xp.where(valid, hi - lo, 0)
+    with _scope("overlay.spans", xp):
+        lcells = xp.asarray(lcells)
+        rcells = xp.asarray(rcells)
+        lo = xp.searchsorted(rcells, lcells, side="left")
+        hi = xp.searchsorted(rcells, lcells, side="right")
+        valid = xp.arange(lcells.shape[0]) < n_left
+        cnt = xp.where(valid, hi - lo, 0)
     return lo, cnt
 
 
@@ -105,86 +144,98 @@ def emit_pairs(lcells, rcells, n_left, emit_limit, pair_bucket: int,
     emit_limit)`` are invalid (the caller books ``total - emitted`` as
     OVERFLOW)."""
     lo, cnt = pair_spans(lcells, rcells, n_left, xp=xp)
-    off = xp.cumsum(cnt) - cnt
-    total = cnt.sum()
-    nl = lcells.shape[0]
-    k = xp.arange(pair_bucket, dtype=off.dtype)
-    li = xp.clip(xp.searchsorted(off, k, side="right") - 1, 0, nl - 1)
-    ri = lo[li] + (k - off[li])
-    valid = k < xp.minimum(total, emit_limit)
-    li = xp.where(valid, li, 0)
-    ri = xp.where(valid, xp.clip(ri, 0, rcells.shape[0] - 1), 0)
+    with _scope("overlay.emit", xp):
+        off = xp.cumsum(cnt) - cnt
+        total = cnt.sum()
+        nl = lcells.shape[0]
+        k = xp.arange(pair_bucket, dtype=off.dtype)
+        li = xp.clip(xp.searchsorted(off, k, side="right") - 1, 0, nl - 1)
+        ri = lo[li] + (k - off[li])
+        valid = k < xp.minimum(total, emit_limit)
+        li = xp.where(valid, li, 0)
+        ri = xp.where(valid, xp.clip(ri, 0, rcells.shape[0] - 1), 0)
     return li.astype(xp.int32), ri.astype(xp.int32), valid
 
 
 # ------------------------------------------------------------- clip area
 
 
-def _gather_rows(arr, idx, xp):
-    """(P, V, 2) rows at per-row vertex index ``idx`` (P,) → (P, 2)."""
-    ix = xp.broadcast_to(
-        idx.astype(xp.int32)[:, None, None], (arr.shape[0], 1, 2)
-    )
-    return xp.take_along_axis(arr, ix, axis=1)[:, 0]
-
-
 def _scatter_rows(buf, pos, vals, width: int, xp):
     """Host-side scatter of ``vals`` (P, W, 2) to ``buf[row,
-    pos[row, j]]``; slots with ``pos == width`` are dropped. Targets are
+    pos[row, j]]``; slots with ``pos >= width`` are dropped. Targets are
     disjoint by construction (exclusive-cumsum positions), so the
     scatter has no ordering dependence. The device lane packs through
-    :func:`_pack_rows` instead — XLA:CPU serializes ScatterOp."""
+    :func:`_pack_rows` instead."""
     m = pos < width
     rr, jj = np.nonzero(m)
     buf[rr, pos[rr, jj]] = vals[rr, jj]
     return buf
 
 
-def _pack_rows(cur, inter, emit0, emit1, base, new_len, jdx):
+def _pack_rows(cur, inter, emit0, emit1, base, jdx):
     """Device-side twin of the two-scatter pack: left-pack each row's
     emitted vertices (``cur[j]`` where ``emit0``, then ``inter[j]``
-    where ``emit1``, in slot order) by INVERTING the CSR placement —
-    each output slot binary-searches its source slot in the exclusive
-    offsets (``vmap``ed ``searchsorted``, all gathers, no ScatterOp)
-    and SELECTS its vertex verbatim. No arithmetic touches the payload,
-    so the packing is bit-exact (signed zeros survive) against the host
-    scatter twin."""
-    import jax
+    where ``emit1``, in slot order). Every output slot SELECTS the one
+    source slot whose exclusive-offset position names it — a
+    ``(P, W_out, W_in)`` compare fused into a masked sum with exactly
+    one live term, so no gather, no scatter and no arithmetic on the
+    payload but ``v + 0.0``: the chip pays a gather per INDEX
+    (``PERF.md`` section 6, PR 34), which at ``P x W`` indices an edge
+    round would be the whole call. Slots nothing is placed in read 0.0,
+    as the host twin's zeroed buffer does."""
+    zero = jnp.zeros((), cur.dtype)
+    out_slot = jdx[:, :, None]                     # (1, W_out, 1)
+    pos0 = jnp.where(emit0, base, -1)[:, None, :]  # (P, 1, W_in)
+    pos1 = jnp.where(emit1, base + emit0.astype(jnp.int32), -1)[:, None, :]
+    hit0 = (pos0 == out_slot)[..., None]           # (P, W_out, W_in, 1)
+    hit1 = (pos1 == out_slot)[..., None]
+    return (
+        jnp.where(hit0, cur[:, None, :, :], zero).sum(axis=2)
+        + jnp.where(hit1, inter[:, None, :, :], zero).sum(axis=2)
+    )
 
-    src = jax.vmap(
-        lambda b: jnp.searchsorted(b, jdx[0], side="right")
-    )(base)
-    j = jnp.clip(src - 1, 0, base.shape[1] - 1).astype(jnp.int32)
-    local = jdx - jnp.take_along_axis(base, j, axis=1)
-    use_cur = jnp.take_along_axis(emit0, j, axis=1) & (local == 0)
-    got_cur = jnp.take_along_axis(cur, j[:, :, None], axis=1)
-    got_int = jnp.take_along_axis(inter, j[:, :, None], axis=1)
-    val = jnp.where(use_cur[:, :, None], got_cur, got_int)
-    live = jdx < new_len[:, None]
-    return jnp.where(live[:, :, None], val, jnp.zeros_like(cur))
+
+def _next_in_ring(arr, clen, jdx, xp):
+    """``arr[:, j + 1]`` with the wrap to slot 0 at each row's own
+    length — a static shift and a select, not a gather."""
+    nxt = xp.concatenate([arr[:, 1:], arr[:, :1]], axis=1)
+    wrap = jdx + 1 < clen[:, None]
+    first = arr[:, :1]
+    if arr.ndim == 3:
+        wrap = wrap[:, :, None]
+    return xp.where(wrap, nxt, first)
 
 
-def clip_area_convex(subj, slen, win, wlen, *, eps=CLIP_EPS, xp=jnp):
+def clip_area_convex(subj, slen, win, wlen, *, eps=CLIP_EPS, xp=jnp,
+                     width: int | None = None):
     """Batched Sutherland–Hodgman clip AREA: signed area of
     ``subj ∩ win`` per row.
 
     ``subj`` (P, Vs, 2) / ``win`` (P, Vw, 2) CCW open rings, left-packed
-    to ``slen`` / ``wlen``; both convex (the table prep routes anything
-    else to the host lane). Returns ``(area, out_len, spill)`` — the
-    half-shoelace of the clipped ring, its vertex count, and a True
-    flag where a round wanted to emit more than the static ``Vs + Vw +
-    2`` buffer (impossible for convex inputs; a misclassified concave
-    ring trips it and is re-answered by the f64 host lane). Rows with
+    to ``slen`` / ``wlen``. The WINDOW must be convex; the subject may
+    be any simple ring (a concave subject leaves zero-width bridges in
+    the clipped ring, which add nothing to its area). Returns ``(area,
+    out_len, spill)`` — the half-shoelace of the clipped ring, its
+    vertex count, and a True flag where a round wanted to emit more
+    than the static buffer (``width``, default ``Vs + Vw + 2``: enough
+    for a convex subject; a wiggly concave one can trip it and is
+    re-answered by the f64 host lane in a wider buffer). Rows with
     ``slen == 0`` report area 0.0 exactly.
 
     Operation order mirrors `core.tessellate.clip_rings_convex_batch`
-    half-plane for half-plane; the shoelace is an unrolled static loop
-    so the f64 device result is bit-identical to the numpy twin
-    (``xp=np``) of this very function.
+    half-plane for half-plane. The shoelace is taken RELATIVE TO THE
+    CLIPPED RING'S FIRST VERTEX, an unrolled static loop on both
+    backends: a ring that collapsed onto one axis-parallel line (a
+    subject that only touches the window along a shared edge) sums
+    exact zeros, and the f64 device result is bit-identical to the
+    numpy twin (``xp=np``) of this very function. The device path
+    holds no gather: window vertex ``e`` is a static slice, a ring's
+    next vertex a shift and a select, the left-pack a masked sum
+    (:func:`_pack_rows`).
     """
     P, Vs, _ = subj.shape
     Vw = win.shape[1]
-    W = Vs + Vw + 2
+    W = Vs + Vw + 2 if width is None else int(width)
     dt = subj.dtype
     zero = xp.asarray(0.0, dt)
     one = xp.asarray(1.0, dt)
@@ -199,17 +250,15 @@ def clip_area_convex(subj, slen, win, wlen, *, eps=CLIP_EPS, xp=jnp):
     jdx = xp.arange(W, dtype=xp.int32)[None, :]
     for e in range(Vw):
         active = (e < wlen) & (clen > 0)
-        a = _gather_rows(win, xp.minimum(e, wlen - 1), xp)
-        b = _gather_rows(win, xp.where(e + 1 < wlen, e + 1, 0), xp)
+        a = win[:, e]
+        b = xp.where((e + 1 < wlen)[:, None], win[:, min(e + 1, Vw - 1)],
+                     win[:, 0])
         ax, ay = a[:, 0][:, None], a[:, 1][:, None]
         dx = (b[:, 0] - a[:, 0])[:, None]
         dy = (b[:, 1] - a[:, 1])[:, None]
         s_cur = dx * (cur[:, :, 1] - ay) - dy * (cur[:, :, 0] - ax)
-        nxt = xp.where(jdx + 1 < clen[:, None], jdx + 1, 0)
-        nxt_xy = xp.take_along_axis(
-            cur, xp.broadcast_to(nxt[:, :, None], (P, W, 2)), axis=1
-        )
-        s_nxt = xp.take_along_axis(s_cur, nxt, axis=1)
+        nxt_xy = _next_in_ring(cur, clen, jdx, xp)
+        s_nxt = _next_in_ring(s_cur, clen, jdx, xp)
         valid = jdx < clen[:, None]
         inside_cur = s_cur >= -eps
         inside_nxt = s_nxt >= -eps
@@ -224,9 +273,7 @@ def clip_area_convex(subj, slen, win, wlen, *, eps=CLIP_EPS, xp=jnp):
         new_len = cnt.sum(axis=1)
         spill = spill | (active & (new_len > W))
         if xp is jnp:
-            buf = _pack_rows(
-                cur, inter, emit0, emit1, base, new_len, jdx
-            )
+            buf = _pack_rows(cur, inter, emit0, emit1, base, jdx)
         else:
             buf = xp.zeros((P, W, 2), dt)
             buf = _scatter_rows(
@@ -238,64 +285,146 @@ def clip_area_convex(subj, slen, win, wlen, *, eps=CLIP_EPS, xp=jnp):
             )
         cur = xp.where(active[:, None, None], buf, cur)
         clen = xp.where(active, xp.minimum(new_len, W), clen)
-    # unrolled shoelace: a fixed-order add chain on both backends
+    # unrolled shoelace about the ring's first vertex: a fixed-order add
+    # chain on both backends
+    ox, oy = cur[:, 0, 0], cur[:, 0, 1]
     acc = xp.zeros(P, dt)
-    for j in range(W):
-        nj = xp.where(j + 1 < clen, j + 1, 0)
-        nxy = _gather_rows(cur, nj, xp)
-        contrib = cur[:, j, 0] * nxy[:, 1] - nxy[:, 0] * cur[:, j, 1]
+    for j in range(1, W):
+        q = xp.where((j + 1 < clen)[:, None], cur[:, min(j + 1, W - 1)],
+                     cur[:, 0])
+        px, py = cur[:, j, 0] - ox, cur[:, j, 1] - oy
+        contrib = px * (q[:, 1] - oy) - (q[:, 0] - ox) * py
         acc = acc + xp.where(j < clen, contrib, zero)
     area = xp.asarray(0.5, dt) * acc
     return area, clen, spill
 
 
+def fan_area(subj, slen, win, wlen, *, eps=CLIP_EPS, xp=jnp,
+             width: int | None = None):
+    """Signed-fan clip AREA against a simple NON-convex window ring.
+
+    With ``w_0 … w_{n-1}`` the window and ``T_i = (w_0, w_i, w_{i+1})``,
+    ``area(S ∩ W) = Σ_i sign(T_i) · area(S ∩ |T_i|)``: the fan's signed
+    triangles cover every point of the plane as often as the window
+    winds round it, so the zero-width bridges a Sutherland–Hodgman chip
+    carries cost nothing. Each ``|T_i|`` is its triangle turned
+    counter-clockwise — a convex window of three half-planes for
+    :func:`clip_area_convex`, which is right for any simple subject
+    ring; a degenerate triangle adds exactly 0.0. Returns ``(area,
+    terms, spill)``: the signed sum, how many triangles gave a non-zero
+    piece (the row's band scales with it: each piece carries the
+    arithmetic's own absolute error, so a sum that cancels to little is
+    only as good as its pieces), and the clip's spill flag. A window
+    whose fan from vertex 0 holds no negative triangle (`prepare_overlay`
+    turns every ring so that its best apex comes first) is a partition:
+    then nothing cancels and a subject outside the window reads exact
+    zeros."""
+    P, Vw, _ = win.shape
+    dt = subj.dtype
+    slen = xp.asarray(slen).astype(xp.int32)
+    wlen = xp.asarray(wlen).astype(xp.int32)
+    total = xp.zeros(P, dt)
+    terms = xp.zeros(P, xp.int32)
+    spill = xp.zeros(P, bool)
+    w0 = win[:, 0]
+    three = xp.full(P, 3, xp.int32)
+    for i in range(1, Vw - 1):
+        a, b = win[:, i], win[:, i + 1]
+        cr = (
+            (a[:, 0] - w0[:, 0]) * (b[:, 1] - w0[:, 1])
+            - (a[:, 1] - w0[:, 1]) * (b[:, 0] - w0[:, 0])
+        )
+        pos = cr > 0
+        live = (i + 1 < wlen) & (cr != 0)
+        tri = xp.stack(
+            [w0, xp.where(pos[:, None], a, b), xp.where(pos[:, None], b, a)],
+            axis=1,
+        )
+        ar, _, sp = clip_area_convex(
+            subj, xp.where(live, slen, 0), tri, three, eps=eps, xp=xp,
+            width=width,
+        )
+        total = total + xp.where(pos, ar, -ar)
+        terms = terms + (ar != 0).astype(xp.int32)
+        spill = spill | sp
+    return total, terms, spill
+
+
 # ------------------------------------------------------ per-pair measure
 
 
-def pair_areas(
-    lcore, rcore, lok, rok,
-    lverts, lvlen, rverts, rvlen,
-    larea, rarea, lcell_area,
-    band, *, eps=CLIP_EPS, xp=jnp,
-):
-    """Per-candidate intersection area with the host-lane routing flag.
-
-    Chips are clipped to their cell, so within a shared cell the pair
-    kinds collapse (``core ∩ X = X``):
-
-    - core × core   → the cell's area (precomputed f64 table);
-    - core × border → the border chip's area (precomputed f64 table);
-    - border × border, both device-clippable (single convex ring within
-      the vertex pad) → :func:`clip_area_convex`;
-    - anything else (multi-ring, holed, concave, over-pad) → area 0.0
-      here and ``host_needed`` True — the f64 host lane recomputes the
-      WHOLE geometry pair, in stream order, exactly as the oracle does.
-
-    ``band`` is the epsilon recheck threshold in area units
-    (``EDGE_BAND_K · eps(dtype) · scale²``): a clipped area whose
-    magnitude falls inside the band (shared edges, slivers, near-
-    degenerate contact) is also flagged for the f64 recheck, so the f32
-    device lane never decides a contact case. Returns ``(area,
-    host_needed)``.
-    """
-    bb = ~lcore & ~rcore
-    ok2 = bb & lok & rok
-    area2, _, spill = clip_area_convex(
-        lverts, xp.where(ok2, lvlen, 0), rverts, rvlen, eps=eps, xp=xp,
-    )
-    zero = xp.asarray(0.0, area2.dtype)
-    area = xp.where(
+def base_areas(lcore, rcore, larea, rarea, lcell_area, xp=jnp):
+    """Per-candidate area of the rows that need no clip. Chips are
+    clipped to their cell, so within a shared cell ``core ∩ X = X``:
+    core × core → the cell's area, core × border → the border ring's
+    signed area, both from the precomputed f64 tables; border × border
+    rows read 0.0 here and are answered by :func:`clip_rows` /
+    :func:`fan_rows` (or the host lane)."""
+    zero = xp.asarray(0.0, larea.dtype)
+    return xp.where(
         lcore & rcore, lcell_area,
-        xp.where(
-            lcore & ~rcore, rarea,
-            xp.where(~lcore & rcore, larea,
-                     xp.where(ok2, area2, zero)),
-        ),
+        xp.where(lcore, rarea, xp.where(rcore, larea, zero)),
     )
-    near = ok2 & (xp.abs(area2) < band)
-    host_needed = (bb & ~(lok & rok)) | spill | near
-    area = xp.where(host_needed, zero, area)
-    return area, host_needed
+
+
+def in_band(area, terms, band, xp=jnp):
+    """The epsilon recheck: a clipped area is the device's to answer
+    where it is exactly 0.0 with no non-zero piece (nothing of the
+    subject lay inside every half-plane of the window: disjoint, or a
+    touch the clip collapsed onto a line) or at least the band; in
+    between — a sliver, a near-degenerate contact, a fan whose pieces
+    cancel — the f64 host lane answers the WHOLE geometry pair. The
+    band is ``EDGE_BAND_K · eps(arithmetic) · cell²`` a piece."""
+    width = band * xp.maximum(terms, 1).astype(area.dtype)
+    return (terms > 0) & (xp.abs(area) < width)
+
+
+def window_swaps(lconvex, rconvex, lstar, rstar):
+    """Which ring is the window — THE rule, for both lanes: ``(clip
+    swap, fan swap)``. The intersection's area is symmetric, so a row
+    with a convex ring clips against it: the right ring in place, the
+    left one SWAPPED where the right is not convex. A fan runs over the
+    right ring unless only the left one's fan is a partition."""
+    return ~rconvex, ~rstar & lstar
+
+
+def _subject_and_window(lverts, lvlen, rverts, rvlen, swap, xp):
+    """``(subj, slen, win, wlen)``: left against right, or right against
+    left where ``swap``."""
+    sw = swap[:, None, None]
+    return (
+        xp.where(sw, rverts, lverts), xp.where(swap, rvlen, lvlen),
+        xp.where(sw, lverts, rverts), xp.where(swap, lvlen, rvlen),
+    )
+
+
+def clip_rows(lverts, lvlen, rverts, rvlen, swap, sign, band, *,
+              eps=CLIP_EPS, xp=jnp, width: int | None = None):
+    """Border × border rows with a convex window (:func:`window_swaps`
+    says which ring it is). ``sign`` is the product of the two rings'
+    signs (a hole ring counts negative). Returns ``(area, host_needed,
+    spill)``."""
+    area, _, spill = clip_area_convex(
+        *_subject_and_window(lverts, lvlen, rverts, rvlen, swap, xp),
+        eps=eps, xp=xp, width=width,
+    )
+    host = spill | in_band(area, (area != 0).astype(xp.int32), band, xp)
+    zero = xp.asarray(0.0, area.dtype)
+    return xp.where(host, zero, sign * area), host, spill
+
+
+def fan_rows(lverts, lvlen, rverts, rvlen, swap, sign, band, *,
+             eps=CLIP_EPS, xp=jnp, width: int | None = None):
+    """Border × border rows where neither ring is convex: the signed
+    fan over the window :func:`window_swaps` names. Returns ``(area,
+    host_needed, spill)``."""
+    area, terms, spill = fan_area(
+        *_subject_and_window(lverts, lvlen, rverts, rvlen, swap, xp),
+        eps=eps, xp=xp, width=width,
+    )
+    host = spill | in_band(area, terms, band, xp)
+    zero = xp.asarray(0.0, area.dtype)
+    return xp.where(host, zero, sign * area), host, spill
 
 
 def host_pair_fold(values, valid, seg, num_segments: int,

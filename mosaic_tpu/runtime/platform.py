@@ -16,6 +16,11 @@
   the CPU, decided from the platform and nowhere else, so no spelling
   of a backend name can put an interpreted kernel on a chip.
 
+- **How fine is the device's arithmetic** — :func:`arithmetic_eps`. An
+  epsilon band has to be as wide as the arithmetic that computes under
+  it is coarse, and ``np.finfo`` does not know that this chip has no
+  float64 unit.
+
 Executables call the first two; the library and the test harness do
 not (tier-1 pins cold-compile counts, which a warm cache would zero).
 """
@@ -99,6 +104,32 @@ def interpret_kernels() -> bool:
     import jax
 
     return jax.devices()[0].platform == "cpu"
+
+
+#: mantissa bits the TPU's emulated float64 keeps (a pair of float32
+#: words; measured, ``PERF.md`` section 6: "f64 on the chip is emulated
+#: (~46 bits)"), against IEEE's 52: 64 times coarser than ``np.finfo``
+TPU_F64_MANTISSA_BITS = 46
+
+
+def arithmetic_eps(dtype, platform: str | None = None) -> float:
+    """The relative rounding step of ``dtype`` as ``platform`` (default:
+    the process's own) really computes in it: ``np.finfo(dtype).eps``
+    everywhere but for float64 on a TPU, which has no float64 unit and
+    emulates it at about 46 bits (``2**-46``, 64 times numpy's). THE
+    rule for every epsilon band sized from the arithmetic — a band
+    sized from ``np.finfo`` there lets the device decide contact cases
+    it cannot see."""
+    import numpy as np
+
+    dt = np.dtype(dtype)
+    if platform is None:
+        import jax
+
+        platform = jax.devices()[0].platform
+    if dt == np.float64 and platform == "tpu":
+        return float(2.0 ** -TPU_F64_MANTISSA_BITS)
+    return float(np.finfo(dt).eps)
 
 
 def per_chip(unit: str, info: dict) -> str:

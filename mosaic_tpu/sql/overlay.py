@@ -10,24 +10,57 @@ reference's `is_core || st_intersects` predicate expresses).
 
 Two lanes share one contract:
 
-- **Device lane** (:func:`overlay_measures`): both chip tables are sorted
-  by int64 cell id once (:func:`prepare_overlay`, amortized like the chip
-  index build), candidate generation runs on device as a sorted segment
-  equi-join (`kernels.overlay.pair_count` / `emit_pairs`) against a static
-  pair bucket, and the overlap measures — per-pair intersection area via
-  batched Sutherland–Hodgman clip, folded per geometry pair, with an
-  `expr/` pair tree evaluated over the folded tables — run as ONE fused
-  program per ``(tree-hash, buckets, index, mesh)`` signature through
-  `DispatchCore` (compile cache, warmup tripwire, watchdog/retry,
-  ``mesh=`` sharding, graceful degradation). Near-degenerate clip areas
-  (inside the ``EDGE_BAND_K·eps(acc)·scale²`` band), non-convex windows,
-  multi-ring/over-pad chips and spills are re-answered by the f64 host
-  lane per WHOLE geometry pair, so the accelerated dtype never decides a
-  contact case.
-- **Host lane** (`expr.host_oracle.host_overlay_measures`): the numpy twin
-  of the same kernels (``xp=np``) — the pure-f64 oracle the device lane
-  must match bitwise under x64, and the degradation target when the
-  device path fails past its retry budget.
+- **Device lane** (:func:`overlay_measures`): both chip tables are
+  exploded into ring rows, sorted by int64 cell id and put on the device
+  once (:func:`prepare_overlay`, amortized like the chip index build);
+  candidate generation runs on device as a sorted segment equi-join
+  (`kernels.overlay.pair_count` / `emit_pairs`) against a static pair
+  bucket, and the overlap measures — per-row intersection areas, folded
+  per geometry pair, with an `expr/` pair tree evaluated over the folded
+  tables — run as ONE fused program per ``(tree-hash, buckets, index,
+  mesh)`` signature through `DispatchCore` (compile cache, warmup
+  tripwire, watchdog/retry, ``mesh=`` sharding, graceful degradation).
+- **Host lane** (`expr.host_oracle.host_overlay_measures`): the numpy
+  twin of the same kernels (``xp=np``) — off the TPU, under x64, the
+  pure-f64 oracle the device lane matches bit for bit, and the
+  degradation target when the device path fails past its retry budget.
+
+**The frame.** Both sides of a candidate row lie in ONE cell, so every
+ring is stored relative to its OWN cell's corner (subtracted in f64 on
+the host, once: :func:`_pack_rings`). A coordinate is then at most a
+cell's extent, wherever on the grid the data stands, and the
+accelerated dtype keeps its whole mantissa for the cell: float32 on the
+TPU, float64 under x64 elsewhere (:func:`overlay_acc_dtype`).
+
+**The band.** ``EDGE_BAND_K · eps · cell²`` with ``eps`` the rounding
+step of the arithmetic the device REALLY computes in
+(`runtime.platform.arithmetic_eps`: the TPU emulates float64 at about 46
+bits, 64 times coarser than ``np.finfo`` says) — :func:`overlay_band`.
+A clipped area is the device's to answer where it is exactly 0.0 (the
+clip left nothing inside the window, or collapsed a touch onto a line)
+or at least the band; strictly in between, the f64 host lane re-answers
+the WHOLE geometry pair, and reads anything under ITS band as exactly
+0.0. So a pair that only touches reports 0.0, and a positive device
+area is positive in truth.
+
+**The three clip routes** of a border × border row
+(:func:`pair_routes`; `kernels.overlay.clip_rows` / `fan_rows`): the
+Sutherland–Hodgman clip against the right ring where it is convex; the
+same clip SWAPPED — the right ring against the left — where only the
+left one is (the area is symmetric); and where neither is, the signed
+fan over the window's triangles from one apex, which is right for any
+simple ring and for the zero-width bridges a clipped concave ring
+carries. A ring with a hole is two rows of opposite sign, so holed and
+many-ring chips fold like any other.
+
+**What the host lane still answers**: pairs with a row in the band
+(slivers; touches the clip could not collapse exactly; fans whose
+pieces cancel), pairs with a ring over the vertex pad
+(``MAX_CHIP_VERTS``), pairs with a clip that spilled its buffer, and
+pairs whose ROWS cancel (a subject inside a hole is the shell's row less
+the hole's: `expr.host_oracle.cancelled_pairs`) —
+by the numpy twins of the same three routes, vectorized, in f64
+(`expr.host_oracle.host_row_areas`).
 
 Caps are full-bucket and structural: when the candidate count exceeds
 ``pair_cap`` (or the top pair bucket), the emission truncates and the
@@ -42,7 +75,6 @@ columnar candidate generator, now deduplicated by geometry pair
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +85,9 @@ from ..core.tessellate import ChipTable, _dedupe_boundaries_batch, tessellate
 from ..core.types import GeometryType, PackedGeometry
 from ..dispatch import core as _dispatch
 from ..kernels import overlay as _k
+from ..obs import stages as _stages
 from ..obs import trace as _trace
+from ..runtime import platform as _platform
 from ..runtime import telemetry as _telemetry
 from ..runtime.errors import DegradedResult
 from .join import EDGE_BAND_K, OVERFLOW
@@ -66,10 +100,13 @@ __all__ = [
     "candidate_pairs",
     "chip_candidate_rows",
     "intersects_join",
+    "overlay_acc_dtype",
+    "overlay_band",
     "overlay_join",
     "overlay_measures",
     "pair_glue",
     "pair_plan",
+    "pair_routes",
     "prepare_overlay",
     "warmup_overlay",
 ]
@@ -86,12 +123,6 @@ PAIR_LADDER = _dispatch.BucketLadder(min_bucket=8, max_bucket=1 << 22)
 #: sorted side-table ladder (chip rows) and geometry-pair segment ladder
 TABLE_LADDER = _dispatch.BucketLadder(min_bucket=64, max_bucket=1 << 21)
 SEG_LADDER = _dispatch.BucketLadder(min_bucket=64, max_bucket=1 << 21)
-
-
-def _acc_name() -> str:
-    """Accelerated fold dtype — f64 under x64 (the CPU oracle contract),
-    f32 on accelerators without it (the epsilon band covers the gap)."""
-    return "float64" if jax.config.jax_enable_x64 else "float32"
 
 
 def pair_plan(total: int, pair_cap: int | None = None):
@@ -319,11 +350,17 @@ overlay_join = intersects_join
 class OverlaySide:
     """One cell-sorted, bucket-padded side table of an overlay prep.
 
+    A ROW is one ring of a border chip (a chip with a hole has two rows,
+    ``sign`` +1 for the shell and -1 for the hole: ``1_chip = Σ sign ·
+    1_ring``, so the fold over a geometry pair's rows is the chips'
+    intersection whatever the ring count) or one core chip (no ring).
     All per-row arrays are in sorted-by-cell order, padded to ``bucket``
     rows (pad cells carry a per-side sentinel that sorts above every
     real cell and can never equi-join the other side's sentinel).
-    ``rows`` maps sorted row → original chip row for the host override
-    lane; ``geom_area`` is indexed by ORIGINAL geometry id.
+    ``rows`` maps sorted row → original chip row; ``geom_area`` is
+    indexed by ORIGINAL geometry id. ``dev`` holds what the fused
+    program reads, put on the device once by `prepare_overlay` in the
+    prep's ``acc`` dtype.
     """
 
     table: ChipTable
@@ -332,22 +369,30 @@ class OverlaySide:
     cells: np.ndarray      # (Lb,) i64 sorted ascending, sentinel tail
     geom: np.ndarray       # (Lb,) i64 geometry id, -1 pad
     core: np.ndarray       # (Lb,) bool
-    ok_subj: np.ndarray    # (Lb,) bool device-clippable as clip SUBJECT
-    ok_win: np.ndarray     # (Lb,) bool device-clippable as clip WINDOW
-    verts: np.ndarray      # (Lb, V, 2) f64 shifted CCW open rings
+    ok: np.ndarray         # (Lb,) bool border ring within the vertex pad
+    convex: np.ndarray     # (Lb,) bool ring usable as a convex window
+    star: np.ndarray       # (Lb,) bool fan from vertex 0 has no negative triangle
+    sign: np.ndarray       # (Lb,) f64 +1 shell / core, -1 hole
+    verts: np.ndarray      # (Lb, V, 2) f64 CELL-LOCAL CCW open rings
     vlen: np.ndarray       # (Lb,) i32 left-packed vertex counts
-    chip_area: np.ndarray  # (Lb,) f64 |chip| (core rows: the cell area)
+    chip_area: np.ndarray  # (Lb,) f64 signed ring area (core: cell area)
     cell_area: np.ndarray  # (Lb,) f64 area of the row's cell
+    origin: np.ndarray     # (Lb, 2) f64 the row's cell corner (frame)
+    ring_start: np.ndarray  # (Lb,) i64 ring span in ``table.chips.xy``
+    ring_len: np.ndarray   # (Lb,) i64
     rows: np.ndarray       # (n,) i64 sorted row -> original chip row
-    geom_area: np.ndarray  # (G,) f64 |geometry| (shifted frame)
+    geom_area: np.ndarray  # (G,) f64 |geometry|
+    dev: dict | None = None
 
 
 @dataclass(frozen=True)
 class OverlayPrep:
-    """Amortized overlay prep: both sorted side tables plus the shared
-    coordinate frame (``shift``/``scale``), the accelerated fold dtype,
-    the epsilon-band threshold in area units and the vertex pad — every
-    static piece of the fused program's signature."""
+    """Amortized overlay prep: both sorted side tables, the frame
+    (every ring is stored relative to its OWN cell's corner; ``scale``
+    is the largest cell extent, ``shift`` the data's centre, kept for
+    callers that want one), the accelerated dtype by
+    :func:`overlay_acc_dtype`, the epsilon band in area units and the
+    vertex pad — every static piece of the fused program's signature."""
 
     left: OverlaySide
     right: OverlaySide
@@ -364,33 +409,57 @@ def _csr_geom_areas(col: PackedGeometry, shift: np.ndarray) -> np.ndarray:
     """(G,) f64 polygon areas (|shells| − |holes|), vectorized over the
     CSR offsets — the columnar twin of `core.geometry.oracle.area`
     (shell = first ring of its part, open rings, wraparound shoelace).
-    Non-polygon rows report 0.0; coordinates are shifted first so the
-    table is computed in the same frame the clip kernels run in."""
+    Non-polygon rows report 0.0; coordinates are shifted first (one
+    ``(2,)`` shift, or ``(G, 2)``: one a geometry) so the products stay
+    small."""
     G = len(col)
     out = np.zeros(G, np.float64)
     nv = int(np.asarray(col.xy).shape[0])
     if not G or not nv:
         return out
-    x = np.asarray(col.xy[:, 0], np.float64) - float(shift[0])
-    y = np.asarray(col.xy[:, 1], np.float64) - float(shift[1])
     ro = np.asarray(col.ring_offsets, np.int64)
     po = np.asarray(col.part_offsets, np.int64)
     go = np.asarray(col.geom_offsets, np.int64)
     R = ro.shape[0] - 1
     ring_of = np.repeat(np.arange(R), np.diff(ro))
+    part_of_ring = np.repeat(np.arange(po.shape[0] - 1), np.diff(po))
+    geom_of_part = np.repeat(np.arange(G), np.diff(go))
+    shift = np.asarray(shift, np.float64)
+    if shift.ndim == 2:
+        shift = shift[geom_of_part[part_of_ring[ring_of]]]
+    xy = np.asarray(col.xy, np.float64)[:, :2] - shift
+    x, y = xy[:, 0], xy[:, 1]
     nxt = np.arange(nv) + 1
     nxt = np.where(nxt == ro[1:][ring_of], ro[:-1][ring_of], nxt)
     ring_area = np.zeros(R, np.float64)
     np.add.at(ring_area, ring_of, x * y[nxt] - x[nxt] * y)
     ring_area *= 0.5
-    part_of_ring = np.repeat(np.arange(po.shape[0] - 1), np.diff(po))
     is_shell = np.arange(R) == po[:-1][part_of_ring]
     signed = np.where(is_shell, np.abs(ring_area), -np.abs(ring_area))
-    geom_of_part = np.repeat(np.arange(G), np.diff(go))
     np.add.at(out, geom_of_part[part_of_ring], signed)
     gt = np.asarray(col.geom_type, np.int64)
     base = np.where(gt > 3, gt - 3, gt)
     return np.where(base == int(GeometryType.POLYGON), out, 0.0)
+
+
+def _first_vertices(col: PackedGeometry) -> np.ndarray:
+    """(G, 2) f64: each geometry's first vertex (zeros where it has
+    none) — a frame of its own for its area."""
+    G = len(col)
+    xy = np.asarray(col.xy, np.float64).reshape(-1, np.asarray(col.xy).shape[-1])[:, :2]
+    out = np.zeros((G, 2), np.float64)
+    if not G or not xy.shape[0]:
+        return out
+    ro = np.asarray(col.ring_offsets, np.int64)
+    po = np.asarray(col.part_offsets, np.int64)
+    go = np.asarray(col.geom_offsets, np.int64)
+    has = (go[1:] > go[:-1])
+    fp = np.minimum(go[:-1], max(po.shape[0] - 2, 0))
+    fr = np.minimum(po[fp], max(ro.shape[0] - 2, 0))
+    v0 = np.minimum(ro[fr], xy.shape[0] - 1)
+    has &= ro[fr + 1] > ro[fr]
+    out[has] = xy[v0[has]]
+    return out
 
 
 def _masked_shoelace(verts: np.ndarray, vlen: np.ndarray) -> np.ndarray:
@@ -404,75 +473,160 @@ def _masked_shoelace(verts: np.ndarray, vlen: np.ndarray) -> np.ndarray:
     return 0.5 * contrib.sum(axis=1)
 
 
-def _chip_analysis(table: ChipTable):
-    """Per-chip-row CSR facts: ``(simple, r0s, r0l)`` — device-clippable
-    shape class (single-part single-ring polygon with a stored geometry)
-    plus its outer ring span."""
+def _ring_rows(table: ChipTable):
+    """The side table's rows before sorting: ``(chip, start, length,
+    sign)`` — one row a ring of every border polygon chip (the shell of
+    each part +1, its holes -1; rings of under three vertices are left
+    out), one row with no ring for every other chip (a core chip; a
+    border chip that is no polygon or stores no geometry, which then
+    clips to nothing)."""
     ch = table.chips
     C = len(ch)
+    z = np.zeros(0, np.int64)
     if not C:
-        z = np.zeros(0, np.int64)
-        return np.zeros(0, bool), z, z
-    has = np.asarray(table.has_geom, bool)
+        return z, z, z, np.zeros(0, np.float64)
     go = np.asarray(ch.geom_offsets, np.int64)
     po = np.asarray(ch.part_offsets, np.int64)
     ro = np.asarray(ch.ring_offsets, np.int64)
     gt = np.asarray(ch.geom_type, np.int64)
-    nparts = np.diff(go)
-    nrings = po[go[1:]] - po[go[:-1]]
-    fr = np.minimum(po[go[:-1]], max(ro.shape[0] - 2, 0))
-    r0s = ro[fr]
-    r0l = ro[fr + 1] - r0s
     base = np.where(gt > 3, gt - 3, gt)
-    simple = (
-        has
+    ringed = (
+        np.asarray(table.has_geom, bool)
+        & ~np.asarray(table.is_core, bool)
         & (base == int(GeometryType.POLYGON))
-        & (nparts == 1)
-        & (nrings == 1)
-        & (r0l >= 3)
     )
-    return simple, r0s, r0l
+    R = ro.shape[0] - 1
+    part_of_ring = np.repeat(np.arange(po.shape[0] - 1), np.diff(po))
+    chip_of_ring = np.repeat(np.arange(C), np.diff(go))[part_of_ring]
+    rlen = np.diff(ro)
+    keep = ringed[chip_of_ring] & (rlen >= 3)
+    shell = np.arange(R) == po[:-1][part_of_ring]
+    has_ring = np.zeros(C, bool)
+    has_ring[chip_of_ring[keep]] = True
+    bare = np.nonzero(~has_ring)[0]
+    chip = np.concatenate([chip_of_ring[keep], bare])
+    start = np.concatenate([ro[:-1][keep], np.zeros(bare.shape[0], np.int64)])
+    length = np.concatenate([rlen[keep], np.zeros(bare.shape[0], np.int64)])
+    sign = np.concatenate([
+        np.where(shell[keep], 1.0, -1.0), np.ones(bare.shape[0]),
+    ])
+    order = np.argsort(chip, kind="stable")
+    return chip[order], start[order], length[order], sign[order]
 
 
-def _side_verts(table: ChipTable, simple, r0s, r0l, V: int,
-                shift: np.ndarray, scale: float):
-    """(eligible, ok_win, verts, vlen) in original chip-row order —
-    left-packed CCW shifted outer rings padded by repeating the last
-    vertex, plus the convex-window eligibility flag."""
-    C = len(table.chips)
-    if not C:
+def _fan_negative(verts: np.ndarray, vlen: np.ndarray, tol: float):
+    """(N,) f64: the area a ring's fan from vertex 0 counts NEGATIVE
+    (0.0: the fan is a partition of the ring)."""
+    w0 = verts[:, :1]
+    a = verts[:, 1:-1] - w0
+    b = verts[:, 2:] - w0
+    cr = a[..., 0] * b[..., 1] - a[..., 1] * b[..., 0]
+    i = np.arange(1, verts.shape[1] - 1)[None, :]
+    live = i + 1 < vlen[:, None]
+    return np.where(live & (cr < -tol), -cr, 0.0).sum(axis=1)
+
+
+def _pack_rings(xy: np.ndarray, start, length, V: int, origin, scale: float):
+    """``(ok, convex, star, verts, vlen)`` for ring spans of ``xy``:
+    left-packed CCW open rings padded by repeating the last vertex, each
+    RELATIVE TO ITS CELL'S CORNER (``origin``, subtracted in f64 here,
+    once: both rings of a candidate row lie in one cell, so the frame
+    costs nothing and a coordinate is at most a cell's extent); a ring
+    over the pad gets ``ok`` False and no vertices. A ring that is not
+    convex is turned so that the apex whose fan counts the least
+    negative area comes first (``star``: none at all)."""
+    N = int(np.asarray(start).shape[0])
+    if not N:
         return (
-            np.zeros(0, bool), np.zeros(0, bool),
+            np.zeros(0, bool), np.zeros(0, bool), np.zeros(0, bool),
             np.zeros((0, V, 2), np.float64), np.zeros(0, np.int32),
         )
-    eligible = simple & (r0l <= V)
-    xy = np.asarray(table.chips.xy, np.float64)
-    safe_len = np.maximum(r0l, 1)
-    idx = r0s[:, None] + np.minimum(np.arange(V)[None, :],
-                                    safe_len[:, None] - 1)
+    ok = (length >= 3) & (length <= V)
+    safe_len = np.maximum(length, 1)
+    j = np.arange(V)[None, :]
+    idx = start[:, None] + np.minimum(j, safe_len[:, None] - 1)
     idx = np.clip(idx, 0, max(xy.shape[0] - 1, 0))
-    verts = xy[idx]
-    vlen = np.where(eligible, r0l, 0).astype(np.int32)
+    verts = xy[idx] - np.asarray(origin, np.float64)[:, None, :]
+    vlen = np.where(ok, length, 0).astype(np.int32)
+    verts = np.where(ok[:, None, None], verts, 0.0)
     # orient CCW (reverse the valid prefix where the ring is CW)
     sa = _masked_shoelace(verts, vlen)
-    j = np.arange(V)[None, :]
     rev = np.where(j < vlen[:, None],
                    np.maximum(vlen[:, None] - 1 - j, 0), j)
     flipped = np.take_along_axis(verts, rev[:, :, None], axis=1)
     verts = np.where((sa < 0)[:, None, None], flipped, verts)
-    verts = verts - np.asarray(shift, np.float64)[None, None, :]
-    # convex-window test on the oriented, shifted ring: every pair of
-    # consecutive edges turns left (cross ≥ -tol), wraparound included
+    # convex-window test: every pair of consecutive edges turns left
+    # (cross ≥ -tol), wraparound included
     nxt = np.where(j + 1 < vlen[:, None], j + 1, 0)
     nxy = np.take_along_axis(verts, nxt[:, :, None], axis=1)
     e = nxy - verts
     en = np.take_along_axis(e, nxt[:, :, None], axis=1)
     cross = e[:, :, 0] * en[:, :, 1] - e[:, :, 1] * en[:, :, 0]
     tol = _k.CLIP_EPS * scale * scale
-    convex = np.all(
+    convex = ok & np.all(
         np.where(j < vlen[:, None], cross, 0.0) >= -tol, axis=1
     )
-    return eligible, eligible & convex, verts, vlen
+    star = convex.copy()
+    bent = np.nonzero(ok & ~convex)[0]
+    if bent.size:
+        bv, bl = verts[bent], vlen[bent]
+        best = _fan_negative(bv, bl, tol)
+        best_at = np.zeros(bent.shape[0], np.int64)
+        for a in range(1, V):
+            turn = np.where(j < bl[:, None], (j + a) % np.maximum(bl, 1)[:, None], j)
+            cand = np.take_along_axis(bv, turn[:, :, None], axis=1)
+            neg = np.where(a < bl, _fan_negative(cand, bl, tol), np.inf)
+            better = neg < best
+            best = np.where(better, neg, best)
+            best_at = np.where(better, a, best_at)
+        turn = np.where(
+            j < bl[:, None], (j + best_at[:, None]) % np.maximum(bl, 1)[:, None], j
+        )
+        verts[bent] = np.take_along_axis(bv, turn[:, :, None], axis=1)
+        star[bent] = best == 0.0
+    # the pad repeats the last vertex (a turned ring's tail is re-made)
+    last = np.take_along_axis(
+        verts, np.maximum(vlen - 1, 0)[:, None, None].astype(np.int64)
+        * np.ones((1, 1, 2), np.int64), axis=1,
+    )
+    verts = np.where((j >= vlen[:, None])[:, :, None] & ok[:, None, None],
+                     last, verts)
+    return ok, convex, star, verts, vlen
+
+
+def overlay_acc_dtype(platform: str | None = None) -> str:
+    """THE rule for the overlay's accelerated dtype (a rule with its
+    reason, no argument — `sql.stream.stream_cell_dtype` is the model).
+
+    Rings are stored relative to their own cell's corner, so a
+    coordinate is at most a cell's extent and float32 holds it to
+    ``1.2e-7`` of that: the band, ``EDGE_BAND_K · eps · cell²``, is a
+    five-hundredth of a percent of the cell. On the TPU that is the
+    clip's dtype: the chip has no float64 unit, emulates it at about 46
+    bits (`runtime.platform.arithmetic_eps`) and pays for it several
+    times over in every half-plane round, to narrow a band under which
+    the f64 host lane answers anyway (both readings: ``PERF.md``
+    section 6, PR 40). Everywhere else float64 under x64 — there the
+    device lane IS the oracle's arithmetic and matches the numpy twin
+    bit for bit — and float32 without it."""
+    if platform is None:
+        platform = jax.devices()[0].platform
+    if platform == "tpu" or not jax.config.jax_enable_x64:
+        return "float32"
+    return "float64"
+
+
+def overlay_band(acc_name: str, scale: float,
+                 platform: str | None = None) -> float:
+    """The recheck band in area units: ``EDGE_BAND_K`` rounding steps of
+    the arithmetic the device REALLY computes ``acc_name`` in
+    (`runtime.platform.arithmetic_eps`: the chip's float64 is not
+    numpy's) times the square of the largest coordinate a ring holds,
+    which in the cell-local frame is a cell's extent."""
+    return float(
+        EDGE_BAND_K * _platform.arithmetic_eps(acc_name, platform)
+        * scale * scale
+    )
 
 
 def prepare_overlay(
@@ -485,19 +639,23 @@ def prepare_overlay(
 ) -> OverlayPrep:
     """Build the amortized device-lane prep for an overlay table pair.
 
-    One host pass per table pair: sort both chip tables by cell id, pad
-    to ladder buckets with per-side sentinels, precompute the f64 area
-    tables (chip, cell, whole-geometry — all in a shared shifted frame
-    centered on the data so the f32 lane keeps maximal mantissa), pack
-    the device-clippable outer rings to the vertex pad, and derive the
-    epsilon-band threshold. Everything here is reused across measures,
-    caps and meshes — only the fused program varies per signature.
+    One host pass per table pair: explode both chip tables into ring
+    rows, sort them by cell id, pad to ladder buckets with per-side
+    sentinels, precompute the f64 area tables (ring, cell,
+    whole-geometry), pack every border ring within the vertex pad in
+    its own cell's frame (:func:`_pack_rings`), derive the epsilon band
+    from the arithmetic the device really computes in
+    (:func:`overlay_band`) and put what the fused program reads on the
+    device, once, in the accelerated dtype. Everything here is reused
+    across measures, caps and meshes — only the fused program varies
+    per signature, and a call moves the candidate rows, the segments
+    and the answers.
     """
     with _trace.span(
         "overlay.prepare",
         left_chips=int(np.asarray(left_chips.cell_id).shape[0]),
         right_chips=int(np.asarray(right_chips.cell_id).shape[0]),
-    ):
+    ) as span:
         lcells_raw = np.asarray(left_chips.cell_id, np.int64)
         rcells_raw = np.asarray(right_chips.cell_id, np.int64)
         ucells = np.unique(np.concatenate([lcells_raw, rcells_raw]))
@@ -507,84 +665,114 @@ def prepare_overlay(
             )
         else:
             bnds = np.zeros((0, 4, 2), np.float64)
-        lxy = np.asarray(left_chips.chips.xy, np.float64).reshape(-1, 2)
-        rxy = np.asarray(right_chips.chips.xy, np.float64).reshape(-1, 2)
-        allxy = np.concatenate([lxy, rxy, bnds.reshape(-1, 2)], axis=0)
-        if allxy.shape[0]:
-            lo, hi = allxy.min(axis=0), allxy.max(axis=0)
-            shift = 0.5 * (lo + hi)
-            scale = float(max(1.0, float(np.max(np.abs(allxy - shift)))))
+        if bnds.shape[0]:
+            corner = bnds.min(axis=1)
+            scale = float(max(
+                np.max(bnds.max(axis=1) - corner), np.finfo(np.float64).tiny
+            ))
+            allxy = bnds.reshape(-1, 2)
+            shift = 0.5 * (allxy.min(axis=0) + allxy.max(axis=0))
         else:
-            shift = np.zeros(2, np.float64)
+            corner = np.zeros((0, 2), np.float64)
             scale = 1.0
+            shift = np.zeros(2, np.float64)
         cell_polys, klen = _dedupe_boundaries_batch(bnds)
         ucell_area = np.abs(_masked_shoelace(
-            cell_polys - shift[None, None, :], klen.astype(np.int64)
+            cell_polys - corner[:, None, :], klen.astype(np.int64)
         ))
 
-        lsimple, lr0s, lr0l = _chip_analysis(left_chips)
-        rsimple, rr0s, rr0l = _chip_analysis(right_chips)
+        lrows = _ring_rows(left_chips)
+        rrows = _ring_rows(right_chips)
 
-        def _border_max(table, simple, r0l):
-            m = simple & ~np.asarray(table.is_core, bool)
-            return int(r0l[m].max()) if m.any() else 0
+        def _longest_ring(rows):
+            length = rows[2]
+            return int(length.max()) if length.shape[0] else 0
 
-        V = int(min(MAX_CHIP_VERTS, max(
-            4,
-            _border_max(left_chips, lsimple, lr0l),
-            _border_max(right_chips, rsimple, rr0l),
-        )))
+        # the pad is part of every program's signature: the longest border
+        # ring, rounded up to a multiple of four so that layers which
+        # differ by a vertex share their programs
+        longest = max(4, _longest_ring(lrows), _longest_ring(rrows))
+        V = int(min(MAX_CHIP_VERTS, -(-longest // 4) * 4))
 
-        acc = _acc_name()
-        band = (
-            EDGE_BAND_K * float(np.finfo(np.dtype(acc)).eps)
-            * scale * scale
-        )
+        acc = overlay_acc_dtype()
+        band = overlay_band(acc, scale)
+        acc_dt = np.dtype(acc)
 
-        def _side(table, col, cells_raw, simple, r0s, r0l, pad_cell):
-            n = int(cells_raw.shape[0])
-            order = np.argsort(cells_raw, kind="stable")
+        def _side(table, col, cells_raw, rows, pad_cell):
+            chip, start, length, sign = rows
+            n = int(chip.shape[0])
+            cells_row = cells_raw[chip]
+            order = np.argsort(cells_row, kind="stable")
             Lb = TABLE_LADDER.bucket_for(max(n, 1))
-            elig, ok_win, verts, vlen = _side_verts(
-                table, simple, r0s, r0l, V, shift, scale
+            pos = np.searchsorted(ucells, cells_row)
+            origin = corner[pos] if n else np.zeros((0, 2), np.float64)
+            xy = np.asarray(table.chips.xy, np.float64)
+            xy = xy.reshape(-1, xy.shape[-1])[:, :2] if xy.size else np.zeros((0, 2))
+            ok, convex, star, verts, vlen = _pack_rings(
+                xy, start, length, V, origin, scale
             )
-            chip_area = _csr_geom_areas(table.chips, shift)
-            pos = np.searchsorted(ucells, cells_raw)
+            core = np.asarray(table.is_core, bool)[chip]
             row_cell_area = (
                 ucell_area[pos] if n else np.zeros(0, np.float64)
             )
-            core = np.asarray(table.is_core, bool)
-            # a core chip covers its cell exactly — use the cell table so
-            # the core branches and the area tables agree bit-for-bit
-            chip_area = np.where(core, row_cell_area, chip_area)
+            # a ring's signed area in its own frame; a core chip covers
+            # its cell exactly — use the cell table so the core
+            # branches and the area tables agree bit-for-bit. A ring
+            # over the pad is measured by the host lane when a row
+            # needs it, so its table entry is its own shoelace too.
+            ring_area = sign * np.abs(_masked_shoelace(verts, vlen))
+            big = np.nonzero((length > V))[0]
+            if big.size:
+                Vb = int(length[big].max())
+                _o, _c, _s, bverts, bvlen = _pack_rings(
+                    xy, start[big], length[big], Vb, origin[big], scale
+                )
+                ring_area[big] = sign[big] * np.abs(
+                    _masked_shoelace(bverts, bvlen)
+                )
+            chip_area = np.where(core, row_cell_area, ring_area)
 
             def pad(a, fill=0):
                 out = np.full((Lb,) + a.shape[1:], fill, a.dtype)
                 out[:n] = a[order]
                 return out
 
-            return OverlaySide(
-                table=table,
-                n=n,
-                bucket=Lb,
-                cells=pad(cells_raw, pad_cell),
-                geom=pad(np.asarray(table.geom_id, np.int64), -1),
+            side = dict(
+                cells=pad(cells_row, pad_cell),
+                geom=pad(np.asarray(table.geom_id, np.int64)[chip], -1),
                 core=pad(core),
-                ok_subj=pad(elig),
-                ok_win=pad(ok_win),
+                ok=pad(ok & ~core),
+                convex=pad(convex & ~core),
+                star=pad(star & ~core),
+                sign=pad(sign, 1.0),
                 verts=pad(verts),
                 vlen=pad(vlen),
                 chip_area=pad(chip_area),
                 cell_area=pad(row_cell_area),
-                rows=order.astype(np.int64),
-                geom_area=_csr_geom_areas(col, shift),
+                origin=pad(origin),
+                ring_start=pad(start),
+                ring_len=pad(length),
+            )
+            dev = {
+                k: jax.device_put(
+                    side[k].astype(acc_dt)
+                    if side[k].dtype == np.float64 else side[k]
+                )
+                for k in ("cells", "core", "sign", "verts", "vlen",
+                          "chip_area", "cell_area")
+            }
+            return OverlaySide(
+                table=table, n=n, bucket=Lb,
+                rows=chip[order].astype(np.int64),
+                geom_area=_csr_geom_areas(col, _first_vertices(col)),
+                dev=dev, **side,
             )
 
-        return OverlayPrep(
-            left=_side(left_chips, left, lcells_raw, lsimple, lr0s,
-                       lr0l, _k.LEFT_PAD_CELL),
-            right=_side(right_chips, right, rcells_raw, rsimple, rr0s,
-                        rr0l, _k.RIGHT_PAD_CELL),
+        prep = OverlayPrep(
+            left=_side(left_chips, left, lcells_raw, lrows,
+                       _k.LEFT_PAD_CELL),
+            right=_side(right_chips, right, rcells_raw, rrows,
+                        _k.RIGHT_PAD_CELL),
             shift=np.asarray(shift, np.float64),
             scale=scale,
             index_system=index_system,
@@ -593,6 +781,15 @@ def prepare_overlay(
             band=float(band),
             vpad=V,
         )
+        span.set(
+            left_rows=prep.left.n, right_rows=prep.right.n, vpad=V,
+            acc=acc, band=float(band), scale=scale,
+            resident_bytes=sum(
+                int(a.nbytes) for s in (prep.left, prep.right)
+                for a in s.dev.values()
+            ),
+        )
+        return prep
 
 
 def pair_glue(prep: OverlayPrep, li, ri, valid):
@@ -609,10 +806,13 @@ def pair_glue(prep: OverlayPrep, li, ri, valid):
     valid = valid & (lg >= 0) & (rg >= 0)
     seg = np.full(li.shape[0], -1, np.int32)
     if valid.any():
-        uniq, inv = np.unique(
-            np.stack([lg[valid], rg[valid]], axis=-1),
-            axis=0, return_inverse=True,
+        # one int64 key a pair, in (left, right) order: the unique of a
+        # 1-d key is a sort of words, of an (N, 2) table a sort of rows
+        width = np.int64(R.geom_area.shape[0] + 1)
+        key, inv = np.unique(
+            lg[valid] * width + rg[valid], return_inverse=True
         )
+        uniq = np.stack([key // width, key % width], axis=-1)
         seg[valid] = inv.astype(np.int32)
     else:
         uniq = np.zeros((0, 2), np.int64)
@@ -620,7 +820,7 @@ def pair_glue(prep: OverlayPrep, li, ri, valid):
     sure = np.zeros(U, bool)
     either = L.core[li] | R.core[ri]
     if valid.any():
-        np.logical_or.at(sure, seg[valid], either[valid])
+        sure[seg[valid & either]] = True
     Sb = SEG_LADDER.bucket_for(max(U, 1))
     seg_larea = np.zeros(Sb, np.float64)
     seg_rarea = np.zeros(Sb, np.float64)
@@ -630,19 +830,101 @@ def pair_glue(prep: OverlayPrep, li, ri, valid):
     return uniq, seg, sure, Sb, seg_larea, seg_rarea
 
 
+def pair_routes(prep: OverlayPrep, li, ri, seg):
+    """Which border × border candidate rows take which clip — host
+    flags, shared by both lanes: ``(clip_rows, clip_swap, fan_rows,
+    fan_swap, shape_rows)``, the rows ascending int32 indices into the
+    candidate stream, the swaps by `kernels.overlay.window_swaps`.
+
+    - ``clip_rows``: one of the two rings is convex, and is the window.
+    - ``fan_rows``: neither is — the signed fan
+      (`kernels.overlay.fan_area`).
+    - ``shape_rows``: a ring over the vertex pad; the f64 host lane
+      answers the whole geometry pair.
+    """
+    L, R = prep.left, prep.right
+    li = np.asarray(li)
+    ri = np.asarray(ri)
+    bb = (np.asarray(seg) >= 0) & ~L.core[li] & ~R.core[ri]
+    ringed = (L.ring_len[li] >= 3) & (R.ring_len[ri] >= 3)
+    okk = L.ok[li] & R.ok[ri]
+    conv = L.convex[li] | R.convex[ri]
+    clip_r, fan_r, shape_r = (
+        np.nonzero(m)[0].astype(np.int32)
+        for m in (bb & okk & conv, bb & okk & ~conv, bb & ringed & ~okk)
+    )
+
+    def swaps(rows):
+        lk, rk = li[rows], ri[rows]
+        return _k.window_swaps(
+            L.convex[lk], R.convex[rk], L.star[lk], R.star[rk]
+        )
+
+    return clip_r, swaps(clip_r)[0], fan_r, swaps(fan_r)[1], shape_r
+
+
+def _host_tables(side: OverlaySide, acc: np.dtype) -> dict:
+    """What ``side.dev`` holds, as host arrays in ``acc``."""
+    out = {}
+    for k in side.dev:
+        a = getattr(side, k)
+        out[k] = a.astype(acc) if a.dtype == np.float64 else a
+    return out
+
+
+def _padded(rows: np.ndarray, bucket: int) -> np.ndarray:
+    out = np.zeros(bucket, rows.dtype)
+    out[: rows.shape[0]] = rows
+    return out
+
+
 # --------------------------------------------------- device-lane programs
 
 
 @_dispatch.bounded_cache("overlay_count_programs", 8)
 def _count_program():
-    return jax.jit(partial(_k.pair_count, xp=jnp))
+    def overlay_count(lcells, rcells, n_left):
+        return _k.pair_count(lcells, rcells, n_left, xp=jnp)
+
+    return jax.jit(overlay_count)
 
 
 @_dispatch.bounded_cache("overlay_emit_programs", 32)
 def _emit_program(pair_bucket: int):
-    return jax.jit(
-        partial(_k.emit_pairs, pair_bucket=pair_bucket, xp=jnp)
-    )
+    def overlay_emit(lcells, rcells, n_left, emit_limit):
+        return _k.emit_pairs(
+            lcells, rcells, n_left, emit_limit, pair_bucket, xp=jnp
+        )
+
+    return jax.jit(overlay_emit)
+
+
+_STAGES_SEEN: set = set()
+
+
+def _register_stages(fn, args: tuple, rows: int) -> None:
+    """Tell `obs.stages` how to lower ``fn(*args)`` again (shapes only;
+    nothing is lowered here), once a (program, shapes) signature."""
+    key = (id(fn), rows, tuple(
+        (getattr(a, "shape", None), str(getattr(a, "dtype", type(a))))
+        for a in args
+    ))
+    if key in _STAGES_SEEN:
+        return
+    if len(_STAGES_SEEN) >= 256:
+        _STAGES_SEEN.clear()
+    _STAGES_SEEN.add(key)
+    _stages.register(fn, _stages.shapes_of(args), rows=rows)
+
+
+def _beside_root(sp, outer):
+    """Keep a detached span BESIDE the call's root: the caller's child
+    where the caller has a span (``outer``), else a root of its own —
+    never the root's child, whose direct children are the call's pieces
+    and nothing else."""
+    if outer is None:
+        sp.parent_id = None
+    return sp
 
 
 @dataclass(frozen=True)
@@ -655,9 +937,10 @@ class OverlayMeasures:
     ``value`` is the evaluated pair tree (f64), ``valid`` its mask lane,
     ``area`` the folded intersection area, ``sure`` the core-chip
     certainty flag, ``host_overridden`` how many pairs the f64 host lane
-    re-answered (epsilon band / shape class), and ``lane`` which lane
-    produced the numbers (``degraded`` True when the device lane failed
-    past its retry budget and the host oracle answered instead)."""
+    re-answered (epsilon band / over-pad ring / spill), and ``lane``
+    which lane produced the numbers (``degraded`` True when the device
+    lane failed past its retry budget and the host oracle answered
+    instead)."""
 
     pairs: np.ndarray
     value: np.ndarray
@@ -717,9 +1000,20 @@ def overlay_measures(
     / ``left_area`` / ``right_area`` (default: the raw intersection
     area); ``st_intersection_area`` and ``st_overlap_fraction`` are the
     canned frontends. Candidate generation runs on device as a sorted
-    segment equi-join over the prep's cell columns, the measures as ONE
-    fused program per ``(tree-hash, buckets, index, mesh)`` signature —
-    warm it with :func:`warmup_overlay` before `expr.compile.freeze`.
+    segment equi-join over the prep's resident cell columns, the
+    measures as ONE fused program per ``(tree-hash, buckets, index,
+    mesh)`` signature — warm it with :func:`warmup_overlay` before
+    `expr.compile.freeze`.
+
+    One call records, under its root span ``overlay.call`` (the pair's
+    ``left_rows``, ``right_rows``, ``acc`` and ``vpad``; counters
+    ``raw_candidates``, ``pairs``, ``bucket``, ``clip_rows``,
+    ``swapped_rows``, ``fan_rows``, ``fan_triangles``,
+    ``host_overridden`` and its split ``host_band`` / ``host_shape`` /
+    ``host_spill`` / ``host_cancel``): ``overlay.count`` (launch and the blocking read of
+    the count), ``overlay.emit`` (launch and pull of the rows),
+    ``overlay.glue`` (`pair_glue`, `pair_routes`), ``overlay.launch``,
+    ``overlay.pull`` and ``overlay.host_override``.
 
     ``lane="host"`` routes to the pure-f64 numpy twin (the oracle); the
     device lane degrades there automatically (result flagged) when the
@@ -756,80 +1050,151 @@ def overlay_measures(
 
     L, R = prep.left, prep.right
     acc = np.dtype(prep.acc_name)
+    meshed = _dispatch.mesh_key(mesh) is not None
+    # a meshed program takes host tables (it places them itself); the
+    # single-device one reads the resident copies
+    lt_, rt_ = (
+        (_host_tables(L, acc), _host_tables(R, acc)) if meshed
+        else (L.dev, R.dev)
+    )
+    outer = _trace.current_context()
     try:
         with _trace.span(
-            "overlay.device_candidates",
-            left_chips=L.n, right_chips=R.n,
-        ) as span:
+            "overlay.call", left_rows=L.n, right_rows=R.n, acc=prep.acc_name,
+            vpad=prep.vpad,
+        ) as call:
+            # the names of before the call had an inside: siblings of the
+            # root, so its direct children stay the pieces and nothing
+            # is counted twice
+            cand_span = _beside_root(_trace.start_span(
+                "overlay.device_candidates", parent=outer, detached=True,
+                left_chips=L.n, right_chips=R.n,
+            ), outer)
             with _telemetry.timed("overlay_stage", stage="candidates"):
 
                 def device_candidates():
-                    total = int(
-                        _count_program()(L.cells, R.cells, L.n)
-                    )
+                    with _trace.span("overlay.count"):
+                        count = _count_program()
+                        args = (lt_["cells"], rt_["cells"], L.n)
+                        _register_stages(count, args, L.bucket)
+                        total = int(count(*args))
                     Pb, emit_limit, overflow = pair_plan(
                         total, pair_cap
                     )
-                    li, ri, valid = _emit_program(Pb)(
-                        L.cells, R.cells, L.n, emit_limit
-                    )
+                    with _trace.span("overlay.emit", bucket=Pb):
+                        emit = _emit_program(Pb)
+                        args = (
+                            lt_["cells"], rt_["cells"], L.n, emit_limit
+                        )
+                        _register_stages(emit, args, Pb)
+                        dli, dri, dvalid = emit(*args)
+                        li = np.asarray(dli)
+                        ri = np.asarray(dri)
                     return (
-                        np.asarray(li), np.asarray(ri),
-                        np.asarray(valid), total, Pb, emit_limit,
-                        overflow,
+                        dli, dri, dvalid, li, ri,
+                        np.arange(Pb) < emit_limit,
+                        total, Pb, emit_limit, overflow,
                     )
 
-                li, ri, valid, total, Pb, emit_limit, overflow = (
-                    _dispatch.guarded_call(
-                        "overlay.device_candidates", device_candidates
+                (dli, dri, dvalid, li, ri, valid, total, Pb,
+                 emit_limit, overflow) = _dispatch.guarded_call(
+                    "overlay.device_candidates", device_candidates
+                )
+                with _trace.span("overlay.glue", rows=emit_limit):
+                    uniq, seg, sure, Sb, seg_l64, seg_r64 = pair_glue(
+                        prep, li, ri, valid
                     )
-                )
-                uniq, seg, sure, Sb, seg_l64, seg_r64 = pair_glue(
-                    prep, li, ri, valid
-                )
-            span.set(
+                    clip_r, clip_swap, fan_r, fan_swap, shape_r = (
+                        pair_routes(prep, li, ri, seg)
+                    )
+                    Cb = PAIR_LADDER.bucket_for(max(clip_r.shape[0], 1))
+                    Fb = PAIR_LADDER.bucket_for(max(fan_r.shape[0], 1))
+            cand_span.set(
                 raw_candidates=total, emitted=emit_limit,
                 overflow=overflow,
             )
-            _candidate_stats(span, sure)
+            _candidate_stats(cand_span, sure)
+            cand_span.end()
 
-        with _trace.span(
-            "overlay.measures", pairs=int(uniq.shape[0]),
-            candidates=total, mesh=_dispatch.mesh_key(mesh) is not None,
-        ) as span:
+            meas_span = _beside_root(_trace.start_span(
+                "overlay.measures", parent=outer, detached=True,
+                pairs=int(uniq.shape[0]), candidates=total, mesh=meshed,
+            ), outer)
             with _telemetry.timed("overlay_stage", stage="measures"):
                 sig = _compile.overlay_signature_of(
-                    value, L.bucket, R.bucket, Pb, Sb, prep.vpad,
+                    value, L.bucket, R.bucket, Pb, Cb, Fb, Sb, prep.vpad,
                     prep.acc_name, index_system, resolution, mesh,
                 )
                 prog = _compile.overlay_program(
-                    value, L.bucket, R.bucket, Pb, Sb, prep.vpad,
+                    value, L.bucket, R.bucket, Pb, Cb, Fb, Sb, prep.vpad,
                     prep.acc_name, mesh,
                 )
-                raw = _dispatch.guarded_call(
-                    "overlay.measures",
-                    _compile.run_tracked, sig, prog,
-                    li, ri, valid, seg,
-                    L.core, L.ok_subj,
-                    L.verts.astype(acc), L.vlen,
-                    L.chip_area.astype(acc), L.cell_area.astype(acc),
-                    R.core, R.ok_win,
-                    R.verts.astype(acc), R.vlen,
-                    R.chip_area.astype(acc),
+                if meshed:
+                    dli, dri, dvalid = li, ri, valid
+                args = (
+                    dli, dri, dvalid, seg,
+                    _padded(clip_r, Cb), _padded(clip_swap, Cb),
+                    np.int32(clip_r.shape[0]),
+                    _padded(fan_r, Fb), _padded(fan_swap, Fb),
+                    np.int32(fan_r.shape[0]),
+                    lt_["core"], lt_["sign"], lt_["verts"], lt_["vlen"],
+                    lt_["chip_area"], lt_["cell_area"],
+                    rt_["core"], rt_["sign"], rt_["verts"], rt_["vlen"],
+                    rt_["chip_area"],
                     seg_l64.astype(acc), seg_r64.astype(acc),
                     acc.type(prep.band),
                 )
-                val, vok, s, _cnt, host_needed = (
-                    np.asarray(x) for x in raw
+                if not meshed:
+                    _register_stages(prog, args, Pb)
+
+                def measures():
+                    with _trace.span(
+                        "overlay.launch", clip_bucket=Cb, fan_bucket=Fb,
+                    ):
+                        raw = _compile.run_tracked(sig, prog, *args)
+                    with _trace.span("overlay.pull"):
+                        return tuple(np.asarray(x) for x in raw)
+
+                val, vok, s, cnt, host_c, host_f, spill_c, spill_f = (
+                    _dispatch.guarded_call("overlay.measures", measures)
                 )
                 val = val.astype(np.float64).copy()
                 vok = vok.astype(bool).copy()
                 area64 = s.astype(np.float64).copy()
-                val, vok, area64, overridden = splice_override(
-                    prep, value, li, ri, valid, seg,
-                    host_needed, seg_l64, seg_r64, val, vok, area64,
-                )
-            span.set(host_overridden=overridden)
+                nc, nf = clip_r.shape[0], fan_r.shape[0]
+                flagged_rows = np.concatenate([
+                    clip_r[host_c[:nc]], fan_r[host_f[:nf]], shape_r,
+                ])
+                with _trace.span("overlay.host_override") as hspan:
+                    (val, vok, area64, overridden, hrows,
+                     cancelled) = splice_override(
+                        prep, value, li, ri, seg, flagged_rows, cnt,
+                        seg_l64, seg_r64, val, vok, area64,
+                    )
+                    hspan.set(pairs=overridden, rows=hrows)
+            meas_span.set(host_overridden=overridden)
+            meas_span.end()
+            wlen = np.where(fan_swap, L.vlen[li[fan_r]], R.vlen[ri[fan_r]])
+
+            def pairs_of(*rows):  # geometry pairs that hold such a row
+                return int(np.unique(seg[np.concatenate(rows)]).shape[0])
+
+            call.set(
+                raw_candidates=total, pairs=int(uniq.shape[0]), bucket=Pb,
+                clip_rows=int(nc + nf + shape_r.shape[0]),
+                swapped_rows=int(clip_swap.sum()), fan_rows=int(nf),
+                fan_triangles=int(np.maximum(wlen - 2, 0).sum()),
+                host_overridden=overridden, host_cancel=cancelled,
+                host_shape=pairs_of(shape_r),
+                host_spill=pairs_of(
+                    clip_r[spill_c[:nc]], fan_r[spill_f[:nf]]
+                ),
+                host_band=pairs_of(
+                    clip_r[host_c[:nc] & ~spill_c[:nc]],
+                    fan_r[host_f[:nf] & ~spill_f[:nf]],
+                ),
+                overflow=overflow,
+            )
         U = uniq.shape[0]
         return _package(
             {

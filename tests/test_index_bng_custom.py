@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from mosaic_tpu.core.index import (
     BNG,
@@ -9,6 +10,24 @@ from mosaic_tpu.core.index import (
     GridConf,
     custom_from_name,
 )
+
+
+def _one_box_walk(bounds, resolution):
+    """One bbox's covering cells, x-major: the grid's corners clamped to
+    its extent, `arange` over the cells' origins, `meshgrid` of their
+    centres. Kept here as the independent walk the batch is held to."""
+    edge = BNG.edge_size(resolution)
+    x0 = max(0, int(np.floor(bounds[0] / edge)) * edge)
+    y0 = max(0, int(np.floor(bounds[1] / edge)) * edge)
+    x1 = min(700_000, int(np.ceil(bounds[2] / edge)) * edge)
+    y1 = min(1_300_000, int(np.ceil(bounds[3] / edge)) * edge)
+    xs = np.arange(x0, x1, edge, dtype=np.float64) + edge / 2
+    ys = np.arange(y0, y1, edge, dtype=np.float64) + edge / 2
+    if not len(xs) or not len(ys):
+        return np.zeros(0, dtype=np.int64)
+    gx, gy = np.meshgrid(xs, ys, indexing="ij")
+    centers = np.stack([gx.ravel(), gy.ravel()], axis=-1)
+    return np.asarray(BNG.point_to_cell(jnp.asarray(centers), resolution))
 
 
 class TestBNG:
@@ -104,6 +123,83 @@ class TestBNG:
         )
         assert len(cand) == 3 * 2
         assert len(set(cand.tolist())) == 6
+
+    def test_host_arrays_are_answered_on_the_host_like_the_device(self):
+        """A numpy input computes in numpy (tessellation asks for the
+        cells of host arrays of every size, and each new size of an eager
+        device op is a compile of its own); the answer is the device's."""
+        import jax
+
+        rng = np.random.default_rng(3)
+        pts = np.column_stack(
+            [rng.uniform(0, 700_000, 300), rng.uniform(0, 1_300_000, 300)]
+        )
+        for res in BNG.resolutions():
+            host = BNG.point_to_cell(pts, res)
+            dev = BNG.point_to_cell(jnp.asarray(pts), res)
+            assert isinstance(host, np.ndarray) and isinstance(dev, jax.Array)
+            np.testing.assert_array_equal(host, np.asarray(dev))
+            for fn in (BNG.cell_boundary, BNG.cell_center):
+                a, b = fn(host), fn(jnp.asarray(host))
+                assert isinstance(a, np.ndarray) and isinstance(b, jax.Array)
+                np.testing.assert_array_equal(a, np.asarray(b))
+        # inside jit the tracer takes the device path
+        jitted = jax.jit(lambda p: BNG.point_to_cell(p, 4))(pts)
+        np.testing.assert_array_equal(
+            np.asarray(jitted), BNG.point_to_cell(pts, 4))
+
+    @pytest.mark.parametrize("res", BNG.resolutions())
+    def test_polyfill_candidates_batch_is_the_one_box_walk(self, res):
+        """The batch against an independent walk of one box (`_one_box_walk`,
+        the arange/meshgrid code the batch replaced), at every resolution:
+        boxes the size of a few cells everywhere on the grid, over its
+        corners and edges, and in the partial 500 km blocks T, O, H and J
+        at its east and north edge."""
+        edge = BNG.edge_size(res)
+        rng = np.random.default_rng(4)
+        lo = np.column_stack(
+            [rng.uniform(0, 690_000, 50), rng.uniform(0, 1_290_000, 50)]
+        )
+        boxes = np.column_stack([lo, lo + rng.uniform(0.01, 3.0, (50, 2)) * edge])
+        # (anchor, extent in cells): the named boxes keep a few cells at
+        # every resolution
+        for i, (x, y, w, h) in enumerate([
+            (-0.5 * edge, -0.2 * edge, 2.5, 1.3),   # over the south-west corner
+            (700_000 - 0.9 * edge, 5.0, 1.4, 0.01),  # over the east edge
+            (1_010_000.0, 5.0, 1.0, 0.01),          # outside: nothing
+            (530_000.0, 180_000.0, 1.2, 1.0),       # T (London)
+            (600_000.0, 600_000.0, 1.0, 1.5),       # O
+            (100_000.0, 1_100_000.0, 0.8, 0.3),     # H
+            (650_000.0, 1_250_000.0, 0.7, 0.1),     # J
+            (700_000 - 0.5 * edge, 1_300_000 - 0.5 * edge, 1.0, 1.0),  # far corner
+        ]):
+            boxes[i] = [x, y, x + w * edge, y + h * edge]
+        got = BNG.polyfill_candidates_batch(boxes, res)
+        assert len(got) == 50 and got[2].shape == (0,)
+        for box, cells in zip(boxes, got):
+            np.testing.assert_array_equal(cells, _one_box_walk(box, res))
+            np.testing.assert_array_equal(
+                cells, BNG.polyfill_candidates(box, res))
+            if box[0] < 700_000:
+                assert cells.size
+            if not cells.size:
+                continue
+            assert len(set(cells.tolist())) == cells.size
+            b = BNG.cell_boundary(cells)
+            # every cell meets the box, and the box's corners are covered
+            assert (b[..., 0].max(1) > box[0]).all() and (b[..., 0].min(1) < box[2] + edge).all()
+            assert (b[..., 1].max(1) > box[1]).all() and (b[..., 1].min(1) < box[3] + edge).all()
+            for corner in (box[[0, 1]], box[[2, 3]] - 1e-6):
+                inside = np.clip(corner, 0, [699_999.0, 1_299_999.0])
+                assert BNG.point_to_cell(inside[None], res)[0] in cells
+
+    def test_polyfill_reaches_the_partial_500km_blocks(self):
+        """London at '500km': 500 km blocks do not divide the grid's 700 x
+        1,300 km, and the blocks at its east and north edge are cells."""
+        london = np.array([530_000.0, 180_000.0, 542_000.0, 190_000.0])
+        assert BNG.format(BNG.polyfill_candidates(london, -1)) == ["T"]
+        north = np.array([100_000.0, 1_100_000.0, 650_000.0, 1_250_000.0])
+        assert BNG.format(BNG.polyfill_candidates(north, -1)) == ["H", "J"]
 
     def test_500km_blocks(self):
         pts = jnp.asarray([[100.0, 100.0], [600_000.0, 100.0], [100.0, 1_200_000.0]])

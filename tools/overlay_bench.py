@@ -124,11 +124,15 @@ def main() -> int:
                                    res=args.res)
         from mosaic_tpu.runtime.platform import (
             configure_compile_cache,
+            per_chip,
             require_device,
         )
 
         # raises off-TPU unless JAX_PLATFORMS=cpu asked for the CPU
-        detail["platform"] = require_device()["platform"]
+        info = require_device()
+        detail["platform"] = info["platform"]
+        # a rate from XLA:CPU is never filed under a device's unit
+        line["unit"] = per_chip("zone-pairs/s", info)
         detail["compile_cache_dir"] = configure_compile_cache()
         detail["n_per_side"] = args.n
 
