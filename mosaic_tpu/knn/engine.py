@@ -25,6 +25,10 @@ a query or once a pair. Two ways to evaluate an iteration's pairs:
   host. The host lists (query, block) chunks; the device gathers each
   chunk's block row, evaluates the distances, keeps the chunk's k best
   and folds the chunks of one query together (:func:`block_topk_prog`).
+  The host names, with each launch, the slots where a query begins in it
+  (its *heads*); a second small program gathers those rows on the device
+  (:func:`block_heads_prog`), so one row a query a launch comes back and
+  the per-chunk answers never leave the chip (:func:`fold_heads`).
 """
 
 from __future__ import annotations
@@ -53,6 +57,7 @@ class RingResult:
     pairs: int = 0  # (query, candidate) pairs whose distance was evaluated
     pairs_padded: int = 0  # slots the device evaluated for them
     launches: int = 0  # device launches of the distance programs
+    rows_pulled: int = 0  # block lane: answer rows pulled, padded to a rung
     degraded: "DegradedResult | None" = None
 
 
@@ -126,7 +131,9 @@ def block_topk_prog():
     query point, keep the k best by (distance, id); then fold the chunks
     of one query — they are adjacent — by doubling: after step ``s`` a
     chunk holds the best of the ``2s`` chunks from it on, so the first
-    chunk of a query ends with the query's k best of this launch."""
+    chunk of a query ends with the query's k best of this launch. Its
+    ``(b, k)`` outputs stay on the device: :func:`block_heads_prog` reads
+    the head rows out of them."""
     import jax
     import jax.numpy as jnp
 
@@ -162,6 +169,22 @@ def block_topk_prog():
     return jax.jit(knn_blocks, static_argnames=("k",))
 
 
+@bounded_cache("knn_block_heads", 1)
+def block_heads_prog():
+    """The gather behind every launch of :func:`block_topk_prog`: the rows
+    of its folded ``(d, gid)`` at ``head``, the launch's slots where a
+    query begins, padded to a rung of the block ladder. A program (and a
+    module name, hence a stage table) of its own: the block program's
+    executables stay what they were, whatever the head rung."""
+    import jax
+
+    def knn_heads(d, gid, head):
+        with jax.named_scope("knn.heads"):
+            return d[head], gid[head]
+
+    return jax.jit(knn_heads)
+
+
 def block_chunks(pb, ring: np.ndarray):
     """The (query, block) chunks of one iteration: ``ring`` is (a, M) ring
     cells of ``a`` active point queries, -1 pads. Returns ``(cq, blk,
@@ -180,26 +203,32 @@ def block_chunks(pb, ring: np.ndarray):
     )
 
 
-def fold_heads(cq, out_d, out_i, cap: int, a: int, k: int):
+def launch_heads(cq: np.ndarray, cap: int) -> np.ndarray:
+    """The head chunks of an iteration's launches (``cap`` chunks each):
+    slot 0 of every launch and every chunk whose owner differs from the
+    one before it — ``cq`` is ascending, so that is where a query begins.
+    Ascending positions into ``cq``."""
+    return np.union1d(
+        np.arange(0, cq.shape[0], cap), np.flatnonzero(cq[1:] != cq[:-1]) + 1
+    )
+
+
+def fold_heads(hq, hd, hi, a: int, k: int):
     """Per active query the k best of this iteration, from the launches'
-    per-chunk outputs (concatenated, ``cap`` chunks a launch): the first
-    chunk of a query within a launch holds that launch's answer for it; a
-    query whose chunks straddle launches has one such row a launch, merged
-    here. Returns (a, k) f64 distances and int64 ids (inf / -1 unfilled)."""
+    head rows: ``hq`` (ascending) owns row ``(hd, hi)``, a launch's answer
+    for that query; a query whose chunks straddle launches has one such
+    row a launch (at most one a launch boundary), merged here. Returns
+    (a, k) f64 distances and int64 ids (inf / -1 unfilled)."""
     fd = np.full((a, k), np.inf)
     fi = np.full((a, k), -1, dtype=np.int64)
-    n = cq.shape[0]
-    if not n:
+    if not hq.shape[0]:
         return fd, fi
-    at = np.arange(n)
-    head = np.flatnonzero((at % cap == 0) | np.r_[True, cq[1:] != cq[:-1]])
-    hq = cq[head]
-    hd = out_d[head].astype(np.float64)
-    hi = out_i[head].astype(np.int64)
+    hd = hd.astype(np.float64, copy=False)
+    hi = hi.astype(np.int64)
     hi[hi == _NO_ID] = -1
     first = np.r_[True, hq[1:] != hq[:-1]]
     fd[hq[first]], fi[hq[first]] = hd[first], hi[first]
-    rest = np.flatnonzero(~first)  # at most one a launch boundary
+    rest = np.flatnonzero(~first)
     if rest.size:
         live = hi[rest] >= 0
         fd, fi = merge_topk(
@@ -236,8 +265,10 @@ def ring_search(
 
     ``pair_distances(qi, ci) -> (P,) f64`` evaluates fresh pairs (it may
     return a `DegradedResult`); ``block_topk(active, cq, blk, steps, ring)
-    -> (out_d, out_i, cap, padded, launches)`` evaluates (query, block)
-    chunks on the device — given, it is the lane taken; degraded, it
+    -> (hq, hd, hi, padded, launches, rows)`` evaluates (query, block)
+    chunks on the device and hands back the launches' head rows, their
+    owners and how many rows it pulled for them (see :func:`fold_heads`)
+    — given, it is the lane taken; degraded, it
     returns a `DegradedResult` of (P, 3) rows ``(query, candidate,
     distance)`` the host oracle answered. ``guard(stage, fn)`` runs the
     pure stages (``knn.expand``, ``knn.scatter``: the frontend's failure
@@ -315,7 +346,8 @@ def ring_search(
                 got = block_topk(active, cq, blk, steps, ring)
             if isinstance(got, DegradedResult):
                 # the host oracle answered: (qi, ci, d) triples
-                out.degraded = out.degraded or got
+                if out.degraded is None:  # (an array: it has no truth value)
+                    out.degraded = got
                 rows = np.asarray(got)
                 qi, ci = rows[:, 0].astype(np.int64), rows[:, 1].astype(np.int64)
                 d = rows[:, 2]
@@ -325,13 +357,14 @@ def ring_search(
                         out.dist, out.cid, qi[keep], ci[keep], d[keep], k))
                 out.pairs += pairs
                 continue
-            out_d, out_i, cap, padded, launches = got
+            hq, hd, hi, padded, launches, rows = got
             out.pairs += pairs
             out.pairs_padded += padded
             out.launches += launches
+            out.rows_pulled += rows
 
             def scatter():
-                fd, fi = fold_heads(cq, out_d, out_i, cap, active.size, k)
+                fd, fi = fold_heads(hq, hd, hi, active.size, k)
                 if it == 1:
                     return fd, fi
                 return _merge_rows(out.dist[active], out.cid[active], fd, fi, k)
@@ -351,7 +384,8 @@ def ring_search(
                     _telemetry.timed("knn_stage", stage="distance", pairs=pairs):
                 d = pair_distances(qi, ci)
             if isinstance(d, DegradedResult):
-                out.degraded = out.degraded or d
+                if out.degraded is None:
+                    out.degraded = d
                 d = np.asarray(d)
             out.pairs += pairs
             keep = d <= thr

@@ -245,6 +245,7 @@ class KNNFrontend:
             "pairs": 0,
             "pairs_padded": 0,
             "launches": 0,
+            "rows_pulled": 0,
             "iterations": 0,
             "degraded": 0,
             "lane_ring": 0,
@@ -453,25 +454,31 @@ class KNNFrontend:
     def _block_topk(self, qs64, qsd, k, thr, default_s):
         """The engine's block evaluator (`engine.ring_search`): the
         ``knn.distance`` failure domain over (query, block) chunks, cut at
-        the block ladder's top rung and padded to a rung; every launch is
-        enqueued before the first answer is pulled. Past the retry budget
-        the iteration's pairs are made from the CSR and answered by the
-        f64 host oracle."""
+        the block ladder's top rung and padded to a rung. With each launch
+        go the slots where a query begins in it (`engine.launch_heads`),
+        padded to the rung their number takes; the heads program gathers
+        those rows on the device, and only they are pulled, after every
+        launch is enqueued. Past the retry budget the iteration's pairs
+        are made from the CSR and answered by the f64 host oracle."""
         import jax.numpy as jnp
 
         kx, pb = self.kx, self.kx.points
-        prog = _engine.block_topk_prog()
+        prog, heads_prog = _engine.block_topk_prog(), _engine.block_heads_prog()
         cap = BLOCK_LADDER.max_bucket
         thr = jnp.asarray(thr, dtype=self._dtype)
 
         def evaluate(active, cq, blk, steps, ring):
             def device():
-                outs, padded = [], 0
+                outs, padded, rows = [], 0, 0
                 qx, qy = qsd[active[cq], 0], qsd[active[cq], 1]
+                head = _engine.launch_heads(cq, cap)
                 for c0 in range(0, cq.shape[0], cap):
                     m = min(cap, cq.shape[0] - c0)
                     b = BLOCK_LADDER.bucket_for(m)
                     sl = slice(c0, c0 + m)
+                    h0, h1 = np.searchsorted(head, (c0, c0 + m))
+                    nh = int(h1 - h0)
+                    h = BLOCK_LADDER.bucket_for(nh)
                     args = (
                         pb.x, pb.y, pb.rid,
                         np.pad(qx[sl], (0, b - m)),
@@ -482,23 +489,40 @@ class KNNFrontend:
                                constant_values=-1),
                         thr, np.int32(steps),
                     )
+                    # (a pad head re-reads slot 0 and is cut off the pull)
+                    at = np.pad(
+                        (head[h0:h1] - c0).astype(np.int32), (0, h - nh)
+                    )
                     if self._note(f"blocks.k{k}", b):
                         # how to lower this rung again, for a device
                         # trace's stage table (nothing is lowered here)
                         _stages.register(
                             prog, _stages.shapes_of(args), {"k": k}, rows=b
                         )
-                    with _trace.span("knn.blocks", bucket=b, chunks=m):
-                        outs.append((m, prog(*args, k=k)))
+                    with _trace.span(
+                        "knn.blocks", bucket=b, chunks=m, heads=nh,
+                        head_bucket=h,
+                    ):
+                        folded = prog(*args, k=k)
+                        outs.append((nh, heads_prog(*folded, at)))
+                    if self._note(f"heads.k{k}.b{b}", h):
+                        _stages.register(
+                            heads_prog, _stages.shapes_of((*folded, at)),
+                            rows=h,
+                        )
                     padded += b * pb.width
-                with _trace.span("knn.pull", launches=len(outs)):
-                    out_d = np.concatenate(
-                        [np.asarray(o[0])[:m] for m, o in outs]
+                    rows += h
+                with _trace.span(
+                    "knn.pull", launches=len(outs), rows=rows,
+                    chunks=int(cq.shape[0]),
+                ):
+                    hd = np.concatenate(
+                        [np.asarray(o[0])[:n] for n, o in outs]
                     )
-                    out_i = np.concatenate(
-                        [np.asarray(o[1])[:m] for m, o in outs]
+                    hi = np.concatenate(
+                        [np.asarray(o[1])[:n] for n, o in outs]
                     )
-                return out_d, out_i, cap, padded, len(outs)
+                return cq[head], hd, hi, padded, len(outs), rows
 
             def oracle():
                 qi, ci = _engine.ring_pairs(kx, active, ring)
@@ -555,6 +579,7 @@ class KNNFrontend:
             self.stats["pairs"] += res.pairs
             self.stats["pairs_padded"] += res.pairs_padded
             self.stats["launches"] += res.launches
+            self.stats["rows_pulled"] += res.rows_pulled
         return res
 
     def _ring_lane(self, pts, k, default_s):
@@ -747,8 +772,9 @@ class KNNFrontend:
         compile and fires ``on_cold_compile``. An all-point index
         answers point queries from its blocks: given ``k`` (the block
         program keeps the k best on the device, so k is part of its
-        shape) every block rung is touched, and the pair rungs, which only
-        geometry queries reach, are not."""
+        shape) every block rung is touched with every head rung a launch
+        of it can take (the ladder's rungs up to its own), and the pair
+        rungs, which only geometry queries reach, are not."""
         c0 = backend_compiles()
         blocks = (
             k is not None and self.kx.points is not None and self.mesh is None
@@ -764,13 +790,17 @@ class KNNFrontend:
                     None, np.zeros((1, 2), self._dtype), int(k), np.inf, None
                 )
                 for b in BLOCK_LADDER.buckets:
-                    with _telemetry.timed(
-                        "knn_stage", stage="warmup", kind="blocks", bucket=b,
-                    ):
-                        evaluate(
-                            np.zeros(1, np.int64), np.zeros(b, np.int64),
-                            np.zeros(b, np.int64), 1, None,
-                        )
+                    for h in (r for r in BLOCK_LADDER.buckets if r <= b):
+                        with _telemetry.timed(
+                            "knn_stage", stage="warmup", kind="blocks",
+                            bucket=b, head_bucket=h,
+                        ):
+                            # b chunks of h queries: h heads, the rung itself
+                            evaluate(
+                                np.zeros(h, np.int64),
+                                np.minimum(np.arange(b), h - 1),
+                                np.zeros(b, np.int64), 1, None,
+                            )
             for b in () if blocks else self.pair_ladder.buckets:
                 with _telemetry.timed(
                     "knn_stage", stage="warmup", kind="pairs", bucket=b,
@@ -804,6 +834,7 @@ class KNNFrontend:
                 else None
             ),
             "knn_launches": self.stats["launches"],
+            "knn_rows_pulled": self.stats["rows_pulled"],
             "knn_iterations": self.stats["iterations"],
             "knn_degraded": self.stats["degraded"],
             "knn_lane_ring": self.stats["lane_ring"],

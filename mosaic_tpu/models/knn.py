@@ -265,6 +265,7 @@ class SpatialKNN:
             sp.set(
                 iterations=res.iterations, pairs=res.pairs,
                 pairs_padded=res.pairs_padded, launches=res.launches,
+                rows_pulled=res.rows_pulled,
                 unrested_landmarks=res.unrested,
             )
 

@@ -3,7 +3,9 @@ under `SpatialKNN.transform` and `KNNFrontend.dispatch`, held against the
 benchmark's plain brute force on clustered points (a dense head, a sparse
 tail, landmarks with fewer than k candidates in reach), against a
 per-query loop written the way the deleted one was, and piece by piece
-(merge, chunk folding across launches, ring cells, the rest criterion)."""
+(merge, chunk folding across launches, ring cells, the rest criterion).
+Since PR 41 the block lane pulls one row a landmark a launch (the heads):
+held bit for bit against the per-chunk pull it replaced, kept here."""
 
 import os
 import sys
@@ -21,6 +23,7 @@ from mosaic_tpu.knn import (
 from mosaic_tpu.knn import frontend as knn_frontend
 from mosaic_tpu.knn.index import expand_ranges, point_coords, points_column
 from mosaic_tpu.models import SpatialKNN
+from mosaic_tpu.runtime import telemetry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -60,6 +63,11 @@ def _table(res, n, k):
     ids[res.landmark_id, res.rank - 1] = res.candidate_id
     dist[res.landmark_id, res.rank - 1] = res.distance
     return ids, dist
+
+
+def _spans(events, name):
+    return [e for e in events
+            if e.get("event") == "span" and e["name"] == name]
 
 
 def _model(**kw):
@@ -310,17 +318,204 @@ def test_merge_topk_ranks_by_distance_then_id():
     assert np.isinf(nd[1, 1]) and dist[0, 1] == np.inf  # input untouched
 
 
-def test_chunks_of_one_query_fold_across_launches(clustered, monkeypatch):
-    """A top rung of 4 chunks: the cluster landmarks' chunks straddle
-    launches, and the answer does not change."""
+@pytest.mark.parametrize("ladder", [
+    BucketLadder(2, 4), BucketLadder(4, 64, growth=4),
+], ids=["rungs-2-4", "rungs-4-16-64"])
+def test_chunks_of_one_query_fold_across_launches(clustered, monkeypatch, ladder):
+    """A top rung of 4 (or 64) chunks: the cluster landmarks' chunks
+    straddle launches, launches begin mid-landmark — and the answer is
+    the default ladder's and the brute force's."""
     land, cand = clustered
     kx = build_knn_index(cand, GRID, RES)
     want = _table(_model().transform(land, kx), len(land), K)
-    monkeypatch.setattr(knn_frontend, "BLOCK_LADDER", BucketLadder(2, 4))
+    monkeypatch.setattr(knn_frontend, "BLOCK_LADDER", ladder)
     res = _model().transform(land, kx)
     got = _table(res, len(land), K)
     assert np.array_equal(got[0], want[0]) and np.array_equal(got[1], want[1])
-    assert res.metrics["launches"] > 40
+    assert np.array_equal(got[0], knn_bruteforce.answers(land, cand, K)[0])
+    assert res.metrics["launches"] > 30
+
+
+def test_an_iteration_with_no_chunk_launches_nothing():
+    """Six candidates seven cells from the landmark: rings 1 to 6 meet no
+    block, so those iterations have an expand span and no distance span,
+    and the answer is the brute force's."""
+    w = 3.90625e-3
+    land = np.array([[-74.0 + 0.5 * w, 40.7 + 0.5 * w]])
+    cand = land + np.array([[7 * w, 0.1 * w * j] for j in range(6)])
+    with telemetry.capture() as events:
+        res = _model().transform(land, cand)
+    ids, dist = _table(res, 1, K)
+    want_ids, want_d = knn_bruteforce.answers(land, cand, K)
+    assert np.array_equal(ids, want_ids)
+    np.testing.assert_allclose(dist, want_d, rtol=0, atol=1e-12)
+    assert len(_spans(events, "knn.expand")) >= 7
+    assert 7 > len(_spans(events, "knn.distance")) >= 1
+    assert res.metrics["unrested_landmarks"] == 0
+
+
+# ------------------------------------------- heads against the per-chunk pull
+
+
+def _fold_chunk_rows(cq, out_d, out_i, cap, a, k):
+    """`engine.fold_heads` as it was before PR 41: every chunk's row is
+    in hand, the heads are found here by a scan of ``cq``."""
+    fd = np.full((a, k), np.inf)
+    fi = np.full((a, k), -1, dtype=np.int64)
+    at = np.arange(cq.shape[0])
+    head = np.flatnonzero((at % cap == 0) | np.r_[True, cq[1:] != cq[:-1]])
+    hq = cq[head]
+    hd, hi = out_d[head].astype(np.float64), out_i[head].astype(np.int64)
+    hi[hi == engine._NO_ID] = -1
+    first = np.r_[True, hq[1:] != hq[:-1]]
+    fd[hq[first]], fi[hq[first]] = hd[first], hi[first]
+    rest = np.flatnonzero(~first)
+    if rest.size:
+        live = hi[rest] >= 0
+        fd, fi = engine.merge_topk(
+            fd, fi, np.repeat(hq[rest], k)[live.ravel()],
+            hi[rest][live], hd[rest][live], k,
+        )
+    return fd, fi
+
+
+def _per_chunk_path(kx, qsd, active, cq, blk, steps, thr, k, ladder):
+    """The block lane before PR 41: the block program alone, a launch a
+    ``cap`` chunks, every chunk's (b, k) row pulled."""
+    pb, cap = kx.points, ladder.max_bucket
+    prog = engine.block_topk_prog()
+    qx, qy = qsd[active[cq], 0], qsd[active[cq], 1]
+    ds, ids = [], []
+    for c0 in range(0, cq.shape[0], cap):
+        m = min(cap, cq.shape[0] - c0)
+        b, sl = ladder.bucket_for(m), slice(c0, c0 + m)
+        d, i = prog(
+            pb.x, pb.y, pb.rid, np.pad(qx[sl], (0, b - m)),
+            np.pad(qy[sl], (0, b - m)),
+            np.pad(blk[sl].astype(np.int32), (0, b - m),
+                   constant_values=pb.n_blocks),
+            np.pad(cq[sl].astype(np.int32), (0, b - m), constant_values=-1),
+            np.asarray(thr, qsd.dtype), np.int32(steps), k=k,
+        )
+        ds.append(np.asarray(d)[:m])
+        ids.append(np.asarray(i)[:m])
+    return _fold_chunk_rows(
+        cq, np.concatenate(ds), np.concatenate(ids), cap, active.size, k)
+
+
+#: chunks a landmark, on a ladder of rungs 4 / 16 / 64 (launches of 64)
+HEAD_CASES = {
+    # q1 straddles the first boundary: launch 2 begins mid-landmark
+    "straddle": ([30, 50, 10], np.inf, [(64, 4), (64, 4)]),
+    # one landmark fills two launches and begins a third: one head each
+    "one-head": ([131], np.inf, [(64, 4), (64, 4), (4, 4)]),
+    # 16 heads sit exactly on a rung, 17 take the next
+    "heads-on-a-rung": ([1] * 16, np.inf, [(16, 16)]),
+    "heads-one-over": ([1] * 17, np.inf, [(64, 64)]),
+    # every slot of a full launch is a head; the next holds the rest
+    "all-heads": ([1] * 70, np.inf, [(64, 64), (16, 16)]),
+    "threshold": ([30, 50, 10, 1, 1], 0.01, [(64, 4), (64, 4)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HEAD_CASES))
+def test_head_rows_equal_the_per_chunk_pull_bit_for_bit(
+    case, clustered, monkeypatch
+):
+    """Launch layouts built by hand on the fixture's blocks: what the
+    heads program hands back, folded, is what the per-chunk pull gave —
+    the same bits — and the plain sort's answer over the same blocks."""
+    nchunk, thr, rungs = HEAD_CASES[case]
+    land, cand = clustered
+    ladder = BucketLadder(4, 64, growth=4)
+    monkeypatch.setattr(knn_frontend, "BLOCK_LADDER", ladder)
+    kx = build_knn_index(cand, GRID, RES)
+    pb, a = kx.points, len(nchunk)
+    rng = np.random.default_rng(len(case))
+    active = np.sort(rng.choice(len(land), a, replace=False))
+    cq = np.repeat(np.arange(a), nchunk)
+    # a landmark meets a block once, as ring cells are disjoint
+    blk = np.concatenate([rng.choice(pb.n_blocks, n, replace=False)
+                          for n in nchunk])
+    steps = int(max(max(nchunk) - 1, 0)).bit_length()
+    fe = KNNFrontend(kx, row_ladder=BucketLadder(8, 64))
+    qs64 = land - kx.shift
+    with telemetry.capture() as events:
+        hq, hd, hi, padded, launches, rows = fe._block_topk(
+            qs64, qs64, K, thr, None)(active, cq, blk, steps, None)
+    met = [(e["bucket"], e["head_bucket"])
+           for e in _spans(events, "knn.blocks")]
+    assert met == rungs and launches == len(rungs)
+    assert rows == sum(h for _b, h in rungs) and hd.shape == (hq.size, K)
+    got = engine.fold_heads(hq, hd, hi, a, K)
+    old = _per_chunk_path(kx, qs64, active, cq, blk, steps, thr, K, ladder)
+    assert np.array_equal(got[0], old[0]) and np.array_equal(got[1], old[1])
+    rid = np.asarray(pb.rid)
+    for q in range(a):
+        mine = np.sort(rid[blk[cq == q]].ravel())
+        mine = mine[mine >= 0]
+        ids, dist = knn_bruteforce.answers(land[active[q]][None], cand[mine], K)
+        keep = (dist[0] <= thr) & (ids[0] >= 0)
+        assert np.array_equal(got[1][q][keep], mine[ids[0][keep]])
+        assert (got[1][q][~keep] == -1).all()
+        np.testing.assert_allclose(got[0][q][keep], dist[0][keep], atol=1e-12)
+
+
+def test_rows_pulled_are_the_head_rungs_not_the_chunks(clustered, monkeypatch):
+    land, cand = clustered
+    monkeypatch.setattr(knn_frontend, "BLOCK_LADDER", BucketLadder(4, 64, growth=4))
+    kx = build_knn_index(cand, GRID, RES)
+    m = _model()
+    with telemetry.capture() as events:
+        m.transform(land, kx)
+    blocks, pulls = _spans(events, "knn.blocks"), _spans(events, "knn.pull")
+    (call,) = _spans(events, "knn.transform")
+    rows = sum(e["head_bucket"] for e in blocks)
+    assert call["rows_pulled"] == rows == sum(e["rows"] for e in pulls)
+    assert m._frontend[1].metrics()["knn_rows_pulled"] == rows
+    chunks = sum(e["chunks"] for e in blocks)
+    assert chunks == sum(e["chunks"] for e in pulls)
+    assert sum(e["heads"] for e in blocks) <= rows < chunks
+    assert all(e["heads"] <= e["head_bucket"] <= e["bucket"] for e in blocks)
+
+
+def test_warmed_head_rungs_leave_no_cold_compile(clustered, monkeypatch):
+    """`warmup(k)` touches every (chunk rung, head rung) pair a launch
+    can take; a transform that meets several of them adds no signature."""
+    land, cand = clustered
+    ladder = BucketLadder(4, 64, growth=4)
+    monkeypatch.setattr(knn_frontend, "BLOCK_LADDER", ladder)
+    kx = build_knn_index(cand, GRID, RES)
+    m = _model()
+    m.warmup(kx)
+    fe = m._frontend[1]
+    warmed = fe.signature_count()
+    with telemetry.capture() as events:
+        res = m.transform(land, kx)
+    met = {(e["bucket"], e["head_bucket"])
+           for e in _spans(events, "knn.blocks")}
+    assert len(met) >= 4 and len({b for b, _h in met}) == 3
+    assert fe.cold_compiles == 0 and fe.signature_count() == warmed
+    assert not [e for e in events if e.get("event") == "knn_compile"]
+    assert np.array_equal(
+        _table(res, len(land), K)[0], knn_bruteforce.answers(land, cand, K)[0])
+
+
+def test_degraded_block_launches_are_answered_by_the_host_oracle(clustered):
+    """Past the retry budget the iteration's pairs come from the CSR and
+    the f64 host oracle's (query, candidate, distance) triples are merged
+    as before: the same neighbours, flagged."""
+    from mosaic_tpu.runtime import faults
+
+    land, cand = clustered
+    kx = build_knn_index(cand, GRID, RES)
+    with faults.transient_errors(999, sites=("knn.distance",)):
+        res = _model().transform(land[:50], kx)
+    assert res.metrics["degraded"] is True and res.metrics["launches"] == 0
+    ids, dist = _table(res, 50, K)
+    want_ids, want_d = knn_bruteforce.answers(land[:50], cand, K)
+    assert np.array_equal(ids, want_ids)
+    np.testing.assert_allclose(dist, want_d, rtol=0, atol=1e-12)
 
 
 def test_expand_ranges_and_block_layout(clustered):
@@ -462,7 +657,13 @@ def test_device_programs_register_their_stage_tables(clustered):
     _model().transform(land, build_knn_index(cand, GRID, RES))
     rungs = dict(stages.registered())
     assert rungs.get("jit_knn_blocks") in knn_frontend.BLOCK_LADDER.buckets
+    assert rungs.get("jit_knn_heads") in knn_frontend.BLOCK_LADDER.buckets
     assert stages.lowerings() == n0
     table = stages.tables({"jit_knn_blocks"}, {rungs["jit_knn_blocks"]})
     assert {"knn.gather", "knn.distance", "knn.topk"} <= set(
         table["jit_knn_blocks"].values())
+    # the heads program is a module of its own: its table holds its scope
+    # alone, and the block program's holds none of it
+    assert "knn.heads" not in table["jit_knn_blocks"].values()
+    heads = stages.tables({"jit_knn_heads"}, {rungs["jit_knn_heads"]})
+    assert set(heads["jit_knn_heads"].values()) == {"knn.heads"}
