@@ -116,6 +116,18 @@ class IndexSystem(abc.ABC):
         else None: the search then asks `ring_cells`."""
         return None
 
+    def lattice_coords(self, xy: np.ndarray, resolution: int, face=None):
+        """Where `lattice_keys` is offered: ``(face, xa, xb, margin)`` —
+        each point's CONTINUOUS place on its face's lattice (the cell
+        whose key packs ``(face, a, b)`` is the hexagon around ``(a, b)``)
+        and the rings around it that stay on that face; else None."""
+        return None
+
+    def lattice_pack(self, face, a, b) -> np.ndarray:
+        """The `lattice_keys` key of lattice position ``(a, b)`` of
+        ``face`` (systems that offer `lattice_coords`)."""
+        raise NotImplementedError
+
     @abc.abstractmethod
     def grid_distance(self, cells_a: jax.Array, cells_b: jax.Array) -> jax.Array:
         """(N,),(N,) -> (N,) int64 grid distance, consistent with k_loop:

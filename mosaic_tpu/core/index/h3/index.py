@@ -504,12 +504,7 @@ class H3IndexSystem(IndexSystem):
             (np.abs(x - (a + b / 2.0)) < 1e-3)
             & (np.abs(y - b * _SIN60) < 1e-3) & ~pent
         )
-        half = 1 << (_AXIS_BITS - 1)
-        keys = (
-            (face.astype(np.int64) << (2 * _AXIS_BITS))
-            + ((a.astype(np.int64) + half) << _AXIS_BITS)
-            + (b.astype(np.int64) + half)
-        )
+        keys = self.lattice_pack(face, a, b)
         corners = core._corners_by_res(np)[res]  # (N, 3, 2)
         p = np.stack([x, y], axis=-1)
         dist = np.full(cells.shape, np.inf)
@@ -526,6 +521,42 @@ class H3IndexSystem(IndexSystem):
             )
         margin = np.floor(dist).astype(np.int64) - 1
         return np.where(ok, keys, np.int64(-1)), np.where(ok, margin, -1)
+
+    def lattice_coords(self, xy, resolution: int, face=None):
+        """(N, 2) lon/lat -> ``(face, xa, xb, margin)``: the nearest face
+        (or the ``face`` given: a point beyond its triangle reads a
+        negative margin) and the point's continuous axial coordinates on that face's
+        lattice at ``resolution`` — hex2d ``x = xa + xb / 2``, ``y = xb
+        sin 60``, so the cell `lattice_keys` packs as ``(face, a, b)`` is
+        the hexagon of the points whose cube rounding is ``(a, b)`` — and
+        ``margin``, the point's distance to the face triangle's nearest
+        edge in cells, less two, rounded down. Host f64 numpy."""
+        xy = np.asarray(xy, dtype=np.float64).reshape(-1, 2)
+        res = int(resolution)
+        face, x, y = hm.geo_to_hex2d(
+            np.radians(xy[:, 1]), np.radians(xy[:, 0]), res, face=face
+        )
+        xb = y / _SIN60
+        corners = core._corners_by_res(np)[res]  # (3, 2)
+        dist = np.full(x.shape, np.inf)
+        for i in range(3):
+            c0, c1, c2 = corners[i], corners[(i + 1) % 3], corners[(i + 2) % 3]
+            e, q = c1 - c0, c2 - c0
+            inward = np.sign(e[0] * q[1] - e[1] * q[0])
+            side = e[0] * (y - c0[1]) - e[1] * (x - c0[0])
+            dist = np.minimum(dist, inward * side / np.hypot(e[0], e[1]))
+        return (
+            face.astype(np.int64), x - xb / 2.0, xb,
+            np.floor(dist).astype(np.int64) - 2,
+        )
+
+    def lattice_pack(self, face, a, b) -> np.ndarray:
+        half = 1 << (_AXIS_BITS - 1)
+        return (
+            (np.asarray(face, np.int64) << (2 * _AXIS_BITS))
+            + ((np.asarray(a, np.int64) + half) << _AXIS_BITS)
+            + (np.asarray(b, np.int64) + half)
+        )
 
     def lattice_ring(self, k: int) -> np.ndarray:
         """(M,) key offsets of the lattice positions a ring search visits

@@ -29,6 +29,11 @@ a query or once a pair. Two ways to evaluate an iteration's pairs:
   (its *heads*); a second small program gathers those rows on the device
   (:func:`block_heads_prog`), so one row a query a launch comes back and
   the per-chunk answers never leave the chip (:func:`fold_heads`).
+  Polygon queries run the same lane from several seed cells each
+  (`index.polygon_cover`): their seeds' rings overlap, so an iteration's
+  (query, cell) keys are deduplicated and held against the cells the
+  query has met (:func:`block_chunks_multi`), and a chunk's candidates
+  are evaluated against the query's edges (:func:`poly_block_topk_prog`).
 """
 
 from __future__ import annotations
@@ -41,7 +46,7 @@ from ..dispatch import bounded_cache
 from ..obs import trace as _trace
 from ..runtime import telemetry as _telemetry
 from ..runtime.errors import DegradedResult
-from .index import KNNIndex, expand_ranges
+from .index import KNNIndex, edge_terms, expand_ranges
 
 _NO_ID = np.iinfo(np.int32).max
 
@@ -59,6 +64,8 @@ class RingResult:
     launches: int = 0  # device launches of the distance programs
     rows_pulled: int = 0  # block lane: answer rows pulled, padded to a rung
     degraded: "DegradedResult | None" = None
+    #: what the evaluator counted besides (polygon queries: `edge_pairs`, ...)
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 # ------------------------------------------------------------ host merge
@@ -147,26 +154,81 @@ def block_topk_prog():
             d = jnp.where(live, d, jnp.inf)
             gid = jnp.where(live, gid, _NO_ID)
         with jax.named_scope("knn.topk"):
-            d, gid = _topk_rows(d, gid, k)
-
-            def fold(i, state):
-                d, gid = state
-                s = jnp.left_shift(jnp.int32(1), i)
-                same = (jnp.roll(qid, -s) == qid) & (
-                    jnp.arange(qid.shape[0]) + s < qid.shape[0]
-                )
-                nd = jnp.where(same[:, None], jnp.roll(d, -s, axis=0), jnp.inf)
-                ni = jnp.where(
-                    same[:, None], jnp.roll(gid, -s, axis=0), _NO_ID
-                )
-                return _topk_rows(
-                    jnp.concatenate([d, nd], axis=1),
-                    jnp.concatenate([gid, ni], axis=1), k,
-                )
-
-            return jax.lax.fori_loop(0, steps, fold, (d, gid))
+            return _fold_chunks(d, gid, qid, steps, k)
 
     return jax.jit(knn_blocks, static_argnames=("k",))
+
+
+def _fold_chunks(d, gid, qid, steps, k):
+    """Each chunk's k best of its ``(b, width)`` row, then the chunks of
+    one query folded by doubling (see :func:`block_topk_prog`)."""
+    import jax
+    import jax.numpy as jnp
+
+    d, gid = _topk_rows(d, gid, k)
+
+    def fold(i, state):
+        d, gid = state
+        s = jnp.left_shift(jnp.int32(1), i)
+        same = (jnp.roll(qid, -s) == qid) & (
+            jnp.arange(qid.shape[0]) + s < qid.shape[0]
+        )
+        nd = jnp.where(same[:, None], jnp.roll(d, -s, axis=0), jnp.inf)
+        ni = jnp.where(
+            same[:, None], jnp.roll(gid, -s, axis=0), _NO_ID
+        )
+        return _topk_rows(
+            jnp.concatenate([d, nd], axis=1),
+            jnp.concatenate([gid, ni], axis=1), k,
+        )
+
+    return jax.lax.fori_loop(0, steps, fold, (d, gid))
+
+
+@bounded_cache("knn_poly_block_topk", 1)
+def poly_block_topk_prog():
+    """The block program of polygon queries (jax's trace cache keys the
+    chunk rung, the edge pad and k). Per (query, block) chunk: gather the
+    block's row of candidates and the query's row of ``tab`` (see
+    `index.LandmarkRings`: ``(rows, 5, vpad)``, the rows fixed by the pad,
+    so the landmark column is no part of the shape), then edge after edge
+    keep each candidate's least squared distance to a segment and the
+    parity of the edges a ray from it crosses: the distance is 0 where the
+    parity is odd (inside by the even-odd rule, so not in a courtyard),
+    else the root of the least. From there on it is the point program:
+    k best a chunk, fold by doubling, ``(b, k)`` outputs that stay on the
+    device."""
+    import jax
+    import jax.numpy as jnp
+
+    def knn_poly_blocks(bx, by, rid, tab, blk, lrow, qid, thr, steps, k):
+        with jax.named_scope("knn.gather"):
+            gx, gy, gid = bx[blk], by[blk], rid[blk]
+            # edge-major, so the loop below indexes the leading axis
+            et = jnp.transpose(tab[lrow], (2, 1, 0))[..., None]
+        with jax.named_scope("knn.edges"):
+
+            def edge(j, state):
+                d2, odd = state
+                e = et[j]  # (5, b, 1)
+                nd2, cross = edge_terms(
+                    gx, gy, e[0], e[1], e[2], e[3], e[4], jnp
+                )
+                return jnp.minimum(d2, nd2), odd ^ cross
+
+            d2, odd = jax.lax.fori_loop(
+                0, et.shape[0], edge,
+                (jnp.full(gx.shape, jnp.inf, gx.dtype),
+                 jnp.zeros(gx.shape, bool)),
+            )
+            d = jnp.where(odd, jnp.zeros((), gx.dtype), jnp.sqrt(d2))
+            live = (gid >= 0) & (d <= thr)
+            d = jnp.where(live, d, jnp.inf)
+            gid = jnp.where(live, gid, _NO_ID)
+        with jax.named_scope("knn.topk"):
+            return _fold_chunks(d, gid, qid, steps, k)
+
+    return jax.jit(knn_poly_blocks, static_argnames=("k",))
 
 
 @bounded_cache("knn_block_heads", 1)
@@ -194,13 +256,42 @@ def block_chunks(pb, ring: np.ndarray):
     flat = ring.ravel()
     pos = np.minimum(np.searchsorted(pb.ucells, flat), pb.ucells.size - 1)
     hit = np.flatnonzero(pb.ucells[pos] == flat)
-    own, pos = hit // m, pos[hit]
+    return _cell_chunks(pb, hit // m, pos[hit], a)
+
+
+def _cell_chunks(pb, own, pos, a: int):
+    """``(cq, blk, fresh)`` of the occupied cells ``pos`` (indices into
+    ``pb.ucells``) that the queries ``own`` (ascending) meet."""
     nblk = pb.blk_start[pos + 1] - pb.blk_start[pos]
     fresh = np.bincount(own, weights=pb.count[pos], minlength=a)
     return (
         np.repeat(own, nblk), expand_ranges(pb.blk_start[pos], nblk),
         fresh.astype(np.int64),
     )
+
+
+def block_chunks_multi(pb, active, owner, ring, met, many):
+    """:func:`block_chunks` for queries of several seeds: ``ring`` is (S,
+    M) ring cells of seed ``s`` whose query is ``active[owner[s]]``. The
+    seeds' rings overlap, so the occupied (query, cell) keys are made
+    unique, and those in ``met`` — sorted keys of the cells the queries
+    ``many`` marks (those of more than one seed) met in earlier
+    iterations — are dropped: a cell is counted, and its blocks listed,
+    once a query. Returns ``(cq, blk, fresh, met)`` with ``met`` grown by
+    this iteration's keys."""
+    m = ring.shape[1]
+    u = pb.ucells.size
+    flat = ring.ravel()
+    pos = np.minimum(np.searchsorted(pb.ucells, flat), u - 1)
+    hit = np.flatnonzero(pb.ucells[pos] == flat)
+    key = np.unique(active[owner[hit // m]] * u + pos[hit])
+    if met.size and key.size:
+        at = np.minimum(np.searchsorted(met, key), met.size - 1)
+        key = key[met[at] != key]
+    query, pos = key // u, key % u
+    met = np.union1d(met, key[many[query]])
+    own = np.searchsorted(active, query)
+    return (*_cell_chunks(pb, own, pos, active.size), met)
 
 
 def launch_heads(cq: np.ndarray, cap: int) -> np.ndarray:
@@ -258,7 +349,7 @@ def ring_search(
     kx: KNNIndex, seed_ptr: np.ndarray, seed_cells: np.ndarray, k: int, *,
     exact: bool = True, max_iterations: int, early_stop: "int | None" = None,
     threshold: "float | None" = None, pair_distances=None, block_topk=None,
-    guard=None, on_iteration=None,
+    guard=None, on_iteration=None, seed_keys=None,
 ) -> RingResult:
     """Ring-expansion KNN for ``n`` queries given by their cover cells
     (CSR ``seed_ptr`` / ``seed_cells``; a point has one).
@@ -277,7 +368,9 @@ def ring_search(
     the loop (the reference's `earlyStoppingCheck`); an exact search ends
     by the rest criterion or ``max_iterations`` alone.
     ``on_iteration(it, qi, ci, d)`` sees each iteration's evaluated pairs
-    (the pairs lane only: the checkpoint log)."""
+    (the pairs lane only: the checkpoint log). ``seed_keys`` is ``(keys,
+    margin)`` of the seeds where the caller made them on the lattice
+    (`index.polygon_cover`), else they are looked up (`KNNIndex.probe_keys`)."""
     n = seed_ptr.shape[0] - 1
     guard = guard or (lambda site, fn: fn())
     out = RingResult(
@@ -288,7 +381,10 @@ def ring_search(
     seen_keys = np.zeros(0, dtype=np.int64)  # pairs lane: q * N + c, sorted
     seen_count = np.zeros(n, dtype=np.int64)
     nseed = np.diff(seed_ptr)
-    seed_keys, seed_margin = kx.probe_keys(seed_cells)
+    seed_keys, seed_margin = seed_keys or kx.probe_keys(seed_cells)
+    # block lane, queries of several seeds: the cells they have met
+    many = nseed > 1
+    met = np.zeros(0, dtype=np.int64)
     stable, prev = 0, (n, 0)
 
     def owed(it):
@@ -317,6 +413,10 @@ def ring_search(
                 seed_cells[at], seed_keys[at],
                 None if seed_margin is None else seed_margin[at], it,
             )
+            if block_topk is not None and many.any():
+                return ring, block_chunks_multi(
+                    kx.points, active, owner, ring, met, many
+                )
             if block_topk is not None:
                 return ring, block_chunks(kx.points, ring)
             qi, ci = ring_pairs(kx, active[owner], ring)
@@ -332,7 +432,9 @@ def ring_search(
             ring, found = guard("knn.expand", expand)
 
         if block_topk is not None:
-            cq, blk, fresh = found
+            cq, blk, fresh, *grown = found
+            if grown:
+                met = grown[0]
             seen_count[active] += fresh
             pairs = int(fresh.sum())
             if not cq.size:

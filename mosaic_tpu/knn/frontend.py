@@ -76,7 +76,7 @@ from ..runtime import telemetry as _telemetry
 from ..runtime.errors import DegradedResult
 from ..utils import get_logger
 from . import engine as _engine
-from .index import KNNIndex
+from .index import KNNIndex, host_polygon_distances, table_rows
 from .oracle import host_pair_distances
 
 logger = get_logger(__name__)
@@ -92,6 +92,11 @@ DEFAULT_ROW_LADDER = BucketLadder(min_bucket=64, max_bucket=4096)
 #: rung; a transform's chunks are cut at the top one, which the chip
 #: chose (PERF.md section 6, PR 33)
 BLOCK_LADDER = BucketLadder(min_bucket=1024, max_bucket=1 << 16, growth=4)
+#: edges a polygon landmark's row of the block program's table pads to: a
+#: launch holds landmarks of one rung, so the few long outlines of a table
+#: (a building layer's 2% of 24-80 vertices) do not pad the rest; past the
+#: top rung the host answers
+VERTEX_LADDER = BucketLadder(min_bucket=8, max_bucket=128, growth=4)
 
 
 @dataclasses.dataclass
@@ -238,6 +243,7 @@ class KNNFrontend:
                 devices=self.mesh.size,
             )
             self._programs = None
+        self._block_fill: "np.ndarray | None" = None
         self._aot: dict = {}  # (kind, bucket) -> compiled | None
         self.aot_stats = {"loaded": 0, "exported": 0, "fallback": 0}
         self.stats = {
@@ -451,6 +457,67 @@ class KNNFrontend:
 
     # ------------------------------------------------------- ring lane
 
+    def _launch_chunks(self, prog, kind, k, lead, cols, steps, thr, **fields):
+        """Enqueue one chunk list: cut at the block ladder's top rung, each
+        cut padded to a rung and handed to ``prog`` (after the blocks and
+        ``lead``: the per-chunk columns ``cols``, ``(column, pad value)``
+        with the chunks' owner last) and, behind it, to the heads program
+        with the slots where a query begins in the cut (`engine.launch_heads`),
+        padded to the rung their number takes. Nothing is pulled. Returns
+        ``(outs, head, padded slots, head rows)``, an entry of ``outs``
+        being ``(heads, the head rows' (d, id))``."""
+        pb = self.kx.points
+        heads_prog = _engine.block_heads_prog()
+        cap = BLOCK_LADDER.max_bucket
+        cq = cols[-1][0]
+        head = _engine.launch_heads(cq, cap)
+        outs, padded, rows = [], 0, 0
+        for c0 in range(0, cq.shape[0], cap):
+            m = min(cap, cq.shape[0] - c0)
+            b = BLOCK_LADDER.bucket_for(m)
+            h0, h1 = np.searchsorted(head, (c0, c0 + m))
+            nh = int(h1 - h0)
+            h = BLOCK_LADDER.bucket_for(nh)
+            args = (
+                pb.x, pb.y, pb.rid, *lead,
+                *(
+                    np.pad(c[c0 : c0 + m], (0, b - m), constant_values=v)
+                    for c, v in cols
+                ),
+                thr, np.int32(steps),
+            )
+            # (a pad head re-reads slot 0 and is cut off the pull)
+            at = np.pad((head[h0:h1] - c0).astype(np.int32), (0, h - nh))
+            if self._note(f"{kind}.k{k}", b):
+                # how to lower this rung again, for a device
+                # trace's stage table (nothing is lowered here)
+                _stages.register(
+                    prog, _stages.shapes_of(args), {"k": k}, rows=b
+                )
+            with _trace.span(
+                "knn.blocks", bucket=b, chunks=m, heads=nh, head_bucket=h,
+                **fields,
+            ):
+                d, gid = prog(*args, k=k)
+                outs.append((nh, heads_prog(d, gid, at)))
+            if self._note(f"heads.k{k}.b{b}", h):
+                _stages.register(
+                    heads_prog, _stages.shapes_of((d, gid, at)), rows=h,
+                )
+            padded += b * pb.width
+            rows += h
+        return outs, head, padded, rows
+
+    @staticmethod
+    def _pull_heads(outs, rows: int, chunks: int):
+        """The blocking pull of every enqueued launch's head rows."""
+        with _trace.span(
+            "knn.pull", launches=len(outs), rows=rows, chunks=chunks,
+        ):
+            hd = np.concatenate([np.asarray(o[0])[:n] for n, o in outs])
+            hi = np.concatenate([np.asarray(o[1])[:n] for n, o in outs])
+        return hd, hi
+
     def _block_topk(self, qs64, qsd, k, thr, default_s):
         """The engine's block evaluator (`engine.ring_search`): the
         ``knn.distance`` failure domain over (query, block) chunks, cut at
@@ -463,65 +530,19 @@ class KNNFrontend:
         import jax.numpy as jnp
 
         kx, pb = self.kx, self.kx.points
-        prog, heads_prog = _engine.block_topk_prog(), _engine.block_heads_prog()
-        cap = BLOCK_LADDER.max_bucket
+        prog = _engine.block_topk_prog()
         thr = jnp.asarray(thr, dtype=self._dtype)
 
         def evaluate(active, cq, blk, steps, ring):
             def device():
-                outs, padded, rows = [], 0, 0
                 qx, qy = qsd[active[cq], 0], qsd[active[cq], 1]
-                head = _engine.launch_heads(cq, cap)
-                for c0 in range(0, cq.shape[0], cap):
-                    m = min(cap, cq.shape[0] - c0)
-                    b = BLOCK_LADDER.bucket_for(m)
-                    sl = slice(c0, c0 + m)
-                    h0, h1 = np.searchsorted(head, (c0, c0 + m))
-                    nh = int(h1 - h0)
-                    h = BLOCK_LADDER.bucket_for(nh)
-                    args = (
-                        pb.x, pb.y, pb.rid,
-                        np.pad(qx[sl], (0, b - m)),
-                        np.pad(qy[sl], (0, b - m)),
-                        np.pad(blk[sl].astype(np.int32), (0, b - m),
-                               constant_values=pb.n_blocks),
-                        np.pad(cq[sl].astype(np.int32), (0, b - m),
-                               constant_values=-1),
-                        thr, np.int32(steps),
-                    )
-                    # (a pad head re-reads slot 0 and is cut off the pull)
-                    at = np.pad(
-                        (head[h0:h1] - c0).astype(np.int32), (0, h - nh)
-                    )
-                    if self._note(f"blocks.k{k}", b):
-                        # how to lower this rung again, for a device
-                        # trace's stage table (nothing is lowered here)
-                        _stages.register(
-                            prog, _stages.shapes_of(args), {"k": k}, rows=b
-                        )
-                    with _trace.span(
-                        "knn.blocks", bucket=b, chunks=m, heads=nh,
-                        head_bucket=h,
-                    ):
-                        folded = prog(*args, k=k)
-                        outs.append((nh, heads_prog(*folded, at)))
-                    if self._note(f"heads.k{k}.b{b}", h):
-                        _stages.register(
-                            heads_prog, _stages.shapes_of((*folded, at)),
-                            rows=h,
-                        )
-                    padded += b * pb.width
-                    rows += h
-                with _trace.span(
-                    "knn.pull", launches=len(outs), rows=rows,
-                    chunks=int(cq.shape[0]),
-                ):
-                    hd = np.concatenate(
-                        [np.asarray(o[0])[:n] for n, o in outs]
-                    )
-                    hi = np.concatenate(
-                        [np.asarray(o[1])[:n] for n, o in outs]
-                    )
+                outs, head, padded, rows = self._launch_chunks(
+                    prog, "blocks", k, (),
+                    ((qx, 0), (qy, 0), (blk.astype(np.int32), pb.n_blocks),
+                     (cq.astype(np.int32), -1)),
+                    steps, thr,
+                )
+                hd, hi = self._pull_heads(outs, rows, int(cq.shape[0]))
                 return cq[head], hd, hi, padded, len(outs), rows
 
             def oracle():
@@ -536,11 +557,100 @@ class KNNFrontend:
 
         return evaluate
 
+    def _poly_block_topk(self, rings, k, thr, default_s, tally):
+        """:meth:`_block_topk` for polygon queries (`index.LandmarkRings`):
+        an iteration's chunks are split by their query's table (one edge
+        rung, a fixed number of rows), a launch holding one table, and
+        pulled together. Chunks of a query past the top rung are answered
+        here on the host, in f64; past the retry budget all of them are
+        (`host_polygon_distances`).
+        ``tally`` takes what the call's span reports of the edges."""
+        import jax.numpy as jnp
+
+        kx, pb = self.kx, self.kx.points
+        prog = _engine.poly_block_topk_prog()
+        limit = float(thr)
+        thr = jnp.asarray(thr, dtype=self._dtype)
+        if self._block_fill is None:
+            nblk = np.diff(pb.blk_start)
+            rank = np.arange(pb.n_blocks) - np.repeat(pb.blk_start[:-1], nblk)
+            self._block_fill = np.minimum(
+                pb.width, np.repeat(pb.count, nblk) - pb.width * rank
+            )
+        fill = self._block_fill
+
+        def host_pairs(land, blk):
+            """Every real pair of chunks ``(land, blk)`` and its f64 distance."""
+            rid = np.asarray(pb.rid[blk.astype(np.int32)])
+            keep = rid >= 0
+            qi, ci = np.repeat(land, pb.width)[keep.ravel()], rid[keep]
+            return qi, ci, host_polygon_distances(rings, qi, kx.host.xy[ci])
+
+        def evaluate(active, cq, blk, steps, ring):
+            land = active[cq]
+            table = rings.table[land]
+            real = fill[blk]
+            mine = rings.edges[land]
+
+            def device():
+                outs, hq, padded, rows = [], [], 0, 0
+                for t in np.unique(table[table >= 0]):
+                    sel = np.flatnonzero(table == t)
+                    tab, vpad = rings.tables[t], int(rings.pads[t])
+                    o, head, p, w = self._launch_chunks(
+                        prog, f"polyblocks.v{vpad}", k, (tab,),
+                        ((blk[sel].astype(np.int32), pb.n_blocks),
+                         (rings.row[land[sel]].astype(np.int32),
+                          tab.shape[0] - 1),
+                         (cq[sel].astype(np.int32), -1)),
+                        steps, thr, vpad=vpad,
+                    )
+                    outs += o
+                    hq.append(cq[sel][head])
+                    padded += p
+                    rows += w
+                hd, hi = self._pull_heads(outs, rows, int(cq.shape[0]))
+                sel = np.flatnonzero(table < 0)
+                if sel.size:  # the host's landmarks: a row each
+                    qi, ci, d = host_pairs(cq[sel], blk[sel])
+                    keep = d <= limit
+                    fd = np.full((active.size, k), np.inf)
+                    fi = np.full((active.size, k), -1, dtype=np.int64)
+                    fd, fi = _engine.merge_topk(
+                        fd, fi, qi[keep], ci[keep], d[keep], k
+                    )
+                    uq = np.unique(cq[sel])
+                    hq.append(uq)
+                    hd = np.concatenate([hd.astype(np.float64), fd[uq]])
+                    hi = np.concatenate([
+                        hi.astype(np.int64),
+                        np.where(fi[uq] < 0, _engine._NO_ID, fi[uq]),
+                    ])
+                    padded += int(sel.size) * pb.width
+                return np.concatenate(hq), hd, hi, padded, len(outs), rows
+
+            def oracle():
+                return np.column_stack(host_pairs(land, blk))
+
+            got = guarded_call(
+                "knn.distance", device, default_s=default_s, fallback=oracle
+            )
+            tally["edge_rows"] += int(mine.sum())
+            tally["edge_pairs"] += int((real * mine).sum())
+            # (a table of -1 reads the last pad and is masked: the host
+            # evaluates a landmark's own edges)
+            tally["edge_pairs_padded"] += int(
+                (real * np.where(table < 0, mine, rings.pads[table])).sum()
+            )
+            return got
+
+        return evaluate
+
     def search(
         self, k: int, *, points=None, seed_ptr=None, seed_cells=None,
         exact: bool = True, max_iterations=None, early_stop=None,
         threshold=None, pair_distances=None, on_iteration=None,
-        default_s=None,
+        default_s=None, polygons=None, seeds=None,
     ) -> "_engine.RingResult":
         """One ring search (`engine.ring_search`) under this frontend's
         discipline: ``points`` (n, 2) raw query coordinates — their cells
@@ -548,10 +658,29 @@ class KNNFrontend:
         the index is all points and there is no mesh, else by the pair
         program (or the caller's ``pair_distances``, where it brings
         one) — or, for geometry queries, their cover cells (``seed_ptr``
-        / ``seed_cells``) with the caller's own ``pair_distances``.
+        / ``seed_cells``) with the caller's own ``pair_distances``; or
+        ``polygons`` (`index.LandmarkRings`) with their ``seeds``
+        (`index.LandmarkSeeds`), evaluated by the polygon block program
+        against an all-point index without a mesh.
         ``knn.expand`` and ``knn.scatter`` guard the pure stages."""
         kx = self.kx
-        block_topk = None
+        block_topk = seed_keys = tally = None
+        if polygons is not None:
+            tally = dict.fromkeys(
+                ("edge_pairs", "edge_pairs_padded", "edge_rows"), 0
+            )
+            if kx.points is None or self.mesh is not None:
+                raise ValueError(
+                    "polygon queries run the block lane: an all-point "
+                    "index and no mesh"
+                )
+            seed_ptr, seed_cells = seeds.ptr, seeds.cells
+            if seeds.keys is not None:
+                seed_keys = (seeds.keys, seeds.margin)
+            block_topk = self._poly_block_topk(
+                polygons, k, np.inf if threshold is None else threshold,
+                default_s, tally,
+            )
         if points is not None:
             n = points.shape[0]
             qs64 = points - kx.shift
@@ -572,8 +701,10 @@ class KNNFrontend:
             early_stop=early_stop, threshold=threshold,
             pair_distances=pair_distances, block_topk=block_topk,
             guard=guarded_call,
-            on_iteration=on_iteration,
+            on_iteration=on_iteration, seed_keys=seed_keys,
         )
+        if tally is not None:
+            res.counters.update(tally)
         self.stats["iterations"] += res.iterations
         if block_topk is not None:
             self.stats["pairs"] += res.pairs
@@ -707,7 +838,8 @@ class KNNFrontend:
             )
             dist[sub] = fdist
             cid[sub] = fcid
-            degraded = degraded or fdeg
+            if degraded is None:  # (an array: it has no truth value)
+                degraded = fdeg
         return dist, cid, degraded
 
     # --------------------------------------------------------- serving
@@ -765,7 +897,7 @@ class KNNFrontend:
             for i in range(ids.shape[0])
         ]
 
-    def warmup(self, k: "int | None" = None) -> dict:
+    def warmup(self, k: "int | None" = None, polygons: bool = False) -> dict:
         """Touch every (kind, rung) pair so serving can only replay:
         compiles (or AOT loads) every cell and pair program, then
         freezes the signature set — any later signature is a cold
@@ -774,7 +906,12 @@ class KNNFrontend:
         program keeps the k best on the device, so k is part of its
         shape) every block rung is touched with every head rung a launch
         of it can take (the ladder's rungs up to its own), and the pair
-        rungs, which only geometry queries reach, are not."""
+        rungs, which only geometry queries reach, are not. ``polygons``
+        (the model's lane for the landmarks it was shown) warms the
+        polygon block program in the point program's place: every (block
+        rung, edge rung) of it — its table's shape is the edge rung's
+        alone, so these are the programs any polygon column launches —
+        and the same head rungs."""
         c0 = backend_compiles()
         blocks = (
             k is not None and self.kx.points is not None and self.mesh is None
@@ -785,7 +922,9 @@ class KNNFrontend:
                     "knn_stage", stage="warmup", kind="cells", bucket=b,
                 ):
                     self._cells_bucket(np.zeros((b, 2)))
-            if blocks:
+            if blocks and polygons:
+                self._warm_polygon_blocks(int(k))
+            elif blocks:
                 evaluate = self._block_topk(
                     None, np.zeros((1, 2), self._dtype), int(k), np.inf, None
                 )
@@ -823,6 +962,32 @@ class KNNFrontend:
         }
         _telemetry.record("knn_warmup", **report)
         return report
+
+    def _warm_polygon_blocks(self, k: int) -> None:
+        """One launch of every (block rung, edge rung) of the polygon
+        block program, the first edge rung's with every head rung."""
+        import jax.numpy as jnp
+
+        prog = _engine.poly_block_topk_prog()
+        thr = jnp.asarray(np.inf, dtype=self._dtype)
+        for v, vpad in enumerate(VERTEX_LADDER.buckets):
+            tab = jnp.zeros((table_rows(vpad), 5, vpad), dtype=self._dtype)
+            for b in BLOCK_LADDER.buckets:
+                heads = [r for r in BLOCK_LADDER.buckets if r <= b]
+                for h in heads if v == 0 else heads[:1]:
+                    with _telemetry.timed(
+                        "knn_stage", stage="warmup", kind="polyblocks",
+                        bucket=b, head_bucket=h, vpad=vpad,
+                    ):
+                        zeros = np.zeros(b, np.int32)
+                        outs, _, _, rows = self._launch_chunks(
+                            prog, f"polyblocks.v{vpad}", k, (tab,),
+                            ((zeros, 0), (zeros, 0),
+                             (np.minimum(np.arange(b), h - 1).astype(np.int32),
+                              -1)),
+                            1, thr, vpad=vpad,
+                        )
+                        self._pull_heads(outs, rows, b)
 
     def metrics(self) -> dict:
         return {

@@ -178,6 +178,263 @@ class KNNIndex:
         return out
 
 
+@dataclasses.dataclass
+class LandmarkSeeds:
+    """The cells polygon landmarks search from, CSR by landmark: a superset
+    of each landmark's cover (every point of the polygon lies in one of its
+    seed cells), which is all the ring search's rest criterion asks."""
+
+    ptr: np.ndarray  # (L+1,) int64
+    #: (S,) int64 cell ids; -1 where the seed was made on the lattice and
+    #: never needs its id (`KNNIndex.ring_keys` walks only short margins)
+    cells: np.ndarray
+    keys: "np.ndarray | None"  # (S,) lattice keys (a lattice index), else None
+    margin: "np.ndarray | None"
+    tessellated: int = 0  # landmarks whose cover the grid's clipper made
+
+
+#: edge slots of one table of the polygon block program (`LandmarkRings`):
+#: a table's rows are these over its pad whatever the landmark column
+#: holds, so the program that takes it is one shape an edge rung
+TABLE_SLOTS = 1 << 17
+
+
+def table_rows(vpad: int) -> int:
+    """Rows of a `LandmarkRings` table whose rows pad to ``vpad`` edges."""
+    return TABLE_SLOTS // vpad
+
+
+@dataclasses.dataclass
+class LandmarkRings:
+    """Polygon landmarks' edges, laid out for the block program: landmark
+    ``l`` is row ``row[l]`` of table ``table[l]`` — ``(table_rows(vpad),
+    5, vpad)`` in the index's dtype and frame: an edge's ``ax, ay, bx, by``
+    and ``1 / length^2`` (0 for a pad or a point), every ring closed, holes
+    in the same row, pads repeating the landmark's first vertex (an edge of
+    no length: it crosses nothing and is no nearer than the vertex). A
+    table's shape is its pad's alone (`table_rows`): the landmarks of one
+    edge rung fill as many tables as they need, in their order, and the
+    program compiled for a rung serves every column. The last row of a
+    table is the padding chunks'. ``table`` is -1 where a landmark has no
+    edge (it has no seed either) or more than the ladder's top rung: the
+    host answers that one from ``flat``, the f64 edges of every landmark
+    (``start``, ``edges``)."""
+
+    edges: np.ndarray  # (L,) int64 real edges (one a vertex)
+    start: np.ndarray  # (L,) int64 first edge in ``flat``
+    flat: np.ndarray  # (5, V) f64
+    table: np.ndarray  # (L,) int64 index into ``tables``, -1 = host
+    row: np.ndarray  # (L,) int64
+    pads: np.ndarray  # (T,) int64 the edges a row of each table pads to
+    tables: list  # (T,) device arrays
+    nbytes: int = 0
+
+
+def _geom_vertex_runs(land: PackedGeometry):
+    """(lo, hi) vertex runs of each geometry in ``land.xy``."""
+    first = land.ring_offsets[land.part_offsets[land.geom_offsets]]
+    return first[:-1], first[1:]
+
+
+def pack_landmark_rings(kx: "KNNIndex", land: PackedGeometry, ladder) -> LandmarkRings:
+    """`LandmarkRings` of a polygon column, recentred on ``kx.shift`` in
+    f64 on the host and put on the device in ``kx.dtype``; array code."""
+    import jax.numpy as jnp
+
+    lo, hi = _geom_vertex_runs(land)
+    nv = land.xy.shape[0]
+    # every vertex starts an edge to the next of its ring, the last to the first
+    nxt = np.arange(1, nv + 1, dtype=np.int64)
+    ends = land.ring_offsets[1:]
+    full = ends > land.ring_offsets[:-1]
+    nxt[ends[full] - 1] = land.ring_offsets[:-1][full]
+    a = land.xy - kx.shift
+    b = a[nxt]
+    d = b - a
+    len2 = (d * d).sum(axis=1)
+    inv = np.divide(1.0, len2, out=np.zeros(nv), where=len2 > 0)
+    flat = np.stack([a[:, 0], a[:, 1], b[:, 0], b[:, 1], inv])
+    edges = (hi - lo).astype(np.int64)
+    vpads = np.asarray(ladder.buckets)
+    rung = np.searchsorted(vpads, edges)
+    rung[(rung >= vpads.size) | (edges == 0)] = -1
+    table = np.full(edges.shape[0], -1, dtype=np.int64)
+    row = np.zeros(edges.shape[0], dtype=np.int64)
+    tables, pads, nbytes = [], [], 0
+    owner = np.repeat(np.arange(edges.shape[0]), edges)  # landmark of each edge
+    within = np.arange(nv, dtype=np.int64) - np.repeat(lo, edges)
+    for r, vpad in enumerate(vpads.tolist()):
+        mine = np.flatnonzero(rung == r)
+        held = table_rows(vpad) - 1  # (the last row is the pads')
+        table[mine] = len(tables) + np.arange(mine.size) // held
+        row[mine] = np.arange(mine.size) % held
+        sel = np.flatnonzero(rung[owner] == r)
+        for t in range(-(-mine.size // held)):
+            part = mine[t * held : (t + 1) * held]
+            tab = np.zeros((held + 1, 5, vpad))
+            tab[: part.size, 0] = tab[: part.size, 2] = a[lo[part], 0][:, None]
+            tab[: part.size, 1] = tab[: part.size, 3] = a[lo[part], 1][:, None]
+            e = sel[table[owner[sel]] == len(tables)]
+            tab[row[owner[e]], :, within[e]] = flat[:, e].T
+            dev = jnp.asarray(tab, dtype=kx.dtype)
+            nbytes += int(dev.nbytes)
+            tables.append(dev)
+            pads.append(vpad)
+    return LandmarkRings(
+        edges=edges, start=lo.astype(np.int64), flat=flat, table=table,
+        row=row, pads=np.asarray(pads, dtype=np.int64), tables=tables,
+        nbytes=nbytes,
+    )
+
+
+def edge_terms(px, py, ax, ay, bx, by, inv, xp=np):
+    """A point against an edge ``a -> b``, elementwise over broadcast
+    shapes: ``(squared distance to the segment, whether a ray from the
+    point towards +x crosses it)`` — the even-odd rule's crossing, half
+    open in y so a vertex counts once. ``inv`` is ``1 / |b - a|^2`` (0
+    for an edge of no length: the distance is the vertex's)."""
+    dx, dy = bx - ax, by - ay
+    rx, ry = px - ax, py - ay
+    t = xp.clip((rx * dx + ry * dy) * inv, 0.0, 1.0)
+    cx, cy = rx - t * dx, ry - t * dy
+    crossing = ((ay > py) != (by > py)) & ((rx * dy < ry * dx) == (dy > 0))
+    return cx * cx + cy * cy, crossing
+
+
+def host_polygon_distances(rings: LandmarkRings, qi, cxy) -> np.ndarray:
+    """(P,) f64 `st_distance(polygon, point)` of landmark ``qi[p]`` and the
+    shifted point ``cxy[p]``: 0.0 inside or on the boundary (even-odd over
+    all rings, so a courtyard is outside), else the nearest edge's — the
+    block program's arithmetic in numpy on the f64 edges, a slice of pairs
+    at a time."""
+    out = np.empty(qi.shape[0], dtype=np.float64)
+    if not qi.size:
+        return out
+    width = int(rings.edges[qi].max())
+    if not width:
+        out[:] = np.inf
+        return out
+    step = max((1 << 21) // width, 1)
+    lane = np.arange(width)
+    for s in range(0, qi.shape[0], step):
+        q = qi[s : s + step]
+        n = rings.edges[q][:, None]
+        # (a pad lane reads the landmark's first edge again: min and parity
+        # are masked below)
+        at = rings.start[q][:, None] + np.where(lane < n, lane, 0)
+        e = rings.flat[:, at]  # (5, p, width)
+        p = cxy[s : s + step]
+        d2, cross = edge_terms(p[:, :1], p[:, 1:], e[0], e[1], e[2], e[3], e[4])
+        real = lane < n
+        odd = (np.count_nonzero(cross & real, axis=1) & 1) == 1
+        d = np.sqrt(np.where(real, d2, np.inf).min(axis=1))
+        out[s : s + step] = np.where(odd, 0.0, d)
+    return out
+
+
+#: a lattice hexagon reaches 2/3 of a step along each axis from its centre
+_HEX_REACH = 2.0 / 3.0
+#: what a cover's ranges give way for an edge that is straight in lon/lat
+#: and not on the face's plane (under 4e-3 of a cell at `_COVER_SPAN`)
+_COVER_SLACK = 0.01
+#: cells a landmark may span along an axis and still be covered on the lattice
+_COVER_SPAN = 32
+
+
+def polygon_cover(kx: "KNNIndex", land: PackedGeometry, rings: int) -> LandmarkSeeds:
+    """The seed cells of polygon landmarks as array code, with no clipping.
+
+    On a lattice index: a landmark's vertices are placed on the lattice
+    (`IndexSystem.lattice_coords`, continuous); a polygon lies in the hull
+    of its vertices, so each of the lattice's six linear forms — the axial
+    coordinates ``a, b, c = -a - b`` and their differences — stays within
+    its range over the vertices, and a hexagon meets the polygon only if
+    its centre is within a hexagon's reach of every range (2/3 along an
+    axis, 1 along a difference: the hexagon IS ``|da - db|, |db - dc|,
+    |dc - da| <= 1``). The cells that pass are a superset of the cover —
+    exactly the cover for a footprint small against a cell. ``rings`` is
+    how many rings the search may ask of a seed: a landmark nearer its
+    face's edge than that (or past it, or spanning more than
+    `_COVER_SPAN` cells, or on a grid with no lattice) has its cover made
+    by `tessellate` instead."""
+    # (a landmark's vertices are one run of ``land.xy``, landmark after
+    # landmark: `_geom_vertex_runs`)
+    n = len(land)
+    lo, hi = _geom_vertex_runs(land)
+    some = np.flatnonzero(hi > lo)
+    regular = np.zeros(n, dtype=bool)
+    own = np.zeros(0, dtype=np.int64)
+    cells = keys = margin = own
+    if kx.lattice and some.size:
+        # every vertex on the face of its landmark's first: one beyond that
+        # face's triangle reads a negative margin below
+        at = lo[some]
+        face = kx.index_system.lattice_coords(land.xy[at], kx.resolution)[0]
+        _, xa, xb, edge = kx.index_system.lattice_coords(
+            land.xy[at[0] :], kx.resolution,
+            face=np.repeat(face, np.diff(np.r_[at, land.xy.shape[0]])),
+        )
+        at = at - at[0]
+        xc = -xa - xb
+
+        def span(v):
+            return np.minimum.reduceat(v, at), np.maximum.reduceat(v, at)
+
+        forms = [span(v) for v in (xa, xb, xc, xa - xb, xb - xc, xc - xa)]
+        reach = _HEX_REACH + _COVER_SLACK
+        a_lo = np.ceil(forms[0][0] - reach).astype(np.int64)
+        a_hi = np.floor(forms[0][1] + reach).astype(np.int64)
+        b_lo = np.ceil(forms[1][0] - reach).astype(np.int64)
+        b_hi = np.floor(forms[1][1] + reach).astype(np.int64)
+        na, nb = a_hi - a_lo + 1, b_hi - b_lo + 1
+        room = np.minimum.reduceat(edge, at) - np.maximum(na, nb) - 1
+        ok = (np.maximum(na, nb) <= _COVER_SPAN) & (room >= rings)
+        regular[some[ok]] = True
+        pick = np.flatnonzero(ok)
+        box = (na * nb)[pick]  # lattice positions in each landmark's a x b box
+        o = np.repeat(pick, box)
+        t = expand_ranges(np.zeros_like(box), box)
+        a = a_lo[o] + t // nb[o]
+        b = b_lo[o] + t % nb[o]
+        c = -a - b
+        keep = np.ones(o.size, dtype=bool)
+        for (v_lo, v_hi), v, r in zip(
+            forms, (a, b, c, a - b, b - c, c - a), (reach,) * 3 + (1.0 + _COVER_SLACK,) * 3
+        ):
+            keep &= (v >= v_lo[o] - r) & (v <= v_hi[o] + r)
+        o, a, b = o[keep], a[keep], b[keep]
+        own = some[o]
+        keys = kx.index_system.lattice_pack(face[o], a, b)
+        margin = room[o]
+        cells = np.full(own.size, -1, dtype=np.int64)
+    rest = np.flatnonzero(~regular & (hi > lo))
+    if rest.size:
+        table = tessellate(
+            land if rest.size == n else land.take(rest), kx.index_system,
+            kx.resolution, keep_core_geoms=False,
+        )
+        cover = np.unique(
+            np.stack([rest[table.geom_id.astype(np.int64)],
+                      np.asarray(table.cell_id, dtype=np.int64)]), axis=1,
+        )
+        t_keys, t_margin = kx.probe_keys(cover[1])
+        own = np.concatenate([own, cover[0]])
+        cells = np.concatenate([cells, cover[1]])
+        if kx.lattice:
+            keys = np.concatenate([keys, t_keys])
+            margin = np.concatenate([margin, t_margin])
+        order = np.argsort(own, kind="stable")
+        own, cells = own[order], cells[order]
+        if kx.lattice:
+            keys, margin = keys[order], margin[order]
+    ptr = np.concatenate([[0], np.cumsum(np.bincount(own, minlength=n))])
+    return LandmarkSeeds(
+        ptr=ptr.astype(np.int64), cells=cells,
+        keys=keys if kx.lattice else None,
+        margin=margin if kx.lattice else None, tessellated=int(rest.size),
+    )
+
+
 def expand_ranges(start: np.ndarray, count: np.ndarray) -> np.ndarray:
     """``concatenate([arange(s, s + c) for s, c in zip(start, count)])``
     as array code."""

@@ -17,9 +17,13 @@ landmarks at once. The candidates live in a `knn.KNNIndex`
 (`build_knn_index`), which `transform` builds from a geometry column or
 takes prebuilt, so a table held resident on the device answers call after
 call. Point landmarks against an all-point index run the engine's block
-lane (pairs never leave the device); any other pairing hands each
-iteration's fresh pairs to one padded device call over two DeviceGeometry
-columns that share the index's f64 recenter shift.
+lane (pairs never leave the device), and so do polygon landmarks: their
+seed cells are made without clipping (`knn.index.polygon_cover`), their
+edges put on the device once a call (`knn.index.pack_landmark_rings`), and
+every (landmark, block) chunk's point-to-polygon distances evaluated
+there. Any other pairing (`mesh=`, a checkpoint, candidates that are not
+points) hands each iteration's fresh pairs to one padded device call over
+two DeviceGeometry columns that share the index's f64 recenter shift.
 """
 
 from __future__ import annotations
@@ -32,6 +36,7 @@ from ..core.geometry import affine as _affine
 from ..core.geometry.device import pack_to_device
 from ..core.index.base import IndexSystem
 from ..core.tessellate import tessellate
+from ..core.types import GeometryType
 from ..functions._coerce import to_packed
 from ..dispatch import core as _dispatch
 from ..obs import trace as _trace
@@ -177,11 +182,44 @@ class SpatialKNN:
             self._frontend = (kx, KNNFrontend(kx))
         return self._frontend[1]
 
-    def warmup(self, candidates) -> dict:
+    @staticmethod
+    def _landmarks(landmarks):
+        """``(lxy, land)``: the (N, 2) coordinates where every landmark is
+        a point (``land`` is then None unless it came packed), else the
+        packed column."""
+        from ..knn.index import point_coords
+
+        lxy = point_coords(landmarks)
+        land = None if lxy is not None else to_packed(landmarks)
+        if land is not None:
+            lxy = point_coords(land)
+        return lxy, land
+
+    def _lane(self, kx, lxy, land, checkpoint: bool) -> str:
+        """How a transform evaluates its pairs: ``"blocks"`` (point
+        landmarks) or ``"polygons"`` (polygon landmarks) on the device's
+        block lane — an all-point index, no mesh, no checkpoint — else
+        ``"pairs"``."""
+        if kx.points is None or self.mesh is not None or checkpoint:
+            return "pairs"
+        if lxy is not None:
+            return "blocks"
+        areal = (int(GeometryType.POLYGON), int(GeometryType.MULTIPOLYGON))
+        return "polygons" if np.isin(land.geom_type, areal).all() else "pairs"
+
+    def warmup(self, candidates, landmarks=None) -> dict:
         """Compile every program a transform against ``candidates`` (a
-        resident `knn.KNNIndex`) can launch: `KNNFrontend.warmup` with
-        this model's k."""
-        return self._frontend_of(self._index(candidates)).warmup(k=self.k)
+        resident `knn.KNNIndex`) can launch on landmarks like
+        ``landmarks`` — a sample of the column the calls will bring, any
+        input `transform` takes; None stands for points:
+        `KNNFrontend.warmup` with this model's k, on the lane `transform`
+        would take. The programs' shapes hold none of the sample's
+        counts, so a column of the same kind compiles nothing more."""
+        kx = self._index(candidates)
+        polygons = landmarks is not None and self._lane(
+            kx, *self._landmarks(landmarks), bool(self.checkpoint_dir)
+        ) == "polygons"
+        return self._frontend_of(kx).warmup(k=self.k, polygons=polygons)
 
     # ----------------------------------------------------------- transform
     def transform(self, landmarks, candidates) -> KNNResult:
@@ -189,14 +227,16 @@ class SpatialKNN:
         is any geometry input or a float (N, 2) array of points;
         ``candidates`` likewise, or a prebuilt `knn.KNNIndex` held
         resident across calls."""
-        from ..knn.index import point_coords, points_column
+        from ..knn.frontend import VERTEX_LADDER
+        from ..knn.index import (
+            pack_landmark_rings,
+            polygon_cover,
+            points_column,
+        )
 
         kx = self._index(candidates)
         fe = self._frontend_of(kx)
-        lxy = point_coords(landmarks)
-        land = None if lxy is not None else to_packed(landmarks)
-        if land is not None:
-            lxy = point_coords(land)
+        lxy, land = self._landmarks(landmarks)
         L = lxy.shape[0] if lxy is not None else len(land)
         ring = GridRingNeighbours(kx.index_system, kx.resolution, self.mesh)
         ckpt = (
@@ -234,12 +274,33 @@ class SpatialKNN:
             on_iteration=log if ckpt is not None else None,
         )
         with _trace.span("knn.transform", landmarks=L, k=self.k) as sp:
-            blocks = (
-                lxy is not None and kx.points is not None
-                and self.mesh is None and ckpt is None
-            )
-            if blocks:
+            lane = self._lane(kx, lxy, land, ckpt is not None)
+            if lane == "blocks":
                 res = fe.search(self.k, points=lxy, **search)
+            elif lane == "polygons":
+                with _trace.span("knn.cover", landmarks=L) as cs:
+                    seeds = polygon_cover(kx, land, self.max_iterations + 1)
+                    cs.set(
+                        seeds=int(seeds.cells.shape[0]),
+                        tessellated=seeds.tessellated,
+                    )
+                with _trace.span("knn.landmarks", rows=L) as ls:
+                    rings = pack_landmark_rings(kx, land, VERTEX_LADDER)
+                    ls.set(
+                        nbytes=rings.nbytes, tables=len(rings.tables),
+                        vpad=np.unique(rings.pads).tolist(),
+                    )
+                res = fe.search(
+                    self.k, polygons=rings, seeds=seeds, **search
+                )
+                res.counters.update(
+                    seeds=int(seeds.cells.shape[0]),
+                    edges=int(rings.edges.sum()),
+                    host_landmarks=int(
+                        ((rings.table < 0) & (rings.edges > 0)).sum()
+                    ),
+                )
+                sp.set(**res.counters)
             elif lxy is not None:
                 res = fe.search(
                     self.k, points=lxy, pair_distances=pair_distances,
@@ -288,6 +349,7 @@ class SpatialKNN:
             "pairs": res.pairs,
             "pairs_padded": res.pairs_padded,
             "launches": res.launches,
+            **res.counters,
             # True when any iteration's distances came from the f64 host
             # oracle after the device path failed past its retry budget
             "degraded": res.degraded is not None,

@@ -5,7 +5,8 @@ The join names its stages with ``jax.named_scope`` (``pip.cells``,
 ``pip.hash_probe``, ``pip.compact``, ``pip.tier1``, ``pip.writeback``,
 ``stream.fold``, ...; the raster tile's own are ``zonal.centers`` and
 ``zonal.fold``; the KNN block program's ``knn.gather``, ``knn.distance``,
-``knn.topk``, and ``knn.heads`` of the program that gathers a launch's
+``knn.topk`` (``knn.edges`` in the distance's place where the queries are
+polygons), and ``knn.heads`` of the program that gathers a launch's
 head rows). This libtpu's device trace does not carry them: an
 ``XLA Ops`` event is named by the HLO instruction's text
 (``%fusion.504 = f32[4000000,153]{...} fusion(...)``) and the number
