@@ -7,8 +7,19 @@ index build), and the per-query work runs on device:
 
 - :func:`pair_spans` / :func:`pair_count` — run-length segment spans via
   two ``searchsorted`` probes of the right table per left row: the span
-  ``[lo, lo+cnt)`` of right rows sharing the left row's cell.
-- :func:`emit_pairs` — bounded CSR cross-join emission: pair rank ``k``
+  ``[lo, lo+cnt)`` of right rows sharing the left row's cell. The SEARCHED
+  form, over the raw int64 cell ids: what the numpy twin (the oracle)
+  runs, and what the form below is tested against.
+- :func:`run_offsets` / :func:`rank_spans` — the same spans, READ: the
+  prep ranks every row's cell densely (one rank a distinct cell of the
+  pair, the two pad sentinels after them), the right column is kept as
+  the offsets of its runs over those ranks (an index of it, built once a
+  prep), and a left row's span is ``roff[rank], roff[rank + 1]`` — two
+  int32 gathers, where a search is some twenty rounds of two-word
+  compares on a chip with no 64-bit integers. The device lane's form,
+  computed once a call.
+- :func:`emit_spans` (and :func:`emit_pairs`, the searched spans in front
+  of it) — bounded CSR cross-join emission: pair rank ``k``
   maps to its left row by a ``searchsorted`` over the exclusive span
   offsets and to its right row by the in-span remainder, against a
   STATIC pair bucket so the compiled program population stays on the
@@ -72,12 +83,15 @@ __all__ = [
     "clip_area_convex",
     "clip_rows",
     "emit_pairs",
+    "emit_spans",
     "fan_area",
     "fan_rows",
     "host_pair_fold",
     "in_band",
     "pair_count",
     "pair_spans",
+    "rank_spans",
+    "run_offsets",
     "window_swaps",
 ]
 
@@ -87,7 +101,9 @@ CLIP_EPS = 1e-12
 
 #: pad sentinels for the sorted cell columns. Distinct per side so a pad
 #: row can never equi-join another pad row; both sort above every real
-#: cell id, so pads stay at the tail of the sorted table.
+#: cell id, so pads stay at the tail of the sorted table. The dense ranks
+#: keep the order: the right sentinel's rank follows the last real
+#: cell's, the left sentinel's follows that.
 LEFT_PAD_CELL = np.int64(2**62 - 1)
 RIGHT_PAD_CELL = np.int64(2**62 - 2)
 
@@ -108,10 +124,13 @@ def _scope(name: str, xp):
 
 
 def pair_spans(lcells, rcells, n_left, xp=jnp):
-    """Per-left-row right-table span: ``(lo, cnt)`` with ``cnt[i]`` right
-    rows sharing cell ``lcells[i]`` starting at sorted right row
-    ``lo[i]``. Both cell columns must be sorted ascending with their pad
-    sentinels at the tail; rows at and past ``n_left`` count zero."""
+    """Per-left-row right-table span, SEARCHED: ``(lo, cnt)`` with
+    ``cnt[i]`` right rows sharing cell ``lcells[i]`` starting at sorted
+    right row ``lo[i]``. Both cell columns must be sorted ascending with
+    their pad sentinels at the tail; rows at and past ``n_left`` count
+    zero. The numpy twin's form (raw ids, no prepared table between the
+    oracle and the answer); the device lane reads the same integers
+    through :func:`rank_spans`."""
     with _scope("overlay.spans", xp):
         lcells = xp.asarray(lcells)
         rcells = xp.asarray(rcells)
@@ -129,32 +148,72 @@ def pair_count(lcells, rcells, n_left, xp=jnp):
     return cnt.sum()
 
 
-def emit_pairs(lcells, rcells, n_left, emit_limit, pair_bucket: int,
-               xp=jnp):
-    """CSR cross-join emission against a static ``pair_bucket``.
+def run_offsets(rank, length: int) -> np.ndarray:
+    """(length,) int32 run offsets of a SORTED dense-rank column (host,
+    once a prep): ``roff[r]`` is the first row holding rank ``r`` — the
+    count of rows ranked below it, whether or not ``r`` occurs — so
+    ``roff[r + 1] - roff[r]`` is rank ``r``'s run length, 0 where the
+    column has no such row. ``length`` must exceed every rank by two
+    (``roff[r + 1]`` is read for the largest); past the largest rank the
+    table holds the column's length."""
+    roff = np.zeros(length, np.int32)
+    roff[1:] = np.cumsum(np.bincount(rank, minlength=length - 1))
+    return roff
+
+
+def rank_spans(rank, roff, n_left, xp=jnp):
+    """:func:`pair_spans`' ``(lo, cnt)``, READ: ``rank`` the left rows'
+    dense cell ranks (sorted; the pad rows carry the left sentinel's
+    rank, whose run is empty), ``roff`` the right column's
+    :func:`run_offsets` over the same ranks. Equal to the searched form
+    integer for integer, pad rows included, when the ranks are those of
+    the pair's distinct cells followed by ``RIGHT_PAD_CELL`` and
+    ``LEFT_PAD_CELL`` (`sql.overlay.prepare_overlay`)."""
+    with _scope("overlay.spans", xp):
+        rank = xp.asarray(rank)
+        roff = xp.asarray(roff)
+        lo = roff[rank]
+        valid = xp.arange(rank.shape[0]) < n_left
+        cnt = xp.where(valid, roff[rank + 1] - lo, 0)
+    return lo, cnt
+
+
+def emit_spans(lo, cnt, emit_limit, pair_bucket: int, xp=jnp):
+    """CSR cross-join emission of the spans ``(lo, cnt)`` against a
+    static ``pair_bucket``.
 
     Returns ``(li, ri, valid)`` — (Pb,) int32 sorted-table row indices
     and the live-slot mask. Pair rank ``k`` resolves to its left row by
     ``searchsorted(off, k, 'right') - 1`` over the exclusive span
     offsets (zero-count rows are skipped by construction) and to its
-    right row by ``lo + (k - off)``. Emission order is left-row-major
+    right row by ``lo + (k - off)``, which lies inside the row's span
+    and so inside the right table. Emission order is left-row-major
     over the cell-sorted table == cell-major — the exact stream order of
     the host candidate generator, which is what makes the downstream
     fold order reproducible. Slots at and past ``min(total,
-    emit_limit)`` are invalid (the caller books ``total - emitted`` as
-    OVERFLOW)."""
-    lo, cnt = pair_spans(lcells, rcells, n_left, xp=xp)
+    emit_limit)`` are invalid and read row 0 on both sides (the caller
+    books ``total - emitted`` as OVERFLOW)."""
     with _scope("overlay.emit", xp):
         off = xp.cumsum(cnt) - cnt
         total = cnt.sum()
-        nl = lcells.shape[0]
+        nl = cnt.shape[0]
         k = xp.arange(pair_bucket, dtype=off.dtype)
         li = xp.clip(xp.searchsorted(off, k, side="right") - 1, 0, nl - 1)
         ri = lo[li] + (k - off[li])
         valid = k < xp.minimum(total, emit_limit)
         li = xp.where(valid, li, 0)
-        ri = xp.where(valid, xp.clip(ri, 0, rcells.shape[0] - 1), 0)
+        ri = xp.where(valid, ri, 0)
     return li.astype(xp.int32), ri.astype(xp.int32), valid
+
+
+def emit_pairs(lcells, rcells, n_left, emit_limit, pair_bucket: int,
+               xp=jnp):
+    """:func:`emit_spans` of the SEARCHED spans (:func:`pair_spans`):
+    the whole candidate stream from the two raw cell columns, as the
+    numpy twin runs it. The device lane has its spans from the count
+    program already and emits from those."""
+    lo, cnt = pair_spans(lcells, rcells, n_left, xp=xp)
+    return emit_spans(lo, cnt, emit_limit, pair_bucket, xp=xp)
 
 
 # ------------------------------------------------------------- clip area

@@ -14,11 +14,13 @@ Two lanes share one contract:
   exploded into ring rows, sorted by int64 cell id and put on the device
   once (:func:`prepare_overlay`, amortized like the chip index build);
   candidate generation runs on device as a sorted segment equi-join
-  (`kernels.overlay.pair_count` / `emit_pairs`) against a static pair
-  bucket, and the overlap measures — per-row intersection areas, folded
-  per geometry pair, with an `expr/` pair tree evaluated over the folded
-  tables — run as ONE fused program per ``(tree-hash, buckets, index,
-  mesh)`` signature through `DispatchCore` (compile cache, warmup
+  whose spans are READ, once a call, through the prep's dense cell
+  ranks and the right column's run offsets
+  (`kernels.overlay.rank_spans`, then `emit_spans` against a static
+  pair bucket), and the overlap measures — per-row intersection areas,
+  folded per geometry pair, with an `expr/` pair tree evaluated over
+  the folded tables — run as ONE fused program per ``(tree-hash,
+  buckets, index, mesh)`` signature through `DispatchCore` (compile cache, warmup
   tripwire, watchdog/retry, ``mesh=`` sharding, graceful degradation).
 - **Host lane** (`expr.host_oracle.host_overlay_measures`): the numpy
   twin of the same kernels (``xp=np``) — off the TPU, under x64, the
@@ -123,6 +125,16 @@ PAIR_LADDER = _dispatch.BucketLadder(min_bucket=8, max_bucket=1 << 22)
 #: sorted side-table ladder (chip rows) and geometry-pair segment ladder
 TABLE_LADDER = _dispatch.BucketLadder(min_bucket=64, max_bucket=1 << 21)
 SEG_LADDER = _dispatch.BucketLadder(min_bucket=64, max_bucket=1 << 21)
+
+#: the run-offset table's ladder (`OverlaySide.roff`), on
+#: `TABLE_LADDER`'s rungs and two beyond: the table has an entry a
+#: distinct cell of the PAIR — two full side tables with no cell in
+#: common hold twice the top bucket — and three more (the two pad
+#: sentinels' ranks and the end of the last run)
+RANK_LADDER = _dispatch.BucketLadder(
+    min_bucket=TABLE_LADDER.min_bucket,
+    max_bucket=4 * TABLE_LADDER.max_bucket,
+)
 
 
 def pair_plan(total: int, pair_cap: int | None = None):
@@ -358,15 +370,26 @@ class OverlaySide:
     rows (pad cells carry a per-side sentinel that sorts above every
     real cell and can never equi-join the other side's sentinel).
     ``rows`` maps sorted row → original chip row; ``geom_area`` is
-    indexed by ORIGINAL geometry id. ``dev`` holds what the fused
-    program reads, put on the device once by `prepare_overlay` in the
-    prep's ``acc`` dtype.
+    indexed by ORIGINAL geometry id.
+
+    ``rank`` is the cell column again, as dense ranks: a row's cell's
+    place among the distinct cells of BOTH sides of the pair, the pad
+    rows ranked as their sentinel sorts (all real cells, then the right
+    sentinel, then the left one). On the right side ``roff`` holds the
+    rank column's run offsets (`kernels.overlay.run_offsets`, on
+    `RANK_LADDER`): the storage form the device's equi-join reads its
+    spans from, where ``cells`` (int64, host only) is what the numpy
+    twin searches. ``dev`` holds what the device programs read, put on
+    the device once by `prepare_overlay`: the left side's ``rank`` or
+    the right side's ``roff`` for the candidate programs, and the
+    fused measure program's tables in the prep's ``acc`` dtype.
     """
 
     table: ChipTable
     n: int
     bucket: int
     cells: np.ndarray      # (Lb,) i64 sorted ascending, sentinel tail
+    rank: np.ndarray       # (Lb,) i32 dense rank of ``cells`` in the pair
     geom: np.ndarray       # (Lb,) i64 geometry id, -1 pad
     core: np.ndarray       # (Lb,) bool
     ok: np.ndarray         # (Lb,) bool border ring within the vertex pad
@@ -382,6 +405,7 @@ class OverlaySide:
     ring_len: np.ndarray   # (Lb,) i64
     rows: np.ndarray       # (n,) i64 sorted row -> original chip row
     geom_area: np.ndarray  # (G,) f64 |geometry|
+    roff: np.ndarray | None = None  # (T,) i32 run offsets of ``rank`` (right)
     dev: dict | None = None
 
 
@@ -392,7 +416,9 @@ class OverlayPrep:
     is the largest cell extent, ``shift`` the data's centre, kept for
     callers that want one), the accelerated dtype by
     :func:`overlay_acc_dtype`, the epsilon band in area units and the
-    vertex pad — every static piece of the fused program's signature."""
+    vertex pad — every static piece of the fused program's signature —
+    and ``ranks``, the pair's distinct cells (what the sides' ``rank``
+    columns count in)."""
 
     left: OverlaySide
     right: OverlaySide
@@ -403,6 +429,7 @@ class OverlayPrep:
     acc_name: str
     band: float
     vpad: int
+    ranks: int
 
 
 def _csr_geom_areas(col: PackedGeometry, shift: np.ndarray) -> np.ndarray:
@@ -641,8 +668,10 @@ def prepare_overlay(
 
     One host pass per table pair: explode both chip tables into ring
     rows, sort them by cell id, pad to ladder buckets with per-side
-    sentinels, precompute the f64 area tables (ring, cell,
-    whole-geometry), pack every border ring within the vertex pad in
+    sentinels, rank every row's cell among the pair's distinct cells and
+    lay the right column out as its runs' offsets over those ranks (the
+    index the device's equi-join reads its spans from), precompute the
+    f64 area tables (ring, cell, whole-geometry), pack every border ring within the vertex pad in
     its own cell's frame (:func:`_pack_rings`), derive the epsilon band
     from the arithmetic the device really computes in
     (:func:`overlay_band`) and put what the fused program reads on the
@@ -697,8 +726,15 @@ def prepare_overlay(
         acc = overlay_acc_dtype()
         band = overlay_band(acc, scale)
         acc_dt = np.dtype(acc)
+        # the column the ranks count in: the sentinels keep their order
+        ranked = np.concatenate(
+            [ucells, [_k.RIGHT_PAD_CELL, _k.LEFT_PAD_CELL]]
+        )
+        roff_len = RANK_LADDER.bucket_for(ranked.shape[0] + 1)
 
-        def _side(table, col, cells_raw, rows, pad_cell):
+        def _side(table, col, cells_raw, rows, pad_cell, probed):
+            # probed: the side whose column the other's rows look their
+            # spans up in (the right); it carries the run offsets
             chip, start, length, sign = rows
             n = int(chip.shape[0])
             cells_row = cells_raw[chip]
@@ -739,6 +775,10 @@ def prepare_overlay(
 
             side = dict(
                 cells=pad(cells_row, pad_cell),
+                rank=pad(
+                    pos.astype(np.int32),
+                    np.searchsorted(ranked, pad_cell),
+                ),
                 geom=pad(np.asarray(table.geom_id, np.int64)[chip], -1),
                 core=pad(core),
                 ok=pad(ok & ~core),
@@ -753,13 +793,15 @@ def prepare_overlay(
                 ring_start=pad(start),
                 ring_len=pad(length),
             )
+            if probed:
+                side["roff"] = _k.run_offsets(side["rank"], roff_len)
             dev = {
                 k: jax.device_put(
                     side[k].astype(acc_dt)
                     if side[k].dtype == np.float64 else side[k]
                 )
-                for k in ("cells", "core", "sign", "verts", "vlen",
-                          "chip_area", "cell_area")
+                for k in ("roff" if probed else "rank", "core", "sign",
+                          "verts", "vlen", "chip_area", "cell_area")
             }
             return OverlaySide(
                 table=table, n=n, bucket=Lb,
@@ -770,9 +812,9 @@ def prepare_overlay(
 
         prep = OverlayPrep(
             left=_side(left_chips, left, lcells_raw, lrows,
-                       _k.LEFT_PAD_CELL),
+                       _k.LEFT_PAD_CELL, False),
             right=_side(right_chips, right, rcells_raw, rrows,
-                        _k.RIGHT_PAD_CELL),
+                        _k.RIGHT_PAD_CELL, True),
             shift=np.asarray(shift, np.float64),
             scale=scale,
             index_system=index_system,
@@ -780,9 +822,11 @@ def prepare_overlay(
             acc_name=acc,
             band=float(band),
             vpad=V,
+            ranks=int(ucells.shape[0]),
         )
         span.set(
             left_rows=prep.left.n, right_rows=prep.right.n, vpad=V,
+            ranks=prep.ranks,
             acc=acc, band=float(band), scale=scale,
             resident_bytes=sum(
                 int(a.nbytes) for s in (prep.left, prep.right)
@@ -883,18 +927,19 @@ def _padded(rows: np.ndarray, bucket: int) -> np.ndarray:
 
 @_dispatch.bounded_cache("overlay_count_programs", 8)
 def _count_program():
-    def overlay_count(lcells, rcells, n_left):
-        return _k.pair_count(lcells, rcells, n_left, xp=jnp)
+    def overlay_count(rank, roff, n_left):
+        lo, cnt = _k.rank_spans(rank, roff, n_left, xp=jnp)
+        with jax.named_scope("overlay.spans"):  # a trace books the sum there
+            total = cnt.sum()
+        return total, lo, cnt
 
     return jax.jit(overlay_count)
 
 
 @_dispatch.bounded_cache("overlay_emit_programs", 32)
 def _emit_program(pair_bucket: int):
-    def overlay_emit(lcells, rcells, n_left, emit_limit):
-        return _k.emit_pairs(
-            lcells, rcells, n_left, emit_limit, pair_bucket, xp=jnp
-        )
+    def overlay_emit(lo, cnt, emit_limit):
+        return _k.emit_spans(lo, cnt, emit_limit, pair_bucket, xp=jnp)
 
     return jax.jit(overlay_emit)
 
@@ -1000,9 +1045,9 @@ def overlay_measures(
     / ``left_area`` / ``right_area`` (default: the raw intersection
     area); ``st_intersection_area`` and ``st_overlap_fraction`` are the
     canned frontends. Candidate generation runs on device as a sorted
-    segment equi-join over the prep's resident cell columns, the
-    measures as ONE fused program per ``(tree-hash, buckets, index,
-    mesh)`` signature — warm it with :func:`warmup_overlay` before
+    segment equi-join over the prep's resident rank column and run
+    offsets, the measures as ONE fused program per ``(tree-hash,
+    buckets, index, mesh)`` signature — warm it with :func:`warmup_overlay` before
     `expr.compile.freeze`.
 
     One call records, under its root span ``overlay.call`` (the pair's
@@ -1010,8 +1055,13 @@ def overlay_measures(
     ``raw_candidates``, ``pairs``, ``bucket``, ``clip_rows``,
     ``swapped_rows``, ``fan_rows``, ``fan_triangles``,
     ``host_overridden`` and its split ``host_band`` / ``host_shape`` /
-    ``host_spill`` / ``host_cancel``): ``overlay.count`` (launch and the blocking read of
-    the count), ``overlay.emit`` (launch and pull of the rows),
+    ``host_spill`` / ``host_cancel``): ``overlay.count`` (the launch of the
+    program that READS every left row's span of right rows — two gathers
+    through the dense ranks, ``spans="rank"`` over ``ranks`` distinct
+    cells, the call's only pass over the cell columns — and the blocking
+    read of their total; the spans stay on the device), ``overlay.emit``
+    (the launch that turns those spans into candidate rows at the pair
+    bucket the total picked, and the pull of the rows),
     ``overlay.glue`` (`pair_glue`, `pair_routes`), ``overlay.launch``,
     ``overlay.pull`` and ``overlay.host_override``.
 
@@ -1073,19 +1123,20 @@ def overlay_measures(
             with _telemetry.timed("overlay_stage", stage="candidates"):
 
                 def device_candidates():
-                    with _trace.span("overlay.count"):
+                    with _trace.span(
+                        "overlay.count", spans="rank", ranks=prep.ranks,
+                    ):
                         count = _count_program()
-                        args = (lt_["cells"], rt_["cells"], L.n)
+                        args = (lt_["rank"], rt_["roff"], L.n)
                         _register_stages(count, args, L.bucket)
-                        total = int(count(*args))
+                        dtotal, dlo, dcnt = count(*args)
+                        total = int(dtotal)
                     Pb, emit_limit, overflow = pair_plan(
                         total, pair_cap
                     )
                     with _trace.span("overlay.emit", bucket=Pb):
                         emit = _emit_program(Pb)
-                        args = (
-                            lt_["cells"], rt_["cells"], L.n, emit_limit
-                        )
+                        args = (dlo, dcnt, emit_limit)
                         _register_stages(emit, args, Pb)
                         dli, dri, dvalid = emit(*args)
                         li = np.asarray(dli)

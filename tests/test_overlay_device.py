@@ -193,6 +193,29 @@ def test_mesh_sharded_bit_identity():
     _assert_bitwise(meshed, single)
 
 
+def test_the_meshed_program_is_handed_the_resident_columns():
+    """`_host_tables` gives the meshed lane, as host arrays, exactly what
+    the single-device lane keeps resident: the left side's dense ranks,
+    the right side's run offsets, the fused program's tables — and no
+    int64 cell column on either."""
+    from mosaic_tpu.sql import overlay as ov
+
+    left = _squares([(i * 2.9, 0.2, 2.7, 2.7) for i in range(3)])
+    right = _squares([(i * 2.9 + 0.9, 0.6, 2.4, 2.4) for i in range(3)])
+    grid = _grid()
+    prep = prepare_overlay(tessellate(left, grid, RES),
+                           tessellate(right, grid, RES), left, right, grid, RES)
+    acc = np.dtype(prep.acc_name)
+    for side, column in ((prep.left, "rank"), (prep.right, "roff")):
+        host = ov._host_tables(side, acc)
+        assert list(host) == list(side.dev) and column in host
+        assert "cells" not in host
+        for name, table in host.items():
+            resident = np.asarray(side.dev[name])
+            assert table.dtype == resident.dtype, name
+            assert np.array_equal(table, resident), name
+
+
 def test_function_frontends():
     from mosaic_tpu.functions.geometry import (
         st_intersection_area,

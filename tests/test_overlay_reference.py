@@ -311,10 +311,173 @@ def test_the_programs_register_their_stages(world):
         {"jit_overlay_count", "jit_overlay_emit", "jit_overlay_measure"}
     )
     assert set(tables["jit_overlay_count"].values()) == {"overlay.spans"}
-    assert set(tables["jit_overlay_emit"].values()) >= {
-        "overlay.spans", "overlay.emit"}
+    # the emission has its spans from the count program: it holds none
+    emit = set(tables["jit_overlay_emit"].values())
+    assert "overlay.emit" in emit and "overlay.spans" not in emit
     assert set(tables["jit_overlay_measure"].values()) >= {
         "overlay.gather", "overlay.clip", "overlay.fan", "overlay.fold"}
+
+
+def _rank_form(lcells, rcells):
+    """The dense-rank form of two sorted, sentinel-padded cell columns, as
+    `prepare_overlay` lays it out: ranks among the pair's distinct cells,
+    then the right sentinel, then the left; the right column as its runs'
+    offsets over them."""
+    real = np.concatenate([
+        lcells[lcells != K.LEFT_PAD_CELL], rcells[rcells != K.RIGHT_PAD_CELL],
+    ])
+    ranked = np.concatenate(
+        [np.unique(real), [K.RIGHT_PAD_CELL, K.LEFT_PAD_CELL]])
+    rank = np.searchsorted(ranked, lcells).astype(np.int32)
+    roff = K.run_offsets(
+        np.searchsorted(ranked, rcells),
+        ov.RANK_LADDER.bucket_for(ranked.shape[0] + 1),
+    )
+    return rank, roff
+
+
+def _column(cells, bucket, pad):
+    out = np.full(bucket, pad, np.int64)
+    out[: len(cells)] = np.sort(np.asarray(cells, np.int64))
+    return out
+
+
+def _seeded_columns(case):
+    """``(lcells, rcells, n_left)`` of one case of the span property."""
+    rng = np.random.default_rng(43)
+    big = 5_000_000_000_000  # cell ids past 32 bits, as BNG's are
+    if case == "cells_on_one_side_only":  # evens left, odds right: no pair
+        left, right = big + 2 * np.arange(70), big + 2 * np.arange(90) + 1
+    elif case == "runs_of_duplicates":
+        left = big + rng.integers(0, 40, 300)
+        right = big + rng.integers(0, 40, 500)
+    elif case == "an_empty_right_table":
+        left, right = big + rng.integers(0, 40, 100), np.zeros(0, np.int64)
+    elif case == "n_left_under_the_bucket":  # 3 rows of a bucket of 64
+        left, right = big + np.array([5, 5, 9]), big + rng.integers(0, 12, 200)
+    elif case == "full_buckets_no_pad_row":
+        left, right = big + rng.integers(0, 30, 128), big + rng.integers(0, 30, 64)
+    elif case == "a_random_mix":
+        left = big + rng.integers(0, 4000, 3000)
+        right = big + rng.integers(1000, 6000, 2000)
+    else:
+        raise ValueError(case)
+    Lb = ov.TABLE_LADDER.bucket_for(max(len(left), 1))
+    Rb = ov.TABLE_LADDER.bucket_for(max(len(right), 1))
+    return (_column(left, Lb, K.LEFT_PAD_CELL),
+            _column(right, Rb, K.RIGHT_PAD_CELL), len(left))
+
+
+@pytest.mark.parametrize("xp_name", ["numpy", "jax"])
+@pytest.mark.parametrize("case", [
+    "cells_on_one_side_only", "runs_of_duplicates", "an_empty_right_table",
+    "n_left_under_the_bucket", "full_buckets_no_pad_row", "a_random_mix",
+    "districts", "flood",
+])
+def test_the_spans_read_are_the_spans_searched(world, case, xp_name):
+    """`rank_spans` over the dense ranks and the right column's run offsets
+    gives `pair_spans`' ``(lo, cnt)`` over the raw int64 ids, integer for
+    integer, the pad rows' too (both sentinels have their rank)."""
+    import jax.numpy as jnp
+
+    if case in world["themes"]:  # the form as `prepare_overlay` made it
+        prep = world["themes"][case][2]
+        L, R = prep.left, prep.right
+        lcells, rcells, n_left, rank, roff = L.cells, R.cells, L.n, L.rank, R.roff
+        again = _rank_form(lcells, rcells)
+        assert np.array_equal(rank, again[0]) and np.array_equal(roff, again[1])
+        assert prep.ranks == np.unique(np.concatenate(
+            [lcells[:L.n], rcells[:R.n]])).shape[0]
+        assert rank.dtype == roff.dtype == np.int32 and L.roff is None
+        assert set(L.dev) - set(R.dev) == {"rank"}
+        assert set(R.dev) - set(L.dev) == {"roff"}
+    else:
+        lcells, rcells, n_left = _seeded_columns(case)
+        rank, roff = _rank_form(lcells, rcells)
+    want_lo, want_cnt = K.pair_spans(lcells, rcells, n_left, xp=np)
+    xp = np if xp_name == "numpy" else jnp
+    lo, cnt = K.rank_spans(xp.asarray(rank), xp.asarray(roff), n_left, xp=xp)
+    assert np.array_equal(np.asarray(lo), want_lo)
+    assert np.array_equal(np.asarray(cnt), want_cnt)
+    assert (np.asarray(cnt)[n_left:] == 0).all()
+    if case not in ("cells_on_one_side_only", "an_empty_right_table"):
+        assert want_cnt.sum() > 0
+    for n in (0, n_left // 2):  # fewer live rows than the column holds
+        lo, cnt = K.rank_spans(rank, roff, n, xp=np)
+        w_lo, w_cnt = K.pair_spans(lcells, rcells, n, xp=np)
+        assert np.array_equal(lo, w_lo) and np.array_equal(cnt, w_cnt)
+
+
+@pytest.mark.parametrize("pair_cap", [None, 1000])
+@pytest.mark.parametrize("name", ["districts", "flood"])
+def test_the_device_candidates_are_the_numpy_twins(world, name, pair_cap):
+    """The device lane's two programs — spans read once, emission from
+    them — give the twin's ``li, ri, valid`` (spans searched, on the raw
+    ids), capped or not."""
+    prep = world["themes"][name][2]
+    L, R = prep.left, prep.right
+    total, lo, cnt = ov._count_program()(L.dev["rank"], R.dev["roff"], L.n)
+    assert int(total) == int(K.pair_count(L.cells, R.cells, L.n, xp=np)) > 1000
+    Pb, emit_limit, overflow = ov.pair_plan(int(total), pair_cap)
+    assert (overflow > 0) == (pair_cap is not None)
+    li, ri, valid = ov._emit_program(Pb)(lo, cnt, emit_limit)
+    w_li, w_ri, w_valid = K.emit_pairs(
+        L.cells, R.cells, L.n, emit_limit, Pb, xp=np)
+    for got, want in ((li, w_li), (ri, w_ri), (valid, w_valid)):
+        got = np.asarray(got)
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+    assert int(np.asarray(valid).sum()) == emit_limit
+
+
+def test_a_call_reads_its_spans_once_and_the_emission_cannot_search(
+        world, monkeypatch):
+    """One count program a call, whose device arrays ``lo`` and ``cnt``
+    are the emission's only tables: no cell column, no rank column, and
+    nothing pulled to the host between the two but the total."""
+    import functools
+    import inspect
+
+    import jax
+
+    polygons, col, prep = world["themes"]["districts"]
+    assert list(inspect.signature(ov._emit_program(64)).parameters) == [
+        "lo", "cnt", "emit_limit"]
+    seen = {"count": [], "emit": []}
+
+    def spy(kind, make):
+        def made(*key):
+            fn = make(*key)
+
+            @functools.wraps(fn)
+            def run(*args):
+                out = fn(*args)
+                seen[kind].append((args, out))
+                return out
+
+            run.lower = fn.lower  # `obs.stages` lowers what is registered
+            return run
+        return made
+
+    monkeypatch.setattr(ov, "_count_program", spy("count", ov._count_program))
+    monkeypatch.setattr(ov, "_emit_program", spy("emit", ov._emit_program))
+    with telemetry.capture() as events:
+        ans = ov.overlay_measures(world["pcol"], col, world["grid"], RES,
+                                  E.overlap_fraction(), prep=prep)
+    assert ans.lane == "device" and not ans.degraded
+    ((count_args, (_total, lo, cnt)),) = seen["count"]
+    ((emit_args, _rows),) = seen["emit"]
+    assert count_args[0] is prep.left.dev["rank"]
+    assert count_args[1] is prep.right.dev["roff"]
+    assert emit_args[0] is lo and emit_args[1] is cnt
+    assert isinstance(lo, jax.Array) and isinstance(cnt, jax.Array)
+    assert isinstance(emit_args[2], int) and len(emit_args) == 3
+    (span,) = [e for e in events
+               if e.get("event") == "span" and e.get("name") == "overlay.count"]
+    assert span["spans"] == "rank" and span["ranks"] == prep.ranks
+    # the resident tables hold no int64 cell column any more
+    for side in (prep.left, prep.right):
+        assert "cells" not in side.dev
+        assert all(np.dtype(a.dtype) != np.int64 for a in side.dev.values())
 
 
 def test_rows_that_cancel_are_the_host_lanes(world, monkeypatch):
