@@ -34,11 +34,19 @@ a query or once a pair. Two ways to evaluate an iteration's pairs:
   (query, cell) keys are deduplicated and held against the cells the
   query has met (:func:`block_chunks_multi`), and a chunk's candidates
   are evaluated against the query's edges (:func:`poly_block_topk_prog`).
+  An iteration of this lane is a software pipeline over **slabs** of its
+  active queries (:func:`slab_bounds`): slab ``s`` is expanded and its
+  launches enqueued, nothing pulled; only then are the launches enqueued
+  before it pulled and folded, so the host expands and merges while the
+  device works. What a slab leaves past its last full launch is carried
+  into the next slab's first launch, so the launches are the one-slab
+  schedule's, cut for cut, and so are the answers, bit for bit.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import numpy as np
 
@@ -49,6 +57,16 @@ from ..runtime.errors import DegradedResult
 from .index import KNNIndex, edge_terms, expand_ranges
 
 _NO_ID = np.iinfo(np.int32).max
+#: ring keys a slab of the block lane's iteration searches: the host's
+#: expand costs what its keys cost (the probe of the occupied cells), so
+#: this is the host work that is not yet under device work when an
+#: iteration begins, and the grain at which the two alternate after that.
+#: An iteration under two slabs' worth is one slab: the schedule of
+#: before PR 45, which late iterations, served queries and small tables
+#: keep. The chip chose it: a 100,000-landmark call takes 738 ms at
+#: 65,536, 699 at 131,072 and 717-729 at 262,144, where one slab an
+#: iteration takes 861 (PERF.md section 6, PR 45)
+SLAB_KEYS = 1 << 17
 
 
 @dataclasses.dataclass
@@ -63,6 +81,10 @@ class RingResult:
     pairs_padded: int = 0  # slots the device evaluated for them
     launches: int = 0  # device launches of the distance programs
     rows_pulled: int = 0  # block lane: answer rows pulled, padded to a rung
+    slabs: int = 0  # block lane: slabs of queries expanded, all iterations
+    #: block lane: host seconds of ``knn.expand`` and ``knn.scatter`` spent
+    #: while launches of the same iteration were enqueued and not yet pulled
+    hidden_s: float = 0.0
     degraded: "DegradedResult | None" = None
     #: what the evaluator counted besides (polygon queries: `edge_pairs`, ...)
     counters: dict = dataclasses.field(default_factory=dict)
@@ -277,8 +299,9 @@ def block_chunks_multi(pb, active, owner, ring, met, many):
     unique, and those in ``met`` — sorted keys of the cells the queries
     ``many`` marks (those of more than one seed) met in earlier
     iterations — are dropped: a cell is counted, and its blocks listed,
-    once a query. Returns ``(cq, blk, fresh, met)`` with ``met`` grown by
-    this iteration's keys."""
+    once a query. Returns ``(cq, blk, fresh, new)``, ``new`` the keys
+    ``met`` grows by (ascending; the caller unites them once an iteration:
+    slabs of one iteration hold other queries, so none reads another's)."""
     m = ring.shape[1]
     u = pb.ucells.size
     flat = ring.ravel()
@@ -289,9 +312,35 @@ def block_chunks_multi(pb, active, owner, ring, met, many):
         at = np.minimum(np.searchsorted(met, key), met.size - 1)
         key = key[met[at] != key]
     query, pos = key // u, key % u
-    met = np.union1d(met, key[many[query]])
     own = np.searchsorted(active, query)
-    return (*_cell_chunks(pb, own, pos, active.size), met)
+    return (*_cell_chunks(pb, own, pos, active.size), key[many[query]])
+
+
+def chunk_pairs(kx: KNNIndex, own: np.ndarray, blk: np.ndarray):
+    """Every (query, candidate) pair of chunks ``(own, blk)`` — a chunk's
+    query and its block — from the CSR alone: a cell's candidates fill its
+    blocks in row order (`index.PointBlocks`), so block ``b`` of cell ``u``
+    is a run of the cell's CSR rows. What the host oracle answers where
+    the device could not."""
+    pb = kx.points
+    u = np.searchsorted(pb.blk_start, blk, side="right") - 1
+    at = (blk - pb.blk_start[u]) * pb.width
+    n = np.minimum(pb.width, pb.count[u] - at)
+    lo = np.searchsorted(kx.cells, pb.ucells[u]) + at
+    return np.repeat(own, n), kx.rows[expand_ranges(lo, n)]
+
+
+def slab_bounds(keys: np.ndarray) -> np.ndarray:
+    """Where an iteration's active queries are cut into slabs: ``keys[i]``
+    is the ring keys query ``i`` will search, a slab takes about
+    `SLAB_KEYS` of them, and an iteration under two slabs' worth is one
+    slab. (s + 1,) ascending bounds into the active set."""
+    cum = np.cumsum(keys)
+    n = int(cum[-1]) // SLAB_KEYS
+    if n < 2:
+        return np.array([0, keys.size])
+    cuts = np.searchsorted(cum, cum[-1] * np.arange(1, n) // n) + 1
+    return np.unique(np.r_[0, cuts, keys.size])
 
 
 def launch_heads(cq: np.ndarray, cap: int) -> np.ndarray:
@@ -355,13 +404,18 @@ def ring_search(
     (CSR ``seed_ptr`` / ``seed_cells``; a point has one).
 
     ``pair_distances(qi, ci) -> (P,) f64`` evaluates fresh pairs (it may
-    return a `DegradedResult`); ``block_topk(active, cq, blk, steps, ring)
-    -> (hq, hd, hi, padded, launches, rows)`` evaluates (query, block)
-    chunks on the device and hands back the launches' head rows, their
-    owners and how many rows it pulled for them (see :func:`fold_heads`)
-    — given, it is the lane taken; degraded, it
-    returns a `DegradedResult` of (P, 3) rows ``(query, candidate,
-    distance)`` the host oracle answered. ``guard(stage, fn)`` runs the
+    return a `DegradedResult`). ``block_topk(active, cq, blk, carry, last)``
+    — given, it is the lane taken — ENQUEUES (query, block) chunks on the
+    device and pulls nothing: ``cq`` indexes the iteration's ``active``,
+    ``carry`` is what the call before it in this iteration left unlaunched
+    (None at first), and unless ``last`` it launches whole top-rung cuts
+    only and carries the rest. It returns a pending handle: ``padded``,
+    ``launches``, ``rows`` (what it enqueued), ``carry``, and ``pull(**span
+    fields) -> (hq, hd, hi)``, the blocking pull of the launches' head rows
+    and their owners (see :func:`fold_heads`; rows of one owner adjacent).
+    Either half, degraded, returns a `DegradedResult` of (P, 3) rows
+    ``(query, candidate, distance)`` instead: the host oracle's answer for
+    every chunk that half was handed. ``guard(stage, fn)`` runs the
     pure stages (``knn.expand``, ``knn.scatter``: the frontend's failure
     domains). ``exact=False`` rests a query at k matches, and
     ``early_stop`` rounds without a new match or a newly filled query end
@@ -384,6 +438,7 @@ def ring_search(
     seed_keys, seed_margin = seed_keys or kx.probe_keys(seed_cells)
     # block lane, queries of several seeds: the cells they have met
     many = nseed > 1
+    multi = bool(many.any())
     met = np.zeros(0, dtype=np.int64)
     stable, prev = 0, (n, 0)
 
@@ -398,84 +453,160 @@ def ring_search(
             return need & (reach < out.dist[:, k - 1])
         return need & (out.cid[:, k - 1] < 0)
 
+    def ring_of(sub, it):
+        """``(owner, ring)``: iteration ``it``'s ring keys of the queries
+        ``sub``, a row a seed, and the seed's place in ``sub``."""
+        owner = np.repeat(np.arange(sub.size), nseed[sub])
+        at = expand_ranges(seed_ptr[sub], nseed[sub])
+        return owner, kx.ring_keys(
+            seed_cells[at], seed_keys[at],
+            None if seed_margin is None else seed_margin[at], it,
+        )
+
+    def run_blocks(it, active) -> bool:
+        """One iteration of the block lane, slab after slab of ``active``:
+        pass ``s`` expands slab ``s`` and enqueues its launches, then pulls
+        and folds what pass ``s - 1`` enqueued; the pass after the last
+        only pulls and folds. Every step is pure until it commits, slab by
+        slab (slabs hold other queries). Returns whether a chunk was met."""
+        nonlocal met
+        # a seed searches the lattice ring's positions; off a lattice the
+        # ring cells are the grid's own device ops, a fixed cost a call
+        # whatever the seeds: no keys counted, one slab an iteration
+        width = kx.index_system.lattice_ring(it).size if kx.lattice else 0
+        bounds = slab_bounds(nseed[active] * width)
+        nslab = bounds.size - 1
+        out.slabs += nslab
+        # rows that may hold something: all after iteration 1; in it, those
+        # an earlier pull of this iteration (or the oracle) wrote
+        touched = np.full(active.size, it > 1)
+        flying = carry = None
+        grown, chunks, under = [], 0, 0.0
+
+        def hidden(t0):
+            """The seconds since ``t0``, where launches are in flight."""
+            if flying is None or not flying.launches:
+                return 0.0
+            return time.perf_counter() - t0
+
+        def merge_oracle(rows):
+            """Fold a degraded half's (query, candidate, distance) triples."""
+            if out.degraded is None:  # (an array: it has no truth value)
+                out.degraded = rows
+            rows = np.asarray(rows)
+            qi, ci = rows[:, 0].astype(np.int64), rows[:, 1].astype(np.int64)
+            keep = rows[:, 2] <= thr
+            with _trace.span("knn.scatter", iteration=it, pairs=int(qi.size)):
+                out.dist, out.cid = guard("knn.scatter", lambda: merge_topk(
+                    out.dist, out.cid, qi[keep], ci[keep], rows[keep, 2], k))
+            touched[np.searchsorted(active, np.unique(qi))] = True
+
+        def fold(hq, hd, hi):
+            # (rows of one owner are adjacent: no sort finds the owners)
+            first = np.r_[True, hq[1:] != hq[:-1]]
+            uq = hq[first]
+            fd, fi = fold_heads(np.cumsum(first) - 1, hd, hi, uq.size, k)
+            m = np.flatnonzero(touched[uq])
+            if m.size:
+                at = active[uq[m]]
+                fd[m], fi[m] = _merge_rows(
+                    out.dist[at], out.cid[at], fd[m], fi[m], k)
+            return uq, fd, fi
+
+        for s in range(nslab + 1):
+            todo, pairs, cq = None, 0, ()
+            if s < nslab:
+                lo = int(bounds[s])
+                sub = active[lo : bounds[s + 1]]
+
+                def expand():
+                    owner, ring = ring_of(sub, it)
+                    if multi:
+                        return block_chunks_multi(
+                            kx.points, sub, owner, ring, met, many)
+                    return block_chunks(kx.points, ring)
+
+                t0 = time.perf_counter()
+                with _trace.span(
+                    "knn.expand", iteration=it, queries=int(sub.size)
+                ), _telemetry.timed(
+                    "knn_stage", stage="expand", iteration=it,
+                    queries=int(sub.size),
+                ):
+                    cq, blk, fresh, *new = guard("knn.expand", expand)
+                under += hidden(t0)
+                grown += new
+                seen_count[sub] += fresh
+                pairs = int(fresh.sum())
+                out.pairs += pairs
+                chunks += int(cq.size)
+                last = s == nslab - 1
+                if cq.size or (last and carry is not None):
+                    todo = lo + cq, blk, carry, last
+            if todo is None and flying is None:
+                continue
+            got = rows = None
+            with _trace.span(
+                "knn.distance", iteration=it, pairs=pairs, chunks=len(cq),
+            ), _telemetry.timed("knn_stage", stage="distance", pairs=pairs):
+                if todo is not None:
+                    got = block_topk(active, *todo)
+                if flying is not None:
+                    rows = flying.pull(
+                        iteration=it, slabs=nslab, hidden_s=under)
+                    out.hidden_s += under
+                    under = 0.0
+            flying = None
+            if isinstance(got, DegradedResult):
+                # the host oracle answered the slab and the carry
+                merge_oracle(got)
+                carry = None
+            elif got is not None:
+                out.pairs_padded += got.padded
+                out.launches += got.launches
+                out.rows_pulled += got.rows
+                flying, carry = got, got.carry
+            if isinstance(rows, DegradedResult):
+                merge_oracle(rows)
+            elif rows is not None and rows[0].size:
+                t0 = time.perf_counter()
+                with _trace.span(
+                    "knn.scatter", iteration=it, rows=int(rows[0].size)
+                ), _telemetry.timed(
+                    "knn_stage", stage="scatter", rows=int(rows[0].size)
+                ):
+                    uq, fd, fi = guard("knn.scatter", lambda: fold(*rows))
+                under += hidden(t0)
+                out.dist[active[uq]], out.cid[active[uq]] = fd, fi
+                touched[uq] = True
+        if grown:
+            met = np.union1d(met, np.concatenate(grown))
+        return chunks > 0
+
     for it in range(1, max_iterations + 1):
         active = np.flatnonzero(owed(it))
         if not active.size:
             return out
         out.iterations = it
-
-        def expand():
-            # pure: the state commits after the guarded call returns, so
-            # a transient-fault retry re-reads identical state
-            owner = np.repeat(np.arange(active.size), nseed[active])
-            at = expand_ranges(seed_ptr[active], nseed[active])
-            ring = kx.ring_keys(
-                seed_cells[at], seed_keys[at],
-                None if seed_margin is None else seed_margin[at], it,
-            )
-            if block_topk is not None and many.any():
-                return ring, block_chunks_multi(
-                    kx.points, active, owner, ring, met, many
-                )
-            if block_topk is not None:
-                return ring, block_chunks(kx.points, ring)
-            qi, ci = ring_pairs(kx, active[owner], ring)
-            keys = np.unique(qi * kx.n + ci)
-            keys = keys[~np.isin(keys, seen_keys, assume_unique=True)]
-            return ring, keys
-
-        with _trace.span("knn.expand", iteration=it, queries=int(active.size)), \
-                _telemetry.timed(
-                    "knn_stage", stage="expand", iteration=it,
-                    queries=int(active.size),
-                ):
-            ring, found = guard("knn.expand", expand)
-
         if block_topk is not None:
-            cq, blk, fresh, *grown = found
-            if grown:
-                met = grown[0]
-            seen_count[active] += fresh
-            pairs = int(fresh.sum())
-            if not cq.size:
+            if not run_blocks(it, active):
                 continue
-            nchunk = np.bincount(cq, minlength=active.size)
-            steps = int(max(int(nchunk.max()) - 1, 0)).bit_length()
-            with _trace.span(
-                "knn.distance", iteration=it, pairs=pairs,
-                chunks=int(cq.size),
-            ), _telemetry.timed("knn_stage", stage="distance", pairs=pairs):
-                got = block_topk(active, cq, blk, steps, ring)
-            if isinstance(got, DegradedResult):
-                # the host oracle answered: (qi, ci, d) triples
-                if out.degraded is None:  # (an array: it has no truth value)
-                    out.degraded = got
-                rows = np.asarray(got)
-                qi, ci = rows[:, 0].astype(np.int64), rows[:, 1].astype(np.int64)
-                d = rows[:, 2]
-                keep = d <= thr
-                with _trace.span("knn.scatter", iteration=it, pairs=pairs):
-                    out.dist, out.cid = guard("knn.scatter", lambda: merge_topk(
-                        out.dist, out.cid, qi[keep], ci[keep], d[keep], k))
-                out.pairs += pairs
-                continue
-            hq, hd, hi, padded, launches, rows = got
-            out.pairs += pairs
-            out.pairs_padded += padded
-            out.launches += launches
-            out.rows_pulled += rows
-
-            def scatter():
-                fd, fi = fold_heads(hq, hd, hi, active.size, k)
-                if it == 1:
-                    return fd, fi
-                return _merge_rows(out.dist[active], out.cid[active], fd, fi, k)
-
-            with _trace.span("knn.scatter", iteration=it, pairs=pairs), \
-                    _telemetry.timed("knn_stage", stage="scatter", pairs=pairs):
-                out.dist[active], out.cid[active] = guard("knn.scatter", scatter)
         else:
-            keys = found
+            def expand():
+                # pure: the state commits after the guarded call returns, so
+                # a transient-fault retry re-reads identical state
+                owner, ring = ring_of(active, it)
+                qi, ci = ring_pairs(kx, active[owner], ring)
+                keys = np.unique(qi * kx.n + ci)
+                return keys[~np.isin(keys, seen_keys, assume_unique=True)]
+
+            with _trace.span(
+                "knn.expand", iteration=it, queries=int(active.size)
+            ), _telemetry.timed(
+                "knn_stage", stage="expand", iteration=it,
+                queries=int(active.size),
+            ):
+                keys = guard("knn.expand", expand)
             seen_keys = np.union1d(seen_keys, keys)
             qi, ci = keys // kx.n, keys % kx.n
             seen_count += np.bincount(qi, minlength=n)

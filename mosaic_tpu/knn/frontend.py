@@ -32,7 +32,10 @@ resident and answers queries with the serving discipline of
   flagged :class:`~mosaic_tpu.runtime.errors.DegradedResult` — never
   wrong, never dropped. Expand and scatter are pure functions whose
   results commit only after the guarded call returns, so retries are
-  idempotent.
+  idempotent. The block lane's evaluation has two guarded halves
+  (:meth:`KNNFrontend._enqueue`): the enqueue of a slab's launches and,
+  a slab later, their pull; each degrades to the oracle for its own
+  chunks alone.
 
 Lanes
 -----
@@ -97,6 +100,19 @@ BLOCK_LADDER = BucketLadder(min_bucket=1024, max_bucket=1 << 16, growth=4)
 #: (a building layer's 2% of 24-80 vertices) do not pad the rest; past the
 #: top rung the host answers
 VERTEX_LADDER = BucketLadder(min_bucket=8, max_bucket=128, growth=4)
+
+
+@dataclasses.dataclass
+class _Pending:
+    """Block launches enqueued and not yet pulled (`KNNFrontend._enqueue`):
+    what `engine.ring_search` holds while it expands the next slab."""
+
+    padded: int  # slots the launches evaluate
+    launches: int
+    rows: int  # head rows the pull brings, padded to their rungs
+    #: the chunks past the last whole top-rung cut, for the next call
+    carry: object
+    pull: object  # (**span fields) -> (hq, hd, hi) | DegradedResult
 
 
 @dataclasses.dataclass
@@ -457,13 +473,14 @@ class KNNFrontend:
 
     # ------------------------------------------------------- ring lane
 
-    def _launch_chunks(self, prog, kind, k, lead, cols, steps, thr, **fields):
+    def _launch_chunks(self, prog, kind, k, lead, cols, thr, **fields):
         """Enqueue one chunk list: cut at the block ladder's top rung, each
         cut padded to a rung and handed to ``prog`` (after the blocks and
         ``lead``: the per-chunk columns ``cols``, ``(column, pad value)``
         with the chunks' owner last) and, behind it, to the heads program
         with the slots where a query begins in the cut (`engine.launch_heads`),
-        padded to the rung their number takes. Nothing is pulled. Returns
+        padded to the rung their number takes. A cut folds as many doublings
+        as its own longest run of one owner asks. Nothing is pulled. Returns
         ``(outs, head, padded slots, head rows)``, an entry of ``outs``
         being ``(heads, the head rows' (d, id))``."""
         pb = self.kx.points
@@ -478,6 +495,8 @@ class KNNFrontend:
             h0, h1 = np.searchsorted(head, (c0, c0 + m))
             nh = int(h1 - h0)
             h = BLOCK_LADDER.bucket_for(nh)
+            at = (head[h0:h1] - c0).astype(np.int32)
+            steps = int(np.diff(at, append=m).max() - 1).bit_length()
             args = (
                 pb.x, pb.y, pb.rid, *lead,
                 *(
@@ -487,7 +506,7 @@ class KNNFrontend:
                 thr, np.int32(steps),
             )
             # (a pad head re-reads slot 0 and is cut off the pull)
-            at = np.pad((head[h0:h1] - c0).astype(np.int32), (0, h - nh))
+            at = np.pad(at, (0, h - nh))
             if self._note(f"{kind}.k{k}", b):
                 # how to lower this rung again, for a device
                 # trace's stage table (nothing is lowered here)
@@ -499,7 +518,12 @@ class KNNFrontend:
                 **fields,
             ):
                 d, gid = prog(*args, k=k)
-                outs.append((nh, heads_prog(d, gid, at)))
+                answer = heads_prog(d, gid, at)
+                # the rows set out for the host behind their own launch,
+                # not behind whatever is enqueued before they are asked for
+                for r in answer:
+                    r.copy_to_host_async()
+                outs.append((nh, answer))
             if self._note(f"heads.k{k}.b{b}", h):
                 _stages.register(
                     heads_prog, _stages.shapes_of((d, gid, at)), rows=h,
@@ -509,14 +533,51 @@ class KNNFrontend:
         return outs, head, padded, rows
 
     @staticmethod
-    def _pull_heads(outs, rows: int, chunks: int):
+    def _pull_heads(outs, rows: int, chunks: int, **fields):
         """The blocking pull of every enqueued launch's head rows."""
         with _trace.span(
-            "knn.pull", launches=len(outs), rows=rows, chunks=chunks,
+            "knn.pull", launches=len(outs), rows=rows, chunks=chunks, **fields,
         ):
             hd = np.concatenate([np.asarray(o[0])[:n] for n, o in outs])
             hi = np.concatenate([np.asarray(o[1])[:n] for n, o in outs])
         return hd, hi
+
+    def _enqueue(self, launch, oracle, default_s, carry):
+        """The two halves of one block evaluation (`engine.ring_search`),
+        each under the ``knn.distance`` failure domain. ``launch() ->
+        (outs, hq, padded, rows, host rows, chunks)`` enqueues and pulls
+        nothing; the handle's ``pull`` waits for those launches (a retry of
+        it launches them again) and appends the rows the host answered
+        meanwhile. Past the retry budget ``oracle(launched)`` answers in
+        f64 on the host: every chunk the enqueue was handed, the carried
+        ones too, or (``launched``) those whose launches the pull gave up."""
+        got = guarded_call(
+            "knn.distance", launch, default_s=default_s,
+            fallback=lambda: oracle(False),
+        )
+        if isinstance(got, DegradedResult):
+            return got
+        outs, hq, padded, rows, _host, chunks = got
+        first = [got]
+
+        def pull(**fields):
+            def attempt():
+                sent = first.pop() if first else launch()
+                o, mine = sent[0], sent[4]
+                parts = [self._pull_heads(o, rows, chunks, **fields)] if o else []
+                if mine is not None:
+                    parts.append(mine)
+                if not parts:
+                    return hq, None, None
+                return (hq, np.concatenate([d for d, _ in parts]),
+                        np.concatenate([i for _, i in parts]))
+
+            return guarded_call(
+                "knn.distance", attempt, default_s=default_s,
+                fallback=lambda: oracle(True),
+            )
+
+        return _Pending(padded, len(outs), rows, carry, pull)
 
     def _block_topk(self, qs64, qsd, k, thr, default_s):
         """The engine's block evaluator (`engine.ring_search`): the
@@ -524,45 +585,56 @@ class KNNFrontend:
         the block ladder's top rung and padded to a rung. With each launch
         go the slots where a query begins in it (`engine.launch_heads`),
         padded to the rung their number takes; the heads program gathers
-        those rows on the device, and only they are pulled, after every
-        launch is enqueued. Past the retry budget the iteration's pairs
-        are made from the CSR and answered by the f64 host oracle."""
+        those rows on the device, and only they are pulled, by the handle
+        this returns, when the engine asks. Unless ``last``, what lies past
+        the last whole top-rung cut is carried to the next call. Past the
+        retry budget the chunks' pairs are made from the CSR and answered
+        by the f64 host oracle."""
         import jax.numpy as jnp
 
         kx, pb = self.kx, self.kx.points
         prog = _engine.block_topk_prog()
         thr = jnp.asarray(thr, dtype=self._dtype)
+        cap = BLOCK_LADDER.max_bucket
 
-        def evaluate(active, cq, blk, steps, ring):
-            def device():
-                qx, qy = qsd[active[cq], 0], qsd[active[cq], 1]
+        def evaluate(active, cq, blk, carry=None, last=True):
+            if carry is not None:
+                cq = np.concatenate([carry[0], cq])
+                blk = np.concatenate([carry[1], blk])
+            cut = cq.size if last else cq.size - cq.size % cap
+
+            def launch():
+                land = active[cq[:cut]]
                 outs, head, padded, rows = self._launch_chunks(
                     prog, "blocks", k, (),
-                    ((qx, 0), (qy, 0), (blk.astype(np.int32), pb.n_blocks),
-                     (cq.astype(np.int32), -1)),
-                    steps, thr,
+                    ((qsd[land, 0], 0), (qsd[land, 1], 0),
+                     (blk[:cut].astype(np.int32), pb.n_blocks),
+                     (cq[:cut].astype(np.int32), -1)),
+                    thr,
                 )
-                hd, hi = self._pull_heads(outs, rows, int(cq.shape[0]))
-                return cq[head], hd, hi, padded, len(outs), rows
+                return outs, cq[head], padded, rows, None, cut
 
-            def oracle():
-                qi, ci = _engine.ring_pairs(kx, active, ring)
+            def oracle(launched):
+                sl = slice(0, cut if launched else None)
+                qi, ci = _engine.chunk_pairs(kx, active[cq[sl]], blk[sl])
                 return np.column_stack(
                     [qi, ci, host_pair_distances(qs64, kx, qi, ci)]
                 )
 
-            return guarded_call(
-                "knn.distance", device, default_s=default_s, fallback=oracle
+            return self._enqueue(
+                launch, oracle, default_s,
+                (cq[cut:], blk[cut:]) if cut < cq.size else None,
             )
 
         return evaluate
 
     def _poly_block_topk(self, rings, k, thr, default_s, tally):
         """:meth:`_block_topk` for polygon queries (`index.LandmarkRings`):
-        an iteration's chunks are split by their query's table (one edge
-        rung, a fixed number of rows), a launch holding one table, and
-        pulled together. Chunks of a query past the top rung are answered
-        here on the host, in f64; past the retry budget all of them are
+        a call's chunks are split by their query's table (one edge rung, a
+        fixed number of rows), a launch holding one table and each table
+        carrying its own remainder, and pulled together. Chunks of a query
+        past the top rung are answered here on the host, in f64, behind
+        the launches; past the retry budget all of them are
         (`host_polygon_distances`).
         ``tally`` takes what the call's span reports of the edges."""
         import jax.numpy as jnp
@@ -571,6 +643,7 @@ class KNNFrontend:
         prog = _engine.poly_block_topk_prog()
         limit = float(thr)
         thr = jnp.asarray(thr, dtype=self._dtype)
+        cap = BLOCK_LADDER.max_bucket
         if self._block_fill is None:
             nblk = np.diff(pb.blk_start)
             rank = np.arange(pb.n_blocks) - np.repeat(pb.blk_start[:-1], nblk)
@@ -579,62 +652,9 @@ class KNNFrontend:
             )
         fill = self._block_fill
 
-        def host_pairs(land, blk):
-            """Every real pair of chunks ``(land, blk)`` and its f64 distance."""
-            rid = np.asarray(pb.rid[blk.astype(np.int32)])
-            keep = rid >= 0
-            qi, ci = np.repeat(land, pb.width)[keep.ravel()], rid[keep]
-            return qi, ci, host_polygon_distances(rings, qi, kx.host.xy[ci])
-
-        def evaluate(active, cq, blk, steps, ring):
-            land = active[cq]
-            table = rings.table[land]
-            real = fill[blk]
-            mine = rings.edges[land]
-
-            def device():
-                outs, hq, padded, rows = [], [], 0, 0
-                for t in np.unique(table[table >= 0]):
-                    sel = np.flatnonzero(table == t)
-                    tab, vpad = rings.tables[t], int(rings.pads[t])
-                    o, head, p, w = self._launch_chunks(
-                        prog, f"polyblocks.v{vpad}", k, (tab,),
-                        ((blk[sel].astype(np.int32), pb.n_blocks),
-                         (rings.row[land[sel]].astype(np.int32),
-                          tab.shape[0] - 1),
-                         (cq[sel].astype(np.int32), -1)),
-                        steps, thr, vpad=vpad,
-                    )
-                    outs += o
-                    hq.append(cq[sel][head])
-                    padded += p
-                    rows += w
-                hd, hi = self._pull_heads(outs, rows, int(cq.shape[0]))
-                sel = np.flatnonzero(table < 0)
-                if sel.size:  # the host's landmarks: a row each
-                    qi, ci, d = host_pairs(cq[sel], blk[sel])
-                    keep = d <= limit
-                    fd = np.full((active.size, k), np.inf)
-                    fi = np.full((active.size, k), -1, dtype=np.int64)
-                    fd, fi = _engine.merge_topk(
-                        fd, fi, qi[keep], ci[keep], d[keep], k
-                    )
-                    uq = np.unique(cq[sel])
-                    hq.append(uq)
-                    hd = np.concatenate([hd.astype(np.float64), fd[uq]])
-                    hi = np.concatenate([
-                        hi.astype(np.int64),
-                        np.where(fi[uq] < 0, _engine._NO_ID, fi[uq]),
-                    ])
-                    padded += int(sel.size) * pb.width
-                return np.concatenate(hq), hd, hi, padded, len(outs), rows
-
-            def oracle():
-                return np.column_stack(host_pairs(land, blk))
-
-            got = guarded_call(
-                "knn.distance", device, default_s=default_s, fallback=oracle
-            )
+        def evaluate(active, cq, blk, carry=None, last=True):
+            table = rings.table[active[cq]]
+            real, mine = fill[blk], rings.edges[active[cq]]
             tally["edge_rows"] += int(mine.sum())
             tally["edge_pairs"] += int((real * mine).sum())
             # (a table of -1 reads the last pad and is masked: the host
@@ -642,7 +662,79 @@ class KNNFrontend:
             tally["edge_pairs_padded"] += int(
                 (real * np.where(table < 0, mine, rings.pads[table])).sum()
             )
-            return got
+            # a table's stream: what it carried, then this call's chunks
+            streams, rest = {}, {}
+            carry = carry or {}
+            for t in np.union1d(
+                table[table >= 0], np.fromiter(carry, np.int64)
+            ).tolist():
+                sel = np.flatnonzero(table == t)
+                tq, tb = cq[sel], blk[sel]
+                if t in carry:
+                    tq = np.concatenate([carry[t][0], tq])
+                    tb = np.concatenate([carry[t][1], tb])
+                cut = tq.size if last else tq.size - tq.size % cap
+                if cut:
+                    streams[t] = tq[:cut], tb[:cut]
+                if cut < tq.size:
+                    rest[t] = tq[cut:], tb[cut:]
+            sel = np.flatnonzero(table < 0)
+            hostq, hostb = cq[sel], blk[sel]
+
+            def host_pairs(own, blk):
+                """Every real pair of chunks ``(own, blk)``, ``own`` into
+                ``active``, and its f64 distance."""
+                oi, ci = _engine.chunk_pairs(kx, own, blk)
+                return oi, ci, host_polygon_distances(
+                    rings, active[oi], kx.host.xy[ci]
+                )
+
+            def launch():
+                outs, hq, padded, rows = [], [], 0, 0
+                for t, (tq, tb) in streams.items():
+                    tab, vpad = rings.tables[t], int(rings.pads[t])
+                    o, head, p, w = self._launch_chunks(
+                        prog, f"polyblocks.v{vpad}", k, (tab,),
+                        ((tb.astype(np.int32), pb.n_blocks),
+                         (rings.row[active[tq]].astype(np.int32),
+                          tab.shape[0] - 1),
+                         (tq.astype(np.int32), -1)),
+                        thr, vpad=vpad,
+                    )
+                    outs += o
+                    hq.append(tq[head])
+                    padded += p
+                    rows += w
+                host = None
+                if hostq.size:  # the host's landmarks: a row each
+                    oi, ci, d = host_pairs(hostq, hostb)
+                    keep = d <= limit
+                    uq = np.unique(hostq)
+                    fd, fi = _engine.merge_topk(
+                        np.full((uq.size, k), np.inf),
+                        np.full((uq.size, k), -1, dtype=np.int64),
+                        np.searchsorted(uq, oi[keep]), ci[keep], d[keep], k,
+                    )
+                    hq.append(uq)
+                    host = fd, np.where(fi < 0, _engine._NO_ID, fi)
+                    padded += int(hostq.size) * pb.width
+                chunks = sum(tq.size for tq, _ in streams.values())
+                return (
+                    outs, np.concatenate(hq or [np.zeros(0, np.int64)]),
+                    padded, rows, host, chunks,
+                )
+
+            def oracle(launched):
+                parts = [*streams.values(), (hostq, hostb)]
+                if not launched:
+                    parts += rest.values()
+                oi, ci, d = host_pairs(
+                    np.concatenate([q for q, _ in parts]),
+                    np.concatenate([b for _, b in parts]),
+                )
+                return np.column_stack([active[oi], ci, d])
+
+            return self._enqueue(launch, oracle, default_s, rest or None)
 
         return evaluate
 
@@ -938,8 +1030,8 @@ class KNNFrontend:
                             evaluate(
                                 np.zeros(h, np.int64),
                                 np.minimum(np.arange(b), h - 1),
-                                np.zeros(b, np.int64), 1, None,
-                            )
+                                np.zeros(b, np.int64),
+                            ).pull()
             for b in () if blocks else self.pair_ladder.buckets:
                 with _telemetry.timed(
                     "knn_stage", stage="warmup", kind="pairs", bucket=b,
@@ -985,7 +1077,7 @@ class KNNFrontend:
                             ((zeros, 0), (zeros, 0),
                              (np.minimum(np.arange(b), h - 1).astype(np.int32),
                               -1)),
-                            1, thr, vpad=vpad,
+                            thr, vpad=vpad,
                         )
                         self._pull_heads(outs, rows, b)
 

@@ -328,6 +328,7 @@ class SpatialKNN:
                 pairs_padded=res.pairs_padded, launches=res.launches,
                 rows_pulled=res.rows_pulled,
                 unrested_landmarks=res.unrested,
+                slabs=res.slabs, hidden_s=res.hidden_s,
             )
 
         # flatten result: one row a filled (landmark, rank) slot

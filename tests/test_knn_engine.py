@@ -441,12 +441,14 @@ def test_head_rows_equal_the_per_chunk_pull_bit_for_bit(
     fe = KNNFrontend(kx, row_ladder=BucketLadder(8, 64))
     qs64 = land - kx.shift
     with telemetry.capture() as events:
-        hq, hd, hi, padded, launches, rows = fe._block_topk(
-            qs64, qs64, K, thr, None)(active, cq, blk, steps, None)
+        sent = fe._block_topk(qs64, qs64, K, thr, None)(active, cq, blk)
+        assert not _spans(events, "knn.pull") and sent.carry is None
+        hq, hd, hi = sent.pull()
     met = [(e["bucket"], e["head_bucket"])
            for e in _spans(events, "knn.blocks")]
-    assert met == rungs and launches == len(rungs)
-    assert rows == sum(h for _b, h in rungs) and hd.shape == (hq.size, K)
+    assert met == rungs and sent.launches == len(rungs)
+    assert sent.rows == sum(h for _b, h in rungs) and hd.shape == (hq.size, K)
+    assert sent.padded == sum(b for b, _h in rungs) * pb.width
     got = engine.fold_heads(hq, hd, hi, a, K)
     old = _per_chunk_path(kx, qs64, active, cq, blk, steps, thr, K, ladder)
     assert np.array_equal(got[0], old[0]) and np.array_equal(got[1], old[1])
@@ -516,6 +518,206 @@ def test_degraded_block_launches_are_answered_by_the_host_oracle(clustered):
     want_ids, want_d = knn_bruteforce.answers(land[:50], cand, K)
     assert np.array_equal(ids, want_ids)
     np.testing.assert_allclose(dist, want_d, rtol=0, atol=1e-12)
+
+
+# ------------------------------------------------ the slab pipeline (PR 45)
+
+
+@pytest.fixture(scope="module")
+def hexed():
+    """H3 res 10 (a lattice: only there is an iteration cut into slabs):
+    4,000 candidates, a cluster whose cells hold up to 150 and a background
+    under one a cell; 2,000 landmarks, a fifth of them in the cluster and
+    two a dozen rings outside everything, in no spatial order."""
+    rng = np.random.default_rng(45)
+    centre = np.array([-73.98, 40.75])
+    cand = np.concatenate([centre + rng.normal(0, 0.002, (2500, 2)),
+                           centre + rng.uniform(-0.03, 0.03, (1500, 2))])
+    land = np.concatenate([centre + rng.normal(0, 0.002, (400, 2)),
+                           centre + rng.uniform(-0.03, 0.03, (1598, 2)),
+                           centre + [[0.045, 0.0], [0.0, -0.045]]])
+    h3 = H3IndexSystem()
+    return land[rng.permutation(2000)], cand, h3, build_knn_index(cand, h3, 10)
+
+
+SLAB_LADDER = BucketLadder(16, 64, growth=4)
+
+
+def _slab_run(monkeypatch, hexed, slab_keys, **kw):
+    land, _cand, h3, kx = hexed
+    monkeypatch.setattr(knn_frontend, "BLOCK_LADDER", SLAB_LADDER)
+    monkeypatch.setattr(engine, "SLAB_KEYS", slab_keys)
+    args = dict(index=h3, resolution=10, k_neighbours=K, approximate=False,
+                max_iterations=32)
+    args.update(kw)
+    with telemetry.capture() as events:
+        res = SpatialKNN(**args).transform(land, kx)
+    return res, events
+
+
+def _by_iteration(events, name, field):
+    out = {}
+    for e in _spans(events, name):
+        out[e["iteration"]] = out.get(e["iteration"], 0) + e[field]
+    return out
+
+
+SLAB_CASES = {
+    "straddle": {},
+    "k-over-a-cell": {"k_neighbours": 12},
+    "threshold": {"distance_threshold": 0.0015},
+    "approximate": {"approximate": True, "early_stop_iterations": 1},
+}
+
+
+@pytest.mark.parametrize("case", sorted(SLAB_CASES))
+def test_slabbed_schedule_equals_the_one_slab_schedule_bit_for_bit(
+    case, hexed, monkeypatch
+):
+    """`engine.SLAB_KEYS` down, so the early iterations run 4 to 12 slabs,
+    and up, so every iteration is one: the same answers to the bit, the
+    same launches cut for cut (what a slab leaves past a whole cut rides
+    the next slab's first launch), and counts that follow from the chunks."""
+    kw = SLAB_CASES[case]
+    one, ev1 = _slab_run(monkeypatch, hexed, 1 << 40, **kw)
+    cut, ev2 = _slab_run(monkeypatch, hexed, 3000, **kw)
+    for f in ("landmark_id", "candidate_id", "distance", "rank"):
+        assert np.array_equal(getattr(one, f), getattr(cut, f)), f
+    for f in ("iterations", "pairs", "pairs_padded", "launches",
+              "unrested_landmarks", "match_count"):
+        assert one.metrics[f] == cut.metrics[f], f
+    cuts = [[(e["bucket"], e["chunks"], e["heads"], e["head_bucket"])
+             for e in _spans(ev, "knn.blocks")] for ev in (ev1, ev2)]
+    assert cuts[0] == cuts[1]
+    # launches and padded slots from the iterations' chunks alone
+    cap, width = SLAB_LADDER.max_bucket, hexed[3].points.width
+    chunks = _by_iteration(ev2, "knn.distance", "chunks")
+    assert chunks == _by_iteration(ev1, "knn.distance", "chunks")
+    assert cut.metrics["launches"] == sum(-(-c // cap) for c in chunks.values())
+    assert cut.metrics["pairs_padded"] == width * sum(
+        c - c % cap + (SLAB_LADDER.bucket_for(c % cap) if c % cap else 0)
+        for c in chunks.values())
+    # slabs from the ring keys: queries x the ring's cells, an iteration
+    queries = _by_iteration(ev2, "knn.expand", "queries")
+    want = [max(q * (7 if it == 1 else 6 * it) // 3000, 1)
+            for it, q in queries.items()]
+    (root1,), (root2,) = (_spans(ev, "knn.transform") for ev in (ev1, ev2))
+    assert root2["slabs"] == sum(want) and 3 <= max(want) <= 12
+    assert root1["slabs"] == one.metrics["iterations"] and root1["hidden_s"] == 0
+    assert root2["slabs"] > cut.metrics["iterations"] and root2["hidden_s"] > 0
+    pulls = _spans(ev2, "knn.pull")
+    assert sum(e["hidden_s"] for e in pulls) == pytest.approx(root2["hidden_s"])
+    assert {e["slabs"] for e in pulls} == set(want)
+    # the expand is no part of the distance span: the call's split adds up
+    ids = {e["span_id"]: e["name"] for e in _spans(ev2, "knn.distance")}
+    assert all(e["parent_id"] not in ids for e in _spans(ev2, "knn.expand"))
+    assert all(e["parent_id"] in ids for e in pulls)
+    if case == "approximate":  # rests at k matches: the far two end it sooner
+        assert 10 < cut.metrics["iterations"] < 20
+    if case == "threshold":
+        assert cut.distance.max() <= 0.0015 < one.metrics["max_kth_distance"] + 1
+    if case == "k-over-a-cell":
+        assert hexed[3].points.count.min() < 12 == cut.rank.max()
+
+
+def test_a_landmark_straddles_a_launch_inside_a_slab(hexed):
+    """What the case above runs into: in iteration 1 a launch boundary
+    falls between two chunks of one landmark, away from any slab's edge."""
+    land, _cand, h3, kx = hexed
+    cells = knn_frontend.KNNFrontend(kx)._assign_cells(land)
+    keys, margin = kx.probe_keys(cells)
+    cq, _blk, _fresh = engine.block_chunks(
+        kx.points, kx.ring_keys(cells, keys, margin, 1))
+    cap = SLAB_LADDER.max_bucket
+    edge = np.arange(cap, cq.size, cap)
+    mid = edge[cq[edge] == cq[edge - 1]]
+    real = engine.SLAB_KEYS
+    try:
+        engine.SLAB_KEYS = 3000
+        bounds = engine.slab_bounds(np.full(len(land), 7))
+    finally:
+        engine.SLAB_KEYS = real
+    assert bounds.tolist() == [0, 500, 1000, 1500, 2000]
+    inside = ~np.isin(cq[mid], bounds) & ~np.isin(cq[mid], bounds - 1)
+    assert inside.sum() >= 10
+
+
+def test_slab_bounds_follow_the_keys():
+    assert engine.slab_bounds(np.full(10, 7)).tolist() == [0, 10]
+    keys = np.full(100_000, 7)
+    b = engine.slab_bounds(keys)  # 700,000 keys: five slabs of 20,000
+    assert b.size == 6 and (np.diff(b) == 20_000).all()
+    # under two slabs' worth is one slab
+    n = 2 * engine.SLAB_KEYS // 7
+    assert engine.slab_bounds(np.full(n, 7)).tolist() == [0, n]
+    assert engine.slab_bounds(np.full(n + 1, 7)).size == 3
+    # seeds a query: slabs hold about the same keys, not the same queries
+    keys = np.r_[np.full(1000, 7 * 40), np.full(40_000, 7)]
+    b = engine.slab_bounds(keys)
+    got = np.add.reduceat(keys, b[:-1])
+    assert b[0] == 0 and b[-1] == keys.size and (np.diff(b) > 0).all()
+    assert got.max() < 1.1 * got.min()
+    # off a lattice the keys are not counted: one slab
+    assert engine.slab_bounds(np.zeros(10**6, np.int64)).tolist() == [0, 10**6]
+
+
+def test_chunk_pairs_are_the_blocks_candidates(clustered):
+    _, cand = clustered
+    kx = build_knn_index(cand, GRID, RES)
+    pb = kx.points
+    rid = np.asarray(pb.rid)
+    rng = np.random.default_rng(2)
+    blk = rng.choice(pb.n_blocks, 200)
+    own = np.sort(rng.integers(0, 50, 200))
+    qi, ci = engine.chunk_pairs(kx, own, blk)
+    want = rid[blk]
+    assert np.array_equal(ci, want[want >= 0])
+    assert np.array_equal(qi, np.repeat(own, (want >= 0).sum(axis=1)))
+
+
+#: ``knn.distance`` is met, in an iteration of slabs: enqueue 1, enqueue 2,
+#: pull 1, enqueue 3, pull 2, ...: (site, hits let through, hits failed)
+SLAB_FAULTS = {
+    "expand-2-retried": ("knn.expand", 1, 1, False),
+    "enqueue-2-retried": ("knn.distance", 1, 1, False),
+    "pull-1-retried": ("knn.distance", 2, 1, False),
+    "enqueue-2-exhausted": ("knn.distance", 1, 3, True),
+    "pull-1-exhausted": ("knn.distance", 2, 3, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SLAB_FAULTS))
+def test_a_fault_in_one_slab_stays_in_that_slab(case, hexed, monkeypatch):
+    """A transient fault in the second slab's expand or enqueue, or in the
+    first slab's pull, is retried to the same bits (a retried pull launches
+    again); past the budget that half's chunks — the slab's and what it
+    carried, or the launches the pull gave up — are answered by the f64
+    host oracle, the other slabs' device answers stand, and the call is
+    flagged once."""
+    from mosaic_tpu.runtime import faults
+
+    site, skip, n, exhausted = SLAB_FAULTS[case]
+    monkeypatch.setenv("MOSAIC_RETRY_BASE_S", "0.001")
+    sound, _ = _slab_run(monkeypatch, hexed, 3000)
+    with faults.transient_errors(n, sites=(site,), skip_first=skip):
+        got, events = _slab_run(monkeypatch, hexed, 3000)
+    retries = [e for e in events if e.get("event") == "transient_retry"]
+    assert len(retries) == n and {e["label"] for e in retries} == {site}
+    assert len([e for e in events if e.get("event") == "degraded"]) == exhausted
+    assert got.metrics["degraded"] is exhausted
+    assert got.metrics["pairs"] == sound.metrics["pairs"]
+    for f in ("landmark_id", "candidate_id", "rank"):
+        assert np.array_equal(getattr(got, f), getattr(sound, f)), f
+    if not exhausted:
+        assert np.array_equal(got.distance, sound.distance)
+        assert got.metrics["launches"] == sound.metrics["launches"]
+        return
+    np.testing.assert_allclose(got.distance, sound.distance, rtol=0, atol=1e-12)
+    # one slab's launches are the host's now; what it carried was theirs too
+    lost = sound.metrics["launches"] - got.metrics["launches"]
+    assert 0 <= lost < sound.metrics["launches"] / 2
+    if case == "enqueue-2-exhausted":
+        assert lost >= 1
 
 
 def test_expand_ranges_and_block_layout(clustered):

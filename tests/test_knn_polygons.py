@@ -272,8 +272,10 @@ def test_chunks_of_several_seeds_count_a_cell_once():
     seen = [set(), set()]
     for it in (1, 2, 3):
         ring = GRID.ring_cells(cells, it)
-        cq, blk, fresh, met = engine.block_chunks_multi(
+        cq, blk, fresh, new = engine.block_chunks_multi(
             pb, active, owner, ring, met, many)
+        assert not np.isin(new, met).any() and (np.diff(new) > 0).all()
+        met = np.union1d(met, new)
         assert (np.diff(cq) >= 0).all()
         for q in (0, 1):
             mine = {c for s in np.flatnonzero(owner == q)
@@ -452,6 +454,65 @@ def test_h3_another_table_after_warmup_compiles_nothing(fabric, monkeypatch):
     assert len(set(counts)) == 3
     assert backend_compiles() == c0
     assert fe.cold_compiles == 0 and fe.signature_count() == warmed
+
+
+@pytest.mark.parametrize("against", ["one-slab", "host-oracle"])
+def test_h3_slabbed_schedule_is_the_one_slab_schedule_and_the_oracles(
+    against, fabric, monkeypatch
+):
+    """PR 45 on polygon landmarks: `engine.SLAB_KEYS` down, so an iteration
+    of 700 footprints runs several slabs — queries of several seeds among
+    them, whose met cells grow slab by slab and iteration by iteration, and
+    three edge rungs in every early iteration, each table carrying its own
+    remainder from slab to slab, and one footprint the host answers. The answers are the one-slab schedule's to
+    the bit with its launches cut for cut, and the f64 host oracle's (every
+    launch refused) within its rounding."""
+    fps, kinds, cand, h3, _kx = fabric
+    kx = build_knn_index(cand[:400], h3, 10)  # sparse: landmarks walk rings
+    pick = np.sort(np.r_[np.flatnonzero(kinds == 2)[:40],
+                         np.flatnonzero(kinds != 2)[:660]])
+    box = buildings.footprints_bbox(fps)
+    big = [_ngon((box[0] + box[2]) / 2, (box[1] + box[3]) / 2, 4e-4, 200)]
+    land = pack([fps[i] for i in pick[:350]] + [big] + [fps[i] for i in pick[350:]])
+    monkeypatch.setattr(knn_frontend, "BLOCK_LADDER", BucketLadder(16, 64, growth=4))
+
+    def run(slab_keys):
+        monkeypatch.setattr(engine, "SLAB_KEYS", slab_keys)
+        m = SpatialKNN(index=h3, resolution=10, k_neighbours=K,
+                       approximate=False, max_iterations=32)
+        with telemetry.capture() as events:
+            return m.transform(land, kx), events
+
+    cut, events = run(1500)
+    (root,) = _spans(events, "knn.transform")
+    assert root["iterations"] >= 3 and root["hidden_s"] > 0
+    assert root["slabs"] >= root["iterations"] + 6
+    # (one footprint is past the vertex ladder: the host answers its chunks
+    # behind the launches of its slab)
+    assert root["seeds"] > len(pick) and root["host_landmarks"] == 1
+    first = [e for e in _spans(events, "knn.blocks")][:40]
+    assert {e["vpad"] for e in first} == {8, 32, 128}
+    if against == "one-slab":
+        one, ev1 = run(1 << 40)
+        (root1,) = _spans(ev1, "knn.transform")
+        assert root1["slabs"] == root1["iterations"] and root1["hidden_s"] == 0
+        for f in ("landmark_id", "candidate_id", "distance", "rank"):
+            assert np.array_equal(getattr(one, f), getattr(cut, f)), f
+        for f in ("iterations", "pairs", "pairs_padded", "launches",
+                  "edge_pairs", "edge_pairs_padded", "edge_rows", "seeds"):
+            assert one.metrics[f] == cut.metrics[f], f
+        cuts = [sorted((e["vpad"], e["bucket"], e["chunks"], e["heads"])
+                       for e in _spans(ev, "knn.blocks"))
+                for ev in (ev1, events)]
+        assert cuts[0] == cuts[1]
+        return
+    with faults.transient_errors(10 ** 6, sites=("knn.distance",)):
+        host, _ = run(1500)
+    assert host.metrics["degraded"] is True and host.metrics["launches"] == 0
+    for f in ("landmark_id", "candidate_id", "rank"):
+        assert np.array_equal(getattr(host, f), getattr(cut, f)), f
+    np.testing.assert_allclose(cut.distance, host.distance, rtol=0, atol=1e-14)
+    assert (host.distance == 0.0).sum() == (cut.distance == 0.0).sum() > 0
 
 
 def test_h3_footprint_too_wide_for_the_lattice_cover_is_clipped(fabric):
