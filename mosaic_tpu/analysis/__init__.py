@@ -9,7 +9,7 @@ goldens), applies inline suppressions (``# lint: <rule>-ok (reason)``)
 and the committed baseline, and returns typed :class:`Finding` records.
 ``tools/lint.py`` is the CLI driver; ``tests/test_analysis.py`` holds
 the per-rule fixtures and ``tests/test_registry_coverage.py`` pins the
-generated registry against ARCHITECTURE.md and the perf_gate golden.
+generated registry against ARCHITECTURE.md.
 
 Rules shipped (see ``docs/ARCHITECTURE.md`` "Static analysis"):
 
@@ -20,8 +20,7 @@ Rules shipped (see ``docs/ARCHITECTURE.md`` "Static analysis"):
 - ``thread-context-adoption`` — worker threads must adopt telemetry
   sinks + trace context + fault plans;
 - ``registry-drift`` — fault sites / spans / event stages / env knobs
-  vs the committed registry, ARCHITECTURE's span table, the perf_gate
-  golden, and the docs;
+  vs the committed registry, ARCHITECTURE's span table and the docs;
 - ``broad-except`` — ``except Exception`` must re-raise, convert into
   the runtime error taxonomy, or carry a justification;
 - ``unbounded-cache`` — ``lru_cache(maxsize=None)`` pins device arrays

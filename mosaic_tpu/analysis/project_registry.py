@@ -5,8 +5,8 @@ and ``MOSAIC_*`` env knobs, scanned from the AST.
 This is the anti-drift substrate: the committed copy
 (``tests/goldens/registry.json``, regenerated with
 ``python tools/lint.py --update-registry``) plus the ``registry-drift``
-rule keep code, ARCHITECTURE.md's span taxonomy, the perf_gate golden,
-and the env-knob docs from diverging — the invariant PRs 3-6 each
+rule keep code, ARCHITECTURE.md's span taxonomy and the env-knob docs
+from diverging — the invariant PRs 3-6 each
 re-checked by hand.
 
 Dynamic names register as wildcard families: an f-string span like
@@ -132,8 +132,8 @@ def build_registry_from_modules(
     """``modules`` is ``[(repo-relative path, parsed tree), ...]``;
     tests/ modules are excluded (fixture names are not registered
     surface). Library spans and tool-only spans are kept apart: the
-    ARCHITECTURE span table documents the library taxonomy, while bench
-    root spans (``probe_smoke``, ``stream_bench``) are tool-scoped."""
+    ARCHITECTURE span table documents the library taxonomy, while a
+    tool's root span (``probe_smoke``) is tool-scoped."""
     cats: dict[str, set[str]] = {
         "fault_sites": set(), "spans": set(), "spans_tools": set(),
         "events": set(), "stages": set(), "env_knobs": set(),

@@ -1,21 +1,20 @@
-"""Registry drift: code vs committed registry vs docs vs perf gate.
+"""Registry drift: code vs committed registry vs docs.
 
-Four surfaces name the same things — the code (fault sites, spans,
+Three surfaces name the same things — the code (fault sites, spans,
 telemetry stages, env knobs), the committed registry golden
-(``tests/goldens/registry.json``), the docs (ARCHITECTURE.md's span
-taxonomy + knob/fault-site mentions in README/docs), and the perf_gate
-golden's stage list. They drift apart one PR at a time unless a machine
-reconciles them; this rule is that machine.
+(``tests/goldens/registry.json``) and the docs (ARCHITECTURE.md's span
+taxonomy + knob/fault-site mentions in README/docs). They drift apart
+one PR at a time unless a machine reconciles them; this rule is that
+machine.
 
 Checks:
 
 1. fresh AST scan == committed registry (else: regenerate + review);
 2. every library span name appears in ARCHITECTURE.md's span-taxonomy
    table, and every table row still exists in code (both directions);
-3. every perf_gate golden stage is a registered stage/span/event name;
-4. every ``MOSAIC_*`` env knob read in code is documented in
+3. every ``MOSAIC_*`` env knob read in code is documented in
    README/docs (wildcard families by prefix);
-5. every fault-injection site string is documented in README/docs.
+4. every fault-injection site string is documented in README/docs.
 """
 
 from __future__ import annotations
@@ -31,7 +30,6 @@ from ..project_registry import (
 )
 
 REGISTRY_GOLDEN = "tests/goldens/registry.json"
-PERF_GOLDEN = "tests/goldens/perf_gate.json"
 ARCHITECTURE = "docs/ARCHITECTURE.md"
 
 _ROW_RE = re.compile(r"^\|\s*`([^`]+)`\s*\|")
@@ -69,8 +67,7 @@ def span_table_names(arch_text: str) -> list[str]:
 @rule("registry-drift", scope="project")
 def registry_drift(project: ProjectContext) -> list[Finding]:
     """Fault sites, span names, telemetry stages, and MOSAIC_* knobs
-    must agree across code, the committed registry, the docs, and the
-    perf_gate golden."""
+    must agree across code, the committed registry and the docs."""
     out: list[Finding] = []
     reg = fresh_registry(project)
 
@@ -136,29 +133,7 @@ def registry_drift(project: ProjectContext) -> list[Finding]:
                 hint="delete the stale row (or restore the span)",
             ))
 
-    # 3) perf_gate golden stages are registered names
-    perf_text = project.read_text(PERF_GOLDEN)
-    if perf_text is not None:
-        gate = json.loads(perf_text)
-        known = (
-            reg["stages"] + reg["events"] + reg["spans"]
-            + reg["spans_tools"]
-        )
-        for stage in sorted(gate.get("stages", {})):
-            if not name_matches(stage, known):
-                out.append(Finding(
-                    rule="registry-drift", path=PERF_GOLDEN, line=0,
-                    message=(
-                        f"perf_gate stage {stage!r} is not a registered "
-                        "telemetry stage/event/span"
-                    ),
-                    hint=(
-                        "the gated stage was renamed or removed — "
-                        "regenerate the perf_gate golden"
-                    ),
-                ))
-
-    # 4) env knobs + 5) fault sites are documented
+    # 3) env knobs + 4) fault sites are documented
     docs = project.docs_text()
     for knob in reg["env_knobs"]:
         probe = knob[:-1] if knob.endswith("*") else knob

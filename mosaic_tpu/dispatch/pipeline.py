@@ -1,6 +1,6 @@
 """Pipelined execution core: bounded in-flight window + async snapshots.
 
-The round-12 stall attribution (``STALL_r12.json``) showed the durable
+The round-12 stall attribution (a CPU run) showed the durable
 stream's remaining loss is structural: a strictly synchronous segment
 loop pays a dispatch → block → host-pull → snapshot round-trip per
 segment, so the device idles while the host writes checkpoints and the

@@ -48,12 +48,10 @@ backward-compatible with) `runtime/telemetry.py`'s flat event trail:
 
 Tools: `tools/trace_report.py` renders/diffs per-stage latency
 breakdowns from trails; `tools/stall_report.py` decomposes a window of
-wall time into stall classes; `tools/perf_gate.py` is the CI
-regression gate over committed stage-share goldens
-(`tests/goldens/perf_gate.json`); `tools/fleet_report.py` stitches many
+wall time into stall classes; `tools/fleet_report.py` stitches many
 processes' trails into one incarnation-linked timeline;
 `tools/doctor.py` runs the known-failure-signature checks over
-committed artifacts and trails.
+result artifacts and trails.
 
 Importing this package registers the tracer, the metric bridge, the
 flight recorder, the SLO monitor, and the health monitor with

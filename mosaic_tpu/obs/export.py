@@ -5,10 +5,10 @@ dicts (spans included, as ``event="span"``). This module turns that
 trail into the formats the outside world reads:
 
 - :func:`write_jsonl` / :func:`read_trail` — the trail itself, one JSON
-  object per line (the durable interchange format benches export with
-  ``--trail`` and `tools/trace_report.py` / `tools/perf_gate.py`
-  consume; ``read_trail`` also accepts a bench artifact whose last line
-  is one JSON object and reads ``detail.trail`` / ``detail.stages``);
+  object per line (the durable interchange format the tools export with
+  ``--trail`` and `tools/trace_report.py` consumes; ``read_trail`` also
+  accepts an artifact whose last line is one JSON object and reads
+  ``detail.trail`` / ``detail.stages``);
 - :func:`chrome_trace` — Chrome trace-event JSON (the ``traceEvents``
   array format Perfetto and ``chrome://tracing`` load): spans become
   complete ``"X"`` events on one timeline row per trace, flat events
@@ -87,8 +87,8 @@ def read_trail(path: str) -> list[dict]:
         det = rows[0]["detail"] or {}
         stages = det.get("trail") or det.get("stages") or []
         if isinstance(stages, dict):
-            # summary-only artifact ({stage_key: {total_s, count, ...}},
-            # the perf_gate golden shape): synthesize one pseudo-event
+            # summary-only artifact ({stage_key: {total_s, count, ...}}):
+            # synthesize one pseudo-event
             # per stage so breakdowns/diffs keep a real base instead of
             # iterating the dict's key strings.
             return [

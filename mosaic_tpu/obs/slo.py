@@ -49,8 +49,8 @@ benches shed on purpose. Knobs (all read at enable time):
 - ``MOSAIC_SLO_STREAM_RATE_MIN`` — sustained stream points/sec floor
   (default 0 = that SLO disabled).
 
-Benches evaluate the same specs post-hoc over a captured trail with
-:func:`evaluate_trail` (the ``--slo`` lane of serve_bench/stream_bench).
+The same specs are evaluated post-hoc over a captured trail with
+:func:`evaluate_trail`.
 """
 
 from __future__ import annotations

@@ -131,7 +131,6 @@ CLASS_RULES: tuple = (
     ("device", "stream_stage.gen_loop"),
     ("device", "probe_stage.*"),
     ("device", "raster_stage.*"),
-    ("device", "multichip_stage.*"),
 )
 
 #: container keys spanning their own children — never classified
@@ -149,9 +148,6 @@ CONTAINER_KEYS = frozenset({
     "stream_stage.join_loop",
     "stream_stage.single_batch",
     "raster_stage.scan",
-    "span.stream_bench",
-    "span.raster_bench",
-    "span.multichip_bench",
     "span.probe_smoke",
 })
 
@@ -160,7 +156,7 @@ def event_key(e: dict) -> str | None:
     """The stage key of one event — the `tools/trace_report.py`
     convention, restated here so the library layer never imports tools:
     ``span.<name>`` for spans, ``<event>.<stage>`` for staged events, a
-    pass-through ``stage_key`` (perf_gate golden pseudo-events), else
+    pass-through ``stage_key`` (a summary artifact's pseudo-events), else
     the bare event name when it carries a numeric ``seconds``."""
     if e.get("event") == "span" and e.get("name"):
         return f"span.{e['name']}"

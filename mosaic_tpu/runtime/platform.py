@@ -1,7 +1,7 @@
 """Start-up decisions every executable makes, made once here.
 
 - **Which platform am I on** — :func:`require_device`. A measurement
-  surface (``chip_smoke.py``, ``bench.py``, ``tools/*_bench.py``) runs on
+  surface (``chip_smoke.py``, ``benchmark/run.py``) runs on
   the TPU, or on the CPU only when the caller asked for the CPU by name
   (``JAX_PLATFORMS=cpu``). A process that merely *ended up* on the CPU —
   no chip, a plug-in that failed to load — raises instead of printing

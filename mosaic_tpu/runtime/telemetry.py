@@ -232,10 +232,9 @@ def summarize(
     ``{"count", "p50", "p90", "p99", "mean", "max", "sum"}`` over
     ``e[key]`` for every event dict in ``events`` carrying the field
     (restricted to ``e["event"] == event`` when given); all values 0.0
-    when nothing matches. The ONE percentile implementation the benches
-    share (`tools/serve_bench.py` latencies, `tools/stream_bench.py`
-    stage timings) — a p99 computed two different ad-hoc ways is two
-    different metrics.
+    when nothing matches. The ONE percentile implementation every reader
+    shares (request latencies, stage timings) — a p99 computed two
+    different ad-hoc ways is two different metrics.
 
     Percentiles are explicit nearest-rank (``ceil(q*n) - 1`` on the
     sorted sample): the q-th percentile is the smallest value with at

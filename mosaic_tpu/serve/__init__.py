@@ -26,10 +26,8 @@ Component map: `bucket.py` (pad-to-bucket ladder + compile accounting),
 per-request deadline shedding), `engine.py` (lifecycle + resilience
 wiring), `router.py` (multi-tenant front door: per-tenant engines with
 hard isolation, bounded residency with occupancy-aware LRU eviction,
-per-tenant tuning profiles and shed accounting). Benches:
-`tools/serve_bench.py` (single- and multi-tenant),
-`tools/restart_bench.py` (zero-cold-start restart storm over the AOT
-program store).
+per-tenant tuning profiles and shed accounting). Measured on the chip
+by the benchmark's `taxi.serve` cell (`benchmark/run.py`).
 """
 
 from .admission import AdmissionController, Request

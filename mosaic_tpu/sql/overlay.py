@@ -962,16 +962,6 @@ def _register_stages(fn, args: tuple, rows: int) -> None:
     _stages.register(fn, _stages.shapes_of(args), rows=rows)
 
 
-def _beside_root(sp, outer):
-    """Keep a detached span BESIDE the call's root: the caller's child
-    where the caller has a span (``outer``), else a root of its own —
-    never the root's child, whose direct children are the call's pieces
-    and nothing else."""
-    if outer is None:
-        sp.parent_id = None
-    return sp
-
-
 @dataclass(frozen=True)
 class OverlayMeasures:
     """Fused overlay measure result — one row per unique geometry pair
@@ -1107,124 +1097,99 @@ def overlay_measures(
         (_host_tables(L, acc), _host_tables(R, acc)) if meshed
         else (L.dev, R.dev)
     )
-    outer = _trace.current_context()
     try:
         with _trace.span(
             "overlay.call", left_rows=L.n, right_rows=R.n, acc=prep.acc_name,
             vpad=prep.vpad,
         ) as call:
-            # the names of before the call had an inside: siblings of the
-            # root, so its direct children stay the pieces and nothing
-            # is counted twice
-            cand_span = _beside_root(_trace.start_span(
-                "overlay.device_candidates", parent=outer, detached=True,
-                left_chips=L.n, right_chips=R.n,
-            ), outer)
-            with _telemetry.timed("overlay_stage", stage="candidates"):
-
-                def device_candidates():
-                    with _trace.span(
-                        "overlay.count", spans="rank", ranks=prep.ranks,
-                    ):
-                        count = _count_program()
-                        args = (lt_["rank"], rt_["roff"], L.n)
-                        _register_stages(count, args, L.bucket)
-                        dtotal, dlo, dcnt = count(*args)
-                        total = int(dtotal)
-                    Pb, emit_limit, overflow = pair_plan(
-                        total, pair_cap
-                    )
-                    with _trace.span("overlay.emit", bucket=Pb):
-                        emit = _emit_program(Pb)
-                        args = (dlo, dcnt, emit_limit)
-                        _register_stages(emit, args, Pb)
-                        dli, dri, dvalid = emit(*args)
-                        li = np.asarray(dli)
-                        ri = np.asarray(dri)
-                    return (
-                        dli, dri, dvalid, li, ri,
-                        np.arange(Pb) < emit_limit,
-                        total, Pb, emit_limit, overflow,
-                    )
-
-                (dli, dri, dvalid, li, ri, valid, total, Pb,
-                 emit_limit, overflow) = _dispatch.guarded_call(
-                    "overlay.device_candidates", device_candidates
+            def device_candidates():
+                with _trace.span(
+                    "overlay.count", spans="rank", ranks=prep.ranks,
+                ):
+                    count = _count_program()
+                    args = (lt_["rank"], rt_["roff"], L.n)
+                    _register_stages(count, args, L.bucket)
+                    dtotal, dlo, dcnt = count(*args)
+                    total = int(dtotal)
+                Pb, emit_limit, overflow = pair_plan(total, pair_cap)
+                with _trace.span("overlay.emit", bucket=Pb):
+                    emit = _emit_program(Pb)
+                    args = (dlo, dcnt, emit_limit)
+                    _register_stages(emit, args, Pb)
+                    dli, dri, dvalid = emit(*args)
+                    li = np.asarray(dli)
+                    ri = np.asarray(dri)
+                return (
+                    dli, dri, dvalid, li, ri,
+                    np.arange(Pb) < emit_limit,
+                    total, Pb, emit_limit, overflow,
                 )
-                with _trace.span("overlay.glue", rows=emit_limit):
-                    uniq, seg, sure, Sb, seg_l64, seg_r64 = pair_glue(
-                        prep, li, ri, valid
-                    )
-                    clip_r, clip_swap, fan_r, fan_swap, shape_r = (
-                        pair_routes(prep, li, ri, seg)
-                    )
-                    Cb = PAIR_LADDER.bucket_for(max(clip_r.shape[0], 1))
-                    Fb = PAIR_LADDER.bucket_for(max(fan_r.shape[0], 1))
-            cand_span.set(
-                raw_candidates=total, emitted=emit_limit,
-                overflow=overflow,
+
+            (dli, dri, dvalid, li, ri, valid, total, Pb,
+             emit_limit, overflow) = _dispatch.guarded_call(
+                "overlay.device_candidates", device_candidates
             )
-            _candidate_stats(cand_span, sure)
-            cand_span.end()
+            with _trace.span("overlay.glue", rows=emit_limit):
+                uniq, seg, sure, Sb, seg_l64, seg_r64 = pair_glue(
+                    prep, li, ri, valid
+                )
+                clip_r, clip_swap, fan_r, fan_swap, shape_r = (
+                    pair_routes(prep, li, ri, seg)
+                )
+                Cb = PAIR_LADDER.bucket_for(max(clip_r.shape[0], 1))
+                Fb = PAIR_LADDER.bucket_for(max(fan_r.shape[0], 1))
 
-            meas_span = _beside_root(_trace.start_span(
-                "overlay.measures", parent=outer, detached=True,
-                pairs=int(uniq.shape[0]), candidates=total, mesh=meshed,
-            ), outer)
-            with _telemetry.timed("overlay_stage", stage="measures"):
-                sig = _compile.overlay_signature_of(
-                    value, L.bucket, R.bucket, Pb, Cb, Fb, Sb, prep.vpad,
-                    prep.acc_name, index_system, resolution, mesh,
-                )
-                prog = _compile.overlay_program(
-                    value, L.bucket, R.bucket, Pb, Cb, Fb, Sb, prep.vpad,
-                    prep.acc_name, mesh,
-                )
-                if meshed:
-                    dli, dri, dvalid = li, ri, valid
-                args = (
-                    dli, dri, dvalid, seg,
-                    _padded(clip_r, Cb), _padded(clip_swap, Cb),
-                    np.int32(clip_r.shape[0]),
-                    _padded(fan_r, Fb), _padded(fan_swap, Fb),
-                    np.int32(fan_r.shape[0]),
-                    lt_["core"], lt_["sign"], lt_["verts"], lt_["vlen"],
-                    lt_["chip_area"], lt_["cell_area"],
-                    rt_["core"], rt_["sign"], rt_["verts"], rt_["vlen"],
-                    rt_["chip_area"],
-                    seg_l64.astype(acc), seg_r64.astype(acc),
-                    acc.type(prep.band),
-                )
-                if not meshed:
-                    _register_stages(prog, args, Pb)
+            sig = _compile.overlay_signature_of(
+                value, L.bucket, R.bucket, Pb, Cb, Fb, Sb, prep.vpad,
+                prep.acc_name, index_system, resolution, mesh,
+            )
+            prog = _compile.overlay_program(
+                value, L.bucket, R.bucket, Pb, Cb, Fb, Sb, prep.vpad,
+                prep.acc_name, mesh,
+            )
+            if meshed:
+                dli, dri, dvalid = li, ri, valid
+            args = (
+                dli, dri, dvalid, seg,
+                _padded(clip_r, Cb), _padded(clip_swap, Cb),
+                np.int32(clip_r.shape[0]),
+                _padded(fan_r, Fb), _padded(fan_swap, Fb),
+                np.int32(fan_r.shape[0]),
+                lt_["core"], lt_["sign"], lt_["verts"], lt_["vlen"],
+                lt_["chip_area"], lt_["cell_area"],
+                rt_["core"], rt_["sign"], rt_["verts"], rt_["vlen"],
+                rt_["chip_area"],
+                seg_l64.astype(acc), seg_r64.astype(acc),
+                acc.type(prep.band),
+            )
+            if not meshed:
+                _register_stages(prog, args, Pb)
 
-                def measures():
-                    with _trace.span(
-                        "overlay.launch", clip_bucket=Cb, fan_bucket=Fb,
-                    ):
-                        raw = _compile.run_tracked(sig, prog, *args)
-                    with _trace.span("overlay.pull"):
-                        return tuple(np.asarray(x) for x in raw)
+            def measures():
+                with _trace.span(
+                    "overlay.launch", clip_bucket=Cb, fan_bucket=Fb,
+                ):
+                    raw = _compile.run_tracked(sig, prog, *args)
+                with _trace.span("overlay.pull"):
+                    return tuple(np.asarray(x) for x in raw)
 
-                val, vok, s, cnt, host_c, host_f, spill_c, spill_f = (
-                    _dispatch.guarded_call("overlay.measures", measures)
+            val, vok, s, cnt, host_c, host_f, spill_c, spill_f = (
+                _dispatch.guarded_call("overlay.measures", measures)
+            )
+            val = val.astype(np.float64).copy()
+            vok = vok.astype(bool).copy()
+            area64 = s.astype(np.float64).copy()
+            nc, nf = clip_r.shape[0], fan_r.shape[0]
+            flagged_rows = np.concatenate([
+                clip_r[host_c[:nc]], fan_r[host_f[:nf]], shape_r,
+            ])
+            with _trace.span("overlay.host_override") as hspan:
+                (val, vok, area64, overridden, hrows,
+                 cancelled) = splice_override(
+                    prep, value, li, ri, seg, flagged_rows, cnt,
+                    seg_l64, seg_r64, val, vok, area64,
                 )
-                val = val.astype(np.float64).copy()
-                vok = vok.astype(bool).copy()
-                area64 = s.astype(np.float64).copy()
-                nc, nf = clip_r.shape[0], fan_r.shape[0]
-                flagged_rows = np.concatenate([
-                    clip_r[host_c[:nc]], fan_r[host_f[:nf]], shape_r,
-                ])
-                with _trace.span("overlay.host_override") as hspan:
-                    (val, vok, area64, overridden, hrows,
-                     cancelled) = splice_override(
-                        prep, value, li, ri, seg, flagged_rows, cnt,
-                        seg_l64, seg_r64, val, vok, area64,
-                    )
-                    hspan.set(pairs=overridden, rows=hrows)
-            meas_span.set(host_overridden=overridden)
-            meas_span.end()
+                hspan.set(pairs=overridden, rows=hrows)
             wlen = np.where(fan_swap, L.vlen[li[fan_r]], R.vlen[ri[fan_r]])
 
             def pairs_of(*rows):  # geometry pairs that hold such a row

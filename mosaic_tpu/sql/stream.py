@@ -1,7 +1,7 @@
 """Streaming join pipeline: HBM-resident batch ring + double-buffered
 cell-assignment prefetch.
 
-Why this layer exists (round-5 measurement, `STREAM_1B_r05.json`): the
+Why this layer exists (round-5 chip measurement, 2026-07-31): the
 1B-point device-gen stream sustained 47.2M pts/s against a 132.2M pts/s
 single-batch rate (0.357x) because the `fori_loop` folded point
 *generation* into every iteration and nothing overlapped batch staging
@@ -25,7 +25,7 @@ Three pieces, all CPU-testable and bit-identical to the per-batch path:
   (`runtime/telemetry.py`) with measured wall seconds, and
   :func:`hbm_peak` reports the loop's high-water device memory — from
   runtime memory stats when the backend exposes them, else a live-buffer
-  census (STREAM_1B_r05 recorded ``peak_hbm_bytes: 0`` from a backend
+  census (that round-5 run recorded ``peak_hbm_bytes: 0`` from a backend
   that reported no stats; that zero is the bug this closes).
 
 Completion is always forced by :func:`fold_stats` — a device-side
@@ -803,7 +803,8 @@ class StreamJoin:
         """Compile the durable-segment executables BEFORE the segment
         loop starts.
 
-        Round-12 stall attribution (``STALL_r12.json``) put 1.95 s of a
+        Round-12 stall attribution (a CPU run; the record last stood at
+        commit 76d32e0) put 1.95 s of a
         2.28 s durable run inside ``stream.segment[0]`` — almost all of
         it the seg_loop trace+compile, booked as *device* time because
         it happened under the segment span. Executing each distinct

@@ -21,7 +21,7 @@ from .profiler import (
     profile_polygons,
     profile_raster,
 )
-from .recommend import TuningProfile, load_priors, recommend
+from .recommend import TuningProfile, recommend
 from .resolve import KNOBS, resolve_knob, resolve_knobs
 from .store import (
     ProfileFingerprintMismatch,
@@ -38,7 +38,6 @@ __all__ = [
     "TuningProfile",
     "WorkloadProfile",
     "index_fingerprint",
-    "load_priors",
     "profile_overlay",
     "profile_points",
     "profile_polygons",

@@ -1,8 +1,7 @@
 """Workload profiling: sample a workload into a typed `WorkloadProfile`.
 
-The profile is the optimizer's input contract — the same statistics the
-bench ``detail`` blocks already collect (`tools/stream_bench.py`
-presample, `tools/raster_bench.py` occupancy), computed once on a capped
+The profile is the optimizer's input contract — match rate, class
+shares, tile occupancy — computed once on a capped
 host-side sample and recorded under a ``tune.profile`` span so profiling
 shows up in trails like any other stage:
 

@@ -2,10 +2,9 @@
 
 PAPERS.md's *Adaptive Geospatial Joins for Modern Hardware* picks the join
 strategy from measured data statistics; this module is that idea over our
-knob surface. Every rule is measurement-backed — either by the profile
-statistic it reads or by the committed bench history (`TREND.json`,
-``BENCH_*``/``STREAM_*``/``RASTER_*`` artifacts) loaded as priors — and
-every recommendation carries a machine-checkable rationale entry
+knob surface. Every rule reads a statistic of the profile it is given
+and nothing else: no file, no environment, no record of an earlier run.
+Every recommendation carries a machine-checkable rationale entry
 ``{knob, value, rule, evidence}`` so a reviewer (or a test) can replay the
 decision from the profile alone. A knob the rules have no evidence for
 stays None, which the resolver reads as "keep the built-in default" — the
@@ -15,19 +14,17 @@ optimizer never guesses.
 from __future__ import annotations
 
 import dataclasses
-import json
-from pathlib import Path
 
 from ..runtime import telemetry as _telemetry
 from .profiler import WorkloadProfile
 
-#: class-share threshold above which the per-cell router pays for itself —
-#: the round-7 probe bench (BENCH_r07) showed adaptive winning once dense
-#: cells carry >~25% of the points and losing (router overhead) below it
+#: class-share threshold above which the per-cell router is chosen: below
+#: it the router's overhead is spent on cells the scatter lane serves as
+#: well (a pre-chip CPU reading set it; no chip reading has moved it)
 ADAPTIVE_DENSE_SHARE = 0.25
 
-#: tile occupancy below which halving the tile shape wins — raster_bench
-#: round 6 (RASTER_r06): sparse coverage wastes pad compute in big tiles
+#: tile occupancy below which the tile shape is halved: sparse coverage
+#: spends a big tile's compute on padding
 SPARSE_TILE_OCCUPANCY = 0.5
 
 #: border-pair share above which an overlay join is predicate-bound and
@@ -38,20 +35,16 @@ SPARSE_TILE_OCCUPANCY = 0.5
 #: candidate list grows
 OVERLAY_BORDER_SHARE = 0.5
 
-#: candidate-pair count above which the device overlay lane amortizes its
-#: fixed costs (prep transfer + one fused launch) over enough pairs to beat
-#: the host numpy twin — the OVERLAY_r17 bench lane crosses over well below
-#: this, so the threshold is conservative; below it the host oracle lane is
-#: both exact and cheaper
+#: candidate-pair count above which the device overlay lane spreads its
+#: fixed costs (prep transfer + one fused launch) over enough pairs; below
+#: it the host numpy twin is exact and needs neither
 OVERLAY_DEVICE_CANDIDATES = 4096
 
 #: convex-candidate share above which the KNN Voronoi fast path pays: the
 #: one-shot cover dispatch needs the Voronoi walk's strict-descent
 #: guarantee, which only convex chip sites give, so its fallback-to-ring
-#: fraction tracks (1 - convex share). The KNN_r19 bench lane measured the
-#: Voronoi lane well above parity on an all-convex fixture (see
-#: ``detail.voronoi_speedup_vs_ring``); at half-convex the saved ring
-#: iterations still dominate the wasted walk on the non-convex half
+#: fraction tracks (1 - convex share); past half-convex the ring
+#: iterations saved outnumber the walks wasted on the non-convex half
 KNN_CONVEX_SHARE = 0.5
 
 
@@ -112,42 +105,14 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, int(n) - 1).bit_length()
 
 
-def load_priors(root: "str | Path | None" = None) -> dict:
-    """Best-effort read of the committed bench history: ``TREND.json``
-    plus any ``BENCH_*``/``STREAM_*``/``RASTER_*`` round artifacts under
-    ``root`` (default: the repository root, found relative to this file).
-    Missing or unreadable files are skipped — priors sharpen rules, they
-    never gate them."""
-    if root is None:
-        root = Path(__file__).resolve().parents[2]
-    root = Path(root)
-    priors: dict = {"artifacts": {}}
-    for pattern in (
-        "TREND.json",
-        "BENCH_*.json",
-        "STREAM_*.json",
-        "RASTER_*.json",
-        "OVERLAY_*.json",
-        "KNN_*.json",
-    ):
-        for path in sorted(root.glob(pattern)):
-            try:
-                priors["artifacts"][path.name] = json.loads(path.read_text())
-            except (OSError, ValueError):
-                continue
-    return priors
-
-
-def recommend(profile: WorkloadProfile, priors: "dict | None" = None) -> TuningProfile:
+def recommend(profile: WorkloadProfile) -> TuningProfile:
     """The rule table. Each branch appends one rationale entry; the
     returned profile's ``source`` echoes the statistics it read."""
-    if priors is None:
-        priors = load_priors()
     with _telemetry.timed("tune_stage", stage="recommend", kind=profile.kind):
-        return _recommend(profile, priors)
+        return _recommend(profile)
 
 
-def _recommend(profile: WorkloadProfile, priors: dict) -> TuningProfile:
+def _recommend(profile: WorkloadProfile) -> TuningProfile:
     out = TuningProfile()
     why = out.rationale
 
@@ -182,19 +147,13 @@ def _recommend(profile: WorkloadProfile, priors: dict) -> TuningProfile:
         )
 
     if profile.kind == "overlay" and profile.n_sampled:
-        speedup, artifact = _overlay_lane_prior(priors)
         evidence = {
             "candidates": profile.n_sampled,
             "threshold": OVERLAY_DEVICE_CANDIDATES,
-            "artifact": artifact,
-            "speedup_vs_host": speedup,
         }
-        if profile.n_sampled >= OVERLAY_DEVICE_CANDIDATES and (
-            speedup is None or speedup >= 1.0
-        ):
-            # the fused device lane wins once the fixed prep/launch cost is
-            # spread over enough pairs, provided the committed bench did not
-            # measure it losing to the host twin on this hardware
+        if profile.n_sampled >= OVERLAY_DEVICE_CANDIDATES:
+            # the fused device lane spreads its fixed prep/launch cost
+            # over enough pairs
             set_knob("overlay_lane", "device",
                      "device-lane-amortized-candidates", evidence)
         else:
@@ -218,18 +177,11 @@ def _recommend(profile: WorkloadProfile, priors: dict) -> TuningProfile:
             )
 
     if profile.kind == "points" and shares:
-        speedup, artifact = _knn_lane_prior(priors)
         convex = float(shares.get("convex", 0.0))
-        evidence = {
-            "convex": convex,
-            "threshold": KNN_CONVEX_SHARE,
-            "artifact": artifact,
-            "voronoi_speedup_vs_ring": speedup,
-        }
-        if convex > KNN_CONVEX_SHARE and (speedup is None or speedup >= 1.0):
+        evidence = {"convex": convex, "threshold": KNN_CONVEX_SHARE}
+        if convex > KNN_CONVEX_SHARE:
             # mostly-convex candidates: the Voronoi walk's one-shot cover
-            # replaces the iterative ring loop, and the committed bench
-            # did not measure it losing to ring on this hardware
+            # replaces the iterative ring loop
             set_knob("knn_lane", "voronoi",
                      "convex-share-voronoi-lane", evidence)
         else:
@@ -278,24 +230,7 @@ def _recommend(profile: WorkloadProfile, priors: dict) -> TuningProfile:
                  "threshold": SPARSE_TILE_OCCUPANCY},
             )
 
-    stream = _stream_pipeline_prior(priors)
-    if stream is not None:
-        window, speedup, name = stream
-        set_knob(
-            "stream_window", window, "bench-history-window",
-            {"artifact": name, "speedup_vs_sync": speedup},
-        )
-        if speedup is not None:
-            set_knob(
-                "stream_pipeline", bool(speedup >= 1.0),
-                "bench-history-pipeline-speedup",
-                {"artifact": name, "speedup_vs_sync": speedup},
-            )
-
-    out.source = {
-        "profile": profile.as_dict(),
-        "priors": sorted(priors.get("artifacts", {})),
-    }
+    out.source = {"profile": profile.as_dict()}
     _telemetry.record(
         "tune_recommend",
         kind=profile.kind,
@@ -303,68 +238,3 @@ def _recommend(profile: WorkloadProfile, priors: dict) -> TuningProfile:
         rules=",".join(sorted({r["rule"] for r in why})),
     )
     return out
-
-
-def _overlay_lane_prior(priors: dict):
-    """The committed overlay bench's device-vs-host measurement, when one
-    exists: ``(speedup_vs_host, artifact)``. A measured speedup < 1.0 means
-    the fused device lane lost to the host numpy twin on this hardware, so
-    the router should keep candidates on the host lane regardless of size."""
-    speedup, artifact = None, None
-    for name, art in sorted(priors.get("artifacts", {}).items()):
-        if not name.startswith("OVERLAY_") or not isinstance(art, dict):
-            continue
-        detail = art.get("detail")
-        if not isinstance(detail, dict):
-            continue
-        s = detail.get("speedup_vs_host")
-        if isinstance(s, (int, float)):
-            # newest round wins (names sort by round suffix)
-            speedup, artifact = float(s), name
-    return speedup, artifact
-
-
-def _knn_lane_prior(priors: dict):
-    """The committed KNN bench's Voronoi-vs-ring measurement, when one
-    exists: ``(voronoi_speedup_vs_ring, artifact)``. A measured speedup
-    < 1.0 means the one-shot Voronoi cover lost to iterative ring
-    expansion on this hardware, so the router keeps the ring lane even
-    for convex-dominated candidates."""
-    speedup, artifact = None, None
-    for name, art in sorted(priors.get("artifacts", {}).items()):
-        if not name.startswith("KNN_") or not isinstance(art, dict):
-            continue
-        detail = art.get("detail")
-        if not isinstance(detail, dict):
-            continue
-        s = detail.get("voronoi_speedup_vs_ring")
-        if isinstance(s, (int, float)):
-            # newest round wins (names sort by round suffix)
-            speedup, artifact = float(s), name
-    return speedup, artifact
-
-
-def _stream_pipeline_prior(priors: dict):
-    """The committed stream bench's pipelined-executor measurement, when
-    one exists: ``(window, speedup_vs_sync, artifact)``. The measured-good
-    window depth beats the hardcoded default, and the measured speedup
-    decides whether the pipelined lane is worth turning on at all."""
-    best = None
-    for name, art in sorted(priors.get("artifacts", {}).items()):
-        if not name.startswith("STREAM_") or not isinstance(art, dict):
-            continue
-        detail = art.get("detail")
-        pipe = detail.get("pipeline") if isinstance(detail, dict) else None
-        if not isinstance(pipe, dict):
-            continue
-        win = pipe.get("window")
-        if isinstance(win, (int, float)) and int(win) >= 1:
-            speedup = pipe.get("speedup_vs_sync")
-            cand = (
-                int(win),
-                float(speedup) if isinstance(speedup, (int, float)) else None,
-                name,
-            )
-            # newest round wins (names sort by round suffix)
-            best = cand
-    return best

@@ -118,6 +118,28 @@ class TestShardedBitIdentity:
         )
         np.testing.assert_array_equal(np.asarray(sharded), oracle)
 
+    @pytest.mark.parametrize("mesh", MESHES)
+    def test_dispatch_core_padded(self, index, points, mesh):
+        """The core itself, no frontend around it: one padded batch
+        through a ``dp``-sized mesh is the single-device core's answer
+        and the f64 oracle's, under ONE signature a core."""
+        ladder = BucketLadder(1024, 1024)
+        oracle = host_join(points, index.host, CUSTOM, RES)
+
+        def run(dp):
+            core = dispatch.DispatchCore(
+                index, CUSTOM, RES, ladder=ladder,
+                mesh=None if dp == 1 else dp,
+            )
+            padded, n = core.ladder.pad(points)
+            out = np.asarray(core.execute_padded(padded))[:n]
+            assert len(core.signatures) == 1
+            return out
+
+        single = run(1)
+        np.testing.assert_array_equal(single, oracle)
+        np.testing.assert_array_equal(run(mesh), single)
+
     def test_pip_join_mesh_rejects_recheck(self, index, points):
         with pytest.raises(ValueError, match="recheck"):
             pip_join(

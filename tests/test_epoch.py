@@ -254,6 +254,55 @@ class TestFrontendsVsOracle:
             )
 
 
+    def test_publishes_under_live_traffic_answer_an_epoch_each(self, pts):
+        """Publishes INTO an engine a client thread keeps querying: no
+        request errors, and every answer is one whole epoch's — the
+        f64 oracle's of an epoch that was published by the time the
+        request returned, never a mix of two."""
+        import threading
+
+        ep = mk()
+        ep.publish()
+        oracles = [host_join(pts, ep.index.host, CUSTOM, RES)]
+        answers, errors = [], []
+        stop = threading.Event()
+        with ServeEngine(
+            ep.index, CUSTOM, RES, ladder=BucketLadder(64, 1024),
+            bounds=BBOX, max_wait_s=0.0,
+        ) as eng:
+            eng.warmup()
+
+            def client():
+                while not stop.is_set():
+                    try:
+                        answers.append(
+                            np.asarray(eng.join(pts, deadline_s=60.0))
+                        )
+                    except Exception as e:  # lint: broad-except-ok (the assertion IS that no request errors; collect, don't mask)
+                        errors.append(repr(e))
+                        return
+
+            t = threading.Thread(target=client, daemon=True)  # lint: thread-context-adoption-ok (load generator: answers only, no telemetry read from this thread)
+            t.start()
+            for edit in EDITS:
+                edit(ep)
+                before = len(answers)
+                ep.publish(eng)
+                oracles.append(host_join(pts, ep.index.host, CUSTOM, RES))
+                while len(answers) == before and not errors:
+                    stop.wait(0.001)  # traffic flows past every publish
+            stop.set()
+            t.join(timeout=60)
+            # a request in flight at a swap finishes on the epoch it began
+            # on; one sent after the last publish is the last epoch's
+            final = np.asarray(eng.join(pts, deadline_s=60.0))
+        assert not errors, errors[0]
+        assert ep.epoch == len(EDITS)
+        for got in answers:
+            assert any(np.array_equal(got, want) for want in oracles)
+        np.testing.assert_array_equal(final, oracles[-1])
+
+
 # ------------------------------------------------- kill-storm replay
 
 

@@ -347,6 +347,34 @@ class TestFusion:
             if e["event"] == "span" and e["name"] == "dispatch.compile"
         ]
 
+    def test_fused_launches_once_a_tile_where_the_staged_ops_launch_twice(
+        self, engine, raster
+    ):
+        """The fusion claim, counted: ``map`` dispatches every tile once;
+        the op sequence it replaces (``rst_mapbands`` into a NaN-nodata
+        raster, then ``zones`` over that raster) dispatches every tile
+        twice — and both give the same bits."""
+        value = E.ndvi(nir=2, red=1).mask_where(E.band(3) < 80.0)
+        with telemetry.capture() as fused_ev:
+            fused = engine.map(value.zonal(by="zones"), raster,
+                               tile=(32, 32))
+        with telemetry.capture() as staged_ev:
+            px = rst_mapbands([raster], value, tile=(32, 32))[0]
+            staged = engine.zones(px, tile=(32, 32))
+        _assert_result_equal(fused, staged)
+
+        def tiles(events, event, stage):
+            return sum(
+                int(e["ntiles"]) for e in events
+                if e["event"] == event and e.get("stage") == stage
+            )
+
+        n = tiles(fused_ev, "expr_stage", "map")
+        assert n == 9  # 75 x 90 at (32, 32)
+        assert tiles(fused_ev, "raster_stage", "zonal") == 0
+        assert tiles(staged_ev, "expr_stage", "pixels") == n
+        assert tiles(staged_ev, "raster_stage", "zonal") == n
+
     def test_map_emits_expr_stage(self, engine, raster):
         with telemetry.capture() as ev:
             engine.map(_pipeline(), raster, tile=(32, 32))

@@ -368,6 +368,40 @@ class TestBatchModelCache:
         )
 
 
+class TestStoreRelaunch:
+    def test_second_frontend_warms_from_the_store_and_compiles_nothing(
+        self, knn_problem, tmp_path
+    ):
+        """A frontend relaunched against the program store the first one
+        exported loads every rung, exports none, and serves with zero
+        backend compiles — the oracle's bits."""
+        _, kx, qpts = knn_problem
+        store = str(tmp_path)
+        kw = dict(lane="ring", row_ladder=ROWS, pair_ladder=PAIRS,
+                  program_store=store)
+        fe1 = KNNFrontend(kx, **kw)
+        w1 = fe1.warmup()
+        assert w1["aot"]["exported"] > 0 and w1["aot"]["loaded"] == 0
+        # the same query through the first frontend: the engine's eager
+        # host-side ops compile here at this query's shapes, so what is
+        # counted below is the relaunched frontend's own programs
+        q = qpts(9, seed=77)
+        fe1.dispatch(q, 3)
+        fe = KNNFrontend(kx, **kw)
+        w2 = fe.warmup()
+        assert w2["aot"] == {
+            "loaded": w1["aot"]["exported"], "exported": 0, "fallback": 0,
+        }
+        n0 = _dispatch.backend_compiles()
+        out, _ = fe.dispatch(q, 3)
+        assert _dispatch.backend_compiles() == n0
+        assert fe.cold_compiles == 0
+        ids, dist = decode_knn(np.asarray(out), 3)
+        oids, odist = oracle(kx, q, 3)
+        np.testing.assert_array_equal(ids, oids)
+        assert np.array_equal(dist, odist)
+
+
 class TestTuneRouting:
     def test_convex_share_routes_voronoi_with_machine_rationale(self):
         from mosaic_tpu.tune.profiler import WorkloadProfile
@@ -377,33 +411,9 @@ class TestTuneRouting:
             kind="points", n_sampled=100, n_total=1000,
             class_shares={"light": 0.2, "heavy": 0.1, "convex": 0.7},
         )
-        rec = recommend(prof, priors={})
+        rec = recommend(prof)
         assert rec.knn_lane == "voronoi"
         (entry,) = [r for r in rec.rationale if r["knob"] == "knn_lane"]
         assert set(entry) == {"knob", "value", "rule", "evidence"}
         assert entry["rule"] == "convex-share-voronoi-lane"
         assert entry["evidence"]["threshold"] == pytest.approx(0.5)
-
-    def test_measured_regression_keeps_ring_lane(self):
-        from mosaic_tpu.tune.profiler import WorkloadProfile
-        from mosaic_tpu.tune.recommend import recommend
-
-        prof = WorkloadProfile(
-            kind="points", n_sampled=100, n_total=1000,
-            class_shares={"light": 0.1, "heavy": 0.1, "convex": 0.8},
-        )
-        priors = {"artifacts": {"KNN_r19.json": {
-            "detail": {"voronoi_speedup_vs_ring": 0.7},
-        }}}
-        rec = recommend(prof, priors=priors)
-        assert rec.knn_lane == "ring"
-        (entry,) = [r for r in rec.rationale if r["knob"] == "knn_lane"]
-        assert entry["evidence"]["voronoi_speedup_vs_ring"] == 0.7
-
-    def test_committed_artifact_loads_as_prior(self):
-        from mosaic_tpu.tune.recommend import load_priors
-
-        priors = load_priors()
-        knn = [a for a in priors["artifacts"] if a.startswith("KNN_")]
-        assert knn, "KNN_r19.json must be committed and loadable"
-

@@ -487,7 +487,7 @@ class TestDurableStreamTrace:
         assert t["roots"] == 1 and not t["orphans"], t
 
 
-# ------------------------------------------------------------- perf gate
+# ----------------------------------------------------------- trace report
 
 
 def _mk_trail(tmp_path, name, stages):
@@ -509,151 +509,6 @@ BASE_STAGES = {
     "join_loop": (2.0, 2),
     "dispatch": (0.5, 10),
 }
-
-
-class TestPerfGate:
-    def test_green_on_identical_and_uniformly_slower_runs(self, tmp_path):
-        import perf_gate
-
-        trail = _mk_trail(tmp_path, "a.jsonl", BASE_STAGES)
-        fresh = perf_gate.stage_odds(obs.read_trail(trail))
-        golden = {
-            "tolerance": 3.0, "odds_floor": 0.02,
-            "stages": {
-                k: {"odds": v["odds"], "require": True}
-                for k, v in fresh.items()
-            },
-        }
-        ok, verdicts = perf_gate.evaluate(fresh, golden)
-        assert ok, verdicts
-        # a uniformly 5x slower machine keeps every odds identical
-        slow = _mk_trail(tmp_path, "slow.jsonl", {
-            k: (s * 5, c) for k, (s, c) in BASE_STAGES.items()
-        })
-        ok, verdicts = perf_gate.evaluate(
-            perf_gate.stage_odds(obs.read_trail(slow)), golden
-        )
-        assert ok, verdicts
-
-    def test_red_on_10x_single_stage_slowdown(self, tmp_path):
-        import perf_gate
-
-        trail = _mk_trail(tmp_path, "a.jsonl", BASE_STAGES)
-        fresh = perf_gate.stage_odds(obs.read_trail(trail))
-        golden = {
-            "tolerance": 3.0, "odds_floor": 0.02,
-            "stages": {
-                k: {"odds": v["odds"], "require": True}
-                for k, v in fresh.items()
-            },
-        }
-        for stage in ("compile", "join_loop", "dispatch"):
-            bad = _mk_trail(tmp_path, f"bad_{stage}.jsonl", {
-                k: ((s * 10 if k == stage else s), c)
-                for k, (s, c) in BASE_STAGES.items()
-            })
-            ok, verdicts = perf_gate.evaluate(
-                perf_gate.stage_odds(obs.read_trail(bad)), golden
-            )
-            assert not ok, (stage, verdicts)
-            assert verdicts[f"bench_stage.{stage}"]["status"] == "SLOW"
-
-    def test_trail_pools_isolate_odds(self, tmp_path, monkeypatch,
-                                      capsys):
-        """Each --trail is its own odds pool: a huge unrelated bench in
-        another trail must not dilute a small stage's odds below the
-        point where a 10x slowdown can escape odds_floor."""
-        import perf_gate
-
-        small = _mk_trail(tmp_path, "small.jsonl", {
-            "light": (0.02, 3), "heavy": (0.04, 3),
-        })
-        # 1000x the small trail's total: pooled odds would sink
-        # heavy to ~0.0007, where 10x stays under 3*odds + 0.02
-        huge = _mk_trail(tmp_path, "huge.jsonl", {"compile": (60.0, 1)})
-        golden = str(tmp_path / "golden.json")
-        monkeypatch.setattr(sys, "argv", [
-            "perf_gate.py", "--update", "--golden", golden,
-            "--trail", small, "--trail", huge,
-        ])
-        assert perf_gate.main() == 0
-        capsys.readouterr()
-        monkeypatch.setattr(sys, "argv", [
-            "perf_gate.py", "--golden", golden,
-            "--trail", small, "--trail", huge,
-            "--inject-slowdown", "bench_stage.heavy:10",
-        ])
-        assert perf_gate.main() == 1
-        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert out["stages"]["bench_stage.heavy"]["status"] == "SLOW"
-        # and the huge trail's own stage still gates green
-        assert out["stages"]["bench_stage.compile"]["ok"] is True
-
-    def test_missing_required_stage_is_red(self, tmp_path):
-        import perf_gate
-
-        golden = {
-            "tolerance": 3.0, "odds_floor": 0.02,
-            "stages": {
-                "bench_stage.vanished": {"odds": 0.5, "require": True},
-            },
-        }
-        trail = _mk_trail(tmp_path, "a.jsonl", {"other": (1.0, 1)})
-        ok, verdicts = perf_gate.evaluate(
-            perf_gate.stage_odds(obs.read_trail(trail)), golden
-        )
-        assert not ok
-        assert (
-            verdicts["bench_stage.vanished"]["status"]
-            == "MISSING_REQUIRED"
-        )
-
-    def test_cli_update_then_gate_and_inject(self, tmp_path, monkeypatch,
-                                             capsys):
-        import perf_gate
-
-        trail = _mk_trail(tmp_path, "a.jsonl", BASE_STAGES)
-        golden = str(tmp_path / "golden.json")
-        monkeypatch.setattr(sys, "argv", [
-            "perf_gate.py", "--update", "--golden", golden,
-            "--trail", trail,
-        ])
-        assert perf_gate.main() == 0
-        capsys.readouterr()
-        monkeypatch.setattr(sys, "argv", [
-            "perf_gate.py", "--golden", golden, "--trail", trail,
-        ])
-        assert perf_gate.main() == 0
-        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert out["pass"] is True
-        monkeypatch.setattr(sys, "argv", [
-            "perf_gate.py", "--golden", golden, "--trail", trail,
-            "--inject-slowdown", "bench_stage.join_loop:10",
-        ])
-        assert perf_gate.main() == 1
-        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert out["pass"] is False
-
-    def test_committed_golden_parses_and_gates_its_own_stages(self):
-        """The committed golden is well-formed: stage odds positive,
-        tolerance sane, and every stage key names a real bench stage."""
-        with open(REPO / "tests" / "goldens" / "perf_gate.json") as f:
-            golden = json.load(f)
-        assert 1.0 < golden["tolerance"] <= 10.0
-        assert golden["stages"], "empty golden gates nothing"
-        for key, g in golden["stages"].items():
-            assert g["odds"] > 0, key
-            assert key.split(".")[0] in (
-                "serve_stage", "stream_stage", "serve_request",
-                "recheck_narrow", "quarantine_stage", "snapshot_saved",
-                "probe_stage", "raster_stage", "multichip_stage",
-                "expr_stage", "tune_stage", "router_stage",
-                "overlay_stage", "epoch_stage", "knn_stage",
-                "ops_stage",
-            ), key
-
-
-# ----------------------------------------------------------- trace report
 
 
 class TestTraceReport:
@@ -730,7 +585,7 @@ class TestTraceReport:
     def test_diff_against_summary_only_artifact(self, tmp_path,
                                                 monkeypatch, capsys):
         """A bench artifact whose detail.stages is a DICT of per-stage
-        summaries (the perf_gate golden shape) must yield a real base
+        summaries ({stage_key: {total_s, count}}) must yield a real base
         breakdown, not a silently-empty one."""
         import trace_report
 

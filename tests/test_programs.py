@@ -28,6 +28,11 @@ from mosaic_tpu.sql.join import build_chip_index, pip_join
 
 BBOX = (-25.0, -25.0, 35.0, 20.0)
 RES = 3
+ZONES = [
+    "POLYGON ((1 1, 13 2, 12 11, 6 14, 2 9, 1 1))",
+    "POLYGON ((-20 -20, -5 -20, -5 -5, -20 -5, -20 -20))",
+    "POLYGON ((20 -10, 30 -10, 30 5, 20 5, 20 -10))",
+]
 
 
 @pytest.fixture(scope="module")
@@ -37,13 +42,7 @@ def grid():
 
 @pytest.fixture(scope="module")
 def index(grid):
-    col = wkt.from_wkt(
-        [
-            "POLYGON ((1 1, 13 2, 12 11, 6 14, 2 9, 1 1))",
-            "POLYGON ((-20 -20, -5 -20, -5 -5, -20 -5, -20 -20))",
-            "POLYGON ((20 -10, 30 -10, 30 5, 20 5, 20 -10))",
-        ]
-    )
+    col = wkt.from_wkt(ZONES)
     return build_chip_index(tessellate(col, grid, RES, keep_core_geoms=False))
 
 
@@ -251,6 +250,75 @@ class TestCoreAOT:
         assert core._programs is None
         w = core.warmup()
         assert "aot" not in w
+
+
+#: one serve lifetime in a process of its own: build the SAME index
+#: (the tessellation fingerprint is the store key), warm from the store,
+#: answer a fixed probe set, print one JSON line
+_CHILD = """
+import hashlib, json, sys
+import numpy as np
+from mosaic_tpu.core.geometry import wkt
+from mosaic_tpu.core.index import CustomIndexSystem, GridConf
+from mosaic_tpu.core.tessellate import tessellate
+from mosaic_tpu.dispatch import DispatchCore, backend_compiles
+from mosaic_tpu.serve import BucketLadder
+from mosaic_tpu.sql.join import build_chip_index
+
+grid = CustomIndexSystem(GridConf(-180, 180, -90, 90, 2, 10.0, 10.0))
+col = wkt.from_wkt(json.loads(sys.argv[2]))
+index = build_chip_index(tessellate(col, grid, 3, keep_core_geoms=False))
+core = DispatchCore(
+    index, grid, 3, ladder=BucketLadder(64, 256), program_store=sys.argv[1]
+)
+n0 = backend_compiles()
+warm = core.warmup()
+pts = np.random.default_rng(11).uniform((-25, -25), (35, 20), (100, 2))
+padded, n = core.ladder.pad(pts)
+out = np.asarray(core.execute_padded(padded))[:n]
+print(json.dumps({
+    "aot": warm["aot"], "cold_compiles": core.cold_compiles,
+    "backend_compiles": backend_compiles() - n0,
+    "sha": hashlib.sha256(out.astype(np.int64).tobytes()).hexdigest(),
+}))
+"""
+
+
+class TestRelaunchedProcess:
+    def test_a_child_process_warms_from_the_store_and_compiles_nothing(
+        self, index, grid, tmp_path, pts
+    ):
+        """A REAL relaunch: no in-memory executable cache can stand in
+        for the store. The first child exports every rung; the second
+        loads them all, exports none, compiles nothing, and both answer
+        this process's reference bit for bit."""
+        import hashlib
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+        env.pop("MOSAIC_PROGRAM_STORE", None)
+
+        def child():
+            done = subprocess.run(
+                [sys.executable, "-c", _CHILD, str(tmp_path),
+                 json.dumps(ZONES)], env=env,
+                capture_output=True, text=True, timeout=300,
+            )
+            assert done.returncode == 0, done.stderr[-2000:]
+            return json.loads(done.stdout.strip().splitlines()[-1])
+
+        cold, warm = child(), child()
+        assert cold["aot"] == {"loaded": 0, "exported": 6, "fallback": 0}
+        assert warm["aot"] == {"loaded": 6, "exported": 0, "fallback": 0}
+        assert warm["cold_compiles"] == 0
+        assert warm["backend_compiles"] == 0
+        ref = np.asarray(
+            pip_join(pts, None, grid, RES, chip_index=index, recheck=False)
+        )
+        want = hashlib.sha256(ref.astype(np.int64).tobytes()).hexdigest()
+        assert cold["sha"] == warm["sha"] == want
 
 
 # --------------------------------------------------- epochal provenance

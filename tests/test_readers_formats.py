@@ -1340,7 +1340,7 @@ def test_write_kml_round_trip(tmp_path):
 def test_osm_closed_waterway_and_place_are_polygons(tmp_path):
     """`waterway` and `place` are SEPARATE area keys: the seed's missing
     comma concatenated them into one bogus "waterwayplace" key, so a
-    closed riverbank way came back as a line (ADVICE.md)."""
+    closed riverbank way came back as a line."""
     from mosaic_tpu.readers import read
 
     osm = """<?xml version='1.0'?>

@@ -10,7 +10,7 @@ backend is bit-identity and accounting:
    values);
 3. every pipeline stage emits a `stream_stage` telemetry event with a
    non-negative measured duration;
-4. memory accounting never reports zero (the STREAM_1B_r05
+4. memory accounting never reports zero (the round-5
    ``peak_hbm_bytes: 0`` artifact bug): when the backend exposes no
    memory stats, the live-buffer census lower-bounds the peak.
 """

@@ -92,7 +92,7 @@ class TestKeysAndClasses:
     def test_containers_and_unknowns_stay_unclassified(self):
         for key in (
             "span.stream.durable_run", "stream_stage.durable_loop",
-            "span.serve.request", "span.stream_bench",
+            "span.serve.request",
             "stream_stage.single_batch", "no_such_key", None,
             # host intervals around a dispatch: containers of the spans
             # that say what the host did, not device time
@@ -402,7 +402,7 @@ class TestRealDurableRunAttribution:
             sj.run_durable(
                 ring, 6, run_dir=str(tmp_path / "run"), snapshot_every=2
             )
-            # the single-batch rate stream_bench would have measured
+            # a single-batch rate, recorded by the caller
             telemetry.record(
                 "stream_stage", stage="single_batch", seconds=0.001,
                 batch=2048, points_per_sec=2048 / 0.001,
@@ -601,7 +601,7 @@ def _fresh_stream(found_cap):
 
 
 class TestSegLoopCompileHoist:
-    """Satellite of ISSUE 13: STALL_r12.json booked 1.95 s of a 2.28 s
+    """Satellite of ISSUE 13: a round-12 CPU run booked 1.95 s of a 2.28 s
     durable run inside stream.segment[0] — the seg_loop trace+compile,
     misattributed as device time. The hoist compiles BEFORE the segment
     loop under a ``dispatch.compile`` span, so segment[0]'s device

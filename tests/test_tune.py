@@ -108,6 +108,13 @@ def _mk_raster(h=64, w=64, nodata=-9.0, seed=5):
     )
 
 
+def _small_ring():
+    rng = np.random.default_rng(3)
+    return ring_from_host(
+        [rng.uniform(BBOX[:2], BBOX[2:], (512, 2)) for _ in range(2)]
+    )
+
+
 def resolve_events(events, entry):
     return [
         e for e in events
@@ -291,10 +298,7 @@ class TestStreamJoinPrecedence:
 
     def test_durable_run_knobs(self, index, tmp_path, monkeypatch):
         """stream_window / stream_pipeline resolve per durable run."""
-        rng = np.random.default_rng(3)
-        ring = ring_from_host(
-            [rng.uniform(BBOX[:2], BBOX[2:], (512, 2)) for _ in range(2)]
-        )
+        ring = _small_ring()
         prof = TuningProfile(stream_window=2, stream_pipeline=True)
         sj = StreamJoin(index, CUSTOM, RES, profile=prof)
 
@@ -681,10 +685,8 @@ class TestProfiler:
 
 class TestRecommend:
     def test_rationale_is_machine_checkable(self, zones, index, points):
-        poly = recommend(profile_polygons(zones, CUSTOM), priors={})
-        pts = recommend(
-            profile_points(points, index, CUSTOM, RES), priors={}
-        )
+        poly = recommend(profile_polygons(zones, CUSTOM))
+        pts = recommend(profile_points(points, index, CUSTOM, RES))
         merged = TuningProfile.merged(poly, pts)
         assert merged.resolution == poly.resolution
         assert merged.probe == pts.probe
@@ -704,7 +706,7 @@ class TestRecommend:
             kind="points", n_sampled=4096,
             class_shares={"heavy": 0.3, "convex": 0.1, "light": 0.6},
         )
-        rec = recommend(prof, priors={})
+        rec = recommend(prof)
         assert rec.probe == "adaptive"
         (rule,) = [r for r in rec.rationale if r["knob"] == "probe"]
         assert rule["rule"] == "dense-share-router"
@@ -714,13 +716,13 @@ class TestRecommend:
             kind="points", n_sampled=4096,
             class_shares={"heavy": 0.05, "convex": 0.05, "light": 0.9},
         )
-        assert recommend(prof, priors={}).probe == "scatter"
+        assert recommend(prof).probe == "scatter"
 
     def test_band_fraction_pins_fold_lane(self):
         prof = WorkloadProfile(
             kind="points", n_sampled=64, band_fraction=0.2
         )
-        assert recommend(prof, priors={}).zonal_lane == "fold"
+        assert recommend(prof).zonal_lane == "fold"
 
     def test_sparse_raster_shrinks_tiles(self):
         sparse = WorkloadProfile(
@@ -729,26 +731,110 @@ class TestRecommend:
         dense = WorkloadProfile(
             kind="raster", n_sampled=9, tile_occupancy=0.9
         )
-        assert recommend(sparse, priors={}).raster_tile == (128, 128)
-        assert recommend(dense, priors={}).raster_tile == (256, 256)
+        assert recommend(sparse).raster_tile == (128, 128)
+        assert recommend(dense).raster_tile == (256, 256)
 
-    def test_stream_prior_sets_window(self):
-        priors = {"artifacts": {"STREAM_CPU_r99.json": {
-            "detail": {"pipeline": {"window": 6, "speedup_vs_sync": 1.2}}
-        }}}
-        rec = recommend(
-            WorkloadProfile(kind="points", n_sampled=0), priors=priors
-        )
-        assert rec.stream_window == 6 and rec.stream_pipeline is True
 
-    def test_stream_prior_can_disable_pipeline(self):
-        priors = {"artifacts": {"STREAM_CPU_r99.json": {
-            "detail": {"pipeline": {"window": 4, "speedup_vs_sync": 0.8}}
-        }}}
-        rec = recommend(
-            WorkloadProfile(kind="points", n_sampled=0), priors=priors
+    def test_recommended_profile_through_the_store_answers_the_default_bits(
+        self, zones, index, points, tmp_path
+    ):
+        """Profile, recommend, persist, load, join: whatever resolution
+        and probe the rules pick, the rechecked answer is the f64
+        oracle's, so it is the default knobs' answer bit for bit."""
+        rec = TuningProfile.merged(
+            recommend(profile_polygons(zones, CUSTOM)),
+            recommend(profile_points(points, index, CUSTOM, RES)),
         )
-        assert rec.stream_pipeline is False
+        assert rec.resolution is not None and rec.probe is not None
+        tuned = build_chip_index(tessellate(
+            zones, CUSTOM, rec.resolution, keep_core_geoms=False
+        ))
+        store = ProfileStore(str(tmp_path))
+        store.save(rec, fingerprint=index_fingerprint(tuned))
+        loaded, _ = store.load_latest(
+            expect_fingerprint=index_fingerprint(tuned)
+        )
+        assert loaded.as_dict() == rec.as_dict()
+        got = pip_join(
+            points, None, CUSTOM, None, chip_index=tuned, profile=loaded,
+            recheck=True,
+        )
+        want = pip_join(
+            points, None, CUSTOM, RES, chip_index=index, recheck=True
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+#: one profile a kind, every rule of its kind firing
+RULE_PROFILES = {
+    "points": WorkloadProfile(
+        kind="points", n_sampled=4096, n_total=100_000, band_fraction=0.2,
+        class_shares={"heavy": 0.1, "convex": 0.6, "light": 0.3},
+    ),
+    "polygons": WorkloadProfile(
+        kind="polygons", n_sampled=3, optimal_resolution=4,
+        cells_per_geom={"mean": 12.0},
+    ),
+    "overlay": WorkloadProfile(
+        kind="overlay", n_sampled=8192, resolution=3,
+        sure_fraction=0.2, border_fraction=0.8,
+    ),
+    "raster": WorkloadProfile(
+        kind="raster", n_sampled=9, tile_occupancy=0.2
+    ),
+}
+
+
+class TestRulesReadTheirArgument:
+    @pytest.mark.parametrize("kind", sorted(RULE_PROFILES))
+    def test_recommend_reads_no_file_and_no_directory(
+        self, kind, tmp_path, monkeypatch
+    ):
+        """The same profile gives the same `TuningProfile` from any
+        working directory, with every way to list or read a file made
+        to raise for the length of the call."""
+        import pathlib
+
+        def refuse(*a, **k):
+            raise AssertionError("recommend() touched the file system")
+
+        prof = RULE_PROFILES[kind]
+        answers = []
+        for i in range(2):
+            d = tmp_path / f"cwd{i}"
+            d.mkdir()
+            (d / "TREND.json").write_text("{not json")
+            with monkeypatch.context() as m:
+                m.chdir(d)
+                for name in ("glob", "rglob", "iterdir", "open",
+                             "read_text", "read_bytes"):
+                    m.setattr(pathlib.Path, name, refuse)
+                answers.append(recommend(prof).as_dict())
+        assert answers[0] == answers[1]
+        assert answers[0]["rationale"], "the profile fired no rule"
+        assert set(answers[0]["source"]) == {"profile"}
+
+    def test_no_kind_sets_a_stream_knob(self, index, tmp_path):
+        """The durable loop's executor and window are the resolver's
+        defaults to decide (pipeline off, window None): no rule has a
+        chip reading to set them from."""
+        for prof in RULE_PROFILES.values():
+            rec = recommend(prof)
+            assert rec.stream_window is None
+            assert rec.stream_pipeline is None
+            assert not {
+                r["knob"] for r in rec.rationale
+            } & {"stream_window", "stream_pipeline"}
+        ring = _small_ring()
+        sj = StreamJoin(
+            index, CUSTOM, RES, profile=recommend(RULE_PROFILES["points"])
+        )
+        with telemetry.capture() as events:
+            sj.run_durable(ring, 2, run_dir=str(tmp_path))
+        (ev,) = resolve_events(events, "stream_join.run_durable")
+        assert ev["stream_pipeline_source"] == "default"
+        assert ev["stream_window_source"] == "default"
+        assert ev["stream_pipeline"] is False and ev["stream_window"] is None
 
 
 # ------------------------------------------------------------ satellites
@@ -827,7 +913,7 @@ class TestOverlayProfile:
             kind="overlay", n_sampled=100, resolution=3,
             sure_fraction=0.2, border_fraction=0.8,
         )
-        rec = recommend(prof, priors={})
+        rec = recommend(prof)
         assert rec.resolution == 4
         (rule,) = [r for r in rec.rationale if r["knob"] == "resolution"]
         assert rule["rule"] == "border-dominated-finer-tessellation"
@@ -839,6 +925,6 @@ class TestOverlayProfile:
             kind="overlay", n_sampled=100, resolution=3,
             sure_fraction=0.9, border_fraction=0.1,
         )
-        rec = recommend(prof, priors={})
+        rec = recommend(prof)
         assert rec.resolution is None
         assert not [r for r in rec.rationale if r["knob"] == "resolution"]

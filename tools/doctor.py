@@ -28,15 +28,14 @@ known-failure-signature checks this codebase has accumulated:
 
 Every check reports ``green`` or ``red`` with its findings; overall
 ``status`` is red when any check is. The LAST stdout line is one JSON
-object (the repo-wide bench contract); exit code 1 on red. The scan
+object (the repo-wide tool contract); exit code 1 on red. The scan
 and checks run under timed ``ops_stage`` telemetry (``ops_stage.scan``,
-``ops_stage.checks``), exportable with ``--trail`` — the doctor's own
-work is gated by `tools/perf_gate.py` like every other stage.
+``ops_stage.checks``), exportable with ``--trail``.
 
 Usage:
-  python tools/doctor.py *.json                      # committed artifacts
+  python tools/doctor.py /tmp/run/*.json             # result artifacts
   python tools/doctor.py /tmp/storm/*.jsonl          # live trails
-  python tools/doctor.py SERVE_r16.json /tmp/t.jsonl --trail /tmp/doc.jsonl
+  python tools/doctor.py /tmp/serve.json /tmp/t.jsonl --trail /tmp/doc.jsonl
 """
 
 from __future__ import annotations

@@ -55,8 +55,8 @@ def _obs_lines() -> list[str]:
         "`runtime/telemetry.py` event spine (see ARCHITECTURE.md "
         "\"Observability\" for the span taxonomy). CLI companions: "
         "`tools/trace_report.py` (per-stage breakdown + `--against` "
-        "diff), `tools/perf_gate.py` (CI regression gate over "
-        "`tests/goldens/perf_gate.json`).",
+        "diff), `tools/stall_report.py` (a window's wall time by "
+        "stall class).",
         "",
         "| Name | Summary |",
         "|---|---|",

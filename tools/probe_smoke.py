@@ -12,18 +12,14 @@ CPU (the Pallas kernel under ``interpret=True``), force each lane via
 2. the rechecked adaptive join equals the exact f64 host oracle row for
    row (``host_join_with_cells``);
 3. each forced lane emits one timed ``probe_stage.<lane>`` telemetry
-   event — the stage keys `tools/perf_gate.py` gates, so a lane-share
-   regression fails CI, not just a headline slowdown.
+   event.
 
-The per-lane roofline rides along in ``detail.roofline``: bytes/pt per
-lane computed from the index arrays the lane actually touches (never
-hand-written) times the measured rate. The final stdout line is ALWAYS
-one machine-parseable JSON object; everything else goes to stderr.
+It states no rate: what a lane does in a second is the chip's to say
+(`benchmark/run.py`). The final stdout line is ALWAYS one
+machine-parseable JSON object; everything else goes to stderr.
 
 Usage (CI probe-smoke lane):
   python tools/probe_smoke.py --points 60000 --trail /tmp/probe.jsonl
-  python tools/perf_gate.py --golden tests/goldens/perf_gate.json \
-      --trail /tmp/probe.jsonl ...
 """
 
 from __future__ import annotations
@@ -217,40 +213,16 @@ def main() -> int:
                 )
         detail["oracle_identical"] = True
 
-        # 3) timed forced-lane dispatches -> the gated probe_stage keys
-        bucket_b = (
-            int(index.table_rows.shape[1]) * index.table_rows.dtype.itemsize
-        )
-        edge_b = (
-            int(index.cell_edges.shape[-1])
-            * index.cell_edges.dtype.itemsize
-            + index.cell_ebits.dtype.itemsize
-        )
-        e1 = int(index.cell_edges.shape[1])
-        e2 = int(index.heavy_edges.shape[1])
-        e3 = int(index.convex_edges.shape[2])
-        lane_bpp = {
-            "light": bucket_b + edge_b * e1,
-            "heavy": bucket_b + edge_b * (e1 + e2),
-            "convex": bucket_b + edge_b * e3,
-        }
-        roofline = {"per_lane": {}}
+        # 3) one timed probe_stage event a forced lane, warm
         n = len(pts)
         for lane in LANES:
             run(pts, f"force:{lane}")  # warm: compile outside the timing
             t0 = time.perf_counter()
             run(pts, f"force:{lane}")
-            dt = time.perf_counter() - t0
             telemetry.record(
-                "probe_stage", stage=lane, seconds=round(dt, 6), n=n
+                "probe_stage", stage=lane,
+                seconds=round(time.perf_counter() - t0, 6), n=n,
             )
-            rate = n / max(dt, 1e-9)
-            roofline["per_lane"][lane] = {
-                "bytes_per_point": lane_bpp[lane],
-                "points_per_sec": round(rate, 1),
-                "achieved_gbps": round(lane_bpp[lane] * rate / 1e9, 3),
-            }
-        detail["roofline"] = roofline
         line["value"] = len(LANES)
         rc = 0
     except Exception as e:

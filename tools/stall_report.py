@@ -2,7 +2,7 @@
 
 The answer to the ROADMAP's streaming question ("sustained is 0.26× of
 single-batch — find where the 0.74 goes before rewriting"): given a
-telemetry trail (`stream_bench --durable --trail ...`, a serve trail,
+telemetry trail (a durable stream's, a serve trail,
 or a flight-recorder dump), reconstruct the interval timeline
 (`mosaic_tpu/obs/timeline.py`), pick the attribution window (the
 durable loop when present), and partition its wall time into the
@@ -15,7 +15,7 @@ every instant has ONE owner), so the classes sum to the measured wall;
 the CI lane asserts the 5% bound anyway as an end-to-end tripwire.
 
 When the trail carries both the durable loop and a single-batch rate
-(``stream_stage.single_batch``, emitted by `tools/stream_bench.py`),
+(a ``stream_stage.single_batch`` event its writer recorded),
 the report additionally decomposes the sustained-vs-single loss:
 ``ideal_s`` is the wall the run WOULD take at the single-batch rate,
 and the loss (``wall - ideal``) is split into the non-device classes
@@ -38,8 +38,7 @@ A serve trail has no device class without one: a dispatch's host spans
 and the pull is booked as ``transfer``.
 
 Usage:
-  python tools/stream_bench.py --durable --trail /tmp/stream.jsonl ...
-  python tools/stall_report.py /tmp/stream.jsonl
+  python tools/stall_report.py /tmp/stream.jsonl      # obs.write_jsonl(events, path)
   python tools/stall_report.py fresh.jsonl --against base.jsonl
   python tools/stall_report.py t.jsonl --inject-slowdown 'span.stream.snapshot:10'
   python tools/stall_report.py serve.jsonl --xplane plugins/profile/*/vm.xplane.pb

@@ -11,13 +11,12 @@ on:
 - trace connectivity: traces, spans, roots, orphans
   (`obs.trace_summary`) — the "is one request one trace?" check at a
   glance;
-- ``--against OTHER``: per-stage share/total deltas between two trails
-  — the human twin of `tools/perf_gate.py`'s enforced comparison.
+- ``--against OTHER``: per-stage share/total deltas between two trails.
 
-Accepts JSONL trails or a bench artifact whose last line is one JSON
+Accepts JSONL trails or an artifact whose last line is one JSON
 object with ``detail.stages``/``detail.trail``. The human-readable
 report goes to stderr; the LAST stdout line is always one
-machine-parseable JSON object (the repo-wide bench contract).
+machine-parseable JSON object (the repo-wide tool contract).
 
 ``--fleet`` accepts MANY trails (different processes' exports, flight-
 recorder dumps) and stitches them onto one wall-clock axis via their
@@ -25,8 +24,7 @@ incarnation headers (`tools/fleet_report.py` does the merging) before
 reporting — the breakdown then covers the whole storm, not one child.
 
 Usage:
-  python tools/serve_bench.py ... --trail /tmp/serve.jsonl
-  python tools/trace_report.py /tmp/serve.jsonl
+  python tools/trace_report.py /tmp/serve.jsonl       # obs.write_jsonl(events, path)
   python tools/trace_report.py fresh.jsonl --against golden.jsonl
   python tools/trace_report.py --fleet /tmp/storm/*.jsonl
 """
