@@ -14,6 +14,7 @@ import sys
 import time
 
 from . import check as _check
+from . import stage_table, xplane
 from .context import Ctx, SpanLog, TraceSession
 from .spec import Spec
 
@@ -163,8 +164,6 @@ def run_cell(
     else:
         ctx.say("end_to_end_while_traced", **result["metrics"],
                 setup_s=setup_s)
-        from . import xplane
-
         red = xplane.reduce_file(
             xplane.newest_xplane(trace_dir), ctx.tracer.window_s
         )
@@ -175,6 +174,9 @@ def run_cell(
             )
         device_block["busy_s"] = red["busy_s"]
         device_block["window_s"] = red["window_s"]
+        # built here, before any reader: the breakdown's `device_stages`
+        # and the stage readers take the one table
+        stage_seconds = stage_table.of_run(ctx)
         for m in spec.per_layer(workload):
             desc = spec.data("layer_metrics", m["name"])
             value = spec.module("readers", desc["reader"]).read(
@@ -192,10 +194,8 @@ def run_cell(
         "device": device_block,
     }
     if trace:
-        line["breakdown"] = {
-            "device_ops": ctx.trace_reduction["device_ops"],
-            "idle_gaps": ctx.trace_reduction["idle_gaps"],
-        }
+        line["breakdown"] = xplane.breakdown(
+            ctx.trace_reduction, stage_seconds)
     # each number compared beside its limit: the line's last key, and the
     # last lines of standard error (what a record of a failed run keeps)
     line["checks"] = {c.name: c.entry() for c in comparisons}

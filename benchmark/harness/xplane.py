@@ -10,12 +10,15 @@ of ``/host:CPU``; the benchmark's own `jax.profiler.TraceAnnotation` spans
   containers such as ``while`` left out: they only hold other ops);
 - idle share: 1 - busy / traced window, mean over the devices used;
 - op durations by name (the HLO instruction's own name, ``%fusion.12``);
-- idle gaps of the first device, each attributed to the innermost
-  ``bench.*`` host span that covers its midpoint (else ``host:other``).
+- idle gaps of the first device, cut at every annotation's start and end,
+  each piece given to the innermost of the program's own ``mosaic.*``
+  spans that covers its midpoint, else to the innermost ``bench.*`` span of
+  the benchmark, else to ``host:other``.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import gzip
 import os
@@ -25,6 +28,9 @@ DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
 ANNOTATION_PREFIX = "bench."
+#: the program's own spans (`mosaic_tpu/obs/trace.py` enters one annotation
+#: per non-detached span while a profiler session is on)
+PROGRAM_PREFIX = "mosaic."
 #: control-flow ops that only contain other ops: their interval says when
 #: the loop ran, not that an operation was running, so they count neither
 #: as busy time nor in the ranking
@@ -77,7 +83,9 @@ def union_seconds(intervals) -> tuple[float, list]:
 
 def read_planes(path: str) -> dict:
     """``{"devices": {plane: [(name, start_ns, end_ns)]}, "host":
-    [(name, start_ns, end_ns)]}`` from one ``.xplane.pb``."""
+    [(name, start_ns, end_ns)], "program": [(name, start_ns, end_ns)]}``
+    from one ``.xplane.pb``: ``host`` the benchmark's ``bench.*``
+    annotations, ``program`` the program's ``mosaic.*`` ones, names whole."""
     from jax.profiler import ProfileData
 
     if path.endswith(".gz"):
@@ -87,6 +95,7 @@ def read_planes(path: str) -> dict:
         data = ProfileData.from_file(path)
     devices: dict = {}
     host: list = []
+    program: list = []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             ops = []
@@ -103,20 +112,58 @@ def read_planes(path: str) -> dict:
             for line in plane.lines:
                 for ev in line.events:
                     if ev.name.startswith(ANNOTATION_PREFIX):
-                        host.append(
-                            (ev.name, float(ev.start_ns),
-                             float(ev.start_ns) + float(ev.duration_ns))
-                        )
-    return {"devices": devices, "host": host}
+                        into = host
+                    elif ev.name.startswith(PROGRAM_PREFIX):
+                        into = program
+                    else:
+                        continue
+                    into.append(
+                        (ev.name, float(ev.start_ns),
+                         float(ev.start_ns) + float(ev.duration_ns))
+                    )
+    return {"devices": devices, "host": host, "program": program}
 
 
-def _attribute(gap, host_spans) -> str:
-    mid = (gap[0] + gap[1]) / 2.0
+def idle_pieces(merged, cuts) -> list:
+    """The gaps between the merged busy intervals, each cut at every one of
+    the sorted ``cuts`` inside it. One gap of a serve cycle runs from a
+    dispatch's last op through scatter-back, the linger and the next
+    dispatch's puts: its midpoint alone would give all of it to one."""
+    pieces = []
+    for a, b in zip(merged, merged[1:]):
+        lo, hi = a[1], b[0]
+        if hi <= lo:
+            continue
+        inner = cuts[bisect.bisect_right(cuts, lo):bisect.bisect_left(cuts, hi)]
+        edges = [lo, *inner, hi]
+        pieces.extend(zip(edges, edges[1:]))
+    return pieces
+
+
+def span_index(spans) -> dict:
+    """``{name: (starts, [(start, end)])}``, each in order of start, from
+    tuples that begin ``(name, start, end)``."""
+    out: dict = {}
+    for name, s, e, *_rest in sorted(spans, key=lambda sp: sp[1]):
+        starts, ivs = out.setdefault(name, ([], []))
+        starts.append(s)
+        ivs.append((s, e))
+    return out
+
+
+def covering(index: dict, mid: float, names=None):
+    """The innermost (shortest) span covering ``mid``, among ``names`` if
+    given; None if there is none. Of one name, the two that started last
+    before ``mid`` are looked at (one thread runs a span name one at a
+    time; two threads may overlap)."""
     best = None
-    for name, s, e in host_spans:
-        if s <= mid <= e and (best is None or (e - s) < best[1]):
-            best = (name, e - s)
-    return best[0] if best else "host:other"
+    for name in index if names is None else names:
+        starts, ivs = index.get(name, ((), ()))
+        i = bisect.bisect_right(starts, mid)
+        for s, e in ivs[max(i - 2, 0):i]:
+            if e >= mid and (best is None or e - s < best[1]):
+                best = (name, e - s)
+    return None if best is None else best[0]
 
 
 def reduce_planes(planes: dict, window_s: float, top: int = 10) -> dict:
@@ -150,12 +197,14 @@ def reduce_planes(planes: dict, window_s: float, top: int = 10) -> dict:
         if any(w in op_name(name) for w in COLLECTIVE_WORDS):
             collective_ns += e - s
     gaps: dict = {}
-    for a, b in zip(merged_first, merged_first[1:]):
-        gap = (a[1], b[0])
-        if gap[1] - gap[0] <= 0:
-            continue
-        who = _attribute(gap, planes["host"])
-        gaps[who] = gaps.get(who, 0.0) + (gap[1] - gap[0]) / 1e9
+    annotations = [*planes.get("program", ()), *planes["host"]]
+    program = span_index(planes.get("program", ()))
+    bench = span_index(planes["host"])
+    cuts = sorted({x for _n, s, e in annotations for x in (s, e)})
+    for lo, hi in idle_pieces(merged_first, cuts):
+        mid = (lo + hi) / 2.0
+        who = covering(program, mid) or covering(bench, mid) or "host:other"
+        gaps[who] = gaps.get(who, 0.0) + (hi - lo) / 1e9
     rank = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]  # noqa: E731
     return {
         "devices": len(devices),
@@ -172,3 +221,20 @@ def reduce_planes(planes: dict, window_s: float, top: int = 10) -> dict:
 
 def reduce_file(path: str, window_s: float) -> dict:
     return reduce_planes(read_planes(path), window_s)
+
+
+def breakdown(reduction: dict, stage_seconds=None, top: int = 10) -> dict:
+    """The result line's ``breakdown``, three rankings of ``[name, seconds]``
+    with at most ``top`` rows each: ``device_ops`` the longest ops by HLO
+    name, ``idle_gaps`` the idle time by what the host was doing, and
+    ``device_stages`` the device seconds by the program's own stage names
+    (`harness/stage_table.py`; the stages' seconds contain the ops', so the
+    table has a key of its own, left out where the run has no table)."""
+    out = {"device_ops": reduction["device_ops"][:top],
+           "idle_gaps": reduction["idle_gaps"][:top]}
+    stages = sorted(
+        ([k, v] for k, v in (stage_seconds or {}).items() if v > 0.0),
+        key=lambda kv: -kv[1])
+    if stages:
+        out["device_stages"] = stages[:top]
+    return out

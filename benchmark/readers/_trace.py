@@ -13,7 +13,7 @@ import os
 from benchmark.harness import xplane
 
 MODULES_LINE = "XLA Modules"
-PROGRAM_PREFIX = "mosaic."
+PROGRAM_PREFIX = xplane.PROGRAM_PREFIX
 _CACHE: dict = {}
 
 

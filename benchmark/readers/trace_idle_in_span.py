@@ -9,7 +9,7 @@ them — and each piece goes by its midpoint. Also prints, once a run,
 every piece attributed to the innermost program span that covers it, as
 one ``[bench] idle_by_program_span:`` line (``none``: no span covers it)."""
 
-import bisect
+from benchmark.harness.xplane import covering, idle_pieces, span_index
 
 
 def gaps_of(tr_mod, tr) -> list:
@@ -17,40 +17,7 @@ def gaps_of(tr_mod, tr) -> list:
     intervals, cut at every program annotation's start and end."""
     merged = tr_mod.busy_intervals(tr_mod.first_device(tr)["ops"])
     cuts = sorted({x for _n, s, e, _t in tr["program"] for x in (s, e)})
-    pieces = []
-    for a, b in zip(merged, merged[1:]):
-        lo, hi = a[1], b[0]
-        if hi <= lo:
-            continue
-        inner = cuts[bisect.bisect_right(cuts, lo):bisect.bisect_left(cuts, hi)]
-        edges = [lo, *inner, hi]
-        pieces.extend(zip(edges, edges[1:]))
-    return pieces
-
-
-def by_name(program) -> dict:
-    """``{name: (starts, [(start, end)])}``, each in order of start."""
-    out: dict = {}
-    for name, s, e, _t in program:
-        starts, ivs = out.setdefault(name, ([], []))
-        starts.append(s)
-        ivs.append((s, e))
-    return out
-
-
-def covering(index: dict, mid: float, names=None):
-    """The innermost (shortest) annotation covering ``mid``, among
-    ``names`` if given; None if there is none. Of one name, the two that
-    started last before ``mid`` are looked at (one thread runs a span
-    name one at a time; two threads may overlap)."""
-    best = None
-    for name in index if names is None else names:
-        starts, ivs = index.get(name, ((), ()))
-        i = bisect.bisect_right(starts, mid)
-        for s, e in ivs[max(i - 2, 0):i]:
-            if e >= mid and (best is None or e - s < best[1]):
-                best = (name, e - s)
-    return None if best is None else best[0]
+    return idle_pieces(merged, cuts)
 
 
 def read(ctx, params):
@@ -63,7 +30,7 @@ def read(ctx, params):
     if idle <= 0:
         return None
     names = set(params["spans"])
-    index = by_name(tr["program"])
+    index = span_index(tr["program"])
     mine = 0.0
     by_span: dict = {}
     for a, b in gaps:

@@ -73,8 +73,7 @@ def prepare(ctx) -> dict:
         sj.compile(ring, nb)
     ctx.say(
         "stream_ready", ring=tuple(ring.shape), steps_per_dispatch=nb,
-        lookup=sj.lookup, compaction=sj.compaction, probe=sj.probe,
-        mesh=None if sj.mesh is None else dict(sj.mesh.shape),
+        probe=sj.probe, mesh=None if sj.mesh is None else dict(sj.mesh.shape),
         ring_build_s=round(ctx.spans.seconds("ring_build"), 3),
         loop_warmup_s=round(ctx.spans.seconds("loop_warmup"), 3),
     )

@@ -4,7 +4,12 @@ the cell's name joining the ``workloads`` of metrics that are there — edits
 no file of the benchmark, and every check of the contract and of each
 per-layer entry still holds on the result. A second "PR" then appends one
 more metric after the first's, and both still pass: the case an assertion
-on the LAST entries of ``per_layer`` refused until PR 39.
+on the LAST entries of ``per_layer`` refused until PR 39. A third appends a
+host-fed KNN cell that brings NO entry: its name joins the lists PR 47
+merged (`device_idle.batch`, `compiles_in_window.batch`, `pool_build_s.batch`,
+the `.knn` entries both KNN cells share), and the very checks that hold
+those lists on the real file hold them on the copy — the case a list pinned
+with ``==`` refused until PR 47, and the reason twins were minted.
 
 The names below are RESERVED for this test and mean nothing else: no cell,
 mix or metric of the real benchmark, and none of ``PERF.md``'s queue, may
@@ -24,12 +29,23 @@ from test_benchmark_batch_spans import (
     as check_batch_entries_keep_their_order,
 )
 from test_benchmark_contract import check_cells, check_configs, check_metrics
+from test_benchmark_knn_buildings import SIBLING_METRICS, check_sibling_entry
+from test_benchmark_knn_slabs import check_both_entries as check_slab_entries
 from test_benchmark_program_spans import check_entry
+from test_benchmark_shared_entries import (
+    SHARED_BY_THE_HOST_FED,
+    check_host_fed_entry,
+    check_no_twins,
+    check_span_lists,
+)
 
 CELL, MIX = "additive-probe.cell", "additive-probe-mix"
 METRIC = "additive_probe_dispatches.stream"
 SECOND_METRIC = "additive_probe_calls.batch"
 BATCH_CELLS = ["taxi.batch", "taxi.batch-exact"]
+KNN_CELL, KNN_MIX = "additive-probe.knn-cell", "additive-probe-knn-mix"
+KNN_CELLS = {"nyc-knn.transform", "nyc-knn-buildings.transform"}
+PROBE_CELLS = (CELL, KNN_CELL)
 
 
 def _copy(tmp) -> str:
@@ -84,6 +100,26 @@ def _second_pr(tree: str, bench: dict) -> None:
     })
 
 
+def _knn_pr(tree: str, bench: dict) -> None:
+    """A host-fed KNN cell on the point cell's configuration under a mix of
+    its own: two data files, one ``workloads`` entry and NO ``per_layer``
+    entry — its name joins every list both KNN cells stand in."""
+    mix = Spec(os.path.dirname(tree)).traffic("landmarks-host")
+    mix.pop("name")
+    mix["pool_tables"] = 3
+    _write(os.path.join(tree, "traffic", KNN_MIX + ".json"), mix)
+    check = Spec(os.path.dirname(tree)).cell("nyc-knn.transform")["check"]
+    _write(os.path.join(tree, "workloads", KNN_CELL + ".json"),
+           {"check": check})
+    bench["workloads"].append({
+        "name": KNN_CELL, "config": "nyc-knn-h3r10", "traffic": KNN_MIX,
+        "chips": 1, "why": "reserved for the additivity test: three tables",
+    })
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if KNN_CELLS <= set(m.get("workloads", [])):
+            m["workloads"].append(KNN_CELL)
+
+
 def _every_check(root: str) -> Spec:
     check_configs(root)
     check_cells(root)
@@ -92,18 +128,27 @@ def _every_check(root: str) -> Spec:
     for m in spec.benchmark["per_layer"]:
         check_entry(spec, m["name"])
     check_batch_entries_keep_their_order(spec)
+    check_no_twins(spec)  # a cell appended to a shared list mints no twin
+    # what holds the merged lists on the real file holds them on the copy
+    for name in SHARED_BY_THE_HOST_FED:
+        check_host_fed_entry(spec, name)
+    check_span_lists(spec)
+    for name in SIBLING_METRICS:
+        check_sibling_entry(spec, name)
+    check_slab_entries(spec)
     return spec
 
 
 def _accepted_entries_are_the_real_files(spec: Spec) -> None:
-    """Entry for entry the real file's, but for the appended cell's name."""
+    """Entry for entry the real file's, but for the appended cells' names."""
     real = Spec(REPO).benchmark
     for section in ("configs", "workloads", "end_to_end", "per_layer"):
         assert len(spec.benchmark[section]) >= len(real[section])
         for was, now in zip(real[section], spec.benchmark[section]):
             now = dict(now)
             if "workloads" in now:
-                now["workloads"] = [c for c in now["workloads"] if c != CELL]
+                now["workloads"] = [c for c in now["workloads"]
+                                    if c not in PROBE_CELLS]
             assert was == now
 
 
@@ -111,10 +156,11 @@ def test_the_names_are_reserved_for_this_test():
     real = Spec(REPO).benchmark
     taken = {e["name"] for s in ("workloads", "end_to_end", "per_layer")
              for e in real[s]} | {w["traffic"] for w in real["workloads"]}
-    assert not taken & {CELL, MIX, METRIC, SECOND_METRIC}, (
+    assert not taken & {CELL, MIX, METRIC, SECOND_METRIC, KNN_CELL, KNN_MIX}, (
         "these names are reserved for tests/benchmark_harness/"
         "test_benchmark_additive.py; give the real entry another")
     for kind, name in (("traffic", MIX), ("workloads", CELL),
+                       ("traffic", KNN_MIX), ("workloads", KNN_CELL),
                        ("layer_metrics", METRIC),
                        ("layer_metrics", SECOND_METRIC)):
         assert not os.path.exists(
@@ -136,6 +182,45 @@ def test_appended_metric_and_cell_pass_every_check_of_the_real_file(tmp_path):
     for w in real.benchmark["workloads"]:
         for reads in (Spec.end_to_end, Spec.per_layer):
             assert [m["name"] for m in reads(spec, w["name"])] == \
+                [m["name"] for m in reads(real, w["name"])]
+
+
+@pytest.mark.parametrize("before", [(), (_first_pr, _second_pr)],
+                         ids=["alone", "after-two-prs"])
+def test_a_host_fed_cell_joins_the_merged_lists_and_mints_no_entry(
+        tmp_path, before):
+    """The cell PR 47's rule is for: its name appended to `device_idle.batch`,
+    `compiles_in_window.batch`, `pool_build_s.batch` and the shared `.knn`
+    entries, not one entry or file of metrics added — and the checks of those
+    lists, of every entry and of the contract hold on the copy."""
+    root = _copy(tmp_path)
+    for add in before:
+        append_as_a_pr(root, add)
+    append_as_a_pr(root, _knn_pr)
+    spec = _every_check(root)
+    real = Spec(REPO)
+    assert len(spec.benchmark["per_layer"]) == \
+        len(real.benchmark["per_layer"]) + len(before)
+    _accepted_entries_are_the_real_files(spec)
+    # the new cell reads what both KNN cells read, and nothing it has no
+    # span, counter or kernel for
+    mine = [m["name"] for m in spec.per_layer(KNN_CELL)]
+    both = [m["name"] for m in real.per_layer("nyc-knn.transform")
+            if m in real.per_layer("nyc-knn-buildings.transform")]
+    assert mine == both
+    assert set(mine) >= set(SIBLING_METRICS) | set(SHARED_BY_THE_HOST_FED) | {
+        "pool_build_s.batch", "index_build_s", "warmup_s"}
+    assert not set(mine) & {"pair_hbm_share.knn", "edge_pair_hbm_share.knn"}
+    assert [m["name"] for m in spec.end_to_end(KNN_CELL)] == \
+        ["setup_s", "batch_rows_per_s"]
+    assert spec.traffic(spec.cell(KNN_CELL)["traffic"])["kind"] == \
+        "knn_transform"
+    # no cell of the real file reads anything more or less than before
+    # (but the metric an earlier probe PR brought it)
+    for w in real.benchmark["workloads"]:
+        for reads in (Spec.end_to_end, Spec.per_layer):
+            assert [m["name"] for m in reads(spec, w["name"])
+                    if m["name"] not in (METRIC, SECOND_METRIC)] == \
                 [m["name"] for m in reads(real, w["name"])]
 
 

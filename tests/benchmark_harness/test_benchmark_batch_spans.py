@@ -62,9 +62,10 @@ def test_the_twenty_entries_are_the_batch_cells_and_move_their_rate(spec):
     for n in names:
         e = entries[n]
         assert e["moves"] == "batch_rows_per_s"
-        assert e["workloads"] == (
-            ["taxi.batch-exact"] if n in EXACT_SPAN_METRICS
-            else ["taxi.batch", "taxi.batch-exact"])
+        # membership: a later batch cell appends its name to these lists
+        assert "taxi.batch-exact" in e["workloads"]
+        # (the recheck's spans are in no call of the default cell)
+        assert ("taxi.batch" in e["workloads"]) == (n not in EXACT_SPAN_METRICS)
         assert e["source"] == ("device_trace" if n in STAGE_METRICS + IDLE_METRICS
                                else "program_span")
 
@@ -262,7 +263,7 @@ def test_recorded_events_of_pr35_hold_nothing_for_the_under_sync_metric(
                  if m["name"] == UNDER_SYNC)
     assert (entry["moves"], entry["source"], entry["layer"]) == (
         "batch_rows_per_s", "program_span", "dispatch core")
-    assert entry["workloads"] == ["taxi.batch", "taxi.batch-exact"]
+    assert {"taxi.batch", "taxi.batch-exact"} <= set(entry["workloads"])
 
 
 def test_recorded_stages_are_the_calls_device_time(spec, recorded, monkeypatch):

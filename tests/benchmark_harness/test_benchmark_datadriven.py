@@ -69,7 +69,7 @@ def test_new_stream_cell_on_a_mesh_traced(tmp_path):
     assert "rows_per_s" not in m and "setup_s" not in m
     assert m["compiles_in_window.stream"] == {"value": 0.0, "unit": "count"}
     assert m["index_build_s"]["value"] > 0 and m["warmup_s"]["value"] > 0
-    assert m["loop_ms_per_step.stream"]["value"] > 0
+    assert m["launch_ms_per_dispatch.stream"]["value"] > 0
     assert "device_idle.stream" not in m and "collective_share.x4" not in m
     # the metric added as files, read by the reader added as a file
     assert m["tiny_dispatches"]["value"] == 2 * (line["attempted"] // (2 * 4 * 2048))

@@ -222,7 +222,7 @@ def test_reference_counts_a_courtyard_as_outside():
 def test_new_metric_reads_nothing_on_an_empty_run(name):
     spec = Spec(REPO)
     entry = next(m for m in spec.benchmark["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == ["osm-buildings.join"]
+    assert "osm-buildings.join" in entry["workloads"]
     check_entry(spec, name)
     # nor on a run of a program without the counter or the scope
     desc = spec.data("layer_metrics", name)

@@ -108,13 +108,37 @@ def test_batch_cell_traced_reads_the_programs_call_span(root):
     assert "device_idle.batch" not in m
     assert "device_busy_ms_per_call.batch" not in m
     # the tails of the calls made with the profiler off
-    assert m["call_max_ms.batch"]["value"] >= m["call_p95_ms.batch"]["value"] > 0
+    assert m["call_max_ms.batch"]["value"] >= m["call_p50_ms.batch"]["value"] > 0
+
+
+def test_the_result_line_takes_the_stage_table_from_the_harness(
+        root, monkeypatch):
+    """The breakdown's ``device_stages`` is what `harness/stage_table.py`
+    hands `run_cell`, under a key of its own beside the two rankings (on the
+    CPU there is no device plane to build one from: the table is stood in
+    for here, and no reader of the cell is asked for it)."""
+    from benchmark.harness import stage_table
+
+    built = []
+
+    def of_run(ctx):
+        built.append(len(built))
+        return {"pip.tier1": 0.5, "unscoped": 0.125, "pip.cells": 0.25}
+
+    monkeypatch.setattr(stage_table, "of_run", of_run)
+    line = _run(root, "tiny.batch", 53, trace=True)
+    assert line["correct"] is True and built[0] == 0
+    assert list(line["breakdown"]) == ["device_ops", "idle_gaps",
+                                       "device_stages"]
+    assert line["breakdown"]["device_stages"] == [
+        ["pip.tier1", 0.5], ["pip.cells", 0.25], ["unscoped", 0.125]]
 
 
 def test_call_metrics_read_the_unprofiled_calls():
     """`call_p50_ms.batch` reads the program's span over the calls after the
-    profiler stopped; `call_p95_ms.batch` and `call_max_ms.batch` read the
-    benchmark's own series of those calls, where one stalled call shows."""
+    profiler stopped; `call_max_ms.batch` reads the benchmark's own series of
+    those calls, where one stalled call shows (`call_p95_ms.batch`, between
+    the two, went with PR 47: `series_percentile` still takes any ``q``)."""
     from types import SimpleNamespace
 
     from bh_fixtures import REPO
@@ -137,10 +161,13 @@ def test_call_metrics_read_the_unprofiled_calls():
         return spec.module("readers", desc["reader"]).read(ctx, desc["params"])
 
     assert read("call_p50_ms.batch") == pytest.approx(500.0)
-    assert read("call_p95_ms.batch") == pytest.approx(400.0)
     assert read("call_max_ms.batch") == pytest.approx(10900.0)
+    desc = spec.data("layer_metrics", "call_max_ms.batch")
+    tail = spec.module("readers", desc["reader"]).read
+    assert tail(ctx, dict(desc["params"], q=0.95)) == pytest.approx(400.0)
     ctx.series = {}
-    assert read("call_p95_ms.batch") is None and read("call_max_ms.batch") is None
+    assert read("call_max_ms.batch") is None
+    assert tail(ctx, dict(desc["params"], q=0.95)) is None
 
 
 def test_the_benchmarks_span_wraps_every_call(root, monkeypatch):

@@ -30,14 +30,22 @@ NEW_METRICS = [
     "edges_device_ms_per_call.knn", "edge_pair_hbm_share.knn",
 ]
 SHARED_METRICS = ["index_build_s", "warmup_s"]
-#: the sibling cell's `.knn` metrics this cell's spans and trace feed too,
-#: under names of its own: the sibling's entries are pinned to their one cell
-#: by tests the benchmark has, so the names merge in a `benchmark` PR
+#: the sibling cell's `.knn` entries this cell's spans, counters and trace
+#: feed too: since PR 47 the cell stands in their ``workloads`` (until then
+#: six of them were twins under `.knn-buildings` names: none is left), and
+#: in the entries every host-fed cell shares
 SIBLING_METRICS = [
-    "call_p50_ms", "expand_ms_per_call", "distance_ms_per_call",
-    "device_busy_ms_per_call", "device_idle", "compiles_in_window",
+    "call_p50_ms.knn", "expand_ms_per_call.knn", "distance_ms_per_call.knn",
+    "merge_ms_per_call.knn", "enqueue_ms_per_call.knn", "pull_ms_per_call.knn",
+    "overlap_ms_per_call.knn", "slabs_per_call.knn", "iterations_per_call.knn",
+    "launches_per_call.knn", "pulled_rows_per_call.knn",
+    "device_busy_ms_per_call.knn",
 ]
-SUFFIX = ".knn-buildings"
+HOST_FED_METRICS = ["device_idle.batch", "compiles_in_window.batch",
+                    "pool_build_s.batch"]
+#: these reckon the point block kernel (the edge kernel has its own three)
+POINT_ONLY = ["pair_hbm_share.knn", "pairs_per_landmark.knn",
+              "pair_occupancy.knn"]
 #: at resolution 10 cells of 9.8e-4 degrees, the size of a large footprint
 GRID, RES = "CUSTOM(-75,-73,40,42,2,1,1)", 10
 CENTRE = [-74.02, 40.48]
@@ -148,9 +156,11 @@ def test_the_cells_files_resolve():
         assert spec.module(registry, name)
     assert [m["name"] for m in spec.end_to_end(REAL)] == \
         ["setup_s", "batch_rows_per_s"]
-    assert {m["name"] for m in spec.per_layer(REAL)} == \
-        set(NEW_METRICS + SHARED_METRICS
-            + [m + SUFFIX for m in SIBLING_METRICS])
+    mine = {m["name"] for m in spec.per_layer(REAL)}
+    assert mine >= set(NEW_METRICS + SHARED_METRICS + SIBLING_METRICS
+                       + HOST_FED_METRICS)
+    assert not mine & set(POINT_ONLY)
+    assert not [n for n in mine if n.endswith(".knn-buildings")]
     limits = cell["check"]
     assert limits["sample_landmarks"] == 512 and limits["why"]
     assert 0 < limits["max_distance_error"] < 1e-9
@@ -303,7 +313,10 @@ def test_builder_packs_and_takes_in_array_code():
 def test_new_metric_is_this_cells_and_reads_nothing_on_an_empty_run(name):
     spec = Spec(REPO)
     entry = next(m for m in spec.benchmark["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == [REAL]
+    # the polygon-landmark lane's own: this cell is in the list, the point
+    # cell is not (it reads nothing, below); a later footprint cell appends
+    assert REAL in entry["workloads"]
+    assert "nyc-knn.transform" not in entry["workloads"]
     assert entry["moves"] == "batch_rows_per_s"
     assert entry["layer"] == (
         "kernels" if name == "edge_pair_hbm_share.knn" else "knn ring engine")
@@ -320,18 +333,19 @@ def test_new_metric_is_this_cells_and_reads_nothing_on_an_empty_run(name):
         ctx, desc["params"]) is None
 
 
-@pytest.mark.parametrize("stem", SIBLING_METRICS)
-def test_sibling_metric_under_this_cells_name_is_the_siblings_reader(stem):
-    spec = Spec(REPO)
+def check_sibling_entry(spec, name) -> None:
+    """Both KNN cells are IN the entry's list (membership: the next KNN
+    cell appends its name; the additivity test holds a copy with one
+    appended to this) and no twin stands beside it."""
     by = {m["name"]: m for m in spec.benchmark["per_layer"]}
-    mine, theirs = by[stem + SUFFIX], by[stem + ".knn"]
-    assert mine["workloads"] == [REAL] and REAL not in theirs["workloads"]
-    for key in ("unit", "better", "source", "layer", "moves"):
-        assert mine[key] == theirs[key]
-    check_entry(spec, stem + SUFFIX)
-    desc, sib = (spec.data("layer_metrics", stem + s) for s in (SUFFIX, ".knn"))
-    assert (desc["reader"], desc["params"]) == (sib["reader"], sib["params"])
-    assert "footprint cell" in desc["what"]
+    assert {"nyc-knn.transform", REAL} <= set(by[name]["workloads"])
+    assert name[: -len(".knn")] + ".knn-buildings" not in by
+    check_entry(spec, name)
+
+
+@pytest.mark.parametrize("name", SIBLING_METRICS)
+def test_sibling_entry_lists_both_knn_cells_and_no_twin_is_left(name):
+    check_sibling_entry(Spec(REPO), name)
 
 
 def test_sibling_span_metrics_read_a_footprint_calls_spans():
@@ -349,17 +363,18 @@ def test_sibling_span_metrics_read_a_footprint_calls_spans():
         ]
     ctx = _ctx(spec, events=events, counters={"compiles_in_window": 0})
 
-    def read(stem):
-        desc = spec.data("layer_metrics", stem + SUFFIX)
+    def read(name):
+        desc = spec.data("layer_metrics", name)
         return spec.module("readers", desc["reader"]).read(ctx, desc["params"])
 
-    assert read("call_p50_ms") == pytest.approx(900.0)
-    assert read("expand_ms_per_call") == pytest.approx(110.0)
-    assert read("distance_ms_per_call") == pytest.approx(500.0)
-    assert read("compiles_in_window") == 0
+    assert read("call_p50_ms.knn") == pytest.approx(900.0)
+    assert read("expand_ms_per_call.knn") == pytest.approx(110.0)
+    assert read("distance_ms_per_call.knn") == pytest.approx(500.0)
+    assert read("pull_ms_per_call.knn") == pytest.approx(400.0)
+    assert read("compiles_in_window.batch") == 0
     # the two device metrics need a trace
-    assert read("device_idle") is None
-    assert read("device_busy_ms_per_call") is None
+    assert read("device_idle.batch") is None
+    assert read("device_busy_ms_per_call.knn") is None
 
 
 @pytest.mark.parametrize("name", SHARED_METRICS)

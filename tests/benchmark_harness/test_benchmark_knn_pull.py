@@ -32,7 +32,7 @@ def _read(spec, name, ctx):
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_entry_is_the_knn_cells_and_reads_nothing_on_an_empty_run(spec, name):
     entry = next(m for m in spec.benchmark["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == ["nyc-knn.transform"]
+    assert "nyc-knn.transform" in entry["workloads"]
     assert entry["layer"] == "knn ring engine"
     assert entry["moves"] == "batch_rows_per_s" and entry["better"] == "lower"
     assert entry["source"] == (

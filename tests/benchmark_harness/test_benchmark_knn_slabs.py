@@ -1,12 +1,11 @@
 """The metric PR 45 appended for `nyc-knn.transform` — how much of a call's
-host work ran under queued device work: a new data file and an appended
-entry read by a reader the benchmark had (`event_percentile` over the
-``knn.transform`` span, as `launches_per_call.knn`); it reads nothing on an
-empty run and on a program whose span lacks the field (the parent commit),
-and the right number on hand-made events and on a span the program itself
-recorded, beside which ``slabs`` is read by the same reader (no entry takes
-it: `per_layer` is full at 128). A slabbed call of a warmed model compiles
-nothing."""
+host work ran under queued device work — and, since PR 47 made room,
+`slabs_per_call.knn` beside it: each a data file and an entry read by a
+reader the benchmark had (`event_percentile` over the ``knn.transform``
+span, as `launches_per_call.knn`), on both KNN cells; each reads nothing on
+an empty run and on a program whose span lacks the field (the parent
+commit), and the right number on hand-made events and on a span the program
+itself recorded. A slabbed call of a warmed model compiles nothing."""
 
 import numpy as np
 import pytest
@@ -17,6 +16,8 @@ from benchmark.harness.spec import Spec
 from test_benchmark_program_spans import _ctx, _span, check_entry
 
 NAME = "overlap_ms_per_call.knn"
+SLABS = "slabs_per_call.knn"
+KNN_CELLS = ["nyc-knn.transform", "nyc-knn-buildings.transform"]
 
 
 @pytest.fixture(scope="module")
@@ -24,33 +25,46 @@ def spec():
     return Spec(REPO)
 
 
-def _read(spec, ctx, **params):
-    desc = spec.data("layer_metrics", NAME)
-    return spec.module("readers", desc["reader"]).read(
-        ctx, {**desc["params"], **params})
+def _read(spec, ctx, name=NAME):
+    desc = spec.data("layer_metrics", name)
+    return spec.module("readers", desc["reader"]).read(ctx, desc["params"])
 
 
-def test_entry_is_the_point_cells_and_reads_nothing_without_the_field(spec):
-    entry = spec.benchmark["per_layer"][-1]
-    assert entry == {
+def check_both_entries(spec) -> None:
+    """Each entry as it was appended, both KNN cells IN its list
+    (membership: a later KNN cell appends its name; the additivity test
+    holds a copy with one appended to this)."""
+    by = {m["name"]: dict(m) for m in spec.benchmark["per_layer"]}
+    for name in (NAME, SLABS):
+        assert set(KNN_CELLS) <= set(by[name].pop("workloads"))
+    assert by[NAME] == {
         "name": NAME, "unit": "ms", "better": "higher",
         "source": "program_span", "layer": "knn ring engine",
-        "moves": "batch_rows_per_s", "workloads": ["nyc-knn.transform"],
+        "moves": "batch_rows_per_s",
     }
-    desc = spec.data("layer_metrics", NAME)
-    assert desc["reader"] == "event_percentile"
-    assert desc["params"] == {
-        "event": "span", "where": {"name": "knn.transform"},
-        "field": "hidden_s", "q": 0.5, "scale": 1000,
+    assert by[SLABS] == {
+        "name": SLABS, "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "knn ring engine",
+        "moves": "batch_rows_per_s",
     }
-    check_entry(spec, NAME)
+    span = {"event": "span", "where": {"name": "knn.transform"}, "q": 0.5}
+    for name, more in ((NAME, {"field": "hidden_s", "scale": 1000}),
+                       (SLABS, {"field": "slabs"})):
+        desc = spec.data("layer_metrics", name)
+        assert desc["reader"] == "event_percentile"
+        assert desc["params"] == {**span, **more}
+        check_entry(spec, name)
+
+
+def test_both_entries_are_the_knn_cells_and_read_nothing_without_the_field(spec):
+    check_both_entries(spec)
     # the parent's transform span has launches and rows_pulled, nothing more
     ctx = _ctx(spec, events=[
         dict(_span("knn.transform", "t", None, 1.0, 5.0), launches=40,
              rows_pulled=562_176),
         _span("knn.pull", "p", "t", 0.26, 4.0),
     ])
-    assert _read(spec, ctx) is None and _read(spec, ctx, field="slabs") is None
+    assert _read(spec, ctx) is None and _read(spec, ctx, SLABS) is None
 
 
 def test_it_reads_the_p50_of_the_calls_in_the_window(spec):
@@ -64,7 +78,7 @@ def test_it_reads_the_p50_of_the_calls_in_the_window(spec):
     ]
     ctx = _ctx(spec, events=events)
     assert _read(spec, ctx) == pytest.approx(180.0)
-    assert _read(spec, ctx, field="slabs", scale=1) == 47
+    assert _read(spec, ctx, SLABS) == 47
 
 
 def test_a_recorded_call_is_read_and_a_slabbed_call_compiles_nothing(
@@ -102,4 +116,4 @@ def test_a_recorded_call_is_read_and_a_slabbed_call_compiles_nothing(
     assert root["slabs"] > root["iterations"] and root["hidden_s"] > 0
     ctx = _ctx(spec, events=[dict(root, ts_mono=5.0)])
     assert _read(spec, ctx) == pytest.approx(1000 * root["hidden_s"])
-    assert _read(spec, ctx, field="slabs", scale=1) == root["slabs"]
+    assert _read(spec, ctx, SLABS) == root["slabs"]

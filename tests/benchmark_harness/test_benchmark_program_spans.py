@@ -252,6 +252,37 @@ def test_trace_stage_busy_by_module_and_label(spec, monkeypatch):
     assert _read(spec, "trace_stage_busy", no_steps, per_step) is None
 
 
+def test_the_harness_builds_the_stage_table_and_the_readers_take_it(
+        spec, monkeypatch):
+    """`harness/stage_table.py` builds the table with no reader's help
+    (`run_cell` calls it before the first reader, for the breakdown's
+    ``device_stages``); a stage reader after it lowers nothing again."""
+    from mosaic_tpu.obs import stages
+
+    from benchmark.harness import stage_table
+
+    asked = []
+
+    def tables(modules, rows):
+        asked.append(set(modules))
+        return {"jit_join": {"fusion.1 f32[64]": "pip.tier1"}}
+
+    monkeypatch.setattr(stages, "tables", tables)
+    bare = _ctx(spec)  # no trace: nothing to read, nothing lowered
+    assert stage_table.of_run(bare) is None and not asked
+    _with_trace(spec, monkeypatch, _tr())
+    ctx = _ctx(spec, counters={"traced_steps": 2})
+    table = stage_table.of_run(ctx)
+    assert table == pytest.approx({"pip.tier1": 1.25, "unscoped": 0.625})
+    assert ctx.device_by_stage is table and len(asked) == 2
+    assert [w for w, _ in ctx.said] == ["stage_tables", "device_by_stage"]
+    assert stage_table.of_run(ctx) is table
+    assert _read(spec, "trace_stage_busy", ctx,
+                 {"stage": "pip.tier1", "steps": "traced_steps"}) == \
+        pytest.approx(1000 * 1.25 / 2)
+    assert len(asked) == 2 and len(ctx.said) == 2
+
+
 def test_a_trace_without_devices_or_program_spans_is_nothing_to_read(
         spec, tmp_path):
     """The CPU rehearsal's trace (no device plane) and the parent's (no

@@ -34,9 +34,11 @@ ZONAL_METRICS = [
     "host_patch_p50_ms.zonal", "fold_launch_p50_ms.zonal",
     "drain_p50_ms.zonal", "patched_pixel_share.zonal",
     "probe_device_ms_per_tile.zonal", "fold_device_ms_per_tile.zonal",
-    "fold_hbm_share.zonal", "device_idle.zonal", "compiles_in_window.zonal",
-    "scene_pool_build_s.zonal", "scan_warmup_s.zonal",
+    "fold_hbm_share.zonal", "scene_pool_build_s.zonal", "scan_warmup_s.zonal",
 ]
+#: entries this cell shares with the other host-fed cells since PR 47
+SHARED_METRICS = ["device_idle.batch", "compiles_in_window.batch",
+                  "index_build_s", "warmup_s"]
 
 
 def add_raster_cell(root: str) -> None:
@@ -129,12 +131,12 @@ def test_raster_cell_traced_reads_the_programs_spans(root, capfd):
     # the device-trace metrics find nothing to read on the CPU; every other
     # new metric is there
     device = {"probe_device_ms_per_tile.zonal", "fold_device_ms_per_tile.zonal",
-              "fold_hbm_share.zonal", "device_idle.zonal"}
-    assert {k for k in m if k.endswith(".zonal")} == set(ZONAL_METRICS) - device
-    for name in set(ZONAL_METRICS) - device - {
-            "compiles_in_window.zonal", "patched_pixel_share.zonal"}:
+              "fold_hbm_share.zonal"}
+    assert set(m) == (set(ZONAL_METRICS) - device) | (
+        set(SHARED_METRICS) - {"device_idle.batch"})
+    for name in set(ZONAL_METRICS) - device - {"patched_pixel_share.zonal"}:
         assert m[name]["value"] > 0, name
-    assert m["compiles_in_window.zonal"] == {"value": 0.0, "unit": "count"}
+    assert m["compiles_in_window.batch"] == {"value": 0.0, "unit": "count"}
     assert 0.0 <= m["patched_pixel_share.zonal"]["value"] < 5.0
     assert m["index_build_s"]["value"] > 0 and m["warmup_s"]["value"] > 0
     # a scan is its tiles: the tile's pieces lie inside the tile's span
@@ -143,7 +145,7 @@ def test_raster_cell_traced_reads_the_programs_spans(root, capfd):
         "probe_pull_p50_ms.zonal", "host_patch_p50_ms.zonal",
         "fold_launch_p50_ms.zonal"))
     assert pieces < 1.5 * m["tile_cycle_p50_ms.zonal"]["value"]
-    assert "nothing_to_read: metric=device_idle.zonal" in capfd.readouterr().out
+    assert "nothing_to_read: metric=device_idle.batch" in capfd.readouterr().out
 
 
 def test_the_traced_run_profiles_tiles_in_the_first_scans_middle(
@@ -346,12 +348,75 @@ def test_zonal_tile_device_per_tile_and_roofline_share(monkeypatch):
     assert reader.read(ctx, fold) is None
 
 
+def _fold_events(*lanes):
+    """One ``raster.zonal`` span a scan, oldest first, as the program emits
+    them (`mosaic_tpu/raster/zonal.py`: ``fold_lane``, ``values_dtype``)."""
+    events = [{"event": "span", "name": "join.pip", "seconds": 0.1}]
+    for i, (lane, dtype) in enumerate(lanes):
+        e = {"event": "span", "name": "raster.zonal", "seconds": 0.4,
+             "ts_mono": float(i), "lane": "device"}
+        if lane is not None:
+            e.update(fold_lane=lane, values_dtype=dtype)
+        events.append(e)
+    events.append({"event": "span", "name": "raster.tile", "seconds": 0.01})
+    return events
+
+
+@pytest.mark.parametrize("lanes, widths, tile_bytes", [
+    # the int32 lane (PR 28): int16 pixels at their own width, int32 sums
+    ([("int32", "int16")], (2, 4), 397_312),
+    ([("int32", "uint8")], (1, 4), 65536 * 5 + 4 * 256 * 4),
+    # the wide lane, and a program from before PR 28 that names no lane
+    ([("wide", "float64")], (8, 8), 794_624),
+    ([(None, None)], (8, 8), 794_624),
+    ([], (8, 8), 794_624),
+    # the newest event decides, either way
+    ([("wide", "float64"), ("int32", "int16")], (2, 4), 397_312),
+    ([("int32", "int16"), ("wide", "float64")], (8, 8), 794_624),
+], ids=["int32-int16", "int32-uint8", "wide", "no-lane-named", "no-event",
+        "newest-int32", "newest-wide"])
+def test_fold_widths_follow_the_lane_the_program_names(lanes, widths,
+                                                       tile_bytes):
+    reader = Spec(REPO).module("readers", "zonal_tile_device")
+    assert reader.fold_widths(_fold_events(*lanes)) == widths
+    assert reader.fold_bytes(65536, 256, *widths) == tile_bytes
+
+
+def test_fold_hbm_share_prices_the_lane_that_folded(monkeypatch):
+    """`fold_hbm_share.zonal` through `read`: the same trace reads twice the
+    share on the wide lane's bytes as on the int32 lane's int16 pixels — a
+    mistyped field name would fall back to the wide price unseen."""
+    spec = Spec(REPO)
+    reader = spec.module("readers", "zonal_tile_device")
+    modules = [("jit_zones_fold(2)", 0.0, 0.002 * S)]
+    ops = [("%scatter.3 = s32[257]{0} scatter()", 0.0, 0.002 * S)]
+    tr = {"devices": {"/device:TPU:0": {"ops": ops, "modules": modules}},
+          "program": [("raster.zonal", 0.0, 1.0, None)]}
+    table = {"jit_zones_fold": {"scatter.3 s32[257]": "zonal.fold"}}
+    desc = spec.data("layer_metrics", "fold_hbm_share.zonal")
+    shares = {}
+    for lane, dtype in (("int32", "int16"), ("wide", "float64")):
+        ctx = _reader_ctx(spec, monkeypatch, tr, table,
+                          {"tile_pixels": 65536, "zones": 256})
+        ctx.events = _fold_events((lane, dtype))
+        shares[lane] = reader.read(ctx, desc["params"])
+    assert shares["int32"] == pytest.approx(100 * (397_312 / 819e9) / 0.002)
+    assert shares["wide"] == pytest.approx(100 * (794_624 / 819e9) / 0.002)
+    # the program's own span carries the two fields under these names
+    import inspect
+
+    from mosaic_tpu.raster import zonal
+
+    said = inspect.getsource(zonal)
+    assert "fold_lane=fold" in said and "values_dtype=stage_dt.name" in said
+
+
 def test_the_real_cell_lists_every_zonal_metric_and_no_other_list_grew():
     spec = Spec(REPO)
     assert [m["name"] for m in spec.end_to_end("modis-zonal.scan")] == \
         ["setup_s", "batch_rows_per_s"]
     names = {m["name"] for m in spec.per_layer("modis-zonal.scan")}
-    assert names == set(ZONAL_METRICS) | {"index_build_s", "warmup_s"}
+    assert names == set(ZONAL_METRICS) | set(SHARED_METRICS)
     cfg = spec.config("modis-zonal")
     assert cfg["scene"]["height"] == cfg["scene"]["width"] == 2400
     assert cfg["batch_rows_per_chip"] == 2400 * 2400
