@@ -128,6 +128,10 @@ class IndexSystem(abc.ABC):
         ``face`` (systems that offer `lattice_coords`)."""
         raise NotImplementedError
 
+    def lattice_unpack(self, keys):
+        """``(face, a, b)`` of `lattice_pack`'s keys."""
+        raise NotImplementedError
+
     @abc.abstractmethod
     def grid_distance(self, cells_a: jax.Array, cells_b: jax.Array) -> jax.Array:
         """(N,),(N,) -> (N,) int64 grid distance, consistent with k_loop:

@@ -558,6 +558,14 @@ class H3IndexSystem(IndexSystem):
             + (np.asarray(b, np.int64) + half)
         )
 
+    def lattice_unpack(self, keys):
+        keys = np.asarray(keys, np.int64)
+        half, mask = 1 << (_AXIS_BITS - 1), (1 << _AXIS_BITS) - 1
+        return (
+            keys >> (2 * _AXIS_BITS),
+            ((keys >> _AXIS_BITS) & mask) - half, (keys & mask) - half,
+        )
+
     def lattice_ring(self, k: int) -> np.ndarray:
         """(M,) key offsets of the lattice positions a ring search visits
         at iteration ``k``: the centre and its six neighbours at ``k ==

@@ -161,26 +161,33 @@ def run_offsets(rank, length: int) -> np.ndarray:
     return roff
 
 
-def rank_spans(rank, roff, n_left, xp=jnp):
+def rank_spans(rank, roff, n_left, xp=jnp, after_self: bool = False):
     """:func:`pair_spans`' ``(lo, cnt)``, READ: ``rank`` the left rows'
     dense cell ranks (sorted; the pad rows carry the left sentinel's
     rank, whose run is empty), ``roff`` the right column's
     :func:`run_offsets` over the same ranks. Equal to the searched form
     integer for integer, pad rows included, when the ranks are those of
     the pair's distinct cells followed by ``RIGHT_PAD_CELL`` and
-    ``LEFT_PAD_CELL`` (`sql.overlay.prepare_overlay`)."""
+    ``LEFT_PAD_CELL`` (`sql.overlay.prepare_overlay`).
+
+    ``after_self``: ONE table joined with itself (``roff`` its own run
+    offsets) — a row's span is the rows of its run that come AFTER it,
+    so every unordered pair of rows sharing a rank is emitted once and
+    no row meets itself (`sql.proximity`: the self-join's ``a < b``)."""
     with _scope("overlay.spans", xp):
         rank = xp.asarray(rank)
         roff = xp.asarray(roff)
-        lo = roff[rank]
-        valid = xp.arange(rank.shape[0]) < n_left
-        cnt = xp.where(valid, roff[rank + 1] - lo, 0)
+        row = xp.arange(rank.shape[0])
+        lo = (row + 1).astype(roff.dtype) if after_self else roff[rank]
+        cnt = xp.where(row < n_left, roff[rank + 1] - lo, 0)
     return lo, cnt
 
 
-def emit_spans(lo, cnt, emit_limit, pair_bucket: int, xp=jnp):
+def emit_spans(lo, cnt, emit_limit, pair_bucket: int, xp=jnp, start=None):
     """CSR cross-join emission of the spans ``(lo, cnt)`` against a
-    static ``pair_bucket``.
+    static ``pair_bucket`` — the pair ranks ``start .. start +
+    pair_bucket`` (``start`` None: from 0; a traced scalar lets one
+    compiled bucket emit a long stream a slice at a time).
 
     Returns ``(li, ri, valid)`` — (Pb,) int32 sorted-table row indices
     and the live-slot mask. Pair rank ``k`` resolves to its left row by
@@ -198,6 +205,8 @@ def emit_spans(lo, cnt, emit_limit, pair_bucket: int, xp=jnp):
         total = cnt.sum()
         nl = cnt.shape[0]
         k = xp.arange(pair_bucket, dtype=off.dtype)
+        if start is not None:
+            k = k + xp.asarray(start).astype(off.dtype)
         li = xp.clip(xp.searchsorted(off, k, side="right") - 1, 0, nl - 1)
         ri = lo[li] + (k - off[li])
         valid = k < xp.minimum(total, emit_limit)

@@ -341,6 +341,195 @@ _COVER_SLACK = 0.01
 _COVER_SPAN = 32
 
 
+def lattice_forms(xa, xb, at, ends):
+    """The ranges ``(lo, hi)`` of the lattice's six linear forms — the
+    axial coordinates ``a, b, c = -a - b`` and their differences ``a - b,
+    b - c, c - a`` — over runs of points given by their continuous axial
+    coordinates: run ``i`` is ``at[i]:ends[i]`` (runs may overlap or
+    leave points out; each must hold a point)."""
+    xc = -xa - xb
+    # (an odd slot reduces nothing that is read; the last one may not name
+    # the end of the array)
+    idx = np.stack([at, ends], axis=1).reshape(-1)
+    if idx[-1] >= xa.shape[0]:
+        idx = idx[:-1]
+
+    def span(v):
+        return (np.minimum.reduceat(v, idx)[::2],
+                np.maximum.reduceat(v, idx)[::2])
+
+    return [span(v) for v in (xa, xb, xc, xa - xb, xb - xc, xc - xa)]
+
+
+def _cover_box(forms, reach):
+    """``(a_lo, na, nb)``: the box of lattice positions within ``reach``
+    (six values, each a number or one a run) of the forms' ranges along
+    the two axes — where it starts along ``a`` and its two widths."""
+    a_lo = np.ceil(forms[0][0] - reach[0]).astype(np.int64)
+    a_hi = np.floor(forms[0][1] + reach[0]).astype(np.int64)
+    b_lo = np.ceil(forms[1][0] - reach[1]).astype(np.int64)
+    b_hi = np.floor(forms[1][1] + reach[1]).astype(np.int64)
+    return a_lo, a_hi - a_lo + 1, b_hi - b_lo + 1
+
+
+def cover_scanlines(forms, reach, pick, a_lo, na):
+    """``(run, a, b_lo, n)``: the lattice positions whose six forms all
+    lie within ``reach`` of a run's ranges, for the runs ``pick``, as
+    SCANLINES — run ``run`` holds the positions ``(a, b_lo) .. (a, b_lo +
+    n - 1)``; run by run, ``a`` ascending. With ``a`` fixed each of the
+    other five forms bounds ``b`` to an interval, and a scanline is the
+    integers of their intersection (no position is made that a later test
+    would drop; ``n`` may be 0)."""
+    def at(v):
+        return v[pick] if isinstance(v, np.ndarray) else v
+
+    lo = [at(f[0]) - at(r) for f, r in zip(forms, reach)]
+    hi = [at(f[1]) + at(r) for f, r in zip(forms, reach)]
+    lines = na[pick]
+    s = np.repeat(np.arange(pick.size), lines)  # scanline -> run of `pick`
+    a = a_lo[pick][s] + expand_ranges(np.zeros_like(lines), lines)
+
+    def of(v):
+        return v[s] if isinstance(v, np.ndarray) else v
+
+    # b itself; c = -a - b; a - b; b - c = a + 2b; c - a = -2a - b
+    b_lo = np.maximum.reduce([
+        np.broadcast_to(of(lo[1]), a.shape), -a - of(hi[2]), a - of(hi[3]),
+        (of(lo[4]) - a) / 2.0, -2 * a - of(hi[5]),
+    ])
+    b_hi = np.minimum.reduce([
+        np.broadcast_to(of(hi[1]), a.shape), -a - of(lo[2]), a - of(lo[3]),
+        (of(hi[4]) - a) / 2.0, -2 * a - of(lo[5]),
+    ])
+    b_lo = np.ceil(b_lo).astype(np.int64)
+    n = np.maximum(np.floor(b_hi).astype(np.int64) - b_lo + 1, 0)
+    return pick[s], a, b_lo, n
+
+
+#: the longest run of a line, in lattice steps along it, that one cover
+#: piece holds: the six forms bound a run's hull by a 12-gon, which for a
+#: straight run of length L is up to L sin 15 degrees wider than the run
+#: (a quarter of L), so a piece is kept to a few cells
+_PIECE_STEPS = 4.0
+#: what a reach in coordinate units gives way for the lattice not being
+#: affine over a piece (the gnomonic projection bends under 1e-4 a cell
+#: over `_COVER_SPAN` cells)
+_REACH_SLACK = 1.0 + 1e-3
+
+
+#: a line whose box's half diagonal is under this share of its reach is
+#: covered as one point, the box's centre, the reach made longer by that
+#: half diagonal: a moored vessel's pings, placed on the lattice once
+_POINT_SHARE = 0.25
+
+
+def reach_cover(index_system, resolution: int, xy, starts, ends, reach):
+    """The cells within ``reach`` of polylines, as array code with no
+    buffer and no clipping: `polygon_cover`'s lattice arithmetic with the
+    ranges widened by a radius.
+
+    Line ``i`` is the vertices ``xy[starts[i]:ends[i]]`` (at least one;
+    one vertex is a point), ``reach[i]`` its radius in coordinate units
+    (planar, as `st_buffer` reads it). A line is cut into PIECES of a few
+    cells (`_PIECE_STEPS` along the line; a segment is never cut), a
+    piece's vertices are placed on the lattice, and the hexagons kept are
+    those whose centre is within a hexagon's reach PLUS the radius of
+    every one of the six forms' ranges over the piece — the radius in
+    lattice units a form, ``|grad form| * reach`` with the gradients taken
+    at the line's first vertex by differences. Every point within
+    ``reach`` of the line lies in a kept cell (a superset of
+    ``tessellate(st_buffer(line))``'s cells).
+
+    Returns ``(ok (N,) bool, face (N,), line (S,), a (S,), b (S,), n
+    (S,))``: the lines covered here (the rest — nearer their face's edge
+    than their cover, wider than `_COVER_SPAN` cells a piece, or all of
+    them on a grid with no lattice — are the caller's to tessellate),
+    each line's face, and the cover as scanlines: line ``line[s]`` holds
+    the lattice positions ``(a[s], b[s]) .. (a[s], b[s] + n[s] - 1)`` of
+    its face, line by line. A line's pieces overlap where they meet: a
+    position may come twice."""
+    n = int(np.asarray(starts).shape[0])
+    none = np.zeros(0, dtype=np.int64)
+    if not n or index_system.lattice_keys(none) is None:
+        return np.zeros(n, dtype=bool), none, none, none, none, none
+    starts = np.asarray(starts, dtype=np.int64)
+    count = np.asarray(ends, dtype=np.int64) - starts
+    reach = np.broadcast_to(np.asarray(reach, dtype=np.float64), (n,))
+    pts = xy[expand_ranges(starts, count)]
+    first = np.cumsum(count) - count             # line -> its first vertex
+    lo = np.minimum.reduceat(pts, first, axis=0)
+    hi = np.maximum.reduceat(pts, first, axis=0)
+    half = 0.5 * np.hypot(*(hi - lo).T)
+    dot = half <= _POINT_SHARE * reach
+    if dot.any():
+        keep = np.repeat(~dot, count)
+        keep[first[dot]] = True
+        pts[first[dot]] = 0.5 * (lo[dot] + hi[dot])
+        pts = pts[keep]
+        count = np.where(dot, 1, count)
+        first = np.cumsum(count) - count
+        reach = reach + np.where(dot, half, 0.0)
+    line = np.repeat(np.arange(n), count)        # vertex -> line
+    p0 = pts[first]
+    face = index_system.lattice_coords(p0, resolution)[0]
+    _, xa, xb, edge = index_system.lattice_coords(
+        pts, resolution, face=face[line]
+    )
+    # the lattice's gradients at each line's first vertex, by differences
+    # over a step of the reach's own size
+    h = np.maximum(reach, 1e-9)
+    zero = np.zeros(n)
+    _, ax, bx, _ = index_system.lattice_coords(
+        p0 + np.stack([h, zero], axis=1), resolution, face=face)
+    _, ay, by, _ = index_system.lattice_coords(
+        p0 + np.stack([zero, h], axis=1), resolution, face=face)
+    ga = np.stack([ax - xa[first], ay - xa[first]], axis=1) / h[:, None]
+    gb = np.stack([bx - xb[first], by - xb[first]], axis=1) / h[:, None]
+
+    # pieces: a line's segments bucketed by the lattice steps walked
+    # before them (hex2d's metric in axial coordinates); a line of one
+    # vertex is one piece of one vertex
+    nseg = count - 1
+    seg = np.flatnonzero(
+        np.arange(pts.shape[0]) + 1 < np.repeat(first + count, count)
+    )
+    da, db = xa[seg + 1] - xa[seg], xb[seg + 1] - xb[seg]
+    steps = np.sqrt(da * da + da * db + db * db)
+    before = np.cumsum(steps) - steps
+    sl = line[seg]
+    bucket = np.floor(
+        (before - before[(np.cumsum(nseg) - nseg)[sl]]) / _PIECE_STEPS
+    ).astype(np.int64)
+    head = np.ones(seg.size, dtype=bool)
+    head[1:] = (sl[1:] != sl[:-1]) | (bucket[1:] != bucket[:-1])
+    head = np.flatnonzero(head)
+    tail = np.concatenate([head[1:], [seg.size]])[: head.size] - 1
+    dots = np.flatnonzero(count == 1)
+    p_lo = np.concatenate([seg[head], first[dots]])
+    p_hi = np.concatenate([seg[tail] + 2, first[dots] + 1])
+    order = np.argsort(p_lo, kind="stable")
+    p_lo, p_hi = p_lo[order], p_hi[order]
+    p_line = line[p_lo]
+
+    forms = lattice_forms(xa, xb, p_lo, p_hi)
+    r = reach[p_line] * _REACH_SLACK
+    cell = (_HEX_REACH + _COVER_SLACK,) * 3 + (1.0 + _COVER_SLACK,) * 3
+    widen = [
+        np.hypot(g[p_line, 0], g[p_line, 1]) * r + c
+        for g, c in zip(
+            (ga, gb, -ga - gb, ga - gb, ga + 2.0 * gb, -2.0 * ga - gb), cell
+        )
+    ]
+    a_lo, na, nb = _cover_box(forms, widen)
+    room = np.minimum.reduceat(edge, first)[p_line] - np.maximum(na, nb) - 1
+    ok = np.ones(n, dtype=bool)
+    ok[p_line[(np.maximum(na, nb) > _COVER_SPAN) | (room < 0)]] = False
+    piece, a, b, width = cover_scanlines(
+        forms, widen, np.flatnonzero(ok[p_line]), a_lo, na
+    )
+    return ok, face, p_line[piece], a, b, width
+
+
 def polygon_cover(kx: "KNNIndex", land: PackedGeometry, rings: int) -> LandmarkSeeds:
     """The seed cells of polygon landmarks as array code, with no clipping.
 

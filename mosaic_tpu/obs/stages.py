@@ -63,7 +63,7 @@ _LOCK = threading.Lock()
 _LOWERINGS = [0]
 _RECOMPILED = [0]
 
-_STAGE = re.compile(r"^(?:pip|stream|zonal|knn|overlay)\.[A-Za-z0-9_.]+$")
+_STAGE = re.compile(r"^(?:pip|stream|zonal|knn|overlay|proximity)\.[A-Za-z0-9_.]+$")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')

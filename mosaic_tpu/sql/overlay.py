@@ -926,9 +926,11 @@ def _padded(rows: np.ndarray, bucket: int) -> np.ndarray:
 
 
 @_dispatch.bounded_cache("overlay_count_programs", 8)
-def _count_program():
+def _count_program(after_self: bool = False):
     def overlay_count(rank, roff, n_left):
-        lo, cnt = _k.rank_spans(rank, roff, n_left, xp=jnp)
+        lo, cnt = _k.rank_spans(
+            rank, roff, n_left, xp=jnp, after_self=after_self
+        )
         with jax.named_scope("overlay.spans"):  # a trace books the sum there
             total = cnt.sum()
         return total, lo, cnt
@@ -937,9 +939,18 @@ def _count_program():
 
 
 @_dispatch.bounded_cache("overlay_emit_programs", 32)
-def _emit_program(pair_bucket: int):
-    def overlay_emit(lo, cnt, emit_limit):
-        return _k.emit_spans(lo, cnt, emit_limit, pair_bucket, xp=jnp)
+def _emit_program(pair_bucket: int, sliced: bool = False):
+    """The emission at ``pair_bucket``; ``sliced``: the program takes the
+    first pair rank to emit as well (`sql.proximity` emits a stream
+    longer than the top bucket a slice a launch)."""
+    if sliced:
+        def overlay_emit(lo, cnt, emit_limit, start):
+            return _k.emit_spans(
+                lo, cnt, emit_limit, pair_bucket, xp=jnp, start=start
+            )
+    else:
+        def overlay_emit(lo, cnt, emit_limit):
+            return _k.emit_spans(lo, cnt, emit_limit, pair_bucket, xp=jnp)
 
     return jax.jit(overlay_emit)
 

@@ -230,3 +230,21 @@ def test_dist_join_step_lowers_for_tpu(problem, devices):
     )
     hlo = _tpu_lower(step.trace(jnp.asarray(p), jnp.asarray(c), idx))
     assert "all-gather" in hlo or "all_gather" in hlo  # ICI collective present
+
+
+def test_distance_join_programs_lower_for_tpu():
+    """The distance join's gather and segment-pair predicate at float32,
+    the dtype the chip runs them in (`sql.proximity`): field-major rows
+    in, one int8 a candidate row out, no float64 array (a weak-typed
+    scalar bound of `clip` is converted where it is used)."""
+    from mosaic_tpu.sql import proximity as P
+
+    width, rows = 2 * P.PIECE_VERTS + 5, 4096
+    table = jnp.zeros((512, width), jnp.float32)
+    idx = jnp.zeros(rows, jnp.int32)
+    hlo = _tpu_lower(P._gather_program().trace(idx, idx, idx, idx, table, table))
+    assert f"{width}x{rows}xf32" in hlo.replace(" ", "")
+    fields = jnp.zeros((width, rows), jnp.float32)
+    hlo = _tpu_lower(P._segpair_program().trace(
+        fields, fields, jnp.zeros(rows, bool), np.float32(1e-6)))
+    assert f"{rows}xf64" not in hlo and f"{rows}xi8" in hlo.replace(" ", "")
