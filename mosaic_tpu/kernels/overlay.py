@@ -19,12 +19,18 @@ index build), and the per-query work runs on device:
   compares on a chip with no 64-bit integers. The device lane's form,
   computed once a call.
 - :func:`emit_spans` (and :func:`emit_pairs`, the searched spans in front
-  of it) — bounded CSR cross-join emission: pair rank ``k``
-  maps to its left row by a ``searchsorted`` over the exclusive span
-  offsets and to its right row by the in-span remainder, against a
-  STATIC pair bucket so the compiled program population stays on the
-  dispatch ladder. Caps are full-bucket: overflow is structural (the
-  caller truncates at an explicit cap and reports OVERFLOW(-2)
+  of it) — bounded CSR cross-join emission: pair rank ``k`` maps to its
+  left row, the last one whose exclusive span offset is at most ``k``,
+  and to its right row by the in-span remainder, against a STATIC pair
+  bucket so the compiled program population stays on the dispatch
+  ladder. The numpy twin finds the left row by a ``searchsorted`` over
+  the offsets (the oracle's job is to be obviously right); the device
+  does NOT search — the offsets are sorted and the slots consecutive, so
+  it scatters a mark at every row's offset onto the slots, sums the
+  marks along the slots and gathers once (:func:`_rows_by_marks`; a
+  search is some twenty rounds of a gather of every slot, and the chip
+  pays a gather per index). Caps are full-bucket: overflow is structural
+  (the caller truncates at an explicit cap and reports OVERFLOW(-2)
   in-band), never an escalation.
 - :func:`clip_area_convex` — batched Sutherland–Hodgman clip area
   against a CONVEX window, mirroring
@@ -60,14 +66,18 @@ Every kernel takes ``xp`` (jnp or numpy) and is written against the
 array-API subset the two share, so the f64 host twin used by the
 overlay oracle IS this code: elementwise IEEE ops agree bitwise between
 numpy and XLA CPU, integer searchsorted/cumsum/gather are exact, and
-the only scatter (:func:`_scatter_rows`, the host's left-pack) writes
-disjoint targets. The shoelace accumulation is an UNROLLED python loop
-over the static width on both sides — XLA preserves the float op order
-of an unrolled chain, which is what makes the device area bit-identical
-to the numpy twin under x64 off the TPU. The fold back to
-per-geometry-pair totals is `kernels.zonal.zonal_fold_masked` on device
-and :func:`host_pair_fold` (``np.add.at`` — sequential in row order,
-like XLA's CPU scatter) on host.
+the only float scatter (:func:`_scatter_rows`, the host's left-pack)
+writes disjoint targets. (The one place the lanes share no logic is the
+emission's left row — searched on the host, marked and summed on the
+device, integer adds whose order cannot matter — and the tests hold the
+two to each other array for array.) The shoelace accumulation is an
+UNROLLED python loop over the static width on both sides — XLA
+preserves the float op order of an unrolled chain, which is what makes
+the device area bit-identical to the numpy twin under x64 off the TPU.
+The fold back to per-geometry-pair totals is
+`kernels.zonal.zonal_fold_masked` on device and :func:`host_pair_fold`
+(``np.add.at`` — sequential in row order, like XLA's CPU scatter) on
+host.
 """
 
 from __future__ import annotations
@@ -183,32 +193,60 @@ def rank_spans(rank, roff, n_left, xp=jnp, after_self: bool = False):
     return lo, cnt
 
 
-def emit_spans(lo, cnt, emit_limit, pair_bucket: int, xp=jnp, start=None):
+def _rows_by_marks(off, start, pair_bucket: int):
+    """``searchsorted(off, start + arange(pair_bucket), 'right') - 1``
+    with no search — the device lane's form. ``off`` is sorted and the
+    slots are consecutive, so the count of rows with ``off[i] <= k`` is a
+    running sum over the slots of how many rows' offsets land on each:
+    every row adds 1 at ``off[i] - start``, the rows at or before the
+    slice's first slot onto slot 0 (what a ``base`` would count), the rows
+    at or past its end out of range and dropped. The clamp keeps the
+    targets non-decreasing, which the scatter is told; they are NOT
+    unique — every zero-count row shares its successor's offset — so the
+    marks are added, not set. One scatter of ``nl`` updates and one
+    running sum, where the search is some twenty rounds of
+    ``pair_bucket`` gathered indices (a gather is paid per index on the
+    chip: ``PERF.md`` section 6, PRs 34 and 49)."""
+    pos = jnp.clip(off - start, 0, pair_bucket)
+    marks = jnp.zeros(pair_bucket, off.dtype).at[pos].add(
+        1, mode="drop", indices_are_sorted=True
+    )
+    return jnp.cumsum(marks) - 1
+
+
+def emit_spans(lo, cnt, emit_limit, pair_bucket: int, xp=jnp, start=0):
     """CSR cross-join emission of the spans ``(lo, cnt)`` against a
     static ``pair_bucket`` — the pair ranks ``start .. start +
-    pair_bucket`` (``start`` None: from 0; a traced scalar lets one
-    compiled bucket emit a long stream a slice at a time).
+    pair_bucket`` (a traced ``start`` lets one compiled bucket emit a
+    long stream a slice at a time).
 
     Returns ``(li, ri, valid)`` — (Pb,) int32 sorted-table row indices
-    and the live-slot mask. Pair rank ``k`` resolves to its left row by
-    ``searchsorted(off, k, 'right') - 1`` over the exclusive span
-    offsets (zero-count rows are skipped by construction) and to its
-    right row by ``lo + (k - off)``, which lies inside the row's span
-    and so inside the right table. Emission order is left-row-major
-    over the cell-sorted table == cell-major — the exact stream order of
-    the host candidate generator, which is what makes the downstream
-    fold order reproducible. Slots at and past ``min(total,
-    emit_limit)`` are invalid and read row 0 on both sides (the caller
-    books ``total - emitted`` as OVERFLOW)."""
+    and the live-slot mask. Pair rank ``k`` resolves to its left row, the
+    last row whose exclusive span offset is at most ``k`` (zero-count
+    rows are skipped by construction), and to its right row by ``lo + (k
+    - off)``, which lies inside the row's span and so inside the right
+    table. The two lanes share no logic for the left row: the numpy twin
+    (the oracle) SEARCHES, ``searchsorted(off, k, 'right') - 1``; the
+    device scatters the rows' offsets onto the slots and sums
+    (:func:`_rows_by_marks`), then reads ``(lo - off)[li] + k`` in one
+    gather — the same integers, tested array for array. Emission order
+    is left-row-major over the cell-sorted table == cell-major — the
+    exact stream order of the host candidate generator, which is what
+    makes the downstream fold order reproducible. Slots at and past
+    ``min(total, emit_limit)`` are invalid and read row 0 on both sides
+    (the caller books ``total - emitted`` as OVERFLOW)."""
     with _scope("overlay.emit", xp):
         off = xp.cumsum(cnt) - cnt
-        total = cnt.sum()
+        total = cnt.sum(dtype=off.dtype)  # (jnp would widen an int32 sum)
         nl = cnt.shape[0]
-        k = xp.arange(pair_bucket, dtype=off.dtype)
-        if start is not None:
-            k = k + xp.asarray(start).astype(off.dtype)
-        li = xp.clip(xp.searchsorted(off, k, side="right") - 1, 0, nl - 1)
-        ri = lo[li] + (k - off[li])
+        start = xp.asarray(start).astype(off.dtype)
+        k = xp.arange(pair_bucket, dtype=off.dtype) + start
+        if xp is jnp:
+            li = xp.clip(_rows_by_marks(off, start, pair_bucket), 0, nl - 1)
+            ri = (lo - off)[li] + k
+        else:
+            li = xp.clip(xp.searchsorted(off, k, side="right") - 1, 0, nl - 1)
+            ri = lo[li] + (k - off[li])
         valid = k < xp.minimum(total, emit_limit)
         li = xp.where(valid, li, 0)
         ri = xp.where(valid, ri, 0)

@@ -17,11 +17,13 @@ Two lanes share one contract:
   whose spans are READ, once a call, through the prep's dense cell
   ranks and the right column's run offsets
   (`kernels.overlay.rank_spans`, then `emit_spans` against a static
-  pair bucket), and the overlap measures — per-row intersection areas,
-  folded per geometry pair, with an `expr/` pair tree evaluated over
-  the folded tables — run as ONE fused program per ``(tree-hash,
-  buckets, index, mesh)`` signature through `DispatchCore` (compile cache, warmup
-  tripwire, watchdog/retry, ``mesh=`` sharding, graceful degradation).
+  pair bucket: a slot finds its left row by a scatter of the rows' span
+  offsets and a running sum, not by a search), and the overlap measures
+  — per-row intersection areas, folded per geometry pair, with an
+  `expr/` pair tree evaluated over the folded tables — run as ONE fused
+  program per ``(tree-hash, buckets, index, mesh)`` signature through
+  `DispatchCore` (compile cache, warmup tripwire, watchdog/retry,
+  ``mesh=`` sharding, graceful degradation).
 - **Host lane** (`expr.host_oracle.host_overlay_measures`): the numpy
   twin of the same kernels (``xp=np``) — off the TPU, under x64, the
   pure-f64 oracle the device lane matches bit for bit, and the
@@ -939,18 +941,14 @@ def _count_program(after_self: bool = False):
 
 
 @_dispatch.bounded_cache("overlay_emit_programs", 32)
-def _emit_program(pair_bucket: int, sliced: bool = False):
-    """The emission at ``pair_bucket``; ``sliced``: the program takes the
-    first pair rank to emit as well (`sql.proximity` emits a stream
-    longer than the top bucket a slice a launch)."""
-    if sliced:
-        def overlay_emit(lo, cnt, emit_limit, start):
-            return _k.emit_spans(
-                lo, cnt, emit_limit, pair_bucket, xp=jnp, start=start
-            )
-    else:
-        def overlay_emit(lo, cnt, emit_limit):
-            return _k.emit_spans(lo, cnt, emit_limit, pair_bucket, xp=jnp)
+def _emit_program(pair_bucket: int):
+    """The emission at ``pair_bucket``, from the pair rank ``start`` on:
+    0 for a stream one bucket holds (`overlay_measures`), a slice a launch
+    for a longer one (`sql.proximity`)."""
+    def overlay_emit(lo, cnt, emit_limit, start=0):
+        return _k.emit_spans(
+            lo, cnt, emit_limit, pair_bucket, xp=jnp, start=start
+        )
 
     return jax.jit(overlay_emit)
 
@@ -1062,7 +1060,8 @@ def overlay_measures(
     cells, the call's only pass over the cell columns — and the blocking
     read of their total; the spans stay on the device), ``overlay.emit``
     (the launch that turns those spans into candidate rows at the pair
-    bucket the total picked, and the pull of the rows),
+    bucket the total picked — ``form="marks"``: no search — and the pull
+    of the rows),
     ``overlay.glue`` (`pair_glue`, `pair_routes`), ``overlay.launch``,
     ``overlay.pull`` and ``overlay.host_override``.
 
@@ -1123,9 +1122,9 @@ def overlay_measures(
                     dtotal, dlo, dcnt = count(*args)
                     total = int(dtotal)
                 Pb, emit_limit, overflow = pair_plan(total, pair_cap)
-                with _trace.span("overlay.emit", bucket=Pb):
+                with _trace.span("overlay.emit", bucket=Pb, form="marks"):
                     emit = _emit_program(Pb)
-                    args = (dlo, dcnt, emit_limit)
+                    args = (dlo, dcnt, emit_limit, np.int32(0))
                     _register_stages(emit, args, Pb)
                     dli, dri, dvalid = emit(*args)
                     li = np.asarray(dli)

@@ -23,7 +23,12 @@ with no buffer and no clipper:
   composite and, for ONE table joined with itself, with every row's span
   starting after the row (``after_self``: each unordered pair once).
   The emission runs a slice of ``CHUNK_PAIRS`` candidate rows a launch,
-  so a stream of any length compiles one bucket.
+  so a stream of any length compiles one bucket. On the device a slot of
+  the slice finds its left row with no search: every row's span offset
+  is scattered onto the slice's slots as a mark and the marks are summed
+  along them (`kernels.overlay._rows_by_marks`; the spans carry
+  ``form="marks"``); the numpy twin (``lane="host"``) searches the
+  offsets, and is what the device form is tested against.
 - **Predicate** (`kernels.proximity`): a candidate row's two PIECES — a
   line cut into runs of ``PIECE_VERTS`` vertices, the source's tracks
   being one piece each — gathered from the resident piece table into the
@@ -759,7 +764,7 @@ def _device_launch(prep: ProximityPrep, pair_cap, call):
     Pb, emit_limit, overflow, starts = _chunk_plan(total, pair_cap)
     call.set(raw_candidates=total, bucket=Pb, overflow=overflow,
              launches=len(starts))
-    emit = _emit_program(Pb, True)
+    emit = _emit_program(Pb)
     gather = _gather_program()
     segpairs = _segpair_program()
     band = acc.type(prep.band)
@@ -767,7 +772,7 @@ def _device_launch(prep: ProximityPrep, pair_cap, call):
     def launches():
         out = []
         for start in starts:
-            with _trace.span("proximity.emit", bucket=Pb):
+            with _trace.span("proximity.emit", bucket=Pb, form="marks"):
                 args = (dlo, dcnt, emit_limit, np.int32(start))
                 _register_stages(emit, args, Pb)
                 dli, dri, dvalid = emit(*args)
@@ -838,7 +843,7 @@ def warmup_dwithin(
         for Tk in around(RANK_LADDER, prep.roff.shape[0]):
             _t, lo, cnt = count(lrank, jnp.zeros(Tk, jnp.int32), 0)
         for Pb in chunk:
-            li, ri, _v = _emit_program(Pb, True)(lo, cnt, 0, np.int32(0))
+            li, ri, _v = _emit_program(Pb)(lo, cnt, 0, np.int32(0))
             for dt in (-1, 0, 1):
                 gather(
                     li, ri, lrank, rrank,

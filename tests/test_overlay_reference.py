@@ -440,7 +440,7 @@ def test_a_call_reads_its_spans_once_and_the_emission_cannot_search(
 
     polygons, col, prep = world["themes"]["districts"]
     assert list(inspect.signature(ov._emit_program(64)).parameters) == [
-        "lo", "cnt", "emit_limit"]
+        "lo", "cnt", "emit_limit", "start"]
     seen = {"count": [], "emit": []}
 
     def spy(kind, make):
@@ -469,10 +469,14 @@ def test_a_call_reads_its_spans_once_and_the_emission_cannot_search(
     assert count_args[1] is prep.right.dev["roff"]
     assert emit_args[0] is lo and emit_args[1] is cnt
     assert isinstance(lo, jax.Array) and isinstance(cnt, jax.Array)
-    assert isinstance(emit_args[2], int) and len(emit_args) == 3
+    assert isinstance(emit_args[2], int) and len(emit_args) == 4
+    assert emit_args[3] == 0  # one bucket holds the stream: from rank 0
     (span,) = [e for e in events
                if e.get("event") == "span" and e.get("name") == "overlay.count"]
     assert span["spans"] == "rank" and span["ranks"] == prep.ranks
+    (span,) = [e for e in events
+               if e.get("event") == "span" and e.get("name") == "overlay.emit"]
+    assert span["form"] == "marks"
     # the resident tables hold no int64 cell column any more
     for side in (prep.left, prep.right):
         assert "cells" not in side.dev

@@ -71,6 +71,8 @@ def test_self_join_with_key_and_a_radius_a_row(table):
         assert "proximity." + child in names, child
     root = next(e for e in spans if e.get("name") == "proximity.call")
     assert root["hits"] == len(want) and root["vpad"] == 16
+    emits = [e for e in spans if e.get("name") == "proximity.emit"]
+    assert emits and all(e["form"] == "marks" for e in emits)
 
 
 def test_no_key_and_a_scalar_radius(table):
@@ -270,6 +272,124 @@ def test_rank_spans_after_self_and_sliced_emission():
     # two tables: the spans are the searched ones
     lo2, cnt2 = K.rank_spans(rank, roff, 6, xp=np)
     assert cnt2.tolist() == [3, 3, 3, 1, 2, 2, 0, 0]
+
+
+def _span_set(case: str, nl: int = 64):
+    """``(lo, cnt)`` int32 span sets of ``nl`` table rows, built to break
+    a form that marks the rows' offsets on the slots and sums them."""
+    rng = np.random.default_rng(49)
+    cnt = np.zeros(nl, np.int32)
+    if case == "zero_runs_at_the_head_the_tail_and_between":
+        cnt[5:9] = (3, 1, 4, 2)
+        cnt[20:23] = (7, 0, 2)
+        cnt[40] = 5
+    elif case == "one_row_spans_several_slices":
+        cnt[3], cnt[4], cnt[9] = 2, 50, 3
+    elif case == "every_count_zero":
+        pass
+    elif case == "every_row_live":
+        cnt[:] = rng.integers(1, 4, nl)
+    elif case == "pad_rows_behind":
+        cnt[:40] = rng.integers(0, 3, 40)
+    elif case == "one_live_row_last":
+        cnt[-1] = 9
+    elif case == "one_live_row_first":
+        cnt[0] = 9
+    elif case == "a_self_joins_runs":  # every run's last row counts zero
+        rank = np.sort(rng.integers(0, 12, nl)).astype(np.int32)
+        roff = K.run_offsets(rank, 16)
+        lo, cnt = K.rank_spans(rank, roff, nl - 6, xp=np, after_self=True)
+        return lo.astype(np.int32), cnt.astype(np.int32)
+    else:
+        raise ValueError(case)
+    return rng.integers(0, 1000, nl).astype(np.int32), cnt
+
+
+def _assert_same_rows(got, want, note):
+    """``(li, ri, valid)`` of the device against the twin's: array for
+    array, dtype for dtype."""
+    for g, w in zip(got, want):
+        assert np.asarray(g).dtype == w.dtype and np.array_equal(g, w), note
+
+
+@pytest.mark.parametrize("bucket", [16, 64])
+@pytest.mark.parametrize("case", [
+    "zero_runs_at_the_head_the_tail_and_between",
+    "one_row_spans_several_slices",
+    "every_count_zero",
+    "every_row_live",
+    "pad_rows_behind",
+    "one_live_row_last",
+    "one_live_row_first",
+    "a_self_joins_runs",
+])
+def test_the_device_emission_is_the_searched_twin(case, bucket):
+    """The device finds a slot's left row by a scatter of the rows' span
+    offsets and a running sum (`kernels.overlay._rows_by_marks`); the
+    numpy twin searches. ``li``, ``ri``, ``valid`` agree array for array
+    and dtype for dtype: unsliced and at every slice of the stream —
+    ``start`` at a span's first slot, inside a span, at ``total`` and past
+    it — capped under ``total`` or not."""
+    import jax.numpy as jnp
+
+    from mosaic_tpu.sql.overlay import _emit_program
+
+    lo, cnt = _span_set(case)
+    off = np.cumsum(cnt) - cnt
+    total = int(cnt.sum())
+    live = np.flatnonzero(cnt)
+    starts = {0, total, total + 1, total + 3 * bucket, max(total - 1, 0)}
+    starts.update(range(0, total + bucket, bucket))           # every slice
+    starts.update(int(off[i]) for i in live[:6])               # a span's first slot
+    starts.update(int(off[i] + cnt[i] // 2) for i in live[:6])  # inside a span
+    starts.update(int(off[i] + cnt[i] - 1) for i in live[-3:])  # a span's last slot
+    emit = _emit_program(bucket)
+    dlo, dcnt = jnp.asarray(lo), jnp.asarray(cnt)
+    checked = 0
+    for limit in {total, total // 2, max(total - 1, 0), total + 5}:
+        # unsliced: the overlay's call
+        _assert_same_rows(emit(dlo, dcnt, limit),
+                          K.emit_spans(lo, cnt, limit, bucket, xp=np), (limit,))
+        for start in sorted(starts):
+            got = emit(dlo, dcnt, limit, np.int32(start))
+            _assert_same_rows(
+                got, K.emit_spans(lo, cnt, limit, bucket, xp=np, start=start),
+                (limit, start))
+            checked += int(np.asarray(got[2]).sum())
+    assert (checked > 0) == (total > 0)
+    # the live slots of the whole stream name each row as often as it counts
+    if total:
+        li = np.concatenate([
+            np.asarray(emit(dlo, dcnt, total, np.int32(s))[0])[
+                : max(min(bucket, total - s), 0)]
+            for s in range(0, total, bucket)
+        ])
+        assert np.array_equal(np.bincount(li, minlength=cnt.size), cnt)
+
+
+def test_random_span_sets_emit_the_searched_twins_rows():
+    """Three hundred seeded span sets — zero-count runs, pad rows, caps,
+    slices from the middle and past the end — through one compiled
+    bucket: no difference from the search."""
+    import jax.numpy as jnp
+
+    from mosaic_tpu.sql.overlay import _emit_program
+
+    rng = np.random.default_rng(4949)
+    emit = _emit_program(32)
+    for _ in range(300):
+        nl = 128
+        n_left = int(rng.integers(0, nl + 1))
+        cnt = np.zeros(nl, np.int32)
+        cnt[:n_left] = rng.integers(0, 5, n_left) * (rng.random(n_left) < rng.random())
+        lo = rng.integers(0, 500, nl).astype(np.int32)
+        total = int(cnt.sum())
+        start = int(rng.integers(0, total + 40))
+        limit = int(rng.integers(0, total + 10))
+        _assert_same_rows(
+            emit(jnp.asarray(lo), jnp.asarray(cnt), limit, np.int32(start)),
+            K.emit_spans(lo, cnt, limit, 32, xp=np, start=start),
+            (start, limit, cnt.tolist()))
 
 
 def test_a_cap_that_cuts_rows_yields_the_overflow_row(table):

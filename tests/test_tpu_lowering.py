@@ -248,3 +248,26 @@ def test_distance_join_programs_lower_for_tpu():
     hlo = _tpu_lower(P._segpair_program().trace(
         fields, fields, jnp.zeros(rows, bool), np.float32(1e-6)))
     assert f"{rows}xf64" not in hlo and f"{rows}xi8" in hlo.replace(" ", "")
+
+
+@pytest.mark.parametrize("rows, bucket", [(4096, 1024), (1024, 1024)])
+def test_the_emission_holds_one_scatter_one_gather_and_no_search(rows, bucket):
+    """The equi-join's emission lowered for the TPU is what the marks form
+    is made of and no more: ONE scatter (the rows' span offsets onto the
+    slots), ONE gather (``(lo - off)[li]``) and no ``while`` — a
+    ``searchsorted`` is a loop of some twenty rounds, each a gather of
+    ``bucket`` indices, which the chip pays per index (`PERF.md` section
+    6, PR 49). int32 throughout: no 64-bit integer array reaches the
+    chip."""
+    from mosaic_tpu.sql.overlay import _emit_program
+
+    spans = jnp.zeros(rows, jnp.int32)
+    traced = _emit_program(bucket).trace(spans, spans, 7, np.int32(3))
+    hlo = traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert hlo.count('"stablehlo.scatter"(') == 1
+    assert hlo.count('"stablehlo.gather"(') == 1
+    assert "stablehlo.while" not in hlo and "stablehlo.sort" not in hlo
+    assert "overlay.emit" in hlo  # the scope `obs/stages.py` names the ops by
+    flat = hlo.replace(" ", "")
+    assert f"{bucket}xi64" not in flat and f"{rows}xi64" not in flat
+    assert f"tensor<{bucket}xi32>" in flat
