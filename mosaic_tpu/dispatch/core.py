@@ -251,7 +251,10 @@ def _join_mod():
 def jit_join():
     """The process-wide jitted exact join — ONE executable cache shared
     by batch, stream, serve, raster, and the sharded step, so a server
-    and a batch job in one process share compiles."""
+    and a batch job in one process share compiles. A caller that has
+    probed (`pip_join`, from :func:`jit_counts`) passes ``slots=`` and
+    None for the cells; the others pass the cells and the program
+    probes: one cache, keyed as ever on what it is called with."""
     m = _join_mod()
     return jax.jit(
         m.pip_join_points,
@@ -263,8 +266,13 @@ def jit_join():
 
 @bounded_cache("jit_counts", 1)
 def jit_counts():
-    """Jitted exact-cap probe counts ((3,) found/heavy/convex)."""
-    return jax.jit(_join_mod()._probe_counts)
+    """The jitted count sync of `pip_join` (`sql.join._probe_counts`,
+    ``probe`` static): ``(counts, slots)`` — the (3,) found / heavy /
+    convex row counts, which the host pulls to size the join's caps, and
+    the (N,) int32 slot column they were counted on, which stays on the
+    device and is handed to :func:`jit_join` as ``slots=``, so that the
+    hash table is probed once a chunk."""
+    return jax.jit(_join_mod()._probe_counts, static_argnames=("probe",))
 
 
 @bounded_cache("jit_compact", 1)

@@ -929,13 +929,30 @@ def _probe_slot(pcells: jax.Array, index: ChipIndex) -> jax.Array:
 
 
 @jax.named_scope("pip.counts")
-def _probe_counts(pcells: jax.Array, index: ChipIndex):
-    """Device-side exact compaction-cap inputs: one (3,) array of (found
-    count, heavy-cell count, convex-cell count) — `pip_join` pulls these
-    ints in a single transfer instead of the whole cell column (32 MB at
-    4M points). Its own scope, `pip.counts`, so that the probe that sizes
-    the caps reads apart from `pip.hash_probe`, the probe that answers."""
-    u = _probe_slot(pcells, index)
+def _probe_counts(pcells: jax.Array, index: ChipIndex, probe: str = "scatter"):
+    """The probe that sizes `pip_join`'s caps, and answers: ``(counts, u)``.
+
+    ``counts`` is one (3,) array of (found count, heavy-cell count,
+    convex-cell count) — `pip_join` pulls these ints in a single transfer
+    instead of the whole cell column (32 MB at 4M points) and sizes its
+    compaction caps from them. ``u`` is the (N,) int32 slot column the
+    counts were taken on (`_probe_slot`: a row's cell row, -1 on a miss).
+    It stays on the device: `pip_join` hands it to the join program as
+    ``slots=``, which then holds no probe of its own — the table is read
+    once a chunk, here.
+
+    The heavy count gathers ``cell_heavy`` over all rows where the index
+    has heavy cells. The convex count gathers ``cell_convex`` only where a
+    lane reads it: under an adaptive ``probe`` (static) on an index with
+    convex cells; under ``probe="scatter"`` no cap is sized from it, so it
+    is not taken and reads 0 (26.5 ms of a 4M-row call on v5e: PERF.md
+    section 6, PR 50).
+
+    Scopes: the probe sits under ``pip.hash_probe`` (the innermost scope
+    names an op's stage in `obs.stages`), the sums and the heavy / convex
+    gathers under ``pip.counts``."""
+    with jax.named_scope("pip.hash_probe"):
+        u = _probe_slot(pcells, index)
     found = u >= 0
     nf = found.sum()
     us = jnp.maximum(u, 0)
@@ -943,11 +960,11 @@ def _probe_counts(pcells: jax.Array, index: ChipIndex):
         nh = (jnp.where(found, index.cell_heavy[us], -1) >= 0).sum()
     else:
         nh = jnp.zeros((), nf.dtype)
-    if index.convex_edges.shape[0]:
+    if probe != "scatter" and index.convex_edges.shape[0]:
         nc = (jnp.where(found, index.cell_convex[us], -1) >= 0).sum()
     else:
         nc = jnp.zeros((), nf.dtype)
-    return jnp.stack([nf, nh, nc])
+    return jnp.stack([nf, nh, nc]), u
 
 
 def _ray_parity(px, py, edges, bits, eps2=None):
@@ -1328,6 +1345,7 @@ def pip_join_points(
     writeback: str = "scatter",
     probe: str = "scatter",
     convex_cap: int | None = None,
+    slots: jax.Array | None = None,
 ) -> jax.Array:
     """(N,) int32 — smallest matching polygon row per point, -1 if none.
 
@@ -1387,10 +1405,22 @@ def pip_join_points(
     the ``MOSAIC_PROBE_FORCE_LANE`` env knob into these pinned values
     before jit ever sees the argument. Convex-lane overflow returns
     :data:`OVERFLOW`, exactly like the other caps.
+
+    ``slots`` is for the caller that has probed already: the (N,) int32
+    slot column of ``pcells`` on ``index`` (`_probe_slot`: a row's cell
+    row, -1 on a miss), as `_probe_counts` returns it beside the counts.
+    Given it, the program reads it where it would have probed — no hash,
+    no gather of ``table_rows`` — and ``pcells`` is not read and may be
+    None; the rows returned are the probing call's bit for bit. `pip_join`
+    hands each chunk's column from its count sync to the join (and to
+    every escalation of it, and the band's from the recheck's own count to
+    the narrow re-join). Without it (the stream, `DispatchCore`, the raster
+    probe, the sharded lanes: callers with no count sync) the program
+    probes, as ever.
     """
     out, near, _ = _join_points(
         points, pcells, index, heavy_cap, found_cap, edge_eps2, writeback,
-        probe, convex_cap, with_heavy=False,
+        probe, convex_cap, with_heavy=False, slots=slots,
     )
     return out if edge_eps2 is None else (out, near)
 
@@ -1411,10 +1441,11 @@ def pip_join_points_heavy(
 
 def _join_points(
     points, pcells, index, heavy_cap, found_cap, edge_eps2, writeback, probe,
-    convex_cap, *, with_heavy,
+    convex_cap, *, with_heavy, slots=None,
 ):
     """The join behind :func:`pip_join_points`: ``(out, near | None,
-    heavy | None)``; ``heavy`` only where ``with_heavy`` and H > 0."""
+    heavy | None)``; ``heavy`` only where ``with_heavy`` and H > 0. With
+    ``slots`` the probe's answer is an input and ``pcells`` is not read."""
     if writeback not in ("scatter", "gather", "direct"):
         raise ValueError(
             f"writeback must be scatter|gather|direct, got {writeback!r}"
@@ -1435,13 +1466,15 @@ def _join_points(
             "probe='adaptive' routes through compaction; it composes "
             "with writeback scatter|gather, not direct"
         )
+    if pcells is None and slots is None:
+        raise ValueError("pip_join_points needs pcells to probe, or slots")
     N = points.shape[0]
     # named scopes mark the probe stages: every instruction of the join
     # sits under exactly one innermost pip.* scope, which
     # `obs.stages` reads back from the optimized HLO to name the device
     # trace's ops (scopes are HLO metadata only — results do not change)
     with jax.named_scope("pip.hash_probe"):
-        u = _probe_slot(pcells, index)
+        u = _probe_slot(pcells, index) if slots is None else slots
         found = u >= 0
     banded = edge_eps2 is not None
     H = int(index.heavy_edges.shape[0])
@@ -1722,18 +1755,21 @@ def _register_stages(fn, args: tuple, kw: dict, rows: int) -> None:
     )
 
 
-def _launch_counts(cells: jax.Array, index: ChipIndex) -> jax.Array:
-    """Enqueue the counts program over ``cells``: the (found, heavy-cell,
-    convex-cell) row counts as one device array, not waited for."""
+def _launch_counts(cells: jax.Array, index: ChipIndex, probe: str) -> tuple:
+    """Enqueue the counts program (:func:`_probe_counts`) over ``cells``,
+    not waited for: ``(counts, slots)``, both device arrays. ``counts``,
+    the (found, heavy-cell, convex-cell) row counts, is for
+    :func:`_pull_counts`; ``slots``, the (N,) slot column they were
+    counted on, never leaves the device: it is the join's ``slots=``."""
     prog = _dispatch.jit_counts()
-    _register_stages(prog, (cells, index), {}, cells.shape[0])
-    return prog(cells, index)
+    _register_stages(prog, (cells, index), {"probe": probe}, cells.shape[0])
+    return prog(cells, index, probe=probe)
 
 
-def _pull_counts(launched: jax.Array) -> tuple:
+def _pull_counts(counts: jax.Array) -> tuple:
     """The three counts of :func:`_launch_counts` as ints: a blocking
-    pull."""
-    return tuple(int(v) for v in np.asarray(launched))
+    pull (of 24 bytes; the slot column beside them is not touched)."""
+    return tuple(int(v) for v in np.asarray(counts))
 
 
 def _assign_cells(index_system, resolution: int, dev: jax.Array, variant: str):
@@ -1779,6 +1815,15 @@ def pip_join(
     launched, not waited for, before the host's f64 ``chunk - shift``;
     its scalars are pulled after the shifted put, so the subtract runs
     while the device transfers the batch, assigns cells and counts.
+    The count's hash probe is the join's: the counts program returns the
+    slot column it counted on (:func:`_probe_counts`), which stays on the
+    device, and every join launched for the chunk — each escalation
+    attempt too — reads it as ``slots=`` and holds no probe of its own;
+    the recheck's narrow re-join reads the band's column from the band's
+    own count the same way. ``join.launch`` says ``slots="handed"``
+    (``"probed"`` where the sync is skipped: ``writeback="direct"`` on an
+    index with no heavy cell) and the call's ``join.pip`` span counts the
+    ``probes``: programs holding a hash probe launched for the call.
     Should a cap overflow anyway (shrunken by `runtime.faults`
     injection, or user-adversarial inputs), the bounded escalation
     engine (`runtime/escalate.py`) regrows every cap geometrically until
@@ -1915,6 +1960,7 @@ def pip_join(
             # `run_resilient`'s host-oracle degradation like every lane.
             padded, nn = core.ladder.pad(chunk)
             # (`sp`: the call's `join.pip` span, open around every `run`)
+            sp.attrs["probes"] += 1  # the sharded join program's own
             sp.attrs["compacted"] |= core.compacted(padded.shape[0])
             sp.attrs["tier2_compacted"] |= core.tier2_compacted(
                 padded.shape[0]
@@ -1942,9 +1988,14 @@ def pip_join(
         # program is only enqueued here; its scalars are pulled after the
         # host's subtract, which needs none of them
         synced = writeback != "direct" or bool(chip_index.num_heavy_cells)
+        # the count's probe is the join's: `slots` is the column it counted
+        # on, still on the device, and the join handed it holds no probe
+        # (and no longer reads `cells`). With no sync the join probes
+        slots = None
+        sp.attrs["probes"] += 1
         if synced:
             with _obs_trace.span("join.counts_launch") as sl:
-                launched = _launch_counts(cells, chip_index)
+                launched, slots = _launch_counts(cells, chip_index, probe)
                 launched_at = sl.elapsed()  # `hidden_s` runs on this clock
         # the host's f64 subtract and the narrowing to the index's dtype
         # (numpy's cast, IEEE round-to-nearest, bit-identical to XLA's
@@ -1974,9 +2025,12 @@ def pip_join(
                         hcap = min(_next_pow2(nh + 1), fcap)
                     if probe != "scatter" and chip_index.num_convex_cells:
                         ccap = min(_next_pow2(nc + 1), rows)
+                # (`convex`: None under `probe="scatter"`, where the
+                # program does not take the count and nothing reads it)
                 sc.set(
-                    found=nf, heavy=nh, convex=nc, found_cap=fcap,
-                    heavy_cap=hcap, convex_cap=ccap,
+                    found=nf, heavy=nh,
+                    convex=nc if probe != "scatter" else None,
+                    found_cap=fcap, heavy_cap=hcap, convex_cap=ccap,
                 )
         # fault injection may clamp the exactly-sized caps (no-op
         # without an active plan); the escalation loop grows them back
@@ -2026,12 +2080,16 @@ def pip_join(
                 writeback=writeback, probe=probe,
                 convex_cap=c.get("convex_cap", ccap),
             )
-            args = (shifted, cells, chip_index)
+            if slots is None:
+                args = (shifted, cells, chip_index)
+            else:  # every attempt reads the one slot column
+                args, kw["slots"] = (shifted, None, chip_index), slots
             prog = _dispatch.jit_join()
             _register_stages(prog, args, kw, rows)
             with _obs_trace.span(
                 "join.launch", banded=banded, found_cap=kw["found_cap"],
                 heavy_cap=kw["heavy_cap"], convex_cap=kw["convex_cap"],
+                slots="probed" if slots is None else "handed",
             ):
                 res = prog(*args, **kw)
             # waits for the device, then D2H (the banded pair as writable
@@ -2099,19 +2157,24 @@ def pip_join(
                     # exact caps for the narrow join from the band's own
                     # scalar counts (pad rows duplicate row 0, so the
                     # counts upper-bound the real band — still exact; the
-                    # rejoin runs the scatter path, so the convex count
-                    # is unused)
-                    nf2, nh2, _ = _pull_counts(
-                        _launch_counts(alt, chip_index)
+                    # rejoin runs the scatter path, so no convex count is
+                    # taken), and the slot column they were counted on
+                    sp.attrs["probes"] += 1
+                    counts2, slots2 = _launch_counts(
+                        alt, chip_index, "scatter"
                     )
+                    nf2, nh2, _ = _pull_counts(counts2)
                     fcap2 = min(_next_pow2(nf2 + 1), cap)
                     hcap2 = (
                         min(_next_pow2(nh2 + 1), fcap2)
                         if chip_index.num_heavy_cells
                         else None
                     )
-                    args2 = (shifted[src], alt, chip_index)
-                    kw2 = {"heavy_cap": hcap2, "found_cap": fcap2}
+                    args2 = (shifted[src], None, chip_index)
+                    kw2 = {
+                        "heavy_cap": hcap2, "found_cap": fcap2,
+                        "slots": slots2,
+                    }
                     prog = _dispatch.jit_join()
                     _register_stages(prog, args2, kw2, cap)
                     r_alt = np.asarray(prog(*args2, **kw2))[:n_flag]
@@ -2180,10 +2243,13 @@ def pip_join(
     # events inside attach to it, so a trail shows WHICH join they hit
     # (`compacted`, `tier2_compacted`: whether any chunk's program, as
     # first dispatched, compacts before tier 1 — `tier1_compacts` — and
-    # before tier 2 — `tier2_compacted`)
+    # before tier 2 — `tier2_compacted`; `probes`: the programs holding a
+    # hash probe launched for the call — one a chunk, the count sync's or,
+    # with no sync, the join's, and one more for a band re-joined on its
+    # runner-up cells; no escalation adds one)
     with _obs_trace.span(
         "join.pip", n=n, recheck=bool(recheck), probe=probe, compacted=False,
-        tier2_compacted=False,
+        tier2_compacted=False, probes=0,
     ) as sp:
         if batch_size is None or n <= batch_size:
             return run_spanned(raw)

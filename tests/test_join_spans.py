@@ -5,6 +5,9 @@ scope `pip.counts`, and splitting the shift from its put changed no
 answer. Since ISSUE 36 the counts program is launched before the host's
 subtract and pulled after the shifted put: the order of the pieces, what
 `hidden_s` holds, one counts launch a chunk, and every lane's answer.
+Since ISSUE 50 the counts program hands the join the slot column it
+counted on (`slots="handed"` on `join.launch`, `probes` on `join.pip`), and
+counts convex rows only under an adaptive probe.
 Counts, names, orders and answers only: a CPU run states no time."""
 
 import numpy as np
@@ -48,7 +51,7 @@ ATTRIBUTES = {
                     "convex_cap", "hidden_s"},
     "join.shift": {"rows"},
     "join.put_shifted": {"nbytes"},
-    "join.launch": {"banded", "found_cap", "heavy_cap", "convex_cap"},
+    "join.launch": {"banded", "found_cap", "heavy_cap", "convex_cap", "slots"},
     "join.pull": {"nbytes"},
     "join.recheck.band": {"band", "cap", "ties", "mode"},
     "join.recheck.host": {"rows", "n"},
@@ -233,18 +236,21 @@ def test_the_counts_program_is_launched_once_a_chunk(
     launched = []
     real = dispatch.jit_counts()
 
-    def counting(cells, idx):
-        launched.append(cells.shape[0])
-        return real(cells, idx)
+    def counting(cells, idx, **kw):
+        launched.append((cells.shape[0], kw))
+        return real(cells, idx, **kw)
 
     monkeypatch.setattr(dispatch, "jit_counts", lambda: counting)
     monkeypatch.setattr(join_mod, "_register_stages", lambda *a, **k: None)
     _got, spans, _ = _call(points, index, **LANES[lane])
-    _root, kids = _pieces(spans)
+    root, kids = _pieces(spans)
     rows = LANES[lane].get("batch_size", points.shape[0])
-    assert launched == [rows] * (points.shape[0] // rows)
+    assert launched == [(rows, {"probe": "scatter"})] * (points.shape[0] // rows)
     assert len(launched) == sum(k["name"] == "join.counts" for k in kids)
     assert len(launched) == sum(k["name"] == "join.counts_launch" for k in kids)
+    # and its probe is the chunk's only one: every join is handed the slots
+    assert root["probes"] == len(launched)
+    assert {k["slots"] for k in kids if k["name"] == "join.launch"} == {"handed"}
 
 
 def test_with_no_sync_the_shift_still_follows_the_cells_launch(
@@ -262,6 +268,8 @@ def test_with_no_sync_the_shift_still_follows_the_cells_launch(
     assert not set(SYNC_PIECES) & set(names)
     assert names.index("join.shift") == names.index("join.cells") + 1
     by = {k["name"]: k for k in kids}
+    # nobody probed for the join: it does, once
+    assert by["join.launch"]["slots"] == "probed" and _root["probes"] == 1
     assert by["join.cells"]["start_mono"] <= by["join.shift"]["start_mono"]
     np.testing.assert_array_equal(
         got, host_join(points, index.host, CUSTOM, RES))
@@ -301,9 +309,9 @@ ANSWER_LANES = {
 def test_every_lane_answers_as_the_host_oracle_and_joins_the_same_bits(
         points, index, heavy_index, monkeypatch, lane):
     """The order of the sync and the subtract reaches no program: the join
-    is handed the one-expression shift's bits, the cells the counts saw and
-    the caps a blocking count of those cells sizes, and every row is the
-    f64 host oracle's."""
+    is handed the one-expression shift's bits, the slot column of the cells
+    the counts saw (and no cells) and the caps a blocking count of those
+    cells sizes, and every row is the f64 host oracle's."""
     kw = dict(ANSWER_LANES[lane])
     idx = heavy_index if kw.pop("heavy", False) else index
     seen = []
@@ -321,14 +329,19 @@ def test_every_lane_answers_as_the_host_oracle_and_joins_the_same_bits(
     rows = kw.get("batch_size", points.shape[0])
     shift = np.asarray(idx.host.shift, dtype=np.float64)
     assert len(seen) == points.shape[0] // rows
+    probe = kw.get("probe", "scatter")
     for i, (shifted, cells, k) in enumerate(seen):
         chunk = points[i * rows:(i + 1) * rows]
         before = jnp.asarray(chunk - shift, dtype=idx.border.verts.dtype)
         np.testing.assert_array_equal(
             np.asarray(shifted).view(np.uint32),
             np.asarray(before).view(np.uint32))
-        nf, nh, nc = (int(v) for v in np.asarray(
-            dispatch.jit_counts()(cells, idx)))
+        # the slots are those of the chunk's cells, probed again here
+        assert cells is None
+        again = CUSTOM.point_to_cell(jnp.asarray(chunk), RES)
+        counts, u = dispatch.jit_counts()(again, idx, probe=probe)
+        np.testing.assert_array_equal(np.asarray(k["slots"]), np.asarray(u))
+        nf, nh, nc = (int(v) for v in np.asarray(counts))
         fcap = min(join_mod._next_pow2(nf + 1), rows)
         assert k["found_cap"] == fcap
         assert k["heavy_cap"] == (
@@ -343,6 +356,13 @@ def test_every_lane_answers_as_the_host_oracle_and_joins_the_same_bits(
         # recorded after the late pull, from its numbers
         assert (e["found"], e["heavy"], e["convex"]) == (
             c["found"], c["heavy"], c["convex"])
+    # (c) the convex count is taken where a lane reads it, and only there
+    for c in (s for s in spans if s["name"] == "join.counts"):
+        if "probe" in kw:
+            assert c["convex"] is not None and 0 <= c["convex"] <= c["found"]
+            assert (c["convex"] > 0) == bool(idx.num_convex_cells)
+        else:
+            assert c["convex"] is None
 
 
 def test_a_band_with_rows_and_no_alternate_cells_goes_to_the_host(points, index):
@@ -438,11 +458,22 @@ def test_programs_register_once_a_signature_and_counts_has_its_scope(index):
     assert stages.lowerings() == n0  # and nothing is lowered by a call
     tables = stages.tables(["jit__probe_counts"], [rows])
     assert set(tables) == {"jit__probe_counts"}
-    assert set(tables["jit__probe_counts"].values()) == {"pip.counts"}
-    # the probe that answers keeps its own name in the join program
+    # the one probe reads under its own name inside the counts program
+    # (the innermost scope), the sums under `pip.counts`
+    counts = tables["jit__probe_counts"]
+    assert set(counts.values()) == {"pip.counts", "pip.hash_probe"}
+    T, W = index.table_rows.shape
+    probed = [k for k, v in counts.items() if v == "pip.hash_probe"]
+    assert any(f"u32[{T},{W}]" in k for k in probed)  # the table, read here
+    assert any(k.endswith(f"u32[{W},{rows}]") for k in probed)  # its rows
+    assert any(k.startswith("reduce") for k, v in counts.items()
+               if v == "pip.counts")
+    # and the join, handed the slots, holds no table and no gathered rows
     join_table = stages.tables(["jit_pip_join_points"], [rows])
-    assert "pip.hash_probe" in set(join_table["jit_pip_join_points"].values())
-    assert "pip.counts" not in set(join_table["jit_pip_join_points"].values())
+    join = join_table["jit_pip_join_points"]
+    assert not [k for k in join if f"u32[{T}," in k or f"u32[{W},{rows}]" in k]
+    assert "pip.counts" not in set(join.values())
+    assert {"pip.tier1", "pip.writeback"} <= set(join.values())
 
 
 def test_seen_signatures_are_bounded(index, monkeypatch):
@@ -451,7 +482,7 @@ def test_seen_signatures_are_bounded(index, monkeypatch):
     monkeypatch.setattr(join_mod, "_STAGES_SEEN", set())
     monkeypatch.setattr(join_mod, "_STAGES_SEEN_MAX", 4)
     cells = jnp.zeros(8, jnp.int64)
-    prog = dispatch.jit_counts()
+    prog = dispatch.jit_counts()  # (any function: nothing is called here)
     for rows in (1, 2, 3, 4, 4, 3):
         join_mod._register_stages(prog, (cells, index), {}, rows)
     assert len(calls) == 4 and len(join_mod._STAGES_SEEN) == 4

@@ -164,6 +164,61 @@ def test_probe_is_one_u32_row_gather(problem, heavy_problem, edge_cap):
         for ln in join.splitlines()) == 1
 
 
+def _gathers_of(hlo, operand):
+    return [ln for ln in hlo.splitlines()
+            if '"stablehlo.gather"' in ln and f"({operand}," in ln]
+
+
+@pytest.mark.parametrize("probe", ["scatter", "adaptive"])
+@pytest.mark.parametrize("edge_cap", [None, 8], ids=["light", "heavy"])
+def test_the_batch_join_reads_the_table_once_in_the_counts_program(
+        problem, heavy_problem, edge_cap, probe):
+    """`pip_join`'s two programs on the TPU target (ISSUE 50): the counts
+    program gathers the (T, 3B) table once and returns the (N,) int32 slot
+    column beside the (3,) counts; it gathers `cell_convex` over the rows
+    under an adaptive probe only, `cell_heavy` where the index has heavy
+    cells. The join handed that column gathers no table row at all and
+    takes no 64-bit input of the batch's length."""
+    from mosaic_tpu.dispatch import core as dispatch
+
+    h3, index = (problem if edge_cap is None else heavy_problem)[:2]
+    T, W = index.table_rows.shape
+    U = index.cell_convex.shape[0]
+    assert index.num_convex_cells > 0 and index.cell_heavy.shape == (U,)
+    N = 4096
+    pts = jnp.asarray(random_points(N, bbox=BBOX, seed=3), jnp.float32)
+    cells = h3.point_to_cell(pts, 7).astype(jnp.int64)
+    counts = _tpu_lower(dispatch.jit_counts().trace(cells, index, probe=probe))
+    table = f"tensor<{T}x{W}xui32>"
+    assert len(_gathers_of(counts, table)) == 1
+    # per-row class lookups: (U,) int32 columns gathered to (N,)
+    per_row = [ln for ln in _gathers_of(counts, f"tensor<{U}xi32>")
+               if f"-> tensor<{N}xi32>" in ln]
+    assert len(per_row) == (
+        int(probe != "scatter") + int(index.num_heavy_cells > 0))
+    main = next(ln for ln in counts.splitlines() if "@main(" in ln)
+    results = main.split("->", 1)[1]
+    assert results.count("tensor<") == 2
+    assert "tensor<3xi64>" in results and f"tensor<{N}xi32>" in results
+    u = jax.ShapeDtypeStruct((N,), jnp.int32)
+    for kw in ({}, {"found_cap": 1024}, {"edge_eps2": jnp.float32(1e-9)},
+               {"probe": probe}):
+        static = {k: v for k, v in kw.items() if k != "edge_eps2"}
+        dynamic = {k: v for k, v in kw.items() if k == "edge_eps2"}
+        join = _tpu_lower(jax.jit(
+            functools.partial(pip_join_points, **static)
+        ).trace(pts, None, index, slots=u, **dynamic))
+        assert not _gathers_of(join, table), kw
+        assert table not in join  # not even as an argument
+        assert f"tensor<{N}xi64>" not in join
+        assert f"tensor<{N}xui64>" not in join
+        # and the probing call of the same statics holds exactly one
+        probing = _tpu_lower(jax.jit(
+            functools.partial(pip_join_points, **static)
+        ).trace(pts, cells, index, **dynamic))
+        assert len(_gathers_of(probing, table)) == 1, kw
+
+
 @pytest.mark.parametrize("heavy_cap,scatters", [
     (None, False), (4096, False), (2048, True),
 ])
