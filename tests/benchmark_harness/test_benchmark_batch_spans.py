@@ -4,7 +4,15 @@ reads every new span metric as a number, and the stage and idle metrics
 read from four traced calls of `taxi.batch` recorded on the TPU v5e
 (``benchmark/fixtures/taxi_batch_v5e/``, written by
 ``benchmark/tools/record_trace_fixture.py``). A CPU run states counts and
-names, never a device number."""
+names, never a device number.
+
+Two entries appended after those read what they leave unread:
+`probes_per_call.batch` (the field ``probes`` on `join.pip`) and
+`compact_device_ms.batch` (the recheck's scope `pip.compact`). Each resolves
+for the tiny cells, reads hand-made events to the value worked out by hand
+and reads nothing on an empty run; the batch cells are IN their lists, by
+membership, so a later batch cell joins them
+(`check_later_entry`, which `test_benchmark_additive.py` runs on a copy)."""
 
 import gzip
 import json
@@ -18,7 +26,9 @@ from bh_fixtures import REPO, make_copy
 
 from benchmark.harness.spec import Spec
 from test_benchmark_host_batch import add_batch_cells
-from test_benchmark_program_spans import _ctx, _read, _span, _with_trace
+from test_benchmark_program_spans import (
+    _ctx, _read, _span, _with_trace, check_entry,
+)
 
 FIXTURE = os.path.join(REPO, "benchmark", "fixtures", "taxi_batch_v5e")
 SPAN_METRICS = [
@@ -41,6 +51,12 @@ IDLE_METRICS = [
     "idle_host_shift_share.batch", "idle_put_share.batch",
     "idle_sync_share.batch",
 ]
+#: appended after the twenty: name -> (source, unit, the cells that read it)
+LATER_ENTRIES = {
+    "probes_per_call.batch": ("program_counter", "count",
+                              ["taxi.batch", "taxi.batch-exact"]),
+    "compact_device_ms.batch": ("device_trace", "ms", ["taxi.batch-exact"]),
+}
 
 
 @pytest.fixture(scope="module")
@@ -122,6 +138,101 @@ def test_span_cover_share_has_nothing_to_read_without_child_spans(spec):
                  {"root": "join.pip", "pick": "p50"}) is None
 
 
+# ------------------------------------ the probes a call, the compaction
+
+def _pip_calls(probes):
+    """One `join.pip` root a call in the window carrying ``probes``, one
+    more outside it, and another frontend's span with the field."""
+    events = [dict(_span("join.pip", f"j{i}", None, 0.14, 10.0 + i), probes=n)
+              for i, n in enumerate(probes)]
+    events.append(dict(_span("join.pip", "late", None, 0.14, 300.0), probes=9))
+    events.append(dict(_span("serve.batch", "s", None, 0.01, 12.0), probes=7))
+    return events
+
+
+def check_later_entry(spec, name) -> None:
+    """What holds the entry on the real file, and on a copy to which a PR
+    appended a batch cell's name."""
+    source, unit, cells = LATER_ENTRIES[name]
+    entry = next(m for m in spec.benchmark["per_layer"] if m["name"] == name)
+    # membership: a later batch cell appends its name to the list
+    assert set(cells) <= set(entry["workloads"])
+    assert (entry["moves"], entry["layer"], entry["better"]) == (
+        "batch_rows_per_s", "join stages", "lower")
+    assert (entry["source"], entry["unit"]) == (source, unit)
+    check_entry(spec, name)  # an empty run: nothing to read
+    # after the twenty of PR 35 and PR 39's one, whatever else came between
+    names = [m["name"] for m in spec.benchmark["per_layer"]]
+    assert names.index(name) > names.index(UNDER_SYNC)
+
+
+@pytest.mark.parametrize("name", LATER_ENTRIES)
+def test_later_entry_is_the_batch_cells_and_resolves_for_the_tiny_ones(
+        spec, tmp_path, name):
+    cells = LATER_ENTRIES[name][2]
+    check_later_entry(spec, name)
+    # the tiny cells join the lists the real ones stand in
+    root = make_copy(tmp_path)
+    add_batch_cells(root)
+    tiny = Spec(root)
+    for cell in cells:
+        assert name in [m["name"] for m in tiny.per_layer(
+            cell.replace("taxi", "tiny"))]
+    check_entry(tiny, name)
+
+
+#: the exact cell's window: 2 of the pool's 4 batches run `alt_rejoin`'s count
+#: — an odd number of calls, in either parity
+EXACT_ODD, EXACT_OTHER_PARITY = [1, 1, 2, 2, 1, 1, 2], [2, 2, 1, 1, 2, 2, 1]
+
+
+@pytest.mark.parametrize("probes, reads", [
+    ([1] * 7, 1),                    # the join handed its slots: one probe
+    ([2] * 7, 2),                    # the count's probe and the join's
+    (EXACT_ODD, 1),
+    (EXACT_OTHER_PARITY, 1),
+    ([2, 2, 3, 3, 2, 2], 2),         # -exact with two probes a call
+], ids=["one-probe", "two-probes", "exact-odd", "exact-other-parity",
+        "exact-two-probes"])
+def test_probes_per_call_reads_the_call_with_an_empty_band(spec, probes, reads):
+    desc = spec.data("layer_metrics", "probes_per_call.batch")
+    ctx = _ctx(spec, events=_pip_calls(probes))
+    assert _read(spec, desc["reader"], ctx, desc["params"]) == reads
+    # a program whose `join.pip` carries no such field: nothing, never a 0
+    bare = [_span("join.pip", "a", None, 0.14, 10.0)]
+    assert _read(spec, desc["reader"], _ctx(spec, events=bare),
+                 desc["params"]) is None
+
+
+def test_a_median_of_probes_would_follow_the_call_counts_parity(spec):
+    """Why the entry reads the lower quartile: a populated cell band's
+    `alt_rejoin` adds a probe to half of the exact cell's calls."""
+    desc = spec.data("layer_metrics", "probes_per_call.batch")
+    assert desc["params"]["q"] == 0.25
+    median = dict(desc["params"], q=0.5)
+    assert [_read(spec, desc["reader"], _ctx(spec, events=_pip_calls(p)), median)
+            for p in (EXACT_ODD, EXACT_OTHER_PARITY)] == [1, 2]
+
+
+def test_compact_device_ms_reads_the_rechecks_scope_per_traced_call(
+        spec, monkeypatch):
+    desc = spec.data("layer_metrics", "compact_device_ms.batch")
+    assert desc["params"] == {"stage": "pip.compact", "steps": "traced_steps"}
+    # the same reader and parameters as the stream's entry, another moved
+    # metric: no twin (`check_no_twins` holds the real file to it)
+    assert desc["params"] == spec.data(
+        "layer_metrics", "compact_device_ms.stream")["params"]
+    _with_trace(spec, monkeypatch, {"devices": {}})
+    table = {"pip.compact": 0.0394, "pip.tier1": 0.268, "pip.cells": 0.164}
+    ctx = _ctx(spec, counters={"traced_steps": 4}, device_by_stage=table)
+    # the two populated calls' 19.7 ms each, over the four traced calls
+    assert _read(spec, desc["reader"], ctx, desc["params"]) == \
+        pytest.approx(9.85)
+    # no trace (a CPU run, an untraced one): nothing to read
+    _with_trace(spec, monkeypatch, None)
+    assert _read(spec, desc["reader"], ctx, desc["params"]) is None
+
+
 # ------------------------------------------- a tiny traced cell on the CPU
 
 @pytest.fixture()
@@ -152,6 +263,10 @@ def test_traced_batch_cell_reads_every_span_metric_as_a_number(
     # PR 36's counter, appended after the twenty (PR 39): call by call the
     # host work hidden under the count sync holds the shift, so p50 by p50
     assert m[UNDER_SYNC]["value"] >= m["host_shift_p50_ms.batch"]["value"] > 0.0
+    # one program holds a hash probe in a call whose cell band is empty,
+    # under either mode
+    assert m["probes_per_call.batch"]["value"] == 1.0
+    assert "compact_device_ms.batch" not in m  # a device number: no trace here
     # the pieces are inside the call they are pieces of
     pieces = sum(m[n]["value"] for n in SPAN_METRICS[:6])
     assert 0.0 < pieces and 0.0 < m["span_coverage_p50.batch"]["value"] <= 100.0

@@ -147,7 +147,8 @@ def test_the_cells_files_resolve():
     reported = {m["name"] for m in spec.end_to_end(REAL)}
     assert reported == {"batch_rows_per_s", "setup_s"}
     mine = {m["name"] for m in spec.per_layer(REAL)}
-    assert mine == set(NEW_METRICS) | set(SHARED_METRICS)
+    # at least these: a later PR may append an entry that lists the cell
+    assert mine >= set(NEW_METRICS) | set(SHARED_METRICS)
     assert set(cell["check"]) >= {"sample_parcels", "touch_area_m2",
                                   "max_area_error", "why"}
 

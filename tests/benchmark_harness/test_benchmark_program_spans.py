@@ -74,7 +74,10 @@ def check_entry(spec, name):
     additivity test runs it against a copy with one more)."""
     entry = next(m for m in spec.benchmark["per_layer"] if m["name"] == name)
     cells = {w["name"] for w in spec.benchmark["workloads"]}
-    assert entry["workloads"] and set(entry["workloads"]) <= cells
+    # an entry lists the cells that read it, or carries no ``workloads`` key
+    # and is read by every cell that reports what it moves (`Spec.per_layer`)
+    listed = entry.get("workloads", cells)
+    assert listed and set(listed) <= cells
     desc = spec.data("layer_metrics", name)
     assert desc["what"] and set(desc) == {"what", "reader", "params"}
     reader = spec.module("readers", desc["reader"])

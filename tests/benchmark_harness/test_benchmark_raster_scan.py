@@ -411,12 +411,13 @@ def test_fold_hbm_share_prices_the_lane_that_folded(monkeypatch):
     assert "fold_lane=fold" in said and "values_dtype=stage_dt.name" in said
 
 
-def test_the_real_cell_lists_every_zonal_metric_and_no_other_list_grew():
+def test_the_real_cell_lists_every_zonal_metric_and_the_shared_ones():
     spec = Spec(REPO)
     assert [m["name"] for m in spec.end_to_end("modis-zonal.scan")] == \
         ["setup_s", "batch_rows_per_s"]
     names = {m["name"] for m in spec.per_layer("modis-zonal.scan")}
-    assert names == set(ZONAL_METRICS) | set(SHARED_METRICS)
+    # at least these: a later PR may append an entry that lists the cell
+    assert names >= set(ZONAL_METRICS) | set(SHARED_METRICS)
     cfg = spec.config("modis-zonal")
     assert cfg["scene"]["height"] == cfg["scene"]["width"] == 2400
     assert cfg["batch_rows_per_chip"] == 2400 * 2400

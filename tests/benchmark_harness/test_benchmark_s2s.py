@@ -5,8 +5,18 @@ reference, traffic kind, generator and metric; 192 vessels a window in a
 box of a third of a degree, 2 tables of 3 windows). The cell's files
 resolve, the sound run reads correct, both controls and a broken path do
 not, a program without the distance join is refused at once, and the
-metric this cell brought reads a hand-made span and returns None where
-there is nothing to read."""
+metrics this cell brought — the predicate's share of the HBM roofline, the
+call and its host pieces grouped by table, the candidate rows a track, the
+predicate's device time — each resolve for the tiny cell, read a hand-made
+`proximity.*` span tree to the value worked out by hand and return None
+where there is nothing to read. The tiny cell traced on the CPU reads every
+host one as a number.
+
+What holds the real file's entries is membership, never equality: the cell
+reads AT LEAST these names and stands IN these lists, so a later PR may
+append an entry for the cell, or a second distance-join cell to the lists
+(`check_s2s_entries`, which `test_benchmark_additive.py` runs on a copy
+with both appended)."""
 
 import json
 import os
@@ -19,14 +29,33 @@ from bh_fixtures import REPO, _snapshot, _write
 
 from benchmark.harness.run_cell import run_cell
 from benchmark.harness.spec import Spec
-from test_benchmark_program_spans import _ctx, check_entry
+from test_benchmark_program_spans import _ctx, _span, _with_trace, check_entry
 from test_benchmark_shared_entries import check_no_twins
 
 CELL, REAL = "tiny.s2s", "ais-s2s.join"
-NEW_METRICS = ["segpair_hbm_share.s2s"]
+#: the cell's own entries: name -> (layer, unit, source)
+NEW_METRICS = {
+    "segpair_hbm_share.s2s": ("kernels", "%", "device_trace"),
+    "call_p50_ms.s2s": ("frontends", "ms", "program_span"),
+    "cover_ms_per_call.s2s": ("proximity join", "ms", "program_span"),
+    "candidates_ms_per_call.s2s": ("dispatch core", "ms", "program_span"),
+    "launch_pull_ms_per_call.s2s": ("dispatch core", "ms", "program_span"),
+    "glue_ms_per_call.s2s": ("proximity join", "ms", "program_span"),
+    "candidate_rows_per_track.s2s": ("proximity join", "count",
+                                     "program_counter"),
+    "segpairs_device_ms_per_call.s2s": ("proximity join", "ms",
+                                        "device_trace"),
+}
+#: the four pieces of a call the host spends, and what they are pieces of
+HOST_PIECES = ["cover_ms_per_call.s2s", "candidates_ms_per_call.s2s",
+               "launch_pull_ms_per_call.s2s", "glue_ms_per_call.s2s"]
+#: the lists that were there and the cell joined
 HOST_FED_METRICS = ["device_idle.batch", "compiles_in_window.batch",
                     "pool_build_s.batch", "call_warmup_s.batch",
-                    "call_max_ms.batch", "device_busy_ms_per_call.overlay"]
+                    "call_max_ms.batch", "device_busy_ms_per_call.overlay",
+                    "candidates_device_ms_per_call.overlay"]
+#: the two that list no cells: every cell reads them
+START_UP_METRICS = ["index_build_s", "warmup_s"]
 #: a small fleet in a small box: every kind of vessel, dense enough to meet
 FLEET = {
     "vessels": 192, "box": [-90.5, 28.0, -90.2, 28.2],
@@ -161,8 +190,7 @@ def test_the_cells_files_resolve():
         assert spec.module(registry, name)
     assert [m["name"] for m in spec.end_to_end(REAL)] == \
         ["setup_s", "batch_rows_per_s"]
-    mine = {m["name"] for m in spec.per_layer(REAL)}
-    assert mine == set(NEW_METRICS + HOST_FED_METRICS)
+    check_s2s_entries(spec)
     limits = cell["check"]
     # every window of each table's first timed answer is held to the reference
     assert limits["sample_windows"] == mix["windows_per_table"]
@@ -258,29 +286,198 @@ def test_a_program_without_the_distance_join_is_refused_at_once(
 
 # -------------------------------------------------------------- the metrics
 
+def check_new_entry(spec, name) -> None:
+    entry = next(m for m in spec.benchmark["per_layer"] if m["name"] == name)
+    # membership: a second distance-join cell joins the list behind this one
+    assert REAL in entry["workloads"]
+    assert entry["moves"] == "batch_rows_per_s"
+    assert (entry["layer"], entry["unit"], entry["source"]) == NEW_METRICS[name]
+    check_entry(spec, name)
+
+
+def check_host_fed_entry_lists_the_cell(spec, name) -> None:
+    entry = next(m for m in spec.benchmark["per_layer"] if m["name"] == name)
+    # membership: the next host-fed cell joins the list behind this one
+    assert REAL in entry["workloads"] and len(entry["workloads"]) > 1
+    assert entry["moves"] in ("batch_rows_per_s", "setup_s")
+    check_entry(spec, name)
+
+
+def check_s2s_entries(spec) -> None:
+    """What the real file's entries for this cell are held to, on the real
+    file and on a copy to which a PR appended: the cell reads at least its
+    own entries, the shared ones it joined and the start-up two."""
+    mine = {m["name"] for m in spec.per_layer(REAL)}
+    assert mine >= (set(NEW_METRICS) | set(HOST_FED_METRICS)
+                    | set(START_UP_METRICS))
+    for name in NEW_METRICS:
+        check_new_entry(spec, name)
+    for name in HOST_FED_METRICS:
+        check_host_fed_entry_lists_the_cell(spec, name)
+
+
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_new_metric_is_this_cells_and_reads_nothing_on_an_empty_run(name):
     spec = Spec(REPO)
-    entry = next(m for m in spec.benchmark["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == [REAL] and entry["layer"] == "kernels"
-    assert entry["moves"] == "batch_rows_per_s" and entry["unit"] == "%"
-    check_entry(spec, name)
-    # nor on a run of the overlay cell: its calls hold no such counter
+    check_new_entry(spec, name)
+    # nor on a run of the overlay cell: its calls hold no such span or counter
     desc = spec.data("layer_metrics", name)
-    ctx = _ctx(spec, counters={"traced_steps": 2},
+    overlay = [dict(_span("overlay.call", "o", None, 0.5, 10.0),
+                    right_rows=384, raw_candidates=10, clip_rows=3),
+               _span("overlay.glue", "o1", "o", 0.1, 9.9)]
+    ctx = _ctx(spec, events=overlay, counters={"traced_steps": 2},
                series={"traced_calls": [{"clip_rows": 10, "vpad": 8,
                                          "acc": "float32"}]})
     assert spec.module("readers", desc["reader"]).read(
         ctx, desc["params"]) is None
 
 
+@pytest.fixture(scope="module")
+def tiny_spec(tmp_path_factory):
+    return Spec(make_copy(tmp_path_factory.mktemp("s2s")))
+
+
+@pytest.mark.parametrize(
+    "name", list(NEW_METRICS) + HOST_FED_METRICS + START_UP_METRICS)
+def test_metric_resolves_for_the_tiny_cell(tiny_spec, name):
+    assert name in [m["name"] for m in tiny_spec.per_layer(CELL)]
+    check_entry(tiny_spec, name)
+
+
 @pytest.mark.parametrize("name", HOST_FED_METRICS)
 def test_host_fed_entries_list_the_cell(name):
+    check_host_fed_entry_lists_the_cell(Spec(REPO), name)
+
+
+@pytest.mark.parametrize("name", START_UP_METRICS)
+def test_the_cell_reads_the_start_up_entries(name):
     spec = Spec(REPO)
-    entry = next(m for m in spec.benchmark["per_layer"] if m["name"] == name)
-    assert entry["workloads"][-1] == REAL
-    assert entry["moves"] in ("batch_rows_per_s", "setup_s")
+    entry = next(m for m in spec.per_layer(REAL) if m["name"] == name)
+    assert entry["moves"] == "setup_s" and "workloads" not in entry
     check_entry(spec, name)
+
+
+def _proximity_calls():
+    """Five calls in the window over a pool of two tables of unlike
+    ``cover_rows`` — an ODD number: table A three times, table B twice —
+    and one call outside it. Per call the children `dwithin_join` records:
+    one cover, count, pull, glue and host band, an emit and a launch a
+    slice (three slices on A, four on B); an overlay call beside them."""
+    events = []
+    calls = [  # (cover_rows, raw_candidates, ts, call, cover, count, pull, glue)
+        (1000, 3_000_000, 10.0, 0.60, 0.20, 0.017, 0.040, 0.10),
+        (1200, 3_300_000, 20.0, 0.80, 0.30, 0.019, 0.060, 0.15),
+        (1000, 3_000_000, 30.0, 0.70, 0.27, 0.017, 0.050, 0.125),
+        (1200, 3_300_000, 40.0, 0.90, 0.34, 0.019, 0.070, 0.13),
+        (1000, 3_000_000, 50.0, 0.64, 0.22, 0.017, 0.045, 0.11),
+        (1000, 9_000_000, 300.0, 9.0, 5.0, 1.0, 1.0, 1.0),  # after the window
+    ]
+    for c, (rows, cand, ts, call, cover, count, pull, glue) in enumerate(calls):
+        root = f"c{c}"
+        events.append(dict(_span("proximity.call", root, None, call, ts),
+                           cover_rows=rows, tracks=32768, raw_candidates=cand))
+        events.append(_span("proximity.cover", root + ".v", root, cover, ts - 0.5))
+        events.append(_span("proximity.count", root + ".n", root, count, ts - 0.4))
+        for k in range(3 if rows == 1000 else 4):
+            events.append(_span("proximity.emit", f"{root}.e{k}", root, 0.001,
+                                ts - 0.35))
+            events.append(_span("proximity.launch", f"{root}.l{k}", root, 0.001,
+                                ts - 0.34))
+        events.append(_span("proximity.pull", root + ".p", root, pull, ts - 0.2))
+        events.append(_span("proximity.glue", root + ".g", root, glue, ts - 0.1))
+        events.append(_span("proximity.host_band", root + ".b", root, 0.007, ts))
+    events.append(dict(_span("overlay.call", "o", None, 0.5, 15.0),
+                       right_rows=384, raw_candidates=77))
+    events.append(_span("overlay.glue", "o1", "o", 0.3, 14.9))
+    return events
+
+
+#: worked out by hand from `_proximity_calls`: p50 by nearest rank inside
+#: each table (A's middle of three, B's lower of two), the mean of the two
+HAND_MADE = {
+    "call_p50_ms.s2s": (0.64 + 0.80) / 2 * 1000,
+    "cover_ms_per_call.s2s": (0.22 + 0.30) / 2 * 1000,
+    "candidates_ms_per_call.s2s": ((0.017 + 3 * 0.001)
+                                   + (0.019 + 4 * 0.001)) / 2 * 1000,
+    "launch_pull_ms_per_call.s2s": ((0.045 + 3 * 0.001)
+                                    + (0.060 + 4 * 0.001)) / 2 * 1000,
+    "glue_ms_per_call.s2s": (0.11 + 0.13) / 2 * 1000,
+    "candidate_rows_per_track.s2s": (3 * 3_000_000 + 2 * 3_300_000)
+                                    / (5 * 32768),
+    # (0.130 + 0.080 + 0.002) s of the three scopes over two traced calls
+    "segpairs_device_ms_per_call.s2s": 106.0,
+    # the overlay's two scopes in this cell's calls: (0.146 + 0.030) s / 2
+    "candidates_device_ms_per_call.overlay": 88.0,
+}
+STAGES = {"proximity.gather": 0.130, "proximity.segpairs": 0.080,
+          "proximity.fold": 0.002, "overlay.emit": 0.146,
+          "overlay.spans": 0.030, "unscoped": 0.004}
+
+
+@pytest.mark.parametrize("name", HAND_MADE)
+def test_metric_reads_a_hand_made_span_tree(monkeypatch, name):
+    spec = Spec(REPO)
+    desc = spec.data("layer_metrics", name)
+    reader = spec.module("readers", desc["reader"])
+    _with_trace(spec, monkeypatch, {"devices": {}})
+    ctx = _ctx(spec, events=_proximity_calls(), counters={"traced_steps": 2},
+               device_by_stage=dict(STAGES))
+    assert reader.read(ctx, desc["params"]) == pytest.approx(HAND_MADE[name])
+    if desc["reader"] != "span_child_by_group":
+        return
+    # each table's p50 is said once a metric, beside its count of calls: an
+    # odd number of calls, and the reading is neither table's alone
+    said = dict(ctx.said)["span_by_group"]
+    assert said["by"] == "cover_rows"
+    assert said["1000"].endswith("/3") and said["1200"].endswith("/2")
+
+
+def test_the_four_host_pieces_lie_inside_the_hand_made_call():
+    spec = Spec(REPO)
+    ctx = _ctx(spec, events=_proximity_calls())
+
+    def read(name):
+        desc = spec.data("layer_metrics", name)
+        return spec.module("readers", desc["reader"]).read(ctx, desc["params"])
+
+    pieces = sum(read(n) for n in HOST_PIECES)
+    assert pieces == pytest.approx(260.0 + 21.5 + 56.0 + 120.0)
+    # 457.5 of 720 ms: the band's 7 and what no child span holds are the rest
+    assert 0.6 < pieces / read("call_p50_ms.s2s") < 0.65
+
+
+def test_traced_tiny_cell_reads_every_host_metric_as_a_number(
+        root, capfd, monkeypatch):
+    # the profiler starts with the window's first call here, not its second:
+    # on a loaded machine one interpreted call can outlast the whole window,
+    # and a traced run must find its trace
+    kind = Spec(root).module("traffic_kinds", "dwithin_join_loop")
+    monkeypatch.setattr(kind, "TRACE_FROM_CALL", 0)
+    line = run_cell(root, CELL, 4_000_000_517, 0.5, True,
+                    t_start=time.perf_counter(), rehearsal=True)
+    assert line["correct"] is True and line["failed"] == 0
+    m = line["metrics"]
+    host = [n for n, (_l, _u, source) in NEW_METRICS.items()
+            if source != "device_trace"]
+    assert len(host) == 6
+    # (`call_max_ms.batch` reads the unprofiled calls' walls: there may be none)
+    for name in host + ["index_build_s", "warmup_s", "pool_build_s.batch",
+                        "call_warmup_s.batch"]:
+        assert name in m and m[name]["value"] > 0.0, name
+    # the pieces are inside the call they are pieces of
+    assert sum(m[n]["value"] for n in HOST_PIECES) < m["call_p50_ms.s2s"]["value"]
+    # the CPU has no device trace: the three stage metrics read nothing
+    assert not {"segpair_hbm_share.s2s", "segpairs_device_ms_per_call.s2s",
+                "candidates_device_ms_per_call.overlay",
+                "device_busy_ms_per_call.overlay", "device_idle.batch"} & set(m)
+    out = capfd.readouterr().out
+    # each grouped metric says its tables' p50s once, by cover rows (two
+    # tables of unlike cover rows: two groups once both were called)
+    by_group = [ln for ln in out.splitlines()
+                if ln.startswith("[bench] span_by_group: ")]
+    assert len(by_group) == 5
+    assert all("by=cover_rows" in ln and 1 <= ln.count("/") <= 2
+               for ln in by_group)
 
 
 def test_segpair_hbm_share_arithmetic(monkeypatch):

@@ -1,17 +1,22 @@
-"""The rule `per_layer` follows since PR 47: ONE entry per (reader,
-parameters, moved end-to-end metric), with every cell that reads it in its
-``workloads`` — as `index_build_s` and `warmup_s` always were. A later PR
-that adds a cell appends the cell's name to the lists that are there and
-mints no twin (the six `.knn-buildings` names, `compiles_in_window.*` and
-`device_idle.*` by cell, `tessellate_s.overlay`, `landmark_pool_build_s.knn`
-were such twins and are gone); the limit of 128 stands, and the room the
-merge made is held here.
+"""The rule `per_layer` follows: ONE entry per (reader, parameters, moved
+end-to-end metric), with every cell that reads it in its ``workloads``. A
+later PR that adds a cell appends the cell's name to the lists that are
+there and mints no twin (the six `.knn-buildings` names,
+`compiles_in_window.*` and `device_idle.*` by cell, `tessellate_s.overlay`,
+`landmark_pool_build_s.knn` were such twins and are gone). The limit on the
+list is the contract's 128 and nothing under it: what keeps it from filling
+with copies is the rule above, not a count (``PERF.md`` says how many places
+are left and who spent which).
 
-Every (shared entry, cell in its ``workloads``) pair is a case: the entry
-resolves for that cell, its reader has nothing to read on an empty run, and
-where the repo has a recording of that cell on the chip
-(``benchmark/fixtures/``) the reader reads the number the recording's own
-run read."""
+The two start-up entries carry NO ``workloads`` key: by the contract, and by
+`Spec.per_layer`, such an entry is read by every cell that reports what it
+moves, and every cell reports `setup_s`. So a PR that adds a cell has no
+list to join for them.
+
+Every (shared entry, cell that reads it) pair is a case: the entry resolves
+for that cell, its reader has nothing to read on an empty run, and where the
+repo has a recording of that cell on the chip (``benchmark/fixtures/``) the
+reader reads the number the recording's own run read."""
 
 import gzip
 import json
@@ -26,8 +31,8 @@ from benchmark.harness.spec import Spec
 from test_benchmark_program_spans import _ctx, _with_trace, check_entry
 
 LIMIT = 128  # the contract's, `test_benchmark_contract.py`
-#: the places PR 47 left for the next deployment's metrics
-ROOM = 16
+#: the entries every cell of the benchmark reads
+START_UP = ("index_build_s", "warmup_s")
 #: the cells the repo holds a recording of, made on the TPU v5e by
 #: `benchmark/tools/record_trace_fixture.py`
 FIXTURES = {"taxi.batch": "taxi_batch_v5e", "taxi.serve": "taxi_serve_v5e",
@@ -121,11 +126,35 @@ def test_a_twin_is_caught(spec, tmp_path):
         check_no_twins(Spec(root))
 
 
-def test_the_merge_left_room_and_the_limit_stands(spec):
-    assert len(spec.benchmark["per_layer"]) <= LIMIT - ROOM
+def check_limit(spec) -> None:
+    assert len(spec.benchmark["per_layer"]) <= LIMIT
+
+
+def test_the_list_is_held_to_the_contracts_limit_and_to_nothing_under_it(spec):
+    check_limit(spec)
     with open(os.path.join(REPO, "tests", "benchmark_harness",
                            "test_benchmark_contract.py"), encoding="utf-8") as f:
         assert f'len(bench["per_layer"]) <= {LIMIT}' in f.read()
+
+
+def check_start_up_entries(spec) -> None:
+    """`index_build_s` and `warmup_s` name no cells: every cell that reports
+    `setup_s` reads both, and `setup_s` names none either, so every cell —
+    whichever PR appended it — does."""
+    by = {m["name"]: m for m in spec.benchmark["per_layer"]}
+    moved = next(m for m in spec.benchmark["end_to_end"]
+                 if m["name"] == "setup_s")
+    assert "workloads" not in moved
+    for name in START_UP:
+        assert "workloads" not in by[name], (
+            f"{name} lists no cells: every cell reads it")
+        assert by[name]["moves"] == "setup_s"
+    for w in spec.benchmark["workloads"]:
+        assert set(START_UP) <= {m["name"] for m in spec.per_layer(w["name"])}
+
+
+def test_the_start_up_entries_list_no_cells_and_every_cell_reads_them(spec):
+    check_start_up_entries(spec)
 
 
 def test_no_twin_name_and_no_retired_name_is_left(spec):
@@ -202,10 +231,14 @@ def test_tessellate_and_pool_build_list_every_cell_that_has_the_span(spec):
 # ------------------------------- every (shared entry, cell) pair is a case
 
 def _pairs():
+    """(entry, cell) for every entry more than one cell reads: the cells it
+    lists, or every cell where it lists none."""
     with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        entries = json.load(f)["per_layer"]
-    return [(m["name"], cell) for m in entries
-            if len(m["workloads"]) > 1 for cell in m["workloads"]]
+        bench = json.load(f)
+    cells = [w["name"] for w in bench["workloads"]]
+    return [(m["name"], cell) for m in bench["per_layer"]
+            if len(m.get("workloads", cells)) > 1
+            for cell in m.get("workloads", cells)]
 
 
 @pytest.fixture(scope="module")
