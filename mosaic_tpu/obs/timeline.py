@@ -88,6 +88,10 @@ CLASS_RULES: tuple = (
     ("host_callback", "span.stream.snapshot"),
     ("host_callback", "span.raster.snapshot"),
     ("host_callback", "span.stream.admit"),
+    #    (a durable run's ring hash — the ring's pull and its SHA-256 —
+    #    and a resume's load: list, re-hash, ``np.load``)
+    ("host_callback", "span.stream.fingerprint"),
+    ("host_callback", "span.stream.resume.load"),
     ("host_callback", "span.serve.admit"),
     ("host_callback", "span.stream.pipeline.flush"),
     ("host_callback", "stream_stage.pipeline_flush"),
@@ -137,6 +141,7 @@ CLASS_RULES: tuple = (
 #: (classifying one would attribute the whole window to a single class)
 CONTAINER_KEYS = frozenset({
     "span.stream.durable_run",
+    "span.stream.resume",
     "span.stream.run",
     "span.serve.request",
     "span.serve.batch",

@@ -110,6 +110,19 @@ def list_snapshots(run_dir: str) -> list[int]:
     return sorted(steps)
 
 
+def newest_meta(run_dir: str) -> dict:
+    """The ``meta`` of the newest sidecar that parses, or ``{}`` — no
+    hash is checked: for what every snapshot of a run shares (its trace
+    context), read before :func:`load_latest` is spanned."""
+    for step in reversed(list_snapshots(run_dir)):
+        try:
+            with open(_snap_paths(run_dir, step)[1]) as f:
+                return dict(json.load(f).get("meta", {}))
+        except (OSError, ValueError, AttributeError):
+            continue
+    return {}
+
+
 def load_latest(
     run_dir: str,
 ) -> tuple[int, dict[str, np.ndarray], dict] | None:

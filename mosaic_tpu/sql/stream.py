@@ -135,6 +135,16 @@ def stream_cell_dtype(index: ChipIndex, index_system, resolution: int):
     return jnp.float32 if share <= CELL_F32_MAX_ULP_SHARE else jnp.float64
 
 
+def _ring_fingerprint(ring) -> "tuple[np.ndarray, str]":
+    """The ring's host twin and its SHA-256 (`runtime/checkpoint.py`
+    ``fingerprint``): every ring hash of this module, under one
+    ``stream.fingerprint`` span — the pull of the whole ring to the host
+    and the hash are seconds at a deployment's ring size."""
+    with _trace.span("stream.fingerprint", nbytes=int(ring.nbytes)):
+        ring_np = np.asarray(ring)
+        return ring_np, _checkpoint.fingerprint(ring_np)
+
+
 def ring_from_host(batches) -> jax.Array:
     """Stack host point batches into one (K, B, 2) f64 device-resident
     ring. Blocks until the ring is staged (staging is not loop time).
@@ -773,7 +783,7 @@ class StreamJoin:
             _telemetry.record("stream_quarantine", **report.metrics())
         # keyed by ring fingerprint: run_durable only surfaces this
         # report for the ring THIS admission staged, never a stale one
-        self._last_quarantine = (_checkpoint.fingerprint(ring), report)
+        self._last_quarantine = (_ring_fingerprint(ring)[1], report)
         return ring, report
 
     def _find_park(self, raws, bounds) -> np.ndarray:
@@ -834,13 +844,22 @@ class StreamJoin:
         )
         try:
             acc0 = jnp.zeros(3, jnp.int32)
+            devices = 1 if self.mesh is None else self.mesh.size
             for nb in sizes:
-                a, _c, _o = self._seg_loop(
-                    ring, self.index, jnp.int32(int(start_step)),
-                    acc0, cells, nb=nb, collect=collect,
+                args = (
+                    ring, self.index, jnp.int32(int(start_step)), acc0,
+                    cells,
                 )
+                a, _c, _o = self._seg_loop(*args, nb=nb, collect=collect)
                 jax.block_until_ready(a)
                 self._seg_warm.add((key0, nb))
+                # so that a traced durable run's device ops read by stage
+                # (shapes only, as `_register_stages`; no lowering here)
+                _stages.register(
+                    self._seg_loop, _stages.shapes_of(args),
+                    {"nb": nb, "collect": collect},
+                    rows=int(ring.shape[1]) // devices,
+                )
         finally:
             span.set(
                 backend_compiles=_dispatch.backend_compiles() - c0
@@ -912,6 +931,9 @@ class StreamJoin:
         one child per segment and snapshot; the span's context is
         persisted in every snapshot sidecar, so a later :meth:`resume`
         JOINS the interrupted run's trace instead of starting a new one.
+        The ring's pull and hash is a ``stream.fingerprint`` span
+        (``nbytes``); each ``stream.snapshot`` span carries ``nbytes``
+        (the carry's) and ``write_s`` (the store's share of it).
         """
         return self._run_segments(
             ring, int(n_batches), run_dir=run_dir,
@@ -945,8 +967,37 @@ class StreamJoin:
         ["resumed_from"]`` records the ring cursor resumed at. With
         ``collect=True``, ``outs`` covers only the batches run by THIS
         call (earlier rows are already folded into the snapshot).
+
+        Tracing: the whole call is one ``stream.resume`` span with the
+        children ``stream.resume.load`` (list, re-hash, ``np.load``) and
+        ``stream.fingerprint`` (the ring is pulled and hashed ONCE: the
+        run takes the twin), and ``ready_s``, its seconds up to the
+        launch of the first resumed segment. The resumed run's
+        ``stream.durable_run`` root carries ``replayed_batches``: the
+        newest snapshot boundary on disk, valid or not, less
+        ``resumed_from`` — 0 unless a newer snapshot was skipped.
         """
-        loaded = _checkpoint.load_latest(run_dir)
+        # every snapshot of a run carries its root's trace context: the
+        # span joins the interrupted run's trace before anything is loaded
+        joined = _trace.SpanContext.from_dict(
+            _checkpoint.newest_meta(run_dir).get("trace")
+        )
+        with _trace.span(
+            "stream.resume", parent=joined, run_dir=run_dir
+        ) as entry:
+            return self._resume(
+                entry, run_dir, ring, collect=collect,
+                watchdog_default_s=watchdog_default_s,
+                retry_policy=retry_policy, pipeline=pipeline, window=window,
+            )
+
+    def _resume(
+        self, entry, run_dir, ring, *, collect, watchdog_default_s,
+        retry_policy, pipeline, window,
+    ) -> StreamResult:
+        """:meth:`resume` under its ``stream.resume`` span, ``entry``."""
+        with _trace.span("stream.resume.load"):
+            loaded = _checkpoint.load_latest(run_dir)
         if loaded is None:
             raise FileNotFoundError(
                 f"no valid snapshot under {run_dir!r} — nothing to resume"
@@ -965,8 +1016,10 @@ class StreamJoin:
                 f"snapshot ring shape ({meta.get('ring_k')}, "
                 f"{meta.get('batch')}) != resumed ring ({k}, {batch})"
             )
+        # the ring is hashed ONCE a resume: the run below takes the twin
+        ring_twin = _ring_fingerprint(ring)
         want_fp = meta.get("ring_sha256")
-        if want_fp and want_fp != _checkpoint.fingerprint(ring):
+        if want_fp and want_fp != ring_twin[1]:
             raise ValueError(
                 "snapshot ring fingerprint mismatch — this is not the "
                 "ring the interrupted run was folding"
@@ -1005,14 +1058,21 @@ class StreamJoin:
             watchdog_default_s=watchdog_default_s,
             retry_policy=retry_policy,
             trace_parent=_trace.SpanContext.from_dict(meta.get("trace")),
-            pipeline=pipeline, window=window,
+            pipeline=pipeline, window=window, ring_twin=ring_twin,
+            entry_span=entry,
+            # the newest boundary the interrupted run left on disk, valid
+            # or not, less where this run starts: folded there, and again
+            replayed_batches=max(
+                _checkpoint.list_snapshots(run_dir), default=int(step)
+            ) - int(step),
         )
 
     def _run_segments(
         self, ring, n_batches, *, run_dir, snapshot_every, start_step,
         acc0, cells0, collect, resumed_from, extra_arrays,
         watchdog_default_s, retry_policy, trace_parent=None,
-        pipeline=None, window=None,
+        pipeline=None, window=None, ring_twin=None, entry_span=None,
+        replayed_batches=None,
     ) -> StreamResult:
         k, batch = int(ring.shape[0]), int(ring.shape[1])
         self._check_batch(batch)
@@ -1025,8 +1085,6 @@ class StreamJoin:
             defaults={"stream_pipeline": False, "stream_window": None},
         )
         pipeline, window = knobs["stream_pipeline"], knobs["stream_window"]
-        ring_np = np.asarray(ring)  # host twin: fingerprint + fallback
-        ring_fp = _checkpoint.fingerprint(ring_np)
         # one root span per durable run; a resume parents to the
         # INTERRUPTED run's root (persisted in the snapshot sidecars),
         # so kill + resume reads as one trace end to end
@@ -1038,12 +1096,18 @@ class StreamJoin:
             snapshot_every=int(snapshot_every),
             pipelined=bool(pipeline),
         )
+        if replayed_batches is not None:
+            root.set(replayed_batches=int(replayed_batches))
         runner = (
             self._run_segments_pipelined if pipeline
             else self._run_segments_traced
         )
         kw = {"window": window} if pipeline else {}
         try:
+            # host twin (the degradation fallback reads it) and
+            # fingerprint: `resume` has hashed the ring already and hands
+            # both over
+            ring_np, ring_fp = ring_twin or _ring_fingerprint(ring)
             return runner(
                 ring, n_batches, run_dir=run_dir,
                 snapshot_every=snapshot_every, start_step=start_step,
@@ -1052,7 +1116,7 @@ class StreamJoin:
                 watchdog_default_s=watchdog_default_s,
                 retry_policy=retry_policy, root=root,
                 ring_np=ring_np, ring_fp=ring_fp, k=k, batch=batch,
-                **kw,
+                entry_span=entry_span, **kw,
             )
         except BaseException as e:  # noqa: BLE001 — stamped, re-raised
             root.set(error=type(e).__name__)
@@ -1064,7 +1128,7 @@ class StreamJoin:
         self, ring, n_batches, *, run_dir, snapshot_every, start_step,
         acc0, cells0, collect, resumed_from, extra_arrays,
         watchdog_default_s, retry_policy, root, ring_np, ring_fp,
-        k, batch,
+        k, batch, entry_span=None,
     ) -> StreamResult:
         acc = (
             np.zeros(3, np.int64) if acc0 is None
@@ -1097,6 +1161,7 @@ class StreamJoin:
             ring, cells, start_step, int(n_batches),
             int(snapshot_every), collect,
         )
+        _stamp_ready(entry_span)
         step = start_step
         t0 = time.perf_counter()
         while step < n_batches:
@@ -1111,18 +1176,11 @@ class StreamJoin:
                 outs_list.append(o_np)
             step += seg_n
 
-            def snap():
-                payload = self._snapshot_payload(
-                    acc, cells, extra_arrays
-                )
-                return _checkpoint.save_snapshot(
-                    run_dir, step, payload, meta
-                )
-
-            with _trace.span("stream.snapshot", step=step):
+            with _trace.span("stream.snapshot", step=step) as ssp:
                 try:
                     _dispatch.guarded_call(
-                        "stream.snapshot", snap,
+                        "stream.snapshot", self._save_snapshot, ssp,
+                        run_dir, step, acc, cells, extra_arrays, meta,
                         default_s=watchdog_default_s,
                         policy=retry_policy,
                     )
@@ -1227,6 +1285,22 @@ class StreamJoin:
                     cells = self.assign(ring[(step + seg_n) % k])
                 return acc, cells, o_np, True
 
+    def _save_snapshot(
+        self, span, run_dir, step, acc, cells, extra_arrays, meta
+    ) -> str:
+        """Pull the carry and persist it (`runtime/checkpoint.py`); the
+        ``stream.snapshot`` span around the call gains ``nbytes`` (the
+        carry's) and ``write_s`` (the store's share: the pull is the
+        rest). Both segment loops write through here."""
+        payload = self._snapshot_payload(acc, cells, extra_arrays)
+        t0 = time.perf_counter()
+        path = _checkpoint.save_snapshot(run_dir, step, payload, meta)
+        span.set(
+            nbytes=sum(int(a.nbytes) for a in payload.values()),
+            write_s=round(time.perf_counter() - t0, 6),
+        )
+        return path
+
     def _snapshot_payload(self, acc, cells, extra_arrays) -> dict:
         """The snapshot carry arrays, every device pull under a
         ``dispatch.transfer.d2h`` span (``cells`` AND the ``x_<key>``
@@ -1253,7 +1327,7 @@ class StreamJoin:
         self, ring, n_batches, *, run_dir, snapshot_every, start_step,
         acc0, cells0, collect, resumed_from, extra_arrays,
         watchdog_default_s, retry_policy, root, ring_np, ring_fp,
-        k, batch, window=None,
+        k, batch, window=None, entry_span=None,
     ) -> StreamResult:
         """The asynchronous pipelined durable loop.
 
@@ -1299,6 +1373,7 @@ class StreamJoin:
             ring, cells_dev, start_step, int(n_batches),
             int(snapshot_every), collect,
         )
+        _stamp_ready(entry_span)
         bounds = [
             (s, min(snapshot_every, n_batches - s))
             for s in range(start_step, int(n_batches), snapshot_every)
@@ -1312,18 +1387,13 @@ class StreamJoin:
 
         def submit_snapshot(se, acc, cells):
             def job(se=se, acc=np.asarray(acc, np.int64), cells=cells):
-                def snap():
-                    payload = self._snapshot_payload(
-                        acc, cells, extra_arrays
-                    )
-                    return _checkpoint.save_snapshot(
-                        run_dir, se, payload, meta
-                    )
-
-                with _trace.span("stream.snapshot", step=se, mode="async"):
+                with _trace.span(
+                    "stream.snapshot", step=se, mode="async"
+                ) as ssp:
                     try:
                         _dispatch.guarded_call(
-                            "stream.snapshot", snap,
+                            "stream.snapshot", self._save_snapshot, ssp,
+                            run_dir, se, acc, cells, extra_arrays, meta,
                             default_s=watchdog_default_s,
                             policy=retry_policy,
                         )
@@ -1513,6 +1583,15 @@ class StreamJoin:
             ),
             metrics=metrics,
         )
+
+
+def _stamp_ready(entry_span) -> None:
+    """``ready_s`` on the span a durable entry point opened (`resume`'s
+    ``stream.resume``): its seconds up to here, where the first segment
+    is about to be launched — load, ring hash, carry put and the warm
+    segments are behind, the run is ahead."""
+    if entry_span is not None:
+        entry_span.set(ready_s=round(entry_span.elapsed(), 6))
 
 
 def _wrap_i32(v: np.ndarray) -> np.ndarray:
