@@ -20,7 +20,9 @@ Three pieces, all CPU-testable and bit-identical to the per-batch path:
   the cell ids computed in iteration i-1 and computes batch i+1's cell
   assignment in the same program. The two stages have no data dependency,
   so XLA overlaps the cell pipeline (one-hot MXU work) with the PIP
-  probe's gather/scatter phases instead of serializing them.
+  probe's gather/scatter phases instead of serializing them. A dispatch
+  of ``nb`` batches runs ``nb`` assignments: one before the scan and one
+  in every iteration but the last.
 - **Accounting** — every stage emits a `stream_stage` telemetry event
   (`runtime/telemetry.py`) with measured wall seconds, and
   :func:`hbm_peak` reports the loop's high-water device memory — from
@@ -349,6 +351,13 @@ def build_stream_programs(
             )
 
     def loop(ring, chip_index, nb: int, collect: bool):
+        """One dispatch: batches [0, nb) of the ring, slot ``i % k``,
+        joined and folded in one scan. It assigns cells once a batch:
+        under ``prefetch`` batch 0's before the scan and batch i + 1's in
+        iteration i, guarded on ``i + 1 < nb`` (``nb`` is static); without
+        it batch i's in iteration i. (``seg`` takes its first cells as an
+        argument and returns its last, for the snapshot, so its body's
+        ``nb`` are all read.)"""
         k = ring.shape[0]
         # over an index with heavy cells the fold has a fourth entry, the
         # count of rows whose cell is heavy (`metrics["heavy_rows"]`); an
@@ -375,7 +384,14 @@ def build_stream_programs(
                 # assign batch i+1's cells in the SAME program so XLA
                 # overlaps the cell pipeline with the probe
                 out, heavy = joined(slot(i), cells_cur)
-                cells_next = assign(slot(i + 1))
+                # the last iteration prefetches nothing: batch nb is no
+                # part of this dispatch, and XLA cannot drop an assignment
+                # whose carry every other iteration reads
+                cells_next = jax.lax.cond(
+                    i + 1 < nb,
+                    lambda: assign(slot(i + 1)),
+                    lambda: cells_cur,
+                )
                 return (fold(acc, out, heavy), cells_next), (
                     out if collect else None
                 )
@@ -617,8 +633,9 @@ class StreamJoin:
         """One timed streamed pass over ``n_batches`` ring cycles.
 
         The whole stream is ONE dispatch (per-batch python dispatch
-        measured 146 ms/batch for a 63 ms device step in r05);
-        completion is forced by pulling the (3,) fold. With
+        measured 146 ms/batch for a 63 ms device step in r05) that
+        assigns cells ``n_batches`` times, once a batch, with or without
+        ``prefetch``; completion is forced by pulling the (3,) fold. With
         ``donate_ring`` the ring buffer is donated to the loop —
         ``metrics["ring_donated"]`` records whether the backend applied
         the donation (CPU declines; the ring then stays live).

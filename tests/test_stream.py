@@ -49,12 +49,16 @@ ZONES = [
 K, BATCH, NB = 3, 4096, 7  # NB > K: the ring must cycle
 
 
+def _zones_index(grid=CUSTOM, **kw):
+    return build_chip_index(
+        tessellate(wkt.from_wkt(ZONES), grid, RES, keep_core_geoms=False),
+        **kw,
+    )
+
+
 @pytest.fixture(scope="module")
 def index():
-    col = wkt.from_wkt(ZONES)
-    return build_chip_index(
-        tessellate(col, CUSTOM, RES, keep_core_geoms=False)
-    )
+    return _zones_index()
 
 
 @pytest.fixture(scope="module")
@@ -114,6 +118,103 @@ def test_prefetch_equals_non_prefetch(index, ring, sj):
         r0.checksum, r0.matches, r0.overflow
     )
     assert r1.prefetch and not r0.prefetch
+
+
+class _CountingGrid(CustomIndexSystem):
+    """`CUSTOM` with a host callback in every TRACED `point_to_cell`: one
+    entry of ``ran`` for each time a compiled assignment executes (a
+    trace adds none, and the guarded branch not taken adds none)."""
+
+    def __init__(self, conf):
+        super().__init__(conf)
+        self.ran = []
+
+    def point_to_cell(self, pts, res):
+        if isinstance(pts, jax.core.Tracer):
+            jax.debug.callback(lambda: self.ran.append(1))
+        return super().point_to_cell(pts, res)
+
+
+@pytest.fixture(scope="module")
+def counting():
+    grid = _CountingGrid(CUSTOM.conf)
+    return grid, _zones_index(grid)
+
+
+@pytest.mark.parametrize("prefetch", [True, False])
+@pytest.mark.parametrize("nb", [1, 2, K + 1, NB])
+def test_a_dispatch_assigns_cells_once_a_batch(counting, ring, nb, prefetch):
+    """``run(ring, nb)`` EXECUTES ``nb`` cell assignments: under prefetch
+    the prologue's and one in every iteration but the last (it ran
+    ``nb + 1`` until PR 53 and dropped the last one's cells with the
+    carry: batch ``nb`` belongs to no dispatch); without prefetch one an
+    iteration, as ever. Executions, not traces: the second run of a
+    compiled loop counts the same."""
+    grid, index = counting
+    sj = StreamJoin(index, grid, RES, prefetch=prefetch)
+    for _ in range(2):
+        grid.ran.clear()
+        sj.run(ring, nb)
+        jax.effects_barrier()
+        assert len(grid.ran) == nb
+
+
+@pytest.fixture(scope="module")
+def heavy_index():
+    """`ZONES` at an edge cap of 4: 25 of its cells are heavy, so the
+    loop's fold has its fourth entry (``counted``)."""
+    index = _zones_index(edge_cap=4)
+    assert index.num_heavy_cells > 0
+    return index
+
+
+def _heavy_rows(sj, ring, nb):
+    """Rows of batches [0, nb) whose cell is heavy: a numpy count over
+    the host's tables on the stream's own (jitted, per-batch) cells."""
+    host = sj.index.host
+    if not sj.index.num_heavy_cells:
+        return 0
+    cells = np.concatenate(
+        [np.asarray(sj.assign(ring[i % K])) for i in range(nb)]
+    )
+    u = np.clip(np.searchsorted(host.cells, cells), 0, host.cells.size - 1)
+    return int(((host.cells[u] == cells) & (host.cell_heavy[u] >= 0)).sum())
+
+
+@pytest.mark.parametrize("heavy", [False, True])
+@pytest.mark.parametrize("nb", [1, 2, K, K + 1, NB])
+def test_every_dispatch_length_equals_the_unpipelined_paths(
+        index, heavy_index, ring, tmp_path, nb, heavy):
+    """The guarded prefetch is invisible in the answers at every dispatch
+    length — one batch, two, under the ring, the ring, past it (wrapping):
+    ``run``'s fold and collected rows are ``run_batched``'s, an unbroken
+    ``run_durable``'s (whose segments DO read their last assignment: it
+    is the snapshot's) and the unprefetched loop's, bit for bit, also
+    over heavy cells, whose row count is the host tables' own."""
+    ix = heavy_index if heavy else index
+    sj = StreamJoin(ix, CUSTOM, RES)
+    got = sj.run(ring, nb, collect=True)
+    fold = (got.checksum, got.matches, got.overflow)
+    assert got.outs.shape == (nb, BATCH) and got.matches > 0
+    unprefetched = StreamJoin(ix, CUSTOM, RES, prefetch=False).run(
+        ring, nb, collect=True
+    )
+    others = {
+        "uncollected": sj.run(ring, nb),
+        "batched": sj.run_batched(ring, nb),
+        "durable": sj.run_durable(
+            ring, nb, run_dir=str(tmp_path), snapshot_every=2, collect=True
+        ),
+        "unprefetched": unprefetched,
+    }
+    for name, r in others.items():
+        assert fold == (r.checksum, r.matches, r.overflow), name
+        if r.outs is not None:
+            np.testing.assert_array_equal(got.outs, r.outs, err_msg=name)
+    heavy_rows = _heavy_rows(sj, ring, nb)
+    assert (heavy_rows > 0) == heavy
+    assert got.metrics["heavy_rows"] == heavy_rows
+    assert unprefetched.metrics["heavy_rows"] == heavy_rows
 
 
 def test_step_stats_folds_step(ring, sj):

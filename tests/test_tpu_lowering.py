@@ -263,6 +263,47 @@ def test_bench_step_lowers_for_tpu(problem):
     assert len(hlo) > 1000
 
 
+def test_stream_loop_assigns_once_a_batch_in_the_tpu_lowering(problem):
+    """`StreamJoin`'s prefetching loop at four steps holds the cell
+    assignment twice — the prologue's, for batch 0, and the scan body's,
+    for batch i + 1 — and the body's sits under ONE conditional whose
+    other branch hands the carried cells on: the prologue and three
+    guarded prefetches make four assignments for four batches. Until
+    PR 53 the body's ran unguarded, five for four, the fifth's cells
+    dropped with the carry (`PERF.md` section 6, PR 53). Pinned on the
+    traced program and on its TPU lowering, so that a later edit cannot
+    bring the fifth back unseen."""
+    from mosaic_tpu.sql.stream import StreamJoin
+
+    h3, index, _ = problem
+    ring = jnp.asarray(np.stack(
+        [random_points(4096, bbox=BBOX, seed=s) for s in (1, 2)]))
+    sj = StreamJoin(index, h3, 7)
+    assert sj.prefetch
+    traced = sj._loop.trace(ring, index, 4, False)
+
+    def in_cells(eqn):
+        return "pip.cells" in str(eqn.source_info.name_stack)
+
+    program = traced.jaxpr.jaxpr
+    assert any(in_cells(e) for e in program.eqns)  # the prologue's
+    (scan,) = [e for e in program.eqns if e.primitive.name == "scan"]
+    assert scan.params["length"] == 4
+    body = scan.params["jaxpr"].jaxpr
+    assert not any(in_cells(e) for e in body.eqns)  # none unguarded
+    (guard,) = [e for e in body.eqns if e.primitive.name == "cond"]
+    kept, assigned = (b.jaxpr for b in guard.params["branches"])
+    assert not kept.eqns and kept.outvars == kept.invars[-1:]
+    assert sum(map(in_cells, assigned.eqns)) > len(assigned.eqns) // 2
+    # the lowering: one `while`, and one conditional more than the two
+    # assignments bring (H3's digit pipeline holds some of its own)
+    hlo = traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    own = _tpu_lower(sj.assign.trace(ring[0])).count('"stablehlo.case"(')
+    assert hlo.count("stablehlo.while") == 1
+    assert hlo.count('"stablehlo.case"(') == 2 * own + 1
+    assert "pip.cells" in hlo  # the scope `obs/stages.py` names the ops by
+
+
 def test_dist_join_step_lowers_for_tpu(problem, devices):
     from mosaic_tpu.parallel import (
         distributed_join_step,
